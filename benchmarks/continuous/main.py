@@ -52,7 +52,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    maybe_init_distributed,
+    start_host_only_run,
+)
 
 
 def bucket_bytes(root: str) -> int:
@@ -78,6 +81,7 @@ def main() -> None:
     # single-vCPU hosts and the whole chain story silently degrades to
     # full rewrites (same rationale as benchmarks/incremental).
     os.environ["TORCHSNAPSHOT_TPU_DEDUP_DIGESTS"] = "1"
+    host_only = start_host_only_run("continuous")
     maybe_init_distributed()
 
     from torchsnapshot_tpu import Snapshot, StateDict
@@ -318,6 +322,7 @@ def main() -> None:
                 f"false-positive anomalies on clean run: {kinds}"
             )
         result["detail"]["problems"] = problems
+        result["device"] = host_only
         print(json.dumps(result))
         if problems:
             print(f"FAILED: {problems}", file=sys.stderr)

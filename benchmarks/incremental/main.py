@@ -18,7 +18,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    maybe_init_distributed,
+    start_host_only_run,
+)
 
 
 def main() -> None:
@@ -27,6 +30,7 @@ def main() -> None:
     # every incremental take to a full rewrite — this benchmark would then
     # "pass" while measuring nothing (ADVICE round 5).
     os.environ["TORCHSNAPSHOT_TPU_DEDUP_DIGESTS"] = "1"
+    start_host_only_run("incremental")
     maybe_init_distributed()
     parser = argparse.ArgumentParser()
     parser.add_argument("--frozen-gb", type=float, default=1.0)

@@ -17,10 +17,14 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    maybe_init_distributed,
+    start_host_only_run,
+)
 
 
 def main() -> None:
+    start_host_only_run("load_tensor")
     maybe_init_distributed()
     parser = argparse.ArgumentParser()
     parser.add_argument("--gb", type=float, default=1.0)

@@ -20,11 +20,11 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import start_measured_run  # noqa: E402
 
 
 def main() -> None:
-    maybe_init_distributed()
+    start_measured_run()  # refuses the CPU backend
     parser = argparse.ArgumentParser()
     parser.add_argument("--layers", type=int, default=4)
     parser.add_argument("--d-model", type=int, default=1024)

@@ -10,7 +10,7 @@ devices and reports, per leg:
 - total save wall time (stall + background drain / wait_until_finished);
 - blocking restore time, with bit-exactness asserted for both.
 
-Legs (``--leg``, VERDICT round 2 item 5 — the differentiating axes):
+Legs (``--leg`` — the differentiating axes):
 
 - ``single``  — one-chip bf16 param pytree (the round-2 leg);
 - ``sharded`` — params + adam moments sharded over a (dp, tp) device mesh;
@@ -21,10 +21,10 @@ Legs (``--leg``, VERDICT round 2 item 5 — the differentiating axes):
   of the same changed state.
 
   python benchmarks/orbax_compare/main.py --gb 0.5
-  python benchmarks/orbax_compare/main.py --cpu --leg sharded
+  python benchmarks/orbax_compare/main.py --leg sharded   # needs >= 2 chips
 
-Runs on the real TPU chip by default; pass --cpu for the virtual 8-device
-mesh (required for the sharded/reshard legs on a single-chip host).
+Runs on the accelerator and refuses the CPU backend: a time from XLA's host
+code is not a device metric.
 """
 
 import argparse
@@ -36,7 +36,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import start_measured_run  # noqa: E402
 
 
 def _bit_eq(a, b) -> bool:
@@ -286,13 +286,11 @@ def _run_incremental_leg(root: str, gb: float) -> None:
 
 
 def main() -> None:
-    maybe_init_distributed()
     parser = argparse.ArgumentParser()
     parser.add_argument("--gb", type=float, default=0.5)
     parser.add_argument(
         "--reps", type=int, default=2, help="interleaved reps per library (sharded legs)"
     )
-    parser.add_argument("--cpu", action="store_true")
     parser.add_argument(
         "--leg",
         choices=["single", "sharded", "reshard", "incremental", "all"],
@@ -300,18 +298,13 @@ def main() -> None:
     )
     args = parser.parse_args()
 
-    if args.cpu:
-        os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    start_measured_run()  # refuses the CPU backend
     import jax
 
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict
-
-    print(f"device: {jax.devices()[0].device_kind}", file=sys.stderr)
 
     if args.leg in ("sharded", "reshard", "incremental", "all"):
         root = tempfile.mkdtemp()

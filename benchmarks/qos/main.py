@@ -55,6 +55,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
+from benchmarks.common import start_host_only_run  # noqa: E402
+
 BG_MB = int(os.environ.get("QOS_BENCH_BG_MB", "64"))
 FG_MB = int(os.environ.get("QOS_BENCH_FG_MB", "8"))
 RESTORES = int(os.environ.get("QOS_BENCH_RESTORES", "3"))
@@ -364,6 +366,7 @@ def e2e_leg(root: str) -> dict:
 
 
 def main() -> None:
+    host_only = start_host_only_run("qos")
     root = tempfile.mkdtemp(prefix="qos_bench_")
     try:
         sides = {"on": [], "off": []}
@@ -451,6 +454,7 @@ def main() -> None:
                 f"({on_p99:.4f}s) did not beat FIFO ({off_p99:.4f}s) — "
                 "preemption is not delivering foreground latency"
             )
+        result["device"] = host_only
         print(json.dumps(result))
     finally:
         shutil.rmtree(root, ignore_errors=True)

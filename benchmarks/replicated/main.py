@@ -19,7 +19,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    maybe_init_distributed,
+    start_host_only_run,
+)
 
 
 def _make_state(total_gb: float):
@@ -46,6 +49,7 @@ def _worker(rank: int, world_size: int, shared: str, total_gb: float) -> None:
 
 
 def main() -> None:
+    start_host_only_run("replicated")
     maybe_init_distributed()
     parser = argparse.ArgumentParser()
     parser.add_argument("--gb", type=float, default=1.0)

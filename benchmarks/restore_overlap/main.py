@@ -1,16 +1,14 @@
-"""Restore-overlap A/B on real hardware (VERDICT round 4, item 5).
+"""Restore-overlap A/B on real hardware.
 
 The overlapped restore (``TORCHSNAPSHOT_TPU_RESTORE_OVERLAP``) finalizes
 each entry's host→device transfer inline as its last storage read consumes,
-instead of phase-splitting all H2D after the read pipeline. Until round 5
-the overlap win was demonstrated only on a synthetic latency-bound storage
-fake (``tests/test_restore_overlap.py``); this harness measures both modes
-on real hardware, wall + peak RSS, interleaved with alternating order. Its
-round-5 run on the 1-vCPU host + real TPU (overlap 3.60 s vs phase-split
-5.57 s median, peak RSS 0.94 vs 1.32 GB; ``results_round5_tpu.txt``) is
-what flipped the auto gate to platform-aware: accelerator-backend H2D
-dispatch is a PJRT hand-off, so overlap wins even with no spare core —
-only the CPU backend on one core keeps the phase split.
+instead of phase-splitting all H2D after the read pipeline. The suite
+demonstrates the overlap only on a synthetic latency-bound storage fake
+(``tests/test_restore_overlap.py``); this harness measures both modes on
+real hardware, wall + peak RSS, interleaved with alternating order. The
+auto gate is platform-aware: accelerator-backend H2D dispatch is a PJRT
+hand-off, so overlap needs no spare core — only the CPU backend on one
+core keeps the phase split. Not measured on the current chip.
 
   python benchmarks/restore_overlap/main.py --gb 0.5 --reps 3
 
@@ -28,35 +26,22 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-from benchmarks.common import maybe_init_distributed  # noqa: E402
+from benchmarks.common import start_measured_run  # noqa: E402
 
 
 def main() -> None:
-    maybe_init_distributed()
     parser = argparse.ArgumentParser()
     parser.add_argument("--gb", type=float, default=0.5)
     parser.add_argument("--reps", type=int, default=3)
-    parser.add_argument(
-        "--cpu", action="store_true", help="force the (multi-device) CPU platform"
-    )
     args = parser.parse_args()
 
-    if args.cpu:
-        os.environ.setdefault(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8"
-        )
+    start_measured_run()  # refuses the CPU backend
     import jax
-
-    if args.cpu:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from torchsnapshot_tpu import Snapshot, StateDict
     from torchsnapshot_tpu.utils import knobs
     from torchsnapshot_tpu.utils.rss_profiler import measure_rss_deltas
-
-    d = jax.devices()[0]
-    print(f"device: {d.device_kind} ({d.platform})", file=sys.stderr)
 
     n_arrays = max(2, round(args.gb * 1e9 / (32 * 1024 * 1024)))
     ks = jax.random.split(jax.random.PRNGKey(0), n_arrays)

@@ -38,6 +38,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
+from benchmarks.common import start_host_only_run  # noqa: E402
+
 import numpy as np  # noqa: E402
 
 from torchsnapshot_tpu import Snapshot, StateDict, telemetry  # noqa: E402
@@ -346,6 +348,7 @@ def run_lazy_leg(origin_root: str, total_mb: float) -> dict:
 
 
 def main() -> None:
+    host_only = start_host_only_run("serving")
     total_mb = float(os.environ.get("SERVING_BENCH_MB", "64"))
     replicas = int(os.environ.get("SERVING_BENCH_REPLICAS", "8"))
     bcast_on = os.environ.get("SERVING_BENCH_BCAST", "1") not in ("0", "false")
@@ -375,6 +378,7 @@ def main() -> None:
                     "metric": "serving_cold_start_restore_p50",
                     "value": cache["on"]["restore_p50_s"],
                     "unit": "s",
+                    "device": host_only,
                     "detail": {
                         "payload_mb": total_mb,
                         "replicas": replicas,

@@ -31,6 +31,8 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
+from benchmarks.common import start_host_only_run  # noqa: E402
+
 import numpy as np  # noqa: E402
 
 from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer  # noqa: E402
@@ -148,6 +150,7 @@ def run_config(
 
 
 def main() -> None:
+    host_only = start_host_only_run("staging", writes_files=False)  # null sink
     total_mb = int(os.environ.get("STAGING_BENCH_MB", "512"))
     arrays = int(os.environ.get("STAGING_BENCH_ARRAYS", "8"))
     arrs = build_host_state(total_mb, arrays)
@@ -221,6 +224,7 @@ def main() -> None:
                 "metric": "staging_overhead_gbps",
                 "value": results["full"]["gbps"],
                 "unit": "GB/s",
+                "device": host_only,
                 "detail": {
                     "size_gb": round(total_gb, 3),
                     "arrays": arrays,

@@ -10,7 +10,7 @@ sharded over the global (dp, tp) mesh, and each rank reports its stall, its
 per-phase decomposition, and — new in round 3 — its **store round-trip
 counts** per take from ``parallel.store.get_op_counts``.
 
-Why round-trips: on this 1-vCPU host, wall time at world 8 confounds
+Why round-trips: on a host with fewer cores than ranks, wall time at world 8 confounds
 coordination cost with CPU time-slicing; the round-trip count is the
 confound-free quantity. Steady-state takes hit the cross-take plan cache
 (``take_plan.py``) and issue a CONSTANT number of round-trips per rank
@@ -18,7 +18,7 @@ regardless of world size; first takes pay O(world) on rank 0's gathers. The
 ``--sweep`` mode runs worlds {1,2,4,8}, verifies the constant-steady-state
 property, and projects the v5e-256 stall as
 ``roundtrips x per-op latency`` — a calculation, not an extrapolated wall
-time (VERDICT round 2, items 1 and 8).
+time.
 
   python benchmarks/stall/main.py --nproc 4 --mb-per-rank 64 --steps 3
   python benchmarks/stall/main.py --sweep
@@ -32,6 +32,8 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
+
+from benchmarks.common import start_host_only_run  # noqa: E402
 
 
 def _worker(
@@ -258,7 +260,7 @@ def _sweep(mb_per_rank: int, steps: int) -> None:
         ),
         # The 2 ms/op RTT is an assumption, not a measurement; carry the
         # projection across plausible control-plane latencies so the <5 s
-        # claim's sensitivity is explicit (VERDICT round 3, weak 6).
+        # claim's sensitivity is explicit.
         "rtt_sensitivity": {
             f"{rtt * 1000:g}ms": {
                 "world256_stall_cached_s": round((a_c * 256 + b_c) * rtt, 4),
@@ -274,6 +276,7 @@ def _sweep(mb_per_rank: int, steps: int) -> None:
 
 
 def main() -> None:
+    start_host_only_run("stall")
     parser = argparse.ArgumentParser()
     parser.add_argument("--nproc", type=int, default=4)
     parser.add_argument("--mb-per-rank", type=int, default=64)

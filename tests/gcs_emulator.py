@@ -1,7 +1,7 @@
 """A minimal local GCS emulator speaking the JSON/upload API subset the real
 ``google-cloud-storage`` + ``google-resumable-media`` SDKs use.
 
-Purpose (VERDICT round 2, missing #1 / next-round #3): the round-2 GCS tests
+Purpose: the round-2 GCS tests
 drilled the plugin's retry/recovery logic through monkeypatched fakes, which
 leaves the actual SDK wire path — multipart uploads, the resumable-upload
 session protocol (308/Range cursor semantics), ranged media downloads, the

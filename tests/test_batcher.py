@@ -92,11 +92,13 @@ def _device_arrays(n=12, dtype="bfloat16"):
 
 
 @pytest.mark.parametrize(
-    "dtype", ["bfloat16", "float32", "int8", "bool", "float8_e4m3fn"]
+    "dtype", ["bfloat16", "float32", "int8", "bool", "float8_e4m3fn", "float16"]
 )
-def test_device_batched_take_restore(tmp_path, dtype, caplog) -> None:
-    """On-device slab packing (single D2H) must be byte-identical to the
-    host-side packing path for every byte-width dtype family."""
+def test_device_batched_take_restore(tmp_path, dtype) -> None:
+    """Slabs of dtypes the pack program returns bit for bit are packed on
+    the device (single D2H); sub-32-bit float slabs are packed on the host
+    (the device pack rewrites their denormals / NaN payloads on the TPU).
+    Either way the take says which, and the restore is byte-identical."""
     import jax.numpy as jnp
 
     if dtype == "bool":
@@ -110,22 +112,21 @@ def test_device_batched_take_restore(tmp_path, dtype, caplog) -> None:
         }
     else:
         arrs = _device_arrays(dtype=dtype)
+    on_device = dtype in ("float32", "int8", "bool")
     expected = {k: np.ascontiguousarray(np.asarray(v)) for k, v in arrs.items()}
     path = str(tmp_path / "dev")
     from torchsnapshot_tpu import batcher as batcher_mod
 
     batcher_mod._PACK_FNS.clear()
-    with caplog.at_level("WARNING", logger="torchsnapshot_tpu.batcher"):
-        with knobs.override_batching_enabled(
-            True
-        ), knobs.override_slab_size_threshold_bytes(10**6):
-            snap = Snapshot.take(path, {"s": StateDict(**arrs)})
-    # The on-device packer must have engaged AND not fallen back to host
-    # packing (the jit wrapper is cached even when its call fails).
-    assert len(batcher_mod._PACK_FNS) == 1, "device packing did not engage"
-    assert not any(
-        "falling back" in r.message for r in caplog.records
-    ), "device packing fell back to host path"
+    with knobs.override_batching_enabled(
+        True
+    ), knobs.override_slab_size_threshold_bytes(10**6):
+        snap = Snapshot.take(path, {"s": StateDict(**arrs)})
+    metrics = Snapshot.last_telemetry.metrics.as_dict()
+    assert len(batcher_mod._PACK_FNS) == (1 if on_device else 0)
+    assert metrics.get("batcher.slabs_device_packed", 0) == (1 if on_device else 0)
+    assert metrics.get("batcher.slabs_host_packed", 0) == (0 if on_device else 1)
+    assert "batcher.slabs_pack_degraded" not in metrics
     out = StateDict(**{k: jnp.zeros_like(v) for k, v in arrs.items()})
     Snapshot(path).restore({"s": out})
     for k, want in expected.items():
@@ -141,6 +142,36 @@ def test_device_batched_take_restore(tmp_path, dtype, caplog) -> None:
         if getattr(e, "location", "").startswith("batched/")
     }
     assert len(slabbed) == 1  # all members fit one slab
+
+
+def test_mixed_dtype_members_split_into_device_and_host_slabs(tmp_path) -> None:
+    """A sub-32-bit float member must not drag the members the device can
+    pack onto the host path, nor ride a device slab itself: slabs close at
+    the boundary."""
+    import jax.numpy as jnp
+
+    arrs = {f"f{k}": v for k, v in _device_arrays(n=4, dtype="float32").items()}
+    arrs.update({f"b{k}": v for k, v in _device_arrays(n=4, dtype="bfloat16").items()})
+    path = str(tmp_path / "mixed")
+    with knobs.override_batching_enabled(
+        True
+    ), knobs.override_slab_size_threshold_bytes(10**6):
+        snap = Snapshot.take(path, {"s": StateDict(**arrs)})
+    metrics = Snapshot.last_telemetry.metrics.as_dict()
+    assert metrics["batcher.slabs_device_packed"] == 1
+    assert metrics["batcher.slabs_host_packed"] == 1
+    by_slab = {}
+    for logical, e in snap.get_manifest().items():
+        if getattr(e, "location", "").startswith("batched/"):
+            by_slab.setdefault(e.location, set()).add(e.dtype)
+    assert sorted(map(sorted, by_slab.values())) == [["bfloat16"], ["float32"]]
+    out = StateDict(**{k: jnp.zeros_like(v) for k, v in arrs.items()})
+    Snapshot(path).restore({"s": out})
+    for k, v in arrs.items():
+        assert np.array_equal(
+            np.ascontiguousarray(np.asarray(out[k])).view(np.uint8),
+            np.ascontiguousarray(np.asarray(v)).view(np.uint8),
+        ), k
 
 
 def test_device_batched_matches_host_packed_bytes(tmp_path) -> None:
@@ -170,7 +201,7 @@ def test_device_batched_async_take(tmp_path, caplog) -> None:
     """Deferred (async) slabs of device arrays pack on the background thread."""
     from torchsnapshot_tpu import batcher as batcher_mod
 
-    arrs = _device_arrays(dtype="bfloat16")
+    arrs = _device_arrays(dtype="int8")
     expected = {k: np.ascontiguousarray(np.asarray(v)) for k, v in arrs.items()}
     path = str(tmp_path / "async")
     batcher_mod._PACK_FNS.clear()
@@ -188,35 +219,65 @@ def test_device_batched_async_take(tmp_path, caplog) -> None:
     )
 
 
-def test_device_batching_fallback_unsupported_dtype(tmp_path) -> None:
-    """A slab with a non-packable member (complex) takes the host path and
-    still round-trips."""
+def test_device_batching_unsupported_dtype_packs_on_host(tmp_path) -> None:
+    """Non-packable members (complex) get a host-packed slab of their own
+    and still round-trip; the packable members keep the device path."""
     import jax.numpy as jnp
 
     from torchsnapshot_tpu import batcher as batcher_mod
 
     arrs = _device_arrays(n=4, dtype="float32")
-    arrs["c"] = jnp.arange(8, dtype=jnp.complex64)
+    arrs["c0"] = jnp.arange(8, dtype=jnp.complex64)
+    arrs["c1"] = jnp.arange(8, 16, dtype=jnp.complex64)
     batcher_mod._PACK_FNS.clear()
     path = str(tmp_path / "mix")
     with knobs.override_batching_enabled(True), knobs.override_slab_size_threshold_bytes(
         10**6
     ):
         Snapshot.take(path, {"s": StateDict(**arrs)})
-    assert len(batcher_mod._PACK_FNS) == 0  # device packer must NOT engage
+    metrics = Snapshot.last_telemetry.metrics.as_dict()
+    assert len(batcher_mod._PACK_FNS) == 1  # the float32 slab only
+    assert metrics["batcher.slabs_device_packed"] == 1
+    assert metrics["batcher.slabs_host_packed"] == 1
     out = StateDict(**{k: jnp.zeros_like(v) for k, v in arrs.items()})
     Snapshot(path).restore({"s": out})
     for k, v in arrs.items():
         assert np.array_equal(np.asarray(out[k]), np.asarray(v)), k
 
 
-def test_device_pack_failure_memoized(tmp_path, caplog, monkeypatch) -> None:
-    """A failing pack signature warns once, then skips the device path on
+def test_device_pack_refusal_is_an_error_not_a_fallback(tmp_path, monkeypatch) -> None:
+    """Only an allocation failure may degrade to host packing: a compile
+    refusal (or any other failure) would otherwise be indistinguishable from
+    HBM pressure and hide for the cooldown. It aborts the take."""
+    from torchsnapshot_tpu import batcher as batcher_mod
+    from torchsnapshot_tpu.snapshot import CheckpointAbortedError
+
+    def boom(key, arrs):
+        raise RuntimeError("INVALID_ARGUMENT: simulated compile refusal")
+
+    monkeypatch.setattr(batcher_mod, "_pack_to_device_bytes", boom)
+    monkeypatch.setattr(batcher_mod, "_PACK_FAILED", {})  # auto-restored
+    arrs = _device_arrays(n=4, dtype="float32")
+    with knobs.override_batching_enabled(
+        True
+    ), knobs.override_slab_size_threshold_bytes(10**6):
+        with pytest.raises(CheckpointAbortedError, match="simulated compile refusal"):
+            Snapshot.take(str(tmp_path / "a"), {"s": StateDict(**arrs)})
+    assert not batcher_mod._PACK_FAILED
+
+
+def test_device_pack_oom_degrades_counted_and_memoized(
+    tmp_path, caplog, monkeypatch
+) -> None:
+    """A pack that cannot get its HBM warns once and packs on the host, is
+    counted (``batcher.slabs_pack_degraded``), then skips the device path on
     subsequent takes instead of re-failing (and re-warning) every time."""
     from torchsnapshot_tpu import batcher as batcher_mod
 
     def boom(key, arrs):
-        raise RuntimeError("simulated pack failure")
+        raise RuntimeError(
+            "RESOURCE_EXHAUSTED: Error allocating device buffer (simulated)"
+        )
 
     monkeypatch.setattr(batcher_mod, "_pack_to_device_bytes", boom)
     monkeypatch.setattr(batcher_mod, "_PACK_FAILED", {})  # auto-restored
@@ -234,6 +295,9 @@ def test_device_pack_failure_memoized(tmp_path, caplog, monkeypatch) -> None:
     total_warnings = sum("falling back" in r.message for r in caplog.records)
     assert first_warnings == 1
     assert total_warnings == 1  # second take skipped silently
+    metrics = Snapshot.last_telemetry.metrics.as_dict()
+    assert metrics["batcher.slabs_pack_degraded"] == 1
+    assert "batcher.slabs_device_packed" not in metrics
     out = StateDict()
     Snapshot(str(tmp_path / "b")).restore({"s": out})
     for k, want in expected.items():

@@ -7,7 +7,6 @@ the serializer recorded per entry so restore auto-detects and mixed
 snapshots coexist.
 """
 
-import importlib.util
 import os
 
 import numpy as np
@@ -20,17 +19,6 @@ from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.serialization import Serializer
 from torchsnapshot_tpu.test_utils import rand_array
 from torchsnapshot_tpu.utils import knobs
-
-# Capability gate: most tests here drive REAL zstd compression and need the
-# zstandard package; environments without it (it is an optional dependency)
-# skip them rather than fail. Tests that only *simulate* a missing
-# zstandard (test_missing_zstandard_fails_fast) stay ungated, and zlib
-# coverage (stdlib) always runs.
-HAS_ZSTD = importlib.util.find_spec("zstandard") is not None
-requires_zstd = pytest.mark.skipif(
-    not HAS_ZSTD, reason="zstandard not installed (optional dependency)"
-)
-
 
 def _app():
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("x",))
@@ -80,7 +68,7 @@ def _tree_bytes(root: str) -> int:
 @pytest.mark.parametrize(
     "codec,serializer",
     [
-        pytest.param("zstd", Serializer.RAW_ZSTD, marks=requires_zstd),
+        ("zstd", Serializer.RAW_ZSTD),
         ("zlib", Serializer.RAW_ZLIB),
     ],
 )
@@ -99,7 +87,6 @@ def test_compressed_roundtrip(tmp_path, codec, serializer) -> None:
     assert Snapshot(path).verify() == {}
 
 
-@requires_zstd
 def test_compression_shrinks_storage(tmp_path) -> None:
     app = _app()  # arange/ones data: highly compressible
     plain = str(tmp_path / "plain")
@@ -110,7 +97,6 @@ def test_compression_shrinks_storage(tmp_path) -> None:
     assert _tree_bytes(comp) < _tree_bytes(plain) * 0.7
 
 
-@requires_zstd
 def test_compressed_read_object_ignores_byte_budget_correctly(tmp_path) -> None:
     """Compressed entries are not byte-range addressable: read_object with a
     budget still returns exact data via whole-object reads."""
@@ -124,7 +110,6 @@ def test_compressed_read_object_ignores_byte_budget_correctly(tmp_path) -> None:
     assert np.array_equal(got, app["m"]["f32"])
 
 
-@requires_zstd
 def test_compressed_chunked_roundtrip(tmp_path) -> None:
     with knobs.override_max_chunk_size_bytes(1024), knobs.override_compression("zstd"):
         arr = np.arange(64 * 32, dtype=np.float32).reshape(64, 32)
@@ -138,13 +123,12 @@ def test_compressed_chunked_roundtrip(tmp_path) -> None:
     assert np.array_equal(tgt["a"], arr)
 
 
-@requires_zstd
 def test_compression_composes_with_batching(tmp_path) -> None:
     """Small compressed entries coalesce into member-framed compressed
     slabs: the manifest records each member's RAW range within the packed
     slab (compressed sizes don't exist at planning time), the slab's
     ``.ftab`` maps raw ranges to compressed frames, and restore reads each
-    member via its covering frames (VERDICT round 3, item 8)."""
+    member via its covering frames."""
     app = _app()
     path = str(tmp_path / "b")
     with knobs.override_batching_enabled(True), knobs.override_slab_size_threshold_bytes(1 << 20):
@@ -168,12 +152,11 @@ def test_compression_composes_with_batching(tmp_path) -> None:
         assert Snapshot(path).verify() == {}
 
 
-@requires_zstd
 def test_async_device_compressed_entries_batch_into_slabs(tmp_path) -> None:
     """Async takes get BOTH wins now: small compressed device entries join
     slabs (one storage object, one D2H via the device-batched packer) and
     compress at drain time — never inside the stall window — because the
-    slab is compressed member-framed at staging (VERDICT round 3, item 8)."""
+    slab is compressed member-framed at staging."""
     dev = jax.devices()[0]
     dev_a = jax.device_put(jnp.asarray(np.arange(256, dtype=np.float32)), dev)
     dev_b = jax.device_put(jnp.asarray(np.arange(256, dtype=np.float32) + 1), dev)
@@ -244,7 +227,6 @@ def _worker_replicated_compressed_slab(rank, world_size, shared):
 
 
 @pytest.mark.multiprocess
-@requires_zstd
 def test_replicated_compressed_slab_consolidates_across_ranks(tmp_path) -> None:
     from torchsnapshot_tpu.test_utils import run_with_processes
 
@@ -267,7 +249,6 @@ def _worker_take_replicated_slab(rank, world_size, shared):
 
 
 @pytest.mark.multiprocess
-@requires_zstd
 def test_compressed_slab_snapshot_elastic_across_world_sizes(tmp_path) -> None:
     """Elasticity x compressed slabs: a replicated state taken at world 2
     (slab written by one rank, entries consolidated) restores in a world-1
@@ -291,7 +272,6 @@ def test_compressed_slab_snapshot_elastic_across_world_sizes(tmp_path) -> None:
     assert Snapshot(path).verify() == {}
 
 
-@requires_zstd
 def test_compressed_slab_ftab_lost_degrades_to_whole_slab_read(tmp_path, caplog) -> None:
     """A lost/corrupt slab frame table degrades to reading + decoding the
     whole slab and slicing members out — never a failed restore."""
@@ -318,7 +298,6 @@ def test_compressed_slab_ftab_lost_degrades_to_whole_slab_read(tmp_path, caplog)
     assert np.array_equal(tgt["b"], app["m"]["b"])
 
 
-@requires_zstd
 def test_compressed_slabs_shrink_small_param_storage(tmp_path) -> None:
     """The done-criterion composition: a small-param-heavy state (MoE/
     embedding shaped: many sub-threshold arrays) gets one-object-per-slab
@@ -359,11 +338,9 @@ def test_compressed_slabs_shrink_small_param_storage(tmp_path) -> None:
         assert np.array_equal(tgt[f"e{i}"], base + np.float32(i))
 
 
-@requires_zstd
 def test_framed_budgeted_subreads_never_read_whole_object(tmp_path) -> None:
     """Large compressed arrays are framed: read_object with a memory budget
-    fetches + decompresses only covering frames, never the whole payload
-    (VERDICT round 2, item 4 done-criterion)."""
+    fetches + decompresses only covering frames, never the whole payload."""
     from torchsnapshot_tpu.storage_plugins.fs import FSStoragePlugin
 
     rng = np.random.default_rng(0)
@@ -397,7 +374,6 @@ def test_framed_budgeted_subreads_never_read_whole_object(tmp_path) -> None:
     assert max(data_reads) < payload_bytes * 0.5, (read_sizes, payload_bytes)
 
 
-@requires_zstd
 def test_framed_sharded_budgeted_restore(tmp_path) -> None:
     """Budgeted sub-reads work on compressed SHARDED arrays: no read ever
     fetches a whole shard payload, and the reshard stays bit-exact."""
@@ -441,7 +417,6 @@ def test_framed_sharded_budgeted_restore(tmp_path) -> None:
     )
 
 
-@requires_zstd
 def test_framed_whole_restore_no_table_needed(tmp_path) -> None:
     """Unbudgeted restores of framed entries decode the concatenated frames
     without touching the .ftab (it may even be lost)."""
@@ -469,7 +444,6 @@ def test_framed_zlib_roundtrip(tmp_path) -> None:
     assert np.array_equal(tgt["a"], arr)
 
 
-@requires_zstd
 def test_codec_versions_recorded_in_metadata(tmp_path) -> None:
     path = str(tmp_path / "v")
     with knobs.override_compression("zstd"):
@@ -478,7 +452,6 @@ def test_codec_versions_recorded_in_metadata(tmp_path) -> None:
     assert versions and "zstd" in versions
 
 
-@requires_zstd
 def test_compression_composes_with_incremental_dedup(tmp_path) -> None:
     """Byte-identical compressed objects dedup against a base snapshot
     (zstd is deterministic for a fixed level/version)."""
@@ -507,7 +480,6 @@ def test_compression_composes_with_incremental_dedup(tmp_path) -> None:
     assert np.array_equal(tgt["head"], np.full((10,), 1, np.float32))
 
 
-@requires_zstd
 def test_exotic_dtypes_compress(tmp_path) -> None:
     arrays = {d: rand_array((32, 8), d, seed=1) for d in ("bfloat16", "float8_e4m3fn", "int4", "uint16")}
     path = str(tmp_path / "d")
@@ -543,7 +515,6 @@ def test_missing_zstandard_fails_fast(monkeypatch) -> None:
             knobs.get_compression()
 
 
-@requires_zstd
 def test_compression_level_validated_per_codec() -> None:
     with knobs.override_compression("zlib"), knobs.override_compression_level(12):
         with pytest.raises(ValueError, match="out of range"):
@@ -561,7 +532,6 @@ def test_compression_level_validated_per_codec() -> None:
         assert knobs.get_compression_level() == 1
 
 
-@requires_zstd
 def test_compressed_staging_costs_account_double() -> None:
     from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer, entry_cost_bytes
 
@@ -575,7 +545,6 @@ def test_compressed_staging_costs_account_double() -> None:
     assert reqs_plain[0].buffer_stager.get_staging_cost_bytes() == arr.nbytes
 
 
-@requires_zstd
 def test_stage_level_keyed_by_entry_not_env(tmp_path) -> None:
     """An entry recorded under one codec compresses correctly even if the
     env codec/level changed before its (deferred) staging ran."""
@@ -600,7 +569,6 @@ def test_stage_level_keyed_by_entry_not_env(tmp_path) -> None:
     assert np.array_equal(np.frombuffer(raw, np.float32), arr)
 
 
-@requires_zstd
 def test_async_host_arrays_safe_to_mutate_after_compressed_take(tmp_path) -> None:
     """The RAW path defensively copies mutable host arrays for async takes;
     compressed payloads are consumed inside staging, so mutating the live
@@ -617,7 +585,6 @@ def test_async_host_arrays_safe_to_mutate_after_compressed_take(tmp_path) -> Non
     assert np.array_equal(tgt["a"], want)
 
 
-@requires_zstd
 def test_divergent_codec_across_ranks_fails_loudly(tmp_path) -> None:
     """A replicated entry's manifest copy on a non-writer rank must never
     lie about the writer's bytes: codec divergence across ranks aborts the
@@ -633,20 +600,27 @@ def _divergent_codec_worker(rank, world_size, shared):
     from torchsnapshot_tpu import Snapshot, StateDict
     from torchsnapshot_tpu.utils import knobs as _knobs
 
+    from torchsnapshot_tpu.snapshot import CheckpointAbortedError
+
     codec = "zstd" if rank == 0 else "none"
     state = StateDict(w=np.arange(512, dtype=np.float32))
     with _knobs.override_compression(codec):
+        # The contract since structured aborts: EVERY rank gets a
+        # CheckpointAbortedError (the detecting rank's ValueError is its
+        # detail, and its cause on that rank); nothing is committed.
         try:
             Snapshot.take(
                 os.path.join(shared, "ckpt"), {"m": state}, replicated=["m/*"]
             )
-        except ValueError as e:
+        except CheckpointAbortedError as e:
             assert "TORCHSNAPSHOT_TPU_COMPRESSION" in str(e)
         else:
             raise AssertionError("divergent codecs did not fail the take")
+    assert not os.path.exists(
+        os.path.join(shared, "ckpt", ".snapshot_metadata")
+    )
 
 
-@requires_zstd
 def test_restore_without_zstandard_fails_fast_at_planning(tmp_path, monkeypatch) -> None:
     """Restoring a zstd snapshot on a host lacking zstandard must raise an
     actionable error at read planning, not ImportError mid-pipeline."""
@@ -668,7 +642,6 @@ def test_restore_without_zstandard_fails_fast_at_planning(tmp_path, monkeypatch)
         Snapshot(path).restore({"s": StateDict(a=np.zeros(64, np.float32))})
 
 
-@requires_zstd
 def test_compressed_sharded_reshard(tmp_path) -> None:
     """Elasticity composes with compression: a compressed sharded snapshot
     restores into different layouts (the two flagship features together).

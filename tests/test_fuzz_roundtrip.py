@@ -71,10 +71,6 @@ def test_random_state_roundtrip(tmp_path, seed) -> None:
             stack.enter_context(knobs.override_batching_enabled(True))
             stack.enter_context(knobs.override_max_chunk_size_bytes(64))
         codec = ("none", "zstd", "zlib")[seed % 3]
-        if codec == "zstd":
-            pytest.importorskip(
-                "zstandard", reason="zstd seeds need the zstandard package"
-            )
         if codec != "none":
             stack.enter_context(knobs.override_compression(codec))
             if seed < 12:

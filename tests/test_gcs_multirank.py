@@ -1,6 +1,6 @@
 """Multi-rank cloud composition: full ``async_take`` → commit → ``restore``
 against the GCS emulator, with slabs + compression + resumable uploads all
-active at once (VERDICT round 4, next-round item 4).
+active at once.
 
 Every component below has single-process emulator coverage in
 ``test_gcs_storage_plugin.py``; what had never been proven is the *pod

@@ -683,7 +683,7 @@ def test_resumable_upload_lost_final_ack_treated_as_committed(
 # monkeypatch-faked tests above cannot: the multipart upload body, the
 # resumable session protocol (308/Range cursors, `bytes */N` recovery
 # probes), ranged media downloads, and the rewrite-token loop — without any
-# cloud credentials (VERDICT round 2, next-round item 3).
+# cloud credentials.
 # ---------------------------------------------------------------------------
 
 

@@ -7,7 +7,6 @@ of mostly-frozen state (LoRA, partial finetunes) cost only the changed
 bytes. Deleting the base later must NOT invalidate the incremental.
 """
 
-import importlib.util
 import os
 
 import numpy as np
@@ -161,10 +160,6 @@ def test_incremental_dedups_batched_slabs_by_content(tmp_path) -> None:
     assert Snapshot(inc).verify() == {}
 
 
-@pytest.mark.skipif(
-    importlib.util.find_spec("zstandard") is None,
-    reason="zstandard not installed (optional dependency)",
-)
 def test_incremental_dedups_compressed_slabs(tmp_path) -> None:
     """Member-framed COMPRESSED slabs dedup too: member packing order and
     zstd at a fixed level are deterministic, so an unchanged state's slab

@@ -1,6 +1,6 @@
-"""Per-device drain-scaling bench harness: fast tier-1 smoke + the
-slow-lane sweep (ROADMAP item 1: make multi-device drain a measured curve,
-not a smoke)."""
+"""Per-device drain-scaling bench harness, driven as the explicit CPU dry
+run (virtual devices; bytes and counts only — the curve itself is a chip
+measurement): fast tier-1 smoke + the slow-lane sweep."""
 
 import json
 import subprocess
@@ -9,9 +9,9 @@ import sys
 import pytest
 
 
-def _run_bench(devices: str, mb: int, timeout: int = 420) -> dict:
-    out = subprocess.run(
-        [sys.executable, "benchmarks/multichip/main.py"],
+def _run_bench(devices: str, mb: int, timeout: int = 420, platform="cpu"):
+    return subprocess.run(
+        [sys.executable, "benchmarks/multichip/main.py", "--platform", platform],
         env={
             "PATH": "/usr/bin:/bin:/usr/local/bin",
             "JAX_PLATFORMS": "cpu",
@@ -22,31 +22,45 @@ def _run_bench(devices: str, mb: int, timeout: int = 420) -> dict:
         text=True,
         timeout=timeout,
     )
+
+
+def _dry_run(devices: str, mb: int, timeout: int = 420) -> dict:
+    out = _run_bench(devices, mb, timeout)
     assert out.returncode == 0, out.stderr[-3000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def _check_curve(det: dict, expected_devices) -> None:
+def _check_curve(rec: dict, expected_devices) -> None:
+    assert rec["metric"] == "multichip_dry_run_cells"
+    det = rec["detail"]
+    assert det["platform"] == "cpu"  # a CPU run says so
     curve = det["curve"]
     assert [c["devices"] for c in curve] == expected_devices
     for cell in curve:
-        assert cell["drain_gbps"] > 0
-        assert cell["drain_s"] > 0
+        assert cell["platform"] == "cpu"
         assert cell["payload_gb"] > 0
-        # The drain decomposition rode along (attributable cells).
-        assert "stage_busy_s" in cell and "io_busy_s" in cell
-    assert det["scaling_vs_single"] > 0
+        # Every device of the mesh drained its share; none queued on the first.
+        per_device = cell["d2h_bytes_per_device"]
+        assert len(per_device) == cell["devices"]
+        assert all(v > 0 for v in per_device.values()), per_device
+        # No time or rate is reported from the CPU backend.
+        assert not {"drain_gbps", "drain_s", "stall_s"} & set(cell)
 
 
 def test_multichip_bench_smoke_tiny() -> None:
-    rec = _run_bench(devices="1,2", mb=8)
-    assert rec["metric"] == "drain_gbps_at_max_devices"
-    _check_curve(rec["detail"], [1, 2])
+    _check_curve(_dry_run(devices="1,2", mb=8), [1, 2])
+
+
+def test_multichip_bench_refuses_cpu_unless_asked() -> None:
+    """The default is the chip: a cell that finds only the CPU backend
+    fails, naming what it found, instead of measuring virtual devices."""
+    out = _run_bench(devices="1", mb=8, platform="tpu")
+    assert out.returncode != 0
+    assert "'platform': 'cpu'" in out.stderr
 
 
 @pytest.mark.slow
 def test_multichip_bench_full_sweep() -> None:
-    """The full 1→8 virtual-device curve at a size where every cell
-    streams; the artifact IS the scaling trajectory."""
-    rec = _run_bench(devices="1,2,4,8", mb=128, timeout=900)
-    _check_curve(rec["detail"], [1, 2, 4, 8])
+    """The full 1→8 virtual-device sweep at a size where every cell
+    streams."""
+    _check_curve(_dry_run(devices="1,2,4,8", mb=128, timeout=900), [1, 2, 4, 8])

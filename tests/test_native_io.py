@@ -128,13 +128,37 @@ def _plugin_roundtrip(plugin: FSStoragePlugin, nbytes: int) -> None:
 def test_fs_plugin_native_path(tmp_path) -> None:
     # Build/load the engine BLOCKING so this test exercises the native path
     # even standalone (the plugin's own _native property is non-blocking and
-    # would return None while a cold-cache background build is running).
+    # would return None while a first-use background build is running).
     if native.load_native() is None:
         pytest.skip("native IO engine unavailable")
     with knobs.override_direct_io_threshold_bytes(1024):
         plugin = FSStoragePlugin(str(tmp_path))
         assert plugin._native is not None
         _plugin_roundtrip(plugin, 1 << 20)
+
+
+def test_take_telemetry_says_which_write_path_objects_used(tmp_path) -> None:
+    """A run that starts before the engine is built writes buffered at
+    first and O_DIRECT later; the take's metrics tell the two apart."""
+    from torchsnapshot_tpu import Snapshot, StateDict
+
+    assert native.load_native() is not None
+    arr = np.arange(64 * 1024, dtype=np.float32)  # 256 KiB
+    with knobs.override_direct_io_threshold_bytes(1024):
+        Snapshot.take(str(tmp_path / "native"), {"s": StateDict(a=arr)})
+        metrics = Snapshot.last_telemetry.metrics.as_dict()
+        assert metrics["storage.fs.native_write_bytes"] >= arr.nbytes
+        assert "storage.fs.native_fallback_bytes" not in metrics
+        # The engine "not loaded yet": same knobs, nothing to write through.
+        orig = native.load_native_nonblocking
+        native.load_native_nonblocking = lambda: None
+        try:
+            Snapshot.take(str(tmp_path / "buffered"), {"s": StateDict(a=arr)})
+        finally:
+            native.load_native_nonblocking = orig
+        metrics = Snapshot.last_telemetry.metrics.as_dict()
+        assert metrics["storage.fs.native_fallback_bytes"] >= arr.nbytes
+        assert "storage.fs.native_write_bytes" not in metrics
 
 
 def test_fs_plugin_python_path_parity(tmp_path) -> None:
@@ -159,8 +183,6 @@ def test_write_file_digest_matches_zlib(lib, tmp_path, nbytes, direct) -> None:
     digest = native.write_file_digest(
         lib, path, data, direct=direct, chunk_bytes=64 * 1024
     )
-    if digest is None:
-        pytest.skip("engine built without zlib (-DTSS_NO_ZLIB)")
     assert digest == [zlib.crc32(data), nbytes, None]
     with open(path, "rb") as f:
         assert f.read() == data
@@ -255,8 +277,6 @@ def test_fs_stream_abort_leaves_nothing(lib, tmp_path) -> None:
 
 def test_write_at_direct_binding(lib, tmp_path) -> None:
     """The raw native binding: positioned aligned writes + truncate_to."""
-    if not native.supports_write_at(lib):
-        pytest.skip("cached .so predates tss_write_at")
     path = str(tmp_path / "f")
     rng = np.random.default_rng(9)
     a = rng.integers(0, 255, size=8192, dtype=np.uint8)

@@ -1,7 +1,7 @@
 """Cross-take plan cache: a second take of an identical app-state structure
 must issue NO O(world) collectives — no key/partition/hostname all_gathers,
 no per-key barriers — only the constant-cost preflight round, the manifest
-delta gather, and the commit barriers (VERDICT round 2, next-round item 1).
+delta gather, and the commit barriers.
 
 Correctness under the cache is covered from several angles: changed primitive
 values must flow through the delta gather into the committed manifest,
@@ -70,7 +70,7 @@ def _worker_steady_state_no_allgathers(rank, world_size, shared):
     Snapshot.take(os.path.join(shared, "c1"), app, replicated=["repl/*"])
     second = dict(counts)
 
-    # The VERDICT done-criterion: no key-gather/partition/hostname
+    # The done-criterion: no key-gather/partition/hostname
     # all_gathers and no per-key barriers on a steady-state take. The
     # data-done/commit-visible rendezvous no longer rides coordinator
     # barriers at all: sync takes commit through the store-based
@@ -320,7 +320,7 @@ def _worker_lru_keeps_steadily_hit_plan(rank, world_size, shared):
     """Hits refresh recency: a steadily-hit structure must survive more cold
     structures passing through than the cache bound (default 4) can hold —
     the round-3 behavior only reordered on store, so 4 cold takes evicted
-    the hot plan (VERDICT round 3, weak 5)."""
+    the hot plan."""
     from torchsnapshot_tpu import Snapshot, StateDict
 
     coord, counts = _counting_coordinator()
@@ -398,7 +398,7 @@ def _worker_restore_constant_round_trips(rank, world_size, shared):
     gather+broadcast plus a single post-load barrier, independent of the
     number of app-state keys (the round-3 design paid a key all_gather plus
     a barrier per key on the exact path a preempted pod takes while
-    restarting; VERDICT round 3, item 3)."""
+    restarting)."""
     from torchsnapshot_tpu import Snapshot, StateDict
     from torchsnapshot_tpu.parallel import store as store_mod
 

@@ -1,7 +1,7 @@
 """Reshard bench harness: fast 2→4 smoke in tier-1 + the slow-lane
 MULTICHIP reshard matrix (8→4, 4→8, transposed axes, N→M with
-replication) and the K-rank replicated-overlap fleet leg — the measured
-form of "elastic reshard at production speed" (bit-exact, origin bytes ≤
+replication) and the K-rank replicated-overlap fleet leg — the counted
+form of "elastic reshard is minimal-byte" (bit-exact, origin bytes ≤
 1.1× theoretical overlap, replicated overlaps fetched once fleet-wide)."""
 
 import json
@@ -32,12 +32,15 @@ def _run_bench(cells: str, mb: int, fleet_ks: str, timeout: int = 420) -> dict:
 
 
 def _check_cells(det: dict, expected) -> None:
+    assert det["platform"] == "cpu"  # a CPU run says so
     cells = det["cells"]
     assert [c["cell"] for c in cells] == expected
     for c in cells:
+        assert c["platform"] == "cpu"
         assert c["bit_exact"] is True
         assert c["origin_ratio"] <= 1.1
-        assert c["reshard_gbps"] > 0
+        # Bytes and ratios only: no time or rate from the CPU backend.
+        assert not {"reshard_gbps", "reshard_wall_s"} & set(c)
         assert c["theoretical_overlap_bytes"] > 0
         # Per-object attribution rode along.
         assert set(c["attribution"]) >= {"origin_bytes", "peer_bytes"}

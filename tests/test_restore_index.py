@@ -1,6 +1,6 @@
 """Restore planning must be O(manifest) total, not O(keys x manifest):
 ``restore()`` builds a one-pass prefix index instead of rescanning the full
-per-rank manifest for every app-state key (VERDICT round 2, item 7).
+per-rank manifest for every app-state key.
 """
 
 import numpy as np

@@ -4,9 +4,7 @@ steady-state stall of a sharded take stays within budget.
 The stall (planning + mutable-host capture, NOT device bytes) is the
 framework's headline metric; these tests keep it observable and bounded so a
 planning-path regression (e.g. an accidental collective or full D2H inside
-``async_take``) fails the suite rather than silently eating the budget
-(VERDICT round 1, weak #2: the stall was only ever measured at world 1 with
-no in-suite guard).
+``async_take``) fails the suite rather than silently eating the budget.
 """
 
 import time
@@ -91,7 +89,7 @@ def test_sync_take_also_records_phases(tmp_path) -> None:
 def test_drain_stats_recorded(tmp_path) -> None:
     """The background drain reports stream-overlap accounting (D2H+serialize
     vs storage-write busy time) so drain-throughput regressions are
-    observable (VERDICT round 1, weak #4)."""
+    observable."""
     app = _sharded_app()
     pending = Snapshot.async_take(str(tmp_path / "s"), app)
     snap = pending.wait()

@@ -200,3 +200,59 @@ def test_linear_barrier_rank_death_times_out_peers(death_point) -> None:
     # Prompt: bounded by (at most) the two phases' timeouts plus polling
     # slack, not a hang.
     assert elapsed < 10, elapsed
+
+
+def _worker_jax_store_overwrites(rank, world_size, shared):
+    import logging
+    import os
+
+    import numpy as np
+
+    from torchsnapshot_tpu import Snapshot, StateDict
+    from torchsnapshot_tpu.parallel.coordinator import get_coordinator
+    from torchsnapshot_tpu.parallel.store import JaxCoordinationStore
+    from torchsnapshot_tpu.telemetry import fleet
+    from torchsnapshot_tpu.utils import knobs
+
+    store = JaxCoordinationStore(namespace="overwrite_test")
+    key = f"k/{rank}"
+    assert store.try_get(key) is None  # absent is None, not an error
+    store.set(key, b"one")
+    store.set(key, b"two")  # last writer wins, as on every other Store
+    assert store.try_get(key) == b"two"
+    assert store.get(key, 5.0) == b"two"
+
+    # The fleet bus overwrites one beacon key per process on this store
+    # (auto-on at world > 1): every publish after the first used to fail
+    # with ALREADY_EXISTS and was swallowed fail-open.
+    assert isinstance(get_coordinator().store, JaxCoordinationStore)
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    logging.getLogger("torchsnapshot_tpu").addHandler(handler)
+    with knobs.override_fleet_beacon_s(0.05):
+        fleet.reset()
+        for i in range(2):
+            Snapshot.take(
+                os.path.join(shared, f"ckpt{i}"),
+                {"s": StateDict(w=np.full(1 << 16, rank, np.float32))},
+            )
+        bus = fleet.get_bus()
+    assert bus is not None and bus.publishes >= 2, bus
+    assert bus.publish_failures == 0
+    assert not [
+        r for r in records if "fleet beacon publish failed" in r.getMessage()
+    ]
+
+
+def test_jax_coordination_store_overwrites_and_beacons_keep_publishing(
+    tmp_path,
+) -> None:
+    from torchsnapshot_tpu.test_utils import run_with_processes
+
+    run_with_processes(
+        _worker_jax_store_overwrites,
+        nproc=2,
+        init_jax_distributed=True,
+        args=(str(tmp_path),),
+    )

@@ -1,8 +1,8 @@
 """Streaming auto-select (``stream_select.py``): the measurement-driven
 resolution of ``TORCHSNAPSHOT_TPU_STREAM_WRITES=auto``.
 
-BENCH_r07 shipped the streaming default inverted on its host (ON drained
-slower than OFF). These tests pin the machinery that replaces the global
+A global streaming default can be inverted on a host (ON drains slower than
+OFF). These tests pin the machinery that replaces the global
 boolean with a per-plugin measured decision: the scorecard arithmetic,
 the credibility thresholds, the forced/insufficient/measured resolution
 paths, the process-wide mirror ``knobs.is_stream_writes_enabled`` reads,

@@ -39,7 +39,7 @@ from .manifest import (
     Entry,
     ShardedArrayEntry,
 )
-from .io_preparer import _device_assignment_key
+from .io_preparer import _device_assignment_key, _is_oom_error
 from .io_preparers.array import (
     FRAME_TABLE_SUFFIX as _FRAME_TABLE_SUFFIX,
     PollingTableStager,
@@ -82,7 +82,7 @@ class CompressedSlabStager(BufferStager):
     (``ArrayEntry.raw_range``) and the raw→compressed mapping travels in
     the slab's ``.ftab`` side object. Round 3 instead compressed eagerly at
     plan time (host members only, serially, inside the stall) and left
-    deferred device members unbatched entirely (VERDICT round 3, item 8)."""
+    deferred device members unbatched entirely."""
 
     def __init__(
         self,
@@ -176,6 +176,7 @@ class BatchedBufferStager(BufferStager):
         self.total = members[-1][2] if members else 0
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
+        telemetry.counter_add("batcher.slabs_host_packed")
         slab = bytearray(self.total)
 
         async def stage_one(req: WriteReq, begin: int, end: int) -> None:
@@ -217,6 +218,7 @@ class BatchedBufferStager(BufferStager):
         staging lookahead — member k+1's D2H runs while member k's bytes
         are appended to storage. Peak host RAM is ~2 members instead of
         the whole slab."""
+        telemetry.counter_add("batcher.slabs_host_packed")
         next_task = None
         try:
             for idx, (req, begin, end) in enumerate(self.members):
@@ -264,9 +266,14 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
     a jitted bitcast-to-bytes + concatenate: per-transfer overhead (latency,
     descriptor setup) is paid once per slab instead of once per member —
     exactly the regime slab batching targets (thousands of small params).
-    Any failure (unsupported dtype snuck through, compile error, device OOM,
-    a byte-length mismatch) falls back to the host-side per-member packing
-    inherited from :class:`BatchedBufferStager`.
+    Only a device allocation failure (the pack needs a slab's worth of HBM)
+    falls back to the host-side per-member packing inherited from
+    :class:`BatchedBufferStager`; anything else — a refused compile, a
+    byte-length mismatch — is a bug on this backend and raises. Which path
+    each slab took is counted: ``batcher.slabs_device_packed``, or
+    ``batcher.slabs_host_packed`` (every slab packed on the host, by plan
+    or by degradation) of which ``batcher.slabs_pack_degraded`` were
+    planned for the device.
     """
 
     # stage_chunks yields views into the one packed host buffer — the
@@ -299,31 +306,26 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
             if failed_at is not None and (
                 time.monotonic() - failed_at >= _PACK_RETRY_COOLDOWN_S
             ):
-                # Cooldown elapsed: transient causes (a momentary HBM
-                # pressure spike at the to_host resolve) deserve another
-                # chance; a deterministic compile failure will just
-                # re-memoize.
+                # Cooldown elapsed: HBM pressure is transient and deserves
+                # another chance.
                 _PACK_FAILED.pop(key, None)
                 failed_at = None
         if failed_at is not None:
-            # This signature failed recently; don't pay a failed
-            # trace/compile plus a full-traceback warning on every take.
+            # This signature hit HBM pressure recently; don't pay a failed
+            # allocation plus a full-traceback warning on every take.
+            telemetry.counter_add("batcher.slabs_pack_degraded")
             return await super().stage_buffer(executor)
         try:
             packed = _pack_to_device_bytes(key, arrs)
             # _traced_to_host wraps the async-hint-then-resolve pattern (plus
-            # a d2h telemetry span when tracing); a device-side failure
-            # (e.g. async HBM OOM from the pack's allocation) surfaces at
-            # the resolve and falls back too.
+            # a d2h telemetry span when tracing); an asynchronous HBM OOM
+            # from the pack's allocation surfaces at the resolve.
             host = await _traced_to_host(
                 packed, executor, self.members[0][0].path, self.total
             )
-            if host.nbytes != self.total:
-                raise RuntimeError(
-                    f"Device-packed slab is {host.nbytes} bytes, "
-                    f"planned {self.total}"
-                )
-        except Exception:
+        except Exception as e:
+            if not _is_oom_error(e):
+                raise
             with _PACK_LOCK:
                 if len(_PACK_FAILED) >= _PACK_FAILED_CAP:
                     # Evict oldest (insertion order) rather than refusing
@@ -332,14 +334,21 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
                     _PACK_FAILED.pop(next(iter(_PACK_FAILED)), None)
                 _PACK_FAILED[key] = time.monotonic()
             logger.warning(
-                "On-device slab packing failed; falling back to host-side "
-                "packing for %d members (device path for this slab "
-                "signature paused for %.0f s)",
+                "On-device slab packing hit HBM pressure; falling back to "
+                "host-side packing for %d members (device path for this "
+                "slab signature paused for %.0f s)",
                 len(self.members),
                 _PACK_RETRY_COOLDOWN_S,
                 exc_info=True,
             )
+            telemetry.counter_add("batcher.slabs_pack_degraded")
             return await super().stage_buffer(executor)
+        if host.nbytes != self.total:
+            raise RuntimeError(
+                f"Device-packed slab is {host.nbytes} bytes, "
+                f"planned {self.total}"
+            )
+        telemetry.counter_add("batcher.slabs_device_packed")
         return np.ascontiguousarray(host)
 
     def start_d2h_hint(self) -> None:
@@ -355,8 +364,11 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
 # bitcast-to-uint8 byte stream equals the host array's raw little-endian
 # bytes. Sub-byte dtypes (int4/uint4/float4) are excluded — numpy stores
 # them unpacked one-per-byte, and an 8→4-bit bitcast would mis-size the
-# slab. bool packs via astype (same 0/1 byte representation). Complex
-# bitcasts are unsupported by XLA.
+# slab. Sub-32-bit floats (bfloat16, float16, float8) are excluded too: the
+# pack program flushes their denormals and rewrites their NaN payloads
+# (``io_preparers.array.slice_preserves_bits``), so their slabs are packed
+# on the host from per-member transfers. bool packs via astype (same 0/1
+# byte representation). Complex bitcasts are unsupported by XLA.
 _DEVICE_PACKABLE_DTYPES = frozenset(
     {
         "bool",
@@ -368,15 +380,8 @@ _DEVICE_PACKABLE_DTYPES = frozenset(
         "uint16",
         "uint32",
         "uint64",
-        "float16",
         "float32",
         "float64",
-        "bfloat16",
-        "float8_e4m3fn",
-        "float8_e5m2",
-        "float8_e4m3b11fnuz",
-        "float8_e4m3fnuz",
-        "float8_e5m2fnuz",
     }
 )
 
@@ -449,11 +454,10 @@ _PACK_FNS = BoundedLRU(capacity=256)
 # BoundedLRU nor the dict's check-then-mutate sequences are atomic.
 _PACK_LOCK = threading.Lock()
 
-# key -> monotonic time of last device-path failure. Failed signatures skip
-# straight to host packing until the cooldown elapses (transient causes like
-# momentary HBM pressure recover; deterministic compile failures re-memoize
-# after one retry per cooldown). Capped so pathological signature churn
-# can't grow it forever (beyond the cap, new failures just retry+warn).
+# key -> monotonic time of the last device-path allocation failure. Such
+# signatures skip straight to host packing until the cooldown elapses (HBM
+# pressure is transient). Capped so pathological signature churn can't grow
+# it forever (beyond the cap, new failures just retry+warn).
 _PACK_FAILED: dict = {}
 _PACK_FAILED_CAP = 1024
 _PACK_RETRY_COOLDOWN_S = 600.0
@@ -522,12 +526,21 @@ def batch_write_requests(
         # Deterministic packing order; deferred (device) members group
         # together so their slabs stay all-deferred — one mutable host
         # member would otherwise drag a whole slab's D2H into the capture
-        # point. Slabs close at the threshold (raw sizes either way: slab
-        # offsets must be known at planning time, and compressed sizes
-        # aren't — that is the whole reason member-framing exists).
-        members = sorted(
-            members, key=lambda t: (0 if t[0].defer_staging else 1, t[0].path)
-        )
+        # point. Likewise members the device pack cannot carry bit for bit
+        # (sub-32-bit floats, complex): one of them would send its whole
+        # slab through the host pack. Slabs close at a group boundary and at
+        # the threshold (raw sizes either way: slab offsets must be known at
+        # planning time, and compressed sizes aren't — that is the whole
+        # reason member-framing exists).
+        device_pack = knobs.is_device_batching_enabled()
+
+        def group(req: WriteReq) -> Tuple[int, int]:
+            return (
+                0 if req.defer_staging else 1,
+                0 if device_pack and _device_batchable(req) else 1,
+            )
+
+        members = sorted(members, key=lambda t: (group(t[0]), t[0].path))
         slab: List[Tuple[WriteReq, int, int]] = []
         slab_entries: List[ArrayEntry] = []
         offset = 0
@@ -599,8 +612,8 @@ def batch_write_requests(
             slab, slab_entries, offset = [], [], 0
 
         for req, entry, nbytes in members:
-            if (offset + nbytes > threshold and slab) or (
-                slab and slab[0][0].defer_staging != req.defer_staging
+            if slab and (
+                offset + nbytes > threshold or group(slab[0][0]) != group(req)
             ):
                 close_slab()
             slab.append((req, offset, offset + nbytes))

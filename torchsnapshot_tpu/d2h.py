@@ -50,31 +50,13 @@ from .utils import knobs
 logger = logging.getLogger(__name__)
 
 
-# One warning per process when a platform lacks the async D2H hint — not one
-# per array per take. (Moved here from io_preparers/array.py, which
-# re-exports it: the lanes issue hints too, and the single owner of the
-# "hint unsupported" state must sit below both.)
-_hint_unsupported_warned = False
-
-
 def hint_copy_to_host(arr: Any) -> None:
-    """Best-effort ``copy_to_host_async`` D2H hint.
-
-    Only the narrow "this platform/array doesn't implement the hint" errors
-    are swallowed (logged once; ``np.asarray`` still works, just without the
-    overlap). A real XLA transfer failure propagates — silently retrying it
-    as a blocking ``np.asarray`` would hide the device-side error until it
-    resurfaces somewhere far less attributable."""
-    global _hint_unsupported_warned
-    try:
-        arr.copy_to_host_async()
-    except (NotImplementedError, AttributeError) as e:
-        if not _hint_unsupported_warned:
-            _hint_unsupported_warned = True
-            logger.info(
-                "copy_to_host_async unavailable on this platform (%s); "
-                "D2H transfers will not be hinted ahead of np.asarray", e
-            )
+    """Issue ``arr``'s D2H transfer now so a later ``np.asarray`` finds it
+    in flight. Every backend of the installed jax implements it; any failure
+    is a real transfer error and propagates — retrying it silently as a
+    blocking ``np.asarray`` would lose the overlap and hide the device-side
+    error until it resurfaces somewhere far less attributable."""
+    arr.copy_to_host_async()
 
 
 class StageTimes:
@@ -104,7 +86,11 @@ class StageTimes:
         path: str = "",
         nbytes: int = 0,
         span: Optional[str] = None,
+        device: Optional[int] = None,
     ) -> None:
+        # ``device``: id of the single device a d2h transfer read from, when
+        # there is one — ``d2h.device_bytes.<id>`` shows whether a
+        # multi-device drain pulls from every device or queues on the first.
         # ``span`` overrides the exported span name while the interval still
         # joins ``kind``'s sub-stream — parallel chunk hashes export as
         # ``stage.hash_chunk`` spans but stay inside ``stage_hash_s``.
@@ -122,6 +108,8 @@ class StageTimes:
             if kind == "d2h":
                 tm.metrics.counter("d2h.bytes").add(nbytes)
                 tm.metrics.histogram("d2h.seconds").observe(t1 - t0)
+                if device is not None:
+                    tm.metrics.counter(f"d2h.device_bytes.{device}").add(nbytes)
 
     def intervals(self) -> Dict[str, List[Tuple[float, float]]]:
         """A snapshot copy per kind (safe to merge/clip while staging runs)."""
@@ -250,13 +238,20 @@ class TransferLanes:
         (that wait is exactly the overlap the lanes exist to create)."""
         if not skip_hint:
             hint_copy_to_host(arr)
+        devices = arr.devices()
+        device = next(iter(devices)).id if len(devices) == 1 else None
 
         def resolve() -> np.ndarray:
             t0 = time.monotonic()
             host = np.asarray(arr)
             if times is not None:
                 times.record(
-                    "d2h", t0, time.monotonic(), path=location, nbytes=nbytes
+                    "d2h",
+                    t0,
+                    time.monotonic(),
+                    path=location,
+                    nbytes=nbytes,
+                    device=device,
                 )
             return host
 

@@ -34,10 +34,11 @@ from .manifest import (
     PrimitiveEntry,
     PRIMITIVE_TYPES,
 )
-from .io_preparers.array import ArrayIOPreparer
+from .io_preparers.array import ArrayIOPreparer, copy_preserves_bits
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer, should_chunk
 from .io_preparers.object import ObjectIOPreparer
 from .io_preparers.sharded_array import ShardedArrayIOPreparer
+from . import telemetry
 from .utils import knobs
 from .utils.lru import BoundedLRU
 
@@ -179,11 +180,32 @@ def _defensive_device_copies(arrs: List[Any]) -> List[Any]:
     available than the reference: the HBM overhead is bounded by what
     actually fit (by construction), and only the host-captured bytes extend
     the stall (a warning reports both).
+
+    **Dtypes the copy would rewrite** (float16, float8: the device copy
+    replaces NaN payloads, ``io_preparers.array.copy_preserves_bits``) are
+    never forked: those leaves are host-captured up front — D2H moves bits
+    unchanged — and counted (``capture.dtype_captured_leaves``).
     """
     groups: Dict[Any, List[int]] = {}
-    for i, a in enumerate(arrs):
-        groups.setdefault(_device_assignment_key(a.sharding), []).append(i)
     out: List[Any] = [None] * len(arrs)
+    inexact = [i for i, a in enumerate(arrs) if not copy_preserves_bits(a.dtype)]
+    if inexact:
+        telemetry.counter_add("capture.dtype_captured_leaves", len(inexact))
+        global _dtype_capture_warned
+        if not _dtype_capture_warned:
+            _dtype_capture_warned = True
+            logger.warning(
+                "async_take captures %d leaves of dtype %s through host RAM "
+                "inside the stall: a device copy does not return these "
+                "dtypes bit for bit (NaN payloads are rewritten)",
+                len(inexact),
+                sorted({str(arrs[i].dtype) for i in inexact}),
+            )
+        for i, c in zip(inexact, _host_capture_group([arrs[i] for i in inexact])):
+            out[i] = c
+    for i, a in enumerate(arrs):
+        if out[i] is None:
+            groups.setdefault(_device_assignment_key(a.sharding), []).append(i)
     # Cumulative successfully-forked local bytes across this take, for the
     # simulated-HBM-limit knob (mirrors real accounting: forks accumulate).
     forked_bytes = [0]
@@ -194,7 +216,6 @@ def _defensive_device_copies(arrs: List[Any]) -> List[Any]:
         for i, c in zip(indices, copies):
             out[i] = c
     if captured:
-        total = sum(_local_fork_nbytes(a) for a in captured)
         logger.warning(
             "async_take defensive fork hit HBM pressure: %d of %d leaves "
             "(%.3f GB) were captured through host RAM instead (blocking "
@@ -202,7 +223,7 @@ def _defensive_device_copies(arrs: List[Any]) -> List[Any]:
             "in the background). The snapshot remains donation-safe.",
             len(captured),
             len(arrs),
-            total / 1e9,
+            sum(_local_fork_nbytes(a) for a in captured) / 1e9,
         )
     return out
 
@@ -217,18 +238,20 @@ def _is_oom_error(e: BaseException) -> bool:
     return "RESOURCE_EXHAUSTED" in s or "out of memory" in s.lower()
 
 
-# Log-once guard for the backend-capability degradation below.
+# Log-once guards: the backend-capability degradation below, and leaves whose
+# dtype the fork program would rewrite (a property of the model, not of a take).
 _fork_unsupported_warned = False
+_dtype_capture_warned = False
 
 
-def _is_fork_unsupported_error(e: BaseException) -> bool:
-    """The batched copy is impossible on this backend — notably jax's CPU
-    backend, which refuses multiprocess jitted computations outright
+def _is_fork_unsupported_error(group: List[Any], e: BaseException) -> bool:
+    """jax's CPU backend refuses multiprocess jitted computations outright
     (INVALID_ARGUMENT), regardless of size. Bisection can't help; the whole
     group must capture through host RAM (the reference's design, still
-    donation-safe)."""
-    s = str(e)
-    return "implemented on the CPU backend" in s
+    donation-safe). CPU-only by construction: on an accelerator the same
+    text is an error like any other."""
+    on_cpu = all(d.platform == "cpu" for d in group[0].sharding.device_set)
+    return on_cpu and "implemented on the CPU backend" in str(e)
 
 
 def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
@@ -246,6 +269,7 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
                 f"({forked_bytes[0]} + {need} > {limit} bytes)"
             )
     copies = _batch_copy_fn(tuple(a.sharding for a in group))(group)
+    telemetry.counter_add("capture.forked_leaves", len(group))
     if limit is not None:
         # Accounting feeds only the simulated limit; skip the per-shard
         # walk on the production hot path.
@@ -271,7 +295,7 @@ def _fork_or_capture(
     try:
         return _try_fork(group, forked_bytes)
     except Exception as e:  # noqa: BLE001 - only OOM/capability degrades
-        if _is_fork_unsupported_error(e):
+        if _is_fork_unsupported_error(group, e):
             global _fork_unsupported_warned
             if not _fork_unsupported_warned:
                 _fork_unsupported_warned = True
@@ -300,6 +324,12 @@ def _host_capture_group(group: List[Any]) -> List[HostCapturedArray]:
     transfer engine instead of serializing array by array."""
     from .io_preparers.array import hint_copy_to_host
 
+    # Which path a leaf took is a fact of the take, exported with it (the
+    # persisted telemetry artifact), not only a log line.
+    telemetry.counter_add("capture.host_captured_leaves", len(group))
+    telemetry.counter_add(
+        "capture.host_captured_bytes", sum(_local_fork_nbytes(a) for a in group)
+    )
     for a in group:
         for s in a.addressable_shards:
             hint_copy_to_host(s.data)
@@ -334,15 +364,9 @@ def _host_capture(arr: Any) -> HostCapturedArray:
 
 
 def _device_assignment_key(sharding) -> Any:
-    try:
-        return tuple(d.id for d in sharding._device_assignment)
-    except AttributeError:
-        # Not part of jax's public API. Fall back to one group per distinct
-        # sharding: equal shardings trivially share an assignment, while a
-        # set-based key would merge same-device-set/different-order
-        # assignments into one jit call, which jax rejects. Costs batching
-        # granularity, never correctness.
-        return sharding
+    """One jitted computation requires all operands to share a device
+    assignment (order included, which ``device_set`` loses)."""
+    return tuple(d.id for d in sharding._device_assignment)
 
 
 def _batch_copy_fn(shardings: Tuple[Any, ...]):

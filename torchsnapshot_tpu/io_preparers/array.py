@@ -66,6 +66,37 @@ def _is_jax_array(obj: Any) -> bool:
     return isinstance(obj, jax.Array)
 
 
+# XLA does not treat sub-32-bit floats as opaque bits. On the TPU toolchain
+# this repository was brought up on (v5e, jax/jaxlib 0.9.0, libtpu 0.0.34;
+# every bit pattern of each dtype put from the host) a slice or a bitcast of
+# bfloat16 flushes all 254 denormals to zero, and a copy, slice or bitcast of
+# float16 / float8_e4m3fn / float8_e5m2 rewrites NaN payloads; ``jnp.copy`` of
+# bfloat16 kept every pattern, and 32-bit floats, integers and bool are exact
+# in every program. Transfers (D2H, H2D) move bits unchanged. So a leaf of
+# such a dtype never enters a device program that would rewrite it: it
+# reaches the host whole and is cut, packed or captured there. The rule is
+# by dtype alone, on every backend, so the CPU suite runs the routing the
+# chip runs.
+
+
+def _is_small_float(dtype: Any) -> bool:
+    dt = np.dtype(dtype)
+    return dt.itemsize < 4 and dt.name.startswith(("float", "bfloat"))
+
+
+def slice_preserves_bits(dtype: Any) -> bool:
+    """Whether a device slice / bitcast / concatenate of ``dtype`` returns
+    the operand's bits unchanged (chunk slices, shard subdivision, the slab
+    pack)."""
+    return not _is_small_float(dtype)
+
+
+def copy_preserves_bits(dtype: Any) -> bool:
+    """Whether ``jnp.copy`` of ``dtype`` returns the operand's bits unchanged
+    (the async-take fork)."""
+    return not _is_small_float(dtype) or np.dtype(dtype).name == "bfloat16"
+
+
 # The hint's single owner moved to ``d2h`` (the transfer lanes issue hints
 # too); re-exported here for the existing importers (io_preparer, tests).
 hint_copy_to_host = d2h.hint_copy_to_host
@@ -349,6 +380,10 @@ class ArrayBufferStager(BufferStager):
             # Mutable host source on an async take: capture semantics
             # require a private buffer before async_take returns; a stream
             # keeps reading the live array long after training resumed.
+            return False
+        if _is_jax_array(self.arr) and not slice_preserves_bits(self.arr.dtype):
+            # A stream cuts its chunks on the device, and a device slice
+            # rewrites this dtype's bits: transfer whole, write whole.
             return False
         return len(self._stream_row_ranges()) > 1
 

@@ -5,7 +5,8 @@ pipeline its D2H transfer with storage I/O *within* one array, and lets the
 partitioner split a replicated array's write load across processes at chunk
 granularity. On TPU the per-chunk slice ``arr[r0:r1]`` is an XLA device op, so
 chunk transfers stream out of HBM back-to-back without a full host-side copy
-first.
+first — for the dtypes a device slice returns bit for bit; a sub-32-bit float
+array is not chunked (``array.slice_preserves_bits``).
 
 The row-range math (``chunk_row_ranges``) lives in ``array.py`` and is shared
 with the streaming stager: each chunk OBJECT produced here is itself streamed
@@ -22,12 +23,18 @@ import numpy as np
 from ..io_types import ReadReq, WriteReq
 from ..manifest import ChunkedArrayEntry, Shard
 from ..utils import knobs
-from .array import ArrayIOPreparer, chunk_row_ranges
+from .array import ArrayIOPreparer, chunk_row_ranges, slice_preserves_bits
 
 __all__ = ["should_chunk", "chunk_row_ranges", "ChunkedArrayIOPreparer"]
 
 
 def should_chunk(arr: Any) -> bool:
+    if not slice_preserves_bits(arr.dtype):
+        # Chunks of a device array are cut on the device, and a device slice
+        # rewrites this dtype's bits: the array stays one object. By dtype
+        # alone, host arrays too: every rank must lay a replicated leaf out
+        # the same way whether it forked it or captured it through the host.
+        return False
     nbytes = int(np.prod(arr.shape)) * np.dtype(arr.dtype).itemsize if arr.shape else 0
     return (
         len(arr.shape) >= 1

@@ -40,7 +40,7 @@ from ..serialization import (
     string_to_dtype,
 )
 from ..utils import knobs
-from .array import ArrayIOPreparer, FramedSliceConsumer
+from .array import ArrayIOPreparer, FramedSliceConsumer, slice_preserves_bits
 
 # A target to restore into: (host buffer, global offsets, sizes)
 TargetShard = Tuple[np.ndarray, Sequence[int], Sequence[int]]
@@ -107,6 +107,40 @@ def subdivide(  # spmd-pure
         s[dim] = r1 - r0
         pieces.append((o, s))
     return pieces
+
+
+def shard_pieces(
+    data: Any, offsets: List[int], sizes: List[int], max_bytes: int
+) -> List[Tuple[List[int], List[int], Any]]:
+    """One local shard as the ``(offsets, sizes, data)`` pieces it is written
+    in: subdivided to ``max_bytes`` for pipelining, except that a sub-32-bit
+    float shard stays whole — its pieces would be cut on the device, and a
+    device slice rewrites that dtype's bits (``array.slice_preserves_bits``;
+    by dtype alone, so a host-captured shard lays out the same). Shared by
+    ``prepare_write`` and the prepared-state cache's rebind, which must
+    produce the same pieces in the same order."""
+    if not slice_preserves_bits(data.dtype):
+        return [(offsets, sizes, data)]
+    subs = subdivide(offsets, sizes, np.dtype(data.dtype).itemsize, max_bytes)
+    if len(subs) == 1:
+        # Whole-shard piece: skip the jax slicing dispatch — `data[full
+        # slices]` still traces a gather, and at hundreds of params x
+        # shards that dispatch dominated the planning stall (measured
+        # 0.17 s of a 0.29 s prepare_write at 240 sharded entries).
+        return [(offsets, sizes, data)]
+    return [
+        (
+            sub_off,
+            sub_sz,
+            data[
+                tuple(
+                    slice(o - bo, o - bo + s)
+                    for o, bo, s in zip(sub_off, offsets, sub_sz)
+                )
+            ],
+        )
+        for sub_off, sub_sz in subs
+    ]
 
 
 def overlap(  # spmd-pure
@@ -424,23 +458,7 @@ class ShardedArrayIOPreparer:
         for data, offsets, sizes, replica_id in local_unique_shards(arr):
             if replica_id != 0:
                 continue  # another process (or device) owns this copy
-            pieces = subdivide(offsets, sizes, dtype.itemsize, max_shard)
-            for sub_off, sub_sz in pieces:
-                if len(pieces) == 1:
-                    # Whole-shard piece (no subdivision): skip the jax
-                    # slicing dispatch — `data[full_slices]` still traces a
-                    # gather, and at hundreds of params x shards that
-                    # dispatch dominated the planning stall (measured 0.17 s
-                    # of a 0.29 s prepare_write at 240 sharded entries).
-                    piece = data
-                else:
-                    # Subdivision implies non-empty sizes, so rel is
-                    # non-empty here.
-                    rel = tuple(
-                        slice(o - bo, o - bo + s)
-                        for o, bo, s in zip(sub_off, offsets, sub_sz)
-                    )
-                    piece = data[rel]
+            for sub_off, sub_sz, piece in shard_pieces(data, offsets, sizes, max_shard):
                 location = cls.shard_location(logical_path, sub_off)
                 sub_entry, sub_reqs = ArrayIOPreparer.prepare_write(
                     storage_path=location,

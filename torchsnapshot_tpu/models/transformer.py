@@ -155,13 +155,15 @@ def shard_params(params, mesh: Mesh, fsdp: bool = True):
 
     def place(path, leaf):
         spec = param_spec(_path_str(path), fsdp=fsdp)
-        spec = _fit_spec(spec, leaf.shape, mesh)
+        spec = fit_spec(spec, leaf.shape, mesh)
         return jax.device_put(leaf, NamedSharding(mesh, spec))
 
     return jax.tree_util.tree_map_with_path(place, params)
 
 
-def _fit_spec(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
+def fit_spec(spec: P, shape: Tuple[int, ...], mesh: Mesh) -> P:
+    """``spec`` with every axis that does not divide its dimension (or has
+    no dimension) dropped, so any leaf can be placed on any mesh."""
     fitted = []
     for d, axis in enumerate(spec):
         if axis is None or d >= len(shape):

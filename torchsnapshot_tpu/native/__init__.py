@@ -1,22 +1,29 @@
 """Loader for the native I/O engine (``tss_io.cpp``).
 
 The engine is a single C++ translation unit compiled on first use with the
-host toolchain (``g++ -O2 -shared -fPIC``) and loaded via :mod:`ctypes` —
+host toolchain (``g++ -O2 -shared -fPIC -lz``) and loaded via :mod:`ctypes` —
 ctypes releases the GIL for the duration of each call, so bounce-buffer
 copies and pwrite/pread syscalls overlap the asyncio event loop without a
 C extension module.
 
-Everything degrades gracefully: if no compiler is available, compilation
-fails, or ``TORCHSNAPSHOT_TPU_DISABLE_NATIVE_IO=1`` is set, ``load_native()``
-returns ``None`` and callers (the FS storage plugin) use the pure-Python
-path. The built ``.so`` is cached next to the source (or in
-``~/.cache/torchsnapshot_tpu`` when the package directory is read-only) and
-rebuilt whenever the source is newer.
+The library is keyed by the hash of its source: it lives at
+``native/build/libtss_io-<sha256[:16]>.so`` inside the package directory and
+nowhere else, so a library built from different source (another checkout, an
+older tree copied over this one) can never load, whatever its mtime says.
+``native/Makefile`` builds to the same name.
+
+If no compiler is available, compilation fails, or
+``TORCHSNAPSHOT_TPU_DISABLE_NATIVE_IO=1`` is set, ``load_native()`` returns
+``None`` and the FS storage plugin uses the pure-Python path (counted as
+``storage.fs.native_fallback_bytes``). Entry points that measure call
+``benchmarks.common.require_native_engine()`` instead and fail.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import logging
 import os
 import subprocess
@@ -27,7 +34,7 @@ from typing import Optional
 logger = logging.getLogger(__name__)
 
 _SRC = os.path.join(os.path.dirname(__file__), "tss_io.cpp")
-_LIB_NAME = "libtss_io.so"
+_BUILD_DIR = os.path.join(os.path.dirname(__file__), "build")
 
 # _lock guards only the published (_lib, _load_attempted) state and is never
 # held across a compile; _build_lock serializes the (multi-second) g++ build
@@ -39,34 +46,27 @@ _load_attempted = False
 _bg_build: Optional[threading.Thread] = None
 
 
-def _candidate_lib_paths():
-    yield os.path.join(os.path.dirname(__file__), _LIB_NAME)
-    cache_dir = os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "torchsnapshot_tpu",
-    )
-    yield os.path.join(cache_dir, _LIB_NAME)
+@functools.lru_cache(maxsize=1)
+def lib_path() -> str:
+    """Where the engine built from the current ``tss_io.cpp`` lives."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libtss_io-{digest}.so")
 
 
 def _build(out_path: str) -> None:
     os.makedirs(os.path.dirname(out_path), exist_ok=True)
     # Build to a temp name then rename so concurrent processes never load a
     # half-written .so.
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(out_path), suffix=".so")
+    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(out_path), suffix=".so.tmp")
     os.close(fd)
-    base = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp]
     try:
-        try:
-            subprocess.run(
-                base + ["-lz"], check=True, capture_output=True, text=True
-            )
-        except subprocess.CalledProcessError:
-            # No zlib dev files on this host: build the engine WITHOUT the
-            # inline-crc digest API rather than losing O_DIRECT entirely
-            # (Python hashing covers digests in that configuration).
-            subprocess.run(
-                base + ["-DTSS_NO_ZLIB"], check=True, capture_output=True, text=True
-            )
+        subprocess.run(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp, "-lz"],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
         os.replace(tmp, out_path)
     except BaseException:
         try:
@@ -97,50 +97,40 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tss_read_file.restype = ctypes.c_int
     lib.tss_file_size.argtypes = [ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
     lib.tss_file_size.restype = ctypes.c_int
-    try:
-        lib.tss_write_file_digest.argtypes = [
-            ctypes.c_char_p,
-            ctypes.c_void_p,
-            ctypes.c_uint64,
-            ctypes.c_int,
-            ctypes.c_uint64,
-            ctypes.POINTER(ctypes.c_uint32),
-        ]
-        lib.tss_write_file_digest.restype = ctypes.c_int
-        lib._tss_has_digest = True
-    except AttributeError:  # pragma: no cover - stale cached .so
-        lib._tss_has_digest = False
-    try:
-        lib.tss_write_at.argtypes = [
-            ctypes.c_char_p,
-            ctypes.c_void_p,
-            ctypes.c_uint64,
-            ctypes.c_uint64,
-            ctypes.c_int,
-            ctypes.c_uint64,
-            ctypes.c_int64,
-        ]
-        lib.tss_write_at.restype = ctypes.c_int
-        lib._tss_has_write_at = True
-    except AttributeError:  # pragma: no cover - stale cached .so
-        lib._tss_has_write_at = False
+    lib.tss_write_file_digest.argtypes = [
+        ctypes.c_char_p,
+        ctypes.c_void_p,
+        ctypes.c_uint64,
+        ctypes.c_int,
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint32),
+    ]
+    lib.tss_write_file_digest.restype = ctypes.c_int
+    lib.tss_write_at.argtypes = [
+        ctypes.c_char_p,
+        ctypes.c_void_p,
+        ctypes.c_uint64,
+        ctypes.c_uint64,
+        ctypes.c_int,
+        ctypes.c_uint64,
+        ctypes.c_int64,
+    ]
+    lib.tss_write_at.restype = ctypes.c_int
     return lib
 
 
-def _load_cached() -> Optional[ctypes.CDLL]:
-    """dlopen an up-to-date cached ``.so`` if one exists (no build)."""
-    for lib_path in _candidate_lib_paths():
-        try:
-            if os.path.exists(lib_path) and os.path.getmtime(
-                lib_path
-            ) >= os.path.getmtime(_SRC):
-                lib = _configure(ctypes.CDLL(lib_path))
-                logger.debug("Loaded native IO engine from %s", lib_path)
-                return lib
-        except OSError as e:
-            logger.debug("Native IO engine unavailable at %s: %s", lib_path, e)
-            continue
-    return None
+def _load_built() -> Optional[ctypes.CDLL]:
+    """dlopen the engine built from the current source, if it exists."""
+    path = lib_path()
+    if not os.path.exists(path):
+        return None
+    try:
+        lib = _configure(ctypes.CDLL(path))
+    except OSError as e:
+        logger.debug("Native IO engine unavailable at %s: %s", path, e)
+        return None
+    logger.debug("Loaded native IO engine from %s", path)
+    return lib
 
 
 def _publish(lib: Optional[ctypes.CDLL]) -> Optional[ctypes.CDLL]:
@@ -161,7 +151,7 @@ def load_native() -> Optional[ctypes.CDLL]:
     with _lock:
         if _load_attempted:
             return _lib
-    lib = _load_cached()
+    lib = _load_built()
     if lib is None:
         # Build under its own lock so _lock stays responsive for
         # load_native_nonblocking callers during the multi-second compile.
@@ -169,29 +159,33 @@ def load_native() -> Optional[ctypes.CDLL]:
             with _lock:
                 if _load_attempted:
                     return _lib
-            lib = _load_cached()  # another builder may have just finished
+            lib = _load_built()  # another builder may have just finished
             if lib is None:
-                for lib_path in _candidate_lib_paths():
-                    try:
-                        _build(lib_path)
-                        lib = _configure(ctypes.CDLL(lib_path))
-                        logger.debug("Built native IO engine at %s", lib_path)
-                        break
-                    except (OSError, subprocess.CalledProcessError) as e:
-                        logger.debug(
-                            "Native IO engine build failed at %s: %s", lib_path, e
-                        )
-                        continue
-    if lib is None:
-        logger.info("Native IO engine unavailable; using pure-Python file I/O")
+                path = lib_path()
+                try:
+                    _build(path)
+                    lib = _configure(ctypes.CDLL(path))
+                    logger.debug("Built native IO engine at %s", path)
+                except (OSError, subprocess.CalledProcessError) as e:
+                    logger.info(
+                        "Native IO engine unavailable (%s: %s); using "
+                        "pure-Python file I/O",
+                        path,
+                        getattr(e, "stderr", None) or e,
+                    )
     return _publish(lib)
+
+
+def loaded_path() -> Optional[str]:
+    """Path of the engine this process actually loaded (None: not loaded)."""
+    return _lib._name if _lib is not None else None
 
 
 def load_native_nonblocking() -> Optional[ctypes.CDLL]:
     """Like :func:`load_native`, but never blocks on compilation.
 
-    If a current ``.so`` is cached on disk this loads it synchronously (a
-    dlopen, milliseconds). Otherwise the g++ build runs on a daemon thread
+    If the engine for the current source is already built this loads it
+    synchronously (a dlopen, milliseconds). Otherwise the g++ build runs on a daemon thread
     and this returns ``None`` until it completes — callers fall back to
     buffered I/O in the meantime, keeping first-``take`` latency free of the
     multi-second compile. ``_lock`` is never held across the build, so this
@@ -204,7 +198,7 @@ def load_native_nonblocking() -> Optional[ctypes.CDLL]:
         return None
     if _load_attempted:
         return _lib
-    lib = _load_cached()
+    lib = _load_built()
     if lib is not None:
         return _publish(lib)
     with _lock:
@@ -256,12 +250,7 @@ def write_file_digest(
     is None by design — hashlib's OpenSSL (SHA-NI) implementation beats any
     embedded portable one, so collision-resistant dedup digests stay in
     Python and the scheduler fills the slot when it needs one.
-
-    Returns None when the loaded engine predates the digest API — the
-    caller then writes via :func:`write_file` and hashes in Python.
     """
-    if not getattr(lib, "_tss_has_digest", False):
-        return None
     mv = _as_uint8_view(buf)
     crc = ctypes.c_uint32(0)
     rc = lib.tss_write_file_digest(
@@ -275,12 +264,6 @@ def write_file_digest(
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
     return [crc.value, mv.nbytes, None]
-
-
-def supports_write_at(lib: ctypes.CDLL) -> bool:
-    """Whether the loaded engine has the streamed positioned-write API (a
-    stale cached ``.so`` built from older source may not)."""
-    return bool(getattr(lib, "_tss_has_write_at", False))
 
 
 def write_at(

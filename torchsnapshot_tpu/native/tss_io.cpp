@@ -25,13 +25,7 @@
 #include <sys/stat.h>
 #include <unistd.h>
 
-// The inline-crc digest path needs zlib headers; hosts without zlib dev
-// files build with -DTSS_NO_ZLIB (the loader retries with it) and keep the
-// full IO engine, just without tss_write_file_digest — Python hashing
-// covers digests there.
-#ifndef TSS_NO_ZLIB
-#include <zlib.h>
-#endif
+#include <zlib.h>  // crc32 for the inline digest path
 
 namespace {
 
@@ -40,7 +34,6 @@ constexpr uint64_t kAlign = 4096;  // covers 512/4096 logical sector sizes
 uint64_t align_up(uint64_t v) { return (v + kAlign - 1) / kAlign * kAlign; }
 uint64_t align_down(uint64_t v) { return v / kAlign * kAlign; }
 
-#ifndef TSS_NO_ZLIB
 // Running CRC32 updated as write chunks advance (bytes hashed exactly once,
 // in file order, while the chunk is cache-hot from the bounce copy).
 // Deliberately crc-only: an embedded scalar SHA-256 was tried and measured
@@ -60,11 +53,6 @@ struct HashCtx {
     }
   }
 };
-#else
-struct HashCtx {  // digest API absent; keeps write_impl's signature uniform
-  void update(const char*, uint64_t) {}
-};
-#endif
 
 // Buffered positional write of [src, src+nbytes) at file offset `off`.
 int write_buffered(int fd, const char* src, uint64_t nbytes, uint64_t off,
@@ -180,7 +168,6 @@ int tss_write_file(const char* path, const void* buf, uint64_t nbytes,
   return write_impl(path, buf, nbytes, use_direct, chunk_bytes, nullptr);
 }
 
-#ifndef TSS_NO_ZLIB
 // Like tss_write_file, but also computes the zlib crc32 over the written
 // bytes in the same pass (*crc_out): the separate memory sweep the Python
 // hashing path pays per object is folded into the write loop here.
@@ -192,7 +179,6 @@ int tss_write_file_digest(const char* path, const void* buf, uint64_t nbytes,
   if (rc == 0 && crc_out) *crc_out = static_cast<uint32_t>(hc.crc);
   return rc;
 }
-#endif
 
 // Positioned write for STREAMED objects: write `nbytes` from `buf` at byte
 // `offset` of `path` (created if absent, never truncated on open — earlier

@@ -234,22 +234,6 @@ class LocalStore(Store):
 class JaxCoordinationStore(Store):
     """Rides ``jax.distributed``'s coordination service (usable off-thread)."""
 
-    # Client methods the Store contract needs. jax versions differ here —
-    # e.g. 0.4.x's DistributedRuntimeClient ships the get/set/delete family
-    # but NOT key_value_increment / key_value_try_get_bytes. On such
-    # versions ``available()`` returns False (logged once) so the
-    # coordinator falls back to a TCPStore instead of dying with an
-    # AttributeError inside the first barrier — and leaving peers hanging
-    # until their store timeout.
-    _REQUIRED_CLIENT_OPS = (
-        "key_value_set_bytes",
-        "blocking_key_value_get_bytes",
-        "key_value_try_get_bytes",
-        "key_value_increment",
-        "key_value_delete",
-    )
-    _capability_warned = False
-
     def __init__(self, namespace: str = "tss") -> None:
         from jax._src import distributed
 
@@ -259,51 +243,25 @@ class JaxCoordinationStore(Store):
                 "jax.distributed is not initialized; "
                 "call jax.distributed.initialize() or provide a TCPStore"
             )
-        missing = [
-            op for op in self._REQUIRED_CLIENT_OPS if not hasattr(client, op)
-        ]
-        if missing:
-            raise RuntimeError(
-                "this jax version's coordination-service client lacks "
-                f"{', '.join(missing)}; use a TCPStore "
-                "(TORCHSNAPSHOT_TPU_STORE_ADDR) for checkpoint coordination"
-            )
         self._client = client
         self._ns = namespace
 
     @classmethod
     def available(cls) -> bool:
-        try:
-            from jax._src import distributed
+        from jax._src import distributed
 
-            client = distributed.global_state.client
-            if client is None:
-                return False
-            missing = [
-                op for op in cls._REQUIRED_CLIENT_OPS if not hasattr(client, op)
-            ]
-            if missing:
-                if not cls._capability_warned:
-                    cls._capability_warned = True
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "jax.distributed is initialized but its coordination "
-                        "client lacks %s; falling back to TCPStore "
-                        "coordination (TORCHSNAPSHOT_TPU_STORE_ADDR)",
-                        ", ".join(missing),
-                    )
-                return False
-            return True
-        except Exception:
-            return False
+        return distributed.global_state.client is not None
 
     def _k(self, key: str) -> str:
         return f"{self._ns}/{key}"
 
     def set(self, key: str, value: bytes) -> None:
         _count_op("set")
-        self._client.key_value_set_bytes(self._k(key), bytes(value))
+        # The Store contract is last-writer-wins (as the TCPStore is); the
+        # coordination service refuses a second set unless told otherwise.
+        self._client.key_value_set_bytes(
+            self._k(key), bytes(value), allow_overwrite=True
+        )
 
     def get(self, key: str, timeout_s: float = _DEFAULT_TIMEOUT_S) -> bytes:
         _count_op("get")
@@ -327,10 +285,13 @@ class JaxCoordinationStore(Store):
     def try_get(self, key: str) -> Optional[bytes]:
         _count_op("try_get")
         try:
-            val = self._client.key_value_try_get_bytes(self._k(key))
-        except Exception:
-            return None
-        return bytes(val) if val is not None else None
+            return bytes(self._client.key_value_try_get_bytes(self._k(key)))
+        except Exception as e:
+            # The service reports an absent key as NOT_FOUND; anything else
+            # (a dead coordinator, a closed client) is not "absent".
+            if "NOT_FOUND" in str(e):
+                return None
+            raise
 
     def add(self, key: str, delta: int) -> int:
         _count_op("add")

@@ -64,7 +64,7 @@ from .io_preparers.array import (
 )
 from .io_preparers.chunked_array import should_chunk
 from .io_preparers.object import ObjectBufferStager
-from .io_preparers.sharded_array import local_unique_shards, subdivide
+from .io_preparers.sharded_array import local_unique_shards, shard_pieces
 from .io_types import WriteReq
 from .manifest import Entry, PrimitiveEntry
 from .utils import knobs
@@ -153,22 +153,14 @@ class PreparedTake:
         produced them at prepare time (their iteration is deterministic
         given the structure the fingerprint pins)."""
         if kind == "sharded":
-            dtype = np.dtype(value.dtype)
             max_shard = knobs.get_max_shard_size_bytes()
             pieces: List[Any] = []
             for data, offsets, sizes, replica_id in local_unique_shards(value):
                 if replica_id != 0:
                     continue
-                subs = subdivide(offsets, sizes, dtype.itemsize, max_shard)
-                for sub_off, sub_sz in subs:
-                    if len(subs) == 1:
-                        pieces.append(data)
-                    else:
-                        rel = tuple(
-                            slice(o - bo, o - bo + s)
-                            for o, bo, s in zip(sub_off, offsets, sub_sz)
-                        )
-                        pieces.append(data[rel])
+                pieces.extend(
+                    piece for _, _, piece in shard_pieces(data, offsets, sizes, max_shard)
+                )
             return pieces
         # array / replicated_array: the same unwraps prepare_write applies.
         arr = value

@@ -16,7 +16,7 @@ Serializer families:
   serialized buffer corresponds to a contiguous region of the flat array).
 - ``raw_zstd`` / ``raw_zlib``: the raw byte stream compressed. Opt-in via
   ``TORCHSNAPSHOT_TPU_COMPRESSION`` — on links/stores slower than the
-  compressor (tunneled transports, cloud buckets, shared NVMe) the ~1.3-1.5x
+  compressor (cloud buckets, shared NVMe) the ~1.3-1.5x
   typical ratio on trained bf16/f32 weights directly multiplies effective
   write throughput and shrinks checkpoints. Payloads above
   ``TORCHSNAPSHOT_TPU_COMPRESSION_FRAME_BYTES`` are FRAMED — independent

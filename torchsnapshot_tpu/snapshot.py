@@ -45,7 +45,7 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from .flatten import flatten, inflate
-from .io_preparer import prepare_write
+from .io_preparer import _is_oom_error, prepare_write
 from .io_preparers.array import ArrayIOPreparer
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer
 from .io_preparers.object import ObjectIOPreparer
@@ -1504,13 +1504,24 @@ class Snapshot:
         must tolerate that (flax/optax dicts do). SPMD: every rank must
         pass the same ``include``.
 
+        Device-resident targets: a restored ``jax.Array`` leaf is a new
+        buffer beside its live target, so the restore peaks at twice the
+        state in HBM. When a leaf cannot be allocated beside its target,
+        the target's buffers are released first (it is about to be
+        replaced; other references to it become deleted arrays) — counted
+        as ``restore.targets_consumed`` — so a job whose state fills more
+        than half of HBM can still resume into zero targets.
+
         Failure semantics mirror ``take``: any mid-restore failure —
         transient storms past the retry window, permanent storage faults,
         verification failures, a dead peer — surfaces as a structured
         :class:`CheckpointAbortedError` naming the failing rank and phase
         on EVERY rank within the barrier timeout. The snapshot itself is
         read-only here and stays untouched; live state may be partially
-        loaded (restore targets must be re-restored before use).
+        loaded (restore targets must be re-restored before use), and device
+        targets that were consumed to make room (above) are DELETED arrays,
+        not stale ones — the closing warning names them, and a retry needs
+        fresh targets for those paths.
 
         ``qos``: the restore's QoS class. ``qos="foreground"`` — the
         serving-replica restart path — registers FOREGROUND demand for the
@@ -1604,8 +1615,8 @@ class Snapshot:
             # segment so per-key planning below is O(bucket), not
             # O(manifest). Without this, restore planning is
             # O(keys x manifest) — at a 10^5-entry manifest with hundreds of
-            # keys that is pure quadratic waste (VERDICT round 2, item 7;
-            # reference pays the same scan per key, ``snapshot.py:693-701``).
+            # keys that is pure quadratic waste (the reference pays the same
+            # scan per key, ``snapshot.py:693-701``).
             # Lookup below is by the KEY's first segment (not the key
             # itself): an app key containing '/' spans paths whose first
             # segment is shorter than the key, and _load_stateful's own
@@ -1622,8 +1633,7 @@ class Snapshot:
             # jax ops inside load_state_dict synchronize on their own terms.
             # Restore coordination is then O(1) store round-trips per rank —
             # it runs on the exact path a pod takes while restarting after
-            # preemption, where O(keys x world) rounds were added downtime
-            # (VERDICT round 3, item 3).
+            # preemption, where O(keys x world) rounds were added downtime.
             keys = self._gather_keys(dict(app_state), coord)
             rng_keys = [
                 k for k in keys if isinstance(app_state.get(k), RNGState)
@@ -1719,6 +1729,7 @@ class Snapshot:
                 raise
             raise aborted from e
         finally:
+            _warn_consumed_targets()
             telemetry.fleet.note_op(None)
             pools.shutdown()
             storage.sync_close(event_loop)
@@ -1783,8 +1794,7 @@ class Snapshot:
         # soon as it is finalized (the counting consumer drops its target
         # reference after consuming; the finalizer closure dies right after
         # it runs), bounding restore peak transient RSS by the scheduler
-        # budget + in-flight entries rather than state size (VERDICT round
-        # 3, item 2). The loop thread IS the main thread, so jax dispatch
+        # budget + in-flight entries rather than state size. The loop thread IS the main thread, so jax dispatch
         # stays where it is fast. Two rejected alternatives, both measured
         # on the reshard workload: finalizing on an executor thread (round
         # 3: 12x slower — jax dispatch off the main thread) and running the
@@ -3661,6 +3671,55 @@ def _fetch_frame_tables(
     return tables
 
 
+# Logical paths of the live targets the restore in progress consumed (see
+# ``_place_over_target``); drained into ONE warning when that restore ends.
+# Finalizers may run on consumer threads, hence the lock.
+_consumed_targets: List[str] = []
+_consumed_targets_lock = threading.Lock()
+
+
+def _place_over_target(logical_path: str, live: Any, place: Callable[[], Any]) -> Any:
+    """Put one restored leaf on device, where its live target already is.
+
+    A restore overwrites its targets (the reference restores in place), but
+    jax arrays are immutable: the restored leaf is a NEW buffer beside the
+    live one, so restoring into device-resident targets peaks at twice the
+    state — a job that fills more than half of HBM could never resume. When,
+    and only when, the allocation fails (it does so synchronously at
+    ``device_put``), the target — about to be replaced anyway — gives up its
+    buffers and the leaf is placed again. Counted as
+    ``restore.targets_consumed`` and named in the restore's closing warning;
+    every other reference the caller holds to a consumed target (tied or
+    EMA parameters, a serving copy) is a deleted array afterwards."""
+    try:
+        return place()
+    except Exception as e:  # noqa: BLE001 - only allocation failure degrades
+        if not _is_oom_error(e) or live.is_deleted():
+            raise
+    telemetry.counter_add("restore.targets_consumed")
+    with _consumed_targets_lock:
+        _consumed_targets.append(logical_path)
+    live.delete()
+    return place()
+
+
+def _warn_consumed_targets() -> None:
+    """One warning per restore — completed or failed — naming the targets it
+    consumed: after a failure they are gone, not merely stale."""
+    with _consumed_targets_lock:
+        paths, _consumed_targets[:] = list(_consumed_targets), []
+    if paths:
+        logger.warning(
+            "restore: HBM could not hold %d restored leaves beside their live "
+            "targets, so those targets' buffers were released first (consumed, "
+            "as by donation; any other reference to them is now a deleted "
+            "array): %s%s",
+            len(paths),
+            ", ".join(paths[:8]),
+            f", ... (+{len(paths) - 8} more)" if len(paths) > 8 else "",
+        )
+
+
 def _prepare_restore_one(  # spmd-pure
     logical_path: str,
     entry: Entry,
@@ -3729,18 +3788,25 @@ def _prepare_restore_one(  # spmd-pure
             def finalize_jax() -> None:
                 import jax
 
-                if live.sharding.is_fully_addressable:
-                    loaded[logical_path] = jax.device_put(target, live.sharding)
+                sharding = live.sharding
+                if sharding.is_fully_addressable:
+                    loaded[logical_path] = _place_over_target(
+                        logical_path, live, lambda: jax.device_put(target, sharding)
+                    )
                 else:
                     # device_put onto a multiprocess sharding runs a jitted
                     # consistency collective (refused outright on the
                     # multiprocess CPU backend); building the global array
                     # shard-by-shard needs no collective on any backend —
                     # every rank holds the full host target here.
-                    loaded[logical_path] = jax.make_array_from_callback(
-                        tuple(int(s) for s in entry.shape),
-                        live.sharding,
-                        lambda idx: target[idx],
+                    loaded[logical_path] = _place_over_target(
+                        logical_path,
+                        live,
+                        lambda: jax.make_array_from_callback(
+                            tuple(int(s) for s in entry.shape),
+                            sharding,
+                            lambda idx: target[idx],
+                        ),
                     )
 
             return reqs, finalize_jax
@@ -3762,8 +3828,10 @@ def _prepare_restore_one(  # spmd-pure
             )
 
             def finalize_sharded() -> None:
-                loaded[logical_path] = assemble_jax_array(
-                    sharding, entry.shape, buffers
+                loaded[logical_path] = _place_over_target(
+                    logical_path,
+                    live,
+                    lambda: assemble_jax_array(sharding, entry.shape, buffers),
                 )
 
             return reqs, finalize_sharded

@@ -25,13 +25,7 @@ import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Set
 
-try:
-    import aiofiles
-except ImportError:  # pragma: no cover - environment-dependent
-    # Gated, not required: containers without aiofiles fall back to blocking
-    # file I/O on the plugin's executor (same thread pool the native engine
-    # uses), preserving the async plugin contract.
-    aiofiles = None
+import aiofiles
 
 from .. import native, telemetry
 from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
@@ -92,8 +86,7 @@ class _FSWriteStream(StorageWriteStream):
         if mv.format != "B" or mv.ndim != 1:
             mv = mv.cast("B")
         if self._native_mode is None:
-            lib = self._plugin._native
-            self._native_mode = lib is not None and native.supports_write_at(lib)
+            self._native_mode = self._plugin._native is not None
         if not self._native_mode:
             if self._file is None:
                 self._file = open(self._tmp_path, "wb")
@@ -190,9 +183,15 @@ class _FSWriteStream(StorageWriteStream):
                 "storage",
                 self._t0,
                 t1 - self._t0,
-                {"plugin": "fs", "path": self._path, "nbytes": total},
+                {
+                    "plugin": "fs",
+                    "path": self._path,
+                    "nbytes": total,
+                    "engine": "native" if self._native_mode else "buffered",
+                },
             )
         telemetry.counter_add("storage.fs.write_bytes", total)
+        self._plugin._count_write_path(total, bool(self._native_mode))
 
     async def abort(self) -> None:
         await asyncio.get_running_loop().run_in_executor(
@@ -256,30 +255,51 @@ class FSStoragePlugin(StoragePlugin):
             and nbytes >= knobs.get_direct_io_threshold_bytes()
         )
 
+    def _count_write_path(self, nbytes: int, used_native: bool) -> None:
+        """Which write path an object's bytes took, as metrics: a run that
+        starts before the engine is built writes its first objects buffered
+        and later ones O_DIRECT, and a measurement must be able to tell."""
+        if used_native:
+            telemetry.counter_add("storage.fs.native_write_bytes", nbytes)
+        elif (
+            knobs.is_native_io_enabled()
+            and nbytes >= knobs.get_direct_io_threshold_bytes()
+        ):
+            telemetry.counter_add("storage.fs.native_fallback_bytes", nbytes)
+
     async def write_stream(self, path: str) -> StorageWriteStream:
         return _FSWriteStream(self, path)
 
     async def write(self, write_io: WriteIO) -> None:
         nbytes = memoryview(write_io.buf).nbytes
+        # Resolved once per object so retries, the span and the counters
+        # agree on which path it took.
+        lib = (
+            self._native
+            if nbytes >= knobs.get_direct_io_threshold_bytes()
+            else None
+        )
         with telemetry.span(
             "storage.write",
             cat="storage",
             plugin="fs",
             path=write_io.path,
             nbytes=nbytes,
+            engine="native" if lib is not None else "buffered",
         ):
             # Retry-safe: every attempt writes a FRESH temp file and the
             # error path below unlinks it, so a retried write can neither
             # observe nor leave a prior attempt's partial bytes.
             await retry_transient(
-                lambda: self._write_inner(write_io, nbytes),
+                lambda: self._write_inner(write_io, lib),
                 _is_transient_oserror,
                 self._progress,
                 "fs",
             )
         telemetry.counter_add("storage.fs.write_bytes", nbytes)
+        self._count_write_path(nbytes, lib is not None)
 
-    async def _write_inner(self, write_io: WriteIO, nbytes: int) -> None:
+    async def _write_inner(self, write_io: WriteIO, lib) -> None:
         path = os.path.join(self.root, write_io.path)
         self._ensure_parent(path)
         # Write-then-rename so a crash mid-write can never leave a truncated
@@ -287,8 +307,7 @@ class FSStoragePlugin(StoragePlugin):
         # presence IS the commit marker (object stores give this per-PUT).
         tmp_path = f"{path}.tmp.{uuid.uuid4().hex[:8]}"
         try:
-            if self._use_native(nbytes):
-                lib = self._native
+            if lib is not None:
                 # The crc digest rides the write loop (chunk-hot hashing in
                 # C++) when the CALLER asked for it; the scheduler uses
                 # digest_out instead of a second full pass over the buffer
@@ -299,16 +318,14 @@ class FSStoragePlugin(StoragePlugin):
                 def work() -> None:
                     with self._get_direct_sem():
                         if want_digest:
-                            digest = native.write_file_digest(
+                            write_io.digest_out = native.write_file_digest(
                                 lib,
                                 tmp_path,
                                 write_io.buf,
                                 direct=True,
                                 chunk_bytes=knobs.get_direct_io_chunk_bytes(),
                             )
-                            if digest is not None:
-                                write_io.digest_out = digest
-                                return
+                            return
                         native.write_file(
                             lib,
                             tmp_path,
@@ -320,18 +337,9 @@ class FSStoragePlugin(StoragePlugin):
                 await asyncio.get_running_loop().run_in_executor(
                     self._get_executor(), work
                 )
-            elif aiofiles is not None:
+            else:
                 async with aiofiles.open(tmp_path, "wb") as f:
                     await f.write(write_io.buf)
-            else:
-
-                def buffered_write() -> None:
-                    with open(tmp_path, "wb") as f:
-                        f.write(write_io.buf)
-
-                await asyncio.get_running_loop().run_in_executor(
-                    self._get_executor(), buffered_write
-                )
             # Rename/cleanup are metadata ops, but on network filesystems
             # (NFS-mounted checkpoint dirs) even those can stall for a
             # round-trip — keep the event loop clean and do them on the
@@ -421,21 +429,10 @@ class FSStoragePlugin(StoragePlugin):
     async def _buffered_read(
         self, path: str, offset: int, nbytes: Optional[int]
     ) -> bytes:
-        if aiofiles is not None:
-            async with aiofiles.open(path, "rb") as f:
-                if offset:
-                    await f.seek(offset)
-                return await (f.read(nbytes) if nbytes is not None else f.read())
-
-        def work() -> bytes:
-            with open(path, "rb") as f:
-                if offset:
-                    f.seek(offset)
-                return f.read(nbytes) if nbytes is not None else f.read()
-
-        return await asyncio.get_running_loop().run_in_executor(
-            self._get_executor(), work
-        )
+        async with aiofiles.open(path, "rb") as f:
+            if offset:
+                await f.seek(offset)
+            return await (f.read(nbytes) if nbytes is not None else f.read())
 
     async def _native_read(
         self, path: str, offset: int, nbytes: Optional[int]

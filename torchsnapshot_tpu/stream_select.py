@@ -1,9 +1,9 @@
 """Measurement-driven streaming auto-select (``STREAM_WRITES=auto``).
 
-BENCH_r07 shipped the streaming A/B **inverted** on its host: streaming ON
-drained at 0.21 GB/s vs 0.36 GB/s OFF, because per-chunk staging overhead
-(slicing + copy per 32 MB chunk, timeshared with the appends on a 1-core
-host) cost more than the intra-request overlap bought. Streaming is a
+A global streaming default can ship **inverted** on a host: streaming ON
+drains slower than OFF wherever per-chunk staging overhead (slicing + copy
+per chunk, timeshared with the appends) costs more than the intra-request
+overlap buys. Streaming is a
 per-host, per-plugin trade — so instead of a global boolean default, the
 shipped default is ``auto``: this module keeps a per-plugin **scorecard**
 of measured throughput on both sides, fed by the write pipeline's own

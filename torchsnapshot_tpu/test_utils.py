@@ -11,8 +11,8 @@
 
 from __future__ import annotations
 
+import contextlib
 import multiprocessing as mp
-import os
 import queue as queue_mod
 import time
 import traceback
@@ -93,6 +93,12 @@ def rand_array(shape, dtype: str, seed: Optional[int] = None) -> np.ndarray:
 # Multi-process launcher
 # ---------------------------------------------------------------------------
 
+_WORKER_ENV = {
+    "JAX_PLATFORMS": "cpu",
+    "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
+}
+
+
 def _worker_entry(
     fn: Callable[..., Any],
     rank: int,
@@ -104,12 +110,6 @@ def _worker_entry(
     args: tuple,
 ) -> None:
     try:
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        # TPU platform plugins can override JAX_PLATFORMS; force cpu.
-        jax.config.update("jax_platforms", "cpu")
         from .utils import knobs
 
         knobs.set_coordinator_env(store_addr, rank, world_size)
@@ -186,23 +186,31 @@ def run_with_processes(
     coordinator_addr = f"127.0.0.1:{coordinator_port}"
     error_queue: mp.Queue = ctx.Queue()
     procs: List[mp.Process] = []
-    for rank in range(nproc):
-        p = ctx.Process(
-            target=_worker_entry,
-            args=(
-                fn,
-                rank,
-                nproc,
-                store_addr,
-                error_queue,
-                init_jax_distributed,
-                coordinator_addr,
-                args,
-            ),
-            daemon=False,
-        )
-        p.start()
-        procs.append(p)
+    # Workers are CPU-platform processes with two virtual devices each, and
+    # are born that way: the environment a spawned child starts with is read
+    # by jax at import, before any worker code could set it.
+    from .utils import knobs
+
+    with contextlib.ExitStack() as born_with:
+        for name, value in _WORKER_ENV.items():
+            born_with.enter_context(knobs._override_env(name, value))
+        for rank in range(nproc):
+            p = ctx.Process(
+                target=_worker_entry,
+                args=(
+                    fn,
+                    rank,
+                    nproc,
+                    store_addr,
+                    error_queue,
+                    init_jax_distributed,
+                    coordinator_addr,
+                    args,
+                ),
+                daemon=False,
+            )
+            p.start()
+            procs.append(p)
     failures: Dict[int, str] = {}
     reported: set = set()
     # A worker killed outright (SIGKILL — the preemption failure mode) never

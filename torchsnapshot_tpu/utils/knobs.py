@@ -561,10 +561,10 @@ def is_dedup_digests_enabled(has_base: bool = False) -> bool:
     Default ``auto``: enabled on multi-core hosts (a spare core hides the
     hash behind the D2H/storage streams) and whenever the take itself
     passes ``base=`` (the dedup identity is the point of that take);
-    disabled otherwise — on a single-vCPU host the hash competes with the
-    CPU-fed device transfer and was measured to cost 10-20% of sync-take
-    throughput (interference, not hash time: sha256 itself runs ~1.3
-    GB/s/core). ``1``/``0`` force it either way.
+    disabled otherwise — with one usable core the hash competes with the
+    CPU-fed device transfer (interference, not hash time). Whether the gate
+    is right for the current chip's host is not measured. ``1``/``0`` force
+    it either way.
 
     Caveat the auto mode implies: on a single-core host, a snapshot taken
     WITHOUT ``base=`` carries no sha256s in its sidecars, so a later
@@ -614,12 +614,11 @@ def is_restore_overlap_enabled(
     actually has live jax device targets (``has_jax_targets``) — on any
     host whose TARGET arrays live on a real accelerator: there the
     ``device_put`` dispatch hands off to the PJRT client (transfer-engine/
-    network bound) and overlap measured a ~1.5x restore win with lower
-    peak RSS even on a single vCPU (``benchmarks/restore_overlap/``).
-    Disabled when the targets are CPU-backed on a single-vCPU host:
+    network bound), so overlap needs no spare core (harness:
+    ``benchmarks/restore_overlap/``; not measured on the current chip).
+    Disabled when the targets are CPU-backed on a single-core host:
     CPU-backend dispatch executes the copy on the host's only core and
-    starves behind the busy read pipeline (measured 2.5-10x slower restores
-    on the reshard workload).
+    starves behind the busy read pipeline.
 
     ``target_platforms``: the platforms of the restore targets' shard
     devices — a set of strings (``{"tpu"}``), or a zero-arg callable
@@ -744,8 +743,7 @@ def get_stream_writes_mode() -> str:
     append throughput vs whole-buffer write throughput (fed by the same
     instrumentation as the ``storage.<plugin>.append_s.<bucket>``
     histograms) and the write pipeline resolves the decision at graph-build
-    time — on hosts where per-chunk staging overhead inverts the A/B
-    (BENCH_r07: ON 0.21 GB/s vs OFF 0.36 GB/s on a 1-core host), auto
+    time — on hosts where per-chunk staging overhead inverts the A/B, auto
     converges to OFF after the first measured takes instead of shipping the
     inversion silently. With no evidence yet, auto streams (the optimistic
     prior: streaming bounds peak RAM and wins wherever appends are not
@@ -793,9 +791,7 @@ def is_stream_writes_enabled() -> bool:
 
 def get_stream_chunk_bytes() -> int:
     """Target bytes per streamed chunk (default 64 MB). Smaller chunks
-    overlap sooner and bound RAM tighter but pay more per-append overhead
-    (BENCH_r07's inversion was overhead-dominated at the old 32 MB default
-    — per-chunk staging burned ~2s of CPU the whole-buffer path didn't);
+    overlap sooner and bound RAM tighter but pay more per-append overhead;
     keep well above the storage plugin's per-op latency·bandwidth product.
     The hash-chunk grain defaults to this value, so changing it re-grids
     dedup identities: objects taken under a different grain re-upload once
