@@ -123,14 +123,11 @@ class CompressedSlabStager(BufferStager):
             raw = await self.inner.stage_buffer(executor)
 
             def work() -> bytes:
-                t0 = time.monotonic()
-                payload, sizes = compress_member_framed(
-                    raw, self.member_sizes, self.serializer, self.level
-                )
-                if times is not None:
-                    times.record(
-                        "serialize", t0, time.monotonic(), nbytes=len(payload)
+                with d2h.timed(times, "serialize") as compressing:
+                    payload, sizes = compress_member_framed(
+                        raw, self.member_sizes, self.serializer, self.level
                     )
+                    compressing.sized(len(payload))
                 with self._frame_lock:
                     self.frame_sizes = sizes
                 return payload

@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .telemetry import core as telemetry_core
 from .utils import knobs
 
 logger = logging.getLogger(__name__)
@@ -107,7 +108,6 @@ class StageTimes:
             )
             if kind == "d2h":
                 tm.metrics.counter("d2h.bytes").add(nbytes)
-                tm.metrics.histogram("d2h.seconds").observe(t1 - t0)
                 if device is not None:
                     tm.metrics.counter(f"d2h.device_bytes.{device}").add(nbytes)
 
@@ -115,6 +115,60 @@ class StageTimes:
         """A snapshot copy per kind (safe to merge/clip while staging runs)."""
         with self._lock:
             return {k: list(v) for k, v in self._intervals.items()}
+
+
+class timed:
+    """``with d2h.timed(times, kind, ...)`` around one stretch of staging
+    work that is synchronous on the calling thread (a lane's resolve, a
+    compress, a whole-leaf hash): the interval is recorded as
+    :meth:`StageTimes.record` would and, under a telemetry session, the
+    stretch is also a ``tss.stage.<kind>`` event of a running profiler
+    trace. ``sized(n)`` inside the body gives the bytes where only the
+    work's result says them. ``times`` None: nothing is recorded. Per-chunk
+    work (``stage.hash_chunk``, streamed appends) stays with ``record``:
+    too many events for a trace."""
+
+    __slots__ = ("_times", "_kind", "_path", "_nbytes", "_device", "_t0", "_ann")
+
+    def __init__(
+        self,
+        times: Optional[StageTimes],
+        kind: str,
+        path: str = "",
+        nbytes: int = 0,
+        device: Optional[int] = None,
+    ) -> None:
+        self._times = times
+        self._kind = kind
+        self._path = path
+        self._nbytes = nbytes
+        self._device = device
+        self._t0 = 0.0
+        self._ann: Optional[Any] = None
+
+    def sized(self, nbytes: int) -> None:
+        self._nbytes = nbytes
+
+    def __enter__(self) -> "timed":
+        times = self._times
+        if times is not None:
+            if times._tm is not None:
+                self._ann = telemetry_core.open_annotation(f"stage.{self._kind}")
+            self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        times = self._times
+        if times is not None:
+            t1 = time.monotonic()
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+            if exc_type is None:
+                times.record(
+                    self._kind, self._t0, t1,
+                    path=self._path, nbytes=self._nbytes, device=self._device,
+                )
+        return False
 
 
 class TransferLanes:
@@ -242,18 +296,8 @@ class TransferLanes:
         device = next(iter(devices)).id if len(devices) == 1 else None
 
         def resolve() -> np.ndarray:
-            t0 = time.monotonic()
-            host = np.asarray(arr)
-            if times is not None:
-                times.record(
-                    "d2h",
-                    t0,
-                    time.monotonic(),
-                    path=location,
-                    nbytes=nbytes,
-                    device=device,
-                )
-            return host
+            with timed(times, "d2h", path=location, nbytes=nbytes, device=device):
+                return np.asarray(arr)
 
         return loop.run_in_executor(self.executor(), resolve)
 

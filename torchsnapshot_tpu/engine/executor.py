@@ -156,6 +156,13 @@ class NodeContext:
         bodies read it to take over per-chunk accounting."""
         return self.engine._reservation.get(self.node, 0)
 
+    @property
+    def admitted_at(self) -> float:
+        """``time.monotonic()`` at which this node was admitted (its span's
+        start): a body that waits for something before its real work
+        measures that wait from here."""
+        return self.engine._t0.get(self.node, 0.0)
+
     def recost(self, nbytes: int) -> None:
         """Correct this node's admission reservation to the actual bytes
         (estimate → real buffer footprint); the corrected reservation rides
@@ -219,6 +226,9 @@ class GraphExecutor:
         self._tasks: Dict[asyncio.Task, Node] = {}
         self._reservation: Dict[Node, int] = {}
         self._t0: Dict[Node, float] = {}
+        # Span ids reserved when a node's body starts, so that what the
+        # body records parents to the node's span (itself recorded at reap).
+        self._span_id: Dict[Node, int] = {}
         self._nbytes: Dict[Node, int] = {}
         self._started: Dict[Node, bool] = {}
         self._inflight: Dict[str, int] = {}
@@ -483,6 +493,8 @@ class GraphExecutor:
         # a never-started self_budget node must credit its admission
         # reservation itself (the body's own finally-credits never execute).
         self._started[node] = True
+        if self._tm is not None and node.record_span:
+            self._span_id[node] = self._tm.begin_deferred_span()
         return await node.run(NodeContext(self, node), payload)
 
     # --------------------------------------------------------------- reaping
@@ -494,6 +506,7 @@ class GraphExecutor:
             reservation = self._reservation.pop(node, 0)
             t0 = self._t0.pop(node, 0.0)
             started = self._started.pop(node, False)
+            span_id = self._span_id.pop(node, None)
             try:
                 result = task.result()
             except BaseException:
@@ -507,7 +520,8 @@ class GraphExecutor:
             nbytes = self._nbytes.pop(node, reservation)
             if node.record_span:
                 self.record_interval(
-                    node.kind, t0, node.path, nbytes, stream=node.stream
+                    node.kind, t0, node.path, nbytes, stream=node.stream,
+                    span_id=span_id,
                 )
             if node.successor is not None:
                 # The edge handoff: result + reservation travel together;
@@ -534,6 +548,7 @@ class GraphExecutor:
         path: str = "",
         nbytes: int = 0,
         stream: Optional[str] = "auto",
+        span_id: Optional[int] = None,
     ) -> None:
         """One finished node/sub-step: record its interval (stats) and,
         when telemetry is on, the corresponding span. ``stream="auto"``
@@ -555,6 +570,7 @@ class GraphExecutor:
                 t0,
                 t1 - t0,
                 {"path": path, "nbytes": nbytes, "rank": self.rank},
+                span_id=span_id,
             )
 
     # ------------------------------------------------------------ preemption
@@ -617,6 +633,7 @@ class GraphExecutor:
             reservation = self._reservation.pop(node, 0)
             started = self._started.pop(node, False)
             self._t0.pop(node, None)
+            self._span_id.pop(node, None)
             self._nbytes.pop(node, None)
             # Started self_budget bodies credit their own debits (including
             # the admission reservation they took over) in their finally

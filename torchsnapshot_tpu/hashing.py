@@ -52,7 +52,7 @@ import time
 import zlib
 from typing import Any, List, Optional, Sequence, Tuple
 
-from . import telemetry
+from . import d2h, telemetry
 
 __all__ = [
     "crc32_combine",
@@ -731,13 +731,8 @@ async def hash_buffer(
     if grain <= 0 or mv.nbytes <= grain:
 
         def serial():
-            t0 = time.monotonic()
-            out = serial_digest(mv, want_sha)
-            if times is not None:
-                times.record(
-                    "hash", t0, time.monotonic(), path=path, nbytes=mv.nbytes
-                )
-            return out
+            with d2h.timed(times, "hash", path=path, nbytes=mv.nbytes):
+                return serial_digest(mv, want_sha)
 
         return await loop.run_in_executor(executor, serial)
 
@@ -745,13 +740,8 @@ async def hash_buffer(
     if want_whole_sha:
 
         def whole():
-            t0 = time.monotonic()
-            out = hashlib.sha256(mv).hexdigest()
-            if times is not None:
-                times.record(
-                    "hash", t0, time.monotonic(), path=path, nbytes=mv.nbytes
-                )
-            return out
+            with d2h.timed(times, "hash", path=path, nbytes=mv.nbytes):
+                return hashlib.sha256(mv).hexdigest()
 
         whole_fut = loop.run_in_executor(executor, whole)
     hasher = ChunkHasher(

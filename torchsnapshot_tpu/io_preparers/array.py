@@ -33,6 +33,7 @@ import numpy as np
 from .. import d2h, telemetry
 from ..io_types import BufferConsumer, BufferStager, BufferType, ReadReq, WriteReq
 from ..manifest import ArrayEntry
+from ..restore_times import run_consume_work
 from ..serialization import (
     Serializer,
     array_as_bytes_view,
@@ -167,10 +168,9 @@ async def _traced_to_host(
     tm = telemetry.get_active()
     if tm is None:
         return await to_host(arr, executor)()
-    with tm.span("stage.d2h", "stage", path=location, nbytes=nbytes) as sp:
+    with tm.span("stage.d2h", "stage", path=location, nbytes=nbytes):
         host = await to_host(arr, executor)()
     tm.metrics.counter("d2h.bytes").add(nbytes)
-    tm.metrics.histogram("d2h.seconds").observe(sp.span.dur or 0.0)
     return host
 
 
@@ -293,18 +293,14 @@ class ArrayBufferStager(BufferStager):
             loop = asyncio.get_running_loop()
             if self.entry.frame_bytes:
                 def framed():
-                    t0 = time.monotonic()
-                    payload, sizes = compress_framed(
-                        view,
-                        self.entry.serializer,
-                        level,
-                        self.entry.frame_bytes,
-                    )
-                    if times is not None:
-                        times.record(
-                            "serialize", t0, time.monotonic(),
-                            path=location, nbytes=len(payload),
+                    with d2h.timed(times, "serialize", path=location) as work:
+                        payload, sizes = compress_framed(
+                            view,
+                            self.entry.serializer,
+                            level,
+                            self.entry.frame_bytes,
                         )
+                        work.sized(len(payload))
                     # Publish for the companion FrameTableStager (same
                     # pipeline, polls until this lands). Cross-thread by
                     # design: a single atomic reference store, and the
@@ -319,25 +315,17 @@ class ArrayBufferStager(BufferStager):
                 return framed()
 
             def compress():
-                t0 = time.monotonic()
-                payload = compress_payload(view, self.entry.serializer, level)
-                if times is not None:
-                    times.record(
-                        "serialize", t0, time.monotonic(),
-                        path=location, nbytes=len(payload),
-                    )
+                with d2h.timed(times, "serialize", path=location) as work:
+                    payload = compress_payload(view, self.entry.serializer, level)
+                    work.sized(len(payload))
                 return payload
 
             if executor is not None:
                 return await loop.run_in_executor(executor, compress)
             return compress()
-        t0 = time.monotonic()
-        payload = pickle.dumps(host, protocol=pickle.HIGHEST_PROTOCOL)
-        if times is not None:
-            times.record(
-                "serialize", t0, time.monotonic(),
-                path=location, nbytes=len(payload),
-            )
+        with d2h.timed(times, "serialize", path=location) as work:
+            payload = pickle.dumps(host, protocol=pickle.HIGHEST_PROTOCOL)
+            work.sized(len(payload))
         return payload
 
     def get_staging_cost_bytes(self) -> int:
@@ -737,11 +725,7 @@ class FramedSliceConsumer(BufferConsumer):
                 memoryview(raw)[off : off + (self.raw_end - self.raw_begin)]
             )
 
-        loop = asyncio.get_running_loop()
-        if executor is not None:
-            await loop.run_in_executor(executor, work)
-        else:
-            work()
+        await run_consume_work(work, executor)
 
     def get_consuming_cost_bytes(self) -> int:
         # Compressed group + decompressed raw coexist during decode.
@@ -816,11 +800,7 @@ class ArrayBufferConsumer(BufferConsumer):
                 src = pickle.loads(bytes(buf))
             np.copyto(self.target, src, casting="no")
 
-        loop = asyncio.get_running_loop()
-        if executor is not None:
-            await loop.run_in_executor(executor, work)
-        else:
-            work()
+        await run_consume_work(work, executor)
 
     def get_consuming_cost_bytes(self) -> int:
         return entry_cost_bytes(self.entry)
@@ -847,11 +827,7 @@ class ChunkedReadConsumer(BufferConsumer):
         def work() -> None:
             flat[begin:end] = np.frombuffer(memoryview(buf), dtype=np.uint8)
 
-        loop = asyncio.get_running_loop()
-        if executor is not None:
-            await loop.run_in_executor(executor, work)
-        else:
-            work()
+        await run_consume_work(work, executor)
 
     def get_consuming_cost_bytes(self) -> int:
         return self.byte_range[1] - self.byte_range[0]

@@ -15,6 +15,7 @@ from typing import Any, Callable, List, Optional, Tuple
 
 from ..io_types import BufferConsumer, BufferStager, BufferType, ReadReq, WriteReq
 from ..manifest import ObjectEntry
+from ..restore_times import run_consume_work
 from ..serialization import Serializer
 
 
@@ -57,9 +58,13 @@ class ObjectBufferConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        obj = pickle.loads(bytes(buf))
-        if self._callback is not None:
-            self._callback(obj)
+        def work() -> None:
+            obj = pickle.loads(bytes(buf))
+            if self._callback is not None:
+                self._callback(obj)
+
+        # Inline on the loop thread, as ever: objects are small.
+        await run_consume_work(work, None)
 
     def get_consuming_cost_bytes(self) -> int:
         return 1024 * 1024

@@ -20,7 +20,6 @@ This is the elasticity engine — the TPU-native analogue of the reference's
 
 from __future__ import annotations
 
-import asyncio
 import pickle
 from concurrent.futures import Executor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -30,6 +29,7 @@ import numpy as np
 from .. import hashing
 from ..io_types import BufferConsumer, BufferType, ReadReq, WriteReq
 from ..manifest import ArrayEntry, Shard, ShardedArrayEntry
+from ..restore_times import run_consume_work
 from ..serialization import (
     Serializer,
     array_from_bytes,
@@ -330,11 +330,7 @@ class ShardedArrayBufferConsumer(BufferConsumer):
                 src_view = src[src_slices] if src_slices else src
                 np.copyto(dst_view, src_view, casting="no")
 
-        loop = asyncio.get_running_loop()
-        if executor is not None:
-            await loop.run_in_executor(executor, work)
-        else:
-            work()
+        await run_consume_work(work, executor)
 
     def get_consuming_cost_bytes(self) -> int:
         from .array import entry_cost_bytes

@@ -64,7 +64,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import psutil
 
-from . import d2h, hashing, stream_select, telemetry
+from . import d2h, hashing, restore_times, stream_select, telemetry
 from .engine import GraphExecutor, Node, Priority
 from .engine.executor import Budget as _Budget  # noqa: F401 - test surface
 from .engine.executor import ProgressReporter as _ProgressReporter  # noqa: F401
@@ -414,19 +414,7 @@ class _WritePipeline:
         return self._engine.io_intervals
 
     def _after_reap(self) -> None:
-        self._publish_progress()
         self._maybe_mark_staged()
-
-    def _publish_progress(self) -> None:
-        """Mirror the progress counters as gauges when a session is on, so
-        the persisted artifact (and any live metrics scrape) carries them."""
-        tm = self._tm
-        if tm is None:
-            return
-        p = self.progress
-        tm.metrics.gauge("progress.bytes_staged").set(p.bytes_staged)
-        tm.metrics.gauge("progress.bytes_written").set(p.bytes_written)
-        tm.metrics.gauge("progress.requests_done").set(p.requests_done)
 
     # ------------------------------------------------------- graph building
 
@@ -723,10 +711,8 @@ class _WritePipeline:
         times = self._staging_ctx.times
 
         def work():
-            t0 = time.monotonic()
-            out = fn()
-            times.record("hash", t0, time.monotonic(), path=path, nbytes=nbytes)
-            return out
+            with d2h.timed(times, "hash", path=path, nbytes=nbytes):
+                return fn()
 
         return work
 
@@ -1305,6 +1291,18 @@ def _verify_checker(
     return None
 
 
+def _timed_checker(times: "restore_times.RestoreTimes", checker, path: str):
+    """``checker`` stamping its own ``verify`` interval, on the executor
+    thread that runs it."""
+    parent = telemetry.core.current_span_id()
+
+    def timed(mv):
+        with times.work("verify", path=path, nbytes=mv.nbytes, parent=parent):
+            return checker(mv)
+
+    return timed
+
+
 async def execute_read_reqs(
     read_reqs: List[ReadReq],
     storage: StoragePlugin,
@@ -1369,14 +1367,27 @@ async def execute_read_reqs(
         bytes_done=lambda: totals["bytes_read"],
     )
 
-    async def fetch(req: ReadReq) -> ReadIO:
-        return await fetch_read_io(
-            storage, req.path, req.byte_range, read_progress
-        )
+    # The restore's interval sink (None outside a restore): each request's
+    # fetch, verify and consume are stamped where they happen.
+    times = restore_times.get_active()
 
-    def make_read_body(req: ReadReq):
+    def make_bodies(req: ReadReq):
+        fetched_at = 0.0  # when read_one handed its buffer on
+
+        async def fetch(ctx) -> ReadIO:
+            t0 = time.monotonic()
+            read_io = await fetch_read_io(
+                storage, req.path, req.byte_range, read_progress
+            )
+            if times is not None:
+                times.record_fetch(
+                    t0, req.path, read_io.buf.getbuffer().nbytes, ctx.admitted_at
+                )
+            return read_io
+
         async def read_one(ctx, _payload):
-            read_io = await fetch(req)
+            nonlocal fetched_at
+            read_io = await fetch(ctx)
             want = (
                 _read_digest_record(digests, req.path) if verify_reads else None
             )
@@ -1386,6 +1397,8 @@ async def execute_read_reqs(
                 else None
             )
             if checker is not None:
+                if times is not None:
+                    checker = _timed_checker(times, checker, req.path)
                 loop = asyncio.get_running_loop()
                 problem = await loop.run_in_executor(
                     executor, checker, read_io.buf.getbuffer()
@@ -1404,7 +1417,7 @@ async def execute_read_reqs(
                             quarantine_cache.quarantine_path,
                             req.path,
                         )
-                    read_io = await fetch(req)
+                    read_io = await fetch(ctx)
                     problem = await loop.run_in_executor(
                         executor, checker, read_io.buf.getbuffer()
                     )
@@ -1419,26 +1432,25 @@ async def execute_read_reqs(
             nbytes = memoryview(buf).nbytes
             totals["bytes_read"] += nbytes
             ctx.note_bytes(nbytes)
+            fetched_at = time.monotonic()
             return buf
 
-        return read_one
-
-    def make_consume_body(req: ReadReq):
         async def consume(_ctx, buf):
+            if times is not None:
+                restore_times.begin_consume(fetched_at)
             await req.buffer_consumer.consume_buffer(buf, executor)
 
-        return consume
+        return read_one, consume
 
     for req in sorted(
         read_reqs, key=lambda r: -r.buffer_consumer.get_consuming_cost_bytes()
     ):
-        consume_node = Node(
-            "consume", make_consume_body(req), pool="consume", path=req.path
-        )
+        read_one, consume = make_bodies(req)
+        consume_node = Node("consume", consume, pool="consume", path=req.path)
         eng.add(
             Node(
                 "read_io",
-                make_read_body(req),
+                read_one,
                 cost_bytes=req.buffer_consumer.get_consuming_cost_bytes(),
                 pool="io",
                 path=req.path,
@@ -1464,7 +1476,6 @@ async def execute_read_reqs(
 
     bytes_read = totals["bytes_read"]
     elapsed = time.monotonic() - begin_ts
-    telemetry.counter_add("scheduler.bytes_read", bytes_read)
     telemetry.gauge_max("scheduler.budget_hwm_bytes", eng.budget.high_water_bytes)
     if bytes_read:
         logger.info(

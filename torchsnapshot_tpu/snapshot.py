@@ -40,7 +40,7 @@ import os
 import threading
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Optional, Set, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
@@ -83,7 +83,7 @@ from .scheduler import (
 )
 from .stateful import AppState, Stateful
 from .storage_plugin import url_to_storage_plugin_in_event_loop
-from . import hashing, telemetry
+from . import hashing, restore_times, telemetry
 from .utils import knobs
 from .version import __version__
 
@@ -283,6 +283,7 @@ def _persist_op_artifact(
     tm: Optional["telemetry.Telemetry"],
     phase_spans=None,
     io_summary: Optional[Dict[str, Any]] = None,
+    restore_stats: Optional[Dict[str, float]] = None,
 ) -> None:
     """Persist this rank's telemetry artifact into the snapshot, fail-open.
 
@@ -305,6 +306,7 @@ def _persist_op_artifact(
                 tm=tm,
                 phase_spans=phase_spans,
                 io_summary=io_summary,
+                restore_stats=restore_stats,
             )
         )
     except Exception:  # noqa: BLE001 - diagnostics must not fail the op
@@ -610,7 +612,7 @@ class Snapshot:
                 )
                 # Commit metadata only after ALL ranks finished writing data.
                 phase = "commit"
-                with telemetry.span("take.commit", cat="take"), \
+                with telemetry.span("take.commit", cat="take", bridge=True), \
                         _barrier_stall_guard(rank):
                     if barrier is not None:
                         barrier.arrive()
@@ -818,7 +820,9 @@ class Snapshot:
 
         # Phase boundaries are telemetry spans; the legacy LAST_TAKE_PHASES
         # dict is derived from the same tracker at the end of _take_impl.
-        tracker = telemetry.PhaseTracker(cat="take.phase")
+        tracker = telemetry.PhaseTracker(
+            cat="take.phase", first="gather_keys_and_flatten"
+        )
 
         # Snapshot the mapping itself: a stateful whose state_dict() mutates
         # the caller's app_state dict must not perturb this iteration.
@@ -845,7 +849,7 @@ class Snapshot:
             mnfst, flat = flatten(sd, prefix=key)
             manifest.update(mnfst)
             flattened.update(flat)
-        tracker.mark("gather_keys_and_flatten")
+        tracker.mark("gather_keys_and_flatten", then="preflight")
 
         # The plan-cache probe only matters at world > 1 (preflight
         # bypasses the collectives entirely at world 1 and plans are never
@@ -881,7 +885,7 @@ class Snapshot:
             plan_token=cached.token if cached is not None else None,
             keys_sig=keys_sig,
         )
-        tracker.mark("preflight")
+        tracker.mark("preflight", then="prepare_write")
         return TakePlan(
             path=pf.path,
             base=pf.base,
@@ -914,10 +918,12 @@ class Snapshot:
         # (plugin construction, event-loop creation) lands in the next
         # phase, so the decomposition COVERS the stall instead of leaking
         # un-phased time (test_stall_decomposition's coverage assertion).
-        tracker = plan.phase_tracker or telemetry.PhaseTracker(cat="take.phase")
+        tracker = plan.phase_tracker or telemetry.PhaseTracker(
+            cat="take.phase", first="prepare_write"
+        )
 
-        def _phase(name: str) -> None:
-            tracker.mark(name)
+        def _phase(name: str, then: Optional[str] = None) -> None:
+            tracker.mark(name, then=then)
 
         manifest: Manifest = dict(plan.manifest)
         flattened = plan.flattened
@@ -1000,7 +1006,7 @@ class Snapshot:
                 leaf_index=leaf_index,
             )
             manifest.update(local_manifest)
-        _phase("prepare_write")
+        _phase("prepare_write", then="partition")
 
         if prepared is None:
             write_reqs, assignment = partition_write_reqs_with_assignment(
@@ -1041,7 +1047,7 @@ class Snapshot:
                 prepare_cache_mod.store(coord, prep_key, entry)
                 plan.prepared_entry = entry
                 prepare_timings["cache_miss"] = time.monotonic() - t0
-        _phase("partition")
+        _phase("partition", then="d2h_hint")
         # Decompose the dominant stall phases into stage.prepare.* sub-spans
         # (d2h_hint: the defensive device fork + transfer hints;
         # stager_construction: per-preparer planning; plan: the remainder;
@@ -1059,7 +1065,7 @@ class Snapshot:
             for req in write_reqs:
                 if req.defer_staging:
                     req.buffer_stager.start_d2h_hint()
-        _phase("d2h_hint")
+        _phase("d2h_hint", then="manifest_gather")
 
         if plan.cache_hit:
             global_manifest = gather_manifest_delta(manifest, coord, plan.cached)
@@ -1095,7 +1101,7 @@ class Snapshot:
             if global_manifest is not None
             else None
         )
-        _phase("manifest_gather")
+        _phase("manifest_gather", then="memory_budget")
 
         # On a cache hit the hostname all_gather inside the budget
         # computation is skipped: the local world size was derived (and
@@ -1104,7 +1110,7 @@ class Snapshot:
         memory_budget = get_process_memory_budget_bytes(
             None if plan.cache_hit else coord
         )
-        _phase("memory_budget")
+        _phase("memory_budget", then="capture")
         if base and not (
             knobs.is_checksums_enabled()
             and knobs.is_dedup_digests_enabled(has_base=True)
@@ -1563,6 +1569,10 @@ class Snapshot:
         swarm_mod.reset_diagnostics()
         LAST_RESTORE_STATS.clear()
         read_totals = {"bytes_read": 0.0, "read_wall_s": 0.0, "requests": 0.0}
+        # Where this restore's time goes (restore_times.py): every layer of
+        # the read path stamps its own intervals, reduced once at the end.
+        times = restore_times.RestoreTimes(tm)
+        times_token = restore_times.activate(times)
         # Before any storage IO: the metadata read below would otherwise
         # freeze the FS plugin's O_DIRECT stream cap at the unscaled default
         # in a fresh (restore-only) process.
@@ -1599,45 +1609,47 @@ class Snapshot:
             )
         phase = "restore.plan"
         try:
-            with telemetry.span("restore.read_metadata", cat="restore"):
-                metadata = self._read_metadata(storage, event_loop)
-            # The snapshot's parsed checksum sidecars, read once per
-            # restore: the read-through cache keys data objects by them,
-            # and the read pipeline / broadcast phase verify fetched bytes
-            # against them (TORCHSNAPSHOT_TPU_VERIFY_READS).
-            digest_index = self._load_digest_index(
-                storage, metadata, event_loop
-            )
-            self._attach_cache_digests(storage, digest_index)
-            phase = "restore.read"
-            manifest = get_manifest_for_rank(metadata, rank)
-            # One-pass prefix index: bucket entries by their FIRST path
-            # segment so per-key planning below is O(bucket), not
-            # O(manifest). Without this, restore planning is
-            # O(keys x manifest) — at a 10^5-entry manifest with hundreds of
-            # keys that is pure quadratic waste (the reference pays the same
-            # scan per key, ``snapshot.py:693-701``).
-            # Lookup below is by the KEY's first segment (not the key
-            # itself): an app key containing '/' spans paths whose first
-            # segment is shorter than the key, and _load_stateful's own
-            # exact-prefix filter narrows the bucket.
-            by_first_seg: Dict[str, Manifest] = {}
-            for p, e in manifest.items():
-                by_first_seg.setdefault(p.partition("/")[0], {})[p] = e
+            # The preamble is planning too, and starts where wall_s does.
+            with times.work("plan", since=restore_t0):
+                with telemetry.span("restore.read_metadata", cat="restore"):
+                    metadata = self._read_metadata(storage, event_loop)
+                # The snapshot's parsed checksum sidecars, read once per
+                # restore: the read-through cache keys data objects by them,
+                # and the read pipeline / broadcast phase verify fetched bytes
+                # against them (TORCHSNAPSHOT_TPU_VERIFY_READS).
+                digest_index = self._load_digest_index(
+                    storage, metadata, event_loop
+                )
+                self._attach_cache_digests(storage, digest_index)
+                phase = "restore.read"
+                manifest = get_manifest_for_rank(metadata, rank)
+                # One-pass prefix index: bucket entries by their FIRST path
+                # segment so per-key planning below is O(bucket), not
+                # O(manifest). Without this, restore planning is
+                # O(keys x manifest) — at a 10^5-entry manifest with hundreds of
+                # keys that is pure quadratic waste (the reference pays the same
+                # scan per key, ``snapshot.py:693-701``).
+                # Lookup below is by the KEY's first segment (not the key
+                # itself): an app key containing '/' spans paths whose first
+                # segment is shorter than the key, and _load_stateful's own
+                # exact-prefix filter narrows the bucket.
+                by_first_seg: Dict[str, Manifest] = {}
+                for p, e in manifest.items():
+                    by_first_seg.setdefault(p.partition("/")[0], {})[p] = e
 
-            # Restore RNG last so loading other statefuls can't perturb it.
-            # One gather+broadcast round resolves the global key order; the
-            # per-key barriers of rounds 1-3 are gone: every rank loads the
-            # union's keys in the same order, so the coordinator's
-            # generation-counted collectives stay aligned without them, and
-            # jax ops inside load_state_dict synchronize on their own terms.
-            # Restore coordination is then O(1) store round-trips per rank —
-            # it runs on the exact path a pod takes while restarting after
-            # preemption, where O(keys x world) rounds were added downtime.
-            keys = self._gather_keys(dict(app_state), coord)
-            rng_keys = [
-                k for k in keys if isinstance(app_state.get(k), RNGState)
-            ]
+                # Restore RNG last so loading other statefuls can't perturb it.
+                # One gather+broadcast round resolves the global key order; the
+                # per-key barriers of rounds 1-3 are gone: every rank loads the
+                # union's keys in the same order, so the coordinator's
+                # generation-counted collectives stay aligned without them, and
+                # jax ops inside load_state_dict synchronize on their own terms.
+                # Restore coordination is then O(1) store round-trips per rank —
+                # it runs on the exact path a pod takes while restarting after
+                # preemption, where O(keys x world) rounds were added downtime.
+                keys = self._gather_keys(dict(app_state), coord)
+                rng_keys = [
+                    k for k in keys if isinstance(app_state.get(k), RNGState)
+                ]
             for key in [k for k in keys if k not in rng_keys] + rng_keys:
                 if key in app_state:
                     with telemetry.span(
@@ -1667,38 +1679,48 @@ class Snapshot:
                             read_totals["requests"] += stats.get(
                                 "requests", 0.0
                             )
-            # Restore telemetry artifact (.telemetry/restore_rank_<k>.json):
-            # the restore-side record — metrics dump (bytes read per
-            # plugin), per-stateful load spans — written through the same
-            # plugin, fail-open (a read-only snapshot store just logs once).
-            _persist_op_artifact(
-                storage,
-                event_loop,
-                rank=rank,
-                world_size=coord.get_world_size(),
-                op="restore",
-                tm=tm,
-                phase_spans=tm.spans(cat="restore") if tm is not None else None,
-            )
-            # Single post-load barrier: no rank observes restore() as
-            # complete (and e.g. deletes/overwrites the snapshot, or
-            # reports readiness) while a peer is still reading storage.
-            # LinearBarrier (not coord.barrier): a failing or dead peer
-            # fails this rank promptly with attribution instead of a bare
-            # timeout.
-            phase = "restore.barrier"
-            if barrier is not None:
-                with _barrier_stall_guard(rank):
-                    barrier.arrive()
-                    barrier.depart()
-                # Full-world rendezvous: the coordinator may collect
-                # collective keys (incl. broadcast-restore payloads)
-                # posted before it.
-                coord.note_external_barrier()
-            # Main-thread op end on the fleet bus: GC superseded beacon
-            # generations (bounded store occupancy).
-            telemetry.fleet.gc_beacons()
+            with times.work("load"):
+                # Restore telemetry artifact
+                # (.telemetry/restore_rank_<k>.json): the restore-side
+                # record — metrics dump (bytes read per plugin),
+                # per-stateful load spans, the split of the restore's time
+                # as far as it has come (``restore_stats_s``: all of it but
+                # this closing interval) — written through the same plugin,
+                # fail-open (a read-only snapshot store just logs once).
+                _persist_op_artifact(
+                    storage,
+                    event_loop,
+                    rank=rank,
+                    world_size=coord.get_world_size(),
+                    op="restore",
+                    tm=tm,
+                    phase_spans=tm.spans(cat="restore") if tm is not None else None,
+                    restore_stats=dict(
+                        read_totals,
+                        wall_s=time.monotonic() - restore_t0,
+                        **times.summary(),
+                    ),
+                )
+                # Single post-load barrier: no rank observes restore() as
+                # complete (and e.g. deletes/overwrites the snapshot, or
+                # reports readiness) while a peer is still reading storage.
+                # LinearBarrier (not coord.barrier): a failing or dead peer
+                # fails this rank promptly with attribution instead of a
+                # bare timeout.
+                phase = "restore.barrier"
+                if barrier is not None:
+                    with _barrier_stall_guard(rank):
+                        barrier.arrive()
+                        barrier.depart()
+                    # Full-world rendezvous: the coordinator may collect
+                    # collective keys (incl. broadcast-restore payloads)
+                    # posted before it.
+                    coord.note_external_barrier()
+                # Main-thread op end on the fleet bus: GC superseded beacon
+                # generations (bounded store occupancy).
+                telemetry.fleet.gc_beacons()
             LAST_RESTORE_STATS.update(read_totals)
+            LAST_RESTORE_STATS.update(times.summary())
             LAST_RESTORE_STATS["wall_s"] = time.monotonic() - restore_t0
             LAST_RESTORE_STATS["bcast"] = dict(bcast_mod.LAST_RESTORE_BCAST)
             LAST_RESTORE_STATS["swarm"] = dict(swarm_mod.LAST_RESTORE_SWARM)
@@ -1729,6 +1751,7 @@ class Snapshot:
                 raise
             raise aborted from e
         finally:
+            restore_times.deactivate(times_token)
             _warn_consumed_targets()
             telemetry.fleet.note_op(None)
             pools.shutdown()
@@ -1751,6 +1774,113 @@ class Snapshot:
         coord: Optional[Coordinator] = None,
         digests: Optional[Dict[str, Any]] = None,
     ) -> Dict[str, float]:
+        """Restore one stateful, in three stretches that the restore's
+        interval sink keeps apart: the plan, the pipeline (broadcast, swarm,
+        the read graph with its consumers and inline finalizers, the
+        deferred finalizers) and the load."""
+        times = restore_times.get_active() or restore_times.RestoreTimes()
+        with times.work("plan", path=key):
+            plan = self._plan_stateful(
+                key, stateful, manifest, storage, memory_budget, event_loop,
+                include, bcast_enabled, swarm_enabled, coord, digests, times,
+            )
+        pipeline_t0 = time.monotonic()
+        from . import bcast as bcast_mod
+        from . import swarm as swarm_mod
+
+        if plan.bcast_items:
+            # Broadcast phase first (replicated entries land before the
+            # bulk pipeline): one elected rank per object reads storage,
+            # the bytes fan out over the coordinator store, every rank
+            # consumes + finalizes locally.
+            bcast_mod.run_broadcast(
+                plan.bcast_items,
+                storage,
+                coord,
+                event_loop,
+                executor=pools.consuming_executor() if pools else None,
+                digests=digests,
+            )
+
+        if plan.swarm_items:
+            # Swarm phase: chunk-granular fan-out for replicated objects
+            # above the broadcast cap — every rank origin-reads a distinct
+            # chunk subset and trades the rest peer-to-peer, each chunk
+            # verified against the sidecar grid on receipt. Reshard items
+            # ride the same exchange with per-chunk need sets: shared
+            # overlap ranges are fetched once fleet-wide, disjoint ones
+            # stay plain direct reads.
+            swarm_mod.run_swarm(
+                plan.swarm_items,
+                storage,
+                coord,
+                event_loop,
+                executor=pools.consuming_executor() if pools else None,
+                digests=digests,
+                need_maps=plan.swarm_need or None,
+            )
+
+        read_stats = sync_execute_read_reqs(
+            read_reqs=plan.read_reqs,
+            storage=storage,
+            memory_budget_bytes=memory_budget,
+            rank=get_coordinator(self._coordinator).get_rank(),
+            event_loop=event_loop,
+            pools=pools,
+            digests=digests,
+        )
+        # Overlap on: a successful pipeline consumed every read, so every
+        # countdown fired and finalized its entry inline; nothing remains.
+        assert not plan.finalizers, f"unfinalized entries: {sorted(plan.finalizers)}"
+        # Overlap off: the phase split — finalize everything post-pipeline.
+        for finalize in plan.deferred_finalizers:
+            finalize()
+        times.add_pipeline_window(pipeline_t0, time.monotonic())
+
+        with times.work("load", path=key):
+            prefix = f"{key}/"
+            loaded = plan.loaded
+            container_manifest = {
+                p: e
+                for p, e in manifest.items()
+                if (p == key or p.startswith(prefix)) and is_container_entry(e)
+            }
+            if not container_manifest and len(loaded) == 1 and key in loaded:
+                state_dict = loaded[key]
+            else:
+                full_manifest: Manifest = dict(container_manifest)
+                state_dict = inflate(full_manifest, loaded, prefix=key)
+            try:
+                stateful.load_state_dict(state_dict)
+            except Exception as e:
+                # The application's own load hook raised: a programming
+                # error in app state (shape drift, missing leaf), not a
+                # checkpoint fault. Mark it so restore() releases waiting
+                # peers but propagates the ORIGINAL exception type to the
+                # caller.
+                with contextlib.suppress(Exception):
+                    e._tss_app_hook_error = True  # type: ignore[attr-defined]
+                raise
+        return read_stats or {}
+
+    def _plan_stateful(
+        self,
+        key: str,
+        stateful: Stateful,
+        manifest: Manifest,
+        storage: StoragePlugin,
+        memory_budget: int,
+        event_loop: asyncio.AbstractEventLoop,
+        include: Optional[List[str]],
+        bcast_enabled: bool,
+        swarm_enabled: bool,
+        coord: Optional[Coordinator],
+        digests: Optional[Dict[str, Any]],
+        times: "restore_times.RestoreTimes",
+    ) -> "_StatefulPlan":
+        """Everything one stateful's restore does before its first byte
+        moves: flatten the live values, fetch frame tables, route and plan
+        every entry (host targets allocated here), batch the reads."""
         # Per-read cap = the whole process budget: a single object/shard
         # larger than the budget would otherwise be admitted whole through
         # the scheduler's one-over-budget escape hatch — the RSS spike the
@@ -1931,9 +2061,18 @@ class Snapshot:
                     # Nothing to read (e.g. no saved shard overlaps this
                     # process): finalize immediately.
                     finalize()
-                elif overlap:
-                    finalizers[idx] = finalize
-                    countdown = _ReadCountdown(idx, len(reqs), finalizers)
+                else:
+                    # The countdown notes when the entry's last read was
+                    # consumed — what its finalizer then waits from — and,
+                    # with overlap on, runs the finalizer at that moment.
+                    countdown = _ReadCountdown(
+                        idx, len(reqs), finalizers if overlap else None
+                    )
+                    finalize = countdown.waited(finalize, times)
+                    if overlap:
+                        finalizers[idx] = finalize
+                    else:
+                        deferred_finalizers.append(finalize)
                     reqs = [
                         ReadReq(
                             path=r.path,
@@ -1944,41 +2083,7 @@ class Snapshot:
                         )
                         for r in reqs
                     ]
-                else:
-                    deferred_finalizers.append(finalize)
             read_reqs.extend(reqs)
-
-        if bcast_items:
-            # Broadcast phase first (replicated entries land before the
-            # bulk pipeline): one elected rank per object reads storage,
-            # the bytes fan out over the coordinator store, every rank
-            # consumes + finalizes locally.
-            bcast_mod.run_broadcast(
-                bcast_items,
-                storage,
-                coord,
-                event_loop,
-                executor=pools.consuming_executor() if pools else None,
-                digests=digests,
-            )
-
-        if swarm_items:
-            # Swarm phase: chunk-granular fan-out for replicated objects
-            # above the broadcast cap — every rank origin-reads a distinct
-            # chunk subset and trades the rest peer-to-peer, each chunk
-            # verified against the sidecar grid on receipt. Reshard items
-            # ride the same exchange with per-chunk need sets: shared
-            # overlap ranges are fetched once fleet-wide, disjoint ones
-            # stay plain direct reads.
-            swarm_mod.run_swarm(
-                swarm_items,
-                storage,
-                coord,
-                event_loop,
-                executor=pools.consuming_executor() if pools else None,
-                digests=digests,
-                need_maps=swarm_need or None,
-            )
 
         if knobs.is_batching_enabled():
             from .batcher import batch_read_requests
@@ -1986,44 +2091,15 @@ class Snapshot:
             read_reqs = batch_read_requests(
                 read_reqs, max_merged_bytes=_memory_budget_bytes_per_read
             )
-
-        read_stats = sync_execute_read_reqs(
-            read_reqs=read_reqs,
-            storage=storage,
-            memory_budget_bytes=memory_budget,
-            rank=get_coordinator(self._coordinator).get_rank(),
-            event_loop=event_loop,
-            pools=pools,
-            digests=digests,
+        return _StatefulPlan(
+            loaded,
+            read_reqs,
+            finalizers,
+            deferred_finalizers,
+            bcast_items,
+            swarm_items,
+            swarm_need,
         )
-        # Overlap on: a successful pipeline consumed every read, so every
-        # countdown fired and finalized its entry inline; nothing remains.
-        assert not finalizers, f"unfinalized entries: {sorted(finalizers)}"
-        # Overlap off: the phase split — finalize everything post-pipeline.
-        for finalize in deferred_finalizers:
-            finalize()
-
-        container_manifest = {
-            p: e
-            for p, e in manifest.items()
-            if (p == key or p.startswith(prefix)) and is_container_entry(e)
-        }
-        if not container_manifest and len(loaded) == 1 and key in loaded:
-            state_dict = loaded[key]
-        else:
-            full_manifest: Manifest = dict(container_manifest)
-            state_dict = inflate(full_manifest, loaded, prefix=key)
-        try:
-            stateful.load_state_dict(state_dict)
-        except Exception as e:
-            # The application's own load hook raised: a programming error
-            # in app state (shape drift, missing leaf), not a checkpoint
-            # fault. Mark it so restore() releases waiting peers but
-            # propagates the ORIGINAL exception type to the caller.
-            with contextlib.suppress(Exception):
-                e._tss_app_hook_error = True  # type: ignore[attr-defined]
-            raise
-        return read_stats or {}
 
     # ----------------------------------------------------------- read_object
     def read_object(
@@ -3354,30 +3430,61 @@ class Snapshot:
 # Per-entry restore planning shared by restore() and read_object()
 # ---------------------------------------------------------------------------
 
-class _ReadCountdown:
-    """Per-entry outstanding-read counter; runs the entry's finalizer (from
-    the shared ``finalizers`` dict, popping it so its host buffers free
-    eagerly) when the last read has been consumed. Called on the event-loop
-    thread — which is the caller's (main) thread, where jax dispatch is
-    fast; the lock makes the countdown safe under any future
-    consumer-threading change."""
+class _StatefulPlan(NamedTuple):
+    """What ``Snapshot._plan_stateful`` hands the pipeline."""
 
-    __slots__ = ("idx", "remaining", "finalizers", "lock")
+    loaded: Dict[str, Any]  # logical path -> restored value, filled as entries land
+    read_reqs: List[ReadReq]
+    finalizers: Dict[int, Callable[[], None]]  # overlap on: run by the countdowns
+    deferred_finalizers: List[Callable[[], None]]  # overlap off: after the pipeline
+    bcast_items: List[Any]
+    swarm_items: List[Any]
+    swarm_need: Dict[str, List[frozenset]]
+
+
+class _ReadCountdown:
+    """Per-entry outstanding-read counter. When the entry's last read has
+    been consumed it keeps the moment (on the consuming thread's clock:
+    what the entry's finalizer then waits from) and, given the shared
+    ``finalizers`` dict (overlap on), runs the entry's finalizer, popping it
+    so its host buffers free eagerly. Called on the event-loop thread —
+    which is the caller's (main) thread, where jax dispatch is fast; the
+    lock makes the countdown safe under any future consumer-threading
+    change."""
+
+    __slots__ = ("idx", "remaining", "finalizers", "consumed_at", "lock")
 
     def __init__(
-        self, idx: int, n_reads: int, finalizers: Dict[int, Callable[[], None]]
+        self,
+        idx: int,
+        n_reads: int,
+        finalizers: Optional[Dict[int, Callable[[], None]]],
     ) -> None:
         self.idx = idx
         self.remaining = n_reads
         self.finalizers = finalizers
+        self.consumed_at = 0.0
         self.lock = threading.Lock()
 
-    def __call__(self) -> None:
+    def __call__(self, consumed_at: float) -> None:
         with self.lock:
             self.remaining -= 1
+            self.consumed_at = max(self.consumed_at, consumed_at)
             done = self.remaining == 0
-        if done:
+        if done and self.finalizers is not None:
             self.finalizers.pop(self.idx)()
+
+    def waited(
+        self, finalize: Callable[[], None], times: "restore_times.RestoreTimes"
+    ) -> Callable[[], None]:
+        """``finalize``, counting first how long the consumed entry waited
+        for it (``place_wait_s``)."""
+
+        def run() -> None:
+            times.add("place_wait_s", max(0.0, time.monotonic() - self.consumed_at))
+            finalize()
+
+        return run
 
 
 class _CountingConsumer:
@@ -3399,7 +3506,7 @@ class _CountingConsumer:
         self.inner = None
         # Back on the event-loop thread here: the countdown's finalize (jax
         # device_put / make_array_from_callback) runs main-thread.
-        self.countdown()
+        self.countdown(restore_times.consumed_at())
 
     def get_consuming_cost_bytes(self) -> int:
         inner = self.inner
@@ -3678,7 +3785,35 @@ _consumed_targets: List[str] = []
 _consumed_targets_lock = threading.Lock()
 
 
-def _place_over_target(logical_path: str, live: Any, place: Callable[[], Any]) -> Any:
+def _placing(
+    times: Optional["restore_times.RestoreTimes"],
+    logical_path: str,
+    sharding: Any,
+    shape: Any,
+    itemsize: int,
+):
+    """The ``place`` interval of one finalizer (a ``restore.place`` span),
+    counting the bytes it puts on this process's devices: one shard's bytes
+    times the addressable devices, replicas included, as the link moves
+    them."""
+    if times is None:
+        return contextlib.nullcontext()
+    shard_shape = sharding.shard_shape(tuple(int(d) for d in shape))
+    nbytes = (
+        int(np.prod(shard_shape, dtype=np.int64))
+        * itemsize
+        * len(sharding.addressable_devices)
+    )
+    times.add("place_bytes", nbytes)
+    return times.work("place", path=logical_path, nbytes=nbytes)
+
+
+def _place_over_target(
+    logical_path: str,
+    live: Any,
+    place: Callable[[], Any],
+    times: Optional["restore_times.RestoreTimes"] = None,
+) -> Any:
     """Put one restored leaf on device, where its live target already is.
 
     A restore overwrites its targets (the reference restores in place), but
@@ -3690,12 +3825,17 @@ def _place_over_target(logical_path: str, live: Any, place: Callable[[], Any]) -
     buffers and the leaf is placed again. Counted as
     ``restore.targets_consumed`` and named in the restore's closing warning;
     every other reference the caller holds to a consumed target (tied or
-    EMA parameters, a serving copy) is a deleted array afterwards."""
+    EMA parameters, a serving copy) is a deleted array afterwards. What the
+    failed first attempt cost goes to the restore's ``place_retry_s``."""
+    t0 = time.monotonic()
     try:
         return place()
     except Exception as e:  # noqa: BLE001 - only allocation failure degrades
         if not _is_oom_error(e) or live.is_deleted():
             raise
+    if times is not None:
+        times.add("place_retry_s", time.monotonic() - t0)
+        times.add("targets_consumed", 1)
     telemetry.counter_add("restore.targets_consumed")
     with _consumed_targets_lock:
         _consumed_targets.append(logical_path)
@@ -3742,6 +3882,9 @@ def _prepare_restore_one(  # spmd-pure
     """
     from .serialization import string_to_dtype
 
+    # The restore's interval sink, where one is active (a restore, not a
+    # read_object): finalizers stamp their ``place`` intervals into it.
+    times = restore_times.get_active()
     if isinstance(entry, PrimitiveEntry):
         loaded[logical_path] = entry.get_value()
         return [], None
@@ -3790,23 +3933,23 @@ def _prepare_restore_one(  # spmd-pure
 
                 sharding = live.sharding
                 if sharding.is_fully_addressable:
-                    loaded[logical_path] = _place_over_target(
-                        logical_path, live, lambda: jax.device_put(target, sharding)
-                    )
+                    place = lambda: jax.device_put(target, sharding)  # noqa: E731
                 else:
                     # device_put onto a multiprocess sharding runs a jitted
                     # consistency collective (refused outright on the
                     # multiprocess CPU backend); building the global array
                     # shard-by-shard needs no collective on any backend —
                     # every rank holds the full host target here.
+                    place = lambda: jax.make_array_from_callback(  # noqa: E731
+                        tuple(int(s) for s in entry.shape),
+                        sharding,
+                        lambda idx: target[idx],
+                    )
+                with _placing(
+                    times, logical_path, sharding, entry.shape, target.dtype.itemsize
+                ):
                     loaded[logical_path] = _place_over_target(
-                        logical_path,
-                        live,
-                        lambda: jax.make_array_from_callback(
-                            tuple(int(s) for s in entry.shape),
-                            sharding,
-                            lambda idx: target[idx],
-                        ),
+                        logical_path, live, place, times
                     )
 
             return reqs, finalize_jax
@@ -3828,11 +3971,15 @@ def _prepare_restore_one(  # spmd-pure
             )
 
             def finalize_sharded() -> None:
-                loaded[logical_path] = _place_over_target(
-                    logical_path,
-                    live,
-                    lambda: assemble_jax_array(sharding, entry.shape, buffers),
-                )
+                with _placing(
+                    times, logical_path, sharding, entry.shape, np_dtype.itemsize
+                ):
+                    loaded[logical_path] = _place_over_target(
+                        logical_path,
+                        live,
+                        lambda: assemble_jax_array(sharding, entry.shape, buffers),
+                        times,
+                    )
 
             return reqs, finalize_sharded
         # No live sharded target: materialize the full array on host.
@@ -3955,7 +4102,13 @@ class PendingSnapshot:
                 io_summary=pending_io_work.telemetry_io_summary(),
             )
             self._phase = "commit"
-            with _barrier_stall_guard(rank):
+            # The take's own session, not whichever is active by now: the
+            # next operation may have begun beside this drain. The artifact
+            # above is already written, so the span shows in
+            # ``Snapshot.last_telemetry`` and the chrome trace only.
+            with (self._tm or telemetry).span(
+                "take.commit", cat="take", bridge=True
+            ), _barrier_stall_guard(rank):
                 barrier.arrive()
                 if rank == 0:
                     Snapshot._write_snapshot_metadata(

@@ -316,7 +316,12 @@ class FSStoragePlugin(StoragePlugin):
                 want_digest = write_io.want_digest
 
                 def work() -> None:
-                    with self._get_direct_sem():
+                    # On the writing thread: what a profiler trace shows of
+                    # this write (the span around it lives across awaits).
+                    with self._get_direct_sem(), telemetry.span(
+                        "storage.write_work", "storage", True,
+                        path=write_io.path,
+                    ):
                         if want_digest:
                             write_io.digest_out = native.write_file_digest(
                                 lib,
@@ -416,15 +421,22 @@ class FSStoragePlugin(StoragePlugin):
             offset, end = read_io.byte_range
             nbytes = end - offset
             if self._use_native(nbytes):
-                read_io.buf.write(await self._native_read(path, offset, nbytes))
-                return
-            read_io.buf.write(await self._buffered_read(path, offset, nbytes))
+                data = await self._native_read(path, offset, nbytes)
+            else:
+                data = await self._buffered_read(path, offset, nbytes)
         elif self._native is not None:
             # Full-object read: the size probe (needed to route + allocate)
             # runs inside the executor task — never stat() on the event loop.
-            read_io.buf.write(await self._native_read(path, 0, None))
+            data = await self._native_read(path, 0, None)
         else:
-            read_io.buf.write(await self._buffered_read(path, 0, None))
+            data = await self._buffered_read(path, 0, None)
+        # The read's second pass over the bytes, on the event-loop thread
+        # and under the GIL: nothing else of the pipeline is admitted,
+        # reaped or finalized meanwhile.
+        with telemetry.span(
+            "storage.read_copy", "storage", True, path=read_io.path, nbytes=len(data)
+        ):
+            read_io.buf.write(data)
 
     async def _buffered_read(
         self, path: str, offset: int, nbytes: Optional[int]
@@ -442,7 +454,10 @@ class FSStoragePlugin(StoragePlugin):
         def work() -> bytearray:
             n = native.file_size(lib, path) - offset if nbytes is None else nbytes
             out = bytearray(n)
-            with self._get_direct_sem():
+            # On the reading thread, as ``storage.write_work`` on the writing.
+            with self._get_direct_sem(), telemetry.span(
+                "storage.read_work", "storage", True, path=path, nbytes=n
+            ):
                 native.read_into(
                     lib,
                     path,

@@ -64,6 +64,7 @@ def build_artifact(
     tm: Optional[Any] = None,
     phase_spans: Optional[Iterable[Any]] = None,
     io_summary: Optional[Dict[str, Any]] = None,
+    restore_stats: Optional[Dict[str, float]] = None,
 ) -> Dict[str, Any]:
     """Assemble one rank's artifact dict.
 
@@ -72,6 +73,9 @@ def build_artifact(
     :class:`~.core.PhaseTracker` spans (or any completed Span iterable) —
     they become wall-clock-stamped phase records. ``io_summary``: the write
     pipeline's summary (``scheduler.PendingIOWork.telemetry_io_summary``).
+    ``restore_stats``: a restore's split of its own time
+    (``restore_times.RestoreTimes.summary`` beside the read totals), as far
+    as the restore had come when the artifact was written.
     """
     from ..utils import knobs
     from ..version import __version__
@@ -139,6 +143,12 @@ def build_artifact(
                     eng.get("pause_intervals") or (), offset
                 ),
             }
+    if restore_stats is not None:
+        # Additive, schema v1: plan / fetch / verify / consume / place /
+        # load / idle seconds, with their waits, bytes and counts.
+        artifact["restore_stats_s"] = {
+            k: round(float(v), 6) for k, v in restore_stats.items()
+        }
     if tm is not None:
         artifact["metrics"] = tm.metrics.as_dict()
         artifact["spans_dropped"] = tm.buffer.dropped

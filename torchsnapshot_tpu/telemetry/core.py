@@ -21,6 +21,16 @@ Design constraints (why this file looks the way it does):
   drops NEW spans (keeping the coherent head of the trace) and counts them
   in ``dropped`` so exports are never silently partial.
 
+- **On the profiler's clock.** A span opened with ``bridge=True`` (and a
+  :class:`PhaseTracker` phase) also opens a
+  ``jax.profiler.TraceAnnotation("tss.<name>")`` on the calling thread, so
+  a running ``jax.profiler`` trace shows the library's work as host events
+  beside the device's operations. Only for synchronous work on ONE thread:
+  TraceMes nest per thread, so a span that lives across ``await``s would
+  nest wrongly with whatever else the loop thread runs meanwhile. jax is
+  looked up in ``sys.modules``, never imported; with no profiler session
+  running the annotation is an inactive TraceMe.
+
 No dependencies outside the stdlib: this module must be importable before
 jax/numpy and from every layer of the package without cycles.
 """
@@ -29,6 +39,7 @@ from __future__ import annotations
 
 import contextvars
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -43,6 +54,27 @@ _CURRENT_SPAN: contextvars.ContextVar[Optional[int]] = contextvars.ContextVar(
 )
 
 DEFAULT_CAPACITY = 100_000
+
+
+def current_span_id() -> Optional[int]:
+    """The span open in the calling context: what work handed to an
+    executor thread (which inherits no context) names as its parent."""
+    return _CURRENT_SPAN.get()
+
+# What every bridged span is named in a profiler trace: ``tss.<span name>``.
+ANNOTATION_PREFIX = "tss."
+
+
+def open_annotation(name: str) -> Optional[Any]:
+    """Enter ``jax.profiler.TraceAnnotation("tss.<name>")`` on this thread
+    and return it (the caller closes it with ``__exit__``); ``None`` while
+    jax is not imported."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -123,12 +155,14 @@ class _SpanCtx:
     """Context manager for one live span; re-entrant use is a bug (each
     ``Telemetry.span`` call makes a fresh one)."""
 
-    __slots__ = ("_tm", "span", "_token")
+    __slots__ = ("_tm", "span", "_token", "_bridge", "_ann")
 
-    def __init__(self, tm: "Telemetry", span: Span) -> None:
+    def __init__(self, tm: "Telemetry", span: Span, bridge: bool = False) -> None:
         self._tm = tm
         self.span = span
         self._token: Optional[contextvars.Token] = None
+        self._bridge = bridge
+        self._ann: Optional[Any] = None
 
     def set_attrs(self, **attrs: Any) -> None:
         self.span.set_attrs(**attrs)
@@ -136,9 +170,13 @@ class _SpanCtx:
     def __enter__(self) -> "_SpanCtx":
         self.span.ts = time.monotonic()
         self._token = _CURRENT_SPAN.set(self.span.span_id)
+        if self._bridge:
+            self._ann = open_annotation(self.span.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self.span.dur = time.monotonic() - self.span.ts
         if exc_type is not None:
             self.span.attrs["error"] = exc_type.__name__
@@ -193,7 +231,11 @@ class Telemetry:
             self._next_id += 1
             return sid
 
-    def span(self, name: str, cat: str = "", **attrs: Any) -> _SpanCtx:
+    def span(
+        self, name: str, cat: str = "", bridge: bool = False, **attrs: Any
+    ) -> _SpanCtx:
+        """``bridge``: also a ``tss.<name>`` event of a running profiler
+        trace — for synchronous work on one thread only (module docstring)."""
         sp = Span(
             name=name,
             cat=cat,
@@ -202,7 +244,7 @@ class Telemetry:
             parent_id=_CURRENT_SPAN.get(),
             attrs=attrs,
         )
-        return _SpanCtx(self, sp)
+        return _SpanCtx(self, sp, bridge)
 
     def add_span(
         self,
@@ -212,15 +254,18 @@ class Telemetry:
         dur: float,
         attrs: Optional[Dict[str, Any]] = None,
         tid: Optional[int] = None,
+        span_id: Optional[int] = None,
     ) -> Span:
         """Record an already-measured interval as a completed span (used by
         the scheduler, whose intervals are measured whether or not telemetry
-        is on — see ``scheduler.py``)."""
+        is on — see ``scheduler.py``). ``span_id``: an id reserved with
+        :meth:`reserve_id` before the interval began, so spans recorded
+        inside it could already name it as their parent."""
         sp = Span(
             name=name,
             cat=cat,
             ts=ts,
-            span_id=self._new_id(),
+            span_id=self._new_id() if span_id is None else span_id,
             parent_id=_CURRENT_SPAN.get(),
             attrs=dict(attrs) if attrs else {},
         )
@@ -229,6 +274,16 @@ class Telemetry:
             sp.tid = tid
         self.buffer.add(sp)
         return sp
+
+    def begin_deferred_span(self) -> int:
+        """Reserve a span id and make it the parent of whatever the calling
+        task (its context) records from here on; the span itself is
+        recorded when its interval is known, with ``add_span(span_id=...)``.
+        Nothing to reset: the engine calls this at the top of a node's own
+        task, whose context ends with it."""
+        sid = self._new_id()
+        _CURRENT_SPAN.set(sid)
+        return sid
 
     def spans(self, name: Optional[str] = None, cat: Optional[str] = None) -> List[Span]:
         """Completed spans, optionally filtered by exact name and/or cat."""
@@ -285,27 +340,41 @@ def deactivate(tm: Telemetry, prev: Optional[Telemetry] = None) -> None:
             _active = prev
 
 
-def span(name: str, cat: str = "", **attrs: Any):
+def span(name: str, cat: str = "", bridge: bool = False, **attrs: Any):
     """Record a span under the active session; free no-op when none is."""
     tm = _active
     if tm is None:
         return NOOP_SPAN
-    return tm.span(name, cat, **attrs)
+    return tm.span(name, cat, bridge, **attrs)
 
 
 class PhaseTracker:
     """Sequential phase boundaries as spans (replaces the hand-rolled
     ``phases[name] = now - t0`` stall-decomposition dicts): ``mark(name)``
     closes the phase that began at the previous mark. The durations dict the
-    old code produced is now a *view* over the recorded spans."""
+    old code produced is now a *view* over the recorded spans.
 
-    def __init__(self, cat: str = "take.phase") -> None:
+    A phase is named when it ends, a profiler annotation when it begins, so
+    the caller says which phase begins: ``first`` at construction, ``then``
+    at each mark. Phases are sequential on the one thread that marks them;
+    the last mark names no successor and leaves nothing open."""
+
+    def __init__(self, cat: str = "take.phase", first: Optional[str] = None) -> None:
         self.cat = cat
         self.spans: List[Span] = []
         self._last = time.monotonic()
         self._seq = 0
+        self._ann = self._open(first)
 
-    def mark(self, name: str, **attrs: Any) -> Span:
+    @staticmethod
+    def _open(phase: Optional[str]) -> Optional[Any]:
+        # Session off: no annotation either (one None-check, as everywhere).
+        return open_annotation(phase) if phase and _active is not None else None
+
+    def mark(self, name: str, then: Optional[str] = None, **attrs: Any) -> Span:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._ann = self._open(then)
         now = time.monotonic()
         self._seq += 1
         sp = Span(
