@@ -938,6 +938,38 @@ def test_corrupt_fault_is_deterministic(tmp_path) -> None:
     assert c != a  # different seed, different flips
 
 
+@pytest.mark.parametrize("byte_range", [None, (64, 192)], ids=["whole", "range"])
+def test_corrupt_fault_rots_a_private_copy_not_the_store(byte_range) -> None:
+    """A read holds its backend's object by reference, and the memory
+    plugin hands out the stored ``bytes`` itself: ``kind=corrupt`` must rot
+    what this one read delivers, never the stored object, so a later clean
+    read (and every other reader) sees the bytes as they were written."""
+    from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
+
+    payload = bytes(range(256))
+    want = payload if byte_range is None else payload[slice(*byte_range)]
+    store = MemoryStoragePlugin()
+    plugin = FaultyStoragePlugin(
+        store, parse_fault_spec("seed=7;op=read,kind=corrupt,bytes=3,at=0")
+    )
+
+    async def run():
+        await plugin.write(WriteIO(path="obj", buf=payload))
+        stored = store.objects["obj"]
+        rotten = ReadIO(path="obj", byte_range=byte_range)
+        await plugin.read(rotten)
+        clean = ReadIO(path="obj", byte_range=byte_range)
+        await plugin.read(clean)  # at=0: only the first read is corrupted
+        return stored, rotten, clean
+
+    stored, rotten, clean = _run(run())
+    assert rotten.buf.getvalue() != want, "seeded corrupt fault flipped nothing?"
+    assert len(rotten.buf.getvalue()) == len(want)
+    assert store.objects["obj"] is stored and stored == payload
+    assert clean.buf.getvalue() == want
+    assert rotten.buf.getbuffer().obj is not stored
+
+
 def test_ranged_read_retries_transient_oserror(tmp_path) -> None:
     """Satellite: ranged (partial-extent) reads ride the transient-OSError
     retry path end to end — both inside the fs plugin and at the

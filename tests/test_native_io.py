@@ -296,3 +296,59 @@ def test_write_at_direct_binding(lib, tmp_path) -> None:
     with open(path, "rb") as f:
         data = f.read()
     assert data == a.tobytes() + b.tobytes() + tail.tobytes()
+
+
+@pytest.mark.parametrize("byte_range", [None, (4096 + 7, 300_000)], ids=["whole", "range"])
+@pytest.mark.parametrize("route", ["native", "buffered"])
+def test_consumer_views_the_object_the_read_filled(tmp_path, route, byte_range) -> None:
+    """No copy lies between the storage read and the consumer: the view the
+    read pipeline hands to ``consume_buffer`` is backed by the very
+    ``bytearray`` the native read filled (or the ``bytes`` ``aiofiles``
+    returned): identity, not equality."""
+    import asyncio
+
+    from torchsnapshot_tpu.io_types import ReadReq
+    from torchsnapshot_tpu.scheduler import execute_read_reqs
+
+    if route == "native" and native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    data = os.urandom(1 << 20)
+    filled, seen = [], []
+
+    class Recording(FSStoragePlugin):
+        async def _native_read(self, path, offset, nbytes):
+            filled.append(await super()._native_read(path, offset, nbytes))
+            return filled[-1]
+
+        async def _buffered_read(self, path, offset, nbytes):
+            filled.append(await super()._buffered_read(path, offset, nbytes))
+            return filled[-1]
+
+    class Consumer:
+        def get_consuming_cost_bytes(self) -> int:
+            return len(data)
+
+        async def consume_buffer(self, buf, executor=None) -> None:
+            seen.append(memoryview(buf))
+
+    async def go() -> None:
+        plugin = Recording(str(tmp_path))
+        assert (plugin._native is not None) == (route == "native")
+        await plugin.write(WriteIO(path="obj", buf=data))
+        await execute_read_reqs(
+            [ReadReq(path="obj", buffer_consumer=Consumer(), byte_range=byte_range)],
+            plugin,
+            memory_budget_bytes=1 << 30,
+            rank=0,
+        )
+        await plugin.close()
+
+    with knobs.override_direct_io_threshold_bytes(1024), knobs.override_native_io_enabled(
+        route == "native"
+    ):
+        asyncio.run(go())
+    (obj,), (view,) = filled, seen
+    assert type(obj) is (bytearray if route == "native" else bytes)
+    assert view.obj is obj
+    begin, end = byte_range or (0, len(data))
+    assert view.nbytes == end - begin and view == data[begin:end]

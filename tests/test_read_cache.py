@@ -581,3 +581,47 @@ def test_bypass_vs_range_miss_metric_split(tmp_path):
     assert m.get("cache.bypass_reads", 0) == 1, m
     assert m.get("cache.range_misses", 0) == 1, m
     run(plugin.close())
+
+
+@pytest.mark.parametrize("byte_range", [None, (1 << 16, 3 << 16)], ids=["whole", "range"])
+def test_cache_entry_outlives_the_fetched_buffer(tmp_path, byte_range):
+    """A fetched buffer belongs to its read (the fs plugin's native read
+    hands its ``bytearray`` on by reference): the cache fills from a copy
+    of its own, so after the consumer has scribbled over and released the
+    buffer it was given, the entry still serves the bytes bit for bit, with
+    no second origin read."""
+    import os
+
+    from torchsnapshot_tpu import native
+    from torchsnapshot_tpu.storage_plugins.fs import FSStoragePlugin
+
+    if native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    data = os.urandom(1 << 18)
+    want = data if byte_range is None else data[slice(*byte_range)]
+    origin_reads = []
+
+    class CountingFS(FSStoragePlugin):
+        async def read(self, read_io: ReadIO) -> None:
+            origin_reads.append(read_io.path)
+            await super().read(read_io)
+
+    (tmp_path / "origin").mkdir()
+    with knobs.override_direct_io_threshold_bytes(1024):
+        inner = CountingFS(str(tmp_path / "origin"))
+        assert inner._native is not None
+        plugin, _ = make_cache(tmp_path / "cache", inner=inner)
+        seed(inner, "obj", data)
+        if byte_range is not None:
+            # A range is cacheable once the digest index knows the chunk grid.
+            plugin.attach_digest_index({"obj": _chunked_index(data, 1 << 16)})
+        first = ReadIO(path="obj", byte_range=byte_range)
+        run(plugin.read(first))
+        view = first.buf.getbuffer()
+        assert type(view.obj) is bytearray and view == want
+        view[:] = bytes(len(want))  # the consumer's buffer, to do with as it likes
+        view.release()
+        del first
+        assert read(plugin, "obj", byte_range) == want
+        assert origin_reads == ["obj"], "the second read went back to the origin"
+        run(plugin.close())

@@ -15,6 +15,7 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.io_types import ReadIO
@@ -204,3 +205,46 @@ def test_overlap_disabled_is_phase_split(tmp_path, monkeypatch) -> None:
         assert np.array_equal(
             np.asarray(tgt[f"w{i}"]), np.arange(64, dtype=np.float32) + i
         )
+
+
+class TwoWriteFSStoragePlugin(FSStoragePlugin):
+    """A plugin written against the reference's ``BytesIO`` contract: it
+    delivers every read in two ``write`` calls."""
+
+    async def read(self, read_io: ReadIO) -> None:
+        whole = ReadIO(path=read_io.path, byte_range=read_io.byte_range)
+        await super().read(whole)
+        data = whole.buf.getvalue()
+        read_io.buf.write(data[: len(data) // 2])
+        read_io.buf.write(data[len(data) // 2 :])
+
+
+@pytest.mark.parametrize("plugin_cls", [FSStoragePlugin, TwoWriteFSStoragePlugin])
+def test_restore_counts_the_bytes_copied_between_read_and_consumer(
+    tmp_path, monkeypatch, plugin_cls
+) -> None:
+    """``fetch_copied_bytes`` of ``LAST_RESTORE_STATS``: 0 through the fs
+    plugin (the buffer a read filled is the buffer the consumer reads); a
+    plugin that writes twice into one read still restores bit for bit, and
+    every byte of its reads is counted as copied."""
+    import jax.numpy as jnp
+
+    import torchsnapshot_tpu.storage_plugin as sp
+    from torchsnapshot_tpu import snapshot as snapshot_mod
+
+    state = {f"w{i}": jnp.arange(4096, dtype=jnp.float32) * (i + 1) for i in range(3)}
+    path = str(tmp_path / "ckpt")
+    Snapshot.take(path, {"s": StateDict(**state)})
+
+    monkeypatch.setattr(sp, "url_to_storage_plugin", lambda url: plugin_cls(url))
+    tgt = StateDict(**{k: jnp.zeros(4096, jnp.float32) for k in state})
+    Snapshot(path).restore({"s": tgt})
+
+    for k, v in state.items():
+        assert np.array_equal(
+            np.asarray(tgt[k]).view(np.uint8), np.asarray(v).view(np.uint8)
+        )
+    stats = snapshot_mod.LAST_RESTORE_STATS
+    assert stats["bytes_read"] >= 3 * 4096 * 4
+    copied = stats["bytes_read"] if plugin_cls is TwoWriteFSStoragePlugin else 0
+    assert stats["fetch_copied_bytes"] == copied

@@ -3,10 +3,12 @@
 ranged reads, delete, and parent-dir creation."""
 
 import asyncio
+import errno
+import io
 
 import pytest
 
-from torchsnapshot_tpu.io_types import ReadIO, WriteIO
+from torchsnapshot_tpu.io_types import ReadBuffer, ReadIO, WriteIO
 from torchsnapshot_tpu.storage_plugin import url_to_storage_plugin
 from torchsnapshot_tpu.storage_plugins.fs import FSStoragePlugin
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
@@ -116,4 +118,125 @@ def test_memoryview_payload_accepted(tmp_path) -> None:
         return rio.buf.getvalue()
 
     assert _run(go()) == bytes(payload)
+    _run(plugin.close())
+
+
+# ---------------------------------------------------------------------------
+# ReadIO.buf: the read's bytes held by reference (io_types.ReadBuffer)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("kind", [bytes, bytearray, memoryview])
+def test_read_buffer_keeps_the_first_write_by_reference(kind) -> None:
+    data = kind(b"0123456789")
+    buf = ReadBuffer()
+    assert buf.write(data) == 10
+    backing = data.obj if kind is memoryview else data
+    assert buf.getbuffer().obj is backing
+    assert buf.getbuffer().format == "B" and buf.getbuffer().nbytes == 10
+    assert buf.getvalue() == b"0123456789"
+    # Only ``bytes`` can be handed on as ``bytes`` without a copy.
+    assert (buf.getvalue() is data) == (kind is bytes)
+    assert buf.copied_bytes == 0
+
+
+def test_read_buffer_second_write_joins_and_counts_the_copy() -> None:
+    """The reference's ``BytesIO`` contract for a plugin that delivers one
+    read in pieces: the concatenation, through a counted copy that leaves
+    the plugin's own objects alone."""
+    first, second = bytearray(b"abc"), b"defg"
+    buf = ReadBuffer()
+    buf.write(first)
+    buf.write(b"")  # nothing delivered, nothing joined
+    assert buf.getbuffer().obj is first and buf.copied_bytes == 0
+    buf.write(second)
+    assert buf.getvalue() == b"abcdefg" and buf.copied_bytes == 7
+    buf.write(memoryview(b"hi"))
+    assert buf.getvalue() == b"abcdefghi" and buf.copied_bytes == 9
+    assert first == b"abc", "joined into the plugin's own buffer"
+
+
+def test_read_buffer_reset_drops_the_failed_attempts_bytes() -> None:
+    buf = ReadBuffer()
+    buf.write(b"torn")
+    buf.seek(0)
+    buf.truncate(0)
+    assert buf.getvalue() == b"" and buf.getbuffer().nbytes == 0
+    whole = b"whole"
+    buf.write(whole)
+    assert buf.getvalue() is whole and buf.copied_bytes == 0
+    # What the read path never asks of it is refused, not half-emulated.
+    with pytest.raises(io.UnsupportedOperation):
+        buf.seek(2)
+    with pytest.raises(io.UnsupportedOperation):
+        buf.truncate(3)
+
+
+class _TornOnce:
+    """First attempt of a read: part of the bytes delivered, then a
+    transient error, as a read torn by a stale handle."""
+
+    def __init__(self) -> None:
+        self.failures = 0
+
+    def tear(self, read_io: ReadIO) -> None:
+        if self.failures == 0:
+            self.failures += 1
+            read_io.buf.write(b"\xff" * 100)
+            raise OSError(errno.ESTALE, "stale handle mid-read")
+
+
+def _read_through_pipeline(plugin, path: str, nbytes: int) -> bytes:
+    from torchsnapshot_tpu.io_types import ReadReq
+    from torchsnapshot_tpu.scheduler import execute_read_reqs
+
+    got = []
+
+    class Consumer:
+        def get_consuming_cost_bytes(self) -> int:
+            return nbytes
+
+        async def consume_buffer(self, buf, executor=None) -> None:
+            got.append(bytes(buf))
+
+    _run(
+        execute_read_reqs(
+            [ReadReq(path=path, buffer_consumer=Consumer())],
+            plugin,
+            memory_budget_bytes=1 << 20,
+            rank=0,
+        )
+    )
+    (data,) = got
+    return data
+
+
+@pytest.mark.parametrize("layer", ["fs_plugin", "read_pipeline"])
+def test_retried_read_delivers_only_the_second_attempt(tmp_path, monkeypatch, layer) -> None:
+    """Both retry layers (the fs plugin's own, and the read pipeline's for
+    any plugin) start the second attempt from an empty buffer."""
+    from torchsnapshot_tpu.storage_plugins import cloud_retry
+
+    monkeypatch.setattr(cloud_retry, "BASE_BACKOFF_S", 0.001)
+    payload = bytes(range(256)) * 8
+    torn = _TornOnce()
+
+    if layer == "fs_plugin":
+
+        class Flaky(FSStoragePlugin):
+            async def _read_inner(self, read_io: ReadIO) -> None:
+                torn.tear(read_io)
+                await super()._read_inner(read_io)
+
+    else:
+
+        class Flaky(FSStoragePlugin):
+            async def read(self, read_io: ReadIO) -> None:
+                torn.tear(read_io)
+                await super().read(read_io)
+
+    plugin = Flaky(root=str(tmp_path))
+    _run(plugin.write(WriteIO(path="obj", buf=payload)))
+    assert _read_through_pipeline(plugin, "obj", len(payload)) == payload
+    assert torn.failures == 1, "the transient fault never fired"
     _run(plugin.close())

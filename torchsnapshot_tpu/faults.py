@@ -532,43 +532,45 @@ class FaultyStoragePlugin(StoragePlugin):
         chunk-targeted. The read still SUCCEEDS — silent bit rot, which
         only digest verification can catch (and, for chunk-targeted rot,
         must attribute to exactly that chunk)."""
-        buf = read_io.buf.getbuffer()
-        try:
-            if buf.nbytes == 0:
-                return
-            lo, hi = 0, buf.nbytes
-            if rule.chunk is not None:
-                from .utils import knobs
+        nbytes = read_io.buf.getbuffer().nbytes
+        if nbytes == 0:
+            return
+        lo, hi = 0, nbytes
+        if rule.chunk is not None:
+            from .utils import knobs
 
-                grain = knobs.get_hash_chunk_bytes()
-                if grain <= 0:
-                    logger.warning(
-                        "FAULT corrupt chunk=%d ignored: hash chunking is "
-                        "disabled (grain 0)",
-                        rule.chunk,
-                    )
-                    return
-                # Chunk extents are object coordinates; a ranged read's
-                # buffer starts at byte_range[0] of the object.
-                base = read_io.byte_range[0] if read_io.byte_range else 0
-                lo = max(0, rule.chunk * grain - base)
-                hi = min(buf.nbytes, (rule.chunk + 1) * grain - base)
-                if hi <= lo:
-                    logger.warning(
-                        "FAULT corrupt chunk=%d skipped: read %s%s does not "
-                        "cover the chunk's extent",
-                        rule.chunk,
-                        read_io.path,
-                        f" range {read_io.byte_range}"
-                        if read_io.byte_range
-                        else "",
-                    )
-                    return
-            flips = max(1, rule.bytes)
-            for _ in range(flips):
-                buf[lo + self._rng.randrange(hi - lo)] ^= 0xFF
-        finally:
-            buf.release()
+            grain = knobs.get_hash_chunk_bytes()
+            if grain <= 0:
+                logger.warning(
+                    "FAULT corrupt chunk=%d ignored: hash chunking is "
+                    "disabled (grain 0)",
+                    rule.chunk,
+                )
+                return
+            # Chunk extents are object coordinates; a ranged read's
+            # buffer starts at byte_range[0] of the object.
+            base = read_io.byte_range[0] if read_io.byte_range else 0
+            lo = max(0, rule.chunk * grain - base)
+            hi = min(nbytes, (rule.chunk + 1) * grain - base)
+            if hi <= lo:
+                logger.warning(
+                    "FAULT corrupt chunk=%d skipped: read %s%s does not "
+                    "cover the chunk's extent",
+                    rule.chunk,
+                    read_io.path,
+                    f" range {read_io.byte_range}"
+                    if read_io.byte_range
+                    else "",
+                )
+                return
+        # The read holds its backend's object by reference, which may be
+        # the store's own or a cache's: rot a private copy, hand that on.
+        rotten = bytearray(read_io.buf.getbuffer())
+        for _ in range(max(1, rule.bytes)):
+            rotten[lo + self._rng.randrange(hi - lo)] ^= 0xFF
+        read_io.buf.seek(0)
+        read_io.buf.truncate(0)
+        read_io.buf.write(rotten)
         logger.warning(
             "FAULT corrupt %d byte(s) on read %s%s",
             max(1, rule.bytes),

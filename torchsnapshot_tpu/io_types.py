@@ -129,11 +129,79 @@ class WriteIO:
     digest_out: Optional[list] = None
 
 
+class ReadBuffer:
+    """The bytes of one storage read, held by reference.
+
+    What a plugin's ``read`` hands to ``write`` IS what ``getbuffer`` views:
+    the ``bytearray`` a native read filled, the ``bytes`` a client library
+    returned. No pass over the bytes lies between the backend's delivery and
+    the consumer. The object must belong to the read or be immutable: a
+    ``bytes`` a plugin also keeps (the memory plugin's store, a cache's
+    shared fetch) is fine, a ``bytearray`` it goes on writing to is not.
+
+    It answers the part of the reference's ``io.BytesIO`` contract the read
+    path uses: ``write``, ``seek(0)`` + ``truncate(0)`` (the reset before a
+    retried attempt, which drops the failed attempt's bytes), ``getbuffer``
+    and ``getvalue``. A plugin written against ``BytesIO`` that delivers one
+    read in several writes still gets their concatenation; that join is a
+    copy, and ``copied_bytes`` counts it (the restore's
+    ``fetch_copied_bytes``).
+    """
+
+    __slots__ = ("_data", "_joined", "copied_bytes")
+
+    def __init__(self) -> None:
+        # bytes, bytearray or a flat byte view: ``len`` is its size.
+        self._data: BufferType = b""
+        self._joined = False  # _data is a bytearray this holder made
+        self.copied_bytes = 0
+
+    def write(self, data: BufferType) -> int:
+        view = memoryview(data)
+        nbytes = view.nbytes
+        if not nbytes:
+            return 0
+        if not len(self._data):
+            # Kept as it came; any other exporter as its flat byte view.
+            self._data = (
+                data if isinstance(data, (bytes, bytearray)) else view.cast("B")
+            )
+            return nbytes
+        if not self._joined:
+            self.copied_bytes += len(self._data)
+            self._data = bytearray(self._data)
+            self._joined = True
+        self._data += view
+        self.copied_bytes += nbytes
+        return nbytes
+
+    def seek(self, pos: int) -> int:
+        if pos != 0:
+            raise io.UnsupportedOperation("a ReadBuffer seeks to 0 only")
+        return 0
+
+    def truncate(self, size: int) -> int:
+        if size != 0:
+            raise io.UnsupportedOperation("a ReadBuffer truncates to 0 only")
+        self._data = b""
+        self._joined = False
+        return 0
+
+    def getbuffer(self) -> memoryview:
+        """A view of the held object itself (read-only over ``bytes``)."""
+        return memoryview(self._data)
+
+    def getvalue(self) -> bytes:
+        """The bytes as ``bytes``: the held object where it is one, else a
+        copy (its callers read metadata, sidecars and frame tables)."""
+        return bytes(self._data)
+
+
 @dataclass
 class ReadIO:
     path: str
     byte_range: Optional[Tuple[int, int]] = None
-    buf: io.BytesIO = field(default_factory=io.BytesIO)
+    buf: ReadBuffer = field(default_factory=ReadBuffer)
 
 
 class StorageWriteStream(abc.ABC):

@@ -68,6 +68,7 @@ _SPANS: Dict[str, Tuple[str, str, bool]] = {
 _PIPELINE_KINDS = ("fetch", "verify", "consume", "place")
 _SUMS = (
     "fetch_wait_s",
+    "fetch_copied_bytes",
     "consume_wait_s",
     "place_wait_s",
     "place_retry_s",
@@ -145,14 +146,25 @@ class RestoreTimes:
         with self._lock:
             self._intervals[kind].append((t0, t1))
 
-    def record_fetch(self, t0: float, path: str, nbytes: int, admitted_at: float) -> None:
+    def record_fetch(
+        self,
+        t0: float,
+        path: str,
+        nbytes: int,
+        admitted_at: float,
+        copied_bytes: int,
+    ) -> None:
         """One storage read that began at ``t0`` and ends now, measured
         around its ``await`` (the read itself runs in the plugin); it had
-        waited for its turn since ``admitted_at``."""
+        waited for its turn since ``admitted_at``. ``copied_bytes``: what
+        of it was copied in Python between the backend's delivery and the
+        buffer the consumer gets (``io_types.ReadBuffer``'s join of a read
+        delivered in several writes)."""
         t1 = time.monotonic()
         with self._lock:
             self._intervals["fetch"].append((t0, t1))
             self._sums["fetch_wait_s"] += max(0.0, t0 - admitted_at)
+            self._sums["fetch_copied_bytes"] += copied_bytes
         if self.tm is not None:
             name, cat, _ = _SPANS["fetch"]
             self.tm.add_span(name, cat, t0, t1 - t0, {"path": path, "nbytes": nbytes})

@@ -1381,7 +1381,11 @@ async def execute_read_reqs(
             )
             if times is not None:
                 times.record_fetch(
-                    t0, req.path, read_io.buf.getbuffer().nbytes, ctx.admitted_at
+                    t0,
+                    req.path,
+                    read_io.buf.getbuffer().nbytes,
+                    ctx.admitted_at,
+                    read_io.buf.copied_bytes,
                 )
             return read_io
 

@@ -430,13 +430,9 @@ class FSStoragePlugin(StoragePlugin):
             data = await self._native_read(path, 0, None)
         else:
             data = await self._buffered_read(path, 0, None)
-        # The read's second pass over the bytes, on the event-loop thread
-        # and under the GIL: nothing else of the pipeline is admitted,
-        # reaped or finalized meanwhile.
-        with telemetry.span(
-            "storage.read_copy", "storage", True, path=read_io.path, nbytes=len(data)
-        ):
-            read_io.buf.write(data)
+        # Handed over, not copied: the object the read filled is the one
+        # the consumer views (``io_types.ReadBuffer``).
+        read_io.buf.write(data)
 
     async def _buffered_read(
         self, path: str, offset: int, nbytes: Optional[int]
