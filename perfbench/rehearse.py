@@ -21,7 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from perfbench import model  # noqa: E402
+from perfbench import run, trainstate  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 # memory_stats()["bytes_limit"] of one v5e chip (my chip run, PR 21).
@@ -30,7 +30,8 @@ BYTES_LIMIT = 16_909_000_000
 
 def rehearse(cfg: dict, topo, micro_batch: int, saved: str) -> dict:
     cfg = dict(cfg, job=dict(cfg["job"], micro_batch=micro_batch))
-    job = model.Job(cfg, list(topo.devices))
+    arch = run.find_architecture(os.path.dirname(HERE), cfg["model_type"])
+    job = trainstate.Job(arch, cfg, list(topo.devices))
     state, tokens = job.abstract_args()
     step = job.train_step.lower(state, tokens).compile()
     mem = step.memory_analysis()
@@ -57,8 +58,8 @@ def rehearse(cfg: dict, topo, micro_batch: int, saved: str) -> dict:
         "micro_batch": micro_batch,
         "saved": saved,
         "chips": chips,
-        "state_bytes_all_chips": model.tree_nbytes(job.abstract),
-        "saved_bytes_all_chips": model.tree_nbytes(saved_tree),
+        "state_bytes_all_chips": trainstate.tree_nbytes(job.abstract),
+        "saved_bytes_all_chips": trainstate.tree_nbytes(saved_tree),
         "step_argument_bytes": mem.argument_size_in_bytes,
         "step_temp_bytes": mem.temp_size_in_bytes,
         "step_output_bytes": mem.output_size_in_bytes,

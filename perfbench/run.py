@@ -2,8 +2,9 @@
 --seed N --seconds S --trace 0|1``. See ``perfbench/README.md``.
 
 Everything a cell is made of is found by name: ``configs/<config>.json``,
-``traffic/<traffic>.json``, ``metrics/<metric>.json``. The last line of
-standard output is the result; every earlier line is commentary.
+``traffic/<traffic>.json``, ``metrics/<metric>.json``, and the architecture
+``models/<model_type>.py`` by the configuration's own ``model_type``. The
+last line of standard output is the result; every earlier line is commentary.
 """
 
 import time
@@ -26,10 +27,6 @@ REPO_ROOT = os.path.dirname(HERE)
 sys.path.insert(0, REPO_ROOT)
 
 from perfbench import cycles, readers, target as target_mod  # noqa: E402
-
-TINY = {  # --platform cpu --tiny: toy widths, a dry run that reports no time
-    "hidden_size": 64, "intermediate_size": 256, "num_attention_heads": 4, "vocab_size": 512,
-}
 
 
 def log(msg: str) -> None:
@@ -63,6 +60,14 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
+def load_module(name: str, code: str):
+    """The Python file ``code`` as a module of its own, by path."""
+    module_spec = importlib.util.spec_from_file_location(name, code)
+    module = importlib.util.module_from_spec(module_spec)
+    module_spec.loader.exec_module(module)
+    return module
+
+
 def find_cell(root: str, workload: str) -> dict:
     """The cell and all it names, from ``BENCHMARK.json`` under ``root``
     and the data files under ``root/perfbench``."""
@@ -81,10 +86,7 @@ def find_cell(root: str, workload: str) -> dict:
         spec = load_json(base, "metrics", entry["name"] + ".json")
         code = os.path.join(base, "metrics", entry["name"] + ".py")
         if os.path.exists(code):
-            module_spec = importlib.util.spec_from_file_location("pb_metric_" + entry["name"], code)
-            module = importlib.util.module_from_spec(module_spec)
-            module_spec.loader.exec_module(module)
-            spec["read"] = module.read
+            spec["read"] = load_module("pb_metric_" + entry["name"], code).read
         return dict(entry, reader=spec["reader"], read=spec.get("read"))
 
     traffic = load_json(base, "traffic", cell["traffic"] + ".json")
@@ -98,6 +100,19 @@ def find_cell(root: str, workload: str) -> dict:
         # files by name, whether or not BENCHMARK.json lists them for this cell.
         "summary": [metric({"name": n, "unit": ""}) for n in traffic.get("summary_metrics", [])],
     }
+
+
+def find_architecture(root: str, model_type: str):
+    """The module ``root/perfbench/models/<model_type>.py``: what the harness
+    knows of a configuration's architecture (``perfbench/README.md``). It
+    imports jax, so a run loads it once preflight has set the platform."""
+    models = os.path.join(root, "perfbench", "models")
+    code = os.path.join(models, model_type + ".py")
+    if not os.path.exists(code):
+        names = os.listdir(models) if os.path.isdir(models) else []
+        there = sorted(n[:-3] for n in names if n.endswith(".py"))
+        raise Refused(f"perfbench: no architecture {model_type!r} in perfbench/models ({there})")
+    return load_module("pb_model_" + model_type, code)
 
 
 def read_metrics(metrics: list, facts: dict) -> dict:
@@ -197,18 +212,21 @@ class Run:
     def __init__(self, args, found: dict, ctx: dict) -> None:
         import jax
 
-        from perfbench import model, reference
+        from perfbench import reference, trainstate
 
-        self.jax, self.model, self.reference = jax, model, reference
+        self.jax, self.trainstate, self.reference = jax, trainstate, reference
         self.args, self.ctx = args, ctx
         self.traffic = found["traffic"]
         cfg = dict(found["config"])
-        if args.tiny:
-            cfg.update(TINY, job=dict(cfg["job"], seq_len=32))
+        arch = find_architecture(REPO_ROOT, cfg["model_type"])
+        if args.tiny:  # toy widths, a dry run that reports no time
+            cfg.update(arch.TINY, job=dict(cfg["job"], seq_len=32))
         self.cfg = cfg
-        self.job = model.Job(cfg, ctx["devices"])
+        self.job = trainstate.Job(arch, cfg, ctx["devices"])
         wants_other = self.traffic.get("restore_layout") == "transposed"
-        self.restore_job = model.Job(cfg, ctx["devices"], transposed=True) if wants_other else self.job
+        self.restore_job = (
+            trainstate.Job(arch, cfg, ctx["devices"], transposed=True) if wants_other else self.job
+        )
         self.saved_key = self.traffic["saved"]  # "params" or "state"
         self.annotate = (
             jax.profiler.TraceAnnotation if args.trace else (lambda name: contextlib.nullcontext())
@@ -238,7 +256,7 @@ class Run:
     def warm_targets(self) -> None:
         """Load the program that makes a restore's zero targets, so the
         window's first restore does not."""
-        self.model.free_tree(self.jax.block_until_ready(self.restore_job.zero_targets(self.saved_key)))
+        self.trainstate.free_tree(self.jax.block_until_ready(self.restore_job.zero_targets(self.saved_key)))
 
     # -- operations --------------------------------------------------------
 
@@ -273,7 +291,7 @@ class Run:
         self.attempted += 1
         app_state, _ = self.app_state(self.saved_of(state))
         committed = threading.Event()
-        rec = {"path": path, "bytes": self.model.tree_nbytes(self.saved_of(state)), "error": None}
+        rec = {"path": path, "bytes": self.trainstate.tree_nbytes(self.saved_of(state)), "error": None}
 
         def waiter(pending, t_call):
             try:
@@ -349,7 +367,7 @@ class Run:
             targets = self.jax.block_until_ready(self.restore_job.zero_targets(self.saved_key))
             app_state, box = self.app_state(targets)
             target_mod.drop_page_cache(target_mod.snapshot_files(path))
-        rec = {"bytes": self.model.tree_nbytes(targets), "error": None}
+        rec = {"bytes": self.trainstate.tree_nbytes(targets), "error": None}
         t0 = time.perf_counter()
         try:
             with self.annotate("pb.restore"):
@@ -406,7 +424,7 @@ class Run:
             path, want = self.snapshot_path, self.fixed_reference
         if "restore" in ops:
             if self.last_restored is not None:
-                self.model.free_tree(self.last_restored)
+                self.trainstate.free_tree(self.last_restored)
             self.last_restored, rec["restore"] = self.restore(path, want)
         rec["wall_s"] = time.perf_counter() - t0
         self.records.append(rec)
@@ -416,7 +434,7 @@ class Run:
 
     def setup(self):
         jax, traffic = self.jax, self.traffic
-        saved_bytes = self.model.tree_nbytes(self.saved_of(self.job.abstract))
+        saved_bytes = self.trainstate.tree_nbytes(self.saved_of(self.job.abstract))
         try:
             self.target = target_mod.resolve_target(
                 2 * saved_bytes + (1 << 30), allow_ram=not self.ctx["measured"]
@@ -437,8 +455,8 @@ class Run:
         self.batches = self.job.make_batches(self.args.seed, traffic["batches"])
         jax.block_until_ready((state, self.batches))
         lap("weights and batches from the seed")
-        log(f"[setup] {self.cfg['name']}: {self.model.tree_nbytes(state['params']) // 2 / 1e9:.3f} B "
-            f"parameters, state {self.model.tree_nbytes(state) / 1e9:.3f} GB, saved part "
+        log(f"[setup] {self.cfg['name']}: {self.trainstate.tree_size(state['params']) / 1e9:.3f} B "
+            f"parameters, state {self.trainstate.tree_nbytes(state) / 1e9:.3f} GB, saved part "
             f"{saved_bytes / 1e9:.3f} GB, batch {self.job.batch_shape}")
         for _ in range(traffic["warmup_steps"]):
             state, loss, _ = self.step(state)
@@ -486,7 +504,7 @@ class Run:
         for _ in range(traffic["loss_steps"]):
             state, loss, _ = self.step(state)
             self.losses_uninterrupted.append(loss)
-        self.model.free_tree(state)
+        self.trainstate.free_tree(state)
         self.warm_targets()
         return None
 
@@ -519,7 +537,7 @@ class Run:
         the gap of the losses after it (restore-only mixes)."""
         if self.traffic["round"] == ["save"]:
             # The state has done its work, and the targets need its room.
-            self.model.free_tree(state)
+            self.trainstate.free_tree(state)
             self.snapshot_path = self.keep
             self.round(None, len(self.records), ops=["restore"])
             return None

@@ -4,8 +4,13 @@ the data files it names."""
 import json
 import os
 import re
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from perfbench import run  # noqa: E402
+
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
@@ -87,10 +92,13 @@ def test_every_name_has_its_data_file_and_the_files_agree_with_the_entries():
         cfg = load(c["file"])
         assert cfg["name"] == c["name"] and set(c["reduced"]) == set(cfg["reduced"])
         assert cfg["guarantees"] and cfg["assumed"]["checkpoint_target_fstype"]
-        # No width is changed: the published Pythia-6.9B sizes.
-        assert (cfg["hidden_size"], cfg["intermediate_size"], cfg["num_attention_heads"]) == (4096, 16384, 32)
-        assert (cfg["vocab_size"], cfg["max_position_embeddings"], cfg["rotary_pct"]) == (50432, 2048, 0.25)
-        assert cfg["job"]["seq_len"] == 2048
+        # No width is changed: every key its architecture publishes is the
+        # source's, but the keys ``reduced`` lists, and those do differ.
+        published = run.find_architecture(ROOT, cfg["model_type"]).PUBLISHED
+        assert published and set(c["reduced"]) <= set(published)
+        for key, value in published.items():
+            assert (cfg[key] == value) == (key not in c["reduced"]), (c["name"], key)
+        assert all(cfg["job"][key] > 0 for key in ("seq_len", "micro_batch", "learning_rate"))
     for w in bench["workloads"]:
         assert load("perfbench", "traffic", w["traffic"] + ".json")["name"] == w["traffic"]
         cfg = next(c for c in bench["configs"] if c["name"] == w["config"])
