@@ -289,6 +289,9 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
         "stage_d2h_s",
         "stage_serialize_s",
         "stage_hash_s",
+        # ... and of two of them the seconds on objects under 1 MiB.
+        "stage_d2h_small_s",
+        "io_busy_small_s",
     } == set(snapshot_mod.LAST_SYNC_DRAIN_STATS)
 
     # Scheduler stage/io spans.
@@ -330,6 +333,23 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
         "scheduler.read_io",
         "storage.read",
     } <= rnames
+
+
+def test_small_object_seconds_skip_records_without_a_size() -> None:
+    """``stage_d2h_small_s`` / ``io_busy_small_s`` are reduced from the
+    streams' own ``(t0, t1, nbytes)`` records: one without a size is no
+    small object, and the union reads only the ends."""
+    from torchsnapshot_tpu.engine.intervals import (
+        measure,
+        merge_intervals,
+        smaller_than,
+    )
+    from torchsnapshot_tpu.io_types import SMALL_OBJECT_BYTES
+
+    records = [(0.0, 1.0, 128), (0.5, 2.0, 0), (3.0, 4.0, SMALL_OBJECT_BYTES), (5.0, 6.0, 4096)]
+    assert smaller_than(records, SMALL_OBJECT_BYTES) == [records[0], records[3]]
+    assert merge_intervals(records) == [(0.0, 2.0), (3.0, 4.0), (5.0, 6.0)]
+    assert measure(merge_intervals(smaller_than(records, SMALL_OBJECT_BYTES))) == 2.0
 
 
 def test_e2e_async_take_trace_written_on_commit(tmp_path) -> None:

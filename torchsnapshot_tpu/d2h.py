@@ -75,7 +75,7 @@ class StageTimes:
         # ``tm``: the op's telemetry.Telemetry session (or None when off).
         self._tm = tm
         self._lock = threading.Lock()
-        self._intervals: Dict[str, List[Tuple[float, float]]] = {
+        self._intervals: Dict[str, List[Tuple[float, float, int]]] = {
             k: [] for k in self.KINDS
         }
 
@@ -96,7 +96,7 @@ class StageTimes:
         # joins ``kind``'s sub-stream — parallel chunk hashes export as
         # ``stage.hash_chunk`` spans but stay inside ``stage_hash_s``.
         with self._lock:
-            self._intervals[kind].append((t0, t1))
+            self._intervals[kind].append((t0, t1, nbytes))
         tm = self._tm
         if tm is not None:
             tm.add_span(
@@ -111,8 +111,9 @@ class StageTimes:
                 if device is not None:
                     tm.metrics.counter(f"d2h.device_bytes.{device}").add(nbytes)
 
-    def intervals(self) -> Dict[str, List[Tuple[float, float]]]:
-        """A snapshot copy per kind (safe to merge/clip while staging runs)."""
+    def intervals(self) -> Dict[str, List[Tuple[float, float, int]]]:
+        """A snapshot copy per kind (safe to merge/clip while staging runs);
+        each interval as ``(t0, t1, nbytes)``, 0 where no size was given."""
         with self._lock:
             return {k: list(v) for k, v in self._intervals.items()}
 

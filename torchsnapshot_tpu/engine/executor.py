@@ -235,7 +235,8 @@ class GraphExecutor:
         self._pool_order: List[str] = []
         self.windows: List[Interval] = []
         self.stage_intervals: List[Interval] = []
-        self.io_intervals: List[Interval] = []
+        # (t0, t1, nbytes); nbytes 0 where the record carries no size.
+        self.io_intervals: List[Tuple[float, float, int]] = []
         self._tm = telemetry.get_active()
         self.reporter = ProgressReporter(rank, kind)
         self._progress = progress
@@ -559,7 +560,7 @@ class GraphExecutor:
         if stream == "auto":
             stream = "io" if kind == "io" else "stage"
         if stream == "io":
-            self.io_intervals.append((t0, t1))
+            self.io_intervals.append((t0, t1, nbytes))
         elif stream == "stage":
             self.stage_intervals.append((t0, t1))
         tm = self._tm

@@ -10,21 +10,31 @@ paths were lowered onto the engine.
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
 
-def merge_intervals(intervals: List[Interval]) -> List[Interval]:
-    """Sorted union of possibly-overlapping intervals."""
+def merge_intervals(intervals: Sequence[Tuple[float, ...]]) -> List[Interval]:
+    """Sorted union of possibly-overlapping intervals. A record may carry
+    more than its two ends (``(t0, t1, nbytes)``): only the ends are read."""
     out: List[Interval] = []
-    for t0, t1 in sorted(i for i in intervals if i[1] > i[0]):
+    for t0, t1 in sorted((i[0], i[1]) for i in intervals if i[1] > i[0]):
         if out and t0 <= out[-1][1]:
             if t1 > out[-1][1]:
                 out[-1] = (out[-1][0], t1)
         else:
             out.append((t0, t1))
     return out
+
+
+def smaller_than(
+    records: Sequence[Tuple[float, float, int]], limit: int
+) -> List[Tuple[float, float, int]]:
+    """The ``(t0, t1, nbytes)`` records of objects under ``limit`` bytes. A
+    record that carries no size (``nbytes`` 0: the commit of a streamed
+    object) is not one of them."""
+    return [r for r in records if 0 < r[2] < limit]
 
 
 def clip_merged(

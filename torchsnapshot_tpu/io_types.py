@@ -21,6 +21,11 @@ from typing import AsyncIterator, List, Optional, Tuple, Union
 # A staged buffer is either raw bytes or a zero-copy view over host memory.
 BufferType = Union[bytes, bytearray, memoryview]
 
+# Below this a leaf, a transfer or a storage write counts as small: the
+# ``take.small_*`` counters and the ``*_small_s`` drain stats say what a
+# state of many sizes pays in per-object fixed costs.
+SMALL_OBJECT_BYTES = 1 << 20
+
 
 class BufferStager(abc.ABC):
     """Produces the bytes for one write request, as lazily as possible.

@@ -72,9 +72,17 @@ from .engine.intervals import (
     clip_merged as _clip_merged,
     measure as _measure,
     merge_intervals as _merge_intervals,
+    smaller_than as _smaller_than,
     stream_stats as _stream_stats,
 )
-from .io_types import ReadIO, ReadReq, StoragePlugin, WriteIO, WriteReq
+from .io_types import (
+    SMALL_OBJECT_BYTES,
+    ReadIO,
+    ReadReq,
+    StoragePlugin,
+    WriteIO,
+    WriteReq,
+)
 from .storage_plugins.cloud_retry import (
     CollectiveProgress,
     is_transient_os_error,
@@ -410,7 +418,7 @@ class _WritePipeline:
         return self._engine.stage_intervals
 
     @property
-    def _io_intervals(self) -> List[Tuple[float, float]]:
+    def _io_intervals(self) -> List[Tuple[float, float, int]]:
         return self._engine.io_intervals
 
     def _after_reap(self) -> None:
@@ -1036,6 +1044,18 @@ class _WritePipeline:
             self.pipeline_stats[f"stage_{kind}_s"] = sum(
                 _measure(_clip_merged(merged, w0, w1))
                 for w0, w1 in windows
+            )
+        # Of the d2h sub-stream and of the io stream, the seconds spent on
+        # transfers and writes under SMALL_OBJECT_BYTES: what a state of many
+        # sizes pays in per-object fixed costs (same algebra, same windows).
+        for name, ivs in (
+            ("stage_d2h_small_s", sub["d2h"]),
+            ("io_busy_small_s", self._io_intervals),
+        ):
+            merged = _merge_intervals(_smaller_than(ivs, SMALL_OBJECT_BYTES))
+            self.drain_stats[name] = _measure(_clip_merged(merged, *drain_window))
+            self.pipeline_stats[name] = sum(
+                _measure(_clip_merged(merged, w0, w1)) for w0, w1 in windows
             )
         # Pipeline-level metrics (no-ops unless a telemetry session is on).
         telemetry.gauge_max(

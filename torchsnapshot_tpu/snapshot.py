@@ -54,7 +54,13 @@ from .io_preparers.sharded_array import (
     alloc_target_shards,
     assemble_jax_array,
 )
-from .io_types import ReadIO, ReadReq, StoragePlugin, WriteIO
+from .io_types import (
+    SMALL_OBJECT_BYTES,
+    ReadIO,
+    ReadReq,
+    StoragePlugin,
+    WriteIO,
+)
 from .manifest import (
     ArrayEntry,
     ChunkedArrayEntry,
@@ -928,6 +934,7 @@ class Snapshot:
         manifest: Manifest = dict(plan.manifest)
         flattened = plan.flattened
         rng_states = plan.rng_states
+        _count_leaves(flattened)
 
         replicated_paths = cls._match_replicated_paths(
             set(flattened.keys()), plan.replicated_globs
@@ -3640,6 +3647,26 @@ def _manifest_storage_locations(manifest: Manifest) -> Set[str]:
         for shard in getattr(entry, "shards", None) or []:
             locations.add(shard.tensor.location)
     return locations
+
+
+def _count_leaves(flattened: Dict[str, Any]) -> None:
+    """How many array leaves this rank's take holds, and how many of them
+    (with their bytes) lie under ``SMALL_OBJECT_BYTES``: with default knobs
+    each is a transfer and a storage object of its own."""
+    if telemetry.get_active() is None:
+        return
+    leaves = small = small_bytes = 0
+    for value in flattened.values():
+        nbytes = getattr(value, "nbytes", None)
+        if nbytes is None or not hasattr(value, "shape"):
+            continue
+        leaves += 1
+        if nbytes < SMALL_OBJECT_BYTES:
+            small += 1
+            small_bytes += nbytes
+    telemetry.counter_add("take.leaves", leaves)
+    telemetry.counter_add("take.small_leaves", small)
+    telemetry.counter_add("take.small_leaf_bytes", small_bytes)
 
 
 def _is_jax_array(obj: Any) -> bool:

@@ -321,6 +321,7 @@ class FSStoragePlugin(StoragePlugin):
                     with self._get_direct_sem(), telemetry.span(
                         "storage.write_work", "storage", True,
                         path=write_io.path,
+                        nbytes=memoryview(write_io.buf).nbytes,
                     ):
                         if want_digest:
                             write_io.digest_out = native.write_file_digest(
