@@ -6,6 +6,7 @@ O_DIRECT (tmpfs), the disable knob, and FS-plugin integration parity with the
 pure-Python path.
 """
 
+import errno
 import os
 
 import numpy as np
@@ -303,7 +304,7 @@ def test_write_at_direct_binding(lib, tmp_path) -> None:
 def test_consumer_views_the_object_the_read_filled(tmp_path, route, byte_range) -> None:
     """No copy lies between the storage read and the consumer: the view the
     read pipeline hands to ``consume_buffer`` is backed by the very
-    ``bytearray`` the native read filled (or the ``bytes`` ``aiofiles``
+    array the native read filled (or the ``bytes`` ``aiofiles``
     returned): identity, not equality."""
     import asyncio
 
@@ -348,7 +349,233 @@ def test_consumer_views_the_object_the_read_filled(tmp_path, route, byte_range) 
     ):
         asyncio.run(go())
     (obj,), (view,) = filled, seen
-    assert type(obj) is (bytearray if route == "native" else bytes)
+    assert type(obj) is (np.ndarray if route == "native" else bytes)
     assert view.obj is obj
     begin, end = byte_range or (0, len(data))
     assert view.nbytes == end - begin and view == data[begin:end]
+
+
+# ----------------------------------------------------------- the chunked read
+#
+# One object is read as positional chunk reads on the engine's reader pool,
+# ``depth`` of them on the mount at once, whichever objects they belong to.
+
+
+@pytest.fixture
+def restore_depth(lib):
+    """Leave the process-wide pool as the library sizes it."""
+    yield
+    native.set_read_depth(lib, knobs.get_direct_read_depth())
+
+
+def _file_of(tmp_path, nbytes: int, seed: int = 0):
+    data = np.random.default_rng(seed).integers(0, 256, size=nbytes, dtype=np.uint8)
+    path = str(tmp_path / f"obj{seed}-{nbytes}")
+    with open(path, "wb") as f:
+        f.write(data.tobytes())
+    return path, data
+
+
+def _size(spec, chunk: int) -> int:
+    return 3 * chunk + 17 if spec == "3c+17" else spec
+
+
+@pytest.mark.parametrize("depth", [1, 2, 8])
+@pytest.mark.parametrize("chunk", [4096, 16384, 1 << 20])
+@pytest.mark.parametrize("offset", [0, 1, 4096, 12345])
+@pytest.mark.parametrize("size", [0, 1, 4095, 4096, 4097, (1 << 20) + 3, "3c+17"])
+def test_chunked_read_is_the_files_bytes(
+    lib, tmp_path, restore_depth, size, offset, chunk, depth
+) -> None:
+    n = _size(size, chunk)
+    path, data = _file_of(tmp_path, offset + n + 5)
+    native.set_read_depth(lib, depth)
+    out = np.full(n, 0xA5, dtype=np.uint8)
+    chunk_reads = native.read_into(
+        lib, path, out, offset=offset, direct=True, chunk_bytes=chunk, stamped=True
+    )
+    assert np.array_equal(out, data[offset : offset + n])
+    # One interval a chunk, on time.monotonic()'s clock.
+    assert len(chunk_reads) == -(-n // chunk)
+    assert all(0.0 < t0 <= t1 for t0, t1 in chunk_reads)
+    stats = native.read_pool_stats(lib)
+    assert stats["depth"] == depth and stats["in_flight"] == 0
+    assert stats["high_water"] <= depth and stats["buffers"] <= depth
+    assert stats["buffer_bytes"] <= depth * (chunk + 4096)
+
+
+@pytest.mark.parametrize("chunk", [4096, 1 << 20])
+@pytest.mark.parametrize("direct", [True, False])
+def test_chunked_read_of_a_short_file_is_eio(lib, tmp_path, chunk, direct) -> None:
+    """A file shorter than the manifest says fails, never a short buffer."""
+    path, _ = _file_of(tmp_path, 3 * chunk)
+    with pytest.raises(OSError) as e:
+        native.read_into(
+            lib, path, np.empty(3 * chunk + 1, np.uint8), direct=direct, chunk_bytes=chunk
+        )
+    assert e.value.errno == errno.EIO
+
+
+@pytest.mark.parametrize("chunk", [4096, 1 << 20])
+def test_chunked_read_without_o_direct_falls_back(lib, chunk) -> None:
+    """tmpfs refuses O_DIRECT at open: every chunk is read buffered."""
+    if not os.path.isdir("/dev/shm"):
+        pytest.skip("no tmpfs mount")
+    path = f"/dev/shm/tss_native_chunked_{os.getpid()}_{chunk}"
+    data = np.random.default_rng(chunk).integers(0, 256, 3 * chunk + 17, dtype=np.uint8)
+    try:
+        with open(path, "wb") as f:
+            f.write(data.tobytes())
+        out = np.empty(data.size - 7, np.uint8)
+        native.read_into(lib, path, out, offset=7, direct=True, chunk_bytes=chunk)
+        assert np.array_equal(out, data[7:])
+        assert native.read_pool_stats(lib)["in_flight"] == 0
+    finally:
+        os.remove(path)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 8])
+def test_eight_objects_from_eight_threads_are_all_exact(
+    lib, tmp_path, restore_depth, depth
+) -> None:
+    """Depth across objects: the cap counts chunks in flight for the
+    process, never more than ``depth``, and the bounce pool never grows
+    past ``depth`` buffers however many callers wait."""
+    import threading
+
+    chunk = 64 * 1024
+    files = [_file_of(tmp_path, 5 * chunk + 4097 * i + 1, seed=i) for i in range(8)]
+    outs = [np.empty(data.size, np.uint8) for _, data in files]
+    native.set_read_depth(lib, depth)
+    errors = []
+
+    def read(i: int) -> None:
+        try:
+            native.read_into(lib, files[i][0], outs[i], direct=True, chunk_bytes=chunk)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=read, args=(i,)) for i in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors
+    for (_, data), out in zip(files, outs):
+        assert np.array_equal(out, data)
+    stats = native.read_pool_stats(lib)
+    assert stats["chunks_read"] == sum(-(-d.size // chunk) for _, d in files)
+    assert 1 <= stats["high_water"] <= depth
+    assert stats["buffers"] <= depth and stats["in_flight"] == 0
+    assert stats["buffer_bytes"] <= depth * (chunk + 4096) <= 256 << 20
+
+
+def test_bounce_memory_is_bounded_whatever_chunk_is_asked(lib, tmp_path, restore_depth) -> None:
+    """depth x chunk stays under 256 MiB: the engine clamps the chunk."""
+    path, data = _file_of(tmp_path, (1 << 20) + 3)
+    native.set_read_depth(lib, 8)
+    out = np.empty(data.size, np.uint8)
+    native.read_into(lib, path, out, direct=True, chunk_bytes=1 << 40)
+    assert np.array_equal(out, data)
+    assert native.read_pool_stats(lib)["buffer_bytes"] <= (256 << 20) + 8 * 4096
+
+
+def test_a_failed_chunk_fails_the_read_and_the_others_land(lib, tmp_path) -> None:
+    """The fault harness's torn read: chunk 1 of 4 fails with ESTALE (a
+    transient errno), chunk 0 has landed in the attempt's destination."""
+    chunk = 16384
+    path, data = _file_of(tmp_path, 4 * chunk)
+    out = np.zeros(data.size, np.uint8)
+    with pytest.raises(OSError) as e:
+        native.read_into(lib, path, out, direct=True, chunk_bytes=chunk, fail_chunk=1)
+    assert e.value.errno == errno.ESTALE
+    assert np.array_equal(out[:chunk], data[:chunk])
+    assert not out[chunk : 2 * chunk].any()
+    assert native.read_pool_stats(lib)["in_flight"] == 0
+
+
+def test_native_read_destination_is_not_zero_filled_and_is_spanned(tmp_path) -> None:
+    """``_native_read`` hands on one writable buffer it allocated
+    uninitialised, inside its ``storage.read_work`` span."""
+    import asyncio
+
+    from torchsnapshot_tpu import telemetry
+    from torchsnapshot_tpu.telemetry import core as telemetry_core
+
+    if native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    data = os.urandom((1 << 20) + 5)
+    allocated = []
+    real_empty = np.empty
+
+    def spying_empty(*args, **kwargs):
+        allocated.append(telemetry_core.current_span_id())
+        return real_empty(*args, **kwargs)
+
+    async def go():
+        from torchsnapshot_tpu.storage_plugins import fs as fs_mod
+
+        plugin = FSStoragePlugin(str(tmp_path))
+        await plugin.write(WriteIO(path="obj", buf=data))
+        read_io = ReadIO(path="obj")
+        tm = telemetry.Telemetry()
+        prev = telemetry.activate(tm)
+        fs_mod.np.empty = spying_empty
+        try:
+            await plugin.read(read_io)
+        finally:
+            fs_mod.np.empty = real_empty
+            telemetry.deactivate(tm, prev)
+        await plugin.close()
+        return read_io, tm
+
+    with knobs.override_direct_io_threshold_bytes(1024):
+        read_io, tm = asyncio.run(go())
+    view = read_io.buf.getbuffer()
+    assert not view.readonly and view == data
+    (work_span,) = tm.spans(name="storage.read_work")
+    assert allocated == [work_span.span_id]
+    assert work_span.attrs["nbytes"] == len(data)
+
+
+def test_a_forked_child_reads_through_a_pool_of_its_own(lib, tmp_path) -> None:
+    """The reader threads do not survive ``fork``: the child's first read
+    starts a pool of its own, sized as the parent's was."""
+    import subprocess
+    import sys
+    import textwrap
+
+    path, data = _file_of(tmp_path, (1 << 20) + 3)
+    script = textwrap.dedent(
+        f"""
+        import os, sys
+        import numpy as np
+        from torchsnapshot_tpu import native
+
+        lib = native.load_native()
+        want = np.fromfile({path!r}, dtype=np.uint8)
+
+        def read():
+            out = np.empty(want.size, np.uint8)
+            native.read_into(lib, {path!r}, out, direct=True, chunk_bytes=65536)
+            return np.array_equal(out, want)
+
+        native.set_read_depth(lib, 3)
+        assert read()
+        pid = os.fork()
+        if pid == 0:
+            ok = read() and native.read_pool_stats(lib)["depth"] == 3
+            os._exit(0 if ok else 1)
+        _, status = os.waitpid(pid, 0)
+        assert read()
+        sys.exit(os.waitstatus_to_exitcode(status))
+        """
+    )
+    done = subprocess.run(
+        [sys.executable, "-W", "ignore", "-c", script],
+        timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True,
+        text=True,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]

@@ -586,7 +586,7 @@ def test_bypass_vs_range_miss_metric_split(tmp_path):
 @pytest.mark.parametrize("byte_range", [None, (1 << 16, 3 << 16)], ids=["whole", "range"])
 def test_cache_entry_outlives_the_fetched_buffer(tmp_path, byte_range):
     """A fetched buffer belongs to its read (the fs plugin's native read
-    hands its ``bytearray`` on by reference): the cache fills from a copy
+    hands its array on by reference): the cache fills from a copy
     of its own, so after the consumer has scribbled over and released the
     buffer it was given, the entry still serves the bytes bit for bit, with
     no second origin read."""
@@ -618,7 +618,7 @@ def test_cache_entry_outlives_the_fetched_buffer(tmp_path, byte_range):
         first = ReadIO(path="obj", byte_range=byte_range)
         run(plugin.read(first))
         view = first.buf.getbuffer()
-        assert type(view.obj) is bytearray and view == want
+        assert type(view.obj) is np.ndarray and view == want
         view[:] = bytes(len(want))  # the consumer's buffer, to do with as it likes
         view.release()
         del first

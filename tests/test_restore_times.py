@@ -27,7 +27,7 @@ NEW_KEYS = (
     "plan_s", "fetch_busy_s", "fetch_sum_s", "fetch_wait_s", "verify_busy_s",
     "consume_busy_s", "consume_sum_s", "consume_wait_s", "place_busy_s",
     "place_bytes", "place_wait_s", "place_retry_s", "targets_consumed",
-    "load_s", "idle_s", "pipeline_s",
+    "load_s", "idle_s", "pipeline_s", "mount_busy_s", "mount_sum_s", "mount_bytes",
 )
 
 
@@ -120,6 +120,42 @@ def test_restore_splits_its_own_time(saved, overlap) -> None:
     assert block["wall_s"] <= stats["wall_s"] and block["load_s"] <= stats["load_s"]
     # A handful of phase lines, not one per leaf.
     assert "restore.place" not in artifact["phases_s"] and "restore.plan" in artifact["phases_s"]
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "phase_split"])
+def test_mount_keys_count_the_engines_chunk_reads(saved, monkeypatch, overlap) -> None:
+    """``mount_*``: the union and the sum of the intervals in which a chunk
+    read of the native engine was on the mount, and the bytes they
+    delivered: what says whether the read depth engages."""
+    from torchsnapshot_tpu import native
+    from torchsnapshot_tpu.storage_plugins import fs as fs_mod
+
+    if native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    path, mesh, tree = saved
+    targets = StateDict(**_targets(mesh, tree))
+    monkeypatch.setattr(fs_mod, "_READ_CHUNK_BYTES", 64 * 1024)
+    native_bytes, chunk_reads = [], []
+    real_read_into = native.read_into
+
+    def counting_read_into(lib, path, dst, **kwargs):
+        out = real_read_into(lib, path, dst, **kwargs)
+        native_bytes.append(memoryview(dst).nbytes)
+        chunk_reads.extend(out)
+        return out
+
+    monkeypatch.setattr(native, "read_into", counting_read_into)
+    with knobs.override_direct_io_threshold_bytes(1024), knobs.override_restore_overlap(overlap):
+        Snapshot(path).restore({"s": targets})
+    for k in ("plain", "big", "sharded", "host"):
+        assert np.array_equal(_bits(targets[k]), _bits(tree[k])), k
+    stats = snapshot_mod.LAST_RESTORE_STATS
+    assert stats["mount_bytes"] == sum(native_bytes) > tree["big"].nbytes
+    assert 0.0 < stats["mount_busy_s"] <= stats["mount_sum_s"] + 1e-9
+    assert stats["mount_sum_s"] == pytest.approx(sum(t1 - t0 for t0, t1 in chunk_reads))
+    # A chunk read is on the mount only while its fetch is open.
+    assert stats["mount_busy_s"] <= stats["fetch_busy_s"] + 1e-9
+    assert len(chunk_reads) > len(native_bytes)  # "big" is sixteen chunks
 
 
 def test_verification_has_its_own_interval(saved) -> None:

@@ -240,3 +240,74 @@ def test_retried_read_delivers_only_the_second_attempt(tmp_path, monkeypatch, la
     assert _read_through_pipeline(plugin, "obj", len(payload)) == payload
     assert torn.failures == 1, "the transient fault never fired"
     _run(plugin.close())
+
+
+@pytest.mark.parametrize("chunk", [0, 2], ids=["first_chunk", "third_chunk"])
+def test_read_torn_at_chunk_grain_delivers_a_fresh_destination(
+    tmp_path, monkeypatch, chunk
+) -> None:
+    """``op=read_chunk``: one chunk of one object's native read fails
+    transiently, inside the engine, once. The plugin's retry reads the
+    object again into a destination of its own; the consumer sees that one
+    and never the array the failed attempt partly filled."""
+    import numpy as np
+
+    from torchsnapshot_tpu import native
+    from torchsnapshot_tpu.io_types import ReadReq
+    from torchsnapshot_tpu.scheduler import execute_read_reqs
+    from torchsnapshot_tpu.storage_plugins import cloud_retry, fs as fs_mod
+    from torchsnapshot_tpu.utils import knobs
+
+    if native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    monkeypatch.setattr(cloud_retry, "BASE_BACKOFF_S", 0.001)
+    monkeypatch.setattr(fs_mod, "_READ_CHUNK_BYTES", 4096)
+    payload = np.random.default_rng(chunk).integers(0, 256, 5 * 4096 + 9, dtype=np.uint8)
+    destinations, delivered, seen = [], [], []
+    real_empty = np.empty
+
+    def recording_empty(*args, **kwargs):
+        destinations.append(real_empty(*args, **kwargs))
+        return destinations[-1]
+
+    class Recording(FSStoragePlugin):
+        async def _native_read(self, path, offset, nbytes):
+            delivered.append(await super()._native_read(path, offset, nbytes))
+            return delivered[-1]
+
+    class Consumer:
+        def get_consuming_cost_bytes(self) -> int:
+            return payload.size
+
+        async def consume_buffer(self, buf, executor=None) -> None:
+            seen.append(memoryview(buf))
+
+    async def go() -> None:
+        plugin = Recording(root=str(tmp_path))
+        await plugin.write(WriteIO(path="obj", buf=payload.tobytes()))
+        await plugin.write(WriteIO(path="other", buf=payload.tobytes()))
+        monkeypatch.setattr(fs_mod.np, "empty", recording_empty)
+        try:
+            await execute_read_reqs(
+                [
+                    ReadReq(path="other", buffer_consumer=Consumer()),
+                    ReadReq(path="obj", buffer_consumer=Consumer()),
+                ],
+                plugin,
+                memory_budget_bytes=1 << 30,
+                rank=0,
+            )
+        finally:
+            monkeypatch.setattr(fs_mod.np, "empty", real_empty)
+        await plugin.close()
+
+    spec = f"op=read_chunk,kind=transient,path=obj,times=1,chunk={chunk}"
+    with knobs.override_direct_io_threshold_bytes(1024), knobs.override_faults(spec):
+        _run(go())
+    # Three attempts allocated, two delivered: the torn one raised.
+    assert len(destinations) == 3 and len(delivered) == 2 and len(seen) == 2
+    torn = [d for d in destinations if not any(d is ok for ok in delivered)]
+    assert len(torn) == 1
+    for view in seen:
+        assert view == payload.tobytes()
+        assert any(view.obj is ok for ok in delivered) and view.obj is not torn[0]

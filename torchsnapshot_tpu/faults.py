@@ -26,7 +26,7 @@ Spec grammar (rules separated by ``;``, fields by ``,``)::
 
     op    = write | read | delete | stream_open | append | commit | abort
           | link | list | peer_serve | any
-          | catalog_append | steprecord_append | cache_bitmap
+          | catalog_append | steprecord_append | cache_bitmap | read_chunk
 
     ``catalog_append`` / ``steprecord_append`` are *derived* write classes:
     they fire at plugin writes landing under the catalog's record /
@@ -36,6 +36,15 @@ Spec grammar (rules separated by ``;``, fields by ``,``)::
     ``cache_bitmap`` fires at the sparse read-cache's bitmap-rename commit
     point (``storage_plugins/cache.py``), which lives BELOW this wrapper —
     it is driven through :func:`maybe_inject_local` instead of ``_guard``.
+
+    ``read_chunk`` is a torn read at chunk grain: the fs plugin's native
+    engine reads one object as chunk reads (``native/tss_io.cpp``), below
+    this wrapper and outside the interpreter. The class counts the plugin's
+    native reads (one per object and attempt); where a rule fires, chunk
+    ``chunk=<k>`` (default 0) of that read fails with ``ESTALE`` while the
+    object's other chunks land in the attempt's destination. ``transient``
+    is its only kind: the plugin's own retry runs, and must fill a fresh
+    destination. Driven through :func:`read_chunk_fault`.
 
     ``peer_serve`` is not a storage op: it fires at the swarm restore's
     peer-serving point, just before a rank posts a fetched chunk for its
@@ -137,6 +146,7 @@ _OPS = (
     "catalog_append",
     "steprecord_append",
     "cache_bitmap",
+    "read_chunk",
     "beacon",
     "any",
 )
@@ -157,7 +167,7 @@ _DERIVED_WRITE_OPS = (
 )
 _DERIVED_OP_SET = frozenset(
     op for op, _ in _DERIVED_WRITE_OPS
-) | {"cache_bitmap"}
+) | {"cache_bitmap", "read_chunk"}
 _KINDS = ("transient", "fail", "torn", "stall", "kill", "corrupt")
 
 # Plugin surface the wrapper deliberately proxies WITHOUT an injection
@@ -370,9 +380,14 @@ def parse_fault_spec(spec: str) -> FaultPlan:
             raise FaultSpecError(
                 f"kind=corrupt applies to read/peer_serve ops, not {rule.op!r}"
             )
-        if rule.chunk is not None and rule.kind != "corrupt":
+        if rule.op == "read_chunk" and rule.kind != "transient":
             raise FaultSpecError(
-                f"chunk= targets corrupt rules only, not kind={rule.kind!r}"
+                f"op=read_chunk fails a chunk transiently, not kind={rule.kind!r}"
+            )
+        if rule.chunk is not None and rule.kind != "corrupt" and rule.op != "read_chunk":
+            raise FaultSpecError(
+                "chunk= targets corrupt and read_chunk rules only, not "
+                f"kind={rule.kind!r}"
             )
         plan.rules.append(rule)
     return plan
@@ -722,18 +737,21 @@ class _LocalInjector:
         self._counters: Dict[str, int] = {}
         self._lock = threading.Lock()
 
-    def inject(self, op: str, path: str) -> None:
+    def match(self, op: str, path: str) -> Optional[FaultRule]:
+        """Count one op of this class; the rule that fires at it, if any."""
         with self._lock:
             index = self._counters.get(op, 0)
             self._counters[op] = index + 1
-            act = None
             for rule in self.plan.rules:
                 if rule.op != op:
                     continue  # local classes match only rules naming them
                 if rule.matches(op, index, path, self._rng, self._rank):
                     rule.injected += 1
-                    act = rule
-                    break
+                    return rule
+        return None
+
+    def inject(self, op: str, path: str) -> None:
+        act = self.match(op, path)
         if act is None:
             return
         telemetry.counter_add(f"faults.{act.kind}")
@@ -760,18 +778,37 @@ _LOCAL_INJECTOR: Optional[_LocalInjector] = None
 _LOCAL_LOCK = threading.Lock()
 
 
-def maybe_inject_local(op: str, path: str) -> None:
-    """Run a plugin-internal injection point (no-op unless the faults knob
-    is set AND the spec names ``op``). Callers sit below the wrapper stack,
-    so this is their only road into chaos schedules."""
+def _local_injector() -> Optional[_LocalInjector]:
     from .utils import knobs
 
     spec = knobs.get_faults_spec()
     if not spec:
-        return
+        return None
     global _LOCAL_INJECTOR
     with _LOCAL_LOCK:
         if _LOCAL_INJECTOR is None or _LOCAL_INJECTOR.spec != spec:
             _LOCAL_INJECTOR = _LocalInjector(spec)
-        injector = _LOCAL_INJECTOR
-    injector.inject(op, path)
+        return _LOCAL_INJECTOR
+
+
+def maybe_inject_local(op: str, path: str) -> None:
+    """Run a plugin-internal injection point (no-op unless the faults knob
+    is set AND the spec names ``op``). Callers sit below the wrapper stack,
+    so this is their only road into chaos schedules."""
+    injector = _local_injector()
+    if injector is not None:
+        injector.inject(op, path)
+
+
+def read_chunk_fault(path: str) -> int:
+    """The ``read_chunk`` injection point, run by the fs plugin before each
+    native read: the chunk of this read that is to fail inside the engine,
+    or -1."""
+    injector = _local_injector()
+    rule = injector.match("read_chunk", path) if injector is not None else None
+    if rule is None:
+        return -1
+    chunk = rule.chunk or 0
+    telemetry.counter_add(f"faults.{rule.kind}")
+    logger.warning("FAULT transient chunk %d of native read %s", chunk, path)
+    return chunk

@@ -138,7 +138,7 @@ class ReadBuffer:
     """The bytes of one storage read, held by reference.
 
     What a plugin's ``read`` hands to ``write`` IS what ``getbuffer`` views:
-    the ``bytearray`` a native read filled, the ``bytes`` a client library
+    the array a native read filled, the ``bytes`` a client library
     returned. No pass over the bytes lies between the backend's delivery and
     the consumer. The object must belong to the read or be immutable: a
     ``bytes`` a plugin also keeps (the memory plugin's store, a cache's

@@ -1,7 +1,7 @@
 """Loader for the native I/O engine (``tss_io.cpp``).
 
 The engine is a single C++ translation unit compiled on first use with the
-host toolchain (``g++ -O2 -shared -fPIC -lz``) and loaded via :mod:`ctypes` —
+host toolchain (``g++ -O2 -shared -fPIC -pthread -lz``) and loaded via :mod:`ctypes` —
 ctypes releases the GIL for the duration of each call, so bounce-buffer
 copies and pwrite/pread syscalls overlap the asyncio event loop without a
 C extension module.
@@ -29,7 +29,7 @@ import os
 import subprocess
 import tempfile
 import threading
-from typing import Optional
+from typing import Dict, List, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -62,7 +62,7 @@ def _build(out_path: str) -> None:
     os.close(fd)
     try:
         subprocess.run(
-            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", _SRC, "-o", tmp, "-lz"],
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread", _SRC, "-o", tmp, "-lz"],
             check=True,
             capture_output=True,
             text=True,
@@ -93,8 +93,17 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_uint64,
         ctypes.c_int,
         ctypes.c_uint64,
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_double)),
+        ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_int64,
     ]
     lib.tss_read_file.restype = ctypes.c_int
+    lib.tss_free.argtypes = [ctypes.c_void_p]
+    lib.tss_free.restype = None
+    lib.tss_read_pool_configure.argtypes = [ctypes.c_int]
+    lib.tss_read_pool_configure.restype = ctypes.c_int
+    lib.tss_read_pool_stats.argtypes = [ctypes.POINTER(ctypes.c_uint64 * 6)]
+    lib.tss_read_pool_stats.restype = None
     lib.tss_file_size.argtypes = [ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
     lib.tss_file_size.restype = ctypes.c_int
     lib.tss_write_file_digest.argtypes = [
@@ -301,12 +310,21 @@ def read_into(
     *,
     offset: int = 0,
     direct: bool = True,
-    chunk_bytes: int = 64 << 20,
-) -> None:
-    """Fill writable buffer ``dst`` from ``path[offset : offset+len(dst)]``."""
+    chunk_bytes: int = 4 << 20,
+    stamped: bool = False,
+    fail_chunk: int = -1,
+) -> List[Tuple[float, float]]:
+    """Fill writable buffer ``dst`` from ``path[offset : offset+len(dst)]``,
+    as chunk reads of ``chunk_bytes`` on the engine's reader pool
+    (:func:`set_read_depth`): several of them, of this call and of others,
+    are on the mount at once. ``stamped``: return each chunk's interval on
+    the mount, on ``time.monotonic()``'s clock (else nothing). ``fail_chunk``
+    is the fault harness's torn read (``faults.read_chunk_fault``)."""
     mv = _as_uint8_view(dst)
     if mv.readonly:
         raise ValueError("read_into requires a writable buffer")
+    stamps = ctypes.POINTER(ctypes.c_double)()
+    chunks = ctypes.c_uint64(0)
     rc = lib.tss_read_file(
         os.fsencode(path),
         _buf_address(mv),
@@ -314,9 +332,35 @@ def read_into(
         mv.nbytes,
         1 if direct else 0,
         chunk_bytes,
+        ctypes.byref(stamps) if stamped else None,
+        ctypes.byref(chunks),
+        fail_chunk,
     )
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
+    try:
+        return [(stamps[2 * k], stamps[2 * k + 1]) for k in range(chunks.value)]
+    finally:
+        lib.tss_free(stamps)
+
+
+def set_read_depth(lib: ctypes.CDLL, depth: int) -> None:
+    """How many chunk reads the engine keeps on the mount at once, across
+    every ``read_into`` of the process (as many reader threads, each with
+    one bounce buffer it keeps)."""
+    rc = lib.tss_read_pool_configure(depth)
+    if rc < 0:
+        raise OSError(-rc, os.strerror(-rc))
+
+
+def read_pool_stats(lib: ctypes.CDLL) -> Dict[str, int]:
+    """The reader pool's gauges: ``depth``, chunk reads ``in_flight``, the
+    ``high_water`` of that and the ``chunks_read`` since the depth was last
+    set, and the ``buffers`` / ``buffer_bytes`` of bounce memory it holds."""
+    out = (ctypes.c_uint64 * 6)()
+    lib.tss_read_pool_stats(ctypes.byref(out))
+    keys = ("depth", "in_flight", "high_water", "buffers", "buffer_bytes", "chunks_read")
+    return dict(zip(keys, out))
 
 
 def file_size(lib: ctypes.CDLL, path: str) -> int:

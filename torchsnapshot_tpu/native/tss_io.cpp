@@ -2,13 +2,20 @@
 //
 // Rationale (TPU-VM analogue of the reference's performance layer): the
 // reference (pure Python) relies on the OS page cache for write throughput
-// (torchsnapshot/storage_plugins/fs.py:19-54 via aiofiles). On TPU-VM hosts
-// buffered writeback is typically throttled far below device bandwidth
-// (measured here: ~0.12 GB/s buffered vs ~0.62 GB/s O_DIRECT writes and
-// ~0.57 GB/s vs ~2.0 GB/s cold reads), so checkpoint streaming goes through
-// this engine instead: aligned O_DIRECT transfers with an internal bounce
-// buffer, falling back to buffered I/O wherever O_DIRECT is unsupported
-// (tmpfs, overlayfs, unaligned tails).
+// (torchsnapshot/storage_plugins/fs.py:19-54 via aiofiles). Checkpoint
+// payloads go through this engine instead: aligned O_DIRECT transfers through
+// a bounce buffer, falling back to buffered I/O wherever O_DIRECT is
+// unsupported (tmpfs, overlayfs, unaligned tails). The rates on record are
+// those of the chip machine's 9p mount (PERF.md; it has no block device): a
+// native write 0.6-0.9 GB/s an object, two at a time; a native read 0.5-0.6
+// GB/s as one serial stream, 3.4-3.9 GB/s as eight 4 MiB chunk reads in
+// flight, 1.2-1.4 GB/s buffered or landing in a fresh destination with no
+// bounce buffer (probe of PR 29).
+//
+// Writes are one serial loop an object; the caller (fs.py) caps how many
+// run at once. Reads are chunk reads on a process-wide pool of reader
+// threads (ReadPool below): the cap counts chunks on the mount, whichever
+// objects they belong to, and lives here, not in the caller's interpreter.
 //
 // C ABI only — loaded from Python via ctypes (which releases the GIL for the
 // duration of each call, so copies and syscalls overlap the event loop).
@@ -16,12 +23,21 @@
 // All functions return 0 on success or -errno on failure.
 
 #include <algorithm>
+#include <atomic>
 #include <cerrno>
+#include <condition_variable>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <ctime>
+#include <deque>
+#include <mutex>
+#include <thread>
+#include <utility>
+#include <vector>
 
 #include <fcntl.h>
+#include <pthread.h>
 #include <sys/stat.h>
 #include <unistd.h>
 
@@ -153,11 +169,235 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
   return rc;
 }
 
+// ---------------------------------------------------------------- read side
+//
+// One object is read as positional chunk reads, and a process-wide pool of
+// `depth` reader threads keeps that many chunk reads on the mount at once,
+// whichever objects they belong to: the chunks of one large object, or whole
+// small objects side by side. A thread is the token: the chunk at the head of
+// the queue starts the moment any read finishes, with no trip through the
+// caller's interpreter in between. Each thread keeps one aligned bounce buffer
+// for its lifetime, so the pool holds at most depth x (chunk + one sector),
+// and the chunk is clamped so that this stays under kMaxBounceBytes.
+
+constexpr uint64_t kMaxBounceBytes = 256ull << 20;
+// The depth a new pool starts with: what tss_read_pool_configure last set, so
+// that a forked child's pool is sized as its parent's was.
+std::atomic<unsigned> g_read_depth{8};
+
+double monotonic_s() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<double>(ts.tv_sec) + 1e-9 * static_cast<double>(ts.tv_nsec);
+}
+
+struct ReadJob {
+  char* dst = nullptr;
+  uint64_t offset = 0, nbytes = 0, chunk = 0, file_size = 0;
+  int fd_direct = -1, fd_buffered = -1;
+  double* stamps = nullptr;
+  int64_t fail_chunk = -1;
+  // Guarded by the pool's mutex.
+  uint64_t pending = 0;
+  int rc = 0;
+  std::condition_variable done;
+};
+
+// One chunk under O_DIRECT: [file_off, file_off + n) into `out` through the
+// thread's bounce buffer. (Reading straight into an aligned destination was
+// measured and is slower: pinning a fresh destination's pages for the
+// transfer caps the mount at 1.3 GB/s whatever the depth, where the copy out
+// of a warm bounce buffer faults them in on every reader thread at once:
+// PERF.md section 6, PR 29.) Returns the bytes delivered (short of n: the
+// mount made no progress under O_DIRECT or refused it, and the caller
+// finishes buffered) or -errno.
+int64_t read_chunk_direct(const ReadJob& job, char* bounce, char* out,
+                          uint64_t file_off, uint64_t n) {
+  uint64_t done = 0;
+  while (done < n) {
+    const uint64_t want_off = file_off + done;
+    const uint64_t read_off = align_down(want_off);
+    const uint64_t lead = want_off - read_off;
+    const uint64_t left = n - done;
+    // O_DIRECT reads must not extend past EOF by more than a sector pad.
+    const uint64_t padded =
+        std::min(align_up(lead + left), align_up(job.file_size - read_off));
+    ssize_t r = pread(job.fd_direct, bounce, padded, read_off);
+    if (r < 0) {
+      if (errno == EINTR) continue;
+      if (errno == EINVAL) break;  // refused mid-stream: finish buffered
+      return -errno;
+    }
+    const uint64_t got = static_cast<uint64_t>(r);
+    // No forward progress (a short read at an unaligned boundary, seen on
+    // NFS/FUSE): finish buffered instead of failing the restore.
+    if (got <= lead) break;
+    const uint64_t usable = std::min(got - lead, left);
+    memcpy(out + done, bounce + lead, usable);
+    done += usable;
+  }
+  return static_cast<int64_t>(done);
+}
+
+class ReadPool {
+ public:
+  unsigned depth() {
+    std::lock_guard<std::mutex> g(mu_);
+    return depth_;
+  }
+
+  // Queue every chunk of `job` and wait until the last has landed.
+  int run(ReadJob* job, uint64_t chunks) {
+    std::unique_lock<std::mutex> lk(mu_);
+    if (threads_.empty() && !stop_) spawn_locked();
+    job->pending = chunks;
+    for (uint64_t k = 0; k < chunks; ++k) queue_.emplace_back(job, k);
+    wake_.notify_all();
+    job->done.wait(lk, [job] { return job->pending == 0; });
+    return job->rc;
+  }
+
+  void configure(unsigned depth) {
+    std::vector<std::thread> old;
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      if (depth == depth_ && !threads_.empty()) return;
+      stop_ = true;
+      old.swap(threads_);
+    }
+    wake_.notify_all();
+    for (auto& t : old) t.join();
+    std::lock_guard<std::mutex> g(mu_);
+    stop_ = false;
+    depth_ = depth;
+    g_read_depth.store(depth);
+    high_water_ = 0;  // every reader has been joined: nothing is in flight
+    chunks_read_ = 0;
+    spawn_locked();
+  }
+
+  void stats(uint64_t out[6]) {
+    std::lock_guard<std::mutex> g(mu_);
+    out[0] = depth_;
+    out[1] = in_flight_;
+    out[2] = high_water_;
+    out[3] = buffers_;
+    out[4] = buffer_bytes_;
+    out[5] = chunks_read_;
+  }
+
+ private:
+  void spawn_locked() {
+    for (unsigned i = 0; i < depth_; ++i) threads_.emplace_back([this] { serve(); });
+  }
+
+  void serve() {
+    pthread_setname_np(pthread_self(), "tss-read");
+    char* bounce = nullptr;
+    uint64_t bounce_cap = 0;
+    std::unique_lock<std::mutex> lk(mu_);
+    for (;;) {
+      wake_.wait(lk, [this] { return stop_ || !queue_.empty(); });
+      if (stop_) break;
+      ReadJob* job = queue_.front().first;
+      const uint64_t k = queue_.front().second;
+      queue_.pop_front();
+      int rc = 0;
+      if (job->rc == 0) {  // a failed object's other chunks are not read
+        high_water_ = std::max<uint64_t>(high_water_, ++in_flight_);
+        if (job->fd_direct >= 0 && bounce_cap < job->chunk + kAlign) {
+          buffer_bytes_ -= bounce_cap;
+          buffers_ -= bounce != nullptr;
+          free(bounce);
+          bounce_cap = job->chunk + kAlign;
+          void* p = nullptr;
+          if (posix_memalign(&p, kAlign, bounce_cap) != 0) p = nullptr;
+          bounce = static_cast<char*>(p);
+          if (bounce == nullptr) bounce_cap = 0;
+          buffer_bytes_ += bounce_cap;
+          buffers_ += bounce != nullptr;
+        }
+        lk.unlock();
+        rc = read_chunk(*job, k, bounce);
+        lk.lock();
+        --in_flight_;
+        ++chunks_read_;
+      }
+      if (rc != 0 && job->rc == 0) job->rc = rc;
+      if (--job->pending == 0) job->done.notify_one();
+    }
+    buffer_bytes_ -= bounce_cap;
+    buffers_ -= bounce != nullptr;
+    free(bounce);
+  }
+
+  static int read_chunk(const ReadJob& job, uint64_t k, char* bounce) {
+    const uint64_t at = k * job.chunk;
+    const uint64_t n = std::min(job.chunk, job.nbytes - at);
+    char* out = job.dst + at;
+    const uint64_t file_off = job.offset + at;
+    const double t0 = monotonic_s();
+    int rc = 0;
+    if (static_cast<int64_t>(k) == job.fail_chunk) {
+      rc = -ESTALE;
+    } else {
+      uint64_t done = 0;
+      if (job.fd_direct >= 0 && bounce != nullptr) {
+        int64_t got = read_chunk_direct(job, bounce, out, file_off, n);
+        if (got < 0) rc = static_cast<int>(got);
+        else done = static_cast<uint64_t>(got);
+      }
+      if (rc == 0 && done < n) {
+        rc = read_buffered(job.fd_buffered, out + done, n - done, file_off + done);
+      }
+    }
+    if (job.stamps != nullptr) {
+      job.stamps[2 * k] = t0;
+      job.stamps[2 * k + 1] = monotonic_s();
+    }
+    return rc;
+  }
+
+  std::mutex mu_;
+  std::condition_variable wake_;
+  std::deque<std::pair<ReadJob*, uint64_t>> queue_;
+  std::vector<std::thread> threads_;
+  unsigned depth_ = g_read_depth.load();
+  bool stop_ = false;
+  uint64_t in_flight_ = 0, high_water_ = 0, buffers_ = 0, buffer_bytes_ = 0,
+           chunks_read_ = 0;
+};
+
+// Never destroyed: its threads sleep on `wake_` until the process exits. A
+// forked child has none of them, so it starts a pool of its own.
+std::atomic<ReadPool*> g_pool{nullptr};
+std::mutex g_pool_mu;
+
+ReadPool& pool() {
+  ReadPool* p = g_pool.load(std::memory_order_acquire);
+  if (p == nullptr) {
+    std::lock_guard<std::mutex> g(g_pool_mu);
+    p = g_pool.load(std::memory_order_relaxed);
+    if (p == nullptr) {
+      static const int registered = pthread_atfork(
+          [] { g_pool_mu.lock(); }, [] { g_pool_mu.unlock(); },
+          [] {
+            g_pool.store(nullptr, std::memory_order_relaxed);
+            g_pool_mu.unlock();
+          });
+      (void)registered;
+      p = new ReadPool();
+      g_pool.store(p, std::memory_order_release);
+    }
+  }
+  return *p;
+}
+
 }  // namespace
 
 extern "C" {
 
-int tss_io_version() { return 3; }
+int tss_io_version() { return 4; }
 
 // Create/truncate `path` and write `nbytes` from `buf`.
 // use_direct != 0 attempts O_DIRECT via an aligned bounce buffer of
@@ -248,84 +488,82 @@ int tss_write_at(const char* path, const void* buf, uint64_t nbytes,
   return rc;
 }
 
-// Read `nbytes` at byte `offset` of `path` into `dst`. Fails with -EIO if the
-// file is shorter than offset+nbytes (callers size reads from the manifest).
+// Read `nbytes` at byte `offset` of `path` into `dst` as chunk reads of
+// `chunk_bytes` on the reader pool (see ReadPool above): the call returns when
+// every chunk has landed. Fails with -EIO if the file is shorter than
+// offset+nbytes (callers size reads from the manifest). `stamps_out`, when
+// given, receives an array of two doubles a chunk (`*chunks_out` chunks; the
+// caller releases it with tss_free): each chunk's time on the mount, start
+// and end on CLOCK_MONOTONIC (Python's time.monotonic()). `fail_chunk` >= 0
+// is the fault harness's torn read: that chunk fails with -ESTALE, the
+// others land.
 int tss_read_file(const char* path, void* dst, uint64_t offset, uint64_t nbytes,
-                  int use_direct, uint64_t chunk_bytes) {
-  char* out = static_cast<char*>(dst);
-
-  int fd = -1;
-  bool direct = use_direct != 0 && nbytes >= kAlign;
-  if (direct) {
-    fd = open(path, O_RDONLY | O_DIRECT);
-    if (fd < 0) direct = false;
+                  int use_direct, uint64_t chunk_bytes, double** stamps_out,
+                  uint64_t* chunks_out, int64_t fail_chunk) {
+  ReadJob job;
+  job.dst = static_cast<char*>(dst);
+  job.offset = offset;
+  job.nbytes = nbytes;
+  job.fail_chunk = fail_chunk;
+  if (stamps_out != nullptr) {
+    *stamps_out = nullptr;
+    *chunks_out = 0;
   }
-  if (fd < 0) fd = open(path, O_RDONLY);
-  if (fd < 0) return -errno;
 
+  if (use_direct != 0 && nbytes >= kAlign) {
+    job.fd_direct = open(path, O_RDONLY | O_DIRECT);  // < 0: fs without it
+  }
+  job.fd_buffered = open(path, O_RDONLY);
+  if (job.fd_buffered < 0) {
+    int rc = -errno;
+    if (job.fd_direct >= 0) close(job.fd_direct);
+    return rc;
+  }
   int rc = 0;
-  if (direct) {
-    if (chunk_bytes < kAlign) chunk_bytes = 64ull << 20;
-    chunk_bytes = align_down(chunk_bytes);
-    void* bounce = nullptr;
-    if (posix_memalign(&bounce, kAlign, chunk_bytes) != 0) {
-      close(fd);
-      return -ENOMEM;
-    }
-    struct stat st;
-    if (fstat(fd, &st) < 0) {
-      free(bounce);
-      close(fd);
-      return -errno;
-    }
-    const uint64_t file_size = static_cast<uint64_t>(st.st_size);
-    if (offset + nbytes > file_size) {
-      free(bounce);
-      close(fd);
-      return -EIO;
-    }
-    uint64_t done = 0;
-    while (done < nbytes && rc == 0) {
-      const uint64_t want_off = offset + done;          // unaligned file offset
-      const uint64_t read_off = align_down(want_off);   // aligned read start
-      const uint64_t lead = want_off - read_off;
-      uint64_t n = std::min(chunk_bytes - lead, nbytes - done);
-      // O_DIRECT reads must not extend past EOF by more than a sector pad.
-      uint64_t padded = std::min(align_up(lead + n), align_up(file_size - read_off));
-      ssize_t r = pread(fd, bounce, padded, read_off);
-      if (r < 0) {
-        if (errno == EINTR) continue;
-        if (errno == EINVAL) break;  // fall back below
-        rc = -errno;
-        break;
-      }
-      uint64_t got = static_cast<uint64_t>(r);
-      if (got <= lead) {
-        // No forward progress under O_DIRECT (short read at an unaligned
-        // boundary — seen on NFS/FUSE). Mirror the write path: finish via
-        // the buffered fallback below instead of failing the restore.
-        break;
-      }
-      uint64_t usable = std::min(got - lead, n);
-      memcpy(out + done, static_cast<char*>(bounce) + lead, usable);
-      done += usable;
-    }
-    free(bounce);
-    if (rc == 0 && done < nbytes) {
-      int fd2 = open(path, O_RDONLY);
-      if (fd2 < 0) {
-        rc = -errno;
-      } else {
-        rc = read_buffered(fd2, out + done, nbytes - done, offset + done);
-        close(fd2);
-      }
-    }
+  struct stat st;
+  if (fstat(job.fd_buffered, &st) < 0) {
+    rc = -errno;
   } else {
-    rc = read_buffered(fd, out, nbytes, offset);
+    job.file_size = static_cast<uint64_t>(st.st_size);
+    if (offset + nbytes > job.file_size) rc = -EIO;
   }
-  if (close(fd) < 0 && rc == 0) rc = -errno;
+  if (rc == 0 && nbytes > 0) {
+    const uint64_t cap = kMaxBounceBytes / pool().depth();
+    if (chunk_bytes < kAlign) chunk_bytes = cap;
+    job.chunk = std::max(kAlign, align_down(std::min(chunk_bytes, cap)));
+    const uint64_t chunks = (nbytes + job.chunk - 1) / job.chunk;
+    if (stamps_out != nullptr) {
+      job.stamps = static_cast<double*>(calloc(2 * chunks, sizeof(double)));
+      if (job.stamps == nullptr) rc = -ENOMEM;
+    }
+    if (rc == 0) rc = pool().run(&job, chunks);
+    if (rc == 0 && stamps_out != nullptr) {
+      *stamps_out = job.stamps;
+      *chunks_out = chunks;
+    } else {
+      free(job.stamps);
+    }
+  }
+  if (job.fd_direct >= 0) close(job.fd_direct);
+  if (close(job.fd_buffered) < 0 && rc == 0) rc = -errno;
   return rc;
 }
+
+// Set the number of chunk reads the pool keeps on the mount at once (and so
+// its reader threads and bounce buffers). Chunks already queued are read by
+// the new threads.
+int tss_read_pool_configure(int depth) {
+  if (depth < 1) return -EINVAL;
+  pool().configure(static_cast<unsigned>(depth));
+  return 0;
+}
+
+void tss_free(void* p) { free(p); }
+
+// Gauges of the reader pool: depth, chunk reads in flight now, the most ever
+// in flight since the last configure, bounce buffers held, their bytes,
+// chunks read since the last configure.
+void tss_read_pool_stats(uint64_t out[6]) { pool().stats(out); }
 
 // File size probe (0 on success with *size set).
 int tss_file_size(const char* path, uint64_t* size) {

@@ -9,6 +9,9 @@ it:
     plan      metadata, digest index, key gather; per stateful: frame tables,
               flatten, every ``_prepare_restore_one``, read batching
     fetch     one storage read of the read pipeline, retries included
+    mount     inside a fetch, one chunk read of the fs plugin's native
+              engine for the time it was on the mount (no span: stamped in
+              the engine, GIL-free)
     verify    the digest check of one fetched buffer
     consume   one consumer's decode + copy into its host target
     place     one finalizer: ``device_put`` / ``assemble_jax_array`` /
@@ -69,6 +72,7 @@ _PIPELINE_KINDS = ("fetch", "verify", "consume", "place")
 _SUMS = (
     "fetch_wait_s",
     "fetch_copied_bytes",
+    "mount_bytes",
     "consume_wait_s",
     "place_wait_s",
     "place_retry_s",
@@ -107,6 +111,7 @@ class RestoreTimes:
         self._lock = threading.Lock()
         self._intervals: Dict[str, List[Interval]] = {k: [] for k in _SPANS}
         self._pipeline: List[Interval] = []
+        self._mount: List[Interval] = []
         self._sums: Dict[str, float] = {k: 0.0 for k in _SUMS}
 
     # ----------------------------------------------------------- recording
@@ -169,6 +174,15 @@ class RestoreTimes:
             name, cat, _ = _SPANS["fetch"]
             self.tm.add_span(name, cat, t0, t1 - t0, {"path": path, "nbytes": nbytes})
 
+    def add_mount_reads(self, chunk_reads: List[Interval], nbytes: int) -> None:
+        """One native read's chunk reads, each for the time it was on the
+        mount (stamped inside the engine, on this clock), and the bytes they
+        delivered. No span of their own: ``storage.read_work`` stays one
+        span an object."""
+        with self._lock:
+            self._mount.extend(chunk_reads)
+            self._sums["mount_bytes"] += nbytes
+
     def add(self, key: str, value: float) -> None:
         with self._lock:
             self._sums[key] += value
@@ -209,6 +223,7 @@ class RestoreTimes:
         with self._lock:
             ivs = {k: list(v) for k, v in self._intervals.items()}
             windows = merge_intervals(self._pipeline)
+            mount = list(self._mount)
             out = dict(self._sums)
         merged = {k: merge_intervals(v) for k, v in ivs.items()}
         busy_any = merge_intervals(
@@ -222,6 +237,8 @@ class RestoreTimes:
             plan_s=measure(merged["plan"]),
             fetch_busy_s=measure(merged["fetch"]),
             fetch_sum_s=measure(ivs["fetch"]),
+            mount_busy_s=measure(merge_intervals(mount)),
+            mount_sum_s=measure(mount),
             verify_busy_s=measure(merged["verify"]),
             consume_busy_s=measure(merged["consume"]),
             consume_sum_s=measure(ivs["consume"]),

@@ -3,15 +3,19 @@
 Analogue of the reference's ``storage_plugins/fs.py:19-54`` (async file I/O
 with a parent-directory creation cache and ranged reads via seek), with one
 TPU-VM-specific addition: large transfers route through the native O_DIRECT
-engine (``torchsnapshot_tpu/native``). Buffered writeback on TPU-VM hosts is
-throttled far below device bandwidth (~0.12 GB/s vs ~0.62 GB/s direct writes,
-~0.57 vs ~2.0 GB/s cold reads measured on v5e local disk), so checkpoint
-payloads bypass the page cache; small objects (manifests, primitives) keep the
-simple buffered path.
+engine (``torchsnapshot_tpu/native``), past the page cache; small objects
+(manifests, primitives) keep the simple buffered path. The rates on record
+are those of the chip machine's 9p mount (``PERF.md``; ``native/tss_io.cpp``
+repeats them).
 
 Concurrency: the event loop may have many plugin ops in flight; blocking work
-runs on a private thread pool, and a semaphore caps concurrent O_DIRECT
-streams (disk saturates at ~2; more interfere).
+runs on a private thread pool. Writes: a semaphore caps concurrent native
+writes at ``knobs.get_direct_io_concurrency()`` objects. Reads: no semaphore
+around an object. A native read is chunk reads of ``_READ_CHUNK_BYTES`` on
+the engine's reader pool, and the cap, ``knobs.get_direct_read_depth()``,
+counts chunks on the mount for the whole process: those of one large leaf,
+or of as many small shards side by side; a caller's thread only waits, GIL
+released, until its object's last chunk has landed.
 """
 
 from __future__ import annotations
@@ -26,8 +30,9 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Set
 
 import aiofiles
+import numpy as np
 
-from .. import native, telemetry
+from .. import native, restore_times, telemetry
 from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
 from ..utils import knobs
 from .cloud_retry import (
@@ -38,6 +43,10 @@ from .cloud_retry import (
 )
 
 _DIRECT_ALIGN = 4096  # matches the native engine's kAlign
+# The grain of a native read: one object is this many bytes a chunk read,
+# ``knobs.get_direct_read_depth()`` of them on the mount at once (probe of
+# PR 29, PERF.md section 6).
+_READ_CHUNK_BYTES = 4 << 20
 
 # The transient-errno classification lives in cloud_retry
 # (TRANSIENT_OS_ERRNOS) so the scheduler's read-pipeline retry and this
@@ -213,6 +222,7 @@ class FSStoragePlugin(StoragePlugin):
         # derives the local world size, and the stream cap must reflect it.
         self._direct_sem: Optional[threading.Semaphore] = None
         self._sem_lock = threading.Lock()
+        self._read_depth_set = False
         # Transient local OSErrors (stale NFS handles, timed-out round-trips
         # — see _TRANSIENT_ERRNOS) retry under the same collective-progress
         # policy the cloud plugins use: a network-filesystem hiccup behaves
@@ -241,10 +251,25 @@ class FSStoragePlugin(StoragePlugin):
                     )
         return self._direct_sem
 
+    def _set_read_depth(self, lib) -> None:
+        """Size the engine's reader pool, once per plugin and lazily, for
+        the reason the semaphore is lazy: the depth reflects the local
+        world size."""
+        if not self._read_depth_set:
+            native.set_read_depth(lib, knobs.get_direct_read_depth())
+            self._read_depth_set = True
+
     def _get_executor(self) -> ThreadPoolExecutor:
         if self._executor is None:
+            # Objects under one chunk reach the read depth only side by
+            # side: as many callers may block in the engine as it has
+            # reader threads.
             self._executor = ThreadPoolExecutor(
-                max_workers=max(4, knobs.get_direct_io_concurrency() + 2),
+                max_workers=max(
+                    4,
+                    knobs.get_direct_io_concurrency() + 2,
+                    knobs.get_direct_read_depth() + 2,
+                ),
                 thread_name_prefix="tss-fs",
             )
         return self._executor
@@ -445,24 +470,41 @@ class FSStoragePlugin(StoragePlugin):
 
     async def _native_read(
         self, path: str, offset: int, nbytes: Optional[int]
-    ) -> bytearray:
+    ) -> np.ndarray:
         lib = self._native
+        # Taken on the loop side: an executor thread inherits no context.
+        times = restore_times.get_active()
 
-        def work() -> bytearray:
-            n = native.file_size(lib, path) - offset if nbytes is None else nbytes
-            out = bytearray(n)
+        def work() -> np.ndarray:
+            self._set_read_depth(lib)
+            fail_chunk = -1
+            if knobs.get_faults_spec():
+                # The chunk reads run BELOW the fault wrapper: this is their
+                # only road into chaos schedules (`op=read_chunk`).
+                from .. import faults
+
+                fail_chunk = faults.read_chunk_fault(path)
             # On the reading thread, as ``storage.write_work`` on the writing.
-            with self._get_direct_sem(), telemetry.span(
-                "storage.read_work", "storage", True, path=path, nbytes=n
-            ):
-                native.read_into(
+            with telemetry.span(
+                "storage.read_work", "storage", True, path=path
+            ) as sp:
+                n = native.file_size(lib, path) - offset if nbytes is None else nbytes
+                sp.set_attrs(nbytes=n)
+                # Uninitialised: ``bytearray(n)`` would zero-fill it under
+                # the GIL. A failed attempt's array dies with its exception.
+                out = np.empty(n, dtype=np.uint8)
+                chunk_reads = native.read_into(
                     lib,
                     path,
                     out,
                     offset=offset,
                     direct=n >= knobs.get_direct_io_threshold_bytes(),
-                    chunk_bytes=knobs.get_direct_io_chunk_bytes(),
+                    chunk_bytes=_READ_CHUNK_BYTES,
+                    stamped=times is not None,
+                    fail_chunk=fail_chunk,
                 )
+            if times is not None:
+                times.add_mount_reads(chunk_reads, n)
             return out
 
         return await asyncio.get_running_loop().run_in_executor(

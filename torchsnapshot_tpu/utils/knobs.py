@@ -156,18 +156,41 @@ def get_direct_io_threshold_bytes() -> int:
 
 
 def get_direct_io_concurrency() -> int:
-    """Max concurrent O_DIRECT transfers per storage plugin.
+    """Max concurrent native *writes* per storage plugin (whole objects and
+    streamed appends, under the plugin's semaphore), and, where the
+    environment sets it, the cap of the read side too
+    (:func:`get_direct_read_depth`).
 
-    Measured on TPU-VM local disk: 1-2 concurrent aligned streams saturate the
-    device; more cause seek interference and *reduce* throughput. The default
-    is therefore divided by the local world size (see
-    :func:`set_local_world_size`) — N co-hosted ranks share one disk, and
-    N x 2 streams would interfere. An explicit env value is used verbatim.
+    The default of two, divided by the local world size (see
+    :func:`set_local_world_size`: N co-hosted ranks share one mount), is
+    what the write path has been measured with on the chip machine's 9p
+    mount (`PERF.md`); no record of this repo says what more writers give.
+    An explicit env value is used verbatim.
     """
+    return _direct_io_cap(2)
+
+
+def _direct_io_cap(default: int) -> int:
     val = os.environ.get(_ENV_DIRECT_IO_CONCURRENCY)
     if val is not None:
         return max(1, int(val))
-    return max(1, 2 // get_local_world_size())
+    return max(1, default // get_local_world_size())
+
+
+# Chunk reads on the mount at once, from the probe of PR 29 on the chip
+# machine's 9p mount (PERF.md section 6): the smallest depth within 5 % of
+# the best.
+_DIRECT_READ_DEPTH = 8
+
+
+def get_direct_read_depth() -> int:
+    """Chunk reads the native engine keeps on the mount at once, over every
+    object the process is reading (``native.set_read_depth``). Not a knob of
+    its own: a constant of the read path divided by the local world size,
+    as the write cap is; where ``TORCHSNAPSHOT_TPU_DIRECT_IO_CONCURRENCY``
+    is set, that value verbatim, as for writes (a mount that wants two
+    streams gets two)."""
+    return _direct_io_cap(_DIRECT_READ_DEPTH)
 
 
 def get_direct_io_chunk_bytes() -> int:
