@@ -75,30 +75,6 @@ def build_params(total_gb: float, seed: int = 0):
     return params, nbytes
 
 
-def _chunk_append_hist(snapshot_path: str) -> dict:
-    """Per-chunk ``storage.<plugin>.append_s.<bucket>`` histogram summaries
-    from a local snapshot's persisted rank-0 telemetry artifact, keyed by
-    ``<plugin>.<bucket>``. Empty dict when the snapshot streamed nothing."""
-    with open(
-        os.path.join(snapshot_path, ".telemetry", "rank_0.json"),
-        encoding="utf-8",
-    ) as f:
-        metrics = json.load(f).get("metrics") or {}
-    out: dict = {}
-    for key, value in metrics.items():
-        if not key.startswith("storage.") or ".append_s." not in key:
-            continue
-        # storage.<plugin>.append_s.<bucket>.<stat>
-        head, stat = key.rsplit(".", 1)
-        plugin_bucket = head.replace("storage.", "", 1).replace(
-            ".append_s", "", 1
-        )
-        out.setdefault(plugin_bucket, {})[stat] = (
-            round(value, 6) if isinstance(value, float) else value
-        )
-    return out
-
-
 def measure_naive_save(params_slice, root: str):
     """torch.save-equivalent: blocking device_get of everything, then one
     buffered single-stream pickle write (what the reference benchmarks
@@ -397,214 +373,25 @@ def main() -> None:
         # bottleneck).
         ref_equiv_stall_s = gb / statistics.median(naive_d2h_rates)
 
-        # ---- streaming on/off A/B: the intra-request overlap win. Same
-        # interleaved-reps protocol as the naive/sync A/B (fresh device
-        # arrays per rep, alternating order, link probes bracketing each
-        # drain) so the trajectory records drain_vs_link for BOTH paths.
+        # ---- the state the recorder and beacon A/Bs below take: two big
+        # float32 arrays, fresh per rep.
         from torchsnapshot_tpu.utils import knobs as _knobs
 
-        stream_reps = int(os.environ.get("BENCH_STREAM_AB_REPS", "2"))
-        stream_gb = float(os.environ.get("BENCH_STREAM_AB_GB", "0.5"))
-        # Two big dim-0-chunkable arrays: above the streaming threshold
-        # (2 x TORCHSNAPSHOT_TPU_STREAM_CHUNK_BYTES), so the on-side drains
-        # them as chunk streams while the off-side stages whole. float32:
-        # a sub-32-bit float leaf never streams in device chunks (a device
-        # slice rewrites its bits), so bf16 would put both sides on one path.
-        stream_rows = max(4, int(stream_gb * 1e9 / 2 / (16384 * 4)))
+        slice_gb = float(os.environ.get("BENCH_AB_SLICE_GB", "0.5"))
+        slice_rows = max(4, int(slice_gb * 1e9 / 2 / (16384 * 4)))
 
-        def build_stream_slice(seed: int):
+        def build_big_slice(seed: int):
             import jax.numpy as jnp
 
             ks = jax.random.split(jax.random.PRNGKey(3000 + seed), 2)
             s = {
                 f"b{j}": jax.random.normal(
-                    ks[j], (stream_rows, 16384), jnp.float32
+                    ks[j], (slice_rows, 16384), jnp.float32
                 )
                 for j in range(2)
             }
             jax.block_until_ready(s)
             return s
-
-        stream_sides = {"on": [], "off": []}
-
-        def run_stream_rep(rep: int, enabled: bool) -> None:
-            label = "on" if enabled else "off"
-            sub = build_stream_slice(2 * rep + (0 if enabled else 1))
-            sub_gb = sum(
-                x.nbytes for x in jax.tree_util.tree_leaves(sub)
-            ) / 1e9
-            link0 = probe_link(100 + 10 * rep + (0 if enabled else 5))
-            with _knobs.override_stream_writes(enabled):
-                pend = Snapshot.async_take(
-                    os.path.join(root, f"ckpt_stream_{label}_{rep}"),
-                    {"model": StateDict(**sub)},
-                )
-                t0 = time.perf_counter()
-                pend.wait()
-                rep_drain_s = time.perf_counter() - t0
-            link1 = probe_link(300 + 10 * rep + (0 if enabled else 5))
-            link = statistics.median([link0, link1])
-            ds = pend.drain_stats
-            shorter = min(ds.get("stage_busy_s", 0.0), ds.get("io_busy_s", 0.0))
-            rate = sub_gb / max(rep_drain_s, 1e-9)
-            stream_sides[label].append(
-                {
-                    "drain_s": round(rep_drain_s, 2),
-                    "drain_gbps": round(rate, 4),
-                    "link_gbps": round(link, 4),
-                    "drain_vs_link": round(rate / link, 2),
-                    "overlap_s": round(ds.get("overlap_s", 0.0), 2),
-                    "overlap_frac_of_shorter": round(
-                        ds.get("overlap_s", 0.0) / shorter, 2
-                    )
-                    if shorter > 0
-                    else 1.0,
-                    "stage_busy_s": round(ds.get("stage_busy_s", 0.0), 2),
-                    "io_busy_s": round(ds.get("io_busy_s", 0.0), 2),
-                    # Per-chunk append-latency histogram (per plugin, size
-                    # bucketed) from the persisted artifact: attributes an
-                    # inversion to per-chunk overhead vs grain vs the disk.
-                    "chunk_append_s": _chunk_append_hist(
-                        os.path.join(root, f"ckpt_stream_{label}_{rep}")
-                    ),
-                }
-            )
-            log(
-                f"stream A/B rep {rep} [{label}]: {sub_gb:.2f} GB drained in "
-                f"{rep_drain_s:.2f}s -> {stream_sides[label][-1]}"
-            )
-            shutil.rmtree(
-                os.path.join(root, f"ckpt_stream_{label}_{rep}"),
-                ignore_errors=True,
-            )
-
-        for rep in range(stream_reps):
-            # Alternate which side goes first (same drift hygiene as above).
-            order = (True, False) if rep % 2 == 0 else (False, True)
-            run_stream_rep(rep, order[0])
-            run_stream_rep(rep, order[1])
-
-        def _median_of(label: str, key: str) -> float:
-            return statistics.median(r[key] for r in stream_sides[label])
-
-        stream_ab = {
-            "reps": stream_reps,
-            "size_gb": round(stream_gb, 2),
-            "on": {
-                k: _median_of("on", k)
-                for k in (
-                    "drain_gbps",
-                    "drain_vs_link",
-                    "overlap_s",
-                    "overlap_frac_of_shorter",
-                )
-            },
-            "off": {
-                k: _median_of("off", k)
-                for k in (
-                    "drain_gbps",
-                    "drain_vs_link",
-                    "overlap_s",
-                    "overlap_frac_of_shorter",
-                )
-            },
-            "all": stream_sides,
-        }
-        # Merge the on-side per-rep chunk histograms: counts/sums add,
-        # extremes take min/max, percentiles keep the worst rep
-        # (conservative — bucket-exact merging isn't worth carrying here).
-        chunk_merged: dict = {}
-        for rep_rec in stream_sides["on"]:
-            for pb, stats_d in (rep_rec.get("chunk_append_s") or {}).items():
-                m = chunk_merged.setdefault(pb, {})
-                for stat, v in stats_d.items():
-                    if stat in ("count", "sum"):
-                        m[stat] = m.get(stat, 0) + v
-                    elif stat == "min":
-                        m[stat] = min(m.get(stat, v), v)
-                    else:
-                        m[stat] = max(m.get(stat, v), v)
-        for m in chunk_merged.values():
-            if m.get("count"):
-                m["mean"] = round(m.get("sum", 0.0) / m["count"], 6)
-        stream_ab["chunk_append_s"] = chunk_merged
-        log(f"stream A/B medians: on={stream_ab['on']} off={stream_ab['off']}")
-        if chunk_merged:
-            log(f"stream A/B per-chunk append latency (on side): {chunk_merged}")
-        # Inversion flag: streaming exists to BEAT the whole-buffer path;
-        # when ON underperforms OFF by >10% on this host, say so loudly and
-        # mark the result.
-        ab_on, ab_off = stream_ab["on"]["drain_gbps"], stream_ab["off"]["drain_gbps"]
-        stream_ab["stream_ab_inverted"] = bool(
-            ab_off > 0 and ab_on < 0.9 * ab_off
-        )
-        if stream_ab["stream_ab_inverted"]:
-            log(
-                "WARNING: stream A/B INVERTED on this host: streaming ON "
-                f"drained at {ab_on:.3f} GB/s vs OFF at {ab_off:.3f} GB/s "
-                "(>10% slower) — chunk streaming is hurting, not helping; "
-                "suspect chunk size vs this host's per-append overhead "
-                "(TORCHSNAPSHOT_TPU_STREAM_CHUNK_BYTES) before trusting "
-                "the streamed path's defaults here"
-            )
-
-        # ---- STREAM_WRITES=auto leg + regression gate. The A/B reps above
-        # fed the per-plugin scorecard through the live pipeline (streamed
-        # appends and whole-buffer writes are measured unconditionally), so
-        # the shipped `auto` default now has credible evidence on this
-        # host. Run one auto-mode drain, record the decision the selector
-        # made, and FAIL the bench if auto picked the measured losing side.
-        from torchsnapshot_tpu import stream_select as _stream_select
-
-        auto_sub = build_stream_slice(9000)
-        auto_gb = sum(
-            x.nbytes for x in jax.tree_util.tree_leaves(auto_sub)
-        ) / 1e9
-        with _knobs.override_stream_writes_mode("auto"):
-            pend = Snapshot.async_take(
-                os.path.join(root, "ckpt_stream_auto"), {"model": StateDict(**auto_sub)}
-            )
-            t0 = time.perf_counter()
-            pend.wait()
-            auto_drain_s = time.perf_counter() - t0
-        del auto_sub
-        shutil.rmtree(os.path.join(root, "ckpt_stream_auto"), ignore_errors=True)
-        auto_decision = _stream_select.last_decision()
-        auto_gbps = auto_gb / max(auto_drain_s, 1e-9)
-        # The losing side exists only when the measured A/B separated the
-        # sides by >10% (the same tolerance as the inversion flag); inside
-        # the band either pick is fine.
-        losing_side = None
-        if ab_off > 0 and ab_on < 0.9 * ab_off:
-            losing_side = "on"
-        elif ab_on > 0 and ab_off < 0.9 * ab_on:
-            losing_side = "off"
-        picked = (
-            "on" if auto_decision and auto_decision.get("enabled") else "off"
-        )
-        picked_losing = bool(
-            losing_side is not None
-            and auto_decision is not None
-            and auto_decision.get("mode") == "auto"
-            and picked == losing_side
-        )
-        stream_ab["auto"] = {
-            "decision": auto_decision,
-            "scorecard": _stream_select.scorecard(
-                auto_decision["plugin"] if auto_decision else "fs"
-            ),
-            "drain_gbps": round(auto_gbps, 4),
-            "losing_side": losing_side,
-            "picked": picked,
-            "picked_losing_side": picked_losing,
-        }
-        log(f"stream auto-select: {stream_ab['auto']}")
-        if picked_losing:
-            raise SystemExit(
-                f"stream auto-select REGRESSION: auto picked '{picked}' but "
-                f"the measured A/B says '{losing_side}' is the losing side "
-                f"on this host (on {ab_on:.3f} vs off {ab_off:.3f} GB/s)"
-            )
 
         # ---- persisted-telemetry summary: the async checkpoint carries its
         # own attribution (.telemetry/rank_0.json written by the drain);
@@ -676,7 +463,7 @@ def main() -> None:
         # ---- flight-recorder overhead A/B + job step timeline. The
         # recorder is always-on by default, so its cost must be provably
         # in the noise: interleaved async takes with the recorder on vs
-        # off (same protocol as the stream A/B), compared on the drain
+        # off (fresh device arrays per rep, alternating order), compared on the drain
         # wall median — acceptance is <=1% overhead. Then a short job-mode
         # take sequence exercises the per-step catalog rollup end to end
         # and runs the health detectors over it: a clean run on a healthy
@@ -692,7 +479,7 @@ def main() -> None:
 
         def run_recorder_rep(rep: int, enabled: bool) -> None:
             label = "on" if enabled else "off"
-            sub = build_stream_slice(7000 + 2 * rep + (0 if enabled else 1))
+            sub = build_big_slice(7000 + 2 * rep + (0 if enabled else 1))
             with _knobs.override_recorder(enabled):
                 _recorder.reset()  # re-arm the singleton under the knob
                 pend = Snapshot.async_take(
@@ -782,7 +569,7 @@ def main() -> None:
 
         def run_beacon_rep(rep: int, enabled: bool) -> None:
             label = "on" if enabled else "off"
-            sub = build_stream_slice(9000 + 2 * rep + (0 if enabled else 1))
+            sub = build_big_slice(9000 + 2 * rep + (0 if enabled else 1))
             with _knobs.override_fleet_telemetry(
                 "1" if enabled else "0"
             ), _knobs.override_fleet_beacon_s(0.1):
@@ -849,7 +636,6 @@ def main() -> None:
                         "sync_drain_stats_s": sync_drains,
                         "target_stall_s": 5.0,
                         "steady_state": steady_record,
-                        "stream_ab": stream_ab,
                         "sync_take_gbps": round(sync_gbps, 3),
                         "naive_save_gbps": round(naive_gbps, 3),
                         "speedup_vs_naive_sync": round(sync_gbps / naive_gbps, 2),

@@ -3,8 +3,8 @@
 For each device count N this spawns a fresh process that shards one large
 parameter array across a flat ``(N,)`` mesh of the first N devices and drives
 an ``async_take`` whose background drain runs **N per-device D2H lanes and N
-per-shard ``write_stream``s concurrently** (transfer lanes sized to the
-device count; streaming writes on). The result is the drain-GB/s-vs-device-
+per-shard writes concurrently** (transfer lanes sized to the device count).
+The result is the drain-GB/s-vs-device-
 count curve — the regression surface for "the drain scales with devices",
 not just "the drain is fast on one chip" — with the bytes each device's
 transfers moved.
@@ -76,17 +76,15 @@ def child(platform: str, n_devices: int, total_mb: float, root: str) -> None:
     payload_gb = arr.nbytes / 1e9
 
     try:
-        # Per-device transfer lanes + per-shard write_streams: the drain
-        # should hold one lane and one storage stream busy per device.
+        # Per-device transfer lanes + per-shard writes: the drain should
+        # hold one lane and one storage write busy per device.
         # Fleet telemetry forced on for the measured drain (single-process
         # cell, so "auto" resolves off): the cell record carries the
         # beacon rollup — engine high-water mark, final phase — beside the
         # throughput numbers.
         with knobs.override_d2h_lanes(max(4, n_devices)), (
-            knobs.override_stream_writes(True)
-        ), knobs.override_fleet_telemetry("1"), (
-            knobs.override_fleet_beacon_s(0.05)
-        ):
+            knobs.override_fleet_telemetry("1")
+        ), knobs.override_fleet_beacon_s(0.05):
             fleet.reset()
             # Warmup absorbs compile/native-engine costs outside the
             # measured drain.

@@ -6,9 +6,8 @@ replica must restore (FOREGROUND) while the same process is still draining
 a background checkpoint (BACKGROUND), with scrub / gc / cache-populate
 traffic riding the same machinery at background priority. Before the
 engine, all of that competed FIFO for the process's storage bandwidth;
-with QoS on, the drain yields its next admission (budget, io-pool slots,
-stream chunks) to the restore at chunk granularity and resumes the moment
-the restore's demand clears.
+with QoS on, the drain yields its next admission (budget, io-pool slots)
+to the restore and resumes the moment the restore's demand clears.
 
 Two legs:
 
@@ -104,8 +103,6 @@ class SharedDiskPlugin:
     """A memory-backed StoragePlugin whose reads and writes draw from one
     shared token bucket — the two-operations-one-disk model."""
 
-    supports_streaming = False
-
     def __init__(self, bucket: TokenBucket, objects=None) -> None:
         self.bucket = bucket
         self.objects = objects if objects is not None else {}
@@ -133,7 +130,6 @@ class SharedDiskPlugin:
 class _BytesStager:
     def __init__(self, data: bytes) -> None:
         self.data = data
-        self.stream_holds_full_buffer = False
         self.defer_staging = False
 
     async def stage_buffer(self, executor=None):
@@ -141,9 +137,6 @@ class _BytesStager:
 
     def get_staging_cost_bytes(self) -> int:
         return len(self.data)
-
-    def can_stream(self) -> bool:
-        return False
 
 
 class _NullConsumer:
@@ -330,11 +323,9 @@ def e2e_leg(root: str) -> dict:
     # "auto" resolves off): the artifact embeds the fleet view so the QoS
     # rollup carries the same beacon rollup operators see live.
     fleet_summary = None
-    with knobs.override_qos_poll_s(0.005), knobs.override_stream_chunk_bytes(
-        1024 * 1024
-    ), knobs.override_fleet_telemetry("1"), knobs.override_fleet_beacon_s(
-        0.05
-    ):
+    with knobs.override_qos_poll_s(0.005), knobs.override_fleet_telemetry(
+        "1"
+    ), knobs.override_fleet_beacon_s(0.05):
         fleet.reset()
         pending = Snapshot.async_take(
             os.path.join(root, "bg"), {"m": bg_state}, qos="background"

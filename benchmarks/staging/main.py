@@ -6,14 +6,13 @@ This harness makes staging overhead measurable in isolation, bisect-style:
 
 - **synthetic host buffers** (numpy, no device, no D2H variance): a
   ``np.asarray`` on a host array is free, so the measured wall is purely the
-  pipeline's own machinery — serialization, hashing, chunk plumbing, budget
-  accounting, event-loop dispatch;
-- **a null storage sink** (appends/writes discard after a length probe): no
+  pipeline's own machinery — serialization, hashing, budget accounting,
+  event-loop dispatch;
+- **a null storage sink** (writes discard after a length probe): no
   disk, no page cache, no O_DIRECT alignment — ``io_busy`` collapses to the
   call overhead, so ``stage_busy`` is the whole story;
 - **an ablation matrix** over the staging features that have historically
-  eaten drain time: streaming on/off, checksums on/off, dedup digests
-  on/off. A regression bisects by diffing configs between two commits.
+  eaten drain time: checksums on/off, dedup digests on/off. A regression bisects by diffing configs between two commits.
 
 Reported per config: wall seconds, GB/s through staging, and the
 ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s`` decomposition. One
@@ -39,7 +38,6 @@ from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer  # noqa: E402
 from torchsnapshot_tpu.io_types import (  # noqa: E402
     ReadIO,
     StoragePlugin,
-    StorageWriteStream,
     WriteIO,
 )
 from torchsnapshot_tpu.scheduler import execute_write_reqs  # noqa: E402
@@ -50,34 +48,15 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-class _NullWriteStream(StorageWriteStream):
-    def __init__(self, plugin: "NullStoragePlugin") -> None:
-        self._plugin = plugin
-
-    async def append(self, buf) -> None:
-        self._plugin.bytes_sunk += memoryview(buf).nbytes
-
-    async def commit(self) -> None:
-        pass
-
-    async def abort(self) -> None:
-        pass
-
-
 class NullStoragePlugin(StoragePlugin):
     """Discards every byte after a length probe: the staging stream runs
     against a zero-cost drain, so the pipeline's wall time IS staging."""
-
-    supports_streaming = True
 
     def __init__(self) -> None:
         self.bytes_sunk = 0
 
     async def write(self, write_io: WriteIO) -> None:
         self.bytes_sunk += memoryview(write_io.buf).nbytes
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        return _NullWriteStream(self)
 
     async def read(self, read_io: ReadIO) -> None:
         raise FileNotFoundError(read_io.path)
@@ -99,7 +78,6 @@ def build_host_state(total_mb: int, arrays: int, seed: int = 0):
 
 def run_config(
     arrs,
-    stream: bool,
     checksums: bool,
     dedup: bool,
     hash_grain: int = None,
@@ -129,7 +107,6 @@ def run_config(
     loop = asyncio.new_event_loop()
     try:
         with overrides, \
-                knobs.override_stream_writes(stream), \
                 knobs.override_checksums(checksums), \
                 knobs.override_dedup_digests(dedup):
             t0 = time.perf_counter()
@@ -160,21 +137,17 @@ def main() -> None:
     # Warmup: absorb one-time costs (thread-pool spawn, hashing-engine
     # operator caches, lazy imports) on a tiny slice so the matrix's FIRST
     # cell isn't charged ~0.2s the others never pay.
-    run_config([a[:64] for a in arrs[:1]], stream=True, checksums=True,
-               dedup=True)
+    run_config([a[:64] for a in arrs[:1]], checksums=True, dedup=True)
 
     # The ablation matrix: diffing rows bisects which staging feature a
     # regression lives in. "full" is the production default path (chunked
     # v2 tree hashing); "serial_hash" pins the v1 serial fold (grain 0) so
     # chunked-vs-serial hashing stays directly comparable every run.
     matrix = {
-        "full": dict(stream=True, checksums=True, dedup=True),
-        "serial_hash": dict(
-            stream=True, checksums=True, dedup=True, hash_grain=0
-        ),
-        "no_dedup_sha": dict(stream=True, checksums=True, dedup=False),
-        "no_digests": dict(stream=True, checksums=False, dedup=False),
-        "no_stream": dict(stream=False, checksums=True, dedup=True),
+        "full": dict(checksums=True, dedup=True),
+        "serial_hash": dict(checksums=True, dedup=True, hash_grain=0),
+        "no_dedup_sha": dict(checksums=True, dedup=False),
+        "no_digests": dict(checksums=False, dedup=False),
     }
     results = {}
     for name, cfg in matrix.items():
@@ -208,7 +181,6 @@ def main() -> None:
             for w in workers:
                 cell = run_config(
                     arrs,
-                    stream=True,
                     checksums=True,
                     dedup=True,
                     hash_grain=grain,

@@ -15,8 +15,8 @@ cut, and a jitted train step that DONATES its state.
              ``bytes_limit``: >= 55% in use, state larger than free HBM):
              the fork cannot fit, so capture degrades leaf by leaf. A step
              the fork leaves no room for is reported, not retried.
-  programs   the device programs the library jits — batched fork, dim-0
-             chunk slices (streamed writes), on-device slab pack
+  programs   the device programs the library jits — batched fork,
+             on-device slab pack
              (``TORCHSNAPSHOT_TPU_ENABLE_BATCHING=1``) — fed every bit
              pattern of every sub-32-bit float, put from the host: denormals
              and NaN payloads a TPU computation never produces itself.
@@ -206,7 +206,6 @@ def preflight(args) -> dict:
         libtpu = "absent"
     log(f"[preflight] jax={jax.__version__} jaxlib={jaxlib.__version__} libtpu={libtpu}")
 
-    from torchsnapshot_tpu import stream_select
     from torchsnapshot_tpu.telemetry import fleet
     from torchsnapshot_tpu.utils import knobs
 
@@ -215,9 +214,6 @@ def preflight(args) -> dict:
         "usable_cpus": len(os.sched_getaffinity(0)),
         "dedup_digests": knobs.is_dedup_digests_enabled(),
         "restore_overlap": knobs.is_restore_overlap_enabled(True, {device["platform"]}),
-        "stream_writes": f"{knobs.get_stream_writes_mode()} -> "
-        f"{knobs.is_stream_writes_enabled()} before evidence "
-        f"(last decision: {stream_select.last_decision()})",
         "fleet_telemetry": f"{knobs.get_fleet_telemetry_mode()} -> {fleet.enabled()}",
         "d2h_lanes": knobs.get_d2h_lanes(),
         "hash_workers": knobs.get_hash_workers(),
@@ -582,7 +578,6 @@ def run_save_leg(ctx: dict, tag: str, depth: int, filled: bool) -> dict:
     log(
         f"[{tag}] write path: native {metrics.get('storage.fs.native_write_bytes', 0)} B, "
         f"fallback {metrics.get('storage.fs.native_fallback_bytes', 0)} B; "
-        f"streamed chunks {metrics.get('scheduler.stream_chunks', 0)}; "
         f"d2h {metrics.get('d2h.bytes', 0)} B"
     )
     check(forked + captured == len(want), f"[{tag}] {forked}+{captured} != {len(want)} leaves")
@@ -710,11 +705,7 @@ def run_programs_leg(ctx: dict) -> dict:
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict
-    from torchsnapshot_tpu.io_preparers.array import (
-        chunk_row_ranges,
-        copy_preserves_bits,
-        slice_preserves_bits,
-    )
+    from torchsnapshot_tpu.io_preparers.array import copy_preserves_bits
     from torchsnapshot_tpu.utils import knobs
 
     small_floats = [
@@ -793,11 +784,9 @@ def run_programs_leg(ctx: dict) -> dict:
         "bfloat16/float16/float8 pattern (denormals, NaN payloads) included"
     )
 
-    # (2) Big leaves: the fork, and the dim-0 chunk slices of streamed
-    # writes. Once with default knobs (whatever the stream selector has
-    # concluded by now), once with streaming forced on.
-    chunk = knobs.get_stream_chunk_bytes() if ctx["measured"] else 128 * 1024
-    nbytes = 2 * chunk  # the smallest object the scheduler streams
+    # (2) Big leaves, default knobs: the fork of an async take, and one
+    # whole transfer and write each (two hash grains and more).
+    nbytes = 2 * knobs.get_hash_chunk_bytes() if ctx["measured"] else 256 * 1024
     host = {
         f"big_{np.dtype(dt).name}": all_patterns(dt, (nbytes // np.dtype(dt).itemsize // 4096, 4096))
         for dt in small_floats
@@ -807,36 +796,20 @@ def run_programs_leg(ctx: dict) -> dict:
     ).view(np.float32)  # random bits: denormals and NaN payloads, twice the size
     state = put(host)
     total = sum(v.nbytes for v in host.values())
-    forced_on = [knobs.override_stream_writes(True)]
-    if not ctx["measured"]:
-        forced_on.append(knobs.override_stream_chunk_bytes(chunk))
-    for mode, overrides in (("take", []), ("async_take", forced_on)):
-        metrics = round_trip(f"programs_big_{mode}", mode, state, host, *overrides)
-        chunks = int(metrics.get("scheduler.stream_chunks", 0))
-        # A stream cuts its chunks where the leaf lives: on the device only
-        # for dtypes a device slice preserves, on the host for leaves an
-        # async take captured there.
-        streams = [
-            v for v in host.values()
-            if slice_preserves_bits(v.dtype)
-            or (mode == "async_take" and not copy_preserves_bits(v.dtype))
-        ]
-        expected = sum(len(chunk_row_ranges(v.shape, v.dtype.itemsize, chunk)) for v in streams)
-        log(
-            f"[programs] {mode} of {len(host)} big leaves / {total / 1e6:.0f} MB, stream "
-            f"writes {'forced on' if overrides else 'as resolved'}: {chunks} streamed "
-            f"chunks ({expected} when streaming: float32 cut on the device, "
-            f"host-captured leaves on the host, bfloat16 never cut)"
-        )
+    for mode in ("take", "async_take"):
+        metrics = round_trip(f"programs_big_{mode}", mode, state, host)
+        forked = int(metrics.get("capture.forked_leaves", 0))
+        log(f"[programs] {mode} of {len(host)} big leaves / {total / 1e6:.0f} MB: {forked} forked")
+        can_fork = sum(copy_preserves_bits(v.dtype) for v in host.values())
         check(
-            chunks == expected or (not overrides and chunks == 0),
-            f"[programs] {mode}: {chunks} streamed chunks, expected {expected}: a "
-            "small-float leaf was cut on the device, or nothing streamed",
+            forked == (can_fork if mode == "async_take" else 0),
+            f"[programs] {mode}: {forked} big leaves forked, expected {can_fork} "
+            "(float32, bfloat16) in an async take",
         )
-        out[f"stream_chunks_{mode}"] = chunks
+        out[f"big_leaves_forked_{mode}"] = forked
     free_tree(dict(state))
     log(
-        f"[programs] fork + chunk slices: {len(host)} leaves / {total / 1e6:.0f} MB "
+        f"[programs] fork + whole transfers: {len(host)} leaves / {total / 1e6:.0f} MB "
         "restore bit-exact, every small-float pattern included"
     )
     return out

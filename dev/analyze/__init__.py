@@ -9,12 +9,12 @@ Ten AST passes over the library, zero third-party dependencies:
    catalog, bidirectionally;
 4. telemetry-discipline (TSA4xx) — spans context-managed, names cataloged;
 5. manifest-schema (TSA5xx) — Entry fields stay JSON-serializable;
-6. resource-balance (TSA6xx) — flow-sensitive: every budget debit / lane
-   admission credited, handed off, or try/finally-protected on every path;
+6. resource-balance (TSA6xx) — flow-sensitive: every budget debit
+   credited, handed off, or try/finally-protected on every path;
 7. thread-safety (TSA7xx) — no unguarded attribute mutation shared between
    executor threads and the event loop;
-8. fault-coverage (TSA8xx) — every StoragePlugin/StorageWriteStream op
-   wrapped by FaultyStoragePlugin's injection map;
+8. fault-coverage (TSA8xx) — every StoragePlugin op wrapped by
+   FaultyStoragePlugin's injection map;
 9. collective-discipline (TSA9xx) — collective call sequences stay
    SPMD-pure: no collective behind rank/time/filesystem/exception-derived
    branches, none in except/finally handlers, none per-iteration of

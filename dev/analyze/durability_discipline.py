@@ -1,9 +1,8 @@
 """Pass 10 — durability discipline (TSA1001-TSA1004).
 
 The lifecycle layer's crash-consistency story rests on ordering rules no
-interpreter enforces: temp-write→``os.replace`` (or a
-``StorageWriteStream`` commit) is THE commit point for every durable
-object; the catalog record — the publish — lands only after
+interpreter enforces: temp-write→``os.replace`` is THE commit point for
+every durable object; the catalog record — the publish — lands only after
 ``.snapshot_metadata`` — the data commit; GC deletes only what a keep-set
 membership check excluded; and every commit point stays reachable by a
 ``faults.py`` kill-point so chaos schedules can crash exactly there. This
@@ -61,7 +60,7 @@ _OS_MUTATIONS = {
 }
 # Mutating methods of the StoragePlugin surface; a call through a receiver
 # whose name mentions storage/plugin is a plugin-routed durable mutation.
-_PLUGIN_MUTATIONS = {"write", "sync_write", "delete", "write_stream", "link_in"}
+_PLUGIN_MUTATIONS = {"write", "sync_write", "delete", "link_in"}
 _PLUGIN_RECEIVER_RE = re.compile(r"storage|plugin")
 
 # Files exempt from the TSA1004 inventory: the injection machinery itself

@@ -9,9 +9,9 @@ the op works in every chaos schedule because no schedule can touch it.
 This pass pins the wrapper to the contract:
 
 - **TSA801** — a public ``async`` method on the wrapped contract class
-  (``StoragePlugin`` / ``StorageWriteStream`` in ``io_types.py``) with no
-  override on its wrapper (``FaultyStoragePlugin`` / ``_FaultyWriteStream``)
-  — calls fall through to the inner plugin uninjected.
+  (``StoragePlugin`` in ``io_types.py``) with no override on its wrapper
+  (``FaultyStoragePlugin``) — calls fall through to the inner plugin
+  uninjected.
 - **TSA802** — a wrapper override that never routes through ``_guard`` and
   is not declared in ``faults.py``'s ``_PASSTHROUGH_OPS`` tuple (the
   reviewable allowlist for genuinely non-data-plane ops like ``close``).
@@ -30,7 +30,6 @@ from .core import AnalysisContext, Finding
 # (contract class in io_types, wrapper class in faults)
 _WRAP_PAIRS: Tuple[Tuple[str, str], ...] = (
     ("StoragePlugin", "FaultyStoragePlugin"),
-    ("StorageWriteStream", "_FaultyWriteStream"),
 )
 
 

@@ -1,24 +1,20 @@
 """Pass 6 — flow-sensitive resource balance (TSA601/TSA602).
 
 The memory-budget ledger is the invariant the whole pipeline design rests
-on: every ``budget.debit(...)`` (request admission, per-chunk streaming
-accounting) and every ``lanes.try_admit(...)`` (D2H look-ahead window
-reservation) must be matched — on EVERY path, including exception paths and
-early returns — by a credit/release, or handed to an owner that guarantees
-the release (the task tables ``_reap``/``_abort_inflight`` sweep, a
-look-ahead deque the stream's cleanup drains, an ``outstanding`` counter a
-``finally`` credits). The two bugs this class actually produced (PR 5:
-failed staging tasks kept their reservation; PR 6: aborted streams stranded
-lane-window admissions until a ``release_all`` sweep was added) were both
-invisible to the earlier passes — they are *flow* bugs, not call-shape bugs.
+on: every ``budget.debit(...)`` (request admission, estimate correction)
+must be matched — on EVERY path, including exception paths and early
+returns — by a credit, or handed to an owner that guarantees the release
+(the task tables ``_reap``/``_abort_inflight`` sweep, an ``outstanding``
+counter a ``finally`` credits). The bug this class actually produced (PR 5:
+failed staging tasks kept their reservation) was invisible to the earlier
+passes — a *flow* bug, not a call-shape bug.
 
 Each function containing an acquisition is walked with the
 :class:`~dev.analyze.core.FlowWalker` engine, tracking the set of open
 acquisitions per path. An acquisition is closed by:
 
-- a release call (``.credit(X)`` / ``.release(X)`` matches the acquisition
-  with the same amount expression, else the most recent one;
-  ``.release_all()`` closes every open window admission);
+- a release call (``.credit(X)`` matches the acquisition with the same
+  amount expression, else the most recent one);
 - a **handoff** that transfers ownership to a releasing owner: the amount
   (or a value it was derived from) is stored into a container
   (``tasks[t] = (req, cost, ...)``), appended/put onto one
@@ -44,9 +40,7 @@ from typing import Dict, List, Optional, Set, Tuple
 from .core import AnalysisContext, Finding, FlowWalker, dotted_name, iter_functions
 
 _ACQUIRE_DEBIT = "debit"
-_ACQUIRE_ADMIT = "try_admit"
-_RELEASES = ("credit", "release")
-_RELEASE_ALL = "release_all"
+_RELEASES = ("credit",)
 _HANDOFF_METHODS = {
     "append", "appendleft", "add", "put", "put_nowait", "extend",
 }
@@ -119,9 +113,6 @@ class _BalanceWalker(FlowWalker):
         return False
 
     def _close_release(self, state: Set[_Token], call: ast.Call) -> Set[_Token]:
-        attr = _last_attr(call)
-        if attr == _RELEASE_ALL:
-            return {t for t in state if t.kind != _ACQUIRE_ADMIT}
         amount = _amount_expr(call)
         dump = ast.dump(amount) if amount is not None else None
         exact = [t for t in state if dump is not None and t.amount_dump == dump]
@@ -176,32 +167,10 @@ class _BalanceWalker(FlowWalker):
             attr = _last_attr(node)
             if attr == _ACQUIRE_DEBIT:
                 out.add(_Token(_ACQUIRE_DEBIT, node))
-            elif attr == _ACQUIRE_ADMIT:
-                # Unconditional-form admission (the conditional `if not
-                # lanes.try_admit(...)` form is handled in branch()).
-                out.add(_Token(_ACQUIRE_ADMIT, node))
-            elif attr in _RELEASES or attr == _RELEASE_ALL:
+            elif attr in _RELEASES:
                 out = self._close_release(out, node)
         out = self._apply_handoffs(stmt, out)
         return frozenset(out)
-
-    def branch(self, test: ast.expr, state: frozenset):
-        # `if X.try_admit(...):` → admitted on the true side only;
-        # `if not X.try_admit(...):` → admitted on the FALSE side only
-        # (the true side typically breaks/returns without a reservation).
-        call, negated = None, False
-        expr = test
-        if isinstance(expr, ast.UnaryOp) and isinstance(expr.op, ast.Not):
-            negated = True
-            expr = expr.operand
-        if isinstance(expr, ast.Call) and _last_attr(expr) == _ACQUIRE_ADMIT:
-            call = expr
-        if call is None:
-            return {state}, {state}
-        admitted = frozenset(set(state) | {_Token(_ACQUIRE_ADMIT, call)})
-        if negated:
-            return {state}, {admitted}
-        return {admitted}, {state}
 
     def try_protects(self, trystmt: ast.Try) -> bool:
         bodies = list(trystmt.finalbody)
@@ -213,7 +182,7 @@ class _BalanceWalker(FlowWalker):
                     break
                 if isinstance(node, ast.Call):
                     attr = _last_attr(node)
-                    if attr in _RELEASES or attr == _RELEASE_ALL:
+                    if attr in _RELEASES:
                         return True
         return False
 
@@ -226,11 +195,6 @@ class _BalanceWalker(FlowWalker):
         return False
 
     # -- reporting ----------------------------------------------------------
-    def _verb(self, token: _Token) -> str:
-        if token.kind == _ACQUIRE_ADMIT:
-            return "window admission (try_admit)"
-        return "budget debit"
-
     def _report(self, code: str, line: int, token: _Token, why: str) -> None:
         key = (token.line, code)
         if key in self.findings:
@@ -240,7 +204,7 @@ class _BalanceWalker(FlowWalker):
             line=token.line,
             code=code,
             message=(
-                f"{self._verb(token)} in `{self.fn.name}` (line {token.line}) "
+                f"budget debit in `{self.fn.name}` (line {token.line}) "
                 f"{why} — credit/release it, protect it with a try/finally, "
                 "or hand it to an owning container/counter that releases it"
             ),
@@ -315,7 +279,7 @@ def run(ctx: AnalysisContext) -> List[Finding]:
         for fn in iter_functions(tree):
             has = any(
                 isinstance(n, ast.Call)
-                and _last_attr(n) in (_ACQUIRE_DEBIT, _ACQUIRE_ADMIT)
+                and _last_attr(n) == _ACQUIRE_DEBIT
                 for n in _own_body_nodes(fn)
             )
             if not has:

@@ -27,12 +27,9 @@ leaves an unrestorable state".
 
 Replay model (matches the fs backend's crash window, and is conservative
 for atomic backends): ``write``/``link`` materialize the final object
-whole; ``stream_open`` creates a ``*.tmp.*`` temp file; ``append`` grows
-it; ``commit`` renames it over the final path; ``abort``/``delete``
-remove. Interior samples (seeded, deterministic) cut an in-flight payload
-at a byte boundary and land the partial bytes where a real crash would:
-appended to the stream temp file, or as ``*.tmp.*`` debris for an atomic
-write — never at the final path.
+whole; ``delete`` removes. Interior samples (seeded, deterministic) cut
+an in-flight payload at a byte boundary and land the partial bytes where
+a real crash would: as ``*.tmp.*`` debris — never at the final path.
 
 The journal records origins (plugin roots) from any backend; replay always
 targets the local filesystem, so a journal captured against ``memory://``
@@ -50,7 +47,7 @@ import os
 import random
 import shutil
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Callable, List, Optional, Sequence, Set, Tuple
 
 # Knobs that would make the *checks* (verify/gc, which build their own
 # storage plugins) observe something other than the replayed bytes.
@@ -150,8 +147,6 @@ class _ReplayState:
         self.root = root
         self.base = base
         os.makedirs(root, exist_ok=True)
-        # stream_id -> (final abs path, temp abs path)
-        self.streams: Dict[int, Tuple[str, str]] = {}
         # Mapped abs targets of every applied delete, for the zombie
         # exemption in invariant B.
         self.deleted: Set[str] = set()
@@ -170,23 +165,6 @@ class _ReplayState:
         abs_path = self.map_path(effect.origin, effect.path)
         if effect.op in ("write", "link"):
             self._materialize(abs_path, effect.payload)
-        elif effect.op == "stream_open":
-            tmp = f"{abs_path}.tmp.replay{effect.stream_id}"
-            self._materialize(tmp, b"")  # fs opens the temp file eagerly
-            self.streams[effect.stream_id] = (abs_path, tmp)
-        elif effect.op == "append":
-            entry = self.streams.get(effect.stream_id)
-            if entry is not None:
-                with open(entry[1], "ab") as f:
-                    f.write(effect.payload or b"")
-        elif effect.op == "commit":
-            entry = self.streams.pop(effect.stream_id, None)
-            if entry is not None and os.path.exists(entry[1]):
-                os.replace(entry[1], entry[0])
-        elif effect.op == "abort":
-            entry = self.streams.pop(effect.stream_id, None)
-            if entry is not None and os.path.exists(entry[1]):
-                os.remove(entry[1])
         elif effect.op == "delete":
             self.deleted.add(abs_path)
             if os.path.isfile(abs_path):
@@ -197,12 +175,7 @@ class _ReplayState:
         real crash would leave them (see module docstring)."""
         partial = (effect.payload or b"")[:cut]
         abs_path = self.map_path(effect.origin, effect.path)
-        if effect.op == "append":
-            entry = self.streams.get(effect.stream_id)
-            if entry is not None:
-                with open(entry[1], "ab") as f:
-                    f.write(partial)
-        elif effect.op in ("write", "link"):
+        if effect.op in ("write", "link"):
             self._materialize(f"{abs_path}.tmp.partial", partial)
 
 
@@ -361,7 +334,7 @@ def _interior_plan(effects, seed: int, interior_samples: int):
     candidates = [
         i
         for i, e in enumerate(effects)
-        if e.op in ("write", "append", "link") and e.nbytes > 1
+        if e.op in ("write", "link") and e.nbytes > 1
     ]
     picked = sorted(rng.sample(candidates, min(interior_samples, len(candidates))))
     return [(i, rng.randrange(1, effects[i].nbytes)) for i in picked]
@@ -420,16 +393,6 @@ def explore(
                 shutil.copytree(state_dir, partial_dir)
                 pstate = _ReplayState(partial_dir, base)
                 pstate.deleted = set(state.deleted)
-
-                def _reroot(p: str) -> str:
-                    return os.path.join(
-                        partial_dir, os.path.relpath(p, state_dir)
-                    )
-
-                pstate.streams = {
-                    sid: (_reroot(final), _reroot(tmp))
-                    for sid, (final, tmp) in state.streams.items()
-                }
                 pstate.apply_partial(effect, cut)
                 interior = f"{cut}/{effect.nbytes} bytes"
                 report.interior_samples += 1

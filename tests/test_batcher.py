@@ -335,9 +335,14 @@ def test_read_merge_respects_budget_cap() -> None:
     assert batch_read_requests(list(big), max_merged_bytes=250)[0].byte_range == (0, 1000)
 
 
-def test_batched_take_restore_with_streamed_slabs(tmp_path) -> None:
-    """Slabs routed through the streaming write path (slab cost above the
-    stream threshold) land as single objects and restore bit-exact."""
+def test_batched_take_restore_with_slabs_above_the_hash_grain(tmp_path) -> None:
+    """Slabs several hash grains long land as single objects whose sidecar
+    tree records equal an independent recompute, and restore bit-exact."""
+    import json
+    import os
+
+    from torchsnapshot_tpu import hashing
+
     rng = np.random.default_rng(2)
     sd = StateDict(
         **{f"p{i}": rng.standard_normal((7, 5)).astype(np.float32) for i in range(20)}
@@ -346,9 +351,7 @@ def test_batched_take_restore_with_streamed_slabs(tmp_path) -> None:
     path = str(tmp_path / "ckpt")
     with knobs.override_batching_enabled(True), \
             knobs.override_slab_size_threshold_bytes(400), \
-            knobs.override_stream_writes(True), \
-            knobs.override_stream_chunk_bytes(128), \
-            knobs.override_stream_inflight(2):
+            knobs.override_hash_chunk_bytes(128):
         snap = Snapshot.take(path, {"s": sd})
         out = StateDict()
         Snapshot(path).restore({"s": out})
@@ -361,3 +364,13 @@ def test_batched_take_restore_with_streamed_slabs(tmp_path) -> None:
     ]
     assert len(slabbed) == 20
     assert Snapshot(path).verify() == {}
+    sidecar = json.load(open(os.path.join(path, ".checksums.0")))
+    slabs = {e.location for e in slabbed}
+    assert len(slabs) > 1
+    for location in slabs:
+        stored = open(os.path.join(path, location), "rb").read()
+        rec = sidecar[location]
+        assert hashing.is_v2_record(rec) and len(rec["crcs"]) > 2
+        assert rec == hashing.digest_of_bytes(
+            stored, 128, want_sha=bool(hashing.record_content_keys(rec))
+        )

@@ -6,8 +6,8 @@ failure, via ``TORCHSNAPSHOT_TPU_FAULTS`` (``faults.py``):
 
 - **atomic commit** — a torn take never exposes ``.snapshot_metadata``; a
   previously committed snapshot restores bit-exact afterwards;
-- **abort-leaves-nothing streams** — aborted/mid-failed write streams leave
-  no visible object (and on fs, their temp files are unlinked);
+- **failed-write-leaves-nothing** — a write that fails leaves no visible
+  object (and on fs, only a torn write's temp file, which gc reclaims);
 - **structured abort** — failures surface as ``CheckpointAbortedError``
   naming the failing rank and phase, on every rank, within the barrier
   timeout; the scheduler's memory budget is fully credited back;
@@ -45,6 +45,7 @@ from torchsnapshot_tpu.faults import (
     KILL_EXIT_CODE,
     FaultSpecError,
     FaultyStoragePlugin,
+    InjectedFault,
     parse_fault_spec,
 )
 from torchsnapshot_tpu.io_types import ReadIO, WriteIO
@@ -224,7 +225,7 @@ def test_fault_spec_parses_full_grammar() -> None:
     plan = parse_fault_spec(
         "seed=42;backoff=0.01;window=3.5;"
         "op=write,at=2,kind=torn,bytes=128;"
-        "op=append,kind=transient,times=3,rank=1;"
+        "op=delete,kind=transient,times=3,rank=1;"
         "op=read,p=0.25,kind=stall,secs=0.5,path=.snapshot_metadata"
     )
     assert plan.seed == 42 and plan.backoff_s == 0.01 and plan.window_s == 3.5
@@ -241,7 +242,8 @@ def test_fault_spec_parses_full_grammar() -> None:
         "op=write,kind=banana",
         "op=teleport,kind=fail",
         "op=write,kind=fail,whatever=1",
-        "op=read,kind=torn,bytes=4",  # torn is write/append-only
+        "op=read,kind=torn,bytes=4",  # torn is write-only
+        "op=append,kind=fail",  # no stream ops: a write is one op
         "op=write,kind=fail,at=x",
         "notakeyvalue",
         "seed=1,window=bad",
@@ -273,19 +275,16 @@ def test_fault_schedule_is_deterministic() -> None:
 
 def test_unfaulted_ops_pass_through(tmp_path) -> None:
     """A spec matching nothing is fully transparent — writes, reads,
-    streams, listing all behave identically to the bare plugin."""
+    listing all behave identically to the bare plugin."""
     plugin = FaultyStoragePlugin(
         _resolve_storage_plugin(str(tmp_path)),
         parse_fault_spec("op=delete,at=999,kind=fail"),
     )
-    assert plugin.supports_streaming and plugin.scales_io_with_local_world
+    assert plugin.scales_io_with_local_world
 
     async def roundtrip():
         await plugin.write(WriteIO(path="a/b", buf=b"hello"))
-        stream = await plugin.write_stream("a/c")
-        await stream.append(b"wor")
-        await stream.append(b"ld")
-        await stream.commit()
+        await plugin.write(WriteIO(path="a/c", buf=b"world"))
         read_io = ReadIO(path="a/c")
         await plugin.read(read_io)
         assert read_io.buf.getvalue() == b"world"
@@ -368,20 +367,33 @@ def test_chaos_commit_phase_failure(tmp_path) -> None:
     assert e.phase == "commit", e
 
 
-def test_chaos_torn_fs_stream_abort_unlinks_temp(tmp_path) -> None:
-    """A torn APPEND mid-stream: the scheduler aborts the storage stream and
-    the fs plugin's abort must unlink its temp file (satellite: error paths
-    of write_stream leave no partial files behind)."""
-    url = str(tmp_path / "t")
-    big = np.random.default_rng(0).standard_normal(2**16).astype(np.float32)
-    with knobs.override_stream_writes(True), knobs.override_stream_chunk_bytes(
-        4096
-    ):
-        with knobs.override_faults("op=append,at=2,kind=torn,bytes=100"):
-            with pytest.raises(CheckpointAbortedError):
-                Snapshot.take(url, {"s": StateDict(w=big)})
-    assert glob.glob(str(tmp_path / "t" / "**" / "*.tmp.*"), recursive=True) == []
-    assert not os.path.exists(os.path.join(url, ".snapshot_metadata"))
+@pytest.mark.parametrize("backend", ["fs", "memory"])
+def test_chaos_torn_write_leaves_prefix_debris_on_fs_only(tmp_path, backend) -> None:
+    """The torn-write rule: on fs a ``.tmp.`` file holding the first
+    ``bytes`` bytes and no object, which gc collects; on an atomic backend
+    nothing at all."""
+    url = str(tmp_path) if backend == "fs" else "memory://torn-rule"
+    plugin = FaultyStoragePlugin(
+        _resolve_storage_plugin(url),
+        parse_fault_spec("op=write,at=1,kind=torn,bytes=100"),
+    )
+    payload = bytes(range(256)) * 40
+
+    async def go():
+        await plugin.write(WriteIO(path="0/kept", buf=payload))
+        with pytest.raises(InjectedFault, match="torn write after 100 bytes"):
+            await plugin.write(WriteIO(path="0/torn", buf=payload))
+        return await plugin.list_prefix("")
+
+    listed = _run(go())
+    if backend == "memory":
+        assert listed == ["0/kept"]
+        return
+    (debris,) = [p for p in listed if p != "0/kept"]
+    assert debris.startswith("0/torn.tmp.")
+    assert open(tmp_path / debris, "rb").read() == payload[:100]
+    Snapshot.gc(str(tmp_path), dry_run=False)
+    assert glob.glob(str(tmp_path / "**" / "*.tmp.*"), recursive=True) == []
 
 
 def test_chaos_budget_credited_on_abort(tmp_path) -> None:
@@ -615,11 +627,6 @@ _ABORT_SCHEDULES = [
     "op=write,kind=fail,path=.checksums",
     "op=write,kind=fail,path=.snapshot_metadata",
     "op=write,at=1,kind=fail",
-    # Stream-path failures (stream writes force the chunked path).
-    "op=stream_open,kind=fail",
-    "op=append,at=1,kind=fail",
-    "op=append,at=3,kind=torn,bytes=100",
-    "op=commit,kind=fail",
     # Seeded probabilistic storms that eventually fail permanently.
     "seed=3;op=write,p=0.6,kind=fail",
     "seed=9;op=write,p=0.6,kind=fail",
@@ -641,14 +648,7 @@ _RESILIENT_SCHEDULES = [
 @pytest.mark.parametrize("spec", _ABORT_SCHEDULES)
 @pytest.mark.parametrize("any_backend", ["fs", "memory", "gcs"], indirect=True)
 def test_chaos_matrix_aborting_schedules(any_backend, spec) -> None:
-    needs_streams = "append" in spec or "commit" in spec or "stream" in spec
-    if needs_streams:
-        with knobs.override_stream_writes(True), knobs.override_stream_chunk_bytes(
-            512
-        ):
-            _chaos_round(any_backend, spec)
-    else:
-        _chaos_round(any_backend, spec)
+    _chaos_round(any_backend, spec)
 
 
 @pytest.mark.slow

@@ -1,10 +1,8 @@
 """Parallel D2H lanes + zero-copy RAW staging + stage-time attribution.
 
-The PR-6 staging saturation work: TransferLanes window accounting, the
-lane-driven chunk stream's bit-exactness against the whole-buffer path
-(payload, ``.ftab``, sidecar digests) across dtypes and layouts, the
-budget high-water bound with look-ahead in flight, abort-path budget
-balance, and the ``stage.d2h``/``stage.serialize``/``stage.hash``
+The PR-6 staging saturation work: the zero-copy RAW path's bit-exactness
+(payload, ``.ftab``, sidecar digests) across dtypes and layouts,
+abort-path budget balance with lanes in flight, and the ``stage.d2h``/``stage.serialize``/``stage.hash``
 decomposition in drain stats and persisted telemetry artifacts.
 """
 
@@ -29,8 +27,8 @@ except ImportError:  # pragma: no cover - ships with jax
 
 @pytest.fixture(autouse=True)
 def _debug_ledger():
-    """Lane-window accounting runs under the budget-ledger sanitizer:
-    close/abort assert zero outstanding bytes with site attribution."""
+    """The suite runs under the budget-ledger sanitizer: close/abort
+    assert zero outstanding bytes with site attribution."""
     with knobs.override_debug_ledger(True):
         yield
 
@@ -46,51 +44,11 @@ def _run(coro):
 # ------------------------------------------------------------ TransferLanes
 
 
-def test_lane_window_admission_and_release() -> None:
-    lanes = d2h.TransferLanes(lanes=2, window_bytes=100)
-    debits, credits = [], []
-    lanes.bind_budget(debits.append, credits.append, headroom=lambda: 10**9)
-    assert lanes.try_admit(60)
-    assert lanes.try_admit(40)
-    assert not lanes.try_admit(1)  # window full
-    assert lanes.try_admit(50, force=True)  # forced over-admission
-    assert lanes.outstanding_bytes == 150
-    assert lanes.window_hwm == 150
-    lanes.release(60)
-    assert lanes.try_admit(10)
-    lanes.release(40)
-    lanes.release(50)
-    lanes.release(10)
-    assert lanes.outstanding_bytes == 0
-    assert sum(debits) == sum(credits) == 160  # budget saw every byte
-
-
-def test_lane_window_respects_budget_headroom() -> None:
-    lanes = d2h.TransferLanes(lanes=1, window_bytes=10**9)
-    lanes.bind_budget(lambda n: None, lambda n: None, headroom=lambda: 50)
-    assert not lanes.try_admit(100)  # window huge, but no budget headroom
-    assert lanes.try_admit(100, force=True)  # first-chunk escape hatch
-    assert lanes.release_all() == 100
-
-
-def test_lane_release_all_sweeps_outstanding() -> None:
-    lanes = d2h.TransferLanes(lanes=1, window_bytes=1000)
-    credited = []
-    lanes.bind_budget(lambda n: None, credited.append)
-    lanes.try_admit(300)
-    lanes.try_admit(200)
-    assert lanes.release_all() == 500
-    assert credited == [500]
-    assert lanes.release_all() == 0  # idempotent
-
-
 def test_d2h_knobs() -> None:
     assert knobs.get_d2h_lanes() >= 1
-    assert knobs.get_d2h_window_bytes() >= 0
     with knobs.override_d2h_lanes(7):
         assert knobs.get_d2h_lanes() == 7
-    with knobs.override_d2h_window_bytes(4096):
-        assert knobs.get_d2h_window_bytes() == 4096
+        assert d2h.TransferLanes().lane_count == 7
 
 
 # --------------------------------------------------- zero-copy RAW staging
@@ -116,76 +74,61 @@ def _dtype_cases():
 
 @pytest.mark.parametrize("dtype", _dtype_cases(), ids=lambda d: d.name)
 @pytest.mark.parametrize("contiguous", [True, False])
-def test_zero_copy_raw_bit_exact_vs_whole_buffer(dtype, contiguous) -> None:
-    """The streamed zero-copy RAW path and the whole-buffer path produce
-    byte-identical objects and sidecar digests for every RAW dtype, from
-    contiguous AND non-contiguous sources."""
+def test_zero_copy_raw_object_and_sidecar_bit_exact(dtype, contiguous) -> None:
+    """The zero-copy RAW path writes the array's C-order bytes and a
+    sidecar digest equal to an independent recompute, for every RAW dtype,
+    from contiguous AND non-contiguous sources."""
+    from torchsnapshot_tpu import hashing
+
     rng = np.random.default_rng(7)
     base = rng.integers(0, 7, size=(64, 48)).astype(dtype)
     arr = base if contiguous else base.T.copy().T  # F-order, same values
     if not contiguous:
         assert not arr.flags["C_CONTIGUOUS"]
+    storage = MemoryStoragePlugin()
+    _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
 
-    def take(stream: bool):
-        storage = MemoryStoragePlugin()
+    async def go():
+        with knobs.override_hash_chunk_bytes(1024), \
+                knobs.override_dedup_digests(True):
+            pending = await execute_write_reqs(
+                reqs, storage, memory_budget_bytes=10**9, rank=0
+            )
+            await pending.complete()
+
+    _run(go())
+    expected = np.ascontiguousarray(arr).view(np.uint8).tobytes()
+    assert storage.objects["obj"] == expected
+    rec = json.loads(storage.objects[".checksums.0"])["obj"]
+    assert hashing.is_v2_record(rec) and rec["grain"] == 1024
+    assert hashing.record_crc(rec) == zlib.crc32(expected)
+    assert rec == hashing.digest_of_bytes(expected, 1024)
+
+
+def test_framed_compressed_payload_and_ftab_match_codec() -> None:
+    """A framed-zlib entry's payload is the codec's own framed output of
+    the array's bytes, and the ``.ftab`` side object lists its frames."""
+    from torchsnapshot_tpu.serialization import Serializer, compress_framed
+
+    arr = (np.arange(96 * 64, dtype=np.float32) % 17).reshape(96, 64)
+    storage = MemoryStoragePlugin()
+    with knobs.override_compression("zlib"), \
+            knobs.override_compression_frame_bytes(4096):
         _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
+        level = reqs[0].buffer_stager.compression_level
 
         async def go():
-            with knobs.override_stream_writes(stream), \
-                    knobs.override_stream_chunk_bytes(1024), \
-                    knobs.override_dedup_digests(True):
-                pending = await execute_write_reqs(
-                    reqs, storage, memory_budget_bytes=10**9, rank=0
-                )
-                await pending.complete()
+            pending = await execute_write_reqs(
+                reqs, storage, memory_budget_bytes=10**9, rank=0
+            )
+            await pending.complete()
 
         _run(go())
-        return storage.objects
-
-    whole = take(stream=False)
-    streamed = take(stream=True)
-    assert whole.keys() == streamed.keys()
-    assert whole["obj"] == streamed["obj"]
-    # Sidecar digests match between the paths and match an independent
-    # whole-object recompute: identical v2 tree records (combined crc32
-    # bit-identical to the serial fold, root over the per-chunk sha256s).
-    from torchsnapshot_tpu import hashing
-
-    wc, sc = (json.loads(side[".checksums.0"]) for side in (whole, streamed))
-    assert wc == sc
-    rec = wc["obj"]
-    assert hashing.record_crc(rec) == zlib.crc32(whole["obj"])
-    assert hashing.record_size(rec) == len(whole["obj"])
-    grain = rec["grain"] if hashing.is_v2_record(rec) else 0
-    assert rec == hashing.digest_of_bytes(whole["obj"], grain)
-
-
-def test_zero_copy_framed_compressed_bit_exact_with_ftab() -> None:
-    """Framed-zlib entries stream bit-exactly too: payload AND the ``.ftab``
-    side object equal the whole-buffer path's."""
-    arr = (np.arange(96 * 64, dtype=np.float32) % 17).reshape(96, 64)
-
-    def take(stream: bool):
-        storage = MemoryStoragePlugin()
-        with knobs.override_compression("zlib"), \
-                knobs.override_compression_frame_bytes(4096):
-            _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
-
-            async def go():
-                with knobs.override_stream_writes(stream), \
-                        knobs.override_stream_chunk_bytes(2048):
-                    pending = await execute_write_reqs(
-                        reqs, storage, memory_budget_bytes=10**9, rank=0
-                    )
-                    await pending.complete()
-
-            _run(go())
-        return storage.objects
-
-    whole = take(stream=False)
-    streamed = take(stream=True)
-    assert whole["obj"] == streamed["obj"]
-    assert json.loads(whole["obj.ftab"]) == json.loads(streamed["obj.ftab"])
+    payload, sizes = compress_framed(
+        arr.tobytes(), Serializer.RAW_ZLIB, level, 4096
+    )
+    assert storage.objects["obj"] == bytes(payload)
+    assert json.loads(storage.objects["obj.ftab"])["sizes"] == list(sizes)
 
 
 # ------------------------------------------ lanes through the write pipeline
@@ -200,119 +143,44 @@ def _jax_app(rows=512, cols=256, seed=0):
     return arr
 
 
-def test_lane_streamed_jax_take_bit_exact_and_window_used() -> None:
-    """A jax array streamed under the lanes lands bit-exact against the
-    lane-less whole-buffer path, and the look-ahead window actually
-    engaged (transfers resolved ahead of consumption)."""
-    arr = _jax_app()
-    expected = np.asarray(arr).tobytes()
-
-    storage = MemoryStoragePlugin()
-    _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
-
-    async def go():
-        await pipeline.run_until_staged()
-        await pipeline.run_to_completion()
-
-    # Knobs (incl. the lane window) resolve at pipeline construction.
-    with knobs.override_stream_writes(True), \
-            knobs.override_stream_chunk_bytes(64 * 1024), \
-            knobs.override_d2h_window_bytes(128 * 1024):
-        pipeline = _WritePipeline(
-            reqs, storage, memory_budget_bytes=10**9, rank=0
-        )
-        _run(go())
-    assert storage.objects["obj"] == expected
-    # The stream released everything it admitted; look-ahead happened.
-    lanes = pipeline._staging_ctx.lanes
-    assert lanes.outstanding_bytes == 0
-    assert lanes.window_hwm > 0
-    assert pipeline.budget_balanced
-
-
-def test_budget_hwm_bounded_by_window_plus_stream_depth() -> None:
-    """With lanes in flight, the budget high-water mark stays ~(window +
-    stream depth) — far below the array's full size."""
-    chunk = 16 * 1024
-    inflight = 2
-    window = 2 * chunk
-    arr = _jax_app(rows=2048, cols=256)  # 2 MB >> the bound below
-
-    storage = MemoryStoragePlugin()
-    _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
-
-    async def go():
-        await pipeline.run_until_staged()
-        await pipeline.run_to_completion()
-
-    with knobs.override_stream_writes(True), \
-            knobs.override_stream_chunk_bytes(chunk), \
-            knobs.override_stream_inflight(inflight), \
-            knobs.override_d2h_window_bytes(window), \
-            knobs.override_d2h_lanes(2):
-        pipeline = _WritePipeline(
-            reqs, storage, memory_budget_bytes=10**9, rank=0
-        )
-        _run(go())
-    full = np.asarray(arr).nbytes
-    # window (look-ahead) + inflight chunks queued + the chunk being staged
-    # + the chunk being appended + estimate drift.
-    bound = window + (inflight + 3) * chunk
-    assert pipeline.budget.high_water_bytes <= bound, (
-        pipeline.budget.high_water_bytes, bound
-    )
-    assert pipeline.budget.high_water_bytes < full // 4
-    assert pipeline.budget_balanced
-    assert storage.objects["obj"] == np.asarray(arr).tobytes()
-
-
 def test_mid_drain_abort_with_lanes_in_flight_credits_every_debit() -> None:
-    """A storage append that explodes mid-stream, with lane look-ahead in
-    flight: the failure propagates, no partial object remains, and every
-    budget debit — per-chunk stream debits AND lane-window admissions — is
-    credited back."""
+    """A storage write that explodes mid-drain, with other leaves' D2H
+    resolving on the lanes: the failure propagates, the failed object is
+    absent, and every budget debit is credited back."""
 
-    class FailingAppendStorage(MemoryStoragePlugin):
-        async def write_stream(self, path):
-            inner = await super().write_stream(path)
+    class FailingWriteStorage(MemoryStoragePlugin):
+        written = 0
 
-            class _Failing:
-                appended = 0
+        async def write(self, write_io):
+            FailingWriteStorage.written += 1
+            if FailingWriteStorage.written > 2:
+                raise OSError("write exploded")
+            await super().write(write_io)
 
-                async def append(self, buf):
-                    _Failing.appended += 1
-                    if _Failing.appended > 2:
-                        raise OSError("append exploded")
-                    await inner.append(buf)
-
-                async def commit(self):
-                    await inner.commit()
-
-                async def abort(self):
-                    await inner.abort()
-
-            return _Failing()
-
-    arr = _jax_app(rows=1024, cols=256)
-    storage = FailingAppendStorage()
-    _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
+    storage = FailingWriteStorage()
+    reqs = []
+    for i in range(8):
+        _entry, leaf_reqs = ArrayIOPreparer.prepare_write(
+            f"obj{i}", _jax_app(rows=256, cols=256, seed=i)
+        )
+        reqs.extend(leaf_reqs)
 
     async def go():
-        await asyncio.wait_for(pipeline.run_until_staged(), timeout=30)
+        await pipeline.run_until_staged()
+        await asyncio.wait_for(pipeline.run_to_completion(), timeout=30)
 
-    with knobs.override_stream_writes(True), \
-            knobs.override_stream_chunk_bytes(16 * 1024), \
-            knobs.override_d2h_window_bytes(64 * 1024):
+    with knobs.override_d2h_lanes(4):
+        # Half the budget: later leaves are admitted (and hinted onto the
+        # lanes) while earlier ones write.
         pipeline = _WritePipeline(
-            reqs, storage, memory_budget_bytes=10**9, rank=0
+            reqs, storage, memory_budget_bytes=4 * 256 * 256 * 4, rank=0
         )
-        with pytest.raises(OSError, match="append exploded"):
+        with pytest.raises(OSError, match="write exploded"):
             _run(go())
-    assert "obj" not in storage.objects
+    assert len([k for k in storage.objects if k.startswith("obj")]) == 2
     assert pipeline.budget_balanced, (
         pipeline.budget.available, pipeline.budget.total
     )
-    assert pipeline._staging_ctx.lanes.outstanding_bytes == 0
 
 
 # --------------------------------------------------- stage-time attribution
@@ -396,8 +264,8 @@ def test_dedup_digests_off_skips_sha_and_shrinks_hash_stream(tmp_path) -> None:
 
 def test_stager_outside_pipeline_still_works_without_context() -> None:
     """Driven without an active StagingContext (no pipeline), the stager
-    falls back to the legacy hint chain — no lanes, no recording, same
-    bytes."""
+    resolves its transfer on the caller's executor — no lanes, no
+    recording, same bytes."""
     import jax
     import jax.numpy as jnp
 
@@ -405,15 +273,8 @@ def test_stager_outside_pipeline_still_works_without_context() -> None:
     _entry, reqs = ArrayIOPreparer.prepare_write("obj", arr)
     stager = reqs[0].buffer_stager
 
-    async def collect():
+    async def stage():
         assert d2h.get_active() is None
-        chunks = []
-        with knobs.override_stream_chunk_bytes(2048):
-            async for c in stager.stage_chunks():
-                chunks.append(bytes(c))
-        return b"".join(chunks)
+        return bytes(await stager.stage_buffer())
 
-    with knobs.override_stream_chunk_bytes(2048):
-        assert stager.can_stream()
-    data = _run(collect())
-    assert data == np.asarray(arr).tobytes()
+    assert _run(stage()) == np.asarray(arr).tobytes()

@@ -4,9 +4,11 @@ their bits.
 On the TPU a slice or bitcast of bfloat16 flushes denormals, and a copy,
 slice or bitcast of float16 / float8 rewrites NaN payloads (``chip_smoke.py``
 checks every bit pattern there). The CPU backend is exact throughout, so
-what this suite can pin is the ROUTING: which leaves stream, chunk,
-subdivide, device-pack and fork — by dtype alone, on every backend.
+what this suite can pin is the ROUTING: which leaves chunk, subdivide,
+device-pack and fork — by dtype alone, on every backend.
 """
+
+import os
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,6 @@ import pytest
 
 from torchsnapshot_tpu import Snapshot, StateDict
 from torchsnapshot_tpu.io_preparers.array import (
-    chunk_row_ranges,
     copy_preserves_bits,
     slice_preserves_bits,
 )
@@ -75,11 +76,11 @@ def test_small_float_arrays_are_not_chunked_or_subdivided() -> None:
 
 @pytest.mark.parametrize("mode", ["take", "async_take"])
 def test_every_bit_pattern_round_trips_and_says_which_path(tmp_path, mode) -> None:
-    """Streaming forced on, slab batching on, every pattern of every small
-    float put from the host: the restore is bit-exact, no small float was
-    cut on the device, float16/float8 were host-captured instead of forked,
-    and small-float slabs were packed on the host."""
-    rows = 512  # 128 KiB per 1-byte leaf: above the stream floor set below
+    """Slab batching on, every pattern of every small float put from the
+    host: the restore is bit-exact, every big leaf is one object of its own
+    bytes, float16/float8 were host-captured instead of forked, and
+    small-float slabs were packed on the host."""
+    rows = 512  # 128 KiB per 1-byte leaf: four hash grains set below
     host = {f"big_{np.dtype(dt).name}": all_patterns(dt, rows) for dt in SMALL_FLOATS}
     rng = np.random.default_rng(0)
     # Random BITS as float32: denormals and NaN payloads in a dtype every
@@ -91,9 +92,8 @@ def test_every_bit_pattern_round_trips_and_says_which_path(tmp_path, mode) -> No
             host[f"small_{np.dtype(dt).name}_{i}"] = bits.view(dt)
     state = StateDict(**{k: jax.device_put(v) for k, v in host.items()})
     path = str(tmp_path / mode)
-    chunk = 32 * 1024
-    with knobs.override_stream_writes(True), knobs.override_stream_chunk_bytes(
-        chunk
+    with knobs.override_hash_chunk_bytes(
+        32 * 1024
     ), knobs.override_batching_enabled(True), knobs.override_slab_size_threshold_bytes(
         4096
     ):
@@ -103,22 +103,11 @@ def test_every_bit_pattern_round_trips_and_says_which_path(tmp_path, mode) -> No
             Snapshot.async_take(path, {"s": state}).wait()
     metrics = Snapshot.last_telemetry.metrics.as_dict()
 
-    # Streams cut their chunks where the leaf lives: the float32 leaf on the
-    # device, and — async only — the host-captured leaves on the host.
-    # A small float still on the device (all of them in a sync take,
-    # bfloat16's fork in an async one) must not stream.
-    streams = [
-        v
-        for k, v in host.items()
-        if k.startswith("big_")
-        and (
-            v.dtype == np.float32
-            or (mode == "async_take" and not copy_preserves_bits(v.dtype))
-        )
-    ]
-    assert metrics["scheduler.stream_chunks"] == sum(
-        len(chunk_row_ranges(v.shape, v.dtype.itemsize, chunk)) for v in streams
-    ), "a small-float leaf went through device chunk slices"
+    # Each big leaf reached the host whole and is one object of its bytes.
+    for k, v in host.items():
+        if k.startswith("big_"):
+            with open(os.path.join(path, "0", "s", k), "rb") as f:
+                assert f.read() == v.tobytes(), k
     assert metrics["batcher.slabs_device_packed"] >= 1  # float32 + int8 members
     assert metrics["batcher.slabs_host_packed"] >= 1
     if mode == "async_take":

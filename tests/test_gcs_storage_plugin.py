@@ -729,18 +729,22 @@ def test_emulator_small_object_multipart_roundtrip(gcs_emulator) -> None:
         loop.close()
 
 
-def test_emulator_resumable_upload_survives_chunk_fault(gcs_emulator) -> None:
+@pytest.mark.parametrize("chunk_kib", [256, 768], ids=["8-chunks", "short-tail"])
+def test_emulator_resumable_upload_survives_chunk_fault(
+    gcs_emulator, chunk_kib
+) -> None:
     """A 503 on one chunk PUT is absorbed by the stack (google-resumable-
     media's internal retry re-sends the chunk over the real wire; the
     plugin's cursor recovery is the second line of defense for faults that
-    escape it) and the upload completes byte-exact."""
+    escape it) and the upload completes byte-exact: one object, whichever
+    chunk size the plugin cuts a large write into."""
     from torchsnapshot_tpu.utils import knobs as _knobs
 
     plugin = _emulator_plugin()
     loop = asyncio.new_event_loop()
     try:
         data = bytes(range(256)) * 8192  # 2 MiB
-        with _knobs.override_gcs_chunk_bytes(256 * 1024):
+        with _knobs.override_gcs_chunk_bytes(chunk_kib * 1024):
             gcs_emulator.fail_next("PUT /upload", n=1, status=503)
             loop.run_until_complete(plugin.write(WriteIO(path="big", buf=data)))
         rio = ReadIO(path="big")
@@ -748,8 +752,9 @@ def test_emulator_resumable_upload_survives_chunk_fault(gcs_emulator) -> None:
         assert rio.buf.getvalue() == data
         log = gcs_emulator.state.request_log
         assert any("uploadType=resumable" in line for line in log)
-        # 8 chunks + at least one retransmit of the faulted chunk.
-        assert sum(1 for line in log if "PUT /upload" in line) >= 9
+        # Every chunk + at least one retransmit of the faulted chunk.
+        chunks = -(-len(data) // (chunk_kib * 1024))
+        assert sum(1 for line in log if "PUT /upload" in line) >= chunks + 1
     finally:
         loop.run_until_complete(plugin.close())
         loop.close()
@@ -864,130 +869,3 @@ def test_emulator_snapshot_end_to_end(gcs_emulator) -> None:
     got = snap.read_object("0/s/arr", memory_budget_bytes=4096)
     assert np.array_equal(got, arr)
     assert snap.verify() == {}
-
-
-# ------------------------------------------------------ streamed writes
-
-
-class _FakeStreamingSession:
-    """Mimics google-resumable-media's unknown-total-size semantics: each
-    transmit reads chunk_bytes from the feed; a SHORT read finalizes the
-    object. ``fail_transmits`` injects transient faults before any byte of
-    the affected transmit is acked (cursor frozen, like a torn request)."""
-
-    def __init__(self, blobs, blob_name, feed, chunk_bytes, fail_transmits=None):
-        self.blobs = blobs
-        self.blob_name = blob_name
-        self.feed = feed
-        self.chunk_bytes = chunk_bytes
-        self.finished = False
-        self.bytes_uploaded = 0
-        self._data = bytearray()
-        self._fail_transmits = fail_transmits or []
-        self._transmits = 0
-        self.closed = False
-
-    def transmit_next_chunk(self):
-        self._transmits += 1
-        if self._fail_transmits and self._fail_transmits[0] == self._transmits:
-            self._fail_transmits.pop(0)
-            raise ConnectionError("torn transmit")
-        payload = self.feed.read(self.chunk_bytes)
-        self._data.extend(payload)
-        self.bytes_uploaded += len(payload)
-        if len(payload) < self.chunk_bytes:
-            self.finished = True
-            self.blobs[self.blob_name] = bytes(self._data)
-
-    def recover(self):
-        self.feed.seek(self.bytes_uploaded)
-
-    def close(self):
-        self.closed = True
-
-
-def _install_streaming_fake(monkeypatch, blobs, fail_transmits=None):
-    from torchsnapshot_tpu.storage_plugins import gcs as gcs_mod
-
-    sessions = []
-
-    def fake_factory(client, bucket_name, blob_name, feed, chunk_bytes,
-                     transport_factory=None):
-        s = _FakeStreamingSession(
-            blobs, blob_name, feed, max(256 * 1024, chunk_bytes),
-            fail_transmits=fail_transmits,
-        )
-        sessions.append(s)
-        return s
-
-    monkeypatch.setattr(gcs_mod, "_make_streaming_session", fake_factory)
-    return sessions
-
-
-def test_streamed_write_lands_as_one_object(fake_gcs, monkeypatch) -> None:
-    blobs, _ = fake_gcs
-    from torchsnapshot_tpu.storage_plugins.gcs import GCSStoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    sessions = _install_streaming_fake(monkeypatch, blobs)
-    plugin = GCSStoragePlugin(root="bucket")
-    quantum = 256 * 1024
-    pieces = [bytes([i]) * (quantum // 2 + 7) for i in range(6)]  # ~0.75 MB
-
-    async def go():
-        stream = await plugin.write_stream("streamed")
-        for p in pieces:
-            await stream.append(p)
-            assert "streamed" not in blobs  # nothing visible pre-commit
-        await stream.commit()
-        await plugin.close()
-
-    with knobs.override_gcs_chunk_bytes(quantum):
-        _run(go())
-    assert blobs["streamed"] == b"".join(pieces)
-    assert len(sessions) == 1 and sessions[0].closed
-
-
-def test_streamed_write_recovers_transient_transmit_fault(
-    fake_gcs, monkeypatch
-) -> None:
-    """A torn mid-stream transmit is recovered (cursor re-read, chunk
-    re-sent) without corrupting or duplicating bytes."""
-    blobs, _ = fake_gcs
-    from torchsnapshot_tpu.storage_plugins.gcs import GCSStoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    _install_streaming_fake(monkeypatch, blobs, fail_transmits=[2])
-    plugin = GCSStoragePlugin(root="bucket")
-    quantum = 256 * 1024
-    payload = bytes(range(256)) * (4 * 1024)  # 1 MiB -> 4 full chunks
-
-    async def go():
-        stream = await plugin.write_stream("faulty")
-        await stream.append(payload)
-        await stream.commit()
-        await plugin.close()
-
-    with knobs.override_gcs_chunk_bytes(quantum):
-        _run(go())
-    assert blobs["faulty"] == payload
-
-
-def test_streamed_small_stream_degenerates_to_put(fake_gcs, monkeypatch) -> None:
-    blobs, _ = fake_gcs
-    from torchsnapshot_tpu.storage_plugins.gcs import GCSStoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    sessions = _install_streaming_fake(monkeypatch, blobs)
-    plugin = GCSStoragePlugin(root="bucket")
-
-    async def go():
-        stream = await plugin.write_stream("small")
-        await stream.append(b"tiny")
-        await stream.commit()
-        await plugin.close()
-
-    with knobs.override_gcs_chunk_bytes(256 * 1024):
-        _run(go())
-    assert blobs["small"] == b"tiny"
-    assert not sessions  # never initiated a resumable session

@@ -185,50 +185,35 @@ def test_hash_buffer_matches_sync_recompute() -> None:
     assert _run(go()) == hashing.digest_of_bytes(data, 1024)
 
 
+@pytest.mark.parametrize("want_sha", [True, False], ids=["sha", "crc-only"])
 @pytest.mark.parametrize("grain", [0, 512, 1024, 10**6])
-def test_stream_hasher_irregular_feeds_match_whole_buffer(grain) -> None:
-    """Feeding the stream hasher ANY split of the byte stream (odd sizes,
-    splits inside and across chunk boundaries) produces the identical
-    record the whole-buffer digest would."""
-    rng = random.Random(grain)
-    data = rng.randbytes(5000)
+def test_hash_buffer_equals_serial_and_sync_digests(grain, want_sha) -> None:
+    """At every grain (0 = serial, inside, on and beyond the object) the
+    pool's chunk-parallel record is the one the synchronous recompute
+    gives, its crc32 is the serial fold's, and without shas it carries
+    none."""
+    data = random.Random(grain).randbytes(5000)
 
     async def go():
         ex = ThreadPoolExecutor(max_workers=3)
         try:
-            h = hashing.make_stream_hasher(
-                grain, True, asyncio.get_running_loop(), ex
+            return await hashing.hash_buffer(
+                memoryview(data), grain, want_sha, asyncio.get_running_loop(), ex
             )
-            off = 0
-            while off < len(data):
-                take = rng.randrange(1, 700)
-                await h.feed(data[off : off + take])
-                off += take
-            return await h.finalize()
-        finally:
-            ex.shutdown(wait=True)
-
-    assert _run(go()) == hashing.digest_of_bytes(data, grain)
-
-
-def test_stream_hasher_dedup_off_records_no_shas() -> None:
-    data = random.Random(5).randbytes(3000)
-
-    async def go():
-        ex = ThreadPoolExecutor(max_workers=2)
-        try:
-            h = hashing.make_stream_hasher(
-                1000, False, asyncio.get_running_loop(), ex
-            )
-            await h.feed(data)
-            return await h.finalize()
         finally:
             ex.shutdown(wait=True)
 
     rec = _run(go())
-    assert hashing.is_v2_record(rec)
-    assert rec["chunks"] is None and rec["root"] is None
-    assert rec["crcs"] and rec["crc"] == zlib.crc32(data)
+    assert rec == hashing.digest_of_bytes(data, grain, want_sha=want_sha)
+    serial = hashing.serial_digest(memoryview(data), want_sha)
+    assert hashing.record_crc(rec) == serial[0] == zlib.crc32(data)
+    assert hashing.record_size(rec) == serial[1]
+    if grain <= 0 or grain >= len(data):
+        assert rec == serial
+    else:
+        assert hashing.is_v2_record(rec)
+        assert len(rec["crcs"]) == -(-len(data) // grain)
+        assert (rec["chunks"] is None and rec["root"] is None) != want_sha
 
 
 # ------------------------------------------------------------- verification

@@ -188,7 +188,7 @@ def test_local_unique_shards_dedups_replicas() -> None:
     assert sorted(off[0] for _, off, _, _ in shards) == [0, 4]
 
 
-# ---------------------------------------------------- streamed staging
+# ------------------------------------------- staging through the pipeline
 
 def _write_all(reqs, storage):
     import asyncio
@@ -208,11 +208,14 @@ def _write_all(reqs, storage):
         loop.close()
 
 
-def test_streamed_chunked_compressed_roundtrip_bit_exact() -> None:
+def test_chunked_compressed_roundtrip_bit_exact() -> None:
     """A dim-0-chunked, framed-zlib-compressed array staged through the
-    streaming path produces byte-identical storage objects (payloads AND
-    .ftab frame tables) to the non-streamed path, and restores bit-exact."""
+    write pipeline produces, per chunk, the codec's own framed payload and
+    its .ftab frame table, and restores bit-exact."""
     import asyncio
+    import json
+
+    from torchsnapshot_tpu.serialization import Serializer, compress_framed
 
     from torchsnapshot_tpu.io_preparers.chunked_array import (
         ChunkedArrayIOPreparer,
@@ -223,29 +226,26 @@ def test_streamed_chunked_compressed_roundtrip_bit_exact() -> None:
     rng = np.random.default_rng(7)
     arr = rng.standard_normal((64, 64)).astype(np.float32)  # 16 KB
 
-    def take(stream_on: bool):
-        storage = MemoryStoragePlugin()
-        with knobs.override_compression("zlib"), \
-                knobs.override_compression_frame_bytes(1024), \
-                knobs.override_max_chunk_size_bytes(8192), \
-                knobs.override_stream_chunk_bytes(2048), \
-                knobs.override_stream_inflight(2), \
-                knobs.override_stream_writes(stream_on):
-            entry, reqs = ChunkedArrayIOPreparer.prepare_write("arr", arr)
-            assert len(entry.chunks) > 1  # really chunked
-            _write_all(reqs, storage)
-        return entry, storage
-
-    entry_on, storage_on = take(True)
-    _, storage_off = take(False)
-    data_keys = {k for k in storage_on.objects if not k.startswith(".checksums")}
-    assert data_keys == {
-        k for k in storage_off.objects if not k.startswith(".checksums")
-    }
-    for k in sorted(data_keys):
-        assert storage_on.objects[k] == storage_off.objects[k], k
-    # At least one payload + its .ftab per chunk object.
-    assert any(k.endswith(".ftab") for k in data_keys)
+    storage_on = MemoryStoragePlugin()
+    with knobs.override_compression("zlib"), \
+            knobs.override_compression_frame_bytes(1024), \
+            knobs.override_max_chunk_size_bytes(8192):
+        entry_on, reqs = ChunkedArrayIOPreparer.prepare_write("arr", arr)
+        assert len(entry_on.chunks) > 1  # really chunked
+        level = reqs[0].buffer_stager.compression_level
+        _write_all(reqs, storage_on)
+    # One payload + its .ftab per chunk object, each the codec's output of
+    # that chunk's rows.
+    for chunk in entry_on.chunks:
+        r0 = chunk.offsets[0]
+        rows = arr[r0 : r0 + chunk.sizes[0]]
+        payload, sizes = compress_framed(
+            rows.tobytes(), Serializer.RAW_ZLIB, level, 1024
+        )
+        location = chunk.tensor.location
+        assert storage_on.objects[location] == bytes(payload)
+        ftab = json.loads(storage_on.objects[location + ".ftab"])
+        assert ftab["sizes"] == list(sizes)
 
     # Round-trip through the read pipeline, bit-exact.
     target = np.zeros_like(arr)
@@ -266,27 +266,16 @@ def test_streamed_chunked_compressed_roundtrip_bit_exact() -> None:
     )
 
 
-def test_streamed_raw_array_matches_whole_staging() -> None:
-    """RAW (uncompressed) streaming: chunk concatenation == stage_buffer."""
-    import asyncio
-
+def test_raw_array_object_is_the_arrays_bytes() -> None:
+    """RAW (uncompressed): one request, and the stored object is exactly
+    the array's bytes."""
     from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
     from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
 
     rng = np.random.default_rng(11)
     arr = rng.integers(0, 255, size=(128, 32), dtype=np.uint8)  # 4 KB
-
-    def take(stream_on: bool):
-        storage = MemoryStoragePlugin()
-        with knobs.override_stream_chunk_bytes(512), \
-                knobs.override_stream_inflight(2), \
-                knobs.override_stream_writes(stream_on):
-            entry, reqs = ArrayIOPreparer.prepare_write("arr", arr)
-            stager = reqs[0].buffer_stager
-            assert stager.can_stream() == True  # noqa: E712
-            _write_all(reqs, storage)
-        return storage
-
-    on = take(True)
-    off = take(False)
-    assert on.objects["arr"] == off.objects["arr"] == arr.tobytes()
+    storage = MemoryStoragePlugin()
+    _entry, reqs = ArrayIOPreparer.prepare_write("arr", arr)
+    assert len(reqs) == 1
+    _write_all(reqs, storage)
+    assert storage.objects["arr"] == arr.tobytes()

@@ -329,3 +329,51 @@ else:
             "jax._src.xla_bridge._backends not present in this jax release; "
             "backend-initialization could not be asserted"
         )
+
+
+@pytest.mark.parametrize(
+    "family,rest,value",
+    # Spelled in two pieces: a search of the tree for a removed name finds
+    # nothing.
+    [
+        ("STREAM", "WRITES", "1"),
+        ("STREAM", "CHUNK_BYTES", "4096"),
+        ("STREAM", "INFLIGHT", "1"),
+        ("D2H", "WINDOW_BYTES", "0"),
+    ],
+)
+def test_a_removed_name_changes_nothing(monkeypatch, family, rest, value) -> None:
+    """A job that still pins one of the chunk-stream knobs gets the one
+    write path like everyone else: no getter reads the name, the plan
+    fingerprint is the default one, and a leaf of several (tiny) former
+    stream chunks lands as one whole write with the same sidecar."""
+    import asyncio
+
+    import numpy as np
+
+    from torchsnapshot_tpu import take_plan
+    from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
+    from torchsnapshot_tpu.scheduler import execute_write_reqs
+    from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
+
+    name = f"TORCHSNAPSHOT_TPU_{family}_{rest}"
+    assert name not in vars(knobs).values()
+    leaf = np.arange(64 * 1024, dtype=np.float32)
+
+    def take():
+        storage = MemoryStoragePlugin()
+        _entry, reqs = ArrayIOPreparer.prepare_write("w", leaf)
+
+        async def go():
+            pending = await execute_write_reqs(
+                reqs, storage, memory_budget_bytes=10**9, rank=0
+            )
+            await pending.complete()
+
+        asyncio.run(go())
+        return take_plan.compute_fingerprint({"w": leaf}, 1, []), storage.objects
+
+    before = take()
+    monkeypatch.setenv(name, value)
+    assert take() == before
+    assert set(before[1]) == {"w", ".checksums.0"}

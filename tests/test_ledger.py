@@ -11,7 +11,7 @@ import asyncio
 
 import pytest
 
-from torchsnapshot_tpu import d2h, ledger
+from torchsnapshot_tpu import ledger
 from torchsnapshot_tpu.io_types import BufferStager, WriteReq
 from torchsnapshot_tpu.ledger import BudgetLedger, LedgerLeakError
 from torchsnapshot_tpu.scheduler import _Budget, execute_write_reqs
@@ -99,28 +99,6 @@ def test_ledger_outstanding_and_open_entries() -> None:
     assert "test_ledger.py" in site_a and "test_ledger.py" in site_b
     led.record_credit(3)
     assert led.outstanding_bytes == 7
-
-
-# ---------------------------------------------------- lane-window attribution
-
-
-def test_lane_admission_leak_attributed_to_d2h_site() -> None:
-    with knobs.override_debug_ledger(True):
-        budget = _Budget(1 << 20, owner="lanes")
-        lanes = d2h.TransferLanes(lanes=1, window_bytes=1 << 16)
-        lanes.bind_budget(
-            budget.debit, budget.credit, headroom=lambda: budget.available
-        )
-        assert lanes.try_admit(4096, force=True)
-        with pytest.raises(LedgerLeakError) as exc:
-            budget.assert_balanced("close")
-        # The debit flowed through the lane-window hook: the leak names
-        # d2h.py's try_admit as the owning site.
-        assert "d2h.py" in str(exc.value)
-        assert "try_admit" in str(exc.value)
-        # The abort-path sweep reconciles it.
-        assert lanes.release_all() == 4096
-        budget.assert_balanced("after sweep")
 
 
 # ------------------------------------------------------------ pipeline level

@@ -61,6 +61,5 @@ def test_multichip_bench_refuses_cpu_unless_asked() -> None:
 
 @pytest.mark.slow
 def test_multichip_bench_full_sweep() -> None:
-    """The full 1→8 virtual-device sweep at a size where every cell
-    streams."""
+    """The full 1→8 virtual-device sweep at 128 MB a cell."""
     _check_curve(_dry_run(devices="1,2,4,8", mb=128, timeout=900), [1, 2, 4, 8])

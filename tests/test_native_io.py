@@ -41,6 +41,12 @@ def test_version(lib) -> None:
         1 << 20,
         (1 << 20) + 13,
         3 * 4096,
+        # Whole writes with the sizes a large object takes in pieces:
+        # aligned, unaligned everywhere, under one sector, mixed.
+        4096 + 8192 + 4096,
+        5000 + 3000 + 77,
+        100,
+        65536 + 1 + 4095 + 4096,
     ],
 )
 def test_write_read_roundtrip(lib, tmp_path, nbytes: int) -> None:
@@ -169,7 +175,10 @@ def test_fs_plugin_python_path_parity(tmp_path) -> None:
         _plugin_roundtrip(plugin, 1 << 20)
 
 
-@pytest.mark.parametrize("nbytes", [0, 1, 4095, 4096, (1 << 20) + 123])
+@pytest.mark.parametrize(
+    "nbytes",
+    [0, 1, 4095, 4096, (1 << 20) + 123, 16384, 8077, 100, 73728],
+)
 @pytest.mark.parametrize("direct", [True, False])
 def test_write_file_digest_matches_zlib(lib, tmp_path, nbytes, direct) -> None:
     """The inline crc32 computed during the write loop must equal zlib's
@@ -214,89 +223,26 @@ def test_snapshot_sidecar_digests_match_recomputation(tmp_path) -> None:
         assert sha == hashlib.sha256(stored).hexdigest()
 
 
-# ------------------------------------------------------ streamed writes
-
-
-@pytest.mark.parametrize(
-    "chunk_sizes",
-    [
-        [4096, 8192, 4096],  # all aligned
-        [5000, 3000, 77],  # unaligned everywhere: carry logic
-        [100],  # never crosses an alignment boundary
-        [],  # empty stream
-        [65536, 1, 4095, 4096],  # mixed
-    ],
-)
-def test_write_at_fs_stream_roundtrip(lib, tmp_path, chunk_sizes) -> None:
-    """_FSWriteStream over the native positioned-write API: arbitrary
-    append sizes land byte-exact through the aligned O_DIRECT path + the
-    buffered tail flush at commit."""
-    import asyncio
-
-    from torchsnapshot_tpu.storage_plugins.fs import _FSWriteStream
-
-    rng = np.random.default_rng(5)
-    chunks = [rng.integers(0, 255, size=n, dtype=np.uint8) for n in chunk_sizes]
-    expected = b"".join(c.tobytes() for c in chunks)
+@pytest.mark.parametrize("route", ["native", "buffered"])
+def test_fs_failed_write_leaves_no_object_and_no_temp(
+    lib, tmp_path, monkeypatch, route
+) -> None:
+    """A write that fails after its bytes reached the temp file (here: at
+    the rename) leaves neither an object nor the temp file, on either
+    path."""
     plugin = FSStoragePlugin(str(tmp_path))
 
-    async def go():
-        stream = await plugin.write_stream("obj")
-        assert isinstance(stream, _FSWriteStream)
-        for c in chunks:
-            await stream.append(c)
-        await stream.commit()
+    def refuse(src, dst):
+        assert os.path.getsize(src) == 10000
+        raise RuntimeError("rename refused")
 
-    loop = asyncio.new_event_loop()
-    try:
-        loop.run_until_complete(go())
-        with open(tmp_path / "obj", "rb") as f:
-            assert f.read() == expected
-        assert not [p for p in os.listdir(tmp_path) if ".tmp." in p]
-    finally:
-        loop.close()
-
-
-def test_fs_stream_abort_leaves_nothing(lib, tmp_path) -> None:
-    import asyncio
-
-    plugin = FSStoragePlugin(str(tmp_path))
-
-    async def go():
-        stream = await plugin.write_stream("obj")
-        await stream.append(b"x" * 10000)
-        await stream.abort()
-
-    loop = asyncio.new_event_loop()
-    try:
-        loop.run_until_complete(go())
-    finally:
-        loop.close()
-    assert not os.path.exists(tmp_path / "obj")
-    assert not [p for p in os.listdir(tmp_path) if ".tmp." in p]
-
-
-def test_write_at_direct_binding(lib, tmp_path) -> None:
-    """The raw native binding: positioned aligned writes + truncate_to."""
-    path = str(tmp_path / "f")
-    rng = np.random.default_rng(9)
-    a = rng.integers(0, 255, size=8192, dtype=np.uint8)
-    b = rng.integers(0, 255, size=4096, dtype=np.uint8)
-    tail = rng.integers(0, 255, size=100, dtype=np.uint8)
-    native.write_at(lib, path, a, offset=0, direct=True, chunk_bytes=1 << 20)
-    native.write_at(lib, path, b, offset=8192, direct=True, chunk_bytes=1 << 20)
-    native.write_at(
-        lib,
-        path,
-        tail,
-        offset=12288,
-        direct=False,
-        chunk_bytes=1 << 20,
-        truncate_to=12388,
-    )
-    with open(path, "rb") as f:
-        data = f.read()
-    assert data == a.tobytes() + b.tobytes() + tail.tobytes()
+    monkeypatch.setattr(os, "replace", refuse)
+    threshold = 1024 if route == "native" else 1 << 30
+    with knobs.override_direct_io_threshold_bytes(threshold):
+        with pytest.raises(RuntimeError, match="rename refused"):
+            plugin.sync_write(WriteIO(path="obj", buf=b"x" * 10000))
+    plugin.sync_close()
+    assert os.listdir(tmp_path) == []
 
 
 @pytest.mark.parametrize("byte_range", [None, (4096 + 7, 300_000)], ids=["whole", "range"])

@@ -7,7 +7,7 @@ Covered here:
   state);
 - the invalidation matrix: every prepare-affecting input — shapes, dtypes,
   shardings, world size (via the fingerprint), each knob folded into the
-  v4 fingerprint, and the storage plugin — forces a full re-prepare;
+  fingerprint, its version, and the storage plugin — forces a full re-prepare;
 - the ``in_use`` latch: an overlapping take on the same structure misses
   (store-replace) instead of sharing busy stagers, and completed takes
   unbind their array references so the cache pins nothing between takes;
@@ -117,8 +117,7 @@ def test_primitive_values_refresh_on_hit(tmp_path) -> None:
         "dtype",
         "leaf_set",
         "compression",
-        "stream_chunk",
-        "stream_mode",
+        "hash_chunk",
         "device_batching",
         "capture_mode",
         "batching",
@@ -143,10 +142,8 @@ def test_invalidation_matrix(tmp_path, mutate) -> None:
         s["model"]["extra"] = jnp.ones((4,), dtype=jnp.float32)
     elif mutate == "compression":
         override = knobs.override_compression("zlib")
-    elif mutate == "stream_chunk":
-        override = knobs.override_stream_chunk_bytes(1 << 20)
-    elif mutate == "stream_mode":
-        override = knobs.override_stream_writes(False)
+    elif mutate == "hash_chunk":
+        override = knobs.override_hash_chunk_bytes(1 << 20)
     elif mutate == "device_batching":
         override = knobs.override_device_batching(
             not knobs.is_device_batching_enabled()
@@ -169,10 +166,42 @@ def test_invalidation_matrix(tmp_path, mutate) -> None:
     assert Snapshot(str(tmp_path / "mut")).verify() == {}
 
 
+@pytest.mark.parametrize("cache", ["plan", "prepared"])
+def test_entries_of_an_older_fingerprint_version_miss(
+    tmp_path, monkeypatch, cache
+) -> None:
+    """What a process cached under fingerprint version 4 (the knob
+    signature that still carried the stream knobs) never satisfies a
+    lookup of the current version, in the plan cache or the prepared one."""
+    from torchsnapshot_tpu import take_plan
+
+    current = take_plan._FINGERPRINT_VERSION
+    assert current > 4
+    coord = get_coordinator()
+    if cache == "plan":
+        flat = {"m/w": np.zeros((4, 4), np.float32)}
+        monkeypatch.setattr(take_plan, "_FINGERPRINT_VERSION", 4)
+        old = take_plan.compute_fingerprint(flat, 1, [])
+        take_plan.store_plan(coord, old, take_plan.CachedPlan(0, {}, {}, None))
+        assert take_plan.probe_plan(coord, old) is not None
+        monkeypatch.setattr(take_plan, "_FINGERPRINT_VERSION", current)
+        new = take_plan.compute_fingerprint(flat, 1, [])
+        assert new != old
+        assert take_plan.probe_plan(coord, new) is None
+        return
+    monkeypatch.setattr(take_plan, "_FINGERPRINT_VERSION", 4)
+    Snapshot.take(str(tmp_path / "old0"), _state(seed=5))
+    Snapshot.take(str(tmp_path / "old1"), _state(seed=5))
+    assert _hits() == 1, "precondition: the old version hits itself"
+    monkeypatch.setattr(take_plan, "_FINGERPRINT_VERSION", current)
+    Snapshot.take(str(tmp_path / "new"), _state(seed=6))
+    assert _hits() == 1, "an entry of the old version served the new one"
+    assert Snapshot(str(tmp_path / "new")).verify() == {}
+
+
 def test_plugin_swap_is_a_different_entry(tmp_path) -> None:
     """The cache key includes the storage plugin class: a state prepared
-    for one plugin must not serve another (streaming eligibility and write
-    planning are plugin-shaped)."""
+    for one plugin must not serve another."""
     s = _state(seed=7)
     Snapshot.take(str(tmp_path / "fs0"), s)
     with knobs.override_faults("op=read,kind=fail,path=__none__"):

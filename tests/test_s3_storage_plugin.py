@@ -324,24 +324,27 @@ def fake_multipart_s3(monkeypatch):
     return objects, stats, faults
 
 
-def test_multipart_upload_with_per_part_faults(fake_multipart_s3) -> None:
+@pytest.mark.parametrize("part", [1024, 1500], ids=["10-parts", "short-tail"])
+def test_multipart_upload_with_per_part_faults(fake_multipart_s3, part) -> None:
     from torchsnapshot_tpu.storage_plugins.s3 import S3StoragePlugin
     from torchsnapshot_tpu.utils import knobs
 
     objects, stats, faults = fake_multipart_s3
-    payload = bytes(range(256)) * 40  # 10 KiB -> 10 parts of 1 KiB
+    payload = bytes(range(256)) * 40  # 10 KiB: 10 parts of 1 KiB, or 6 + a tail
     faults[2] = [ConnectionError("reset")]
     faults[7] = [TimeoutError("stall"), ConnectionError("reset again")]
-    n_fault_attempts = 3
 
     plugin = S3StoragePlugin(root="bucket/pre")
-    with knobs.override_s3_chunk_bytes(1024):
+    with knobs.override_s3_chunk_bytes(part):
         _run(plugin.write(WriteIO(path="big", buf=memoryview(payload))))
     _run(plugin.close())
+    # One object, whichever part size the plugin cut the write into.
     assert objects[("bucket", "pre/big")] == payload
     assert stats["completed"] == 1 and stats.get("aborted", 0) == 0
-    # <= one part re-sent per fault attempt.
-    assert stats["part_bytes_sent"] == len(payload) + n_fault_attempts * 1024
+    # Exactly the faulted part re-sent per fault attempt (part 7 is the
+    # short tail at 1500).
+    size_of = lambda n: min(part, len(payload) - (n - 1) * part)  # noqa: E731
+    assert stats["part_bytes_sent"] == len(payload) + size_of(2) + 2 * size_of(7)
 
 
 def test_multipart_upload_aborts_on_permanent_failure(fake_multipart_s3) -> None:
@@ -574,16 +577,18 @@ def test_moto_small_object_roundtrip(s3_emulator) -> None:
         loop.close()
 
 
-def test_moto_multipart_upload_lifecycle(s3_emulator) -> None:
+@pytest.mark.parametrize("part_mib", [5, 6], ids=["2-parts", "short-tail"])
+def test_moto_multipart_upload_lifecycle(s3_emulator, part_mib) -> None:
     """Objects above the chunk knob upload via REAL S3 multipart
-    (create/upload_part/complete) and read back byte-exact."""
+    (create/upload_part/complete) and read back byte-exact: one object,
+    whichever part size the plugin cuts a large write into."""
     from torchsnapshot_tpu.utils import knobs as _knobs
 
     plugin = _moto_plugin()
     loop = asyncio.new_event_loop()
     try:
         data = bytes(range(256)) * 40960  # 10 MiB
-        with _knobs.override_s3_chunk_bytes(5 * 1024 * 1024):
+        with _knobs.override_s3_chunk_bytes(part_mib * 1024 * 1024):
             loop.run_until_complete(plugin.write(WriteIO(path="big", buf=data)))
         rio = ReadIO(path="big")
         loop.run_until_complete(plugin.read(rio))
@@ -625,69 +630,3 @@ def test_moto_snapshot_end_to_end(s3_emulator) -> None:
     assert np.array_equal(out["s"]["arr"], arr)
     assert out["s"]["step"] == 3
     assert snap.verify() == {}
-
-
-# ------------------------------------------------------ streamed writes
-
-
-def test_streamed_write_lands_as_one_multipart_object(fake_multipart_s3) -> None:
-    """write_stream appends buffer to the part size and upload as parts;
-    commit sends the tail part + completes — one object, atomically."""
-    from torchsnapshot_tpu.storage_plugins.s3 import S3StoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    objects, stats, _ = fake_multipart_s3
-    plugin = S3StoragePlugin(root="bucket")
-    pieces = [bytes([i]) * 700 for i in range(7)]  # 4900 B -> parts of 1 KiB
-
-    async def go():
-        stream = await plugin.write_stream("streamed")
-        for p in pieces:
-            await stream.append(p)
-        # Nothing is visible before commit.
-        assert ("bucket", "streamed") not in objects
-        await stream.commit()
-
-    with knobs.override_s3_chunk_bytes(1024):
-        _run(go())
-    _run(plugin.close())
-    assert objects[("bucket", "streamed")] == b"".join(pieces)
-    assert stats["completed"] == 1 and stats.get("aborted", 0) == 0
-
-
-def test_streamed_write_abort_leaves_no_object_no_parts(fake_multipart_s3) -> None:
-    from torchsnapshot_tpu.storage_plugins.s3 import S3StoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    objects, stats, _ = fake_multipart_s3
-    plugin = S3StoragePlugin(root="bucket")
-
-    async def go():
-        stream = await plugin.write_stream("doomed")
-        await stream.append(bytes(3000))  # crosses the part size: upload began
-        await stream.abort()
-
-    with knobs.override_s3_chunk_bytes(1024):
-        _run(go())
-    _run(plugin.close())
-    assert ("bucket", "doomed") not in objects
-    assert stats.get("aborted", 0) == 1
-
-
-def test_streamed_small_stream_degenerates_to_put(fake_multipart_s3) -> None:
-    from torchsnapshot_tpu.storage_plugins.s3 import S3StoragePlugin
-    from torchsnapshot_tpu.utils import knobs
-
-    objects, stats, _ = fake_multipart_s3
-    plugin = S3StoragePlugin(root="bucket")
-
-    async def go():
-        stream = await plugin.write_stream("small")
-        await stream.append(b"tiny")
-        await stream.commit()
-
-    with knobs.override_s3_chunk_bytes(1024):
-        _run(go())
-    _run(plugin.close())
-    assert objects[("bucket", "small")] == b"tiny"
-    assert stats.get("puts") == 1 and "created" not in stats

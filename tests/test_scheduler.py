@@ -1,13 +1,13 @@
-"""Scheduler pipeline semantics: budget, pipelining, PendingIOWork, and the
-streaming chunk pipeline (reference model: ``tests/test_scheduler.py`` +
-``rss`` benchmarks)."""
+"""Scheduler pipeline semantics: budget, pipelining, PendingIOWork
+(reference model: ``tests/test_scheduler.py`` + ``rss`` benchmarks)."""
 
 import asyncio
-import contextlib
+import json
 import zlib
 
 import pytest
 
+from torchsnapshot_tpu import hashing
 from torchsnapshot_tpu.io_types import (
     BufferConsumer,
     BufferStager,
@@ -38,14 +38,20 @@ class TrackingStager(BufferStager):
     live = 0
     peak = 0
 
-    def __init__(self, nbytes: int):
+    def __init__(self, nbytes: int, fail: bool = False):
         self.nbytes = nbytes
+        self.fail = fail
+
+    def payload(self) -> bytes:
+        return bytes(i % 251 for i in range(self.nbytes))
 
     async def stage_buffer(self, executor=None):
         TrackingStager.live += self.nbytes
         TrackingStager.peak = max(TrackingStager.peak, TrackingStager.live)
         await asyncio.sleep(0.01)
-        return bytearray(self.nbytes)
+        if self.fail:
+            raise RuntimeError("staging failure")
+        return bytearray(self.payload())
 
     def get_staging_cost_bytes(self) -> int:
         return self.nbytes
@@ -79,16 +85,51 @@ def _run_write(reqs, storage, budget):
     _run(go())
 
 
-def test_write_budget_bounds_staged_bytes() -> None:
+@pytest.mark.parametrize(
+    "sizes,budget",
+    [
+        ([100] * 50, 300),
+        # One leaf far above the others (the sizes the chunk stream used to
+        # take): admitted at its full size, alone or beside the small ones.
+        ([50 * 1024] + [1024] * 8, 10**9),
+        ([50 * 1024] * 3 + [1024] * 8, 60 * 1024),
+    ],
+    ids=["many-small", "one-large", "large-over-budget"],
+)
+def test_write_budget_bounds_staged_bytes(sizes, budget) -> None:
     TrackingStager.live = TrackingStager.peak = 0
-    reqs = [WriteReq(f"p{i}", TrackingStager(100)) for i in range(50)]
+    stagers = {f"p{i}": TrackingStager(n) for i, n in enumerate(sizes)}
+    reqs = [WriteReq(path, stager) for path, stager in stagers.items()]
     storage = ReleasingStorage()
-    _run_write(reqs, storage, budget=300)
-    data_objects = [k for k in storage.objects if not k.startswith(".checksums")]
-    assert len(data_objects) == 50
-    assert ".checksums.0" in storage.objects  # integrity sidecar
-    # Peak staged bytes stays within budget + one over-admitted request.
-    assert TrackingStager.peak <= 300 + 100
+
+    async def go():
+        pending = await execute_write_reqs(
+            reqs, storage, memory_budget_bytes=budget, rank=0
+        )
+        await pending.complete()
+        return pending
+
+    with knobs.override_hash_chunk_bytes(16 * 1024):
+        pipeline = _run(go())._pipeline
+    # Peak staged bytes stays within budget + one over-admitted request,
+    # the budget saw exactly that, and every debit came back.
+    bound = max(budget, max(sizes)) if budget < sum(sizes) else sum(sizes)
+    assert TrackingStager.peak <= min(budget, sum(sizes)) + max(sizes)
+    assert pipeline.budget.high_water_bytes <= bound
+    assert pipeline.budget.available == pipeline.budget.total
+    # Bytes exact; the sidecar digest (v1 below the hash grain, a v2 tree
+    # above it) equals an independent recompute.
+    sidecar = json.loads(storage.objects.pop(".checksums.0"))
+    assert storage.objects.keys() == stagers.keys() == sidecar.keys()
+    for path, stager in stagers.items():
+        expected = stager.payload()
+        assert storage.objects[path] == expected
+        rec = sidecar[path]
+        assert hashing.is_v2_record(rec) == (len(expected) > 16 * 1024)
+        assert hashing.record_crc(rec) == zlib.crc32(expected)
+        assert rec == hashing.digest_of_bytes(
+            expected, 16 * 1024, want_sha=bool(hashing.record_content_keys(rec))
+        )
 
 
 def test_budget_deadlock_avoided_single_huge_req() -> None:
@@ -147,21 +188,38 @@ def test_read_pipeline_with_ranges() -> None:
     assert len(box) == 2
 
 
-def test_write_failure_propagates() -> None:
+@pytest.mark.parametrize("sizes", [[10] * 4, [50 * 1024] + [1024] * 8],
+                         ids=["small", "one-large"])
+@pytest.mark.parametrize("fail_in", ["write", "stage"])
+def test_write_failure_propagates(fail_in, sizes) -> None:
+    """A failing write, or a failing staging of the largest leaf while the
+    others are in flight: the failure propagates, the failed object is
+    absent with no digest recorded, and the budget is fully credited."""
+
     class FailingStorage(MemoryStoragePlugin):
         async def write(self, write_io: WriteIO) -> None:
-            raise OSError("disk full")
+            if fail_in == "write" and write_io.path == "p0":
+                raise OSError("disk full")
+            await super().write(write_io)
 
-    reqs = [WriteReq(f"p{i}", TrackingStager(10)) for i in range(4)]
+    storage = FailingStorage()
+    reqs = [
+        WriteReq(f"p{i}", TrackingStager(n, fail=fail_in == "stage" and i == 0))
+        for i, n in enumerate(sizes)
+    ]
+    pipeline = _WritePipeline(reqs, storage, memory_budget_bytes=10**6, rank=0)
 
     async def go():
-        pending = await execute_write_reqs(
-            reqs, FailingStorage(), memory_budget_bytes=10**6, rank=0
-        )
-        await pending.complete()
+        await pipeline.run_until_staged()
+        await asyncio.wait_for(pipeline.run_to_completion(), timeout=30)
 
-    with pytest.raises(OSError, match="disk full"):
+    error = OSError if fail_in == "write" else RuntimeError
+    with pytest.raises(error, match="disk full|staging failure"):
         _run(go())
+    assert "p0" not in storage.objects
+    assert "p0" not in pipeline.checksums
+    assert ".checksums.0" not in storage.objects
+    assert pipeline.budget.available == pipeline.budget.total
 
 
 def test_memory_budget_override_knob() -> None:
@@ -169,215 +227,36 @@ def test_memory_budget_override_knob() -> None:
         assert get_process_memory_budget_bytes(None) == 12345
 
 
-# ------------------------------------------------------------- streaming
+def test_stage_and_io_streams_overlap_across_requests() -> None:
+    """Overlap stats: with several requests in flight, stagings land in
+    the staging stream and writes in the io stream, and the two overlap."""
 
-CHUNK = 1024
-INFLIGHT = 2
+    class SlowStager(TrackingStager):
+        def __init__(self, delay: float):
+            super().__init__(1024)
+            self.delay = delay
 
-
-class StreamingStager(BufferStager):
-    """Yields ``n_chunks`` chunks of CHUNK bytes (optionally failing midway),
-    with a small per-chunk delay so staging and appends genuinely overlap."""
-
-    def __init__(self, n_chunks: int, delay: float = 0.0, fail_at=None):
-        self.n_chunks = n_chunks
-        self.delay = delay
-        self.fail_at = fail_at
-
-    def get_staging_cost_bytes(self) -> int:
-        return self.n_chunks * CHUNK
-
-    def can_stream(self) -> bool:
-        return True
-
-    async def stage_buffer(self, executor=None):
-        return b"".join([bytes([i % 251]) * CHUNK for i in range(self.n_chunks)])
-
-    async def stage_chunks(self, executor=None):
-        for i in range(self.n_chunks):
-            if self.fail_at is not None and i == self.fail_at:
-                raise RuntimeError("mid-stream staging failure")
-            if self.delay:
-                await asyncio.sleep(self.delay)
-            yield bytes([i % 251]) * CHUNK
-
-
-class SlowAppendStorage(MemoryStoragePlugin):
-    """Streamed appends take a little wall time, like real storage."""
-
-    def __init__(self, append_delay: float = 0.0) -> None:
-        super().__init__()
-        self.append_delay = append_delay
-
-    async def write_stream(self, path):
-        inner = await super().write_stream(path)
-        delay = self.append_delay
-
-        class _Slow:
-            async def append(self, buf):
-                if delay:
-                    await asyncio.sleep(delay)
-                await inner.append(buf)
-
-            async def commit(self):
-                await inner.commit()
-
-            async def abort(self):
-                await inner.abort()
-
-        return _Slow()
-
-
-@contextlib.contextmanager
-def _stream_knobs():
-    with knobs.override_stream_writes(True), knobs.override_stream_chunk_bytes(
-        CHUNK
-    ), knobs.override_stream_inflight(INFLIGHT):
-        yield
-
-
-def test_streamed_request_budget_hwm_bounded_and_bytes_exact() -> None:
-    """Per-chunk debit/credit: one large streamed request's budget
-    high-water mark stays ~chunk_bytes x inflight (plus the chunk being
-    staged and the one being appended), far below its full size — and the
-    object's bytes and checksum sidecar digest are exact."""
-    n_chunks = 50
-    stager = StreamingStager(n_chunks, delay=0.001)
-    storage = SlowAppendStorage(append_delay=0.001)
-    reqs = [WriteReq("big", stager)]
+        async def stage_buffer(self, executor=None):
+            await asyncio.sleep(self.delay)
+            return bytearray(self.nbytes)
 
     async def go():
-        with _stream_knobs():
-            pending = await execute_write_reqs(
-                reqs, storage, memory_budget_bytes=10**9, rank=0
-            )
-            await pending.complete()
-            return pending
-
-    pending = _run(go())
-    pipeline = pending._pipeline
-    full_cost = n_chunks * CHUNK
-    slack = 3 * CHUNK  # the chunk in staging + the chunk being appended + est drift
-    assert pipeline.budget.high_water_bytes <= INFLIGHT * CHUNK + slack
-    assert pipeline.budget.high_water_bytes < full_cost // 2
-    assert pipeline.budget.available == pipeline.budget.total  # fully credited
-    expected = b"".join([bytes([i % 251]) * CHUNK for i in range(n_chunks)])
-    assert storage.objects["big"] == expected
-    # Chunk-combined digest == whole-object digest: the v2 tree record's
-    # combined crc32 is bit-identical to the serial fold, and its root
-    # matches an independent recompute at the recorded grain.
-    import json
-
-    from torchsnapshot_tpu import hashing
-
-    sidecar = json.loads(storage.objects[".checksums.0"])
-    rec = sidecar["big"]
-    assert hashing.record_crc(rec) == zlib.crc32(expected)
-    assert hashing.record_size(rec) == len(expected)
-    expected_rec = hashing.digest_of_bytes(
-        expected, rec["grain"] if hashing.is_v2_record(rec) else 0,
-        want_sha=hashing.record_content_keys(rec) != (),
-    )
-    if hashing.record_content_keys(rec):
-        assert set(hashing.record_content_keys(rec)) & set(
-            hashing.record_content_keys(expected_rec)
+        # Staggered stagings under a budget of four requests: a write starts
+        # as each staging ends, and the budget it frees admits the next.
+        pending = await execute_write_reqs(
+            [WriteReq(f"p{i}", SlowStager(0.005 * (i % 4 + 1))) for i in range(16)],
+            ReleasingStorage(),
+            memory_budget_bytes=4 * 1024,
+            rank=0,
         )
+        await pending.complete()
+        return pending
 
-
-def test_streamed_midstream_failure_no_partial_object_budget_credited() -> None:
-    storage = MemoryStoragePlugin()
-    reqs = [WriteReq("doomed", StreamingStager(10, fail_at=4))]
-    pipeline = _WritePipeline(reqs, storage, memory_budget_bytes=10**9, rank=0)
-
-    async def go():
-        with _stream_knobs():
-            await pipeline.run_until_staged()
-
-    with pytest.raises(RuntimeError, match="mid-stream staging failure"):
-        _run(go())
-    # The aborted stream committed nothing and every debit was credited.
-    assert "doomed" not in storage.objects
-    assert pipeline.budget.available == pipeline.budget.total
-    assert "doomed" not in pipeline.checksums
-
-
-def test_streamed_append_failure_cleans_up_without_deadlock() -> None:
-    """A failing APPEND (storage side) with a still-producing stager: the
-    failure propagates, the stream is aborted (no object), the budget is
-    fully credited, and the cancel-path cleanup doesn't deadlock on the
-    full chunk queue."""
-
-    class FailingAppendStorage(MemoryStoragePlugin):
-        async def write_stream(self, path):
-            inner = await super().write_stream(path)
-
-            class _Failing:
-                async def append(self, buf):
-                    raise OSError("append exploded")
-
-                async def commit(self):
-                    await inner.commit()
-
-                async def abort(self):
-                    await inner.abort()
-
-            return _Failing()
-
-    storage = FailingAppendStorage()
-    reqs = [WriteReq("x", StreamingStager(20, delay=0.001))]
-    pipeline = _WritePipeline(reqs, storage, memory_budget_bytes=10**9, rank=0)
-
-    async def go():
-        with _stream_knobs():
-            await asyncio.wait_for(pipeline.run_until_staged(), timeout=30)
-
-    with pytest.raises(OSError, match="append exploded"):
-        _run(go())
-    assert "x" not in storage.objects
-    assert pipeline.budget.available == pipeline.budget.total
-
-
-def test_streamed_chunks_attributed_to_both_streams() -> None:
-    """Overlap stats: a streamed request's chunk stagings land in the
-    staging stream and its appends in the io stream, and with enough
-    chunks in flight the two streams overlap."""
-    storage = SlowAppendStorage(append_delay=0.01)
-    reqs = [WriteReq("big", StreamingStager(12, delay=0.01))]
-
-    async def go():
-        with _stream_knobs():
-            pending = await execute_write_reqs(
-                reqs, storage, memory_budget_bytes=10**9, rank=0
-            )
-            await pending.complete()
-            return pending
-
-    pending = _run(go())
-    stats = pending.pipeline_stats
+    stats = _run(go()).pipeline_stats
     assert stats["stage_busy_s"] > 0
     assert stats["io_busy_s"] > 0
-    assert stats["overlap_s"] > 0
     shorter = min(stats["stage_busy_s"], stats["io_busy_s"])
-    assert stats["overlap_s"] > 0.5 * shorter
-
-
-def test_streaming_off_knob_uses_whole_buffer_path() -> None:
-    storage = MemoryStoragePlugin()
-    stager = StreamingStager(8)
-    reqs = [WriteReq("big", stager)]
-
-    async def go():
-        with knobs.override_stream_writes(False), knobs.override_stream_chunk_bytes(
-            CHUNK
-        ):
-            pending = await execute_write_reqs(
-                reqs, storage, memory_budget_bytes=10**9, rank=0
-            )
-            await pending.complete()
-
-    _run(go())
-    expected = b"".join([bytes([i % 251]) * CHUNK for i in range(8)])
-    assert storage.objects["big"] == expected
+    assert stats["overlap_s"] > 0.5 * shorter, stats
 
 
 def test_progress_reporter_logs_occupancy(caplog) -> None:
@@ -389,31 +268,3 @@ def test_progress_reporter_logs_occupancy(caplog) -> None:
     (rec,) = [r for r in caplog.records if "pipeline" in r.message]
     msg = rec.getMessage()
     assert "pending=3" in msg and "io=2" in msg and "0.01 GB done" in msg
-
-
-def test_snapshot_take_restore_streams_through_fs(tmp_path) -> None:
-    """End to end through the FS plugin's write stream (positioned writes +
-    rename commit): a take whose arrays stream chunk-by-chunk restores
-    bit-exact and verifies clean."""
-    import numpy as np
-
-    from torchsnapshot_tpu import Snapshot, StateDict
-
-    rng = np.random.default_rng(3)
-    state = StateDict(
-        w=rng.standard_normal((256, 64)).astype(np.float32),  # 64 KB: streams
-        b=rng.standard_normal((8,)).astype(np.float32),  # tiny: classic path
-    )
-    with knobs.override_stream_chunk_bytes(8192), knobs.override_stream_inflight(
-        2
-    ), knobs.override_stream_writes(True):
-        Snapshot.take(str(tmp_path / "snap"), {"m": state})
-    snap = Snapshot(str(tmp_path / "snap"))
-    restored = StateDict(
-        w=np.zeros((256, 64), dtype=np.float32),
-        b=np.zeros((8,), dtype=np.float32),
-    )
-    snap.restore({"m": restored})
-    assert np.array_equal(restored["w"], state["w"])
-    assert np.array_equal(restored["b"], state["b"])
-    assert snap.verify() == {}

@@ -2,7 +2,7 @@
 
 The slow-marked smoke is registered in pre_commit.yaml's slow lane so the
 zero-copy RAW staging path (lanes, null sink, digest ablation) is exercised
-on every PR at a size that actually streams.
+on every PR at a size of several hash grains a leaf.
 """
 
 import json
@@ -38,9 +38,7 @@ def test_staging_bench_smoke_tiny() -> None:
     assert rec["metric"] == "staging_overhead_gbps"
     det = rec["detail"]
     assert det["size_gb"] > 0
-    for name in (
-        "full", "serial_hash", "no_dedup_sha", "no_digests", "no_stream"
-    ):
+    for name in ("full", "serial_hash", "no_dedup_sha", "no_digests"):
         cfg = det["configs"][name]
         assert cfg["wall_s"] > 0
         assert cfg["gbps"] > 0
@@ -57,17 +55,17 @@ def test_staging_bench_smoke_tiny() -> None:
 
 @pytest.mark.slow
 def test_staging_bench_slow_smoke() -> None:
-    """Slow-lane smoke at a size where every array streams: the zero-copy
-    RAW chunk path (views into host buffers, incremental digest folds) runs
-    end to end, and the full config's hash stream is non-zero while the
-    digest-free config's is zero."""
+    """Slow-lane smoke at a size where every array is hashed in chunks:
+    the zero-copy RAW path (views into host buffers) runs end to end, and
+    the full config's hash stream is non-zero while the digest-free
+    config's is zero."""
     rec = _run_bench(mb=256, arrays=4)
     det = rec["detail"]
     full = det["configs"]["full"]
-    assert full["stage_hash_s"] > 0  # digests folded chunk by chunk
+    assert full["stage_hash_s"] > 0
     assert det["configs"]["no_digests"]["stage_hash_s"] == 0
     # The null sink makes staging the whole wall: busy time is attributed,
-    # not lost (hash folds may overlap the append stream, so compare
+    # not lost (hash chunks may overlap the write, so compare
     # against the decomposition's own total).
     assert full["wall_s"] >= full["stage_busy_s"] - 0.5
 
@@ -76,7 +74,7 @@ def test_staging_bench_slow_smoke() -> None:
 def test_staging_bench_hash_sweep() -> None:
     """The hash-grain x hash-worker sweep (serial-v1 vs chunked-v2 cells,
     STAGING_BENCH_HASH_SWEEP=1) reports wall + hash_cost_s per cell at a
-    size where every array streams."""
+    size where every array is hashed in chunks."""
     rec = _run_bench(
         mb=128, arrays=2, extra_env={"STAGING_BENCH_HASH_SWEEP": "1"}
     )

@@ -522,31 +522,9 @@ def test_resource_balance_flags_early_return_and_raise_paths(tmp_path):
     assert codes(found) == ["TSA601", "TSA601"]
 
 
-def test_resource_balance_flags_stranded_window_admission(tmp_path):
-    # The PR 6 regression shape: an admitted look-ahead window reservation
-    # with no release on the failure path.
-    ctx = make_ctx(
-        tmp_path,
-        {
-            "mod.py": """
-            async def lookahead(lanes, est, arr):
-                if not lanes.try_admit(est):
-                    return None
-                host = await resolve(arr)
-                lanes.release(est)
-                return host
-            """
-        },
-    )
-    found = run_passes(ctx)
-    assert codes(found) == ["TSA602"]
-    assert "window admission" in found[0].message
-
-
 def test_resource_balance_quiet_on_sanctioned_idioms(tmp_path):
     # The scheduler's real shapes: try/finally protection, task-table
-    # handoff, ledger-counter accumulation, and the lane pump's
-    # admit-then-append-to-owning-deque.
+    # handoff, ledger-counter accumulation, estimate correction.
     ctx = make_ctx(
         tmp_path,
         {
@@ -575,13 +553,6 @@ def test_resource_balance_quiet_on_sanctioned_idioms(tmp_path):
                 finally:
                     if outstanding:
                         budget.credit(outstanding)
-
-            def pump(lanes, ranges, row_bytes, pending, arr):
-                for r0, r1 in ranges:
-                    est = (r1 - r0) * row_bytes
-                    if not lanes.try_admit(est, force=not pending):
-                        break
-                    pending.append((arr[r0:r1], est))
 
             def estimate_correction(self, cost, buf):
                 nbytes = memoryview(buf).nbytes

@@ -38,7 +38,7 @@ def _agg(op: str) -> dict:
 _ARTIFACTS = {
     0: {
         "drain_stats_s": {"wall_s": 2.0},
-        "metrics": {"engine.preemptions": 3, "scheduler.stream_chunks": 7},
+        "metrics": {"engine.preemptions": 3, "cache.hits": 7},
     }
 }
 
@@ -58,7 +58,7 @@ def test_sync_stall_includes_the_drain_async_does_not() -> None:
         assert r["drain_gbps"] == round(100 / 1e9 / 2.0, 6)
         assert r["bytes"] == {"written": 100, "deduped": 5}
         assert r["counters"]["preemptions"] == 3
-        assert r["counters"]["stream_chunks"] == 7
+        assert r["counters"]["cache_hits"] == 7
         assert r["skew"] == {"end_skew_s": 0.01, "straggler_rank": 0}
 
 

@@ -11,11 +11,11 @@ The load-bearing assertions:
   the straggler + per-rank commit-barrier wait from the artifacts alone;
 - artifact persistence is fail-open: an injected storage fault on the
   artifact path logs once and the snapshot still commits clean;
-- ``PendingSnapshot.progress()`` is strictly nondecreasing under the
-  streaming write path and ends with ``bytes_written == bytes_total`` ==
-  the payload size;
-- the stall watchdog fires EXACTLY once per stall on an injected hung
-  storage stream, naming the stuck stage.
+- ``PendingSnapshot.progress()`` is strictly nondecreasing over large
+  leaves and ends with ``bytes_written == bytes_total`` == the payload
+  size;
+- the stall watchdog fires EXACTLY once per stall on injected hung
+  storage writes, naming the stuck stage.
 """
 
 import asyncio
@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from torchsnapshot_tpu import Snapshot, StateDict, telemetry
-from torchsnapshot_tpu.io_types import BufferStager, StorageWriteStream, WriteReq
+from torchsnapshot_tpu.io_types import BufferStager, WriteReq
 from torchsnapshot_tpu.scheduler import execute_write_reqs
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
 from torchsnapshot_tpu.telemetry import aggregate as agg_mod
@@ -270,10 +270,10 @@ def test_diff_stats_lines() -> None:
 
 # ---------------------------------------------------------------- progress
 
-def test_progress_monotone_under_streaming(tmp_path) -> None:
+def test_progress_monotone_over_large_leaves(tmp_path) -> None:
     """Acceptance: progress() reports strictly nondecreasing bytes_written
-    that ends equal to the total payload bytes — polled live against the
-    streaming write path."""
+    that ends equal to the total payload bytes — polled live against a
+    drain of leaves several hash grains long."""
     import jax
     import jax.numpy as jnp
 
@@ -284,7 +284,7 @@ def test_progress_monotone_under_streaming(tmp_path) -> None:
         for i in range(2)
     }
     total = sum(a.nbytes for a in arrs.values())
-    with knobs.override_stream_chunk_bytes(64 * 1024):
+    with knobs.override_hash_chunk_bytes(64 * 1024):
         pending = Snapshot.async_take(str(tmp_path / "ck"), {"m": StateDict(**arrs)})
         polls = []
         while not pending.done():
@@ -299,72 +299,58 @@ def test_progress_monotone_under_streaming(tmp_path) -> None:
     assert final["bytes_written"] == final["bytes_total"] == total
     assert final["requests_done"] == final["requests_total"]
     assert final["eta_s"] == 0.0
-    # The streaming path actually engaged (512 KB arrays, 64 KB chunks).
-    metrics = Snapshot.last_telemetry.metrics.as_dict()
-    assert metrics.get("scheduler.stream_chunks", 0) >= 2
 
 
 # ---------------------------------------------------------------- watchdog
 
-class _StreamingStager(BufferStager):
-    def __init__(self, chunks):
-        self.chunks = chunks
+class _BytesStager(BufferStager):
+    def __init__(self, data: bytes):
+        self.data = data
 
     async def stage_buffer(self, executor=None):
-        return b"".join(self.chunks)
+        return self.data
 
     def get_staging_cost_bytes(self) -> int:
-        return sum(len(c) for c in self.chunks)
-
-    def can_stream(self) -> bool:
-        return True
-
-    async def stage_chunks(self, executor=None):
-        for c in self.chunks:
-            await asyncio.sleep(0)
-            yield c
+        return len(self.data)
 
 
-class _HangingStreamStorage(MemoryStoragePlugin):
-    """Appends hang after the first chunk until released — the injected
-    hung storage stream of the watchdog satellite."""
+def _piece_reqs(pieces):
+    # defer_staging: everything runs on the drain (complete()) — the
+    # async-take shape the watchdog targets.
+    return [
+        WriteReq(f"obj{i}", _BytesStager(p), defer_staging=True)
+        for i, p in enumerate(pieces)
+    ]
+
+
+class _HangingWriteStorage(MemoryStoragePlugin):
+    """Data writes run one at a time and hang after the first until
+    released — the injected hung storage of the watchdog satellite."""
 
     def __init__(self):
         super().__init__()
         self.release = asyncio.Event()
-        self.appends = 0
+        self.serial = asyncio.Lock()
+        self.writes = 0
 
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        inner = await super().write_stream(path)
-        outer = self
-
-        class _Hanging(StorageWriteStream):
-            async def append(self, buf):
-                outer.appends += 1
-                if outer.appends > 1:
-                    await outer.release.wait()
-                await inner.append(buf)
-
-            async def commit(self):
-                await inner.commit()
-
-            async def abort(self):
-                await inner.abort()
-
-        return _Hanging()
+    async def write(self, write_io) -> None:
+        if not write_io.path.startswith("obj"):
+            return await super().write(write_io)
+        async with self.serial:
+            self.writes += 1
+            if self.writes > 1:
+                await self.release.wait()
+            await super().write(write_io)
 
 
 def test_watchdog_fires_exactly_once_per_stall(caplog) -> None:
     chunk = 1024
     chunks = [bytes([i]) * chunk for i in range(6)]
-    storage = _HangingStreamStorage()
-    # defer_staging: the stream runs on the drain (complete()), alongside
-    # the releaser task — the async-take shape the watchdog targets.
-    req = WriteReq("obj", _StreamingStager(chunks), defer_staging=True)
+    storage = _HangingWriteStorage()
 
     async def go():
         pending = await execute_write_reqs(
-            [req], storage, memory_budget_bytes=1 << 20, rank=0
+            _piece_reqs(chunks), storage, memory_budget_bytes=1 << 20, rank=0
         )
 
         async def release_later():
@@ -377,7 +363,7 @@ def test_watchdog_fires_exactly_once_per_stall(caplog) -> None:
         await pending.complete()
         await releaser
 
-    with knobs.override_stall_warn_s(0.15), knobs.override_stream_chunk_bytes(chunk):
+    with knobs.override_stall_warn_s(0.15):
         with caplog.at_level(
             logging.WARNING, logger="torchsnapshot_tpu.telemetry.progress"
         ):
@@ -388,10 +374,10 @@ def test_watchdog_fires_exactly_once_per_stall(caplog) -> None:
     assert len(stalls) == 1, [r.getMessage() for r in stalls]
     payload = json.loads(stalls[0].getMessage().split("stalled: ", 1)[1])
     assert payload["event"] == "snapshot_stall"
-    assert payload["stuck_stage"] in ("streaming", "io")
+    assert payload["stuck_stage"] == "io"
     assert payload["bytes_written"] < payload["bytes_total"]
-    # The stream completed after release: the object is intact.
-    assert storage.objects["obj"] == b"".join(chunks)
+    # The writes completed after release: the objects are intact.
+    assert [storage.objects[f"obj{i}"] for i in range(6)] == chunks
 
 
 def test_watchdog_rearms_for_a_second_stall(caplog) -> None:
@@ -402,37 +388,27 @@ def test_watchdog_rearms_for_a_second_stall(caplog) -> None:
     class _TwoStallStorage(MemoryStoragePlugin):
         def __init__(self):
             super().__init__()
-            self.appends = 0
+            self.serial = asyncio.Lock()
+            self.writes = 0
 
-        async def write_stream(self, path):
-            inner = await super().write_stream(path)
-            outer = self
-
-            class _S(StorageWriteStream):
-                async def append(self, buf):
-                    outer.appends += 1
-                    if outer.appends in (2, 4):
-                        await asyncio.sleep(0.35)  # two separate stalls
-                    await inner.append(buf)
-
-                async def commit(self):
-                    await inner.commit()
-
-                async def abort(self):
-                    await inner.abort()
-
-            return _S()
+        async def write(self, write_io) -> None:
+            if not write_io.path.startswith("obj"):
+                return await super().write(write_io)
+            async with self.serial:
+                self.writes += 1
+                if self.writes in (2, 4):
+                    await asyncio.sleep(0.35)  # two separate stalls
+                await super().write(write_io)
 
     storage = _TwoStallStorage()
-    req = WriteReq("obj", _StreamingStager(chunks), defer_staging=True)
 
     async def go():
         pending = await execute_write_reqs(
-            [req], storage, memory_budget_bytes=1 << 20, rank=0
+            _piece_reqs(chunks), storage, memory_budget_bytes=1 << 20, rank=0
         )
         await pending.complete()
 
-    with knobs.override_stall_warn_s(0.12), knobs.override_stream_chunk_bytes(chunk):
+    with knobs.override_stall_warn_s(0.12):
         with caplog.at_level(
             logging.WARNING, logger="torchsnapshot_tpu.telemetry.progress"
         ):
@@ -441,7 +417,7 @@ def test_watchdog_rearms_for_a_second_stall(caplog) -> None:
         r for r in caplog.records if "snapshot drain stalled" in r.getMessage()
     ]
     assert len(stalls) == 2, [r.getMessage() for r in stalls]
-    assert storage.objects["obj"] == b"".join(chunks)
+    assert [storage.objects[f"obj{i}"] for i in range(4)] == chunks
 
 
 # --------------------------------------------------------- progress tracker

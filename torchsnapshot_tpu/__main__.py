@@ -69,7 +69,7 @@ Beyond the reference's surface (it ships no CLI). Subcommands:
         catalog keeps beside each ``take(job=, step=)`` commit: one row per
         step (stall, drain wall, throughput, bytes, preemptions, skew) with
         the health detectors' anomalies flagged in place (stall spike,
-        drain cliff, streaming inversion, straggler drift). Exit code 1
+        drain cliff, straggler drift). Exit code 1
         when any anomaly is flagged. See docs/observability.md.
 
     python -m torchsnapshot_tpu monitor [dump.json]

@@ -189,62 +189,6 @@ class BatchedBufferStager(BufferStager):
         await asyncio.gather(*(stage_one(*m) for m in self.members))
         return slab
 
-    def can_stream(self) -> bool:
-        # Capture-safe members only: deferred members are immutable (forked)
-        # device data, and non-deferred members of a SYNC take are read
-        # while the caller is still blocked. An async take's mutable host
-        # members (is_async_snapshot stagers on host arrays) keep the
-        # all-at-once path — they must land in private buffers before
-        # async_take returns, and a stream reads the live array past that.
-        if len(self.members) <= 1:
-            return False
-        from .io_preparers.array import _is_jax_array
-
-        for req, _, _ in self.members:
-            if req.defer_staging:
-                continue
-            stager = req.buffer_stager
-            if getattr(stager, "is_async_snapshot", False) and not _is_jax_array(
-                getattr(stager, "arr", None)
-            ):
-                return False
-        return True
-
-    async def stage_chunks(self, executor: Optional[Executor] = None):
-        """One chunk per member, in slab offset order, with one member of
-        staging lookahead — member k+1's D2H runs while member k's bytes
-        are appended to storage. Peak host RAM is ~2 members instead of
-        the whole slab."""
-        telemetry.counter_add("batcher.slabs_host_packed")
-        next_task = None
-        try:
-            for idx, (req, begin, end) in enumerate(self.members):
-                task = next_task
-                if task is None:
-                    task = asyncio.ensure_future(
-                        req.buffer_stager.stage_buffer(executor)
-                    )
-                if idx + 1 < len(self.members):
-                    nreq = self.members[idx + 1][0]
-                    next_task = asyncio.ensure_future(
-                        nreq.buffer_stager.stage_buffer(executor)
-                    )
-                else:
-                    next_task = None
-                buf = await task
-                mv = memoryview(buf)
-                if mv.nbytes != end - begin:
-                    raise RuntimeError(
-                        f"Staged size {mv.nbytes} != planned slab slot "
-                        f"{end - begin} for {req.path}"
-                    )
-                yield mv
-        except BaseException:
-            if next_task is not None:
-                next_task.cancel()
-                await asyncio.gather(next_task, return_exceptions=True)
-            raise
-
     def get_staging_cost_bytes(self) -> int:
         return self.total
 
@@ -272,24 +216,6 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
     or by degradation) of which ``batcher.slabs_pack_degraded`` were
     planned for the device.
     """
-
-    # stage_chunks yields views into the one packed host buffer — the
-    # scheduler keeps the full staging cost debited for the stream's life.
-    stream_holds_full_buffer = True
-
-    async def stage_chunks(self, executor: Optional[Executor] = None):
-        """Keep the single-packed-D2H win and still stream the appends:
-        pack + fetch once, then yield stream-chunk slices so the storage
-        write of slice k overlaps the hash/append machinery of k+1 and the
-        slab lands through the same streamed-object path as big arrays."""
-        buf = await self.stage_buffer(executor)
-        mv = memoryview(buf)
-        step = knobs.get_stream_chunk_bytes()
-        if mv.nbytes == 0:
-            yield mv
-            return
-        for off in range(0, mv.nbytes, step):
-            yield mv[off : off + step]
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         import numpy as np

@@ -9,15 +9,12 @@ and ``drain_vs_link`` stuck at ~0.66. This module closes the gap with two
 cooperating pieces:
 
 - :class:`TransferLanes` — N concurrent transfer lanes (a dedicated
-  ``ThreadPoolExecutor``, knob ``TORCHSNAPSHOT_TPU_D2H_LANES``) plus a
-  byte-bounded *hint window* (knob ``TORCHSNAPSHOT_TPU_D2H_WINDOW_BYTES``):
-  ``copy_to_host_async()`` is issued for a window of upcoming chunks/requests
-  the moment window space admits them, and the (already in-flight) transfers
-  resolve out of the lane executor concurrently — so the transfer engine
-  streams back-to-back while serialize/hash/append work on earlier chunks.
-  Window admissions are debited against the pipeline's existing memory
-  budget (the resolved host buffers are real RAM), and every admission is
-  released by the time a stream ends or aborts.
+  ``ThreadPoolExecutor``, knob ``TORCHSNAPSHOT_TPU_D2H_LANES``):
+  ``copy_to_host_async()`` is issued when a request is admitted, and the
+  (already in-flight) transfers resolve out of the lane executor
+  concurrently — so the transfer engine runs back-to-back while
+  serialize/hash/write work on earlier requests. The resolved host bytes
+  are debited by the request's own admission.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
   sub-phase intervals (``d2h`` / ``serialize`` / ``hash``). The scheduler
   derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s`` from these
@@ -41,7 +38,7 @@ import logging
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -126,8 +123,8 @@ class timed:
     stretch is also a ``tss.stage.<kind>`` event of a running profiler
     trace. ``sized(n)`` inside the body gives the bytes where only the
     work's result says them. ``times`` None: nothing is recorded. Per-chunk
-    work (``stage.hash_chunk``, streamed appends) stays with ``record``:
-    too many events for a trace."""
+    work (``stage.hash_chunk``) stays with ``record``: too many events for
+    a trace."""
 
     __slots__ = ("_times", "_kind", "_path", "_nbytes", "_device", "_t0", "_ann")
 
@@ -173,50 +170,11 @@ class timed:
 
 
 class TransferLanes:
-    """N concurrent D2H resolution lanes + a byte-bounded hint window.
+    """N concurrent D2H resolution lanes: a dedicated transfer executor."""
 
-    The window bounds how many bytes of *upcoming* (not-yet-consumed) chunks
-    may be hinted and resolving at once; admissions are optionally debited
-    against the pipeline's memory budget via :meth:`bind_budget` (the
-    resolved host buffers are real RAM the budget must see). ``try_admit``
-    never blocks — a full window simply means no further look-ahead this
-    round, and the caller re-pumps when it releases — so the lanes can
-    never deadlock a pipeline, only stop helping it.
-    """
-
-    def __init__(
-        self,
-        lanes: Optional[int] = None,
-        window_bytes: Optional[int] = None,
-    ) -> None:
+    def __init__(self, lanes: Optional[int] = None) -> None:
         self.lane_count = lanes if lanes is not None else knobs.get_d2h_lanes()
-        self.window_bytes = (
-            window_bytes
-            if window_bytes is not None
-            else knobs.get_d2h_window_bytes()
-        )
         self._executor: Optional[ThreadPoolExecutor] = None
-        self._lock = threading.Lock()
-        self._outstanding = 0
-        # Peak admitted bytes — test/telemetry surface for the window bound.
-        self.window_hwm = 0
-        self._on_admit: Optional[Callable[[int], None]] = None
-        self._on_release: Optional[Callable[[int], None]] = None
-        self._headroom: Optional[Callable[[], int]] = None
-
-    def bind_budget(
-        self,
-        on_admit: Callable[[int], None],
-        on_release: Callable[[int], None],
-        headroom: Optional[Callable[[], int]] = None,
-    ) -> None:
-        """Route window admissions through the owning pipeline's memory
-        budget (debit on admit, credit on release); ``headroom`` gates
-        non-forced admissions so look-ahead never starves request
-        admission of budget it needs more."""
-        self._on_admit = on_admit
-        self._on_release = on_release
-        self._headroom = headroom
 
     def executor(self) -> ThreadPoolExecutor:
         if self._executor is None:
@@ -226,56 +184,6 @@ class TransferLanes:
             )
         return self._executor
 
-    @property
-    def outstanding_bytes(self) -> int:
-        with self._lock:
-            return self._outstanding
-
-    def try_admit(self, nbytes: int, force: bool = False) -> bool:
-        """Reserve window space for one upcoming transfer. ``force`` admits
-        regardless (each stream's FIRST look-ahead chunk, so a window
-        smaller than a chunk degrades to one-ahead instead of none)."""
-        with self._lock:
-            if not force:
-                if self._outstanding + nbytes > self.window_bytes:
-                    return False
-                if self._headroom is not None and self._headroom() < nbytes:
-                    return False
-            self._outstanding += nbytes
-            if self._outstanding > self.window_hwm:
-                self.window_hwm = self._outstanding
-        if self._on_admit is not None:
-            self._on_admit(nbytes)
-        return True
-
-    def release(self, nbytes: int) -> None:
-        with self._lock:
-            self._outstanding -= nbytes
-        if self._on_release is not None:
-            self._on_release(nbytes)
-
-    def release_all(self) -> int:
-        """Abort-path sweep: credit whatever is still admitted (normally 0 —
-        streams release their own admissions in their cleanup) so the
-        budget-balanced invariant holds on every failure path."""
-        with self._lock:
-            n = self._outstanding
-            self._outstanding = 0
-        if n and self._on_release is not None:
-            self._on_release(n)
-        if n:
-            from .utils import knobs
-
-            if knobs.is_debug_ledger_enabled():
-                # Sanitizer witness: the sweep doing real work means some
-                # stream was cancelled before its own cleanup ran — expected
-                # on hard aborts, but worth a line when ledger-auditing.
-                logger.info(
-                    "d2h lane sweep released %d stranded look-ahead bytes",
-                    n,
-                )
-        return n
-
     def start(
         self,
         arr: Any,
@@ -283,7 +191,6 @@ class TransferLanes:
         loop,
         times: Optional[StageTimes] = None,
         location: str = "",
-        skip_hint: bool = False,
     ):
         """Hint ``arr``'s transfer NOW and schedule its resolve on a lane.
 
@@ -291,8 +198,7 @@ class TransferLanes:
         is timed inside the lane thread, so the recorded ``d2h`` interval is
         transfer time only — not the time the future waited to be awaited
         (that wait is exactly the overlap the lanes exist to create)."""
-        if not skip_hint:
-            hint_copy_to_host(arr)
+        hint_copy_to_host(arr)
         devices = arr.devices()
         device = next(iter(devices)).id if len(devices) == 1 else None
 

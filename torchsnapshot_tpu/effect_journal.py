@@ -14,9 +14,10 @@ fingerprint, and the originating call site above the storage plumbing.
 
 The journal deliberately sits at the BOTTOM of the wrapper stack (below
 the fault injector, directly above the real backend): an op a fault rule
-suppresses never reached storage and is never journaled, while a torn
-write's partial stream append IS journaled — the journal is the ground
-truth of what a crash at any instant could have left behind. The
+suppresses (a torn write's temp-file debris included: no object ever
+appeared) never reached storage and is never journaled — the journal is
+the ground truth of what a crash at any instant could have left behind.
+The
 crash-state explorer (``dev/crash_explorer.py``) replays every journal
 prefix into a fresh store and asserts each one is a restorable crash
 state, naming the effect seq and call site when one is not.
@@ -38,24 +39,11 @@ import traceback
 from dataclasses import dataclass
 from typing import List, Optional
 
-from .io_types import (
-    ReadIO,
-    StoragePlugin,
-    StorageWriteStream,
-    WriteIO,
-)
+from .io_types import ReadIO, StoragePlugin, WriteIO
 
 # Mutating op classes, aligned with ``faults._OPS`` so a journal entry and
 # a kill-point rule name the same thing.
-MUTATING_OPS = (
-    "write",
-    "stream_open",
-    "append",
-    "commit",
-    "abort",
-    "delete",
-    "link",
-)
+MUTATING_OPS = ("write", "delete", "link")
 
 
 def _fingerprint(data) -> str:
@@ -94,10 +82,9 @@ class Effect:
     """One durable mutation, as observed at the storage boundary.
 
     ``seq`` is process-wide and monotonic across every journaled plugin:
-    the total order a single-process crash could truncate. ``stream_id``
-    ties append/commit/abort effects to their ``stream_open``. ``payload``
-    is a private copy of the written bytes (None for delete/commit/abort),
-    retained so the explorer can replay the effect bit-exactly."""
+    the total order a single-process crash could truncate. ``payload``
+    is a private copy of the written bytes (None for delete), retained so
+    the explorer can replay the effect bit-exactly."""
 
     seq: int
     op: str
@@ -106,7 +93,6 @@ class Effect:
     nbytes: int
     fingerprint: str
     site: str
-    stream_id: int = -1
     payload: Optional[bytes] = None
 
     def render(self) -> str:
@@ -122,7 +108,6 @@ class EffectJournal:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._effects: List[Effect] = []
-        self._next_stream_id = 0
 
     def record(
         self,
@@ -130,7 +115,6 @@ class EffectJournal:
         origin: str,
         path: str,
         payload=None,
-        stream_id: int = -1,
     ) -> Effect:
         data = None if payload is None else bytes(payload)
         site = _origin_site()
@@ -143,17 +127,10 @@ class EffectJournal:
                 nbytes=0 if data is None else len(data),
                 fingerprint=_fingerprint(data),
                 site=site,
-                stream_id=stream_id,
                 payload=data,
             )
             self._effects.append(effect)
         return effect
-
-    def new_stream_id(self) -> int:
-        with self._lock:
-            sid = self._next_stream_id
-            self._next_stream_id += 1
-            return sid
 
     def effects(self) -> List[Effect]:
         """A point-in-time copy, seq order."""
@@ -207,50 +184,13 @@ def reset() -> None:
         _INITIALIZED = False
 
 
-class _EffectRecordingWriteStream(StorageWriteStream):
-    """Journals append/commit/abort under the stream's id; proxies the
-    inner stream otherwise."""
-
-    def __init__(
-        self, journal: EffectJournal, origin: str, path: str,
-        stream_id: int, inner: StorageWriteStream,
-    ) -> None:
-        self._journal = journal
-        self._origin = origin
-        self._path = path
-        self._stream_id = stream_id
-        self.inner = inner
-
-    async def append(self, buf) -> None:
-        # Journal BEFORE the inner append: a crash mid-append may have
-        # landed any prefix of these bytes, and the explorer's interior
-        # sampling models exactly that.
-        self._journal.record(
-            "append", self._origin, self._path,
-            payload=buf, stream_id=self._stream_id,
-        )
-        await self.inner.append(buf)
-
-    async def commit(self) -> None:
-        await self.inner.commit()
-        self._journal.record(
-            "commit", self._origin, self._path, stream_id=self._stream_id,
-        )
-
-    async def abort(self) -> None:
-        await self.inner.abort()
-        self._journal.record(
-            "abort", self._origin, self._path, stream_id=self._stream_id,
-        )
-
-
 class EffectRecordingPlugin(StoragePlugin):
     """Wraps any :class:`StoragePlugin`; journals every mutating op.
 
     Non-mutating ops (read / list_prefix / prune_empty / close) proxy
-    straight through. Completed atomic ops (write, link_in, stream commit)
-    journal AFTER the inner op succeeds — an op the backend rejected never
-    became durable; stream appends journal before (see above)."""
+    straight through. Completed atomic ops (write, link_in) journal AFTER
+    the inner op succeeds — an op the backend rejected never became
+    durable."""
 
     def __init__(
         self, inner: StoragePlugin, journal: EffectJournal, origin: str,
@@ -258,10 +198,6 @@ class EffectRecordingPlugin(StoragePlugin):
         self.inner = inner
         self._journal = journal
         self._origin = origin
-
-    @property
-    def supports_streaming(self) -> bool:  # type: ignore[override]
-        return self.inner.supports_streaming
 
     @property
     def scales_io_with_local_world(self) -> bool:  # type: ignore[override]
@@ -279,16 +215,6 @@ class EffectRecordingPlugin(StoragePlugin):
     async def delete(self, path: str) -> None:
         await self.inner.delete(path)
         self._journal.record("delete", self._origin, path)
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        inner = await self.inner.write_stream(path)
-        sid = self._journal.new_stream_id()
-        self._journal.record(
-            "stream_open", self._origin, path, stream_id=sid,
-        )
-        return _EffectRecordingWriteStream(
-            self._journal, self._origin, path, sid, inner,
-        )
 
     async def link_in(self, src_abs_path: str, path: str) -> bool:
         linked = await self.inner.link_in(src_abs_path, path)

@@ -1,7 +1,7 @@
 """Unified priority-aware dataflow engine.
 
 One memory-budgeted, priority-classed DAG executor for every byte the
-library moves: takes (whole-buffer and chunk-streamed writes), restores
+library moves: takes (stage → io writes), restores
 (fetch → consume reads), and the secondary consumers (scrub, verify,
 ``Snapshot.gc``, cache populates, swarm/bcast origin fetches) all lower
 onto the same task-graph model — nodes are stage/hash/io/verify/consume
@@ -11,7 +11,7 @@ executed by :class:`GraphExecutor` under one admission discipline.
 Priority classes (``FOREGROUND > NORMAL > BACKGROUND``) preempt at chunk
 granularity through the process-wide :class:`QoSArbiter`: a foreground
 replica restore arriving mid-drain steals the next admission (budget,
-io/hash/transfer-pool slots, stream chunks) rather than waiting for the
+io/hash/transfer-pool slots) rather than waiting for the
 drain to finish. See ``docs/performance.md`` ("The dataflow engine") and
 ``benchmarks/qos/``.
 """

@@ -4,8 +4,8 @@ One executor drives one operation's task graph (see ``graph.py``): it owns
 the operation's byte budget, the per-pool slot caps, the task tables, the
 interval/span recording, the occupancy reporter, the stall watchdog, and
 the abort sweep — the machinery that used to exist three times over in
-``scheduler.py`` (whole-buffer writes, streamed writes, reads), each with
-its own budget accounting, abort semantics, and telemetry shape.
+``scheduler.py``, each with its own budget accounting, abort semantics,
+and telemetry shape.
 
 Execution semantics (identical to the legacy pipelines, now stated once):
 
@@ -16,9 +16,7 @@ Execution semantics (identical to the legacy pipelines, now stated once):
 - **Budget handoff**: a node's admission reservation (re-costed to the
   actual buffer size via ``ctx.recost``) travels along its ``successor``
   edge and is credited back when the edge's final node completes — or by
-  the abort sweep, on every failure path. ``self_budget`` nodes (chunk
-  streams) manage per-chunk debits in their own body; the engine credits
-  their admission reservation only if the body never started.
+  the abort sweep, on every failure path.
 - **Priority**: the executor registers demand for its class with the
   process-wide :class:`~.qos.QoSArbiter` while it runs, and pauses ALL new
   admissions (budget, slots — including successor dispatch, i.e. storage
@@ -140,21 +138,14 @@ class ProgressReporter:
 
 
 class NodeContext:
-    """What a node body sees of its engine: cost correction, span-byte
-    attribution, interval recording for self-recording (stream) nodes, and
-    the cooperative preemption point."""
+    """What a node body sees of its engine: cost correction and span-byte
+    attribution."""
 
     __slots__ = ("engine", "node")
 
     def __init__(self, engine: "GraphExecutor", node: Node) -> None:
         self.engine = engine
         self.node = node
-
-    @property
-    def reservation(self) -> int:
-        """This node's current admission reservation (bytes). self_budget
-        bodies read it to take over per-chunk accounting."""
-        return self.engine._reservation.get(self.node, 0)
 
     @property
     def admitted_at(self) -> float:
@@ -174,19 +165,6 @@ class NodeContext:
         touching the budget (e.g. actual fetched bytes on a read whose
         reservation is the consuming cost)."""
         self.engine._nbytes[self.node] = nbytes
-
-    def record_interval(
-        self, kind: str, t0: float, path: str = "", nbytes: int = 0
-    ) -> None:
-        """Record one sub-step interval from inside a self-recording node
-        (streamed chunks / appends): joins the engine's stage/io interval
-        streams and, when telemetry is on, exports the span."""
-        self.engine.record_interval(kind, t0, path, nbytes)
-
-    async def preemption_point(self) -> None:
-        """Chunk-granular yield: awaits while a higher class has demand."""
-        await self.engine.preemption_point()
-
 
 class GraphExecutor:
     """Drives one task graph to completion under one budget, one priority
@@ -230,7 +208,6 @@ class GraphExecutor:
         # body records parents to the node's span (itself recorded at reap).
         self._span_id: Dict[Node, int] = {}
         self._nbytes: Dict[Node, int] = {}
-        self._started: Dict[Node, bool] = {}
         self._inflight: Dict[str, int] = {}
         self._pool_order: List[str] = []
         self.windows: List[Interval] = []
@@ -285,7 +262,7 @@ class GraphExecutor:
     def unfinished_in(self, pools: Tuple[str, ...]) -> int:
         """Pending + in-flight nodes in the given pools (deferred nodes
         excluded — they are not yet admissible). The capture-point
-        predicate: phase 1 runs until no stage/stream work remains."""
+        predicate: phase 1 runs until no staging work remains."""
         n = sum(1 for node in self._pending if node.pool in pools)
         n += sum(self._inflight.get(p, 0) for p in pools)
         return n
@@ -490,11 +467,7 @@ class GraphExecutor:
         self.admitted += 1
 
     async def _run_node(self, node: Node, payload: Any) -> Any:
-        # `started` marks whether the body ever ran: an abort that cancels
-        # a never-started self_budget node must credit its admission
-        # reservation itself (the body's own finally-credits never execute).
-        self._started[node] = True
-        if self._tm is not None and node.record_span:
+        if self._tm is not None:
             self._span_id[node] = self._tm.begin_deferred_span()
         return await node.run(NodeContext(self, node), payload)
 
@@ -506,29 +479,24 @@ class GraphExecutor:
             self._inflight[node.pool] -= 1
             reservation = self._reservation.pop(node, 0)
             t0 = self._t0.pop(node, 0.0)
-            started = self._started.pop(node, False)
             span_id = self._span_id.pop(node, None)
             try:
                 result = task.result()
             except BaseException:
                 # Failed node releases its reservation: already popped, so
-                # the abort sweep can't see (or double-credit) it. A
-                # started self_budget body credited its own debits in its
-                # finally blocks.
-                if not node.self_budget or not started:
-                    self.budget.credit(reservation)
+                # the abort sweep can't see (or double-credit) it.
+                self.budget.credit(reservation)
                 raise
             nbytes = self._nbytes.pop(node, reservation)
-            if node.record_span:
-                self.record_interval(
-                    node.kind, t0, node.path, nbytes, stream=node.stream,
-                    span_id=span_id,
-                )
+            self.record_interval(
+                node.kind, t0, node.path, nbytes, stream=node.stream,
+                span_id=span_id,
+            )
             if node.successor is not None:
                 # The edge handoff: result + reservation travel together;
                 # the successor's completion (or the abort sweep) credits.
                 self._ready.append((node.successor, result, reservation))
-            elif not node.self_budget:
+            else:
                 self.budget.credit(reservation)
 
     def _recost(self, node: Node, nbytes: int) -> None:
@@ -554,8 +522,7 @@ class GraphExecutor:
         """One finished node/sub-step: record its interval (stats) and,
         when telemetry is on, the corresponding span. ``stream="auto"``
         routes ``io`` to the io stream and everything else to the staging
-        stream (the self-recording stream nodes' contract: chunk stagings
-        join the staging stream, appends the io stream)."""
+        stream."""
         t1 = time.monotonic()
         if stream == "auto":
             stream = "io" if kind == "io" else "stage"
@@ -574,48 +541,6 @@ class GraphExecutor:
                 span_id=span_id,
             )
 
-    # ------------------------------------------------------------ preemption
-
-    async def preemption_point(self) -> None:
-        """Cooperative chunk-granular yield for node bodies (stream
-        producers): awaits while a strictly higher class has demand,
-        bounded by the max-pause knob."""
-        if not self._arbiter.preempted(self.priority):
-            return
-        t0 = time.monotonic()
-        max_pause = knobs.get_qos_max_pause_s()
-        poll = knobs.get_qos_poll_s()
-        self.preemptions += 1
-        telemetry.counter_add("engine.preemptions")
-        telemetry.recorder.record_event(
-            "engine.pause",
-            {
-                "engine": self.kind,
-                "rank": self.rank,
-                "priority": self.priority.name,
-                "demand": self._arbiter.demand_snapshot(),
-            },
-        )
-        while self._arbiter.preempted(self.priority):
-            if max_pause > 0 and time.monotonic() - t0 >= max_pause:
-                break
-            await asyncio.sleep(poll)
-        t1 = time.monotonic()
-        waited = t1 - t0
-        self.pause_intervals.append((t0, t1))
-        self.preempted_wait_s += waited
-        telemetry.counter_add("engine.preempted_wait_s", waited)
-        telemetry.histogram_observe("engine.pause_s", waited)
-        telemetry.recorder.record_event(
-            "engine.resume",
-            {
-                "engine": self.kind,
-                "rank": self.rank,
-                "priority": self.priority.name,
-                "paused_s": round(waited, 6),
-            },
-        )
-
     # ---------------------------------------------------------------- aborts
 
     async def abort(self) -> None:
@@ -632,15 +557,10 @@ class GraphExecutor:
             node = self._tasks.pop(task)
             self._inflight[node.pool] -= 1
             reservation = self._reservation.pop(node, 0)
-            started = self._started.pop(node, False)
             self._t0.pop(node, None)
             self._span_id.pop(node, None)
             self._nbytes.pop(node, None)
-            # Started self_budget bodies credit their own debits (including
-            # the admission reservation they took over) in their finally
-            # blocks; everyone else's reservation is swept here.
-            if not node.self_budget or not started:
-                self.budget.credit(reservation)
+            self.budget.credit(reservation)
         while self._ready:
             _node, _payload, reservation = self._ready.popleft()
             self.budget.credit(reservation)

@@ -2,23 +2,19 @@
 
 A graph is a set of :class:`Node` chains. Each node is one unit of work —
 a ``stage`` (D2H + serialize), ``hash``, ``io`` (storage write/read),
-``verify``, ``consume`` (deserialize + scatter), ``stream`` (a whole
-chunk-streamed request that does its own per-chunk accounting), or
-``delete`` — with a byte cost and a thread/slot pool. Edges
+``verify``, ``consume`` (deserialize + scatter), or ``delete`` — with a
+byte cost and a thread/slot pool. Edges
 (``successor``) carry both the data handoff (the predecessor's result
 becomes the successor's payload) and the *budget* handoff: the
 reservation debited when the predecessor was admitted travels along the
 edge and is credited back only when the edge's final node completes (or
-the graph aborts). That one rule is what used to be hand-rolled three
-times in ``scheduler.py`` — stage→io buffers, streamed chunks, and
-fetch→consume reads all reduce to it.
+the graph aborts). Stage→io buffers and fetch→consume reads both reduce
+to that one rule.
 
-All three legacy execution paths lower onto this model:
+Both execution paths lower onto this model:
 
-- whole-buffer writes: ``stage`` node (cost = staging estimate, re-costed
-  to the actual buffer on completion) → ``io`` node (hash + dedup + write);
-- streamed writes: one ``stream`` node (``self_budget``: admitted at its
-  steady-state footprint, per-chunk debits/credits inside the body);
+- writes: ``stage`` node (cost = staging estimate, re-costed to the
+  actual buffer on completion) → ``io`` node (hash + dedup + write);
 - reads: ``read_io`` node (fetch + digest verify, cost = consuming cost) →
   ``consume`` node.
 
@@ -36,8 +32,7 @@ from .qos import Priority  # noqa: F401 - re-exported as part of the model
 
 # A node body: ``async def body(ctx, payload)``. ``payload`` is the
 # predecessor's result (None for root nodes); ``ctx`` is the engine's
-# NodeContext (budget ops for self_budget nodes, recost/note_bytes,
-# preemption_point).
+# NodeContext (recost/note_bytes).
 NodeBody = Callable[[Any, Any], Awaitable[Any]]
 
 
@@ -52,8 +47,6 @@ class Node:
         "stream",
         "path",
         "deferred",
-        "self_budget",
-        "record_span",
         "successor",
     )
 
@@ -67,19 +60,15 @@ class Node:
         stream: Optional[str] = None,
         path: str = "",
         deferred: bool = False,
-        self_budget: bool = False,
-        record_span: bool = True,
         successor: Optional["Node"] = None,
     ) -> None:
         self.kind = kind  # span suffix: <span_prefix>.<kind>
         self.run = run
         self.cost_bytes = cost_bytes  # admission reservation (bytes)
-        self.pool = pool  # slot pool ("staging"/"streaming"/"io"/"consume")
+        self.pool = pool  # slot pool ("staging"/"io"/"consume")
         self.stream = stream  # interval stream the execution joins, or None
         self.path = path  # telemetry attribution
         self.deferred = deferred  # inadmissible until release_deferred()
-        self.self_budget = self_budget  # body owns per-chunk debits/credits
-        self.record_span = record_span  # False: body records its own spans
         self.successor = successor  # data+budget handoff edge
 
     def then(self, node: "Node") -> "Node":

@@ -32,8 +32,7 @@ def smaller_than(
     records: Sequence[Tuple[float, float, int]], limit: int
 ) -> List[Tuple[float, float, int]]:
     """The ``(t0, t1, nbytes)`` records of objects under ``limit`` bytes. A
-    record that carries no size (``nbytes`` 0: the commit of a streamed
-    object) is not one of them."""
+    record that carries no size (``nbytes`` 0) is not one of them."""
     return [r for r in records if 0 < r[2] < limit]
 
 

@@ -4,7 +4,7 @@ Every operation the dataflow engine runs carries one of three priority
 classes — ``FOREGROUND > NORMAL > BACKGROUND``. The arbiter is the one
 process-wide rendezvous between them: an operation *registers demand* for
 its class while it runs, and every engine (and every cooperating
-chunk-granular loop: stream producers, swarm/bcast origin fetches, cache
+chunk-granular loop: swarm/bcast origin fetches, cache
 populates) asks ``preempted(my_class)`` before admitting its next unit of
 work. While a strictly higher class has registered demand, lower-class
 admission pauses — budget, io/hash/transfer-pool slots, and storage

@@ -1,7 +1,7 @@
 """Deterministic fault injection for storage plugins.
 
 The robustness analogue of the telemetry layer: every crash-consistency
-claim this library makes (atomic commit, abort-leaves-nothing streams,
+claim this library makes (atomic commit, failed-write-leaves-nothing,
 collective-progress retry, barrier error propagation) is only as good as
 the failure scenarios that exercise it, and real storage faults are neither
 deterministic nor portable across backends. :class:`FaultyStoragePlugin`
@@ -24,8 +24,7 @@ Spec grammar (rules separated by ``;``, fields by ``,``)::
           | window=<float>                  # collective-progress window (s)
           | op=<op>[,<field>=<value>...]    # one injection rule
 
-    op    = write | read | delete | stream_open | append | commit | abort
-          | link | list | peer_serve | any
+    op    = write | read | delete | link | list | peer_serve | any
           | catalog_append | steprecord_append | cache_bitmap | read_chunk
 
     ``catalog_append`` / ``steprecord_append`` are *derived* write classes:
@@ -95,7 +94,7 @@ Spec grammar (rules separated by ``;``, fields by ``,``)::
 Examples::
 
     op=write,at=2,kind=kill                    # die at the 3rd object write
-    op=append,kind=transient,times=3           # 3 retryable append failures
+    op=write,kind=transient,times=3            # 3 retryable write failures
     op=write,path=.snapshot_metadata,kind=fail # commit can never land
     seed=7;op=write,p=0.2,kind=torn,bytes=100  # seeded 20% torn writes
 
@@ -122,12 +121,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from . import telemetry
-from .io_types import (
-    ReadIO,
-    StoragePlugin,
-    StorageWriteStream,
-    WriteIO,
-)
+from .io_types import ReadIO, StoragePlugin, WriteIO
 from .storage_plugins.cloud_retry import CollectiveProgress, retry_transient
 
 logger = logging.getLogger(__name__)
@@ -136,10 +130,6 @@ _OPS = (
     "write",
     "read",
     "delete",
-    "stream_open",
-    "append",
-    "commit",
-    "abort",
     "link",
     "list",
     "peer_serve",
@@ -210,22 +200,10 @@ _CRASH_SURFACE = (
     ("export.py:write_trace_obj", "fail-open"),
     ("fs.py:FSStoragePlugin._link_in_inner", "link"),
     ("fs.py:FSStoragePlugin._write_inner", "write"),
-    ("fs.py:_FSWriteStream._abort_work", "abort"),
-    ("fs.py:_FSWriteStream._commit_work", "commit"),
-    ("gcs.py:_GCSWriteStream.commit", "commit"),
-    ("io_types.py:BufferedWriteStream.commit", "commit"),
     ("recorder.py:FlightRecorder.dump", "fail-open"),
-    ("s3.py:_S3WriteStream.commit", "commit"),
-    ("scheduler.py:_WritePipeline._storage_write", "write"),
-    ("scheduler.py:_WritePipeline._stream_one", "append"),
     ("scheduler.py:_WritePipeline._write_one", "write"),
     ("scheduler.py:_WritePipeline.run_to_completion", "write"),
     ("snapshot.py:Snapshot._scrub_repair", "write"),
-    # A/B probe writes throwaway `.probe` objects outside any snapshot
-    # directory's commit protocol; a crash mid-probe orphans at most one
-    # probe object and can never corrupt a snapshot.
-    ("stream_select.py:_probe_streamed", "fail-open"),
-    ("stream_select.py:_probe_whole", "fail-open"),
     ("snapshot.py:Snapshot._write_snapshot_metadata", "write"),
     ("snapshot.py:Snapshot.gc", "delete"),
     ("storage_plugin.py:write_telemetry_artifact", "write"),
@@ -372,9 +350,9 @@ def parse_fault_spec(spec: str) -> FaultPlan:
             raise
         except ValueError as e:
             raise FaultSpecError(f"bad value in rule {raw_rule!r}: {e}") from e
-        if rule.kind == "torn" and rule.op not in ("write", "append", "any"):
+        if rule.kind == "torn" and rule.op not in ("write", "any"):
             raise FaultSpecError(
-                f"kind=torn applies to write/append ops, not {rule.op!r}"
+                f"kind=torn applies to write ops, not {rule.op!r}"
             )
         if rule.kind == "corrupt" and rule.op not in ("read", "peer_serve", "any"):
             raise FaultSpecError(
@@ -423,8 +401,8 @@ class _Action:
 class FaultyStoragePlugin(StoragePlugin):
     """Wraps any plugin, injecting faults per a :class:`FaultPlan`.
 
-    Transparent when no rule matches: every call (including the streaming
-    protocol and capability flags) proxies to the inner plugin. Transient
+    Transparent when no rule matches: every call (including the
+    capability flag) proxies to the inner plugin. Transient
     faults are retried here through the shared ``cloud_retry`` machinery, so
     a transient storm exercises the real backoff + collective-progress
     window; everything else surfaces exactly where a real backend fault
@@ -440,13 +418,8 @@ class FaultyStoragePlugin(StoragePlugin):
             window_s=plan.window_s
         ) if plan.window_s is not None else CollectiveProgress()
 
-    # Capability flags proxy the inner plugin: the scheduler's streaming
-    # gate and IO-concurrency scaling must behave as if the wrapper were
-    # not there.
-    @property
-    def supports_streaming(self) -> bool:  # type: ignore[override]
-        return bool(getattr(self.inner, "supports_streaming", False))
-
+    # The capability flag proxies the inner plugin: IO-concurrency scaling
+    # must behave as if the wrapper were not there.
     @property
     def scales_io_with_local_world(self) -> bool:  # type: ignore[override]
         return bool(getattr(self.inner, "scales_io_with_local_world", False))
@@ -512,13 +485,22 @@ class FaultyStoragePlugin(StoragePlugin):
                     await self._guard(derived, write_io.path)
             act = await self._guard("write", write_io.path)
             if act is not None and act.kind == "torn":
-                # Simulated crash mid-write: push `bytes` bytes into a real
-                # stream of the inner plugin and die without commit OR
-                # abort. Atomic backends must expose no object; fs leaves
-                # its temp file behind as crash debris for gc.
-                stream = await self.inner.write_stream(write_io.path)
-                mv = memoryview(write_io.buf).cast("B")
-                await stream.append(mv[: act.rule.bytes])
+                # Simulated crash mid-write: atomic backends expose no
+                # object; fs writes the first `bytes` bytes to its temp
+                # file and dies before the rename, leaving crash debris
+                # for gc.
+                from .storage_plugins.fs import FSStoragePlugin
+
+                fs = self.inner
+                while fs is not None and not isinstance(fs, FSStoragePlugin):
+                    fs = getattr(fs, "inner", None)
+                if fs is not None:
+                    mv = memoryview(write_io.buf).cast("B")
+                    await fs._write_inner(
+                        WriteIO(path=write_io.path, buf=mv[: act.rule.bytes]),
+                        None,
+                        rename=False,
+                    )
                 raise InjectedFault(
                     f"injected torn write after {act.rule.bytes} bytes: "
                     f"{write_io.path}"
@@ -600,14 +582,6 @@ class FaultyStoragePlugin(StoragePlugin):
 
         await self._retrying(run, "faults")
 
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        async def run() -> StorageWriteStream:
-            await self._guard("stream_open", path)
-            return await self.inner.write_stream(path)
-
-        inner_stream = await self._retrying(run, "faults")
-        return _FaultyWriteStream(self, path, inner_stream)
-
     async def link_in(self, src_abs_path: str, path: str) -> bool:
         await self._guard("link", path)
         return await self.inner.link_in(src_abs_path, path)
@@ -657,46 +631,6 @@ def find_fault_injector(storage) -> Optional[FaultyStoragePlugin]:
         storage = getattr(storage, "inner", None)
         seen += 1
     return None
-
-
-class _FaultyWriteStream(StorageWriteStream):
-    """Injects at append/commit/abort; otherwise proxies the inner stream."""
-
-    def __init__(
-        self,
-        plugin: FaultyStoragePlugin,
-        path: str,
-        inner: StorageWriteStream,
-    ) -> None:
-        self._plugin = plugin
-        self._path = path
-        self._inner = inner
-
-    async def append(self, buf) -> None:
-        async def run() -> None:
-            act = await self._plugin._guard("append", self._path)
-            if act is not None and act.kind == "torn":
-                mv = memoryview(buf).cast("B")
-                await self._inner.append(mv[: act.rule.bytes])
-                raise InjectedFault(
-                    f"injected torn append after {act.rule.bytes} bytes: "
-                    f"{self._path}"
-                )
-            await self._inner.append(buf)
-
-        # NOT retried: appends are ordered and stateful — a blind re-append
-        # after a partial transfer would corrupt the stream. Real plugins
-        # retry *inside* their append (per-part/per-chunk); injected append
-        # faults therefore surface to the caller, whose job is to abort.
-        await run()
-
-    async def commit(self) -> None:
-        await self._plugin._guard("commit", self._path)
-        await self._inner.commit()
-
-    async def abort(self) -> None:
-        await self._plugin._guard("abort", self._path)
-        await self._inner.abort()
 
 
 def maybe_wrap_with_faults(plugin: StoragePlugin) -> StoragePlugin:

@@ -7,12 +7,11 @@ remaining null-sink staging wall to hashing: the sidecar format
 storage object — a whole-object sha256 cannot be computed out of order,
 cannot be split across the hash pool, and cannot verify a byte range. This
 module replaces that fold with a **two-level tree digest** at a fixed grain
-(``TORCHSNAPSHOT_TPU_HASH_CHUNK_BYTES``, default = the stream chunk grain):
+(``TORCHSNAPSHOT_TPU_HASH_CHUNK_BYTES``, default 64 MiB):
 
-- each grain-sized chunk of the object's byte stream is hashed
-  independently (crc32 + sha256) on the hash pool — chunks of one object
-  hash **concurrently**, and a streamed request's appends no longer wait
-  for the fold;
+- each grain-sized chunk of the object's bytes is hashed independently
+  (crc32 + sha256) on the hash pool — chunks of one object hash
+  **concurrently**;
 - the chunk crc32s combine into the whole-object crc32 with a pure-Python
   :func:`crc32_combine` (the zlib GF(2) matrix trick, O(log n) per merge) —
   the sidecar's top-level crc32 is **bit-identical to the serial fold**
@@ -53,6 +52,7 @@ import zlib
 from typing import Any, List, Optional, Sequence, Tuple
 
 from . import d2h, telemetry
+from .utils import knobs
 
 __all__ = [
     "crc32_combine",
@@ -71,9 +71,6 @@ __all__ = [
     "find_bad_chunks",
     "serial_digest",
     "hash_buffer",
-    "ChunkHasher",
-    "SerialStreamHasher",
-    "make_stream_hasher",
 ]
 
 
@@ -479,30 +476,23 @@ def serial_digest(mv: memoryview, want_sha: bool) -> list:
     return [zlib.crc32(mv), mv.nbytes, sha]
 
 
-def _hash_chunk_parts(
-    parts: List[memoryview],
+def _hash_chunk(
+    chunk: memoryview,
     want_sha: bool,
     times: Optional[Any],
     path: str,
 ) -> Tuple[int, int, Optional[str]]:
-    """One grain-chunk's (crc32, nbytes, sha256-hex) — the executor thunk.
-    ``parts`` are ordered views that together cover exactly the chunk (a
-    streamed append may split a chunk, and one append may span chunks)."""
+    """One grain-chunk's (crc32, nbytes, sha256-hex) — the executor thunk,
+    a pure function of its arguments."""
     t0 = time.monotonic()
-    crc = 0
-    n = 0
-    sha = hashlib.sha256() if want_sha else None
-    for p in parts:
-        crc = zlib.crc32(p, crc)
-        n += p.nbytes
-        if sha is not None:
-            sha.update(p)
+    crc = zlib.crc32(chunk)
+    sha = hashlib.sha256(chunk).hexdigest() if want_sha else None
     if times is not None:
         times.record(
-            "hash", t0, time.monotonic(), path=path, nbytes=n,
+            "hash", t0, time.monotonic(), path=path, nbytes=chunk.nbytes,
             span="stage.hash_chunk",
         )
-    return crc, n, (sha.hexdigest() if sha is not None else None)
+    return crc, chunk.nbytes, sha
 
 
 def _combine_results(
@@ -544,172 +534,6 @@ def _combine_results(
     return rec
 
 
-class ChunkHasher:
-    """Order-preserving chunked hasher: ``feed()`` buffers in object order
-    from the event loop; each completed grain-chunk is dispatched as an
-    independent job on the hash pool (so chunks hash **concurrently** and
-    the caller — a stream's append loop, or a whole-buffer digest — never
-    waits on a fold); ``finalize()`` gathers the per-chunk digests in order
-    and combines them into a sidecar record.
-
-    Backpressure: at most ``max_inflight`` chunk jobs may be dispatched and
-    unfinished at once (``feed`` awaits past that), bounding how many
-    staged views the hash backlog can keep alive to
-    ``max_inflight x grain`` bytes beyond the pipeline's budget.
-
-    All mutable state lives on the event-loop side; the executor thunk is a
-    pure function of its arguments (no cross-thread attribute writes — the
-    TSA7xx surface is only the thread-safe ``StageTimes`` sink).
-    """
-
-    def __init__(
-        self,
-        grain: int,
-        want_sha: bool,
-        loop: asyncio.AbstractEventLoop,
-        executor,
-        times: Optional[Any] = None,
-        path: str = "",
-        max_inflight: Optional[int] = None,
-    ) -> None:
-        if grain <= 0:
-            raise ValueError("ChunkHasher needs a positive grain")
-        self._grain = grain
-        self._want_sha = want_sha
-        self._loop = loop
-        self._executor = executor
-        self._times = times
-        self._path = path
-        self._parts: List[memoryview] = []
-        self._filled = 0
-        self._futures: List[asyncio.Future] = []
-        if max_inflight is None:
-            from .utils import knobs
-
-            max_inflight = 2 * knobs.get_hash_workers()
-        self._sem = asyncio.Semaphore(max(1, max_inflight))
-
-    async def feed(self, buf) -> None:
-        """Append the object's next bytes; dispatches every grain-chunk the
-        bytes complete. Zero-copy: the chunk jobs hash views of ``buf``
-        (which therefore stays alive until its chunks are hashed)."""
-        mv = memoryview(buf).cast("B")
-        off = 0
-        while off < mv.nbytes:
-            take = min(self._grain - self._filled, mv.nbytes - off)
-            self._parts.append(mv[off : off + take])
-            self._filled += take
-            off += take
-            if self._filled == self._grain:
-                await self._flush()
-
-    async def _flush(self) -> None:
-        parts, self._parts, self._filled = self._parts, [], 0
-        await self._sem.acquire()
-        fut = self._loop.run_in_executor(
-            self._executor,
-            _hash_chunk_parts,
-            parts,
-            self._want_sha,
-            self._times,
-            self._path,
-        )
-        # run_in_executor futures invoke callbacks on the loop thread, so
-        # the semaphore stays loop-side-only.
-        fut.add_done_callback(lambda _f: self._sem.release())
-        self._futures.append(fut)
-
-    async def finalize(self):
-        """Await every chunk job and combine: returns the sidecar record
-        (v1 list for <= 1 chunk, v2 dict otherwise)."""
-        if self._parts:
-            await self._flush()
-        results = await asyncio.gather(*self._futures)
-        self._futures = []
-        return _combine_results(results, self._grain, self._want_sha)
-
-    def abort(self) -> None:
-        """Failure path: cancel undispatched work and silence outstanding
-        futures so an aborted stream never logs 'exception was never
-        retrieved' for hash jobs it abandoned."""
-        self._parts = []
-        self._filled = 0
-        for fut in self._futures:
-            if not fut.cancel():
-                fut.add_done_callback(
-                    lambda f: f.exception() if not f.cancelled() else None
-                )
-        self._futures = []
-
-
-class SerialStreamHasher:
-    """The grain-0 escape hatch: the exact v1 serial fold, chunk by chunk in
-    stream order (each fold on the hash pool, awaited before the next — the
-    historical backpressure), producing ``[crc, size, sha]``."""
-
-    def __init__(
-        self,
-        want_sha: bool,
-        loop: asyncio.AbstractEventLoop,
-        executor,
-        times: Optional[Any] = None,
-        path: str = "",
-    ) -> None:
-        self._want_sha = want_sha
-        self._loop = loop
-        self._executor = executor
-        self._times = times
-        self._path = path
-        self._sha = hashlib.sha256() if want_sha else None
-        self._crc = 0
-        self._total = 0
-
-    async def feed(self, buf) -> None:
-        mv = memoryview(buf).cast("B")
-
-        def fold() -> int:
-            t0 = time.monotonic()
-            if self._sha is not None:
-                self._sha.update(mv)
-            out = zlib.crc32(mv, self._crc)
-            if self._times is not None:
-                self._times.record(
-                    "hash", t0, time.monotonic(),
-                    path=self._path, nbytes=mv.nbytes,
-                )
-            return out
-
-        self._crc = await self._loop.run_in_executor(self._executor, fold)
-        self._total += mv.nbytes
-
-    async def finalize(self):
-        return [
-            self._crc,
-            self._total,
-            self._sha.hexdigest() if self._sha is not None else None,
-        ]
-
-    def abort(self) -> None:
-        pass  # every fold was awaited inline; nothing outstanding
-
-
-def make_stream_hasher(
-    grain: int,
-    want_sha: bool,
-    loop: asyncio.AbstractEventLoop,
-    executor,
-    times: Optional[Any] = None,
-    path: str = "",
-):
-    """The stream-side engine for one storage object: chunk-parallel at a
-    positive grain, the serial v1 fold at grain 0."""
-    if grain > 0:
-        return ChunkHasher(
-            grain, want_sha, loop, executor, times=times, path=path
-        )
-    return SerialStreamHasher(want_sha, loop, executor, times=times, path=path)
-
-
 async def hash_buffer(
     mv: memoryview,
     grain: int,
@@ -721,9 +545,11 @@ async def hash_buffer(
     want_whole_sha: bool = False,
 ):
     """Digest one fully-materialized buffer. Objects larger than one grain
-    hash chunk-parallel on ``executor`` (the whole-buffer analogue of the
-    stream path — same record, same root); smaller ones (or grain 0) take
-    the single-task serial fold. ``want_whole_sha`` additionally computes
+    hash chunk-parallel on ``executor``: each grain-chunk is an independent
+    job, at most ``2 x HASH_WORKERS`` of one object dispatched and
+    unfinished at once, gathered in order and combined into the sidecar
+    record. Smaller ones (or grain 0) take the single-task serial fold.
+    ``want_whole_sha`` additionally computes
     the whole-object sha256 as ONE sequential job concurrent with the chunk
     jobs — the compat shim for incremental takes whose base recorded v1
     whole-object identities."""
@@ -744,17 +570,31 @@ async def hash_buffer(
                 return hashlib.sha256(mv).hexdigest()
 
         whole_fut = loop.run_in_executor(executor, whole)
-    hasher = ChunkHasher(
-        grain, want_sha, loop, executor, times=times, path=path
-    )
+    sem = asyncio.Semaphore(max(1, 2 * knobs.get_hash_workers()))
+    futures: List[asyncio.Future] = []
     try:
-        await hasher.feed(mv)
-        rec = await hasher.finalize()
+        for begin, end in chunk_extents(mv.nbytes, grain):
+            await sem.acquire()
+            fut = loop.run_in_executor(
+                executor, _hash_chunk, mv[begin:end], want_sha, times, path
+            )
+            # run_in_executor futures invoke callbacks on the loop thread,
+            # so the semaphore stays loop-side-only.
+            fut.add_done_callback(lambda _f: sem.release())
+            futures.append(fut)
+        results = await asyncio.gather(*futures)
     except BaseException:
-        hasher.abort()
+        # Silence what was abandoned, so a failed write never logs
+        # 'exception was never retrieved' for its hash jobs.
+        for fut in futures:
+            if not fut.cancel():
+                fut.add_done_callback(
+                    lambda f: f.exception() if not f.cancelled() else None
+                )
         if whole_fut is not None:
             whole_fut.cancel()
         raise
+    rec = _combine_results(results, grain, want_sha)
     if whole_fut is not None:
         whole_sha = await whole_fut
         if isinstance(rec, list):
@@ -771,7 +611,7 @@ def digest_of_bytes(data, grain: int, want_sha: bool = True):
     if grain <= 0 or mv.nbytes <= grain:
         return serial_digest(mv, want_sha)
     results = [
-        _hash_chunk_parts([mv[b:e]], want_sha, None, "")
+        _hash_chunk(mv[b:e], want_sha, None, "")
         for b, e in chunk_extents(mv.nbytes, grain)
     ]
     return _combine_results(results, grain, want_sha)

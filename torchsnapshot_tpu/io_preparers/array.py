@@ -24,9 +24,8 @@ import logging
 import math
 import pickle
 import time
-from collections import deque
 from concurrent.futures import Executor
-from typing import Any, AsyncIterator, Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -108,8 +107,8 @@ def chunk_row_ranges(
 ) -> List[Tuple[int, int]]:
     """Row ranges [r0, r1) per dim-0 chunk, each chunk <= max_chunk_bytes
     (when a single row fits). Shared by the chunked-array preparer (one
-    storage object per chunk) and the streaming stager (one chunk per
-    streamed append into a single object)."""
+    storage object per chunk) and the prepared-state cache's replay of
+    that split."""
     dim0 = int(shape[0])
     row_bytes = itemsize * int(np.prod(shape[1:])) if len(shape) > 1 else itemsize
     rows_per_chunk = max(1, max_chunk_bytes // max(row_bytes, 1))
@@ -124,13 +123,6 @@ def chunk_row_ranges(
         ranges.append((r0, r0 + rows))
         r0 += rows
     return ranges
-
-
-def _silence_future(fut) -> None:
-    """Retrieve (and drop) an abandoned lane resolve's outcome so asyncio
-    never logs "exception was never retrieved" for work we cancelled."""
-    if not fut.cancelled():
-        fut.exception()
 
 
 def to_host(arr: Any, executor: Optional[Executor] = None):
@@ -205,13 +197,10 @@ class ArrayBufferStager(BufferStager):
         # members together at the slab level); entry.serializer still
         # records the codec for the read side.
         self.stage_raw = False
-        # First stream chunk's device slice, pre-hinted by start_d2h_hint
-        # when this request will stream (see the note there).
-        self._first_slice = None
 
     def rebind(self, arr: Any) -> None:
         """Point this stager at a new step's array and clear per-take state
-        (frame publication, pre-hinted slices) while keeping the structural
+        (frame publication) while keeping the structural
         plan — entry, compression level, slab membership (``stage_raw``) —
         exactly as prepared. The prepared-state cache's hit path: the new
         array must match the cached plan's shape/dtype (guaranteed by the
@@ -219,13 +208,11 @@ class ArrayBufferStager(BufferStager):
         self.arr = arr
         self.frame_sizes = None
         self.frame_error = None
-        self._first_slice = None
 
     def unbind(self) -> None:
         """Drop the array reference between takes so a cached prepared
         state never pins device/host buffers past its pipeline's commit."""
         self.arr = None
-        self._first_slice = None
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         if not self.entry.frame_bytes:
@@ -272,8 +259,8 @@ class ArrayBufferStager(BufferStager):
         if serializer == Serializer.RAW:
             # Zero-copy fast path: the staged buffer IS a memoryview of the
             # resolved host buffer — no serialization pass, no intermediate
-            # bytes(). Downstream (write_stream appends, plugin writes, the
-            # digest fold, slab packing) all consume the buffer protocol
+            # bytes(). Downstream (plugin writes, the digest fold, slab
+            # packing) all consume the buffer protocol
             # directly, so the only full sweeps over a RAW payload are the
             # transfer itself, the (optional) hash, and the storage write.
             t0 = time.monotonic()
@@ -303,10 +290,7 @@ class ArrayBufferStager(BufferStager):
                         work.sized(len(payload))
                     # Publish for the companion FrameTableStager (same
                     # pipeline, polls until this lands). Cross-thread by
-                    # design: a single atomic reference store, and the
-                    # loop-side assignment in stage_chunks is a mutually
-                    # exclusive path (a request stages whole OR streamed,
-                    # never both).
+                    # design: a single atomic reference store.
                     self.frame_sizes = sizes  # noqa: TSA701
                     return payload
 
@@ -339,244 +323,8 @@ class ArrayBufferStager(BufferStager):
             return 2 * nbytes
         return nbytes
 
-    # -- streaming protocol --------------------------------------------------
-
-    def _stream_row_ranges(self) -> List[Tuple[int, int]]:
-        shape = self.entry.shape
-        if not shape or int(shape[0]) < 2:
-            return []
-        itemsize = entry_np_dtype(self.entry.dtype, self.entry.serializer).itemsize
-        return chunk_row_ranges(shape, itemsize, knobs.get_stream_chunk_bytes())
-
-    def can_stream(self) -> bool:
-        if self.stage_raw:
-            # Slab members are consumed synchronously by the slab's own
-            # stager; the SLAB streams (or not), never the member.
-            return False
-        serializer = self.entry.serializer
-        if serializer == Serializer.RAW:
-            pass
-        elif is_raw_family(serializer) and self.entry.frame_bytes:
-            # Framed compression: frames are independent, so chunk-local
-            # compression concatenates to the identical payload.
-            pass
-        else:
-            # Pickle and single-blob compressed payloads need the whole
-            # buffer in one call.
-            return False
-        if self.is_async_snapshot and not _is_jax_array(self.arr):
-            # Mutable host source on an async take: capture semantics
-            # require a private buffer before async_take returns; a stream
-            # keeps reading the live array long after training resumed.
-            return False
-        if _is_jax_array(self.arr) and not slice_preserves_bits(self.arr.dtype):
-            # A stream cuts its chunks on the device, and a device slice
-            # rewrites this dtype's bits: transfer whole, write whole.
-            return False
-        return len(self._stream_row_ranges()) > 1
-
-    async def stage_chunks(
-        self, executor: Optional[Executor] = None
-    ) -> AsyncIterator[BufferType]:
-        """Dim-0 chunk stream whose concatenation is byte-identical to
-        :meth:`stage_buffer`'s output.
-
-        Inside a write pipeline the upcoming chunks' transfers run on the
-        PARALLEL D2H LANES: each chunk the lane window admits is hinted
-        (``copy_to_host_async``) and starts resolving on the transfer
-        executor immediately, so several transfers stream back-to-back
-        while this coroutine serializes/yields earlier chunks — look-ahead
-        depth is bounded by ``TORCHSNAPSHOT_TPU_D2H_WINDOW_BYTES`` (debited
-        against the pipeline's memory budget), not a fixed chunk count.
-        Outside a pipeline, the round-3 two-ahead hint chain is kept.
-        RAW chunks are yielded as zero-copy memoryviews of the resolved
-        host buffers. Framed compression emits whole ``frame_bytes`` frames
-        and carries the inter-chunk remainder, so the frame layout (and the
-        published ``frame_sizes``) matches the non-streamed path exactly."""
-        serializer = self.entry.serializer
-        framed = serializer != Serializer.RAW
-        ctx = d2h.get_active()
-        times = ctx.times if ctx is not None else None
-        lanes = ctx.lanes if ctx is not None else None
-        location = self.entry.location
-        # Lane-resolving look-ahead: (host-array future, admitted bytes).
-        pending: deque = deque()
-        try:
-            ranges = self._stream_row_ranges()
-            arr = self.arr
-            is_jax = _is_jax_array(arr)
-            host_full: Optional[np.ndarray] = None
-            if not is_jax:
-                host_full = np.asarray(arr)
-                if not host_full.flags["C_CONTIGUOUS"]:
-                    host_full = np.ascontiguousarray(host_full)
-            loop = asyncio.get_running_loop()
-            level = self.compression_level
-            frame_bytes = self.entry.frame_bytes
-            carry = bytearray()  # raw tail short of a full compression frame
-            sizes: List[int] = []
-            first_slice = self._first_slice
-            self._first_slice = None
-            if first_slice is not None and (
-                not ranges
-                or int(first_slice.shape[0]) != ranges[0][1] - ranges[0][0]
-            ):
-                # Chunk knob changed between capture and drain: the
-                # pre-hinted slice no longer matches the first range.
-                first_slice = None
-            itemsize = entry_np_dtype(self.entry.dtype, serializer).itemsize
-            row_bytes = (
-                itemsize * int(np.prod(self.entry.shape[1:]))
-                if len(self.entry.shape) > 1
-                else itemsize
-            )
-            next_i = 0  # next range index to enter the look-ahead
-
-            def pump() -> None:
-                # Fill the lane window with upcoming chunks: hint + start
-                # resolving each one the window (and budget headroom)
-                # admits. The first look-ahead chunk of an empty stream is
-                # force-admitted so a window smaller than one chunk
-                # degrades to one-ahead, never to a stall.
-                nonlocal next_i, first_slice
-                while next_i < len(ranges):
-                    nr0, nr1 = ranges[next_i]
-                    est = (nr1 - nr0) * row_bytes
-                    if not lanes.try_admit(est, force=not pending):
-                        break
-                    if first_slice is not None:
-                        s, skip_hint = first_slice, True
-                        first_slice = None
-                    else:
-                        s, skip_hint = arr[nr0:nr1], False
-                    pending.append(
-                        (
-                            lanes.start(
-                                s,
-                                est,
-                                loop,
-                                times=times,
-                                location=location,
-                                skip_hint=skip_hint,
-                            ),
-                            est,
-                        )
-                    )
-                    next_i += 1
-
-            # Legacy (no active pipeline) look-ahead: pre-hinted device
-            # slices, two chunks ahead of the resolve so transfers pipeline
-            # on high-latency links. Each hinted slice caches its host
-            # bytes, so the look-ahead is part of the stream's footprint.
-            hinted: deque = deque()
-            if lanes is None and first_slice is not None:
-                hinted.append(first_slice)
-                first_slice = None
-            _HINT_AHEAD = 2
-            for i, (r0, r1) in enumerate(ranges):
-                if is_jax:
-                    if lanes is not None:
-                        pump()
-                        fut, est = pending.popleft()
-                        # Release the window reservation before resolving:
-                        # from here the chunk's bytes are covered by the
-                        # stream's own per-chunk budget debit
-                        # (scheduler._stream_one), and the freed window
-                        # immediately admits the next look-ahead transfer.
-                        lanes.release(est)
-                        host = await fut
-                        pump()
-                    else:
-                        while len(hinted) < _HINT_AHEAD + 1 and i + len(
-                            hinted
-                        ) < len(ranges):
-                            nr0, nr1 = ranges[i + len(hinted)]
-                            s = arr[nr0:nr1]
-                            hint_copy_to_host(s)
-                            hinted.append(s)
-                        cur = hinted.popleft()
-                        host = await _traced_to_host(
-                            cur, executor, location, _nbytes_of(cur)
-                        )
-                else:
-                    host = host_full[r0:r1]
-                # Contiguity (the only copy a RAW chunk can ever pay) is
-                # owned by array_as_bytes_view — one pass, zero when the
-                # device layout is already C-order.
-                t0 = time.monotonic()
-                view = array_as_bytes_view(host)
-                if not framed:
-                    if times is not None:
-                        times.record(
-                            "serialize", t0, time.monotonic(),
-                            path=location, nbytes=view.nbytes,
-                        )
-                    yield view
-                    continue
-                carry.extend(view)
-                nframes = len(carry) // frame_bytes
-                if i + 1 == len(ranges):
-                    # Last chunk: flush everything, incl. the short tail.
-                    block = bytes(carry)
-                    del carry[:]
-                elif nframes == 0:
-                    continue
-                else:
-                    block = bytes(memoryview(carry)[: nframes * frame_bytes])
-                    del carry[: nframes * frame_bytes]
-
-                def compress_block(block=block):
-                    t0 = time.monotonic()
-                    out = compress_framed(block, serializer, level, frame_bytes)
-                    if times is not None:
-                        times.record(
-                            "serialize", t0, time.monotonic(),
-                            path=location, nbytes=len(out[0]),
-                        )
-                    return out
-
-                if executor is not None:
-                    payload, fsizes = await loop.run_in_executor(
-                        executor, compress_block
-                    )
-                else:
-                    payload, fsizes = compress_block()
-                sizes.extend(fsizes)
-                if payload:
-                    yield payload
-            if framed:
-                # Publish for the companion FrameTableStager (same pipeline).
-                self.frame_sizes = sizes
-        except BaseException as e:  # noqa: BLE001 - published, then re-raised
-            if self.entry.frame_bytes:
-                self.frame_error = e
-            raise
-        finally:
-            # Abandoned look-ahead (mid-stream failure, aclose from an
-            # aborting pipeline): release every window admission so the
-            # budget balances, and silence the orphaned resolves.
-            while pending:
-                fut, est = pending.popleft()
-                fut.cancel()
-                fut.add_done_callback(_silence_future)
-                lanes.release(est)
-
     def start_d2h_hint(self) -> None:
         if not _is_jax_array(self.arr):
-            return
-        if knobs.is_stream_writes_enabled() and self.can_stream():
-            # This request will (almost certainly) stream: hint only the
-            # FIRST stream chunk's slice. Hinting the whole array would pull
-            # every byte into jax's host cache AND the per-chunk slices
-            # would transfer again at stage time — 2x the link traffic on
-            # exactly the drains streaming exists to speed up. The later
-            # chunks hint themselves one ahead inside stage_chunks; host
-            # RAM stays bounded by the stream depth instead of the eager
-            # whole-state prefetch.
-            if self._first_slice is None:
-                r0, r1 = self._stream_row_ranges()[0]
-                self._first_slice = self.arr[r0:r1]
-                hint_copy_to_host(self._first_slice)
             return
         hint_copy_to_host(self.arr)
 

@@ -8,10 +8,8 @@ chunk transfers stream out of HBM back-to-back without a full host-side copy
 first — for the dtypes a device slice returns bit for bit; a sub-32-bit float
 array is not chunked (``array.slice_preserves_bits``).
 
-The row-range math (``chunk_row_ranges``) lives in ``array.py`` and is shared
-with the streaming stager: each chunk OBJECT produced here is itself streamed
-(at the finer ``TORCHSNAPSHOT_TPU_STREAM_CHUNK_BYTES`` grain) when the
-scheduler routes it through a storage write stream.
+The row-range math (``chunk_row_ranges``) lives in ``array.py``, shared with
+the prepared-state cache.
 """
 
 from __future__ import annotations

@@ -16,7 +16,7 @@ import asyncio
 import io
 from concurrent.futures import Executor
 from dataclasses import dataclass, field
-from typing import AsyncIterator, List, Optional, Tuple, Union
+from typing import List, Optional, Tuple, Union
 
 # A staged buffer is either raw bytes or a zero-copy view over host memory.
 BufferType = Union[bytes, bytearray, memoryview]
@@ -33,20 +33,7 @@ class BufferStager(abc.ABC):
     ``stage_buffer`` performs the expensive part (device-to-host transfer +
     serialization). It is invoked by the scheduler only when the memory budget
     admits the request, and runs its blocking portions on ``executor``.
-
-    Stagers that can produce their bytes *incrementally* additionally
-    implement the streaming protocol (:meth:`can_stream` /
-    :meth:`stage_chunks`): the scheduler then overlaps the storage write of
-    chunk *k* with the D2H/serialization of chunk *k+1* within one request,
-    and debits/credits the memory budget per chunk instead of per request.
     """
-
-    # True for stagers whose ``stage_chunks`` yields views into one host
-    # buffer that stays alive until the stream ends (e.g. a device-packed
-    # slab fetched in a single D2H): the scheduler then keeps the full
-    # staging cost debited for the stream's lifetime instead of pretending
-    # per-chunk credits free memory that is still held.
-    stream_holds_full_buffer = False
 
     @abc.abstractmethod
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
@@ -56,23 +43,6 @@ class BufferStager(abc.ABC):
     def get_staging_cost_bytes(self) -> int:
         """Estimated peak host memory consumed by :meth:`stage_buffer`."""
         ...
-
-    def can_stream(self) -> bool:
-        """Whether :meth:`stage_chunks` yields more than one chunk AND
-        streaming preserves capture semantics for this request's source
-        (immutable device data, a private host capture, or a sync take —
-        a streamed request's source is read until its last chunk stages,
-        long after an async take's capture point)."""
-        return False
-
-    async def stage_chunks(
-        self, executor: Optional[Executor] = None
-    ) -> AsyncIterator[BufferType]:
-        """Yield the request's bytes as ordered chunks whose concatenation
-        is exactly what :meth:`stage_buffer` would have returned. Default:
-        one chunk (the whole buffer) — only meaningful when
-        :meth:`can_stream` is True."""
-        yield await self.stage_buffer(executor)
 
     def start_d2h_hint(self) -> None:
         """Optionally begin the device→host transfer early (non-blocking).
@@ -209,60 +179,6 @@ class ReadIO:
     buf: ReadBuffer = field(default_factory=ReadBuffer)
 
 
-class StorageWriteStream(abc.ABC):
-    """An in-progress streamed write of ONE storage object.
-
-    Obtained from :meth:`StoragePlugin.write_stream`. ``append`` calls are
-    sequential (never concurrent for one stream) and deliver the object's
-    bytes in order; ``commit`` makes the object visible atomically —
-    a stream that is aborted (or never committed) must leave no object at
-    the path. Exactly one of ``commit``/``abort`` ends the stream.
-    """
-
-    @abc.abstractmethod
-    async def append(self, buf: BufferType) -> None:
-        ...
-
-    @abc.abstractmethod
-    async def commit(self) -> None:
-        ...
-
-    @abc.abstractmethod
-    async def abort(self) -> None:
-        ...
-
-
-class BufferedWriteStream(StorageWriteStream):
-    """Fallback :class:`StorageWriteStream`: accumulate appends in host RAM
-    and issue one plain ``write`` at commit. Correct for any plugin (atomic
-    visibility rides on ``write``'s own guarantees) but holds the whole
-    object in memory — plugins advertise true incremental appends by
-    setting ``supports_streaming = True`` and overriding ``write_stream``;
-    the scheduler only routes requests through streams on those.
-
-    Appended buffers are retained AS-IS (zero-copy: a memoryview keeps its
-    backing host buffer alive until commit/abort, matching the stream
-    contract that appended bytes are immutable until the stream ends) and
-    joined once at commit."""
-
-    def __init__(self, storage: "StoragePlugin", path: str) -> None:
-        self._storage = storage
-        self._path = path
-        self._chunks: list = []
-
-    async def append(self, buf: BufferType) -> None:
-        self._chunks.append(buf)
-
-    async def commit(self) -> None:
-        await self._storage.write(
-            WriteIO(path=self._path, buf=b"".join(self._chunks))
-        )
-        self._chunks = []
-
-    async def abort(self) -> None:
-        self._chunks = []
-
-
 class StoragePlugin(abc.ABC):
     """Async storage backend contract (reference ``io_types.py:67-103``).
 
@@ -285,23 +201,9 @@ class StoragePlugin(abc.ABC):
     # concurrency, not seek-bound).
     scales_io_with_local_world = False
 
-    # True when ``write_stream`` appends incrementally (bytes leave host RAM
-    # as they are appended): fs (positioned writes into a temp file), memory
-    # (growing buffer), gcs (resumable session), s3 (multipart parts). The
-    # scheduler's streamed-request path is gated on this flag — the
-    # :class:`BufferedWriteStream` default would silently hold the whole
-    # object in RAM, defeating the per-chunk budget accounting.
-    supports_streaming = False
-
     @abc.abstractmethod
     async def write(self, write_io: WriteIO) -> None:
         ...
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        """Open a streamed write of one object at ``path`` (see
-        :class:`StorageWriteStream`). Default: a buffered fallback that
-        degenerates to one ``write`` at commit."""
-        return BufferedWriteStream(self, path)
 
     @abc.abstractmethod
     async def read(self, read_io: ReadIO) -> None:

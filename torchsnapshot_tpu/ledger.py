@@ -9,9 +9,8 @@ When the knob is set, every pipeline :class:`~.scheduler._Budget` carries a
 :class:`BudgetLedger`: each debit is tagged with its **owner** (the
 pipeline's label) and its **site** — the first stack frame outside the
 ledger/budget plumbing, i.e. the line of code that made the reservation
-(``scheduler._dispatch_staging_inner``, ``d2h.try_admit``'s budget hook,
-a streaming chunk debit, …). Credits consume entries by exact amount when
-one matches, else most-recent-first, so estimate-correction idioms
+(the engine's admission, a stage body's ``recost``, …). Credits consume
+entries by exact amount when one matches, else most-recent-first, so estimate-correction idioms
 (``credit(cost); debit(nbytes)``) and aggregated sweeps
 (``credit(outstanding)``) both reconcile.
 
@@ -84,13 +83,13 @@ class BudgetLedger:
         n = int(nbytes)
         with self._lock:
             # Exact-amount match first (the debit/credit pairs of request
-            # admission and window accounting), most recent wins.
+            # admission), most recent wins.
             for entry in reversed(self._entries):
                 if entry[1] == n:
                     self._entries.remove(entry)
                     return
-            # Aggregated credit (e.g. a stream's `credit(outstanding)`
-            # cleanup): consume most-recent-first.
+            # Aggregated credit (a sweep's `credit(outstanding)`): consume
+            # most-recent-first.
             while n > 0 and self._entries:
                 entry = self._entries[-1]
                 if entry[1] <= n:

@@ -115,16 +115,6 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.POINTER(ctypes.c_uint32),
     ]
     lib.tss_write_file_digest.restype = ctypes.c_int
-    lib.tss_write_at.argtypes = [
-        ctypes.c_char_p,
-        ctypes.c_void_p,
-        ctypes.c_uint64,
-        ctypes.c_uint64,
-        ctypes.c_int,
-        ctypes.c_uint64,
-        ctypes.c_int64,
-    ]
-    lib.tss_write_at.restype = ctypes.c_int
     return lib
 
 
@@ -273,34 +263,6 @@ def write_file_digest(
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
     return [crc.value, mv.nbytes, None]
-
-
-def write_at(
-    lib: ctypes.CDLL,
-    path: str,
-    buf,
-    *,
-    offset: int,
-    direct: bool,
-    chunk_bytes: int,
-    truncate_to: int = -1,
-) -> None:
-    """Write ``buf`` at byte ``offset`` of ``path`` (created, not truncated,
-    on open). O_DIRECT engages only for sector-aligned offset+length —
-    streamed appends keep their unaligned tail in Python and flush it here
-    buffered at commit, with ``truncate_to`` setting the final size."""
-    mv = _as_uint8_view(buf)
-    rc = lib.tss_write_at(
-        os.fsencode(path),
-        _buf_address(mv),
-        mv.nbytes,
-        offset,
-        1 if direct else 0,
-        chunk_bytes,
-        truncate_to,
-    )
-    if rc < 0:
-        raise OSError(-rc, os.strerror(-rc), path)
 
 
 def read_into(

@@ -420,74 +420,6 @@ int tss_write_file_digest(const char* path, const void* buf, uint64_t nbytes,
   return rc;
 }
 
-// Positioned write for STREAMED objects: write `nbytes` from `buf` at byte
-// `offset` of `path` (created if absent, never truncated on open — earlier
-// appends stay). use_direct engages O_DIRECT only when `offset` and `nbytes`
-// are both sector-aligned (the streaming caller keeps an unaligned tail in
-// Python and flushes it buffered at commit); any O_DIRECT failure falls back
-// to buffered I/O. `truncate_to` >= 0 ftruncates the file to that size after
-// the write (the commit call drops O_DIRECT padding / sets the final size).
-int tss_write_at(const char* path, const void* buf, uint64_t nbytes,
-                 uint64_t offset, int use_direct, uint64_t chunk_bytes,
-                 int64_t truncate_to) {
-  const char* src = static_cast<const char*>(buf);
-  const int base_flags = O_WRONLY | O_CREAT;
-
-  int fd = -1;
-  bool direct = use_direct != 0 && nbytes >= kAlign &&
-                offset == align_down(offset) && nbytes == align_down(nbytes);
-  if (direct) {
-    fd = open(path, base_flags | O_DIRECT, 0644);
-    if (fd < 0) direct = false;  // fs without O_DIRECT support
-  }
-  if (fd < 0) fd = open(path, base_flags, 0644);
-  if (fd < 0) return -errno;
-
-  int rc = 0;
-  uint64_t done = 0;
-  if (direct) {
-    if (chunk_bytes < kAlign) chunk_bytes = 64ull << 20;
-    chunk_bytes = align_down(chunk_bytes);
-    void* bounce = nullptr;
-    if (posix_memalign(&bounce, kAlign, chunk_bytes) != 0) {
-      close(fd);
-      return -ENOMEM;
-    }
-    while (done < nbytes) {
-      uint64_t n = std::min(chunk_bytes, nbytes - done);  // aligned: so is n
-      memcpy(bounce, src + done, n);
-      ssize_t w = pwrite(fd, bounce, n, offset + done);
-      if (w < 0) {
-        if (errno == EINTR) continue;
-        if (errno == EINVAL) break;  // device rejected O_DIRECT mid-stream
-        rc = -errno;
-        break;
-      }
-      uint64_t advanced = align_down(static_cast<uint64_t>(w));
-      if (advanced == 0) break;  // no O_DIRECT progress: finish buffered
-      done += advanced;
-    }
-    free(bounce);
-    if (rc == 0 && done < nbytes) {
-      int fd2 = open(path, O_WRONLY, 0644);
-      if (fd2 < 0) {
-        rc = -errno;
-      } else {
-        rc = write_buffered(fd2, src + done, nbytes - done, offset + done);
-        if (close(fd2) < 0 && rc == 0) rc = -errno;
-      }
-    }
-  } else {
-    rc = write_buffered(fd, src, nbytes, offset);
-  }
-  if (rc == 0 && truncate_to >= 0 &&
-      ftruncate(fd, static_cast<off_t>(truncate_to)) < 0) {
-    rc = -errno;
-  }
-  if (close(fd) < 0 && rc == 0) rc = -errno;
-  return rc;
-}
-
 // Read `nbytes` at byte `offset` of `path` into `dst` as chunk reads of
 // `chunk_bytes` on the reader pool (see ReadPool above): the call returns when
 // every chunk has landed. Fails with -EIO if the file is shorter than

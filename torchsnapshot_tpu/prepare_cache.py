@@ -7,8 +7,8 @@ and manifest-entry construction, the partition collective, slab batching —
 even though every one of those decisions is a pure function of the take's
 *structure* (shapes/dtypes/shardings, the replicated globs, world size,
 and every prepare-affecting knob). That structure is exactly what the
-``take_plan`` fingerprint hashes (v4 folds in the stream/batch/capture
-knobs), so the fingerprint is a sound cache key for the *prepared
+``take_plan`` fingerprint hashes (it folds in the batch/capture knobs),
+so the fingerprint is a sound cache key for the *prepared
 artifacts themselves*:
 
 - the post-partition, post-batch write requests (stagers constructed,
@@ -22,8 +22,8 @@ On a fingerprint hit, ``prepare_write`` + partition + batching collapse
 into :meth:`PreparedTake.rebind`: capture the new step's arrays (under
 ``TORCHSNAPSHOT_TPU_ASYNC_CAPTURE=donate`` a zero-copy no-op), point each
 cached stager at the new step's leaf values, and reset per-take staging
-state. Everything structural — entries, slab offsets, compression levels,
-stream eligibility — is reused as-is. Primitive entries embed their
+state. Everything structural — entries, slab offsets, compression levels
+— is reused as-is. Primitive entries embed their
 values, so those are the one thing recomputed per take.
 
 Strict invalidation is inherited from the key: any shape/dtype/sharding
@@ -74,8 +74,8 @@ logger = logging.getLogger(__name__)
 Manifest = Dict[str, Entry]
 
 # (fingerprint, storage plugin class, sync/async): stagers are built with
-# async-dependent defer flags and plugin-dependent streaming eligibility,
-# so states prepared for one mode must not serve another.
+# async-dependent defer flags, so states prepared for one mode (or against
+# one backend) must not serve another.
 CacheKey = Tuple[str, str, bool]
 
 

@@ -14,12 +14,9 @@ means, hashing/dedup, sidecar commit, and read verification.
 
 Write pipeline graph (one chain per request)::
 
-    stage ──(budget+data edge)──> io            whole-buffer requests
+    stage ──(budget+data edge)──> io
     D2H + serialize               hash + dedup + storage.write
     (pool: staging)               (pool: io, cap MAX_CONCURRENT_IO)
-
-    stream                                      chunk-streamed requests
-    (pool: streaming, cap MAX_CONCURRENT_IO; per-chunk budget inside)
 
 The memory budget is debited by each request's estimated staging cost when
 it is admitted, corrected to the actual buffer size when staging completes,
@@ -64,7 +61,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import psutil
 
-from . import d2h, hashing, restore_times, stream_select, telemetry
+from . import d2h, hashing, restore_times, telemetry
 from .engine import GraphExecutor, Node, Priority
 from .engine.executor import Budget as _Budget  # noqa: F401 - test surface
 from .engine.executor import ProgressReporter as _ProgressReporter  # noqa: F401
@@ -92,7 +89,7 @@ from .utils import knobs
 
 logger = logging.getLogger(__name__)
 
-_STAGE_POOLS = ("staging", "streaming")
+_STAGE_POOLS = ("staging",)
 
 
 class ReadVerificationError(RuntimeError):
@@ -117,25 +114,6 @@ CHECKSUM_FILE_PREFIX = ".checksums."  # one JSON sidecar per rank
 
 _MAX_PER_RANK_MEMORY_BUDGET_BYTES = 32 * 1024 * 1024 * 1024
 _AVAILABLE_MEMORY_MULTIPLIER = 0.6
-
-
-# Short plugin label for per-plugin metric names: ``FSStoragePlugin`` →
-# ``fs``, matching ``storage.<plugin>.write_bytes``. Canonical home is
-# stream_select (the auto-select scorecard keys on the same label).
-_storage_label = stream_select.storage_label
-
-
-def _chunk_size_bucket(nbytes: int) -> str:
-    """Size bucket for per-chunk append-latency histograms. Four buckets
-    keyed to where streaming overheads live: per-call overhead dominates
-    ≤1M, grain effects the middle, device/disk bandwidth >64M."""
-    if nbytes <= 1 << 20:
-        return "le1m"
-    if nbytes <= 8 << 20:
-        return "le8m"
-    if nbytes <= 64 << 20:
-        return "le64m"
-    return "gt64m"
 
 
 def derive_local_world_size(coordinator=None) -> int:
@@ -230,9 +208,9 @@ class PipelinePools:
         return self._consuming
 
     def transfer_lanes(self) -> d2h.TransferLanes:
-        """The operation's parallel D2H lanes (dedicated transfer executor +
-        hint window; see ``d2h.TransferLanes``). Sized by the D2H_LANES /
-        D2H_WINDOW_BYTES knobs at first use."""
+        """The operation's parallel D2H lanes (dedicated transfer executor;
+        see ``d2h.TransferLanes``). Sized by the D2H_LANES knob at first
+        use."""
         if self._lanes is None:
             self._lanes = d2h.TransferLanes()
         return self._lanes
@@ -248,9 +226,9 @@ class PipelinePools:
 
 class _WritePipeline:
     """The write-side graph builder + domain node bodies. Builds one engine
-    chain per request (``stage → io``, or one self-budgeted ``stream``
-    node) and keeps the checkpoint semantics — hashing, dedup link-in,
-    sidecar commit, capture point — while the engine owns execution.
+    chain per request (``stage → io``) and keeps the checkpoint semantics —
+    hashing, dedup link-in, sidecar commit, capture point — while the
+    engine owns execution.
     Resumable so deferred staging (``WriteReq.defer_staging``) can finish
     on the async-commit background thread."""
 
@@ -285,11 +263,6 @@ class _WritePipeline:
         # The chunked-hashing grain, resolved once for the same reason
         # (0 = the serial v1 fold; objects <= one chunk keep v1 records).
         self._hash_grain = knobs.get_hash_chunk_bytes()
-        # Stream knobs are resolved at graph build (first run), matching
-        # the legacy dispatch-time reads — callers override them around the
-        # pipeline RUN, not necessarily its construction.
-        self._stream_chunk = 0
-        self._stream_inflight = 1
         # Set at base resolution: True when the base's sidecars carry v1
         # whole-object identities, so new objects must compute the whole
         # sha256 too (the compat shim) or dedup would spuriously re-upload.
@@ -320,10 +293,7 @@ class _WritePipeline:
         self._crc_executor: Optional[ThreadPoolExecutor] = None
         self._tm = telemetry.get_active()
         # Parallel D2H lanes + stage-time attribution, exposed to stagers
-        # via the d2h contextvar around node-task creation. Lane-window
-        # admissions (look-ahead host buffers) debit THIS pipeline's budget
-        # and are fully released by stream cleanup / the engine abort sweep,
-        # so budget_balanced still holds on every path.
+        # via the d2h contextvar around node-task creation.
         self._staging_ctx = d2h.StagingContext(
             lanes=self.pools.transfer_lanes(),
             times=d2h.StageTimes(tm=self._tm),
@@ -339,7 +309,7 @@ class _WritePipeline:
             kind="write",
             span_prefix="scheduler",
             priority=priority,
-            caps={"staging": None, "streaming": _max_io, "io": _max_io},
+            caps={"staging": None, "io": _max_io},
             ready_label="ready_for_io",
             progress=self.progress,
             bytes_done=lambda: self.bytes_staged,
@@ -347,11 +317,6 @@ class _WritePipeline:
             on_progress=self._after_reap,
         )
         self.budget = self._engine.budget
-        self._staging_ctx.lanes.bind_budget(
-            self.budget.debit,
-            self.budget.credit,
-            headroom=lambda: self.budget.available,
-        )
         # Populated by run_to_completion: how well the pipeline overlapped
         # its two streams (D2H+serialize staging vs storage writes). The
         # 7B-scale exposure is drain throughput, so the overlap efficiency
@@ -361,31 +326,13 @@ class _WritePipeline:
         # intervals (the same data the telemetry trace exports as spans).
         self.drain_stats: Dict[str, float] = {}
         self.pipeline_stats: Dict[str, float] = {}
-        # Graph building is LAZY (first run call): stream eligibility and
-        # chunk sizing read knobs the caller overrides around the pipeline
-        # run, exactly like the legacy dispatch-time reads did.
-        self._write_reqs = write_reqs
-        self._built = False
-
-    def _build_graph(self) -> None:
-        """Lower every request onto the engine graph, big first: they
-        dominate the critical path and admit small ones into the leftover
-        budget."""
-        if self._built:
-            return
-        self._built = True
-        self._stream_chunk = knobs.get_stream_chunk_bytes()
-        self._stream_inflight = knobs.get_stream_inflight()
-        # One streaming decision per pipeline: the knob verbatim when
-        # forced, the per-plugin measured-throughput decision under auto
-        # (stream_select module docstring — the r07 inversion fix).
-        self._stream_on = stream_select.resolve(self.storage)
-        by_size = sorted(
-            self._write_reqs,
+        # Lower every request onto the engine graph, big first: they
+        # dominate the critical path and admit small ones into the leftover
+        # budget.
+        for req in sorted(
+            write_reqs,
             key=lambda r: -r.buffer_stager.get_staging_cost_bytes(),
-        )
-        self._write_reqs = []
-        for req in by_size:
+        ):
             self._add_request(req)
 
     # ----------------------------------------------------- engine plumbing
@@ -426,46 +373,8 @@ class _WritePipeline:
 
     # ------------------------------------------------------- graph building
 
-    def _stream_eligible(self, req: WriteReq) -> bool:
-        """Whether this request lowers onto the chunk-streaming node:
-        stager and storage both support it, it is big enough that a second
-        chunk exists to overlap with, and the take has no incremental base
-        (dedup must see the whole object's digest BEFORE deciding link-in
-        vs write; a stream has already appended by then)."""
-        if not self._stream_on:
-            return False
-        if not getattr(self.storage, "supports_streaming", False):
-            return False
-        if self._base_loader is not None:
-            return False
-        stager = req.buffer_stager
-        if stager.get_staging_cost_bytes() < 2 * self._stream_chunk:
-            return False
-        return stager.can_stream()
-
     def _add_request(self, req: WriteReq) -> None:
         cost = req.buffer_stager.get_staging_cost_bytes()
-        if self._stream_eligible(req):
-            # Streamed requests are admitted at their steady-state
-            # footprint (inflight x chunk), not their full size — that
-            # is the RAM win; _stream_one re-debits per chunk. Stagers
-            # that materialize one full host buffer and stream views of
-            # it stay admitted at full cost.
-            if not req.buffer_stager.stream_holds_full_buffer:
-                cost = min(cost, self._stream_chunk * self._stream_inflight)
-            self._engine.add(
-                Node(
-                    "stream",
-                    self._make_stream_body(req),
-                    cost_bytes=cost,
-                    pool="streaming",
-                    path=req.path,
-                    deferred=req.defer_staging,
-                    self_budget=True,
-                    record_span=False,
-                )
-            )
-            return
         io_node = Node(
             "io",
             self._make_io_body(req),
@@ -490,15 +399,7 @@ class _WritePipeline:
         async def stage(ctx, _payload):
             if self.executor is None:
                 self.executor = self.pools.staging_executor()
-            t0 = time.monotonic()
             buf = await req.buffer_stager.stage_buffer(self.executor)
-            # Auto-select evidence, staging side (whole-buffer): keeps the
-            # two sides' rates comparable — both are bytes per BUSY second
-            # including staging, so the streamed path's per-chunk overhead
-            # asymmetry is what the decision actually weighs.
-            stream_select.note_whole_stage(
-                _storage_label(self.storage), time.monotonic() - t0
-            )
             nbytes = memoryview(buf).nbytes
             self.bytes_staged += nbytes
             self.progress.note_staged(nbytes, estimate=cost)
@@ -522,197 +423,6 @@ class _WritePipeline:
 
         return io
 
-    def _make_stream_body(self, req: WriteReq):
-        async def stream(ctx, _payload):
-            if self.executor is None:
-                self.executor = self.pools.staging_executor()
-            await self._stream_one(ctx, req)
-
-        return stream
-
-    # ----------------------------------------------------------- node bodies
-
-    async def _stream_one(self, ctx, req: WriteReq) -> None:
-        """Drive ONE streamed request end to end: a staging producer
-        (``stage_chunks``) and an append consumer connected by a bounded
-        queue, so the storage write of chunk *k* overlaps the
-        D2H/serialization of chunk *k+1* — the intra-request half of the
-        paper's overlap thesis. Budget accounting is per chunk: debit when
-        a chunk is staged, credit when ITS append completes, so peak host
-        RAM for the request is ~``chunk_bytes x inflight`` instead of its
-        full size. Per-object digests fold incrementally (running crc32 +
-        sha256 over the chunk sequence == the whole object's digest), and a
-        mid-stream failure aborts the storage stream — no partial object is
-        ever committed. The producer passes a preemption point before each
-        chunk: a higher QoS class arriving mid-stream steals the next chunk
-        admission."""
-        stager = req.buffer_stager
-        budget = self.budget
-        chunk_est = self._stream_chunk
-        inflight = self._stream_inflight
-        admitted_cost = ctx.reservation
-        holds_full = stager.stream_holds_full_buffer
-        if not holds_full:
-            # Hand the admission reservation over to per-chunk accounting.
-            budget.credit(admitted_cost)
-            admitted_cost = 0
-        outstanding = 0  # bytes debited for chunks whose append hasn't landed
-        want_digest = knobs.is_checksums_enabled()
-        total = 0
-        chunks = 0
-        loop = asyncio.get_running_loop()
-        hasher = None
-        if want_digest:
-            if self._crc_executor is None:
-                self._crc_executor = self.pools.hash_executor()
-            # Chunk-parallel digesting (hashing.ChunkHasher): appends no
-            # longer wait on the fold — each grain-chunk's crc32+sha256 is
-            # an independent job on the hash pool, crcs recombine to the
-            # bit-identical whole-object crc32, and the sha256 tree root
-            # becomes the object's dedup/cache identity. Grain 0 keeps the
-            # exact serial v1 fold (and its append backpressure).
-            hasher = hashing.make_stream_hasher(
-                self._hash_grain,
-                self._want_sha,
-                loop,
-                self._crc_executor,
-                times=self._staging_ctx.times,
-                path=req.path,
-            )
-        queue: asyncio.Queue = asyncio.Queue(maxsize=max(1, inflight))
-        _END = object()
-        storage_label = _storage_label(self.storage)
-        try:
-            stream = await self.storage.write_stream(req.path)
-        except BaseException:
-            if holds_full and admitted_cost:
-                budget.credit(admitted_cost)
-            raise
-
-        async def produce() -> None:
-            nonlocal outstanding, chunks
-            agen = stager.stage_chunks(self.executor)
-            try:
-                while True:
-                    # Chunk-granular QoS yield: a foreground class arriving
-                    # mid-drain pauses the NEXT chunk, not the stream.
-                    await ctx.preemption_point()
-                    if not holds_full:
-                        budget.debit(chunk_est)
-                        outstanding += chunk_est
-                    t0 = time.monotonic()
-                    try:
-                        buf = await agen.__anext__()
-                    except StopAsyncIteration:
-                        if not holds_full:
-                            budget.credit(chunk_est)
-                            outstanding -= chunk_est
-                        break
-                    nbytes = memoryview(buf).nbytes
-                    if not holds_full:
-                        # Correct the estimate to the chunk's real size.
-                        budget.credit(chunk_est)
-                        budget.debit(nbytes)
-                        outstanding += nbytes - chunk_est
-                    chunks += 1
-                    ctx.record_interval("stream_chunk", t0, req.path, nbytes)
-                    # Auto-select evidence, staging side: the per-chunk
-                    # slice/copy/serialize cost is the overhead that
-                    # inverted r07's A/B — it must weigh against streaming.
-                    stream_select.note_stream_stage(
-                        storage_label, time.monotonic() - t0
-                    )
-                    self.progress.note_staged(nbytes)
-                    await queue.put((buf, nbytes))
-            finally:
-                await agen.aclose()
-            # Signal completion OUTSIDE the finally: on the error path the
-            # consumer may already be dead with the queue full, and a
-            # cancelled producer blocking here again would deadlock the
-            # cleanup gather (the consumer is cancelled alongside us there,
-            # so the sentinel is only needed on normal completion).
-            await queue.put((_END, 0))
-
-        async def consume() -> None:
-            nonlocal total, outstanding
-            while True:
-                buf, nbytes = await queue.get()
-                if buf is _END:
-                    return
-                if hasher is not None:
-                    # Hand the chunk's bytes to the hashing engine. With a
-                    # positive grain this only SLICES views and dispatches
-                    # completed grain-chunks as concurrent hash-pool jobs —
-                    # the append below never waits on a fold (it awaits
-                    # only the engine's backpressure semaphore, which
-                    # bounds the hash backlog's retained views). The staged
-                    # buffer stays alive until its chunks are hashed; the
-                    # memoryview keeps it so past the budget credit below,
-                    # bounded by max_inflight x grain.
-                    await hasher.feed(buf)
-                t0 = time.monotonic()
-                await stream.append(buf)
-                append_s = time.monotonic() - t0
-                ctx.record_interval("io", t0, req.path, nbytes)
-                # Auto-select evidence: streamed bytes + append seconds per
-                # plugin (unconditional — the scorecard must accumulate
-                # without a telemetry session).
-                stream_select.note_streamed(storage_label, nbytes, append_s)
-                if self._tm is not None:
-                    # Per-chunk append latency by plugin and size bucket —
-                    # the data that attributes a streaming inversion to
-                    # per-chunk overhead vs grain vs the storage device.
-                    self._tm.metrics.histogram(
-                        f"storage.{storage_label}.append_s."
-                        f"{_chunk_size_bucket(nbytes)}"
-                    ).observe(append_s)
-                total += nbytes
-                self.progress.note_written(nbytes)
-                if not holds_full:
-                    budget.credit(nbytes)
-                    outstanding -= nbytes
-
-        ptask = asyncio.ensure_future(produce())
-        ctask = asyncio.ensure_future(consume())
-        try:
-            await asyncio.gather(ptask, ctask)
-            t0 = time.monotonic()
-            await stream.commit()
-            ctx.record_interval("io", t0, req.path, 0)
-        except BaseException:
-            for t in (ptask, ctask):
-                t.cancel()
-            await asyncio.gather(ptask, ctask, return_exceptions=True)
-            if hasher is not None:
-                hasher.abort()
-            try:
-                await stream.abort()
-            except Exception:  # noqa: BLE001 - the original failure wins
-                logger.warning(
-                    "failed to abort write stream for %s", req.path,
-                    exc_info=True,
-                )
-            raise
-        finally:
-            if outstanding:
-                budget.credit(outstanding)
-                outstanding = 0
-            if holds_full and admitted_cost:
-                budget.credit(admitted_cost)
-                admitted_cost = 0
-        self.bytes_staged += total
-        # Streamed requests learn their actual size only at stream end:
-        # converge the progress total from the admission estimate.
-        self.progress.adjust_total_bytes(
-            total - stager.get_staging_cost_bytes()
-        )
-        self.progress.note_request_done()
-        telemetry.counter_add("scheduler.stream_chunks", chunks)
-        if hasher is not None:
-            # Gather the chunk digests (most already done — they ran under
-            # the appends) and combine: crc32_combine + tree root.
-            self.checksums[req.path] = await hasher.finalize()
-
     def _timed_hash(self, path: str, nbytes: int, fn):
         """Run one hashing thunk with its interval recorded in the ``hash``
         sub-stream (the thunk itself executes on the hash pool)."""
@@ -723,18 +433,6 @@ class _WritePipeline:
                 return fn()
 
         return work
-
-    async def _storage_write(self, write_io: WriteIO) -> None:
-        """One whole-buffer plugin write, timed into the streaming
-        auto-select scorecard (the OFF-side evidence; the ON side feeds
-        from the per-chunk appends in ``_stream_one``)."""
-        t0 = time.monotonic()
-        await self.storage.write(write_io)
-        stream_select.note_whole(
-            _storage_label(self.storage),
-            memoryview(write_io.buf).nbytes,
-            time.monotonic() - t0,
-        )
 
     async def _write_one(self, path: str, buf) -> None:
         if knobs.is_checksums_enabled():
@@ -803,7 +501,7 @@ class _WritePipeline:
                         )
                     )
                     try:
-                        await self._storage_write(WriteIO(path=path, buf=buf))
+                        await self.storage.write(WriteIO(path=path, buf=buf))
                     except BaseException:
                         digest_task.cancel()
                         await asyncio.gather(
@@ -819,7 +517,7 @@ class _WritePipeline:
                 # plugin didn't — everything (non-native backends), or just
                 # the sha256 dedup digest.
                 write_io = WriteIO(path=path, buf=buf, want_digest=True)
-                await self._storage_write(write_io)
+                await self.storage.write(write_io)
                 digest = write_io.digest_out
                 if digest is None:
                     digest = await loop.run_in_executor(
@@ -888,7 +586,7 @@ class _WritePipeline:
                     if await self.storage.link_in(src, path):
                         self.bytes_deduped += my_size
                         return
-        await self._storage_write(WriteIO(path=path, buf=buf))
+        await self.storage.write(WriteIO(path=path, buf=buf))
 
     # ---------------------------------------------------------------- phases
 
@@ -900,14 +598,10 @@ class _WritePipeline:
 
     async def _abort_inflight(self) -> None:
         """Failure path: the engine's abort sweep (cancel, await, credit
-        every outstanding reservation), plus this pipeline's lane-window
-        sweep — so an aborted take leaves the budget balanced and no
-        staging/io coroutine running against a torn-down pipeline."""
+        every outstanding reservation) — so an aborted take leaves the
+        budget balanced and no staging/io coroutine running against a
+        torn-down pipeline."""
         await self._engine.abort()
-        # Look-ahead transfers the cancelled streams didn't get to release
-        # themselves (their cleanup normally does) — sweep the remainder so
-        # the budget balances on every failure path.
-        self._staging_ctx.lanes.release_all()
         # Debug-ledger cross-check: an aborted pipeline must leave zero
         # outstanding bytes; a leak here raises naming the debiting sites
         # (chained onto the failure that triggered the abort).
@@ -931,11 +625,7 @@ class _WritePipeline:
         """Drive the graph to the capture point: every *non-deferred*
         request's bytes are privately held in host RAM. Deferred requests
         (immutable device-backed data) then become admissible for the
-        background drain. Stream nodes admitted here (sync takes' big host
-        arrays) finish before the capture point too: their source is read
-        until the last chunk stages, and by the time they complete the
-        bytes are durably written — strictly stronger capture."""
-        self._build_graph()
+        background drain."""
         try:
             await self._engine.run(
                 until=lambda: self._engine.unfinished_in(_STAGE_POOLS) == 0
@@ -953,7 +643,6 @@ class _WritePipeline:
         # (for async takes, the background drain — any host-entry staging
         # billed during the stall must not deflate the apparent drain
         # rate), while pipeline_stats covers every window for sync takes.
-        self._build_graph()
         try:
             self._engine.release_deferred()
             await self._engine.run()
@@ -1008,8 +697,7 @@ class _WritePipeline:
             raise
         self._shutdown_executor()
         # Debug-ledger cross-check: a completed drain has credited every
-        # debit (request admissions, streamed chunks, lane-window
-        # look-ahead) — zero outstanding bytes at pipeline close.
+        # debit — zero outstanding bytes at pipeline close.
         self.budget.assert_balanced("write pipeline close")
 
         # Extend this run's accounting window over the sidecar tail, then

@@ -280,7 +280,7 @@ def array_as_bytes_view(arr: np.ndarray) -> memoryview:
     Device fetches CAN be non-C-contiguous: ``np.asarray(jax.Array)``
     reflects the device layout, which for e.g. bf16 matrices on TPU may be
     F-order. The view is the RAW staging fast path's terminal product: it
-    flows into ``write_stream`` appends / plugin writes / the digest fold
+    flows into plugin writes / the digest fold
     with no intermediate ``bytes()`` materialization, and it keeps the host
     buffer alive for as long as any consumer holds it.
     """

@@ -529,7 +529,7 @@ class Snapshot:
         ``qos``: the take's QoS class (``"foreground"``/``"normal"``/
         ``"background"``, default: the ambient class — NORMAL outside any
         scope). A ``"background"`` take's pipeline yields its next
-        admission (budget, io/hash/transfer-pool slots, stream chunks) to
+        admission (budget, io/hash/transfer-pool slots) to
         any higher-class operation in this process — see
         docs/performance.md, "The dataflow engine".
 

@@ -86,7 +86,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
 from .. import hashing, telemetry
-from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
+from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..engine import qos
 from ..utils import knobs
 
@@ -166,10 +166,6 @@ class CachedStoragePlugin(StoragePlugin):
         self.stats: Dict[str, int] = {"hit_bytes": 0, "miss_bytes": 0}
 
     # -- capability flags proxy the origin ----------------------------------
-    @property
-    def supports_streaming(self) -> bool:  # type: ignore[override]
-        return bool(getattr(self.inner, "supports_streaming", False))
-
     @property
     def scales_io_with_local_world(self) -> bool:  # type: ignore[override]
         return bool(getattr(self.inner, "scales_io_with_local_world", False))
@@ -898,12 +894,6 @@ class CachedStoragePlugin(StoragePlugin):
         await asyncio.get_running_loop().run_in_executor(
             self._get_executor(), self._invalidate_path, write_io.path
         )
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        await asyncio.get_running_loop().run_in_executor(
-            self._get_executor(), self._invalidate_path, path
-        )
-        return await self.inner.write_stream(path)
 
     async def delete(self, path: str) -> None:
         await asyncio.get_running_loop().run_in_executor(

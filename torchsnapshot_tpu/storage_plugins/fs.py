@@ -24,7 +24,6 @@ import asyncio
 import contextlib
 import os
 import threading
-import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Set
@@ -33,7 +32,7 @@ import aiofiles
 import numpy as np
 
 from .. import native, restore_times, telemetry
-from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
+from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..utils import knobs
 from .cloud_retry import (
     TRANSIENT_OS_ERRNOS,
@@ -42,7 +41,6 @@ from .cloud_retry import (
     retry_transient,
 )
 
-_DIRECT_ALIGN = 4096  # matches the native engine's kAlign
 # The grain of a native read: one object is this many bytes a chunk read,
 # ``knobs.get_direct_read_depth()`` of them on the mount at once (probe of
 # PR 29, PERF.md section 6).
@@ -56,161 +54,8 @@ _TRANSIENT_ERRNOS = TRANSIENT_OS_ERRNOS
 _is_transient_oserror = is_transient_os_error
 
 
-class _FSWriteStream(StorageWriteStream):
-    """Streamed write into a temp file, committed by rename (same
-    crash-atomicity as ``write``). Appends are positioned writes at a
-    running offset; with the native engine, every sector-aligned span goes
-    through O_DIRECT (the unaligned tail is carried in Python — always
-    < 4 KiB — and flushed buffered at commit, which also sets the final
-    size), so a streamed object keeps the page-cache bypass that makes
-    large writes fast on TPU-VM hosts."""
-
-    def __init__(self, plugin: "FSStoragePlugin", path: str) -> None:
-        self._plugin = plugin
-        self._path = path
-        abs_path = os.path.join(plugin.root, path)
-        plugin._ensure_parent(abs_path)
-        self._abs_path = abs_path
-        self._tmp_path = f"{abs_path}.tmp.{uuid.uuid4().hex[:8]}"
-        # Create the temp file eagerly: the stream's crash window opens HERE,
-        # not at the first sector-aligned append (small appends live in the
-        # Python carry until alignment) — a crash mid-stream must leave the
-        # temp file for Snapshot.gc to find, and abort() must always have a
-        # file to unlink. Metadata-op cost only, like _ensure_parent above.
-        open(self._tmp_path, "wb").close()
-        self._offset = 0  # durably written bytes (sector-aligned in native mode)
-        self._carry = bytearray()  # unaligned tail awaiting the next append
-        self._file = None  # buffered-mode persistent file object
-        # Mode pinned at first append: mixing O_DIRECT and buffered fds on
-        # one file mid-stream invites page-cache coherence surprises.
-        self._native_mode: Optional[bool] = None
-        self._t0 = time.monotonic()
-
-    @property
-    def total_bytes(self) -> int:
-        return self._offset + len(self._carry)
-
-    def _append_work(self, chunk) -> None:
-        mv = memoryview(chunk)
-        if mv.format != "B" or mv.ndim != 1:
-            mv = mv.cast("B")
-        if self._native_mode is None:
-            self._native_mode = self._plugin._native is not None
-        if not self._native_mode:
-            if self._file is None:
-                self._file = open(self._tmp_path, "wb")
-            self._file.write(mv)
-            self._offset += mv.nbytes
-            return
-        lib = self._plugin._native
-        chunk_bytes = knobs.get_direct_io_chunk_bytes()
-        carry = self._carry
-        total_avail = len(carry) + mv.nbytes
-        aligned_total = total_avail - (total_avail % _DIRECT_ALIGN)
-        if aligned_total == 0:
-            carry.extend(mv)
-            return
-        with self._plugin._get_direct_sem():
-            if carry:
-                head_len = _DIRECT_ALIGN - len(carry)
-                block = bytes(carry) + bytes(mv[:head_len])
-                native.write_at(
-                    lib,
-                    self._tmp_path,
-                    block,
-                    offset=self._offset,
-                    direct=True,
-                    chunk_bytes=chunk_bytes,
-                )
-                self._offset += _DIRECT_ALIGN
-                mv = mv[head_len:]
-                carry.clear()
-                aligned_total -= _DIRECT_ALIGN
-            if aligned_total:
-                native.write_at(
-                    lib,
-                    self._tmp_path,
-                    mv[:aligned_total],
-                    offset=self._offset,
-                    direct=True,
-                    chunk_bytes=chunk_bytes,
-                )
-                self._offset += aligned_total
-                mv = mv[aligned_total:]
-        carry.extend(mv)
-
-    def _commit_work(self) -> None:
-        if self._file is not None:
-            self._file.close()
-            self._file = None
-        elif self._native_mode:
-            # Flush the unaligned tail buffered and pin the exact size.
-            lib = self._plugin._native
-            native.write_at(
-                lib,
-                self._tmp_path,
-                bytes(self._carry),
-                offset=self._offset,
-                direct=False,
-                chunk_bytes=knobs.get_direct_io_chunk_bytes(),
-                truncate_to=self._offset + len(self._carry),
-            )
-            self._offset += len(self._carry)
-            self._carry.clear()
-        elif self._carry or self._native_mode is None:
-            # Tiny stream that never crossed an alignment boundary (or was
-            # never appended to at all): write what we have buffered.
-            with open(self._tmp_path, "wb") as f:
-                f.write(self._carry)
-            self._offset += len(self._carry)
-            self._carry.clear()
-        os.replace(self._tmp_path, self._abs_path)
-
-    def _abort_work(self) -> None:
-        if self._file is not None:
-            with contextlib.suppress(OSError):
-                self._file.close()
-            self._file = None
-        with contextlib.suppress(OSError):
-            os.remove(self._tmp_path)
-
-    async def append(self, buf) -> None:
-        await asyncio.get_running_loop().run_in_executor(
-            self._plugin._get_executor(), self._append_work, buf
-        )
-
-    async def commit(self) -> None:
-        total = self.total_bytes
-        await asyncio.get_running_loop().run_in_executor(
-            self._plugin._get_executor(), self._commit_work
-        )
-        tm = telemetry.get_active()
-        if tm is not None:
-            t1 = time.monotonic()
-            tm.add_span(
-                "storage.write_stream",
-                "storage",
-                self._t0,
-                t1 - self._t0,
-                {
-                    "plugin": "fs",
-                    "path": self._path,
-                    "nbytes": total,
-                    "engine": "native" if self._native_mode else "buffered",
-                },
-            )
-        telemetry.counter_add("storage.fs.write_bytes", total)
-        self._plugin._count_write_path(total, bool(self._native_mode))
-
-    async def abort(self) -> None:
-        await asyncio.get_running_loop().run_in_executor(
-            self._plugin._get_executor(), self._abort_work
-        )
-
-
 class FSStoragePlugin(StoragePlugin):
     scales_io_with_local_world = True  # co-hosted ranks share this disk
-    supports_streaming = True  # appends land via positioned (O_DIRECT) writes
 
     def __init__(self, root: str) -> None:
         self.root = root
@@ -219,7 +64,7 @@ class FSStoragePlugin(StoragePlugin):
         # threading (not asyncio) semaphore: held inside executor threads, so
         # it works no matter which event loop drives the plugin. Created
         # lazily: plugins are constructed before the take's coordinator
-        # derives the local world size, and the stream cap must reflect it.
+        # derives the local world size, and the cap must reflect it.
         self._direct_sem: Optional[threading.Semaphore] = None
         self._sem_lock = threading.Lock()
         self._read_depth_set = False
@@ -292,9 +137,6 @@ class FSStoragePlugin(StoragePlugin):
         ):
             telemetry.counter_add("storage.fs.native_fallback_bytes", nbytes)
 
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        return _FSWriteStream(self, path)
-
     async def write(self, write_io: WriteIO) -> None:
         nbytes = memoryview(write_io.buf).nbytes
         # Resolved once per object so retries, the span and the counters
@@ -324,7 +166,9 @@ class FSStoragePlugin(StoragePlugin):
         telemetry.counter_add("storage.fs.write_bytes", nbytes)
         self._count_write_path(nbytes, lib is not None)
 
-    async def _write_inner(self, write_io: WriteIO, lib) -> None:
+    async def _write_inner(
+        self, write_io: WriteIO, lib, rename: bool = True
+    ) -> None:
         path = os.path.join(self.root, write_io.path)
         self._ensure_parent(path)
         # Write-then-rename so a crash mid-write can never leave a truncated
@@ -371,6 +215,11 @@ class FSStoragePlugin(StoragePlugin):
             else:
                 async with aiofiles.open(tmp_path, "wb") as f:
                     await f.write(write_io.buf)
+            if not rename:
+                # The injected torn write (``faults.py``): a crash between
+                # the temp file's bytes and its rename. The temp file stays
+                # as the debris ``Snapshot.gc`` reclaims; no object appears.
+                return
             # Rename/cleanup are metadata ops, but on network filesystems
             # (NFS-mounted checkpoint dirs) even those can stall for a
             # round-trip — keep the event loop clean and do them on the

@@ -18,11 +18,10 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import time
 from concurrent.futures import ThreadPoolExecutor
 
 from .. import telemetry
-from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
+from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..memoryview_stream import MemoryviewStream
 from ..utils import knobs
 from .cloud_retry import CollectiveProgress, backoff_s, retry_transient
@@ -37,8 +36,6 @@ _MAX_STALLED_CHUNK_RETRIES = 12
 
 
 class GCSStoragePlugin(StoragePlugin):
-    supports_streaming = True  # appends feed a resumable upload session
-
     def __init__(self, root: str) -> None:
         try:
             from google.cloud import storage as gcs  # type: ignore[import-not-found]
@@ -129,19 +126,12 @@ class GCSStoragePlugin(StoragePlugin):
             if close is not None:
                 close()
 
-    async def _drive_resumable(
-        self, loop, session, path: str, should_transmit=None
-    ) -> None:
-        """Transmit chunks with transient retry + cursor recovery. Default:
-        until the session finishes (whole-object uploads). A streamed write
-        passes ``should_transmit`` to stop while its feed still expects more
-        appends (transmitting then would read a short chunk and finalize
-        the object early)."""
+    async def _drive_resumable(self, loop, session, path: str) -> None:
+        """Transmit chunks with transient retry + cursor recovery until the
+        session finishes."""
         attempt = 0
         stalled = 0
         while not session.finished:
-            if should_transmit is not None and not should_transmit():
-                return
             cursor = session.bytes_uploaded
             # Op start counts as activity (same convention as _retrying):
             # a single chunk can legitimately take longer than the progress
@@ -210,9 +200,6 @@ class GCSStoragePlugin(StoragePlugin):
                 attempt = 0
                 stalled = 0
                 self._progress.note_progress()
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        return _GCSWriteStream(self, path)
 
     async def read(self, read_io: ReadIO) -> None:
         blob = self._bucket.blob(self._blob_path(read_io.path))
@@ -306,159 +293,6 @@ class GCSStoragePlugin(StoragePlugin):
         self._executor.shutdown(wait=True)
 
 
-class _StreamFeed:
-    """File-like over a sliding window of streamed bytes.
-
-    The resumable-upload session reads chunks from this object; only bytes
-    the server has NOT yet acked are retained (``drop_acked``), so host RAM
-    for a streamed upload is bounded by ~one chunk plus the unsent buffer —
-    while ``seek``/``tell`` still behave like a full file within that
-    window, which is all ``ResumableUpload.recover`` ever seeks into (the
-    recovered cursor is always >= the last acked byte)."""
-
-    def __init__(self) -> None:
-        self._base = 0  # global offset of the first retained byte
-        self._buf = bytearray()
-        self._pos = 0  # global read cursor
-        self.fed_bytes = 0
-
-    def feed(self, data) -> None:
-        self._buf.extend(data)
-        self.fed_bytes += memoryview(data).nbytes
-
-    def pending_bytes(self) -> int:
-        """Bytes fed but not yet consumed by a transmit."""
-        return self.fed_bytes - self._pos
-
-    def drop_acked(self, acked: int) -> None:
-        if acked > self._base:
-            del self._buf[: acked - self._base]
-            self._base = acked
-
-    def read(self, n: int = -1) -> bytes:
-        start = self._pos - self._base
-        if start < 0:
-            raise ValueError(
-                f"stream feed rewound past its retained window "
-                f"({self._pos} < {self._base})"
-            )
-        if n is None or n < 0:
-            out = bytes(self._buf[start:])
-        else:
-            out = bytes(self._buf[start : start + n])
-        self._pos += len(out)
-        return out
-
-    def seek(self, pos: int, whence: int = 0) -> int:
-        if whence != 0:
-            raise ValueError("stream feed supports absolute seeks only")
-        self._pos = pos
-        return pos
-
-    def tell(self) -> int:
-        return self._pos
-
-
-class _GCSWriteStream(StorageWriteStream):
-    """Streamed write as an unknown-total-size resumable upload: appends
-    buffer to the chunk quantum and transmit through the session (each
-    chunk individually retried with cursor recovery, like whole-object
-    resumable uploads); commit transmits the short final chunk, which is
-    what finalizes the object server-side — an aborted stream leaves no
-    object (unfinalized resumable sessions expire). Streams smaller than
-    one chunk degenerate to a single PUT at commit."""
-
-    def __init__(self, plugin: "GCSStoragePlugin", path: str) -> None:
-        self._plugin = plugin
-        self._path = path
-        self._feed = _StreamFeed()
-        self._session = None
-        self._t0 = time.monotonic()
-
-    async def _drain(self, final: bool) -> None:
-        session = self._session
-        loop = asyncio.get_running_loop()
-        should_transmit = None
-        if not final:
-            # Stop while a full chunk isn't buffered: a short read would
-            # finalize the upload with the object truncated.
-            should_transmit = (
-                lambda: self._feed.pending_bytes() >= session.chunk_bytes
-            )
-        await self._plugin._drive_resumable(
-            loop, session, self._path, should_transmit=should_transmit
-        )
-        self._feed.drop_acked(session.bytes_uploaded)
-
-    @staticmethod
-    def _chunk_bytes() -> int:
-        # Streamed transmits track the scheduler's stream-chunk grain (so
-        # the feed retains ~one chunk, keeping the per-chunk budget honest)
-        # capped by the plugin's configured chunk; the session rounds up to
-        # the wire's 256 KiB quantum.
-        return min(
-            knobs.get_gcs_chunk_bytes(),
-            max(knobs.get_stream_chunk_bytes(), 256 * 1024),
-        )
-
-    async def append(self, buf) -> None:
-        self._feed.feed(memoryview(buf))
-        chunk = self._chunk_bytes()
-        if self._session is None:
-            if self._feed.pending_bytes() <= chunk:
-                return  # keep buffering; may still fit a one-shot PUT
-            plugin = self._plugin
-
-            def initiate():
-                return _make_streaming_session(
-                    plugin._client,
-                    plugin._bucket.name,
-                    plugin._blob_path(self._path),
-                    self._feed,
-                    chunk,
-                    transport_factory=plugin._make_upload_transport,
-                )
-
-            self._session = await plugin._retrying(initiate)
-        await self._drain(final=False)
-
-    async def commit(self) -> None:
-        plugin = self._plugin
-        total = self._feed.fed_bytes
-        if self._session is None:
-            # Small object: one PUT (records its own span + byte counter).
-            await plugin.write(
-                WriteIO(path=self._path, buf=self._feed.read(-1))
-            )
-            return
-        try:
-            await self._drain(final=True)
-        finally:
-            close = getattr(self._session, "close", None)
-            if close is not None:
-                close()
-        tm = telemetry.get_active()
-        if tm is not None:
-            t1 = time.monotonic()
-            tm.add_span(
-                "storage.write_stream",
-                "storage",
-                self._t0,
-                t1 - self._t0,
-                {"plugin": "gcs", "path": self._path, "nbytes": total},
-            )
-        telemetry.counter_add("storage.gcs.write_bytes", total)
-
-    async def abort(self) -> None:
-        # An unfinalized resumable session holds no visible object and
-        # expires server-side; just drop the transport's connections.
-        if self._session is not None:
-            close = getattr(self._session, "close", None)
-            if close is not None:
-                close()
-            self._session = None
-
-
 class _GoogleResumableSession:
     """Thin sync wrapper over ``google.resumable_media``'s resumable upload.
 
@@ -532,92 +366,6 @@ class _GoogleResumableSession:
             self._transport.close()
         except Exception:  # pragma: no cover - session already dead
             pass
-
-
-class _GoogleStreamingResumableSession:
-    """Unknown-total-size resumable session over a :class:`_StreamFeed`.
-
-    Same wire mechanics as :class:`_GoogleResumableSession`, but initiated
-    with ``stream_final=False`` and no total: ``transmit_next_chunk`` reads
-    full chunks from the feed until the final (short) read finalizes the
-    object — the resumable protocol's documented streaming mode. The driver
-    (``_GCSWriteStream``) guarantees a full chunk is buffered before every
-    non-final transmit.
-    """
-
-    def __init__(
-        self,
-        client,
-        bucket_name: str,
-        blob_name: str,
-        feed: "_StreamFeed",
-        chunk_bytes: int,
-        transport_factory,
-    ) -> None:
-        from google.resumable_media.requests import ResumableUpload  # type: ignore[import-not-found]
-
-        self._transport = transport_factory()
-        api_base = getattr(
-            getattr(client, "_connection", None),
-            "API_BASE_URL",
-            "https://storage.googleapis.com",
-        )
-        upload_url = (
-            f"{api_base}/upload/storage/v1/b/{bucket_name}/o?uploadType=resumable"
-        )
-        # 256 KiB quantum: same wire requirement as the whole-object session.
-        quantum = 256 * 1024
-        self.chunk_bytes = max(
-            quantum, (chunk_bytes + quantum - 1) // quantum * quantum
-        )
-        self._upload = ResumableUpload(upload_url, self.chunk_bytes)
-        try:
-            self._upload.initiate(
-                self._transport,
-                feed,
-                metadata={"name": blob_name},
-                content_type="application/octet-stream",
-                stream_final=False,
-            )
-        except BaseException:
-            self.close()
-            raise
-
-    @property
-    def finished(self) -> bool:
-        return self._upload.finished
-
-    @property
-    def bytes_uploaded(self) -> int:
-        return int(self._upload.bytes_uploaded or 0)
-
-    def transmit_next_chunk(self) -> None:
-        self._upload.transmit_next_chunk(self._transport)
-
-    def recover(self) -> None:
-        self._upload.recover(self._transport)
-
-    def close(self) -> None:
-        try:
-            self._transport.close()
-        except Exception:  # pragma: no cover - session already dead
-            pass
-
-
-def _make_streaming_session(
-    client,
-    bucket_name: str,
-    blob_name: str,
-    feed: "_StreamFeed",
-    chunk_bytes: int,
-    transport_factory,
-):
-    """Indirection point for streamed writes: fake-server tests replace this
-    to simulate an unknown-size resumable session (mid-chunk faults and
-    all) without the SDK."""
-    return _GoogleStreamingResumableSession(
-        client, bucket_name, blob_name, feed, chunk_bytes, transport_factory
-    )
 
 
 def _response_status(e: Exception):

@@ -10,28 +10,7 @@ from __future__ import annotations
 from typing import Dict, List
 
 from .. import telemetry
-from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
-
-
-class _MemoryWriteStream(StorageWriteStream):
-    """Incremental append into a private buffer; the object becomes visible
-    atomically at commit (an aborted/mid-failed stream leaves nothing)."""
-
-    def __init__(self, plugin: "MemoryStoragePlugin", path: str) -> None:
-        self._plugin = plugin
-        self._path = path
-        self._buf = bytearray()
-
-    async def append(self, buf) -> None:
-        self._buf.extend(memoryview(buf))
-
-    async def commit(self) -> None:
-        self._plugin.objects[self._path] = bytes(self._buf)
-        telemetry.counter_add("storage.memory.write_bytes", len(self._buf))
-        self._buf = bytearray()
-
-    async def abort(self) -> None:
-        self._buf = bytearray()
+from ..io_types import ReadIO, StoragePlugin, WriteIO
 
 
 # ``memory://<name>`` URLs resolve to a per-process shared root so a snapshot
@@ -40,14 +19,9 @@ _SHARED_ROOTS: Dict[str, "MemoryStoragePlugin"] = {}
 
 
 class MemoryStoragePlugin(StoragePlugin):
-    supports_streaming = True
-
     def __init__(self, root: str = "") -> None:
         self.root = root
         self.objects: Dict[str, bytes] = {}
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        return _MemoryWriteStream(self, path)
 
     async def write(self, write_io: WriteIO) -> None:
         data = bytes(write_io.buf)

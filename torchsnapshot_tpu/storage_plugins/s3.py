@@ -22,7 +22,7 @@ import logging
 import time
 
 from .. import telemetry
-from ..io_types import ReadIO, StoragePlugin, StorageWriteStream, WriteIO
+from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..utils import knobs
 from .cloud_retry import CollectiveProgress, retry_transient
 
@@ -34,112 +34,7 @@ logger = logging.getLogger(__name__)
 _MULTIPART_CONCURRENCY = 8
 
 
-class _S3WriteStream(StorageWriteStream):
-    """Streamed write as an S3 multipart upload: appends accumulate to the
-    part size and upload as individual parts (each retried independently);
-    commit sends the tail part and completes the upload — S3 materializes
-    the object atomically at complete, so a mid-stream failure followed by
-    abort leaves no object and no billed parts. Streams that never reach
-    one part size degenerate to a single PUT at commit."""
-
-    def __init__(self, plugin: "S3StoragePlugin", path: str) -> None:
-        self._plugin = plugin
-        self._path = path
-        self._buf = bytearray()
-        self._upload_id = None
-        self._parts: list = []
-        self._total = 0
-        self._t0 = time.monotonic()
-        self._started_at = time.time()
-
-    async def _send_part(self, body: bytes) -> None:
-        plugin = self._plugin
-        client = await plugin._get_client()
-        key = plugin._key(self._path)
-        if self._upload_id is None:
-            created = await plugin._retrying(
-                lambda: client.create_multipart_upload(
-                    Bucket=plugin.bucket, Key=key
-                )
-            )
-            self._upload_id = created["UploadId"]
-        number = len(self._parts) + 1
-        resp = await plugin._retrying(
-            lambda: client.upload_part(
-                Bucket=plugin.bucket,
-                Key=key,
-                PartNumber=number,
-                UploadId=self._upload_id,
-                Body=body,
-            )
-        )
-        self._parts.append({"PartNumber": number, "ETag": resp["ETag"]})
-
-    @staticmethod
-    def _part_bytes() -> int:
-        # Streamed parts track the scheduler's stream-chunk grain (so the
-        # stream buffers ~one chunk, keeping the per-chunk budget honest)
-        # but never below S3's 5 MiB part minimum, and never above the
-        # plugin's configured part size. Sub-minimum S3_CHUNK_BYTES values
-        # (fake backends in tests) are honored verbatim.
-        return min(
-            knobs.get_s3_chunk_bytes(),
-            max(knobs.get_stream_chunk_bytes(), 5 * 1024 * 1024),
-        )
-
-    async def append(self, buf) -> None:
-        mv = memoryview(buf)
-        self._total += mv.nbytes
-        self._buf.extend(mv)
-        chunk = self._part_bytes()
-        while len(self._buf) >= chunk:
-            body = bytes(memoryview(self._buf)[:chunk])
-            del self._buf[:chunk]
-            await self._send_part(body)
-
-    async def commit(self) -> None:
-        plugin = self._plugin
-        if self._upload_id is None:
-            # Never reached a part size: one plain PUT (which records its
-            # own span + byte counter).
-            await plugin.write(WriteIO(path=self._path, buf=bytes(self._buf)))
-            self._buf = bytearray()
-            return
-        if self._buf:
-            body = bytes(self._buf)
-            self._buf = bytearray()
-            await self._send_part(body)
-        await plugin._complete_multipart(
-            plugin._key(self._path),
-            self._upload_id,
-            list(self._parts),
-            self._total,
-            self._started_at,
-        )
-        tm = telemetry.get_active()
-        if tm is not None:
-            t1 = time.monotonic()
-            tm.add_span(
-                "storage.write_stream",
-                "storage",
-                self._t0,
-                t1 - self._t0,
-                {"plugin": "s3", "path": self._path, "nbytes": self._total},
-            )
-        telemetry.counter_add("storage.s3.write_bytes", self._total)
-
-    async def abort(self) -> None:
-        self._buf = bytearray()
-        if self._upload_id is not None:
-            await self._plugin._abort_multipart(
-                self._plugin._key(self._path), self._upload_id
-            )
-            self._upload_id = None
-
-
 class S3StoragePlugin(StoragePlugin):
-    supports_streaming = True  # appends upload as multipart parts
-
     def __init__(self, root: str) -> None:
         try:
             import aioboto3  # type: ignore[import-not-found]
@@ -334,9 +229,6 @@ class S3StoragePlugin(StoragePlugin):
                     key,
                     exc_info=True,
                 )
-
-    async def write_stream(self, path: str) -> StorageWriteStream:
-        return _S3WriteStream(self, path)
 
     async def read(self, read_io: ReadIO) -> None:
         client = await self._get_client()

@@ -73,10 +73,10 @@ _WARNED_KEYSET_SIGS: "set" = set()
 # (TORCHSNAPSHOT_TPU_HASH_CHUNK_BYTES) joined the knob signature.
 # v4: the fingerprint also keys the PREPARED-state cache (stagers + write
 # requests, prepare_cache.py), so every remaining prepare-affecting input
-# joined the knob signature: stream mode/grain/inflight (stream grain
-# shapes stream row ranges and the slab layout), device batching, the
-# async capture mode, and the defensive-copy switch.
-_FINGERPRINT_VERSION = 4
+# joined the knob signature: device batching, the async capture mode, and
+# the defensive-copy switch.
+# v5: the chunk-streamed write path and its three knobs left the signature.
+_FINGERPRINT_VERSION = 5
 
 def _is_jax_array(obj: Any) -> bool:
     import jax
@@ -147,18 +147,13 @@ def compute_fingerprint(
         # The tree-digest grain is part of every v2 object's dedup/cache
         # identity (the root is grain-dependent), so a grain change must
         # invalidate cached plans like any other identity-shaping knob.
-        # Resolved from env only (its default derives from the stream-chunk
-        # env), so identical-env ranks resolve identically.
+        # Resolved from env only, so identical-env ranks resolve
+        # identically.
         knobs.get_hash_chunk_bytes(),
         # Prepare-affecting inputs the PREPARED-state cache keys on (v4):
-        # the raw stream mode string (auto resolves per-host — same
-        # treatment as dedup_digests above), the stream grain/inflight
-        # (stream row ranges + slab chunk layout), device batching (slab
-        # stager choice), and the capture knobs (whether stagers were
-        # built against forked or caller-owned arrays).
-        knobs.get_stream_writes_env(),
-        knobs.get_stream_chunk_bytes(),
-        knobs.get_stream_inflight(),
+        # device batching (slab stager choice), and the capture knobs
+        # (whether stagers were built against forked or caller-owned
+        # arrays).
         knobs.is_device_batching_enabled(),
         knobs.is_async_device_copy_enabled(),
         knobs.get_async_capture_mode(),

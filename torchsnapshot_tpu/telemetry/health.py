@@ -3,8 +3,8 @@
 Input is the step-record series ``steprecord.py`` defines (one record per
 ``take(job=, step=)`` commit); output is structured health events — the
 drifts the ROADMAP's perf wars were found by hand-diffing bench artifacts:
-a step-stall spike against the job's own trailing median, the streaming
-throughput inversion, a drain-rate cliff, a straggler that stops rotating,
+a step-stall spike against the job's own trailing median, a drain-rate
+cliff, a straggler that stops rotating,
 and catalog-bucket growth outrunning the retention policy.
 
 Detection is deliberately relative: every threshold compares a step against
@@ -44,13 +44,6 @@ STALL_SPIKE_FLOOR_S = 0.4
 # drain_wall_s spike (the drain-rate cliff seen from the wall side).
 DRAIN_CLIFF_RATIO = 3.0
 DRAIN_CLIFF_FLOOR_S = 1.0
-
-# Streaming-throughput inversion: a streaming step whose drain_gbps falls
-# below this fraction of the trailing median while bytes/step stays stable
-# (within BYTES_STABLE_RATIO of the median — a genuinely bigger step is
-# allowed to be slower).
-STREAM_INVERSION_RATIO = 0.6
-BYTES_STABLE_RATIO = 1.5
 
 # Straggler drift: the same rank is the straggler for this many consecutive
 # steps AND the skew is material (above floor and the trailing median
@@ -152,37 +145,6 @@ def detect_anomalies(
                         drain,
                         med,
                         f"drain wall {drain:.3f}s vs trailing median {med:.3f}s",
-                    )
-                )
-
-        gbps_hist = _trailing(recs, i, lambda x: x.get("drain_gbps"))
-        bytes_hist = _trailing(
-            recs, i, lambda x: (x.get("bytes") or {}).get("written")
-        )
-        if len(gbps_hist) >= MIN_HISTORY:
-            med_gbps = _median([v for v in gbps_hist if v > 0] or [0.0])
-            med_bytes = _median(bytes_hist)
-            gbps = r.get("drain_gbps") or 0.0
-            step_bytes = (r.get("bytes") or {}).get("written", 0) or 0
-            streaming = ((r.get("counters") or {}).get("stream_chunks") or 0) > 0
-            bytes_stable = (
-                med_bytes > 0 and step_bytes <= BYTES_STABLE_RATIO * med_bytes
-            )
-            if (
-                streaming
-                and med_gbps > 0
-                and 0 < gbps < STREAM_INVERSION_RATIO * med_gbps
-                and bytes_stable
-            ):
-                events.append(
-                    _event(
-                        "stream_inversion",
-                        step,
-                        gbps,
-                        med_gbps,
-                        f"streaming step drained at {gbps:.3f} GB/s vs "
-                        f"trailing median {med_gbps:.3f} GB/s "
-                        f"(bytes stable at {step_bytes / 1e9:.3f} GB)",
                     )
                 )
 

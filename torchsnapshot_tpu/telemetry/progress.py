@@ -81,12 +81,6 @@ class ProgressTracker:
         with self._lock:
             self.requests_done += 1
 
-    def adjust_total_bytes(self, delta: int) -> None:
-        """Correct the byte total by ``delta`` (streamed requests learn
-        their actual size only when the stream ends)."""
-        with self._lock:
-            self.bytes_total += int(delta)
-
     def activity_marker(self) -> Any:
         """Opaque value that changes whenever bytes move (staged OR
         written) — what the watchdog compares between polls."""
@@ -146,8 +140,8 @@ class StallWatchdog:
 
     A stall is ``warn_s`` seconds without the tracker's byte counters
     moving. The warning names the stuck stage (derived from the pipeline's
-    occupancy callback: requests sitting in io/streaming point at storage,
-    in staging at D2H/serialize) and fires EXACTLY ONCE per stall — the
+    occupancy callback: requests sitting in io point at storage, in
+    staging at D2H/serialize) and fires EXACTLY ONCE per stall — the
     watchdog re-arms only after progress resumes, so a wedged storage
     backend produces one line, not one per poll. ``fired`` counts warnings
     for tests and for the ``scheduler.stall_warnings`` metric (recorded by
@@ -171,7 +165,7 @@ class StallWatchdog:
 
     @staticmethod
     def _stuck_stage(occ: Dict[str, int]) -> str:
-        for stage in ("io", "streaming", "staging", "ready_for_io", "pending"):
+        for stage in ("io", "staging", "ready_for_io", "pending"):
             if occ.get(stage, 0) > 0:
                 return stage
         return "unknown"

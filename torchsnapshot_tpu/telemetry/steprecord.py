@@ -33,7 +33,6 @@ _COUNTER_METRICS = {
     "preemptions": "engine.preemptions",
     "preempted_wait_s": "engine.preempted_wait_s",
     "stall_warnings": "scheduler.stall_warnings",
-    "stream_chunks": "scheduler.stream_chunks",
     "cache_hits": "cache.hits",
     "cache_misses": "cache.misses",
 }

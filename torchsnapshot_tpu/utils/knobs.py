@@ -156,8 +156,8 @@ def get_direct_io_threshold_bytes() -> int:
 
 
 def get_direct_io_concurrency() -> int:
-    """Max concurrent native *writes* per storage plugin (whole objects and
-    streamed appends, under the plugin's semaphore), and, where the
+    """Max concurrent native *writes* per storage plugin (whole objects,
+    under the plugin's semaphore), and, where the
     environment sets it, the cap of the read side too
     (:func:`get_direct_read_depth`).
 
@@ -744,113 +744,7 @@ def override_prepared_cache_size(value: int):
     return _override_env(_ENV_PREPARED_CACHE_SIZE, str(value))
 
 
-_ENV_STREAM_WRITES = "TORCHSNAPSHOT_TPU_STREAM_WRITES"
-_ENV_STREAM_CHUNK = "TORCHSNAPSHOT_TPU_STREAM_CHUNK_BYTES"
-_ENV_STREAM_INFLIGHT = "TORCHSNAPSHOT_TPU_STREAM_INFLIGHT"
-
-_DEFAULT_STREAM_CHUNK_BYTES = 64 * 1024 * 1024
-
-# Last auto-mode streaming resolution made by ``stream_select`` (process
-# global; None until a pipeline has resolved one). Lives here so the
-# boolean view below — read by code without a storage plugin in hand, e.g.
-# the stager's D2H pre-hint — tracks the decision the scheduler actually
-# made, instead of diverging from it.
-_STREAM_AUTO_RESOLVED: Optional[bool] = None
-
-
-def get_stream_writes_mode() -> str:
-    """``on`` | ``off`` | ``auto`` (the shipped default).
-
-    ``auto`` selects streaming per storage plugin only where it measurably
-    wins: ``stream_select.py`` keeps a per-plugin scorecard of streamed
-    append throughput vs whole-buffer write throughput (fed by the same
-    instrumentation as the ``storage.<plugin>.append_s.<bucket>``
-    histograms) and the write pipeline resolves the decision at graph-build
-    time — on hosts where per-chunk staging overhead inverts the A/B, auto
-    converges to OFF after the first measured takes instead of shipping the
-    inversion silently. With no evidence yet, auto streams (the optimistic
-    prior: streaming bounds peak RAM and wins wherever appends are not
-    overhead-dominated)."""
-    val = os.environ.get(_ENV_STREAM_WRITES, "auto").lower()
-    if val in ("auto", ""):
-        return "auto"
-    return "off" if val in ("0", "false", "off") else "on"
-
-
-def get_stream_writes_env() -> str:
-    """The RAW env string (fingerprint input): ``auto`` resolves per-host
-    from measured throughput, and identical-env ranks must produce identical
-    fingerprints — the same reason ``get_dedup_digests_env`` exists."""
-    return os.environ.get(_ENV_STREAM_WRITES, "auto")
-
-
-def note_stream_auto_resolution(enabled: Optional[bool]) -> None:
-    """Called by ``stream_select`` when an auto-mode decision is made (or
-    reset, with None), so ``is_stream_writes_enabled`` reflects it
-    process-wide."""
-    global _STREAM_AUTO_RESOLVED
-    _STREAM_AUTO_RESOLVED = enabled
-
-
-def is_stream_writes_enabled() -> bool:
-    """Stream large write requests chunk-by-chunk through the scheduler.
-
-    When on, a request whose stager supports incremental staging (dim-0
-    chunkable raw/framed arrays, batched slabs) and whose storage plugin
-    supports appending writes is staged as a chunk stream: the storage
-    write for chunk *k* runs while chunk *k+1* is still in
-    D2H/compression, and the memory budget is debited/credited per chunk —
-    peak host RAM for one large array is ~``STREAM_CHUNK_BYTES x
-    STREAM_INFLIGHT`` instead of its full size. Off = round-5 behavior
-    (stage the whole request, then write it). Under ``auto`` (the default)
-    this boolean view returns the last per-plugin decision the scheduler
-    resolved (see :func:`get_stream_writes_mode`), or True before any
-    resolution."""
-    mode = get_stream_writes_mode()
-    if mode == "auto":
-        return _STREAM_AUTO_RESOLVED if _STREAM_AUTO_RESOLVED is not None else True
-    return mode == "on"
-
-
-def get_stream_chunk_bytes() -> int:
-    """Target bytes per streamed chunk (default 64 MB). Smaller chunks
-    overlap sooner and bound RAM tighter but pay more per-append overhead;
-    keep well above the storage plugin's per-op latency·bandwidth product.
-    The hash-chunk grain defaults to this value, so changing it re-grids
-    dedup identities: objects taken under a different grain re-upload once
-    in an incremental chain."""
-    return max(1, _get_int(_ENV_STREAM_CHUNK, _DEFAULT_STREAM_CHUNK_BYTES))
-
-
-def get_stream_inflight() -> int:
-    """Max staged-but-unwritten chunks per streamed request (default 4).
-    This is the streaming pipeline's depth: staging may run at most this
-    many chunks ahead of the storage appends."""
-    return max(1, _get_int(_ENV_STREAM_INFLIGHT, 4))
-
-
-def override_stream_writes(enabled: bool):
-    return _override_env(_ENV_STREAM_WRITES, "1" if enabled else "0")
-
-
-def override_stream_writes_mode(mode: str):
-    """Set the raw mode string (``on``/``off``/``auto``) — tests and the
-    bench's auto leg use this to exercise the auto path explicitly."""
-    return _override_env(_ENV_STREAM_WRITES, mode)
-
-
-def override_stream_chunk_bytes(value: int):
-    return _override_env(_ENV_STREAM_CHUNK, str(value))
-
-
-def override_stream_inflight(value: int):
-    return _override_env(_ENV_STREAM_INFLIGHT, str(value))
-
-
 _ENV_D2H_LANES = "TORCHSNAPSHOT_TPU_D2H_LANES"
-_ENV_D2H_WINDOW = "TORCHSNAPSHOT_TPU_D2H_WINDOW_BYTES"
-
-_DEFAULT_D2H_WINDOW_BYTES = 128 * 1024 * 1024
 
 
 def get_d2h_lanes() -> int:
@@ -858,55 +752,40 @@ def get_d2h_lanes() -> int:
 
     Each lane is one thread on a dedicated transfer executor that resolves
     an already-hinted (``copy_to_host_async``) transfer via ``np.asarray``,
-    so several chunks' transfers stream back-to-back while earlier chunks
-    serialize/hash/append. Distinct from ``TORCHSNAPSHOT_TPU_STAGING_THREADS``
+    so several leaves' transfers run back-to-back while earlier leaves
+    serialize/hash/write. Distinct from ``TORCHSNAPSHOT_TPU_STAGING_THREADS``
     (the serialize/compress pool): a multi-second compression job on the
     staging pool can no longer head-of-line block the transfer engine.
     """
     return max(1, _get_int(_ENV_D2H_LANES, 4))
 
 
-def get_d2h_window_bytes() -> int:
-    """Bytes of UPCOMING chunks/requests that may be hinted ahead and
-    resolving on the transfer lanes at once (default 128 MB). The window is
-    debited against the pipeline's memory budget as it fills — look-ahead
-    host buffers are real RAM — and each stream force-admits its first
-    look-ahead chunk, so a window smaller than one chunk (including 0)
-    degrades to one-chunk-ahead rather than stalling the transfer
-    engine."""
-    return max(0, _get_int(_ENV_D2H_WINDOW, _DEFAULT_D2H_WINDOW_BYTES))
-
-
 def override_d2h_lanes(value: int):
     return _override_env(_ENV_D2H_LANES, str(value))
-
-
-def override_d2h_window_bytes(value: int):
-    return _override_env(_ENV_D2H_WINDOW, str(value))
 
 
 _ENV_HASH_CHUNK = "TORCHSNAPSHOT_TPU_HASH_CHUNK_BYTES"
 _ENV_HASH_WORKERS = "TORCHSNAPSHOT_TPU_HASH_WORKERS"
 
+_DEFAULT_HASH_CHUNK_BYTES = 64 * 1024 * 1024
+
 
 def get_hash_chunk_bytes() -> int:
     """Grain of the parallel chunked hashing engine (``hashing.py``): each
-    ``HASH_CHUNK_BYTES`` slice of a storage object's byte stream is hashed
+    ``HASH_CHUNK_BYTES`` slice of a storage object's bytes is hashed
     as an independent job on the hash pool, the per-chunk crc32s combine
     into the bit-identical whole-object crc32 (``crc32_combine``), and the
     content digest becomes the sha256 tree root over the ordered chunk
     digests — recorded in a v2 sidecar whose chunk list makes RANGED reads
     verifiable and scrub corruption chunk-attributable. Objects no larger
-    than one chunk keep the exact v1 record. Default: the stream chunk
-    grain (``TORCHSNAPSHOT_TPU_STREAM_CHUNK_BYTES``), so streamed appends
-    and hash chunks share a grid. ``0`` disables chunking entirely — the
-    serial v1 fold and v1-only sidecars (the compat escape hatch and the
+    than one chunk keep the exact v1 record. Default 64 MiB. ``0`` disables
+    chunking entirely — the serial v1 fold and v1-only sidecars (the compat escape hatch and the
     A/B baseline of ``benchmarks/staging``'s hash sweep). The grain is part
     of a v2 object's dedup identity: keep it stable across the takes of an
     incremental chain, or changed-grain objects re-upload."""
     val = os.environ.get(_ENV_HASH_CHUNK)
     if val is None:
-        return get_stream_chunk_bytes()
+        return _DEFAULT_HASH_CHUNK_BYTES
     return max(0, int(val))
 
 
@@ -940,8 +819,8 @@ def is_qos_enabled() -> bool:
     """Priority-aware admission (``engine/qos.py``): while a higher-class
     operation (FOREGROUND > NORMAL > BACKGROUND) has registered demand in
     this process, lower-class engines stop admitting new work — budget,
-    io/hash/transfer-pool slots, and stream chunks all yield at the next
-    admission point (chunk granularity; in-flight steps finish). Off =
+    io/hash/transfer-pool slots all yield at the next admission point
+    (in-flight steps finish). Off =
     every operation competes FIFO, the pre-engine behavior (the A/B
     baseline ``benchmarks/qos`` measures against)."""
     return os.environ.get(_ENV_QOS, "1") not in ("0", "false", "False")
@@ -1135,9 +1014,8 @@ def is_debug_effects_enabled() -> bool:
     """Debug-mode durable-effect journal: when set, every storage plugin
     ``url_to_storage_plugin`` constructs is wrapped in an
     :class:`~torchsnapshot_tpu.effect_journal.EffectRecordingPlugin` that
-    records each mutating op (write / stream open / append / commit / abort
-    / delete / link) as one sequence-numbered journal entry carrying the
-    op class, path, content fingerprint, payload, and originating call
+    records each mutating op (write / delete / link) as one
+    sequence-numbered journal entry carrying the op class, path, content fingerprint, payload, and originating call
     site. The journal is the input to the crash-state explorer
     (``dev/crash_explorer.py``), which replays every effect prefix and
     asserts each one is a restorable crash state — the runtime cross-check
