@@ -263,8 +263,8 @@ def test_consumer_views_the_object_the_read_filled(tmp_path, route, byte_range) 
     filled, seen = [], []
 
     class Recording(FSStoragePlugin):
-        async def _native_read(self, path, offset, nbytes):
-            filled.append(await super()._native_read(path, offset, nbytes))
+        async def _native_read(self, path, offset, nbytes, into=None):
+            filled.append(await super()._native_read(path, offset, nbytes, into))
             return filled[-1]
 
         async def _buffered_read(self, path, offset, nbytes):

@@ -271,8 +271,8 @@ def test_read_torn_at_chunk_grain_delivers_a_fresh_destination(
         return destinations[-1]
 
     class Recording(FSStoragePlugin):
-        async def _native_read(self, path, offset, nbytes):
-            delivered.append(await super()._native_read(path, offset, nbytes))
+        async def _native_read(self, path, offset, nbytes, into=None):
+            delivered.append(await super()._native_read(path, offset, nbytes, into))
             return delivered[-1]
 
     class Consumer:

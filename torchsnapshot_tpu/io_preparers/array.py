@@ -32,7 +32,7 @@ import numpy as np
 from .. import d2h, telemetry
 from ..io_types import BufferConsumer, BufferStager, BufferType, ReadReq, WriteReq
 from ..manifest import ArrayEntry
-from ..restore_times import run_consume_work
+from ..restore_times import consume_landed, run_consume_work
 from ..serialization import (
     Serializer,
     array_as_bytes_view,
@@ -48,6 +48,7 @@ from ..serialization import (
     is_raw_family,
     is_raw_serializable,
     raw_serializer_for_codec,
+    string_to_dtype,
 )
 from ..utils import knobs
 
@@ -500,8 +501,6 @@ def entry_np_dtype(dtype: str, serializer: str) -> np.dtype:
     """Numpy dtype for an entry: raw-family entries use the canonical table;
     pickle entries recorded ``str(np.dtype)`` (e.g. ``datetime64[D]``,
     ``object``)."""
-    from ..serialization import string_to_dtype
-
     if is_raw_family(serializer):
         return string_to_dtype(dtype)
     return np.dtype(dtype)
@@ -525,16 +524,63 @@ def entry_cost_bytes(entry: ArrayEntry) -> int:
         return 1024 * 1024
 
 
-class ArrayBufferConsumer(BufferConsumer):
-    """Deserializes one buffer and copies it into a host target buffer."""
+def landing_view(entry: ArrayEntry, dst: np.ndarray) -> Optional[memoryview]:
+    """``dst``'s bytes as the destination of ``entry``'s read
+    (``BufferConsumer.destination``), where filling them is all a consumer
+    would do: an unframed RAW payload of ``dst``'s own dtype and shape, and
+    ``dst`` C-contiguous and writable. Else None."""
+    if entry.serializer != Serializer.RAW or entry.frame_bytes:
+        return None
+    if not (dst.nbytes and dst.flags.c_contiguous and dst.flags.writeable):
+        return None
+    if dst.dtype != string_to_dtype(entry.dtype) or list(dst.shape) != list(
+        entry.shape
+    ):
+        return None
+    return memoryview(dst.reshape(-1).view(np.uint8))
 
-    def __init__(self, target: np.ndarray, entry: ArrayEntry) -> None:
+
+async def consumed_by_landing(buf: BufferType, dest: Optional[memoryview]) -> bool:
+    """Whether ``buf`` is ``dest``'s own memory (same address, same length):
+    the read landed in the consumer's destination, which is then counted
+    and stamped as consumed (``restore_times.consume_landed``) with nothing
+    copied. False where no destination was offered or the plugin delivered
+    a buffer of its own: the consumer copies as ever."""
+    if dest is None:
+        return False
+    mv = memoryview(buf)
+    if mv.nbytes != dest.nbytes or (
+        np.frombuffer(mv, dtype=np.uint8).ctypes.data
+        != np.frombuffer(dest, dtype=np.uint8).ctypes.data
+    ):
+        return False
+    await consume_landed(dest.nbytes)
+    return True
+
+
+class ArrayBufferConsumer(BufferConsumer):
+    """Deserializes one buffer and copies it into a host target buffer.
+
+    ``fresh_target``: the restore allocated ``target`` itself and nobody
+    sees it before the restore ends, so the read may land in it
+    (:meth:`destination`); a caller's live array restored in place is not."""
+
+    def __init__(
+        self, target: np.ndarray, entry: ArrayEntry, fresh_target: bool = False
+    ) -> None:
         self.target = target  # writable, C-contiguous host array
         self.entry = entry
+        self.fresh_target = fresh_target
+
+    def destination(self) -> Optional[memoryview]:
+        return landing_view(self.entry, self.target) if self.fresh_target else None
 
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
+        if await consumed_by_landing(buf, self.destination()):
+            return
+
         def work() -> None:
             if is_raw_family(self.entry.serializer):
                 decode = (
@@ -702,8 +748,11 @@ class ArrayIOPreparer:
         target: np.ndarray,
         buffer_size_limit_bytes: Optional[int] = None,
         frame_table: Optional[List[int]] = None,
+        fresh_target: bool = False,
     ) -> List[ReadReq]:
         """Plan reads filling ``target`` (a writable host array).
+        ``fresh_target``: the caller allocated it for this read and shows it
+        to nobody before the read ends (``ArrayBufferConsumer``).
 
         ``frame_table`` (the compressed frame sizes from the entry's
         ``.ftab`` side object) enables budgeted sub-reads of framed
@@ -765,7 +814,9 @@ class ArrayIOPreparer:
             return [
                 ReadReq(
                     path=entry.location,
-                    buffer_consumer=ArrayBufferConsumer(target, entry),
+                    buffer_consumer=ArrayBufferConsumer(
+                        target, entry, fresh_target
+                    ),
                     byte_range=(base_range[0], base_range[1]),
                 )
             ]

@@ -77,6 +77,7 @@ class ChunkedArrayIOPreparer:
         target: np.ndarray,
         buffer_size_limit_bytes: Optional[int] = None,
         frame_tables: Optional[dict] = None,
+        fresh_target: bool = False,
     ) -> List[ReadReq]:
         read_reqs: List[ReadReq] = []
         for chunk in entry.chunks:
@@ -89,6 +90,7 @@ class ChunkedArrayIOPreparer:
                     view,
                     buffer_size_limit_bytes,
                     frame_table=(frame_tables or {}).get(chunk.tensor.location),
+                    fresh_target=fresh_target,
                 )
             )
         return read_reqs

@@ -40,7 +40,13 @@ from ..serialization import (
     string_to_dtype,
 )
 from ..utils import knobs
-from .array import ArrayIOPreparer, FramedSliceConsumer, slice_preserves_bits
+from .array import (
+    ArrayIOPreparer,
+    FramedSliceConsumer,
+    consumed_by_landing,
+    landing_view,
+    slice_preserves_bits,
+)
 
 # A target to restore into: (host buffer, global offsets, sizes)
 TargetShard = Tuple[np.ndarray, Sequence[int], Sequence[int]]
@@ -299,19 +305,40 @@ def shard_read_intervals(  # spmd-pure
 
 class ShardedArrayBufferConsumer(BufferConsumer):
     """Deserializes one saved shard and scatters it into every overlapping
-    destination buffer (reference ``ShardedTensorBufferConsumer:288``)."""
+    destination buffer (reference ``ShardedTensorBufferConsumer:288``).
+
+    ``fresh_targets``: the restore allocated the destination buffers itself
+    (``alloc_target_shards``) and nobody sees them before it ends, so a
+    piece that goes whole into one contiguous run of one buffer may be read
+    there (:meth:`destination`)."""
 
     def __init__(
         self,
         entry: ArrayEntry,
         copy_specs: List[Tuple[np.ndarray, Tuple[slice, ...], Tuple[slice, ...]]],
+        fresh_targets: bool = False,
     ) -> None:
         self.entry = entry
         self.copy_specs = copy_specs  # (dst_buffer, src_slices, dst_slices)
+        self.fresh_targets = fresh_targets
+
+    def destination(self) -> Optional[memoryview]:
+        if not self.fresh_targets or len(self.copy_specs) != 1:
+            return None
+        dst, src_slices, dst_slices = self.copy_specs[0]
+        if any(
+            (sl.start, sl.stop) != (0, int(n))
+            for sl, n in zip(src_slices, self.entry.shape)
+        ):
+            return None  # the piece is cut: only part of it goes here
+        return landing_view(self.entry, dst[dst_slices] if dst_slices else dst)
 
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
+        if await consumed_by_landing(buf, self.destination()):
+            return
+
         def work() -> None:
             if is_raw_family(self.entry.serializer):
                 decode = (
@@ -478,8 +505,10 @@ class ShardedArrayIOPreparer:
         buffer_size_limit_bytes: Optional[int] = None,
         frame_tables: Optional[Dict[str, List[int]]] = None,
         digests: Optional[Dict[str, object]] = None,
+        fresh_targets: bool = False,
     ) -> List[ReadReq]:
-        """Plan reads scattering saved shards into ``targets``.
+        """Plan reads scattering saved shards into ``targets``
+        (``fresh_targets``: see :class:`ShardedArrayBufferConsumer`).
 
         **Exact-overlap fetch**: for RAW shards, only the byte ranges the
         targets actually overlap are emitted — the row intervals of the
@@ -528,7 +557,7 @@ class ShardedArrayIOPreparer:
                 return ReadReq(
                     path=shard.tensor.location,
                     buffer_consumer=ShardedArrayBufferConsumer(
-                        shard.tensor, copy_specs
+                        shard.tensor, copy_specs, fresh_targets
                     ),
                     byte_range=base,
                 )
@@ -576,7 +605,7 @@ class ShardedArrayIOPreparer:
                     ReadReq(
                         path=shard.tensor.location,
                         buffer_consumer=ShardedArrayBufferConsumer(
-                            sub_entry, copy_specs
+                            sub_entry, copy_specs, fresh_targets
                         ),
                         byte_range=(base0 + b, base0 + e),
                     )

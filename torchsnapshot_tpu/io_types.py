@@ -80,6 +80,26 @@ class BufferConsumer(abc.ABC):
         """Estimated peak host memory consumed by :meth:`consume_buffer`."""
         ...
 
+    def destination(self) -> Optional[memoryview]:
+        """Where the request's read may land, or None (the default).
+
+        A consumer that would only copy its buffer, byte for byte, into one
+        contiguous host buffer the restore itself allocated answers a
+        writable flat byte view of exactly the bytes the read will deliver
+        (``ReadIO.into``). A plugin may fill it in place of a buffer of its
+        own; ``consume_buffer`` tells from the buffer it is handed whether
+        one did. Never a caller's live array: that goes on being overwritten
+        only by bytes that were fetched whole and, where verification is on,
+        verified."""
+        return None
+
+
+def destination_of(consumer: object) -> Optional[memoryview]:
+    """``consumer.destination()``; None for a consumer that only quacks like
+    a :class:`BufferConsumer` and predates the method."""
+    offer = getattr(consumer, "destination", None)
+    return offer() if offer is not None else None
+
 
 @dataclass
 class ReadReq:
@@ -113,6 +133,9 @@ class ReadBuffer:
     the consumer. The object must belong to the read or be immutable: a
     ``bytes`` a plugin also keeps (the memory plugin's store, a cache's
     shared fetch) is fine, a ``bytearray`` it goes on writing to is not.
+    A read that landed (``ReadIO.into``) holds a view of its consumer's own
+    host target: that memory belongs to the restore's leaf, the read only
+    borrows it, and a retried attempt overwrites it from its start.
 
     It answers the part of the reference's ``io.BytesIO`` contract the read
     path uses: ``write``, ``seek(0)`` + ``truncate(0)`` (the reset before a
@@ -174,9 +197,18 @@ class ReadBuffer:
 
 @dataclass
 class ReadIO:
+    """One storage read: what to fetch, and the buffer it is delivered in.
+
+    ``into`` is an offer, not an order (``BufferConsumer.destination``):
+    writable memory of the read's exact size that the consumer owns and the
+    read only borrows. A plugin that fills it hands ``buf`` a view of it;
+    one that ignores it delivers as ever, and the consumer copies. Wrappers
+    pass the ReadIO on untouched."""
+
     path: str
     byte_range: Optional[Tuple[int, int]] = None
     buf: ReadBuffer = field(default_factory=ReadBuffer)
+    into: Optional[memoryview] = None
 
 
 class StoragePlugin(abc.ABC):

@@ -13,7 +13,8 @@ it:
               engine for the time it was on the mount (no span: stamped in
               the engine, GIL-free)
     verify    the digest check of one fetched buffer
-    consume   one consumer's decode + copy into its host target
+    consume   one consumer's decode + copy into its host target (nothing,
+              where the read landed in that target: ``landed_bytes``)
     place     one finalizer: ``device_put`` / ``assemble_jax_array`` /
               ``make_array_from_callback``, a failed first attempt and the
               target's release included
@@ -54,6 +55,7 @@ from .engine.intervals import (
     measure,
     merge_intervals,
 )
+from . import telemetry
 from .telemetry import core as telemetry_core
 
 # kind -> (span name, span category, bridged onto a profiler trace)
@@ -72,6 +74,7 @@ _PIPELINE_KINDS = ("fetch", "verify", "consume", "place")
 _SUMS = (
     "fetch_wait_s",
     "fetch_copied_bytes",
+    "landed_bytes",
     "mount_bytes",
     "consume_wait_s",
     "place_wait_s",
@@ -283,6 +286,19 @@ def consumed_at() -> float:
     to :func:`run_consume_work`)."""
     clock = _CLOCK.get()
     return (clock.consumed_at if clock is not None else 0.0) or time.monotonic()
+
+
+async def consume_landed(nbytes: int) -> None:
+    """A consumer was handed its own destination's memory
+    (``io_types.ReadIO.into``): the read landed, nothing is left to decode
+    or copy. Counted as ``landed_bytes``, and still one consume interval,
+    stamped where it ran, so the consume layer's share falls by what it no
+    longer does and the request's clock runs on."""
+    times = _ACTIVE.get()
+    if times is not None:
+        times.add("landed_bytes", nbytes)
+    telemetry.counter_add("restore.landed_bytes", nbytes)
+    await run_consume_work(lambda: None, None)
 
 
 async def run_consume_work(

@@ -79,6 +79,7 @@ from .io_types import (
     StoragePlugin,
     WriteIO,
     WriteReq,
+    destination_of,
 )
 from .storage_plugins.cloud_retry import (
     CollectiveProgress,
@@ -957,6 +958,7 @@ async def fetch_read_io(
     path: str,
     byte_range: Optional[Tuple[int, int]],
     progress: "CollectiveProgress",
+    into: Optional[memoryview] = None,
 ) -> ReadIO:
     """One storage fetch of ``path`` (optionally ranged), retrying
     transient local OSErrors through the shared ``cloud_retry`` machinery
@@ -964,8 +966,9 @@ async def fetch_read_io(
     discipline of the read pipeline, shared with the broadcast and swarm
     restore paths so every origin read in the restore story retries
     identically. A retried read never appends to a partially-filled
-    buffer."""
-    read_io = ReadIO(path=path, byte_range=byte_range)
+    buffer. ``into``: the consumer's own destination, offered to the plugin
+    (``ReadIO.into``); a retried read overwrites it from its start."""
+    read_io = ReadIO(path=path, byte_range=byte_range, into=into)
 
     async def attempt() -> None:
         read_io.buf.seek(0)
@@ -1085,7 +1088,11 @@ async def execute_read_reqs(
         async def fetch(ctx) -> ReadIO:
             t0 = time.monotonic()
             read_io = await fetch_read_io(
-                storage, req.path, req.byte_range, read_progress
+                storage,
+                req.path,
+                req.byte_range,
+                read_progress,
+                into=destination_of(req.buffer_consumer),
             )
             if times is not None:
                 times.record_fetch(

@@ -60,6 +60,7 @@ from .io_types import (
     ReadReq,
     StoragePlugin,
     WriteIO,
+    destination_of,
 )
 from .manifest import (
     ArrayEntry,
@@ -3507,6 +3508,9 @@ class _CountingConsumer:
         # unmerged; proxy it or wrapped framed reads would coalesce.
         self.merge_exempt = getattr(inner, "merge_exempt", False)
 
+    def destination(self) -> Optional[memoryview]:
+        return destination_of(self.inner)
+
     async def consume_buffer(self, buf, executor=None) -> None:
         inner = self.inner
         await inner.consume_buffer(buf, executor)
@@ -3941,10 +3945,16 @@ def _prepare_restore_one(  # spmd-pure
             and live.flags["C_CONTIGUOUS"]
             and live.flags["WRITEABLE"]
         )
+        # A target of the restore's own may have its read land in it; the
+        # caller's live array is overwritten only by bytes fetched whole.
         target = live if in_place else np.empty(tuple(entry.shape), dtype=np_dtype)
         if isinstance(entry, ChunkedArrayEntry):
             reqs = ChunkedArrayIOPreparer.prepare_read(
-                entry, target, buffer_size_limit_bytes, frame_tables=frame_tables
+                entry,
+                target,
+                buffer_size_limit_bytes,
+                frame_tables=frame_tables,
+                fresh_target=not in_place,
             )
         else:
             reqs = ArrayIOPreparer.prepare_read(
@@ -3952,6 +3962,7 @@ def _prepare_restore_one(  # spmd-pure
                 target,
                 buffer_size_limit_bytes,
                 frame_table=(frame_tables or {}).get(entry.location),
+                fresh_target=not in_place,
             )
         if _is_jax_array(live):
 
@@ -3995,6 +4006,7 @@ def _prepare_restore_one(  # spmd-pure
                 buffer_size_limit_bytes,
                 frame_tables=frame_tables,
                 digests=digests,
+                fresh_targets=True,
             )
 
             def finalize_sharded() -> None:
@@ -4024,6 +4036,7 @@ def _prepare_restore_one(  # spmd-pure
             buffer_size_limit_bytes,
             frame_tables=frame_tables,
             digests=digests,
+            fresh_targets=not in_place,
         )
         loaded[logical_path] = target
         return reqs, None

@@ -26,7 +26,7 @@ import os
 import threading
 import uuid
 from concurrent.futures import ThreadPoolExecutor
-from typing import List, Optional, Set
+from typing import List, Optional, Set, Union
 
 import aiofiles
 import numpy as np
@@ -296,13 +296,13 @@ class FSStoragePlugin(StoragePlugin):
             offset, end = read_io.byte_range
             nbytes = end - offset
             if self._use_native(nbytes):
-                data = await self._native_read(path, offset, nbytes)
+                data = await self._native_read(path, offset, nbytes, read_io.into)
             else:
                 data = await self._buffered_read(path, offset, nbytes)
         elif self._native is not None:
             # Full-object read: the size probe (needed to route + allocate)
             # runs inside the executor task — never stat() on the event loop.
-            data = await self._native_read(path, 0, None)
+            data = await self._native_read(path, 0, None, read_io.into)
         else:
             data = await self._buffered_read(path, 0, None)
         # Handed over, not copied: the object the read filled is the one
@@ -318,13 +318,21 @@ class FSStoragePlugin(StoragePlugin):
             return await (f.read(nbytes) if nbytes is not None else f.read())
 
     async def _native_read(
-        self, path: str, offset: int, nbytes: Optional[int]
-    ) -> np.ndarray:
+        self,
+        path: str,
+        offset: int,
+        nbytes: Optional[int],
+        into: Optional[memoryview] = None,
+    ) -> Union[np.ndarray, memoryview]:
+        """The engine's chunk reads into ``into``, where the consumer
+        offered its own destination and it is the read's size (fetch and
+        consume are then one pass and one first touch of those pages), else
+        into a fresh array."""
         lib = self._native
         # Taken on the loop side: an executor thread inherits no context.
         times = restore_times.get_active()
 
-        def work() -> np.ndarray:
+        def work() -> Union[np.ndarray, memoryview]:
             self._set_read_depth(lib)
             fail_chunk = -1
             if knobs.get_faults_spec():
@@ -339,9 +347,14 @@ class FSStoragePlugin(StoragePlugin):
             ) as sp:
                 n = native.file_size(lib, path) - offset if nbytes is None else nbytes
                 sp.set_attrs(nbytes=n)
-                # Uninitialised: ``bytearray(n)`` would zero-fill it under
-                # the GIL. A failed attempt's array dies with its exception.
-                out = np.empty(n, dtype=np.uint8)
+                if into is not None and into.nbytes == n and not into.readonly:
+                    # A retried attempt overwrites it from its start.
+                    out = into
+                else:
+                    # Uninitialised: ``bytearray(n)`` would zero-fill it
+                    # under the GIL. A failed attempt's array dies with its
+                    # exception.
+                    out = np.empty(n, dtype=np.uint8)
                 chunk_reads = native.read_into(
                     lib,
                     path,
