@@ -3,10 +3,14 @@ run (virtual devices; bytes and counts only — the curve itself is a chip
 measurement): fast tier-1 smoke + the slow-lane sweep."""
 
 import json
+import os
 import subprocess
 import sys
 
 import pytest
+
+# The harness's children keep their compile cache under this test's tmp_path.
+pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
 def _run_bench(devices: str, mb: int, timeout: int = 420, platform="cpu"):
@@ -15,6 +19,7 @@ def _run_bench(devices: str, mb: int, timeout: int = 420, platform="cpu"):
         env={
             "PATH": "/usr/bin:/bin:/usr/local/bin",
             "JAX_PLATFORMS": "cpu",
+            "JAX_COMPILATION_CACHE_DIR": os.environ["JAX_COMPILATION_CACHE_DIR"],
             "MULTICHIP_BENCH_DEVICES": devices,
             "MULTICHIP_BENCH_MB": str(mb),
         },

@@ -8,6 +8,8 @@ those two files are collected here as they stand.
 import os
 import sys
 
+import pytest
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
@@ -16,3 +18,6 @@ from perfbench import run  # noqa: E402
 for _name in ("test_architecture", "test_contract"):
     _module = run.load_module("pb_" + _name, os.path.join(ROOT, "perfbench", "tests", _name + ".py"))
     globals().update({name: obj for name, obj in vars(_module).items() if name.startswith("test_")})
+
+# Whatever these tests start keeps its compile cache under their own tmp_path.
+pytestmark = pytest.mark.usefixtures("compile_cache_dir")

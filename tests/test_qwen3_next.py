@@ -22,6 +22,8 @@ from perfbench import run, trainstate  # noqa: E402
 _model_tests = run.load_module("pb_test_qwen3_next", os.path.join(ROOT, "perfbench", "tests", "test_qwen3_next.py"))
 globals().update({name: obj for name, obj in vars(_model_tests).items() if name.startswith("test_")})
 arch, TINY = _model_tests.arch, _model_tests.TINY
+# Whatever these tests start keeps its compile cache under their own tmp_path.
+pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
 # (e) the train state through the library, default knobs -------------------------

@@ -5,10 +5,14 @@ form of "elastic reshard is minimal-byte" (bit-exact, origin bytes ≤
 1.1× theoretical overlap, replicated overlaps fetched once fleet-wide)."""
 
 import json
+import os
 import subprocess
 import sys
 
 import pytest
+
+# The harness's children keep their compile cache under this test's tmp_path.
+pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
 def _run_bench(cells: str, mb: int, fleet_ks: str, timeout: int = 420) -> dict:
@@ -17,6 +21,7 @@ def _run_bench(cells: str, mb: int, fleet_ks: str, timeout: int = 420) -> dict:
         env={
             "PATH": "/usr/bin:/bin:/usr/local/bin",
             "JAX_PLATFORMS": "cpu",
+            "JAX_COMPILATION_CACHE_DIR": os.environ["JAX_COMPILATION_CACHE_DIR"],
             "RESHARD_BENCH_CELLS": cells,
             "RESHARD_BENCH_MB": str(mb),
             "RESHARD_BENCH_GRAIN": "65536",
