@@ -170,8 +170,8 @@ def test_mid_drain_abort_with_lanes_in_flight_credits_every_debit() -> None:
         await asyncio.wait_for(pipeline.run_to_completion(), timeout=30)
 
     with knobs.override_d2h_lanes(4):
-        # Half the budget: later leaves are admitted (and hinted onto the
-        # lanes) while earlier ones write.
+        # Half the budget: later leaves are admitted (and hinted by the
+        # lanes at their turn) while earlier ones write.
         pipeline = _WritePipeline(
             reqs, storage, memory_budget_bytes=4 * 256 * 256 * 4, rank=0
         )

@@ -14,7 +14,6 @@ def test_defaults() -> None:
     assert knobs.is_batching_enabled() is False
     assert knobs.get_memory_budget_override_bytes() is None
     assert knobs.is_async_device_copy_enabled() is True
-    assert knobs.is_async_eager_d2h_enabled() is True
 
 
 def test_override_restores_prior_value() -> None:

@@ -144,9 +144,6 @@ class CompressedSlabStager(BufferStager):
         # Raw slab + compressed output coexist during compression.
         return 2 * self.inner.get_staging_cost_bytes()
 
-    def start_d2h_hint(self) -> None:
-        self.inner.start_d2h_hint()
-
 
 class SlabFrameTableStager(PollingTableStager):
     """A compressed slab's ``.ftab``: per-frame raw AND compressed sizes
@@ -191,10 +188,6 @@ class BatchedBufferStager(BufferStager):
 
     def get_staging_cost_bytes(self) -> int:
         return self.total
-
-    def start_d2h_hint(self) -> None:
-        for req, _, _ in self.members:
-            req.buffer_stager.start_d2h_hint()
 
 
 class DeviceBatchedBufferStager(BatchedBufferStager):
@@ -273,14 +266,6 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
             )
         telemetry.counter_add("batcher.slabs_device_packed")
         return np.ascontiguousarray(host)
-
-    def start_d2h_hint(self) -> None:
-        # Deliberately a no-op: packing here would run a jit trace+compile on
-        # async_take's capture path (the stall this design exists to avoid)
-        # and pin every packed slab in HBM until the background drain. Slabs
-        # are < the slab threshold by construction — losing their eager-D2H
-        # prefetch is cheap; the background staging packs and fetches them.
-        pass
 
 
 # Dtypes an on-device packed slab can carry: byte-width dtypes whose jitted

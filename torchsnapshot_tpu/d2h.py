@@ -1,29 +1,31 @@
 """Parallel device→host transfer lanes + stage-time attribution.
 
-The background drain used to resolve device→host transfers one ``np.asarray``
-at a time per request: the staging stream was a chain of
-hint → resolve → serialize → hash → append steps in which the link sat idle
-for every serialize/hash gap. BENCH rounds 2→5 measured the cost —
-``stage_busy`` at 95-99% of drain wall while ``io_busy`` stayed under 10%,
-and ``drain_vs_link`` stuck at ~0.66. This module closes the gap with two
-cooperating pieces:
+A transfer has two halves: ``copy_to_host_async()`` asks the device for it
+(the *hint*), and ``np.asarray`` waits for the host copy (the *resolve*).
+Two cooperating pieces:
 
-- :class:`TransferLanes` — N concurrent transfer lanes (a dedicated
-  ``ThreadPoolExecutor``, knob ``TORCHSNAPSHOT_TPU_D2H_LANES``):
-  ``copy_to_host_async()`` is issued when a request is admitted, and the
-  (already in-flight) transfers resolve out of the lane executor
-  concurrently — so the transfer engine runs back-to-back while
-  serialize/hash/write work on earlier requests. The resolved host bytes
-  are debited by the request's own admission.
+- :class:`TransferLanes` — N resolving lanes (a dedicated
+  ``ThreadPoolExecutor``, knob ``TORCHSNAPSHOT_TPU_D2H_LANES``) behind a
+  **window a device**: a transfer is hinted only while the bytes hinted and
+  not yet resolved on its device stay under :data:`HINT_WINDOW_BYTES`; one
+  bigger than the window goes alone; the others wait their turn in the order
+  they were admitted and are hinted as resolves make room. Nothing is hinted
+  inside ``async_take``'s stall, and a request is hinted at its turn, not at
+  its admission: a take that hands the device the whole snapshot at once
+  makes the job's next step (and its own 4-byte read of the loss) wait behind
+  all of it — 0.9-1.3 s behind 3.1-3.2 GB on a v5e, against 0.00-0.04 s
+  under the window (``PERF.md`` section 6, PR 33's probe). The resolved host bytes are
+  debited by the request's own admission, so no host copy exists that the
+  memory budget has not seen.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
   sub-phase intervals (``d2h`` / ``serialize`` / ``hash``). The scheduler
   derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s`` from these
   by the same interval-union algebra as the stage/io streams, so the
   monolithic ``stage_busy`` decomposes in drain stats, persisted telemetry
-  artifacts, and bench output — the next staging regression is attributable
-  instead of a single opaque number. With a telemetry session active the
-  same intervals are exported as ``stage.d2h``/``stage.serialize``/
-  ``stage.hash`` spans.
+  artifacts, and bench output. The ``d2h`` interval is a lane's resolve, not
+  the wait for room in the window. With a telemetry session active the same
+  intervals are exported as ``stage.d2h``/``stage.serialize``/``stage.hash``
+  spans.
 
 The write pipeline activates a :class:`StagingContext` (lanes + times) via a
 ``contextvars.ContextVar`` around staging-task creation — the same pattern
@@ -33,12 +35,13 @@ degrade gracefully (no lanes, no recording) when driven outside a pipeline.
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import logging
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -46,6 +49,18 @@ from .telemetry import core as telemetry_core
 from .utils import knobs
 
 logger = logging.getLogger(__name__)
+
+# The most bytes hinted and not yet resolved on one device. From the probe of
+# PR 33 on a v5e (``PERF.md`` section 6): beside a donated step that reads its
+# loss, with 3.1-3.2 GB of whole leaves to move, 512 MiB adds 0.13-0.26 s to
+# the job's steps per take where the whole snapshot hinted at once adds
+# 1.2-1.4 s. What the steps lose grows with the window (in the benchmark's
+# own cells 768 MiB and 1 GiB kept 2-6 points less of the step rate than
+# 512 MiB), and the transfers' rate falls with it: one whole leaf in flight
+# reads 0.8-1.0 GB/s, three or four 1.0-1.4, all of them 1.45-1.65, so
+# 256 MiB or one leaf at a time would halve it. A value, not a knob: no
+# caller wants the burst back.
+HINT_WINDOW_BYTES = 512 * 1024 * 1024
 
 
 def hint_copy_to_host(arr: Any) -> None:
@@ -169,12 +184,34 @@ class timed:
         return False
 
 
+class _DeviceWindow:
+    """One device's hinted-and-unresolved bytes, and the transfers waiting
+    for room: ``(future, nbytes)`` in the order they asked."""
+
+    __slots__ = ("ahead", "waiting")
+
+    def __init__(self) -> None:
+        self.ahead = 0
+        self.waiting: Deque[Tuple[Any, int]] = collections.deque()
+
+
 class TransferLanes:
-    """N concurrent D2H resolution lanes: a dedicated transfer executor."""
+    """N concurrent D2H resolution lanes (a dedicated transfer executor)
+    behind a hint window a device (:data:`HINT_WINDOW_BYTES`).
+
+    The windows are touched on the event loop only (``start`` runs there and
+    the lanes only resolve), so they need no lock; the executor's threads
+    never see them."""
 
     def __init__(self, lanes: Optional[int] = None) -> None:
         self.lane_count = lanes if lanes is not None else knobs.get_d2h_lanes()
         self._executor: Optional[ThreadPoolExecutor] = None
+        self._windows: Dict[Optional[int], _DeviceWindow] = {}
+        # What the window did, for the take's telemetry: the most bytes ever
+        # hinted and unresolved on one device (``d2h.hinted_ahead_hwm_bytes``)
+        # and the transfers that waited for room (``d2h.window_waits``).
+        self.hinted_ahead_hwm_bytes = 0
+        self.window_waits = 0
 
     def executor(self) -> ThreadPoolExecutor:
         if self._executor is None:
@@ -184,21 +221,21 @@ class TransferLanes:
             )
         return self._executor
 
-    def start(
+    async def start(
         self,
         arr: Any,
         nbytes: int,
         loop,
         times: Optional[StageTimes] = None,
         location: str = "",
-    ):
-        """Hint ``arr``'s transfer NOW and schedule its resolve on a lane.
+    ) -> np.ndarray:
+        """``arr`` on the host: wait for room in its device's window, hint
+        the transfer, resolve it on a lane.
 
-        Returns an awaitable future of the host ``np.ndarray``. The resolve
-        is timed inside the lane thread, so the recorded ``d2h`` interval is
-        transfer time only — not the time the future waited to be awaited
-        (that wait is exactly the overlap the lanes exist to create)."""
-        hint_copy_to_host(arr)
+        The resolve is timed inside the lane thread, so the recorded ``d2h``
+        interval is transfer time only — neither the wait for room nor the
+        time the result waited to be awaited (that wait is exactly the
+        overlap the lanes exist to create)."""
         devices = arr.devices()
         device = next(iter(devices)).id if len(devices) == 1 else None
 
@@ -206,7 +243,53 @@ class TransferLanes:
             with timed(times, "d2h", path=location, nbytes=nbytes, device=device):
                 return np.asarray(arr)
 
-        return loop.run_in_executor(self.executor(), resolve)
+        window = self._windows.setdefault(device, _DeviceWindow())
+        await self._room(window, nbytes, loop)
+        try:
+            hint_copy_to_host(arr)
+            return await loop.run_in_executor(self.executor(), resolve)
+        finally:
+            self._resolved(window, nbytes)
+
+    def _fits(self, window: _DeviceWindow, nbytes: int) -> bool:
+        return window.ahead == 0 or window.ahead + nbytes <= HINT_WINDOW_BYTES
+
+    def _admit(self, window: _DeviceWindow, nbytes: int) -> None:
+        window.ahead += nbytes
+        if window.ahead > self.hinted_ahead_hwm_bytes:
+            self.hinted_ahead_hwm_bytes = window.ahead
+
+    async def _room(self, window: _DeviceWindow, nbytes: int, loop) -> None:
+        if not window.waiting and self._fits(window, nbytes):
+            self._admit(window, nbytes)
+            return
+        self.window_waits += 1
+        turn = loop.create_future()
+        window.waiting.append((turn, nbytes))
+        try:
+            await turn
+        except BaseException:
+            # Cancelled (an abort's sweep). Still in line: ``_pump`` drops
+            # the cancelled turn when it comes up. Given room in the same
+            # turn of the loop: hand it on.
+            if not turn.cancelled():
+                self._resolved(window, nbytes)
+            raise
+
+    def _resolved(self, window: _DeviceWindow, nbytes: int) -> None:
+        window.ahead -= nbytes
+        self._pump(window)
+
+    def _pump(self, window: _DeviceWindow) -> None:
+        """Give room to the transfers at the head of the line, in order."""
+        while window.waiting:
+            turn, nbytes = window.waiting[0]
+            if not turn.cancelled():
+                if not self._fits(window, nbytes):
+                    break
+                self._admit(window, nbytes)
+                turn.set_result(None)
+            window.waiting.popleft()
 
     def shutdown(self, cancel_queued: bool = False) -> None:
         if self._executor is not None:

@@ -5,11 +5,14 @@ reference's performance trick is overlapping CUDA D2H copies (run on a
 GIL-dropping jit-scripted helper inside a thread pool) with storage I/O; the
 XLA-native equivalent used here is:
 
-1. ``jax.Array.copy_to_host_async()`` at the start of staging — enqueues the
-   transfer on the device without blocking the Python thread or the XLA
-   stream;
-2. ``np.asarray(arr)`` inside a thread-pool executor — resolves the (already
-   in-flight) transfer off the event loop, so many transfers and storage
+1. ``jax.Array.copy_to_host_async()`` (the *hint*) — asks the device for the
+   transfer without blocking the Python thread or the XLA stream. Inside a
+   write pipeline the transfer lanes issue it, at the request's turn under
+   their window a device (``d2h.TransferLanes``, ``d2h.HINT_WINDOW_BYTES``):
+   never inside ``async_take``'s stall, and never the whole snapshot at
+   once, which would make the job's next step wait behind all of it;
+2. ``np.asarray(arr)`` on a lane's thread — resolves the (already
+   in-flight) transfer off the event loop, so a few transfers and the storage
    writes interleave under the scheduler's memory budget.
 
 Serialization is zero-copy for every dtype in ``SUPPORTED_DTYPES`` (including
@@ -127,7 +130,8 @@ def chunk_row_ranges(
 
 
 def to_host(arr: Any, executor: Optional[Executor] = None):
-    """Kick off an async D2H transfer; return an awaitable resolver."""
+    """Kick off an async D2H transfer; return an awaitable resolver. The
+    path of a stager driven outside a write pipeline (no lanes, no window)."""
     if _is_jax_array(arr):
         hint_copy_to_host(arr)
 
@@ -146,7 +150,8 @@ async def _traced_to_host(
     """Resolve one device→host transfer, attributed as ``stage.d2h``.
 
     Inside a write pipeline (an active :class:`~..d2h.StagingContext`) the
-    resolve runs on the DEDICATED transfer-lane executor — never queued
+    transfer waits for room in its device's hint window, is hinted, and
+    resolves on the DEDICATED transfer-lane executor — never queued
     behind serialize/compress jobs on the staging pool — and the lane
     records the transfer interval for the stage-time decomposition. Outside
     a pipeline it falls back to :func:`to_host` on the given executor, with
@@ -324,11 +329,6 @@ class ArrayBufferStager(BufferStager):
             return 2 * nbytes
         return nbytes
 
-    def start_d2h_hint(self) -> None:
-        if not _is_jax_array(self.arr):
-            return
-        hint_copy_to_host(self.arr)
-
 
 class PollingTableStager(BufferStager):
     """Base for ``.ftab`` side-object stagers: polls a main stager's
@@ -377,9 +377,6 @@ class PollingTableStager(BufferStager):
     def get_staging_cost_bytes(self) -> int:
         # ~8 digits per frame size; a 4 GB payload at 8 MiB frames is ~4 KB.
         return 16384
-
-    def start_d2h_hint(self) -> None:
-        pass  # no device data of its own
 
 
 class FrameTableStager(PollingTableStager):

@@ -44,15 +44,6 @@ class BufferStager(abc.ABC):
         """Estimated peak host memory consumed by :meth:`stage_buffer`."""
         ...
 
-    def start_d2h_hint(self) -> None:
-        """Optionally begin the device→host transfer early (non-blocking).
-
-        Called by ``_take_impl`` on deferred-staging requests that survived
-        write partitioning, right before ``async_take`` returns — so DMAs for
-        exactly the bytes this rank will write start overlapping training.
-        Default: no-op (host-resident sources have nothing to transfer).
-        """
-
 
 @dataclass
 class WriteReq:

@@ -209,9 +209,9 @@ class PipelinePools:
         return self._consuming
 
     def transfer_lanes(self) -> d2h.TransferLanes:
-        """The operation's parallel D2H lanes (dedicated transfer executor;
-        see ``d2h.TransferLanes``). Sized by the D2H_LANES knob at first
-        use."""
+        """The operation's parallel D2H lanes (dedicated transfer executor
+        behind a hint window a device; see ``d2h.TransferLanes``). Sized by
+        the D2H_LANES knob at first use."""
         if self._lanes is None:
             self._lanes = d2h.TransferLanes()
         return self._lanes
@@ -750,6 +750,11 @@ class _WritePipeline:
         telemetry.gauge_max(
             "scheduler.budget_hwm_bytes", self.budget.high_water_bytes
         )
+        lanes = self._staging_ctx.lanes
+        telemetry.gauge_max(
+            "d2h.hinted_ahead_hwm_bytes", lanes.hinted_ahead_hwm_bytes
+        )
+        telemetry.counter_add("d2h.window_waits", lanes.window_waits)
         telemetry.counter_add("scheduler.bytes_staged", self.bytes_staged)
         if self.bytes_deduped:
             telemetry.counter_add("scheduler.bytes_deduped", self.bytes_deduped)

@@ -1057,7 +1057,9 @@ class Snapshot:
                 prepare_timings["cache_miss"] = time.monotonic() - t0
         _phase("partition", then="d2h_hint")
         # Decompose the dominant stall phases into stage.prepare.* sub-spans
-        # (d2h_hint: the defensive device fork + transfer hints;
+        # (d2h_hint: the defensive device fork, and a blocking host
+        # capture's own hints; the drain's transfers are hinted by the lanes
+        # under their window, ``d2h.TransferLanes``, never in the stall;
         # stager_construction: per-preparer planning; plan: the remainder;
         # cache_hit / cache_miss: prepared-state rebind / store overhead).
         # Out-of-band notes: they ride the tracker's span list into
@@ -1066,13 +1068,6 @@ class Snapshot:
         for bucket, dur in sorted(prepare_timings.items()):
             tracker.note(f"stage.prepare.{bucket}", dur)
 
-        if is_async_snapshot and knobs.is_async_eager_d2h_enabled():
-            # Post-partition, so DMAs start only for the bytes THIS rank
-            # will actually write — replicated arrays assigned to other
-            # ranks never touch this host's RAM or PCIe.
-            for req in write_reqs:
-                if req.defer_staging:
-                    req.buffer_stager.start_d2h_hint()
         _phase("d2h_hint", then="manifest_gather")
 
         if plan.cache_hit:

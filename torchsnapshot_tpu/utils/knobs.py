@@ -53,7 +53,6 @@ def is_batching_enabled() -> bool:
 
 
 _ENV_ASYNC_DEVICE_COPY = "TORCHSNAPSHOT_TPU_ASYNC_DEVICE_COPY"
-_ENV_ASYNC_EAGER_D2H = "TORCHSNAPSHOT_TPU_ASYNC_EAGER_D2H"
 _ENV_DEVICE_BATCHING = "TORCHSNAPSHOT_TPU_DEVICE_BATCHING"
 
 
@@ -99,17 +98,6 @@ def override_async_fork_hbm_limit_bytes(value: int):
     return _override_env(_ENV_ASYNC_FORK_HBM_LIMIT, str(value))
 
 
-def is_async_eager_d2h_enabled() -> bool:
-    """Start D2H DMAs at ``async_take`` capture time.
-
-    Host buffers for the full captured state materialize outside the staging
-    budget (bounded by device HBM, which is smaller than host RAM on every
-    TPU-VM shape). Disable to strictly budget host memory at the cost of a
-    serialized D2H in the background drain.
-    """
-    return os.environ.get(_ENV_ASYNC_EAGER_D2H, "1") not in ("0", "false", "False")
-
-
 def override_async_device_copy(enabled: bool):
     return _override_env(_ENV_ASYNC_DEVICE_COPY, "1" if enabled else "0")
 
@@ -136,10 +124,6 @@ def get_async_capture_mode() -> str:
 
 def override_async_capture(mode: str):
     return _override_env(_ENV_ASYNC_CAPTURE, mode)
-
-
-def override_async_eager_d2h(enabled: bool):
-    return _override_env(_ENV_ASYNC_EAGER_D2H, "1" if enabled else "0")
 
 
 def is_native_io_enabled() -> bool:
@@ -751,9 +735,11 @@ def get_d2h_lanes() -> int:
     """Concurrent device→host transfer lanes per write pipeline (default 4).
 
     Each lane is one thread on a dedicated transfer executor that resolves
-    an already-hinted (``copy_to_host_async``) transfer via ``np.asarray``,
-    so several leaves' transfers run back-to-back while earlier leaves
-    serialize/hash/write. Distinct from ``TORCHSNAPSHOT_TPU_STAGING_THREADS``
+    a transfer via ``np.asarray`` once the lanes have hinted it
+    (``copy_to_host_async``, issued under a window of
+    ``d2h.HINT_WINDOW_BYTES`` hinted and unresolved a device, not at
+    admission), so a few leaves' transfers run back-to-back while earlier
+    leaves serialize/hash/write. Distinct from ``TORCHSNAPSHOT_TPU_STAGING_THREADS``
     (the serialize/compress pool): a multi-second compression job on the
     staging pool can no longer head-of-line block the transfer engine.
     """
