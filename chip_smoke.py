@@ -15,7 +15,8 @@ cut, and a jitted train step that DONATES its state.
              ``bytes_limit``: >= 55% in use, state larger than free HBM):
              the fork cannot fit, so capture degrades leaf by leaf. A step
              the fork leaves no room for is reported, not retried.
-  programs   the device programs the library jits — batched fork,
+  programs   the device programs the library jits — batched fork (whole
+             copies and the row cut of leaves over the piece size),
              on-device slab pack
              (``TORCHSNAPSHOT_TPU_ENABLE_BATCHING=1``) — fed every bit
              pattern of every sub-32-bit float, put from the host: denormals
@@ -784,9 +785,15 @@ def run_programs_leg(ctx: dict) -> dict:
         "bfloat16/float16/float8 pattern (denormals, NaN payloads) included"
     )
 
-    # (2) Big leaves, default knobs: the fork of an async take, and one
-    # whole transfer and write each (two hash grains and more).
-    nbytes = 2 * knobs.get_hash_chunk_bytes() if ctx["measured"] else 256 * 1024
+    # (2) Big leaves, default knobs: the fork of an async take, which cuts
+    # the leaves over the piece size into row-range pieces (``d2h.PIECE_BYTES``:
+    # on the chip every bfloat16 pattern and the random float32 bits cross in
+    # pieces), and the whole transfers of a synchronous take and of the
+    # dtypes that never fork (two hash grains and more).
+    from torchsnapshot_tpu import d2h
+    from torchsnapshot_tpu.io_preparers.array import piece_row_ranges
+
+    nbytes = max(2 * knobs.get_hash_chunk_bytes(), 3 * d2h.PIECE_BYTES) if ctx["measured"] else 256 * 1024
     host = {
         f"big_{np.dtype(dt).name}": all_patterns(dt, (nbytes // np.dtype(dt).itemsize // 4096, 4096))
         for dt in small_floats
@@ -796,20 +803,41 @@ def run_programs_leg(ctx: dict) -> dict:
     ).view(np.float32)  # random bits: denormals and NaN payloads, twice the size
     state = put(host)
     total = sum(v.nbytes for v in host.values())
+    cut = {
+        k: piece_row_ranges(v.shape, v.dtype)
+        for k, v in host.items() if copy_preserves_bits(v.dtype)
+    }
+    want_pieces = sum(len(r) for r in cut.values() if r)
+    want_pieced = sum(host[k].nbytes for k, r in cut.items() if r)
+    if ctx["measured"]:
+        check(
+            bool(cut["big_bfloat16"]) and bool(cut["big_float32"]),
+            "[programs] the big bfloat16 and float32 leaves are not over the piece size",
+        )
     for mode in ("take", "async_take"):
         metrics = round_trip(f"programs_big_{mode}", mode, state, host)
         forked = int(metrics.get("capture.forked_leaves", 0))
-        log(f"[programs] {mode} of {len(host)} big leaves / {total / 1e6:.0f} MB: {forked} forked")
-        can_fork = sum(copy_preserves_bits(v.dtype) for v in host.values())
+        pieces = int(metrics.get("d2h.pieces", 0))
+        pieced = int(metrics.get("d2h.pieced_bytes", 0))
+        log(
+            f"[programs] {mode} of {len(host)} big leaves / {total / 1e6:.0f} MB: {forked} forked, "
+            f"{pieces} pieces / {pieced / 1e6:.0f} MB crossed in pieces"
+        )
         check(
-            forked == (can_fork if mode == "async_take" else 0),
-            f"[programs] {mode}: {forked} big leaves forked, expected {can_fork} "
+            forked == (len(cut) if mode == "async_take" else 0),
+            f"[programs] {mode}: {forked} big leaves forked, expected {len(cut)} "
             "(float32, bfloat16) in an async take",
         )
+        check(
+            (pieces, pieced) == ((want_pieces, want_pieced) if mode == "async_take" else (0, 0)),
+            f"[programs] {mode}: {pieces} pieces / {pieced} bytes, expected "
+            f"{want_pieces} / {want_pieced} in an async take and none in a synchronous one",
+        )
         out[f"big_leaves_forked_{mode}"] = forked
+        out[f"pieces_{mode}"] = pieces
     free_tree(dict(state))
     log(
-        f"[programs] fork + whole transfers: {len(host)} leaves / {total / 1e6:.0f} MB "
+        f"[programs] fork (whole and in pieces) + transfers: {len(host)} leaves / {total / 1e6:.0f} MB "
         "restore bit-exact, every small-float pattern included"
     )
     return out

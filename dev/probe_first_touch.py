@@ -1,0 +1,94 @@
+"""Host-only probe of PR 39 (``chiprun -- python dev/probe_first_touch.py``):
+what the first write into fresh host pages costs on the chip's machine, and
+whether huge pages or a bulk populate cure it. ``PERF.md`` section 6."""
+
+import json
+import mmap
+import os
+import sys
+import time
+from concurrent.futures import ThreadPoolExecutor
+
+import numpy as np
+
+MIB = 1 << 20
+HUGE = 2 * MIB
+MADV_POPULATE_WRITE = 23
+
+
+def aligned(nbytes, advice=None):
+    m = mmap.mmap(-1, nbytes + HUGE)
+    base = np.frombuffer(m, dtype=np.uint8)
+    off = (-base.ctypes.data) % HUGE
+    if advice is not None:
+        m.madvise(advice, 0, nbytes + HUGE)
+    return base[off : off + nbytes], m
+
+
+def fill(arr, threads=1):
+    t0 = time.perf_counter()
+    if threads == 1:
+        arr.fill(1)
+    else:
+        n = arr.nbytes // threads
+        with ThreadPoolExecutor(threads) as ex:
+            list(ex.map(lambda i: arr[i * n : (i + 1) * n].fill(1), range(threads)))
+    return time.perf_counter() - t0
+
+
+def meminfo(key):
+    with open("/proc/meminfo") as f:
+        for line in f:
+            if line.startswith(key):
+                return line.split()[1] + " kB"
+
+
+def main():
+    n = 512 * MIB
+    out = {}
+    for name in ("enabled", "defrag", "shmem_enabled"):
+        try:
+            with open(f"/sys/kernel/mm/transparent_hugepage/{name}") as f:
+                out[f"thp_{name}"] = f.read().strip()
+        except OSError as e:
+            out[f"thp_{name}"] = repr(e)
+    out["uname"] = " ".join(os.uname())
+    src = np.ones(n, np.uint8)
+
+    def gbps(s):
+        return round(n / s / 1e9, 3)
+
+    for threads in (1, 4):
+        a = np.empty(n, np.uint8)
+        out[f"np_empty_first_fill_t{threads}"] = gbps(fill(a, threads))
+        out[f"np_empty_second_fill_t{threads}"] = gbps(fill(a, threads))
+        t0 = time.perf_counter()
+        np.copyto(a, src)
+        out[f"warm_copy_t{threads}"] = gbps(time.perf_counter() - t0)
+        del a
+        a, m = aligned(n)
+        out[f"mmap_first_fill_t{threads}"] = gbps(fill(a, threads))
+        del a, m
+        a, m = aligned(n, mmap.MADV_HUGEPAGE)
+        before = meminfo("AnonHugePages")
+        out[f"mmap_hugepage_first_fill_t{threads}"] = gbps(fill(a, threads))
+        out[f"anon_huge_pages_t{threads}"] = [before, meminfo("AnonHugePages")]
+        out[f"mmap_hugepage_second_fill_t{threads}"] = gbps(fill(a, threads))
+        del a, m
+    a, m = aligned(n)
+    try:
+        t0 = time.perf_counter()
+        m.madvise(MADV_POPULATE_WRITE, 0, n)
+        out["populate_write"] = gbps(time.perf_counter() - t0)
+        out["populate_then_fill"] = gbps(fill(a))
+    except OSError as e:
+        out["populate_write"] = repr(e)
+    del a, m
+    print(json.dumps(out, indent=1))
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/probe_first_touch.json", "w") as f:
+        json.dump(out, f, indent=1)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

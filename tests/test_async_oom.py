@@ -140,7 +140,7 @@ def test_injected_resource_exhausted_from_fork_degrades(tmp_path, monkeypatch) -
     simulation knob) takes the same degradation path."""
     import torchsnapshot_tpu.io_preparer as iop
 
-    def exploding_copy_fn(shardings):
+    def exploding_copy_fn(shardings, cuts):
         def fn(xs):
             raise RuntimeError(
                 "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
@@ -172,7 +172,7 @@ def test_non_oom_fork_error_still_raises(tmp_path, monkeypatch) -> None:
     real bugs and must propagate."""
     import torchsnapshot_tpu.io_preparer as iop
 
-    def broken_copy_fn(shardings):
+    def broken_copy_fn(shardings, cuts):
         def fn(xs):
             raise ValueError("not an allocation failure")
 

@@ -59,7 +59,7 @@ def test_cpu_tiny_dry_run_passes_end_to_end(tmp_path) -> None:
         "[programs] slab pack: 304 leaves restore bit-exact",
         "132 leaves host-captured because a device copy would rewrite their dtype",
         "[programs] async_take of 5 big leaves / 2 MB: 2 forked",
-        "[programs] fork + whole transfers: 5 leaves",
+        "[programs] fork (whole and in pieces) + transfers: 5 leaves",
         "[four] restore into transposed (tp, dp) mesh: bit-exact",
         "[four] restore into flat (4,) mesh: bit-exact",
         "[resume] bit-exact against saved step 3",

@@ -1,0 +1,556 @@
+"""The transfer grain (``d2h.PIECE_BYTES``): a forked leaf over the piece size
+leaves the fork as row-range pieces, cut bit for bit by a DMA inside the one
+fork program; the lanes move the pieces under their own window into one host
+buffer a leaf, and what is hashed, written and committed is what the
+whole-leaf path writes. Leaves the cut does not take go whole, as before.
+"""
+
+import asyncio
+import os
+
+import numpy as np
+import pytest
+
+from torchsnapshot_tpu import Snapshot, StateDict, d2h, io_preparer, prepare_cache
+from torchsnapshot_tpu.io_preparers.array import (
+    ArrayBufferStager,
+    ArrayIOPreparer,
+    PiecedArray,
+    piece_row_ranges,
+)
+from torchsnapshot_tpu.manifest import entry_to_dict
+from torchsnapshot_tpu.parallel.coordinator import get_coordinator
+from torchsnapshot_tpu.scheduler import _WritePipeline
+from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
+from torchsnapshot_tpu.utils import knobs
+from torchsnapshot_tpu.utils.lru import BoundedLRU
+
+PIECE = 64 * 1024
+WINDOW = 160 * 1024  # two pieces and a half
+WHOLE_WINDOW = 4 * 1024 * 1024
+
+
+@pytest.fixture(autouse=True)
+def _debug_ledger():
+    with knobs.override_debug_ledger(True):
+        yield
+
+
+@pytest.fixture(autouse=True)
+def _fresh_cache():
+    prepare_cache.reset(get_coordinator())
+    yield
+    prepare_cache.reset(get_coordinator())
+
+
+@pytest.fixture
+def grain(monkeypatch):
+    """A piece of 64 KiB under a window of 160 KiB, so leaves of a few
+    hundred KiB run the path the chip runs at tens of MiB."""
+    monkeypatch.setattr(d2h, "PIECE_BYTES", PIECE)
+    monkeypatch.setattr(d2h, "PIECE_WINDOW_BYTES", WINDOW)
+    monkeypatch.setattr(d2h, "HINT_WINDOW_BYTES", WHOLE_WINDOW)
+
+
+def _run(coro):
+    loop = asyncio.new_event_loop()
+    try:
+        return loop.run_until_complete(coro)
+    finally:
+        loop.close()
+
+
+def _every_bf16(tiles: int = 4):
+    import jax.numpy as jnp
+
+    bits = np.tile(np.arange(1 << 16, dtype=np.uint16), tiles)
+    return bits.reshape(-1, 256).view(jnp.bfloat16)
+
+
+def _patterned_state():
+    """Leaves over the piece size of every kind the cut takes, with the bit
+    patterns a device program could rewrite, and leaves that go whole."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(39)
+    f32 = rng.integers(0, 1 << 32, size=128 * 512, dtype=np.uint64).astype(np.uint32)
+    # Denormals, infinities and NaN payloads of both signs among the noise.
+    f32[:8] = [1, 0x007FFFFF, 0x7F800001, 0x7FC00001, 0xFFFFFFFF, 0x80000001, 0x7F800000, 0xFF800000]
+    host = {
+        "every_bf16": _every_bf16(),  # 512 KiB: eight pieces
+        "stack_bf16": _every_bf16(2).reshape(8, 64, 256),  # cut between slabs
+        "f32": f32.view(np.float32).reshape(128, 512),
+        "i8": np.tile(np.arange(256, dtype=np.uint8), 1024).view(np.int8).reshape(256, 1024),
+        "flags": (rng.integers(0, 2, size=(256, 1024)) > 0),  # bool: whole
+        "odd_cols": rng.standard_normal((512, 100)).astype(np.float32),  # whole
+        "vector": rng.standard_normal(1 << 16).astype(np.float32),  # 1-D: whole
+        "small": np.arange(7, dtype=np.int32),
+    }
+    return host, {k: jax.device_put(v) for k, v in host.items()}
+
+
+def _metrics():
+    return Snapshot.last_telemetry.metrics.as_dict()
+
+
+def _objects(path: str) -> dict:
+    """Every file of a snapshot by relative path, the take's own telemetry
+    and journal left out."""
+    out = {}
+    for root, _dirs, files in os.walk(path):
+        for name in files:
+            full = os.path.join(root, name)
+            rel = os.path.relpath(full, path)
+            if rel.startswith(".telemetry") or rel.startswith(".journal"):
+                continue
+            with open(full, "rb") as f:
+                out[rel] = f.read()
+    return out
+
+
+# ------------------------------------------------------------- the row ranges
+
+
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [
+        ((1000, 128), "float32"),  # 125 units of 8 rows: an odd count
+        ((1048, 256), "bfloat16"),  # 131 units
+        ((7, 64, 128), "float32"),  # an odd count of slabs
+        ((3, 8, 65536), "int8"),  # a single slab is over the piece size
+        ((24, 16384), "float32"),  # a single unit of 8 rows is over it
+    ],
+)
+def test_row_ranges_cover_a_leaf_once(grain, shape, dtype) -> None:
+    ranges = piece_row_ranges(shape, np.dtype(dtype))
+    assert ranges is not None and len(ranges) >= 2
+    assert ranges[0][0] == 0 and ranges[-1][1] == shape[0]
+    for (a0, a1), (b0, _b1) in zip(ranges, ranges[1:]):
+        assert a0 < a1 == b0
+    unit = 8 if len(shape) == 2 else 1
+    assert all(r0 % unit == 0 and r1 % unit == 0 for r0, r1 in ranges)
+    row_bytes = int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+    for r0, r1 in ranges:
+        assert (r1 - r0) * row_bytes <= PIECE or r1 - r0 == unit
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,why",
+    [
+        ((64, 256), "float32", "not over the piece size"),
+        ((1, 1 << 20), "float32", "one row"),
+        ((8, 1 << 16), "float32", "one unit of 8 rows"),
+        ((1 << 18,), "float32", "one dimension"),
+        ((1001, 128), "float32", "rows not a multiple of 8"),
+        ((1024, 100), "float32", "columns not a multiple of 128"),
+        ((16, 12, 1024), "float32", "a slab's rows not a multiple of 8"),
+        ((1024, 128), "bool", "the DMA takes no bool"),
+        ((1024, 128), "float16", "float16 never forks"),
+        ((1024, 128), "float64", "the DMA takes no 64-bit type"),
+        ((1024, 256), "float8_e4m3fn", "float8 never forks"),
+    ],
+)
+def test_leaves_the_cut_does_not_take_go_whole(grain, shape, dtype, why) -> None:
+    import jax.numpy as jnp
+
+    assert piece_row_ranges(shape, jnp.dtype(dtype)) is None, why
+
+
+# ------------------------------------------------------------------ the fork
+
+
+def test_fork_pieces_big_leaves_in_one_program_and_keeps_every_bit(grain, monkeypatch) -> None:
+    import jax
+
+    host, state = _patterned_state()
+    built = []
+    real = io_preparer._batch_copy_fn
+
+    def counting(shardings, cuts):
+        built.append(cuts)
+        return real(shardings, cuts)
+
+    monkeypatch.setattr(io_preparer, "_batch_copy_fn", counting)
+    names = list(state)
+    copies = dict(zip(names, io_preparer._defensive_device_copies([state[n] for n in names])))
+    assert len(built) == 1  # one program for the group, pieces and whole copies alike
+    pieced = {n for n, c in copies.items() if isinstance(c, PiecedArray)}
+    assert pieced == {"every_bf16", "stack_bf16", "f32", "i8"}
+    for n in names:
+        c = copies[n]
+        if n not in pieced:
+            assert isinstance(c, jax.Array)
+            assert np.asarray(c).tobytes() == host[n].tobytes(), n
+            continue
+        assert c.shape == host[n].shape and c.dtype == host[n].dtype
+        assert c.sharding == state[n].sharding and c.nbytes == host[n].nbytes
+        assert [p.shape[0] for p in c.pieces] == [r1 - r0 for r0, r1 in c.ranges]
+        assert all(p.nbytes <= PIECE for p in c.pieces)
+        got = np.concatenate([np.asarray(p) for p in c.pieces])
+        assert got.tobytes() == host[n].tobytes(), n
+        assert io_preparer.classify(c, 1) == "array"
+
+
+def test_sharded_replicated_and_offsize_leaves_fork_whole(grain) -> None:
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    big = jax.random.normal(jax.random.PRNGKey(0), (1024, 256), jnp.float32)
+    leaves = [
+        jax.device_put(big, NamedSharding(mesh, P("x"))),
+        jax.device_put(big, NamedSharding(mesh, P())),
+        big[:32],  # under the piece size
+    ]
+    copies = io_preparer._defensive_device_copies(leaves)
+    assert all(isinstance(c, jax.Array) for c in copies)
+    assert isinstance(io_preparer._defensive_device_copies([big])[0], PiecedArray)
+
+
+def test_a_leaf_chunked_into_storage_objects_forks_whole(grain) -> None:
+    """A leaf over the chunking knob is cut into storage objects by device
+    slices of its copy: the fork leaves it whole for them."""
+    import jax
+    import jax.numpy as jnp
+
+    big = jax.random.normal(jax.random.PRNGKey(0), (1024, 256), jnp.float32)
+    with knobs.override_max_chunk_size_bytes(256 * 1024):
+        assert isinstance(io_preparer._defensive_device_copies([big])[0], jax.Array)
+
+
+def test_a_refusal_by_the_kernel_compiler_forks_whole(grain, tmp_path, monkeypatch, caplog) -> None:
+    import jax
+
+    def refuse(x, ranges, interpret):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: no such tiling")
+
+    monkeypatch.setattr(io_preparer, "_cut_rows", refuse)
+    monkeypatch.setattr(io_preparer, "_BATCH_COPIES", BoundedLRU())  # no program built before
+    monkeypatch.setattr(io_preparer, "_cut_refused", False)
+    host, state = _patterned_state()
+    path = str(tmp_path / "ck")
+    with caplog.at_level("WARNING"):
+        Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
+    assert "forking whole leaves" in caplog.text
+    assert io_preparer._cut_refused
+    assert _metrics()["d2h.pieces"] == 0
+    copies = io_preparer._defensive_device_copies(list(state.values()))
+    assert all(isinstance(c, jax.Array) for c in copies)
+    _assert_restores(path, host, state)
+
+
+# ------------------------------------------------- through take and restore
+
+
+def _assert_restores(path, host, state) -> None:
+    import jax.numpy as jnp
+
+    target = StateDict(**{k: jnp.zeros_like(v) for k, v in state.items()})
+    Snapshot(path).restore({"m": target})
+    for k, want in host.items():
+        got = np.asarray(target[k])
+        assert got.dtype == want.dtype and got.tobytes() == want.tobytes(), k
+    assert Snapshot(path).verify() == {}
+
+
+def test_pieced_take_writes_what_the_whole_leaf_path_writes(grain, tmp_path, monkeypatch) -> None:
+    """Every object, checksum sidecar and manifest entry of a pieced take is
+    byte for byte what the take writes with no leaf pieced (the parent's
+    path), the code that wrote the second restores the first bit for bit,
+    and the counters say which ran."""
+    host, state = _patterned_state()
+    pieced_path, whole_path = str(tmp_path / "pieced"), str(tmp_path / "whole")
+    Snapshot.async_take(pieced_path, {"m": StateDict(**state)}).wait()
+    pieced = _metrics()
+    pieced_names = ("every_bf16", "stack_bf16", "f32", "i8")
+    assert pieced["d2h.pieced_bytes"] == sum(host[n].nbytes for n in pieced_names)
+    assert pieced["d2h.pieces"] == sum(
+        len(piece_row_ranges(host[n].shape, host[n].dtype)) for n in pieced_names
+    )
+    assert pieced["d2h.bytes"] == sum(v.nbytes for v in host.values())  # once a byte
+    assert pieced["capture.forked_leaves"] == len(host)
+    assert "capture.host_captured_bytes" not in pieced
+
+    monkeypatch.setattr(d2h, "PIECE_BYTES", 1 << 40)
+    prepare_cache.reset(get_coordinator())
+    Snapshot.async_take(whole_path, {"m": StateDict(**state)}).wait()
+    whole = _metrics()
+    assert whole["d2h.pieces"] == 0 and whole["d2h.pieced_bytes"] == 0
+    assert whole["d2h.bytes"] == pieced["d2h.bytes"]
+
+    a, b = _objects(pieced_path), _objects(whole_path)
+    assert sorted(a) == sorted(b)
+    assert ".snapshot_metadata" in a and any(r.startswith(".checksums") for r in a)
+    for rel in a:
+        assert a[rel] == b[rel], rel
+    manifest_a = Snapshot(pieced_path).get_manifest()
+    manifest_b = Snapshot(whole_path).get_manifest()
+    assert {k: entry_to_dict(v) for k, v in manifest_a.items()} == {
+        k: entry_to_dict(v) for k, v in manifest_b.items()
+    }
+    entry = manifest_a["0/m/every_bf16"]
+    assert entry.location == "0/m/every_bf16" and list(entry.shape) == [1024, 256]
+    # Still with no leaf pieced: the parent's reader on the change's snapshot.
+    _assert_restores(pieced_path, host, state)
+    _assert_restores(whole_path, host, state)
+
+
+def test_a_synchronous_take_pieces_nothing(grain, tmp_path) -> None:
+    host, state = _patterned_state()
+    path = str(tmp_path / "ck")
+    Snapshot.take(path, {"m": StateDict(**state)})
+    metrics = _metrics()
+    assert metrics["d2h.pieces"] == 0 and metrics["d2h.pieced_bytes"] == 0
+    assert metrics["d2h.bytes"] == sum(v.nbytes for v in host.values())
+    _assert_restores(path, host, state)
+
+
+def test_small_float_leaves_are_captured_through_the_host_not_pieced(grain, tmp_path) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    bits = np.tile(np.arange(1 << 16, dtype=np.uint16), 4).reshape(-1, 256)
+    host = {
+        "f16": bits.view(np.float16),
+        "f8": np.tile(np.arange(256, dtype=np.uint8), 1024).reshape(-1, 256).view(jnp.float8_e4m3fn),
+        "bf16": bits.view(jnp.bfloat16),
+    }
+    state = {k: jax.device_put(v) for k, v in host.items()}
+    path = str(tmp_path / "ck")
+    Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
+    metrics = _metrics()
+    assert metrics["capture.dtype_captured_leaves"] == 2
+    assert metrics["d2h.pieced_bytes"] == host["bf16"].nbytes
+    _assert_restores(path, host, state)
+
+
+def test_the_window_counts_pieces_and_whole_leaves_as_before(grain, tmp_path) -> None:
+    """Pieces are admitted under the pieces' window (more than one in flight
+    never exceeds it); a take of whole leaves only is admitted under the
+    whole-leaf window as it was."""
+    import jax
+    import jax.numpy as jnp
+
+    pieced = {
+        f"w{i}": jax.random.normal(jax.random.PRNGKey(i), (1024, 128), jnp.float32)
+        for i in range(3)
+    }  # 512 KiB each: eight pieces of 64 KiB
+    Snapshot.async_take(str(tmp_path / "p"), {"m": StateDict(**pieced)}).wait()
+    metrics = _metrics()
+    assert metrics["d2h.pieces"] == 24
+    assert metrics["d2h.hinted_ahead_hwm_bytes"] == 2 * PIECE  # 160 KiB holds two
+    assert metrics["d2h.window_waits"] == 22
+
+    whole = {
+        f"v{i}": jax.random.normal(jax.random.PRNGKey(i), (512, 100), jnp.float32)
+        for i in range(30)
+    }  # 200 KiB each, not cut (100 columns): twenty fit under 4 MiB
+    Snapshot.async_take(str(tmp_path / "w"), {"m": StateDict(**whole)}).wait()
+    metrics = _metrics()
+    assert metrics["d2h.pieces"] == 0
+    assert metrics["d2h.hinted_ahead_hwm_bytes"] == 20 * 512 * 100 * 4
+    assert metrics["d2h.window_waits"] == 10
+
+
+# ------------------------------------------------------------------- failures
+
+
+def _forked_pipeline(storage, leaves):
+    copies = io_preparer._defensive_device_copies(leaves)
+    assert all(isinstance(c, PiecedArray) for c in copies)
+    reqs = []
+    for i, leaf in enumerate(copies):
+        _entry, leaf_reqs = ArrayIOPreparer.prepare_write(f"obj{i}", leaf, is_async_snapshot=True)
+        reqs.extend(leaf_reqs)
+    return _WritePipeline(reqs, storage, memory_budget_bytes=10**9, rank=0), copies
+
+
+def _drive(pipeline):
+    async def go():
+        await pipeline.run_until_staged()
+        await asyncio.wait_for(pipeline.run_to_completion(), timeout=30)
+
+    _run(go())
+
+
+def _big_leaves(count: int):
+    import jax
+    import jax.numpy as jnp
+
+    arrs = [
+        jax.random.normal(jax.random.PRNGKey(i), (1024, 128), jnp.float32)
+        for i in range(count)
+    ]
+    jax.block_until_ready(arrs)
+    return arrs
+
+
+def _balanced(pipeline) -> None:
+    assert pipeline.budget_balanced, (pipeline.budget.available, pipeline.budget.total)
+    lanes = pipeline._staging_ctx.lanes
+    assert all(w.ahead == 0 and not w.waiting for w in lanes._windows.values())
+
+
+def test_a_failing_piece_mid_leaf_commits_nothing_and_balances(grain, tmp_path, monkeypatch) -> None:
+    """The fifth piece of the first leaf fails at its hint with pieces of
+    three leaves in line behind it: the error propagates, the other pieces
+    are cancelled, window and budget balance, nothing is committed."""
+    calls = []
+    real = d2h.hint_copy_to_host
+
+    def failing(arr):
+        calls.append(arr)
+        if len(calls) == 5:
+            raise RuntimeError("piece exploded")
+        real(arr)
+
+    monkeypatch.setattr(d2h, "hint_copy_to_host", failing)
+    storage = MemoryStoragePlugin()
+    pipeline, _ = _forked_pipeline(storage, _big_leaves(3))
+    with pytest.raises(RuntimeError, match="piece exploded"):
+        _drive(pipeline)
+    assert 5 <= len(calls) < 24
+    assert ".checksums.0" not in storage.objects
+    _balanced(pipeline)
+
+    calls.clear()
+    state = {f"w{i}": a for i, a in enumerate(_big_leaves(3))}
+    path = str(tmp_path / "ck")
+    pending = Snapshot.async_take(path, {"m": StateDict(**state)})
+    with pytest.raises(RuntimeError, match="piece exploded"):
+        pending.wait()
+    assert not os.path.exists(os.path.join(path, ".snapshot_metadata"))
+
+
+def test_an_abort_with_pieces_in_line_balances(grain) -> None:
+    class FailingWriteStorage(MemoryStoragePlugin):
+        async def write(self, write_io):
+            raise OSError("write exploded")
+
+    storage = FailingWriteStorage()
+    pipeline, _ = _forked_pipeline(storage, _big_leaves(4))
+    with pytest.raises(OSError, match="write exploded"):
+        _drive(pipeline)
+    assert not storage.objects
+    _balanced(pipeline)
+
+
+def test_a_landed_leaf_holds_no_device_buffer(grain) -> None:
+    """Each piece is dropped by the lane that landed it: once the leaf is
+    staged, none of its device buffers is left."""
+    storage = MemoryStoragePlugin()
+    pipeline, copies = _forked_pipeline(storage, _big_leaves(2))
+    _drive(pipeline)
+    assert all(p.is_deleted() for c in copies for p in c.pieces)
+    assert len([k for k in storage.objects if k.startswith("obj")]) == 2
+    _balanced(pipeline)
+
+
+def test_a_stager_outside_a_pipeline_gathers_its_pieces(grain) -> None:
+    leaf = _big_leaves(1)[0]
+    want = np.asarray(leaf).tobytes()
+    (copy,) = io_preparer._defensive_device_copies([leaf])
+    entry, _ = ArrayIOPreparer.prepare_write("obj", copy)
+    buf = _run(ArrayBufferStager(copy, entry).stage_buffer())
+    assert bytes(buf) == want
+
+
+# ------------------------------------------------- caches and HBM pressure
+
+
+def test_a_second_take_through_the_prepared_cache_stages_the_new_pieces(grain, tmp_path) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    def state(seed):
+        return {
+            "m": StateDict(
+                w=jax.random.normal(jax.random.PRNGKey(seed), (1024, 128), jnp.float32),
+                b=jnp.full((16,), seed, jnp.float32),
+            )
+        }
+
+    coord = get_coordinator()
+    first, second = state(1), state(2)
+    Snapshot.async_take(str(tmp_path / "a"), first).wait()
+    assert _metrics()["d2h.pieces"] == 8
+    Snapshot.async_take(str(tmp_path / "b"), second).wait()
+    assert sum(prepare_cache.stats(coord)["hits"].values()) == 1
+    assert _metrics()["d2h.pieces"] == 8
+    target = StateDict(w=jnp.zeros((1024, 128), jnp.float32), b=jnp.zeros((16,), jnp.float32))
+    Snapshot(str(tmp_path / "b")).restore({"m": target})
+    assert np.asarray(target["w"]).tobytes() == np.asarray(second["m"]["w"]).tobytes()
+    # Between takes the cached stagers pin nothing.
+    for entry in getattr(coord, "_prepared_take_cache").values():
+        assert not entry.in_use
+        for reqs in entry.leaf_index.values():
+            for req in reqs:
+                if isinstance(req.buffer_stager, ArrayBufferStager):
+                    assert req.buffer_stager.arr is None
+
+
+def test_the_simulated_hbm_limit_still_bisects_and_host_captures(grain, tmp_path) -> None:
+    """Room for two of four 512 KiB leaves: two fork (as pieces), two are
+    captured through the host, and the snapshot is the same."""
+    host = {f"w{i}": np.asarray(a) for i, a in enumerate(_big_leaves(4))}
+    import jax
+
+    state = {k: jax.device_put(v) for k, v in host.items()}
+    path = str(tmp_path / "ck")
+    with knobs.override_async_fork_hbm_limit_bytes(2 * 512 * 1024):
+        Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
+    metrics = _metrics()
+    assert metrics["capture.forked_leaves"] == 2
+    assert metrics["capture.host_captured_leaves"] == 2
+    assert metrics["d2h.pieces"] == 16 and metrics["d2h.pieced_bytes"] == 2 * 512 * 1024
+    _assert_restores(path, host, state)
+
+
+# ------------------------------------------- the real shapes, for the v5e
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize(
+    "shapes",
+    [
+        # pythia-6.9b: embedding, MLP, fused qkv, a square that is exactly the piece size
+        [((50432, 4096), "bfloat16"), ((4096, 16384), "bfloat16"), ((4096, 12288), "bfloat16"),
+         ((4096, 4096), "bfloat16"), ((4096,), "bfloat16")],
+        # expert stacks, a vocabulary share of 18,992 rows, a float32 router
+        [((10, 1536, 5120), "bfloat16"), ((32, 2048, 512), "bfloat16"), ((18992, 2048), "bfloat16"),
+         ((2560, 6144), "bfloat16"), ((16384, 1024), "float32"), ((2560, 512), "float32")],
+    ],
+)
+def test_the_fork_of_real_shapes_compiles_for_the_v5e_with_no_temporary(one_chip, shapes) -> None:
+    """The TPU's own compiler takes the cut at the sizes the benchmark's
+    states have (it refuses a DMA off the HBM tiling, which interpret mode
+    does not), and the program holds no byte beyond its outputs."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves = [jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip) for s, d in shapes]
+    cuts = tuple(
+        None if r is None else tuple(r) for r in (piece_row_ranges(a.shape, a.dtype) for a in leaves)
+    )
+    assert any(cuts) and not all(cuts)
+    compiled = (
+        io_preparer._batch_copy_fn(tuple(one_chip for _ in leaves), cuts).lower(leaves).compile()
+    )
+    stats = compiled.memory_analysis()
+    total = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves)
+    assert stats.temp_size_in_bytes == 0
+    assert total <= stats.output_size_in_bytes <= total + 4096 * len(leaves)
+    assert compiled.as_text().count("tpu_custom_call") == sum(1 for c in cuts if c)

@@ -17,6 +17,19 @@ Two cooperating pieces:
   under the window (``PERF.md`` section 6, PR 33's probe). The resolved host bytes are
   debited by the request's own admission, so no host copy exists that the
   memory budget has not seen.
+  **The grain.** A leaf that ``async_take`` forked and that is over
+  :data:`PIECE_BYTES` reaches the lanes as row-range pieces the fork itself
+  wrote (``io_preparer._cut_rows``: DMAs inside the one fork program, every
+  bit kept). A piece is admitted under :data:`PIECE_WINDOW_BYTES`, and the
+  lane that resolved it copies it into its rows of the leaf's one host
+  buffer and drops it, so a few tens of MiB are in flight where whole
+  leaves put hundreds: the job's steps beside the drain lose a third to a
+  half of what they lost (``PERF.md`` section 6, PR 39). A whole-leaf
+  transfer (a synchronous take, a sharded leaf, a leaf under the piece size
+  or of a shape or dtype the cut does not take) is admitted under
+  :data:`HINT_WINDOW_BYTES` as before: the lanes tell the two apart by
+  what they are handed. Beyond a leaf's buffer the host holds at most one
+  window of resolved pieces.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
   sub-phase intervals (``d2h`` / ``serialize`` / ``hash``). The scheduler
   derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s`` from these
@@ -61,6 +74,42 @@ logger = logging.getLogger(__name__)
 # 256 MiB or one leaf at a time would halve it. A value, not a knob: no
 # caller wants the burst back.
 HINT_WINDOW_BYTES = 512 * 1024 * 1024
+
+# The grain. A forked leaf over PIECE_BYTES leaves the fork as row-range
+# pieces of at most that size (``io_preparers.array.piece_row_ranges``), and
+# pieces are admitted under PIECE_WINDOW_BYTES a device: four of them. From
+# PR 39's runs on a v5e (``PERF.md`` section 6), 3.24 GB of params saved every
+# 44 donated steps of 0.206 s beside the storage writes: whole leaves under
+# 512 MiB cost the steps 0.64-0.92 s a take (``goodput_pct`` 89.2-91.1);
+# pieces of 16 MiB under 64 MiB 0.21-0.42 s (95.7-96.2) with the drain at
+# 4.6-4.9 s against 5.1-5.4; 16 under 128, 8 under 64 and 8 under 128 read
+# 94.5-95.2; 32 under 128 92.1; 32 under 64 94.5-98.3 with the drain at
+# 6.1-6.2 s, the transfers pacing it at 0.55 GB/s. What the steps lose
+# falls with the bytes in flight, and so does the transfers' rate: under
+# 64 MiB they keep the pace of the storage writes (0.77-0.79 GB/s), which
+# pace the drain either way. The window does not buy the 3-4 GB/s that small
+# transfers reach alone: each piece is copied into its leaf's buffer, and
+# the first write into fresh pages runs at 1.0 GB/s a thread on that machine
+# (recycled buffers moved 1.0-1.8 GB/s and cost the steps 0.33-2.3 s a take).
+# Values, not knobs.
+PIECE_BYTES = 16 * 1024 * 1024
+PIECE_WINDOW_BYTES = 64 * 1024 * 1024
+
+
+def resolve_on_host(arr: Any, into: Optional[np.ndarray] = None) -> np.ndarray:
+    """Wait for ``arr``'s host copy. ``into`` (a writable ``uint8`` view of
+    ``arr``'s size) makes ``arr`` a piece of a leaf: it is copied there and
+    dropped, its device buffer and jax's cached host value alike, and
+    ``into`` is returned."""
+    host = np.asarray(arr)
+    if into is None:
+        return host
+    # As bytes: one memcpy with the GIL released, whatever the dtype. Copied
+    # before the piece is dropped, so a backend whose host value aliases the
+    # device buffer is safe too.
+    into[:] = host.reshape(-1).view(np.uint8)
+    arr.delete()
+    return into
 
 
 def hint_copy_to_host(arr: Any) -> None:
@@ -186,13 +235,15 @@ class timed:
 
 class _DeviceWindow:
     """One device's hinted-and-unresolved bytes, and the transfers waiting
-    for room: ``(future, nbytes)`` in the order they asked."""
+    for room: ``(future, nbytes, limit)`` in the order they asked, ``limit``
+    the window the transfer is admitted under (a piece's or a whole
+    leaf's)."""
 
     __slots__ = ("ahead", "waiting")
 
     def __init__(self) -> None:
         self.ahead = 0
-        self.waiting: Deque[Tuple[Any, int]] = collections.deque()
+        self.waiting: Deque[Tuple[Any, int, int]] = collections.deque()
 
 
 class TransferLanes:
@@ -212,6 +263,10 @@ class TransferLanes:
         # and the transfers that waited for room (``d2h.window_waits``).
         self.hinted_ahead_hwm_bytes = 0
         self.window_waits = 0
+        # The transfers that were pieces of a leaf the fork cut, and their
+        # bytes (``d2h.pieces``, ``d2h.pieced_bytes``).
+        self.pieces = 0
+        self.pieced_bytes = 0
 
     def executor(self) -> ThreadPoolExecutor:
         if self._executor is None:
@@ -228,44 +283,60 @@ class TransferLanes:
         loop,
         times: Optional[StageTimes] = None,
         location: str = "",
+        into: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """``arr`` on the host: wait for room in its device's window, hint
         the transfer, resolve it on a lane.
 
+        ``into`` (a writable ``uint8`` view of ``nbytes``) marks ``arr`` as
+        one piece of a leaf that the fork cut: it is admitted under
+        :data:`PIECE_WINDOW_BYTES`, and the lane that resolved it copies it
+        into ``into`` (its rows of the leaf's host buffer) and drops it
+        (:func:`resolve_on_host`).
+
         The resolve is timed inside the lane thread, so the recorded ``d2h``
-        interval is transfer time only — neither the wait for room nor the
-        time the result waited to be awaited (that wait is exactly the
-        overlap the lanes exist to create)."""
+        interval is transfer time only (a piece's copy into its leaf's
+        buffer included: the bytes are not ready for hash and write before
+        it) — neither the wait for room nor the time the result waited to
+        be awaited (that wait is exactly the overlap the lanes exist to
+        create)."""
         devices = arr.devices()
         device = next(iter(devices)).id if len(devices) == 1 else None
 
         def resolve() -> np.ndarray:
             with timed(times, "d2h", path=location, nbytes=nbytes, device=device):
-                return np.asarray(arr)
+                return resolve_on_host(arr, into)
 
+        limit = HINT_WINDOW_BYTES
+        if into is not None:
+            limit = PIECE_WINDOW_BYTES
+            self.pieces += 1
+            self.pieced_bytes += nbytes
         window = self._windows.setdefault(device, _DeviceWindow())
-        await self._room(window, nbytes, loop)
+        await self._room(window, nbytes, limit, loop)
         try:
             hint_copy_to_host(arr)
             return await loop.run_in_executor(self.executor(), resolve)
         finally:
             self._resolved(window, nbytes)
 
-    def _fits(self, window: _DeviceWindow, nbytes: int) -> bool:
-        return window.ahead == 0 or window.ahead + nbytes <= HINT_WINDOW_BYTES
+    def _fits(self, window: _DeviceWindow, nbytes: int, limit: int) -> bool:
+        return window.ahead == 0 or window.ahead + nbytes <= limit
 
     def _admit(self, window: _DeviceWindow, nbytes: int) -> None:
         window.ahead += nbytes
         if window.ahead > self.hinted_ahead_hwm_bytes:
             self.hinted_ahead_hwm_bytes = window.ahead
 
-    async def _room(self, window: _DeviceWindow, nbytes: int, loop) -> None:
-        if not window.waiting and self._fits(window, nbytes):
+    async def _room(
+        self, window: _DeviceWindow, nbytes: int, limit: int, loop
+    ) -> None:
+        if not window.waiting and self._fits(window, nbytes, limit):
             self._admit(window, nbytes)
             return
         self.window_waits += 1
         turn = loop.create_future()
-        window.waiting.append((turn, nbytes))
+        window.waiting.append((turn, nbytes, limit))
         try:
             await turn
         except BaseException:
@@ -283,9 +354,9 @@ class TransferLanes:
     def _pump(self, window: _DeviceWindow) -> None:
         """Give room to the transfers at the head of the line, in order."""
         while window.waiting:
-            turn, nbytes = window.waiting[0]
+            turn, nbytes, limit = window.waiting[0]
             if not turn.cancelled():
-                if not self._fits(window, nbytes):
+                if not self._fits(window, nbytes, limit):
                     break
                 self._admit(window, nbytes)
                 turn.set_result(None)

@@ -34,7 +34,12 @@ from .manifest import (
     PrimitiveEntry,
     PRIMITIVE_TYPES,
 )
-from .io_preparers.array import ArrayIOPreparer, copy_preserves_bits
+from .io_preparers.array import (
+    ArrayIOPreparer,
+    PiecedArray,
+    copy_preserves_bits,
+    piece_row_ranges,
+)
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer, should_chunk
 from .io_preparers.object import ObjectIOPreparer
 from .io_preparers.sharded_array import ShardedArrayIOPreparer
@@ -112,8 +117,11 @@ class HostCapturedArray:
 
 
 def _is_plannable_array(value: Any) -> bool:
-    """jax.Array, or a host capture carrying the same planning metadata."""
-    return _is_jax_array(value) or isinstance(value, HostCapturedArray)
+    """jax.Array, or a capture carrying the same planning metadata: through
+    the host, or forked in pieces."""
+    return _is_jax_array(value) or isinstance(
+        value, (HostCapturedArray, PiecedArray)
+    )
 
 
 def classify(value: Any, world_size: int) -> str:
@@ -242,6 +250,8 @@ def _is_oom_error(e: BaseException) -> bool:
 # dtype the fork program would rewrite (a property of the model, not of a take).
 _fork_unsupported_warned = False
 _dtype_capture_warned = False
+# Set once the kernel compiler has refused the fork's row cut (``_try_fork``).
+_cut_refused = False
 
 
 def _is_fork_unsupported_error(group: List[Any], e: BaseException) -> bool:
@@ -268,7 +278,29 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
                 f"RESOURCE_EXHAUSTED: simulated HBM limit "
                 f"({forked_bytes[0]} + {need} > {limit} bytes)"
             )
-    copies = _batch_copy_fn(tuple(a.sharding for a in group))(group)
+    shardings = tuple(a.sharding for a in group)
+    cuts = tuple(_fork_cut(a) for a in group)
+    try:
+        copies = _batch_copy_fn(shardings, cuts)(group)
+    except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades
+        if not any(cuts) or "Mosaic" not in str(e):
+            raise
+        # The row cut is a Pallas kernel, and its compiler may refuse a
+        # shape the rule above lets through. The take must not fail for it:
+        # this process forks whole leaves from here on.
+        global _cut_refused
+        _cut_refused = True
+        logger.warning(
+            "async_take: the fork's row cut was refused by the kernel "
+            "compiler (%s); forking whole leaves from now on",
+            e,
+        )
+        cuts = (None,) * len(group)
+        copies = _batch_copy_fn(shardings, cuts)(group)
+    copies = [
+        c if cut is None else PiecedArray(a.shape, a.dtype, a.sharding, c, cut)
+        for a, c, cut in zip(group, copies, cuts)
+    ]
     telemetry.counter_add("capture.forked_leaves", len(group))
     if limit is not None:
         # Accounting feeds only the simulated limit; skip the per-shard
@@ -369,16 +401,90 @@ def _device_assignment_key(sharding) -> Any:
     return tuple(d.id for d in sharding._device_assignment)
 
 
-def _batch_copy_fn(shardings: Tuple[Any, ...]):
+_RowRanges = Tuple[Tuple[int, int], ...]
+
+
+def _fork_cut(arr: Any) -> Optional[_RowRanges]:
+    """The row ranges the fork writes ``arr``'s copy as, or None for one
+    whole copy: a leaf that lives whole in one device's own memory, stays
+    one storage object, and is over the piece size in a shape and dtype the
+    cut takes (``io_preparers.array.piece_row_ranges``)."""
+    sharding = arr.sharding
+    if len(sharding.device_set) != 1 or sharding.memory_kind not in (None, "device"):
+        return None
+    if _cut_refused or should_chunk(arr):
+        return None
+    ranges = piece_row_ranges(arr.shape, arr.dtype)
+    return None if ranges is None else tuple(ranges)
+
+
+def _cut_rows(x: Any, ranges: _RowRanges, interpret: bool) -> List[Any]:
+    """``x``'s rows as one array a range, written by HBM-to-HBM DMAs: every
+    byte is read once and written once, as ``jnp.copy`` would, and none is
+    computed on, so every bit pattern of every dtype comes through (an XLA
+    slice of bfloat16 flushes its denormals). Off the TPU the same kernel
+    runs in Pallas's interpreter."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k = len(ranges)
+
+    def kernel(x_ref, *refs):
+        outs, sems = refs[:k], refs[k]
+        copies = [
+            pltpu.make_async_copy(x_ref.at[pl.ds(r0, r1 - r0)], out, sems.at[i])
+            for i, ((r0, r1), out) in enumerate(zip(ranges, outs))
+        ]
+        for c in copies:
+            c.start()
+        for c in copies:
+            c.wait()
+
+    return list(
+        pl.pallas_call(
+            kernel,
+            out_shape=[
+                jax.ShapeDtypeStruct((r1 - r0,) + tuple(x.shape[1:]), x.dtype)
+                for r0, r1 in ranges
+            ],
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * k,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((k,))],
+            interpret=interpret,
+        )(x)
+    )
+
+
+def _on_tpu(sharding: Any) -> bool:
+    return all(d.platform == "tpu" for d in sharding.device_set)
+
+
+def _batch_copy_fn(
+    shardings: Tuple[Any, ...], cuts: Tuple[Optional[_RowRanges], ...]
+):
+    """The fork of one group: a whole ``jnp.copy`` a leaf, or its copy as
+    row-range pieces where ``cuts`` gives ranges (``_fork_cut``), all in one
+    jitted lambda: one program a take, every forked byte written once."""
+
     def build():
         import jax
         import jax.numpy as jnp
 
         return jax.jit(
-            lambda xs: [jnp.copy(x) for x in xs], out_shardings=list(shardings)
+            lambda xs: [
+                jnp.copy(x)
+                if cut is None
+                else _cut_rows(x, cut, interpret=not _on_tpu(s))
+                for x, s, cut in zip(xs, shardings, cuts)
+            ],
+            out_shardings=[
+                s if cut is None else [s] * len(cut)
+                for s, cut in zip(shardings, cuts)
+            ],
         )
 
-    return _BATCH_COPIES.get_or_build(shardings, build)
+    return _BATCH_COPIES.get_or_build((shardings, cuts), build)
 
 
 _BATCH_COPIES = BoundedLRU()

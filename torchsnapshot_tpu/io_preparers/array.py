@@ -10,7 +10,10 @@ XLA-native equivalent used here is:
    write pipeline the transfer lanes issue it, at the request's turn under
    their window a device (``d2h.TransferLanes``, ``d2h.HINT_WINDOW_BYTES``):
    never inside ``async_take``'s stall, and never the whole snapshot at
-   once, which would make the job's next step wait behind all of it;
+   once, which would make the job's next step wait behind all of it. A
+   leaf an async take forked in pieces (:class:`PiecedArray`) is one
+   transfer a piece, under the pieces' own smaller window, gathered into
+   one host buffer of the leaf's size;
 2. ``np.asarray(arr)`` on a lane's thread — resolves the (already
    in-flight) transfer off the event loop, so a few transfers and the storage
    writes interleave under the scheduler's memory budget.
@@ -129,25 +132,103 @@ def chunk_row_ranges(
     return ranges
 
 
-def to_host(arr: Any, executor: Optional[Executor] = None):
+def _dma_moves(dtype: Any) -> bool:
+    """Whether the fork's row cut (a Pallas HBM-to-HBM DMA, which moves bits
+    and computes nothing) takes ``dtype``: bfloat16, the 32-bit types and
+    the 8- and 16-bit integers. Mosaic refuses bool, float16 and 64-bit
+    types; float16 and float8 never fork at all."""
+    dt = np.dtype(dtype)
+    if dt.name == "bfloat16":
+        return True
+    return (dt.kind in "iuf" and dt.itemsize == 4) or (
+        dt.kind in "iu" and dt.itemsize in (1, 2)
+    )
+
+
+def piece_row_ranges(shape, dtype: Any) -> Optional[List[Tuple[int, int]]]:
+    """Row ranges [r0, r1) of the pieces a forked leaf crosses to the host
+    in, each at most ``d2h.PIECE_BYTES`` (when a single row fits), or None
+    where the leaf goes whole: not over the piece size, one row, a dtype the
+    DMA does not take, or a shape it cannot cut. The DMA moves whole HBM
+    tiles: the last dimension is a multiple of 128 and the one before it of
+    8, so a 2-D leaf is cut at multiples of 8 rows and a deeper one between
+    any two of its slabs. By shape and dtype alone, on every backend."""
+    shape = tuple(int(d) for d in shape)
+    if len(shape) < 2 or not _dma_moves(dtype):
+        return None
+    itemsize = np.dtype(dtype).itemsize
+    if itemsize * int(np.prod(shape)) <= d2h.PIECE_BYTES:
+        return None
+    unit = 8 if len(shape) == 2 else 1
+    if shape[0] % unit or shape[-1] % 128 or (len(shape) > 2 and shape[-2] % 8):
+        return None
+    ranges = chunk_row_ranges(
+        (shape[0] // unit, unit) + shape[1:], itemsize, d2h.PIECE_BYTES
+    )
+    if len(ranges) < 2:
+        return None
+    return [(r0 * unit, r1 * unit) for r0, r1 in ranges]
+
+
+class PiecedArray:
+    """A forked leaf that left the fork as row-range pieces: the metadata
+    the write planners read of a ``jax.Array`` that lives whole on one
+    device (``shape`` / ``dtype`` / ``sharding``), so it plans as that leaf
+    does (one ``ArrayEntry``, one storage object at the same location),
+    and the device arrays that hold its rows. Its stager moves the pieces
+    through the transfer lanes into one host buffer of the leaf's size."""
+
+    __slots__ = ("shape", "dtype", "sharding", "pieces", "ranges")
+
+    def __init__(
+        self,
+        shape: Tuple[int, ...],
+        dtype: Any,
+        sharding: Any,
+        pieces: Sequence[Any],
+        ranges: Sequence[Tuple[int, int]],
+    ) -> None:
+        self.shape = shape
+        self.dtype = dtype
+        self.sharding = sharding
+        self.pieces = pieces
+        self.ranges = ranges
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.shape)) * np.dtype(self.dtype).itemsize
+
+
+def to_host(
+    arr: Any, executor: Optional[Executor] = None, into: Optional[np.ndarray] = None
+):
     """Kick off an async D2H transfer; return an awaitable resolver. The
-    path of a stager driven outside a write pipeline (no lanes, no window)."""
+    path of a stager driven outside a write pipeline (no lanes, no window).
+    ``into``: as :func:`~..d2h.resolve_on_host` takes it."""
     if _is_jax_array(arr):
         hint_copy_to_host(arr)
 
     async def resolve() -> np.ndarray:
         loop = asyncio.get_running_loop()
         if executor is not None:
-            return await loop.run_in_executor(executor, np.asarray, arr)
-        return np.asarray(arr)
+            return await loop.run_in_executor(
+                executor, d2h.resolve_on_host, arr, into
+            )
+        return d2h.resolve_on_host(arr, into)
 
     return resolve
 
 
 async def _traced_to_host(
-    arr: Any, executor: Optional[Executor], location: str, nbytes: int
+    arr: Any,
+    executor: Optional[Executor],
+    location: str,
+    nbytes: int,
+    into: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Resolve one device→host transfer, attributed as ``stage.d2h``.
+    ``into``: ``arr`` is a piece of a leaf, to land in these bytes of the
+    leaf's host buffer (``d2h.TransferLanes.start``).
 
     Inside a write pipeline (an active :class:`~..d2h.StagingContext`) the
     transfer waits for room in its device's hint window, is hinted, and
@@ -161,14 +242,48 @@ async def _traced_to_host(
     if ctx is not None:
         loop = asyncio.get_running_loop()
         return await ctx.lanes.start(
-            arr, nbytes, loop, times=ctx.times, location=location
+            arr, nbytes, loop, times=ctx.times, location=location, into=into
         )
     tm = telemetry.get_active()
     if tm is None:
-        return await to_host(arr, executor)()
+        return await to_host(arr, executor, into)()
     with tm.span("stage.d2h", "stage", path=location, nbytes=nbytes):
-        host = await to_host(arr, executor)()
+        host = await to_host(arr, executor, into)()
     tm.metrics.counter("d2h.bytes").add(nbytes)
+    return host
+
+
+async def _gather_pieces(
+    arr: PiecedArray, executor: Optional[Executor], location: str
+) -> np.ndarray:
+    """The pieced leaf whole in one host buffer: every piece is started
+    through the lanes at once (each waits its turn under the pieces' window)
+    and lands in its rows. Beyond the buffer, which the request's admission
+    debited, the host holds at most one window of resolved pieces. A failing
+    piece cancels the others and waits them out, so the window is balanced
+    when the error leaves here."""
+    host = np.empty(arr.shape, dtype=arr.dtype)
+    flat = host.reshape(-1).view(np.uint8)
+    row_bytes = host.nbytes // host.shape[0]
+    tasks = [
+        asyncio.ensure_future(
+            _traced_to_host(
+                piece,
+                executor,
+                location,
+                (r1 - r0) * row_bytes,
+                into=flat[r0 * row_bytes : r1 * row_bytes],
+            )
+        )
+        for piece, (r0, r1) in zip(arr.pieces, arr.ranges)
+    ]
+    try:
+        await asyncio.gather(*tasks)
+    except BaseException:
+        for t in tasks:
+            t.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        raise
     return host
 
 
@@ -238,7 +353,9 @@ class ArrayBufferStager(BufferStager):
         # async defensive copy below.
         serializer = Serializer.RAW if self.stage_raw else self.entry.serializer
         arr = self.arr
-        if _is_jax_array(arr):
+        if isinstance(arr, PiecedArray):
+            host = await _gather_pieces(arr, executor, self.entry.location)
+        elif _is_jax_array(arr):
             host = await _traced_to_host(
                 arr, executor, self.entry.location, _nbytes_of(arr)
             )
@@ -319,6 +436,9 @@ class ArrayBufferStager(BufferStager):
         return payload
 
     def get_staging_cost_bytes(self) -> int:
+        # A pieced leaf costs its one host buffer, like a whole one; the
+        # resolved pieces not yet copied into it are at most one window
+        # (``d2h.PIECE_WINDOW_BYTES``) a device beyond every debit.
         if not is_raw_family(self.entry.serializer):
             return _nbytes_of(self.arr)
         nbytes = array_nbytes(self.entry.shape, self.entry.dtype)

@@ -755,6 +755,8 @@ class _WritePipeline:
             "d2h.hinted_ahead_hwm_bytes", lanes.hinted_ahead_hwm_bytes
         )
         telemetry.counter_add("d2h.window_waits", lanes.window_waits)
+        telemetry.counter_add("d2h.pieces", lanes.pieces)
+        telemetry.counter_add("d2h.pieced_bytes", lanes.pieced_bytes)
         telemetry.counter_add("scheduler.bytes_staged", self.bytes_staged)
         if self.bytes_deduped:
             telemetry.counter_add("scheduler.bytes_deduped", self.bytes_deduped)
