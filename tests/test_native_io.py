@@ -341,9 +341,9 @@ def test_chunked_read_is_the_files_bytes(
         lib, path, out, offset=offset, direct=True, chunk_bytes=chunk, stamped=True
     )
     assert np.array_equal(out, data[offset : offset + n])
-    # One interval a chunk, on time.monotonic()'s clock.
+    # One chunk a row, on time.monotonic()'s clock: the pread inside it.
     assert len(chunk_reads) == -(-n // chunk)
-    assert all(0.0 < t0 <= t1 for t0, t1 in chunk_reads)
+    assert all(0.0 < t0 <= p0 <= p1 <= t1 + 1e-9 for t0, t1, p0, p1 in chunk_reads)
     stats = native.read_pool_stats(lib)
     assert stats["depth"] == depth and stats["in_flight"] == 0
     assert stats["high_water"] <= depth and stats["buffers"] <= depth
@@ -525,3 +525,243 @@ def test_a_forked_child_reads_through_a_pool_of_its_own(lib, tmp_path) -> None:
         text=True,
     )
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# ------------------------------------------------------- the engine's stamps
+#
+# Asked to, the engine says what each thread did with each chunk, on
+# ``time.monotonic()``'s clock: a write's copy into the bounce buffer, its
+# ``pwrite`` and its crc; a read's whole chunk and the ``pread`` inside it.
+# CPU runs: orderings, identities and byte counts, never a rate.
+
+_MIB = 1 << 20
+_WRITE_CASES = {
+    # name: (bytes, chunk, O_DIRECT asked for)
+    "direct_multi_chunk": (3 * _MIB, _MIB, True),
+    "unaligned_tail": (2 * _MIB + 17, _MIB, True),
+    "under_one_chunk": (300_000, _MIB, True),
+    "buffered_fallback": (2 * _MIB + 17, _MIB, False),
+}
+
+
+class _SpyLib:
+    """The engine's library with every call's arguments kept."""
+
+    def __init__(self, lib) -> None:
+        self._lib = lib
+        self.calls = []
+
+    def __getattr__(self, name):
+        fn = getattr(self._lib, name)
+
+        def call(*args):
+            self.calls.append((name, args))
+            return fn(*args)
+
+        return call
+
+
+@pytest.mark.parametrize("digest", [True, False], ids=["digest", "plain"])
+@pytest.mark.parametrize("case", sorted(_WRITE_CASES))
+def test_write_stamps_say_what_the_thread_did_with_each_chunk(lib, tmp_path, case, digest) -> None:
+    import time
+    import zlib
+
+    nbytes, chunk, direct = _WRITE_CASES[case]
+    data = np.random.default_rng(nbytes).integers(0, 256, size=nbytes, dtype=np.uint8)
+    write = native.write_file_digest if digest else native.write_file
+    stamped, plain = str(tmp_path / "stamped"), str(tmp_path / "plain")
+    chunks = []
+    before = time.monotonic()
+    got = write(lib, stamped, data, direct=direct, chunk_bytes=chunk, stamps=chunks)
+    after = time.monotonic()
+    # The same bytes on disk and the same digest, stamped or not.
+    assert write(lib, plain, data, direct=direct, chunk_bytes=chunk) == got
+    assert got == ([zlib.crc32(data), nbytes, None] if digest else None)
+    for path in (stamped, plain):
+        with open(path, "rb") as f:
+            assert f.read() == data.tobytes()
+    # One thread, in series: every chunk is copy, then pwrite, then crc, and
+    # the next chunk starts after it; all of it inside the call.
+    assert chunks and sum(c[4] for c in chunks) == nbytes
+    edges = [t for c in chunks for t in c[:4]]
+    assert edges == sorted(edges) and before <= edges[0] and edges[-1] <= after
+    copy_s = sum(c[1] - c[0] for c in chunks)
+    mount_s = sum(c[2] - c[1] for c in chunks)
+    crc_s = sum(c[3] - c[2] for c in chunks)
+    assert mount_s > 0.0 and copy_s + mount_s + crc_s <= after - before
+    if not digest:
+        assert crc_s == 0.0  # nothing is hashed where no digest is asked for
+    if not direct:
+        assert copy_s == 0.0  # a buffered write has no bounce buffer
+    assert len(chunks) <= -(-nbytes // chunk) + 1
+
+
+@pytest.mark.parametrize("digest", [True, False], ids=["digest", "plain"])
+def test_an_unstamped_write_hands_the_engine_no_stamps_out(lib, tmp_path, digest) -> None:
+    spy = _SpyLib(lib)
+    write = native.write_file_digest if digest else native.write_file
+    write(spy, str(tmp_path / "obj"), os.urandom(70_000), direct=True, chunk_bytes=1 << 20)
+    ((name, args),) = [c for c in spy.calls if c[0].startswith("tss_write_file")]
+    assert args[-2:] == (None, None)
+    assert not [c for c in spy.calls if c[0] == "tss_free" and c[1][0]]
+
+
+@pytest.mark.parametrize(
+    "case,direct,nbytes",
+    [("direct", True, 4 * 16384), ("buffered_tail", False, 3 * 16384 + 5), ("one_short_chunk", True, 100)],
+)
+def test_read_stamps_hold_the_pread_inside_its_chunk(lib, tmp_path, restore_depth, case, direct, nbytes) -> None:
+    import time
+
+    chunk = 16384
+    path, data = _file_of(tmp_path, nbytes)
+    native.set_read_depth(lib, 1)  # one reader thread: its chunks are in series
+    out = np.zeros(nbytes, np.uint8)
+    before = time.monotonic()
+    rows = native.read_into(lib, path, out, direct=direct, chunk_bytes=chunk, stamped=True)
+    after = time.monotonic()
+    assert np.array_equal(out, data)
+    assert len(rows) == -(-nbytes // chunk)
+    for t0, t1, p0, p1 in rows:
+        assert before <= t0 <= p0 < p1 <= t1 + 1e-9 and t1 <= after
+    ends = [t for t0, t1, _, _ in sorted(rows) for t in (t0, t1)]
+    assert ends == sorted(ends)
+    # Unstamped: the same bytes and no array.
+    again = np.zeros(nbytes, np.uint8)
+    assert native.read_into(lib, path, again, direct=direct, chunk_bytes=chunk) == []
+    assert np.array_equal(again, data)
+
+
+def test_a_failed_chunk_of_a_stamped_read_gives_no_stamps(lib, tmp_path) -> None:
+    chunk = 16384
+    path, data = _file_of(tmp_path, 4 * chunk)
+    out = np.zeros(data.size, np.uint8)
+    with pytest.raises(OSError) as e:
+        native.read_into(lib, path, out, chunk_bytes=chunk, stamped=True, fail_chunk=2)
+    assert e.value.errno == errno.ESTALE
+    assert np.array_equal(out[:chunk], data[:chunk]) and not out[2 * chunk : 3 * chunk].any()
+    assert native.read_pool_stats(lib)["in_flight"] == 0
+
+
+_TAKE_KEYS = (
+    "write_work_sum_s", "write_queue_sum_s",
+    "mount_write_s", "mount_write_sum_s", "mount_write_bytes",
+    "write_copy_sum_s", "write_crc_sum_s",
+    "stage_gather_s", "stage_gather_sum_s", "stage_d2h_sum_s",
+)
+_RESTORE_KEYS = ("pread_busy_s", "pread_sum_s", "reader_copy_sum_s")
+
+
+@pytest.fixture(scope="module")
+def taken(tmp_path_factory):
+    """One asynchronous take of a pieced leaf, a whole leaf and a small one
+    through the fs plugin, its artifact, and a restore of it."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from torchsnapshot_tpu import Snapshot, StateDict
+    from torchsnapshot_tpu import snapshot as snapshot_mod
+
+    if native.load_native() is None:
+        pytest.skip("native IO engine unavailable")
+    tree = dict(
+        pieced=jax.random.normal(jax.random.PRNGKey(0), (4096, 4096), jnp.bfloat16),
+        whole=jax.random.normal(jax.random.PRNGKey(1), (1024, 1024), jnp.float32),
+        small=jnp.arange(8, dtype=jnp.float32),
+    )
+    path = str(tmp_path_factory.mktemp("stamps") / "snap")
+    Snapshot.async_take(path, {"m": StateDict(**tree)}).wait()
+    with open(os.path.join(path, ".telemetry", "rank_0.json")) as f:
+        artifact = json.load(f)
+    targets = StateDict(**{k: jnp.zeros_like(v) for k, v in tree.items()})
+    Snapshot(path).restore({"m": targets})
+    for k, v in tree.items():
+        assert np.array_equal(np.asarray(targets[k]).view(np.uint8), np.asarray(v).view(np.uint8)), k
+    return path, tree, artifact, dict(snapshot_mod.LAST_RESTORE_STATS)
+
+
+@pytest.mark.parametrize("view", ["drain_stats_s", "pipeline_stats_s"])
+def test_a_take_says_what_its_writers_and_lanes_seconds_are_made_of(taken, view) -> None:
+    _, tree, artifact, _ = taken
+    stats = artifact[view]
+    assert set(_TAKE_KEYS) <= set(stats)
+    eps = 1e-5  # the artifact rounds to microseconds
+    native_bytes = artifact["metrics"]["storage.fs.native_write_bytes"]
+    assert stats["mount_write_bytes"] == native_bytes == tree["pieced"].nbytes + tree["whole"].nbytes
+    inside = stats["mount_write_sum_s"] + stats["write_copy_sum_s"] + stats["write_crc_sum_s"]
+    assert 0.0 < inside <= stats["write_work_sum_s"] + eps
+    assert stats["write_queue_sum_s"] >= 0.0
+    assert 0.0 < stats["mount_write_s"] <= stats["io_busy_s"] + eps
+    assert stats["mount_write_s"] <= stats["mount_write_sum_s"] + eps
+    # The 32 MiB leaf hashes on the pool beside its write, the 4 MiB one in
+    # the writer's loop.
+    assert stats["write_crc_sum_s"] > 0.0
+    assert 0.0 < stats["stage_gather_sum_s"] <= stats["stage_d2h_sum_s"] + eps
+    assert stats["stage_gather_s"] <= stats["stage_d2h_s"] + eps <= stats["stage_d2h_sum_s"] + 2 * eps
+
+
+def test_the_artifact_carries_the_engines_intervals_and_the_spans_that_anchor_them(taken) -> None:
+    _, _, artifact, _ = taken
+    intervals = artifact["intervals"]
+    assert {"mount_write", "write_copy", "write_work", "stage_gather"} <= set(intervals)
+    assert len(intervals["write_work"]) == 2  # one an object through the engine
+    # Each pwrite and each copy lies inside a storage.write_work interval.
+    for kind in ("mount_write", "write_copy"):
+        for t0, t1 in intervals[kind]:
+            assert any(w0 - 1e-5 <= t0 and t1 <= w1 + 1e-5 for w0, w1 in intervals["write_work"]), kind
+    assert artifact["metrics"]["d2h.pieces"] == 2  # 32 MiB in pieces of 16
+
+
+def test_a_restore_tells_a_readers_pread_from_its_copy(taken) -> None:
+    _, _, _, stats = taken
+    assert set(_RESTORE_KEYS) <= set(stats)
+    assert 0.0 < stats["pread_busy_s"] <= stats["mount_busy_s"] + 1e-9
+    assert stats["pread_busy_s"] <= stats["pread_sum_s"] + 1e-9
+    assert stats["pread_sum_s"] + stats["reader_copy_sum_s"] == pytest.approx(stats["mount_sum_s"], abs=1e-6)
+    assert stats["reader_copy_sum_s"] > 0.0
+
+
+@pytest.mark.parametrize("op", ["read_object", "plugin_write"])
+def test_outside_a_take_or_a_restore_the_engine_is_asked_for_no_stamps(taken, tmp_path, monkeypatch, op) -> None:
+    from torchsnapshot_tpu import Snapshot
+
+    path, tree, _, _ = taken
+    asked = []
+    real_read, real_write = native.read_into, native.write_file
+
+    def read_into(*args, **kwargs):
+        asked.append(kwargs.get("stamped", False))
+        return real_read(*args, **kwargs)
+
+    def write_file(*args, **kwargs):
+        asked.append(kwargs.get("stamps") is not None)
+        return real_write(*args, **kwargs)
+
+    monkeypatch.setattr(native, "read_into", read_into)
+    monkeypatch.setattr(native, "write_file", write_file)
+    if op == "read_object":
+        got = Snapshot(path).read_object("0/m/whole")
+        assert np.array_equal(np.asarray(got), np.asarray(tree["whole"]))
+    else:
+        plugin = FSStoragePlugin(str(tmp_path))
+        plugin.sync_write(WriteIO(path="obj", buf=os.urandom(5 << 20)))
+        plugin.sync_close()
+    assert asked and not any(asked)
+
+
+def test_a_plugin_write_handed_a_sink_tells_it_what_the_engine_did(lib, tmp_path) -> None:
+    from torchsnapshot_tpu.io_types import WriteTimes
+
+    times, nbytes = WriteTimes(), (5 << 20) + 3
+    plugin = FSStoragePlugin(str(tmp_path))
+    plugin.sync_write(WriteIO(path="obj", buf=os.urandom(nbytes), times=times))
+    plugin.sync_close()
+    got = times.intervals()
+    ((handed, held, n),), ((held_too, done, _),) = got["write_queue"], got["write_work"]
+    assert handed <= held == held_too < done and n == nbytes
+    assert sum(taken for _, _, taken in got["mount_write"]) == nbytes
+    for kind in ("write_copy", "mount_write", "write_crc"):
+        assert got[kind] and all(held <= t0 <= t1 <= done for t0, t1, _ in got[kind]), kind

@@ -28,6 +28,7 @@ NEW_KEYS = (
     "consume_busy_s", "consume_sum_s", "consume_wait_s", "place_busy_s",
     "place_bytes", "place_wait_s", "place_retry_s", "targets_consumed",
     "load_s", "idle_s", "pipeline_s", "mount_busy_s", "mount_sum_s", "mount_bytes",
+    "pread_busy_s", "pread_sum_s", "reader_copy_sum_s",
 )
 
 
@@ -152,7 +153,8 @@ def test_mount_keys_count_the_engines_chunk_reads(saved, monkeypatch, overlap) -
     stats = snapshot_mod.LAST_RESTORE_STATS
     assert stats["mount_bytes"] == sum(native_bytes) > tree["big"].nbytes
     assert 0.0 < stats["mount_busy_s"] <= stats["mount_sum_s"] + 1e-9
-    assert stats["mount_sum_s"] == pytest.approx(sum(t1 - t0 for t0, t1 in chunk_reads))
+    assert stats["mount_sum_s"] == pytest.approx(sum(t1 - t0 for t0, t1, _, _ in chunk_reads))
+    assert stats["pread_sum_s"] == pytest.approx(sum(p1 - p0 for _, _, p0, p1 in chunk_reads))
     # A chunk read is on the mount only while its fetch is open.
     assert stats["mount_busy_s"] <= stats["fetch_busy_s"] + 1e-9
     assert len(chunk_reads) > len(native_bytes)  # "big" is sixteen chunks

@@ -292,6 +292,18 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
         # ... and of two of them the seconds on objects under 1 MiB.
         "stage_d2h_small_s",
         "io_busy_small_s",
+        # ... what the lanes' seconds in stage.d2h are made of,
+        "stage_d2h_sum_s",
+        "stage_gather_s",
+        "stage_gather_sum_s",
+        # ... and, inside io, what the native writes' are.
+        "write_work_sum_s",
+        "write_queue_sum_s",
+        "mount_write_s",
+        "mount_write_sum_s",
+        "mount_write_bytes",
+        "write_copy_sum_s",
+        "write_crc_sum_s",
     } == set(snapshot_mod.LAST_SYNC_DRAIN_STATS)
 
     # Scheduler stage/io spans.

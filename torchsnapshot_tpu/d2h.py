@@ -31,14 +31,15 @@ Two cooperating pieces:
   what they are handed. Beyond a leaf's buffer the host holds at most one
   window of resolved pieces.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
-  sub-phase intervals (``d2h`` / ``serialize`` / ``hash``). The scheduler
-  derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s`` from these
-  by the same interval-union algebra as the stage/io streams, so the
-  monolithic ``stage_busy`` decomposes in drain stats, persisted telemetry
-  artifacts, and bench output. The ``d2h`` interval is a lane's resolve, not
-  the wait for room in the window. With a telemetry session active the same
-  intervals are exported as ``stage.d2h``/``stage.serialize``/``stage.hash``
-  spans.
+  sub-phase intervals (``d2h`` / ``serialize`` / ``hash`` / ``gather``). The
+  scheduler derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s``/
+  ``stage_gather_s`` from these by the same interval-union algebra as the
+  stage/io streams, so the monolithic ``stage_busy`` decomposes in drain
+  stats, persisted telemetry artifacts, and bench output. The ``d2h``
+  interval is a lane's resolve, not the wait for room in the window; for a
+  piece it holds the ``gather`` interval, the copy into the leaf's buffer.
+  With a telemetry session active the same intervals are exported as
+  ``stage.d2h``/``stage.serialize``/``stage.hash``/``stage.gather`` spans.
 
 The write pipeline activates a :class:`StagingContext` (lanes + times) via a
 ``contextvars.ContextVar`` around staging-task creation — the same pattern
@@ -96,18 +97,26 @@ PIECE_BYTES = 16 * 1024 * 1024
 PIECE_WINDOW_BYTES = 64 * 1024 * 1024
 
 
-def resolve_on_host(arr: Any, into: Optional[np.ndarray] = None) -> np.ndarray:
+def resolve_on_host(
+    arr: Any,
+    into: Optional[np.ndarray] = None,
+    times: Optional["StageTimes"] = None,
+    path: str = "",
+) -> np.ndarray:
     """Wait for ``arr``'s host copy. ``into`` (a writable ``uint8`` view of
     ``arr``'s size) makes ``arr`` a piece of a leaf: it is copied there and
     dropped, its device buffer and jax's cached host value alike, and
-    ``into`` is returned."""
+    ``into`` is returned. The copy is its own ``gather`` interval of
+    ``times``: the first touch of the leaf's fresh pages, apart from the
+    runtime's resolve before it."""
     host = np.asarray(arr)
     if into is None:
         return host
     # As bytes: one memcpy with the GIL released, whatever the dtype. Copied
     # before the piece is dropped, so a backend whose host value aliases the
     # device buffer is safe too.
-    into[:] = host.reshape(-1).view(np.uint8)
+    with timed(times, "gather", path=path, nbytes=into.nbytes):
+        into[:] = host.reshape(-1).view(np.uint8)
     arr.delete()
     return into
 
@@ -130,7 +139,7 @@ class StageTimes:
     telemetry session is captured at construction because executor threads
     don't inherit the activation contextvar."""
 
-    KINDS = ("d2h", "serialize", "hash")
+    KINDS = ("d2h", "serialize", "hash", "gather")
 
     def __init__(self, tm: Optional[Any] = None) -> None:
         # ``tm``: the op's telemetry.Telemetry session (or None when off).
@@ -181,8 +190,9 @@ class StageTimes:
 
 class timed:
     """``with d2h.timed(times, kind, ...)`` around one stretch of staging
-    work that is synchronous on the calling thread (a lane's resolve, a
-    compress, a whole-leaf hash): the interval is recorded as
+    work that is synchronous on the calling thread (a lane's resolve, the
+    copy of a resolved piece into its leaf, a compress, a whole-leaf hash):
+    the interval is recorded as
     :meth:`StageTimes.record` would and, under a telemetry session, the
     stretch is also a ``tss.stage.<kind>`` event of a running profiler
     trace. ``sized(n)`` inside the body gives the bytes where only the
@@ -296,16 +306,16 @@ class TransferLanes:
 
         The resolve is timed inside the lane thread, so the recorded ``d2h``
         interval is transfer time only (a piece's copy into its leaf's
-        buffer included: the bytes are not ready for hash and write before
-        it) — neither the wait for room nor the time the result waited to
-        be awaited (that wait is exactly the overlap the lanes exist to
-        create)."""
+        buffer included, as the ``gather`` interval inside it: the bytes
+        are not ready for hash and write before it) — neither the wait for
+        room nor the time the result waited to be awaited (that wait is
+        exactly the overlap the lanes exist to create)."""
         devices = arr.devices()
         device = next(iter(devices)).id if len(devices) == 1 else None
 
         def resolve() -> np.ndarray:
             with timed(times, "d2h", path=location, nbytes=nbytes, device=device):
-                return resolve_on_host(arr, into)
+                return resolve_on_host(arr, into, times, location)
 
         limit = HINT_WINDOW_BYTES
         if into is not None:

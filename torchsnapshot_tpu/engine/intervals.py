@@ -48,6 +48,24 @@ def measure(merged: List[Interval]) -> float:
     return sum(t1 - t0 for t0, t1 in merged)
 
 
+def busy_in(merged: List[Interval], windows: Sequence[Interval]) -> float:
+    """The measure of a merged interval list inside the accounting windows."""
+    return sum(measure(clip_merged(merged, w0, w1)) for w0, w1 in windows)
+
+
+def sum_in(
+    records: Sequence[Tuple[float, ...]], windows: Sequence[Interval]
+) -> float:
+    """The plain sum of the records' durations inside the accounting
+    windows, overlaps counted as often as they occur (``sum_in / busy_in``
+    is the depth the records ran at). Only a record's two ends are read."""
+    return sum(
+        max(0.0, min(r[1], w1) - max(r[0], w0))
+        for r in records
+        for w0, w1 in windows
+    )
+
+
 def intersect_merged(
     a: List[Interval], b: List[Interval]
 ) -> List[Interval]:

@@ -14,9 +14,10 @@ from __future__ import annotations
 import abc
 import asyncio
 import io
+import threading
 from concurrent.futures import Executor
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
 
 # A staged buffer is either raw bytes or a zero-copy view over host memory.
 BufferType = Union[bytes, bytearray, memoryview]
@@ -99,6 +100,52 @@ class ReadReq:
     byte_range: Optional[Tuple[int, int]] = None  # [begin, end)
 
 
+class WriteTimes:
+    """What a take's native writes did, told by the plugin that made them
+    (``WriteIO.times``): a thread-safe sink beside the io stream, as
+    ``RestoreTimes`` is for a restore's reads. Every interval is on
+    ``time.monotonic()``'s clock, as ``(t0, t1, nbytes)``."""
+
+    KINDS = ("write_queue", "write_work", "write_copy", "mount_write", "write_crc")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._intervals: Dict[str, List[Tuple[float, float, int]]] = {
+            k: [] for k in self.KINDS
+        }
+
+    def record_native_write(
+        self,
+        handed: float,
+        held: float,
+        done: float,
+        nbytes: int,
+        chunks: List[Tuple[float, float, float, float, float]],
+    ) -> None:
+        """One write of the fs plugin through the native engine, stamped on
+        the writing thread: ``handed`` to the plugin's executor, ``held``
+        when its thread held a writer slot and had opened its
+        ``storage.write_work`` span (``write_queue`` before it,
+        ``write_work`` after it), ``done`` when the engine call had
+        returned, the GIL taken again; ``chunks`` the engine's own stamps
+        (``native.WriteChunk``): each chunk's copy into the bounce buffer,
+        its ``pwrite`` (``mount_write``, with the bytes it took) and its crc.
+        No span of their own: ``storage.write_work`` stays one an object."""
+        with self._lock:
+            w = self._intervals
+            w["write_queue"].append((handed, held, nbytes))
+            w["write_work"].append((held, done, nbytes))
+            for t_copy, t_mount, t_crc, t_end, taken in chunks:
+                w["write_copy"].append((t_copy, t_mount, 0))
+                w["mount_write"].append((t_mount, t_crc, int(taken)))
+                w["write_crc"].append((t_crc, t_end, 0))
+
+    def intervals(self) -> Dict[str, List[Tuple[float, float, int]]]:
+        """A snapshot copy per kind (safe to merge and clip while writes run)."""
+        with self._lock:
+            return {k: list(v) for k, v in self._intervals.items()}
+
+
 @dataclass
 class WriteIO:
     path: str
@@ -113,6 +160,10 @@ class WriteIO:
     # no plugin wastes a pass. digest_out None = not computed.
     want_digest: bool = False
     digest_out: Optional[list] = None
+    # times: the take's sink for what a native write did with its chunks. A
+    # plugin whose engine can stamp them does so only when handed one; any
+    # other write (a sidecar, the catalog, bare plugin use) stamps nothing.
+    times: Optional[WriteTimes] = None
 
 
 class ReadBuffer:

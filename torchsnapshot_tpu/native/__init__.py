@@ -78,12 +78,17 @@ def _build(out_path: str) -> None:
 
 def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tss_io_version.restype = ctypes.c_int
+    stamps_out = [
+        ctypes.POINTER(ctypes.POINTER(ctypes.c_double)),
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
     lib.tss_write_file.argtypes = [
         ctypes.c_char_p,
         ctypes.c_void_p,
         ctypes.c_uint64,
         ctypes.c_int,
         ctypes.c_uint64,
+        *stamps_out,
     ]
     lib.tss_write_file.restype = ctypes.c_int
     lib.tss_read_file.argtypes = [
@@ -93,8 +98,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_uint64,
         ctypes.c_int,
         ctypes.c_uint64,
-        ctypes.POINTER(ctypes.POINTER(ctypes.c_double)),
-        ctypes.POINTER(ctypes.c_uint64),
+        *stamps_out,
         ctypes.c_int64,
     ]
     lib.tss_read_file.restype = ctypes.c_int
@@ -113,6 +117,7 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_int,
         ctypes.c_uint64,
         ctypes.POINTER(ctypes.c_uint32),
+        *stamps_out,
     ]
     lib.tss_write_file_digest.restype = ctypes.c_int
     return lib
@@ -226,14 +231,73 @@ def _buf_address(mv: memoryview) -> int:
     return np.frombuffer(mv, dtype=np.uint8).ctypes.data if mv.nbytes else 0
 
 
-def write_file(lib: ctypes.CDLL, path: str, buf, *, direct: bool, chunk_bytes: int) -> None:
-    """Write ``buf`` (any buffer-protocol object) to ``path`` via the engine."""
+# What the engine stamps, GIL-free, on ``time.monotonic()``'s clock
+# (``tss_io.cpp``): a write chunk as ``(t_copy, t_mount, t_crc, t_end,
+# nbytes)``: the writing thread copied into the bounce buffer over ``[t_copy,
+# t_mount)``, was in ``pwrite`` over ``[t_mount, t_crc)`` and hashed over
+# ``[t_crc, t_end)``; ``nbytes`` is what the ``pwrite`` took of the object. A
+# read chunk as ``(t0, t1, p0, p1)``: the reader thread had the chunk over
+# ``[t0, t1)`` (the ``pread`` into its bounce buffer and the copy out of it
+# into the destination's pages) and was in ``pread`` over ``[p0, p1)``.
+WriteChunk = Tuple[float, float, float, float, float]
+ReadChunk = Tuple[float, float, float, float]
+_WRITE_STAMP_DOUBLES = 5
+_READ_STAMP_DOUBLES = 4
+
+
+class _StampsOut:
+    """The engine's optional stamps-out: two null pointers unless
+    ``wanted``, so an unstamped call reads no clock and allocates nothing."""
+
+    def __init__(self, lib: ctypes.CDLL, wanted: bool, width: int) -> None:
+        self._lib = lib
+        self._width = width
+        self._rows = ctypes.POINTER(ctypes.c_double)()
+        self._count = ctypes.c_uint64(0)
+        self.args = (
+            (ctypes.byref(self._rows), ctypes.byref(self._count))
+            if wanted
+            else (None, None)
+        )
+
+    def take(self) -> List[Tuple[float, ...]]:
+        """The rows as tuples; the engine's array is released."""
+        w = self._width
+        try:
+            return [
+                tuple(self._rows[w * k : w * (k + 1)])
+                for k in range(self._count.value)
+            ]
+        finally:
+            self._lib.tss_free(self._rows)
+
+
+def write_file(
+    lib: ctypes.CDLL,
+    path: str,
+    buf,
+    *,
+    direct: bool,
+    chunk_bytes: int,
+    stamps: Optional[List[WriteChunk]] = None,
+) -> None:
+    """Write ``buf`` (any buffer-protocol object) to ``path`` via the engine.
+    ``stamps``: a list the engine's :data:`WriteChunk` rows are appended to
+    (``None``: the engine stamps nothing)."""
     mv = _as_uint8_view(buf)
+    out = _StampsOut(lib, stamps is not None, _WRITE_STAMP_DOUBLES)
     rc = lib.tss_write_file(
-        os.fsencode(path), _buf_address(mv), mv.nbytes, 1 if direct else 0, chunk_bytes
+        os.fsencode(path),
+        _buf_address(mv),
+        mv.nbytes,
+        1 if direct else 0,
+        chunk_bytes,
+        *out.args,
     )
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
+    if stamps is not None:
+        stamps.extend(out.take())
 
 
 def write_file_digest(
@@ -243,15 +307,18 @@ def write_file_digest(
     *,
     direct: bool,
     chunk_bytes: int,
+    stamps: Optional[List[WriteChunk]] = None,
 ):
     """Write ``buf`` and return its ``[crc32, size, None]`` digest, the crc
     computed inside the write loop (no extra memory pass). The sha256 slot
     is None by design — hashlib's OpenSSL (SHA-NI) implementation beats any
     embedded portable one, so collision-resistant dedup digests stay in
-    Python and the scheduler fills the slot when it needs one.
+    Python and the scheduler fills the slot when it needs one. ``stamps``:
+    as :func:`write_file` takes it.
     """
     mv = _as_uint8_view(buf)
     crc = ctypes.c_uint32(0)
+    out = _StampsOut(lib, stamps is not None, _WRITE_STAMP_DOUBLES)
     rc = lib.tss_write_file_digest(
         os.fsencode(path),
         _buf_address(mv),
@@ -259,9 +326,12 @@ def write_file_digest(
         1 if direct else 0,
         chunk_bytes,
         ctypes.byref(crc),
+        *out.args,
     )
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
+    if stamps is not None:
+        stamps.extend(out.take())
     return [crc.value, mv.nbytes, None]
 
 
@@ -275,18 +345,17 @@ def read_into(
     chunk_bytes: int = 4 << 20,
     stamped: bool = False,
     fail_chunk: int = -1,
-) -> List[Tuple[float, float]]:
+) -> List[ReadChunk]:
     """Fill writable buffer ``dst`` from ``path[offset : offset+len(dst)]``,
     as chunk reads of ``chunk_bytes`` on the engine's reader pool
     (:func:`set_read_depth`): several of them, of this call and of others,
-    are on the mount at once. ``stamped``: return each chunk's interval on
-    the mount, on ``time.monotonic()``'s clock (else nothing). ``fail_chunk``
-    is the fault harness's torn read (``faults.read_chunk_fault``)."""
+    are on the mount at once. ``stamped``: return each chunk's
+    :data:`ReadChunk` (else nothing). ``fail_chunk`` is the fault harness's
+    torn read (``faults.read_chunk_fault``)."""
     mv = _as_uint8_view(dst)
     if mv.readonly:
         raise ValueError("read_into requires a writable buffer")
-    stamps = ctypes.POINTER(ctypes.c_double)()
-    chunks = ctypes.c_uint64(0)
+    out = _StampsOut(lib, stamped, _READ_STAMP_DOUBLES)
     rc = lib.tss_read_file(
         os.fsencode(path),
         _buf_address(mv),
@@ -294,16 +363,12 @@ def read_into(
         mv.nbytes,
         1 if direct else 0,
         chunk_bytes,
-        ctypes.byref(stamps) if stamped else None,
-        ctypes.byref(chunks),
+        *out.args,
         fail_chunk,
     )
     if rc < 0:
         raise OSError(-rc, os.strerror(-rc), path)
-    try:
-        return [(stamps[2 * k], stamps[2 * k + 1]) for k in range(chunks.value)]
-    finally:
-        lib.tss_free(stamps)
+    return out.take()
 
 
 def set_read_depth(lib: ctypes.CDLL, depth: int) -> None:

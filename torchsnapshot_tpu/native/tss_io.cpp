@@ -7,10 +7,19 @@
 // a bounce buffer, falling back to buffered I/O wherever O_DIRECT is
 // unsupported (tmpfs, overlayfs, unaligned tails). The rates on record are
 // those of the chip machine's 9p mount (PERF.md; it has no block device): a
-// native write 0.6-0.9 GB/s an object, two at a time; a native read 0.5-0.6
+// native write 0.6-0.9 GB/s an object, two at a time, timed around the whole
+// call (the copy into the bounce buffer, the pwrite and the crc in series on
+// one thread: the stamps below tell them apart); a native read 0.5-0.6
 // GB/s as one serial stream, 3.4-3.9 GB/s as eight 4 MiB chunk reads in
 // flight, 1.2-1.4 GB/s buffered or landing in a fresh destination with no
 // bounce buffer (probe of PR 29).
+//
+// What is stamped where (all on CLOCK_MONOTONIC, Python's time.monotonic(),
+// on the thread that does the work, GIL-free, and only when the caller hands
+// in a stamps-out): a write stamps each chunk's copy into the bounce buffer,
+// its pwrite and its crc (WriteStamps); a read stamps each chunk's whole
+// interval, which is the pread AND the copy out of the bounce buffer into
+// the destination's pages, and the pread(s) inside it (ReadPool::read_chunk).
 //
 // Writes are one serial loop an object; the caller (fs.py) caps how many
 // run at once. Reads are chunk reads on a process-wide pool of reader
@@ -50,6 +59,32 @@ constexpr uint64_t kAlign = 4096;  // covers 512/4096 logical sector sizes
 uint64_t align_up(uint64_t v) { return (v + kAlign - 1) / kAlign * kAlign; }
 uint64_t align_down(uint64_t v) { return v / kAlign * kAlign; }
 
+double monotonic_s() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return static_cast<double>(ts.tv_sec) + 1e-9 * static_cast<double>(ts.tv_nsec);
+}
+
+// What the writing thread did with each chunk, when the caller asks: five
+// doubles a chunk. [0] to [1] it copied into the bounce buffer (the buffer's
+// allocation on the first chunk, the memcpy, the tail's memset), [1] to [2]
+// it was in pwrite (retries included), [2] to [3] it hashed (nothing where
+// the caller wants no digest); [4] is the bytes of the object the pwrite
+// took. A buffered write has no copy: [0] == [1].
+constexpr uint64_t kWriteStampDoubles = 5;
+
+struct WriteStamps {
+  std::vector<double> v;
+  void add(double copy0, double mount0, double crc0, double end, uint64_t nbytes) {
+    const double row[kWriteStampDoubles] = {copy0, mount0, crc0, end,
+                                            static_cast<double>(nbytes)};
+    v.insert(v.end(), row, row + kWriteStampDoubles);
+  }
+};
+
+// The clock, where stamps are wanted: an unstamped call reads none.
+double now_if(const void* wanted) { return wanted != nullptr ? monotonic_s() : 0.0; }
+
 // Running CRC32 updated as write chunks advance (bytes hashed exactly once,
 // in file order, while the chunk is cache-hot from the bounce copy).
 // Deliberately crc-only: an embedded scalar SHA-256 was tried and measured
@@ -70,18 +105,22 @@ struct HashCtx {
   }
 };
 
-// Buffered positional write of [src, src+nbytes) at file offset `off`.
+// Buffered positional write of [src, src+nbytes) at file offset `off`; each
+// pwrite is one chunk of `st`.
 int write_buffered(int fd, const char* src, uint64_t nbytes, uint64_t off,
-                   HashCtx* hc = nullptr) {
+                   HashCtx* hc, WriteStamps* st) {
   uint64_t done = 0;
   while (done < nbytes) {
     size_t n = std::min<uint64_t>(nbytes - done, 1ull << 30);
-    ssize_t w = pwrite(fd, src + done, n, off + done);
-    if (w < 0) {
-      if (errno == EINTR) continue;
-      return -errno;
-    }
+    const double t0 = now_if(st);
+    ssize_t w;
+    do {
+      w = pwrite(fd, src + done, n, off + done);
+    } while (w < 0 && errno == EINTR);
+    if (w < 0) return -errno;
+    const double t1 = now_if(st);
     if (hc) hc->update(src + done, static_cast<uint64_t>(w));
+    if (st) st->add(t0, t0, t1, hc ? monotonic_s() : t1, static_cast<uint64_t>(w));
     done += static_cast<uint64_t>(w);
   }
   return 0;
@@ -104,9 +143,12 @@ int read_buffered(int fd, char* dst, uint64_t nbytes, uint64_t off) {
 
 // Shared implementation of the write entry points; `hc` (nullable) receives
 // a running crc32 over the bytes, updated chunk-by-chunk while the data is
-// cache-hot from the bounce-buffer copy.
+// cache-hot from the bounce-buffer copy; `st` (nullable) what the thread did
+// with each chunk. open, ftruncate and close are stamped by nobody: they are
+// what is left of the caller's time around the call.
 int write_impl(const char* path, const void* buf, uint64_t nbytes,
-               int use_direct, uint64_t chunk_bytes, HashCtx* hc) {
+               int use_direct, uint64_t chunk_bytes, HashCtx* hc,
+               WriteStamps* st) {
   const char* src = static_cast<const char*>(buf);
   const int base_flags = O_WRONLY | O_CREAT | O_TRUNC;
 
@@ -124,29 +166,35 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
   if (direct) {
     if (chunk_bytes < kAlign) chunk_bytes = 64ull << 20;
     chunk_bytes = align_down(chunk_bytes);
+    // Fresh for every object and freed after it: its pages are first
+    // touched by the copy below, which is why the allocation is the copy's.
     void* bounce = nullptr;
-    if (posix_memalign(&bounce, kAlign, chunk_bytes) != 0) {
-      close(fd);
-      return -ENOMEM;
-    }
     while (off < nbytes) {
       uint64_t n = std::min(chunk_bytes, nbytes - off);
       uint64_t padded = align_up(n);
+      const double copy0 = now_if(st);
+      if (bounce == nullptr && posix_memalign(&bounce, kAlign, chunk_bytes) != 0) {
+        close(fd);
+        return -ENOMEM;
+      }
       memcpy(bounce, src + off, n);
       if (padded > n) memset(static_cast<char*>(bounce) + n, 0, padded - n);
-      ssize_t w = pwrite(fd, bounce, padded, off);
-      if (w < 0) {
-        if (errno == EINTR) continue;
-        if (errno == EINVAL) break;  // device rejected O_DIRECT mid-stream
-        rc = -errno;
-        break;
-      }
+      const double mount0 = now_if(st);
+      ssize_t w;
+      do {
+        w = pwrite(fd, bounce, padded, off);
+      } while (w < 0 && errno == EINTR);
+      const int err = errno;
+      const double crc0 = now_if(st);
       // A short direct write only advances at an aligned boundary; a
       // sub-sector (or zero) count means this fs can't make progress under
       // O_DIRECT — finish buffered below rather than spinning.
-      uint64_t advanced = std::min<uint64_t>(align_down(static_cast<uint64_t>(w)), n);
+      const uint64_t advanced =
+          w < 0 ? 0 : std::min<uint64_t>(align_down(static_cast<uint64_t>(w)), n);
+      if (hc && advanced > 0) hc->update(src + off, advanced);
+      if (st) st->add(copy0, mount0, crc0, hc && advanced > 0 ? monotonic_s() : crc0, advanced);
+      if (w < 0 && err != EINVAL) rc = -err;  // EINVAL: O_DIRECT rejected mid-stream
       if (advanced == 0) break;
-      if (hc) hc->update(src + off, advanced);
       off += advanced;
     }
     free(bounce);
@@ -156,16 +204,31 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
       if (fd2 < 0) {
         rc = -errno;
       } else {
-        rc = write_buffered(fd2, src + off, nbytes - off, off, hc);
+        rc = write_buffered(fd2, src + off, nbytes - off, off, hc, st);
         if (close(fd2) < 0 && rc == 0) rc = -errno;
       }
     }
     // Drop the alignment padding from the final chunk.
     if (rc == 0 && ftruncate(fd, static_cast<off_t>(nbytes)) < 0) rc = -errno;
   } else {
-    rc = write_buffered(fd, src, nbytes, 0, hc);
+    rc = write_buffered(fd, src, nbytes, 0, hc, st);
   }
   if (close(fd) < 0 && rc == 0) rc = -errno;
+  return rc;
+}
+
+// Hand `st`'s rows to the caller as one malloc'd array (released with
+// tss_free); nothing where the write failed or had no chunk.
+int give_write_stamps(int rc, const WriteStamps& st, double** stamps_out,
+                      uint64_t* chunks_out) {
+  *stamps_out = nullptr;
+  *chunks_out = 0;
+  if (rc != 0 || st.v.empty()) return rc;
+  double* out = static_cast<double*>(malloc(st.v.size() * sizeof(double)));
+  if (out == nullptr) return -ENOMEM;
+  std::copy(st.v.begin(), st.v.end(), out);
+  *stamps_out = out;
+  *chunks_out = st.v.size() / kWriteStampDoubles;
   return rc;
 }
 
@@ -181,15 +244,10 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
 // and the chunk is clamped so that this stays under kMaxBounceBytes.
 
 constexpr uint64_t kMaxBounceBytes = 256ull << 20;
+constexpr uint64_t kReadStampDoubles = 4;
 // The depth a new pool starts with: what tss_read_pool_configure last set, so
 // that a forked child's pool is sized as its parent's was.
 std::atomic<unsigned> g_read_depth{8};
-
-double monotonic_s() {
-  struct timespec ts;
-  clock_gettime(CLOCK_MONOTONIC, &ts);
-  return static_cast<double>(ts.tv_sec) + 1e-9 * static_cast<double>(ts.tv_nsec);
-}
 
 struct ReadJob {
   char* dst = nullptr;
@@ -203,6 +261,17 @@ struct ReadJob {
   std::condition_variable done;
 };
 
+// The seconds one chunk spent in pread, when stamps are asked for: `first` is
+// where its first pread began, `total` the sum over its preads (several only
+// after a short read or EINTR).
+struct PreadClock {
+  double first = 0.0, total = 0.0;
+  void add(double t0, double t1) {
+    if (total == 0.0) first = t0;
+    total += t1 - t0;
+  }
+};
+
 // One chunk under O_DIRECT: [file_off, file_off + n) into `out` through the
 // thread's bounce buffer. (Reading straight into an aligned destination was
 // measured and is slower: pinning a fresh destination's pages for the
@@ -212,7 +281,7 @@ struct ReadJob {
 // mount made no progress under O_DIRECT or refused it, and the caller
 // finishes buffered) or -errno.
 int64_t read_chunk_direct(const ReadJob& job, char* bounce, char* out,
-                          uint64_t file_off, uint64_t n) {
+                          uint64_t file_off, uint64_t n, PreadClock* clock) {
   uint64_t done = 0;
   while (done < n) {
     const uint64_t want_off = file_off + done;
@@ -222,11 +291,14 @@ int64_t read_chunk_direct(const ReadJob& job, char* bounce, char* out,
     // O_DIRECT reads must not extend past EOF by more than a sector pad.
     const uint64_t padded =
         std::min(align_up(lead + left), align_up(job.file_size - read_off));
+    const double t0 = now_if(clock);
     ssize_t r = pread(job.fd_direct, bounce, padded, read_off);
+    const int err = errno;  // before the stamp's clock_gettime
+    if (clock) clock->add(t0, monotonic_s());
     if (r < 0) {
-      if (errno == EINTR) continue;
-      if (errno == EINVAL) break;  // refused mid-stream: finish buffered
-      return -errno;
+      if (err == EINTR) continue;
+      if (err == EINVAL) break;  // refused mid-stream: finish buffered
+      return -err;
     }
     const uint64_t got = static_cast<uint64_t>(r);
     // No forward progress (a short read at an unaligned boundary, seen on
@@ -331,29 +403,43 @@ class ReadPool {
     free(bounce);
   }
 
+  // Four doubles a chunk in `job.stamps`: [0] to [1] the chunk's whole
+  // interval on its reader thread, the pread into the warm bounce buffer and
+  // the copy out of it into the destination's (fresh) pages; [2] to [3] the
+  // pread inside it (a buffered tail, which reads straight into the
+  // destination, counts as pread), so the rest of the chunk is the copy.
+  // Several preads of one chunk are laid end to end from the first one's
+  // start: their sum is exact, their place in the chunk is not.
   static int read_chunk(const ReadJob& job, uint64_t k, char* bounce) {
     const uint64_t at = k * job.chunk;
     const uint64_t n = std::min(job.chunk, job.nbytes - at);
     char* out = job.dst + at;
     const uint64_t file_off = job.offset + at;
-    const double t0 = monotonic_s();
+    PreadClock preads;
+    PreadClock* clock = job.stamps != nullptr ? &preads : nullptr;
+    const double t0 = now_if(clock);
     int rc = 0;
     if (static_cast<int64_t>(k) == job.fail_chunk) {
       rc = -ESTALE;
     } else {
       uint64_t done = 0;
       if (job.fd_direct >= 0 && bounce != nullptr) {
-        int64_t got = read_chunk_direct(job, bounce, out, file_off, n);
+        int64_t got = read_chunk_direct(job, bounce, out, file_off, n, clock);
         if (got < 0) rc = static_cast<int>(got);
         else done = static_cast<uint64_t>(got);
       }
       if (rc == 0 && done < n) {
+        const double b0 = now_if(clock);
         rc = read_buffered(job.fd_buffered, out + done, n - done, file_off + done);
+        if (clock) clock->add(b0, monotonic_s());
       }
     }
-    if (job.stamps != nullptr) {
-      job.stamps[2 * k] = t0;
-      job.stamps[2 * k + 1] = monotonic_s();
+    if (clock) {
+      double* row = job.stamps + kReadStampDoubles * k;
+      row[0] = t0;
+      row[1] = monotonic_s();
+      row[2] = preads.total > 0.0 ? preads.first : t0;
+      row[3] = row[2] + preads.total;
     }
     return rc;
   }
@@ -397,15 +483,23 @@ ReadPool& pool() {
 
 extern "C" {
 
-int tss_io_version() { return 4; }
+int tss_io_version() { return 5; }
 
 // Create/truncate `path` and write `nbytes` from `buf`.
 // use_direct != 0 attempts O_DIRECT via an aligned bounce buffer of
 // chunk_bytes; any O_DIRECT failure falls back to buffered I/O and the write
-// still succeeds.
+// still succeeds. `stamps_out`, when given, receives an array of five
+// doubles a chunk (`*chunks_out` chunks; the caller releases it with
+// tss_free): what the writing thread did with the chunk and when, on
+// CLOCK_MONOTONIC (WriteStamps above). Null: the call reads no clock.
 int tss_write_file(const char* path, const void* buf, uint64_t nbytes,
-                   int use_direct, uint64_t chunk_bytes) {
-  return write_impl(path, buf, nbytes, use_direct, chunk_bytes, nullptr);
+                   int use_direct, uint64_t chunk_bytes, double** stamps_out,
+                   uint64_t* chunks_out) {
+  WriteStamps st;
+  int rc = write_impl(path, buf, nbytes, use_direct, chunk_bytes, nullptr,
+                      stamps_out != nullptr ? &st : nullptr);
+  if (stamps_out != nullptr) rc = give_write_stamps(rc, st, stamps_out, chunks_out);
+  return rc;
 }
 
 // Like tss_write_file, but also computes the zlib crc32 over the written
@@ -413,10 +507,14 @@ int tss_write_file(const char* path, const void* buf, uint64_t nbytes,
 // hashing path pays per object is folded into the write loop here.
 int tss_write_file_digest(const char* path, const void* buf, uint64_t nbytes,
                           int use_direct, uint64_t chunk_bytes,
-                          uint32_t* crc_out) {
+                          uint32_t* crc_out, double** stamps_out,
+                          uint64_t* chunks_out) {
   HashCtx hc;
-  int rc = write_impl(path, buf, nbytes, use_direct, chunk_bytes, &hc);
+  WriteStamps st;
+  int rc = write_impl(path, buf, nbytes, use_direct, chunk_bytes, &hc,
+                      stamps_out != nullptr ? &st : nullptr);
   if (rc == 0 && crc_out) *crc_out = static_cast<uint32_t>(hc.crc);
+  if (stamps_out != nullptr) rc = give_write_stamps(rc, st, stamps_out, chunks_out);
   return rc;
 }
 
@@ -424,9 +522,10 @@ int tss_write_file_digest(const char* path, const void* buf, uint64_t nbytes,
 // `chunk_bytes` on the reader pool (see ReadPool above): the call returns when
 // every chunk has landed. Fails with -EIO if the file is shorter than
 // offset+nbytes (callers size reads from the manifest). `stamps_out`, when
-// given, receives an array of two doubles a chunk (`*chunks_out` chunks; the
-// caller releases it with tss_free): each chunk's time on the mount, start
-// and end on CLOCK_MONOTONIC (Python's time.monotonic()). `fail_chunk` >= 0
+// given, receives an array of four doubles a chunk (`*chunks_out` chunks; the
+// caller releases it with tss_free): each chunk's interval on its reader
+// thread and the pread inside it, on CLOCK_MONOTONIC (Python's
+// time.monotonic(); ReadPool::read_chunk). `fail_chunk` >= 0
 // is the fault harness's torn read: that chunk fails with -ESTALE, the
 // others land.
 int tss_read_file(const char* path, void* dst, uint64_t offset, uint64_t nbytes,
@@ -465,7 +564,7 @@ int tss_read_file(const char* path, void* dst, uint64_t offset, uint64_t nbytes,
     job.chunk = std::max(kAlign, align_down(std::min(chunk_bytes, cap)));
     const uint64_t chunks = (nbytes + job.chunk - 1) / job.chunk;
     if (stamps_out != nullptr) {
-      job.stamps = static_cast<double*>(calloc(2 * chunks, sizeof(double)));
+      job.stamps = static_cast<double*>(calloc(kReadStampDoubles * chunks, sizeof(double)));
       if (job.stamps == nullptr) rc = -ENOMEM;
     }
     if (rc == 0) rc = pool().run(&job, chunks);

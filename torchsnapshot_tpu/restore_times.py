@@ -10,8 +10,12 @@ it:
               flatten, every ``_prepare_restore_one``, read batching
     fetch     one storage read of the read pipeline, retries included
     mount     inside a fetch, one chunk read of the fs plugin's native
-              engine for the time it was on the mount (no span: stamped in
-              the engine, GIL-free)
+              engine for the time a reader thread had it: the ``pread``
+              into the thread's bounce buffer AND the copy out of it into
+              the target's pages, so ``mount_*`` is the readers' occupancy
+              (no span: stamped in the engine, GIL-free)
+    pread     inside a chunk, the ``pread`` itself: the mount proper; the
+              rest of the chunk is the reader's copy (``reader_copy_*``)
     verify    the digest check of one fetched buffer
     consume   one consumer's decode + copy into its host target (nothing,
               where the read landed in that target: ``landed_bytes``)
@@ -114,7 +118,7 @@ class RestoreTimes:
         self._lock = threading.Lock()
         self._intervals: Dict[str, List[Interval]] = {k: [] for k in _SPANS}
         self._pipeline: List[Interval] = []
-        self._mount: List[Interval] = []
+        self._mount: List[Tuple[float, float, float, float]] = []
         self._sums: Dict[str, float] = {k: 0.0 for k in _SUMS}
 
     # ----------------------------------------------------------- recording
@@ -177,9 +181,12 @@ class RestoreTimes:
             name, cat, _ = _SPANS["fetch"]
             self.tm.add_span(name, cat, t0, t1 - t0, {"path": path, "nbytes": nbytes})
 
-    def add_mount_reads(self, chunk_reads: List[Interval], nbytes: int) -> None:
-        """One native read's chunk reads, each for the time it was on the
-        mount (stamped inside the engine, on this clock), and the bytes they
+    def add_mount_reads(
+        self, chunk_reads: List[Tuple[float, float, float, float]], nbytes: int
+    ) -> None:
+        """One native read's chunk reads as the engine stamped them, on this
+        clock (``native.ReadChunk``): each chunk's whole interval on its
+        reader thread and the ``pread`` inside it, and the bytes they
         delivered. No span of their own: ``storage.read_work`` stays one
         span an object."""
         with self._lock:
@@ -226,12 +233,19 @@ class RestoreTimes:
         with self._lock:
             ivs = {k: list(v) for k, v in self._intervals.items()}
             windows = merge_intervals(self._pipeline)
-            mount = list(self._mount)
+            chunks = list(self._mount)
             out = dict(self._sums)
         merged = {k: merge_intervals(v) for k, v in ivs.items()}
         busy_any = merge_intervals(
             [iv for k in _PIPELINE_KINDS for iv in merged[k]]
         )
+        # A chunk, the pread inside it, and what its reader did outside the
+        # pread: the copy out of the bounce buffer into the target's pages.
+        mount = [(t0, t1) for t0, t1, _, _ in chunks]
+        pread = [(p0, p1) for _, _, p0, p1 in chunks]
+        reader_copy = [
+            iv for t0, t1, p0, p1 in chunks for iv in ((t0, p0), (p1, t1))
+        ]
         pipeline_s = measure(windows)
         busy_in_pipeline = sum(
             measure(clip_merged(busy_any, w0, w1)) for w0, w1 in windows
@@ -242,6 +256,9 @@ class RestoreTimes:
             fetch_sum_s=measure(ivs["fetch"]),
             mount_busy_s=measure(merge_intervals(mount)),
             mount_sum_s=measure(mount),
+            pread_busy_s=measure(merge_intervals(pread)),
+            pread_sum_s=measure(pread),
+            reader_copy_sum_s=measure(reader_copy),
             verify_busy_s=measure(merged["verify"]),
             consume_busy_s=measure(merged["consume"]),
             consume_sum_s=measure(ivs["consume"]),

@@ -66,11 +66,11 @@ from .engine import GraphExecutor, Node, Priority
 from .engine.executor import Budget as _Budget  # noqa: F401 - test surface
 from .engine.executor import ProgressReporter as _ProgressReporter  # noqa: F401
 from .engine.intervals import (
-    clip_merged as _clip_merged,
-    measure as _measure,
+    busy_in as _busy_in,
     merge_intervals as _merge_intervals,
     smaller_than as _smaller_than,
     stream_stats as _stream_stats,
+    sum_in as _sum_in,
 )
 from .io_types import (
     SMALL_OBJECT_BYTES,
@@ -79,6 +79,7 @@ from .io_types import (
     StoragePlugin,
     WriteIO,
     WriteReq,
+    WriteTimes,
     destination_of,
 )
 from .storage_plugins.cloud_retry import (
@@ -299,6 +300,9 @@ class _WritePipeline:
             lanes=self.pools.transfer_lanes(),
             times=d2h.StageTimes(tm=self._tm),
         )
+        # Beside the io stream: what the plugin's native writes did, handed
+        # to it with each object's ``WriteIO``.
+        self._write_times = WriteTimes()
 
         def _max_io() -> int:
             return knobs.get_max_concurrent_io_for(self.storage)
@@ -502,7 +506,9 @@ class _WritePipeline:
                         )
                     )
                     try:
-                        await self.storage.write(WriteIO(path=path, buf=buf))
+                        await self.storage.write(
+                            WriteIO(path=path, buf=buf, times=self._write_times)
+                        )
                     except BaseException:
                         digest_task.cancel()
                         await asyncio.gather(
@@ -517,7 +523,9 @@ class _WritePipeline:
                 # (WriteIO.digest_out), and Python covers only what the
                 # plugin didn't — everything (non-native backends), or just
                 # the sha256 dedup digest.
-                write_io = WriteIO(path=path, buf=buf, want_digest=True)
+                write_io = WriteIO(
+                    path=path, buf=buf, want_digest=True, times=self._write_times
+                )
                 await self.storage.write(write_io)
                 digest = write_io.digest_out
                 if digest is None:
@@ -587,7 +595,9 @@ class _WritePipeline:
                     if await self.storage.link_in(src, path):
                         self.bytes_deduped += my_size
                         return
-        await self.storage.write(WriteIO(path=path, buf=buf))
+        await self.storage.write(
+            WriteIO(path=path, buf=buf, times=self._write_times)
+        )
 
     # ---------------------------------------------------------------- phases
 
@@ -719,33 +729,59 @@ class _WritePipeline:
             windows, self._stage_intervals, self._io_intervals
         )
         # Decompose stage_busy into its sub-streams (D2H resolve, serialize/
-        # compress, hash fold) from the StageTimes intervals — same union/
-        # clip algebra, so the stats and the stage.* trace spans can never
-        # disagree. With parallel lanes the sub-streams overlap each other,
-        # so their sum may legitimately EXCEED stage_busy_s (that overlap is
-        # the speedup); each value reads "seconds this sub-stream was busy".
+        # compress, hash fold, a piece's gather) from the StageTimes
+        # intervals — same union/clip algebra, so the stats and the stage.*
+        # trace spans can never disagree. With parallel lanes the
+        # sub-streams overlap each other, so their sum may legitimately
+        # EXCEED stage_busy_s (that overlap is the speedup); each value
+        # reads "seconds this sub-stream was busy". Each view clips to its
+        # own windows.
         sub = self._staging_ctx.times.intervals()
-        for kind, ivs in sub.items():
-            merged = _merge_intervals(ivs)
-            self.drain_stats[f"stage_{kind}_s"] = _measure(
-                _clip_merged(merged, *drain_window)
-            )
-            self.pipeline_stats[f"stage_{kind}_s"] = sum(
-                _measure(_clip_merged(merged, w0, w1))
-                for w0, w1 in windows
-            )
-        # Of the d2h sub-stream and of the io stream, the seconds spent on
-        # transfers and writes under SMALL_OBJECT_BYTES: what a state of many
-        # sizes pays in per-object fixed costs (same algebra, same windows).
-        for name, ivs in (
-            ("stage_d2h_small_s", sub["d2h"]),
-            ("io_busy_small_s", self._io_intervals),
+        written = self._write_times.intervals()
+        for stats, wins in (
+            (self.drain_stats, [drain_window]),
+            (self.pipeline_stats, windows),
         ):
-            merged = _merge_intervals(_smaller_than(ivs, SMALL_OBJECT_BYTES))
-            self.drain_stats[name] = _measure(_clip_merged(merged, *drain_window))
-            self.pipeline_stats[name] = sum(
-                _measure(_clip_merged(merged, w0, w1)) for w0, w1 in windows
+            for kind, ivs in sub.items():
+                stats[f"stage_{kind}_s"] = _busy_in(_merge_intervals(ivs), wins)
+            # The plain sums the unions lack: of a lane's seconds in
+            # stage.d2h (sum), those in the gather's copy (sum).
+            stats["stage_d2h_sum_s"] = _sum_in(sub["d2h"], wins)
+            stats["stage_gather_sum_s"] = _sum_in(sub["gather"], wins)
+            # Of the d2h sub-stream and of the io stream, the seconds spent
+            # on transfers and writes under SMALL_OBJECT_BYTES: what a state
+            # of many sizes pays in per-object fixed costs.
+            for name, ivs in (
+                ("stage_d2h_small_s", sub["d2h"]),
+                ("io_busy_small_s", self._io_intervals),
+            ):
+                stats[name] = _busy_in(
+                    _merge_intervals(_smaller_than(ivs, SMALL_OBJECT_BYTES)), wins
+                )
+            # Inside io_busy_s, what the fs plugin's native writes did
+            # (``WriteTimes.record_native_write``; a write under the native
+            # threshold goes through aiofiles on threads the library does
+            # not own and is in io_busy_small_s only): the union and the sum
+            # of the pwrites with their bytes, and the sums of the
+            # storage.write_work intervals, of the copies into the bounce
+            # buffer, of the crc and of the waits for a writer slot.
+            mount = written["mount_write"]
+            stats["mount_write_s"] = _busy_in(_merge_intervals(mount), wins)
+            stats["mount_write_bytes"] = float(
+                sum(
+                    nbytes
+                    for t0, _, nbytes in mount
+                    if any(w0 <= t0 < w1 for w0, w1 in wins)
+                )
             )
+            for name, kind in (
+                ("write_work_sum_s", "write_work"),
+                ("mount_write_sum_s", "mount_write"),
+                ("write_copy_sum_s", "write_copy"),
+                ("write_crc_sum_s", "write_crc"),
+                ("write_queue_sum_s", "write_queue"),
+            ):
+                stats[name] = _sum_in(written[kind], wins)
         # Pipeline-level metrics (no-ops unless a telemetry session is on).
         telemetry.gauge_max(
             "scheduler.budget_hwm_bytes", self.budget.high_water_bytes
@@ -856,6 +892,7 @@ class PendingIOWork:
         pipeline has completed."""
         p = self._pipeline
         counters = p.progress.counters()
+        written = p._write_times.intervals()
         return {
             "pipeline_stats_s": dict(p.pipeline_stats),
             "drain_stats_s": dict(p.drain_stats),
@@ -877,6 +914,18 @@ class PendingIOWork:
             "stage_substreams": {
                 kind: _merge_intervals(ivs)
                 for kind, ivs in p._staging_ctx.times.intervals().items()
+            },
+            # Inside io: when a pwrite of the native engine was on the mount
+            # and when a writer was copying into its bounce buffer (merged),
+            # and every storage.write_work interval as it was (what a
+            # reader of a profiler trace matches the tss.storage.write_work
+            # events against, to put the other two on the trace's clock).
+            "write_substreams": {
+                "mount_write": _merge_intervals(written["mount_write"]),
+                "write_copy": _merge_intervals(written["write_copy"]),
+                "write_work": sorted(
+                    (t0, t1) for t0, t1, _ in written["write_work"]
+                ),
             },
             # Engine/QoS introspection totals + closed pause episodes, so
             # preemption waves survive into the persisted artifact instead

@@ -10,7 +10,9 @@ repeats them).
 
 Concurrency: the event loop may have many plugin ops in flight; blocking work
 runs on a private thread pool. Writes: a semaphore caps concurrent native
-writes at ``knobs.get_direct_io_concurrency()`` objects. Reads: no semaphore
+writes at ``knobs.get_direct_io_concurrency()`` objects; one that is handed a
+``WriteIO.times`` tells it how long it waited for its slot and what the engine
+did with each chunk (copy, ``pwrite``, crc). Reads: no semaphore
 around an object. A native read is chunk reads of ``_READ_CHUNK_BYTES`` on
 the engine's reader pool, and the cap, ``knobs.get_direct_read_depth()``,
 counts chunks on the mount for the whole process: those of one large leaf,
@@ -24,6 +26,7 @@ import asyncio
 import contextlib
 import os
 import threading
+import time
 import uuid
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Set, Union
@@ -183,31 +186,42 @@ class FSStoragePlugin(StoragePlugin):
                 # (and fills the sha256 slot itself if dedup digests are on
                 # — hashlib's OpenSSL sha is the fast one).
                 want_digest = write_io.want_digest
+                nbytes = memoryview(write_io.buf).nbytes
+                # A take's write pipeline hands its sink along and the
+                # engine then stamps what it does with each chunk; any other
+                # write asks for nothing and pays this one check.
+                times = write_io.times
+                handed = time.monotonic()
 
                 def work() -> None:
+                    write = (
+                        native.write_file_digest if want_digest else native.write_file
+                    )
+                    chunks = [] if times is not None else None
                     # On the writing thread: what a profiler trace shows of
                     # this write (the span around it lives across awaits).
                     with self._get_direct_sem(), telemetry.span(
                         "storage.write_work", "storage", True,
-                        path=write_io.path,
-                        nbytes=memoryview(write_io.buf).nbytes,
+                        path=write_io.path, nbytes=nbytes,
                     ):
-                        if want_digest:
-                            write_io.digest_out = native.write_file_digest(
-                                lib,
-                                tmp_path,
-                                write_io.buf,
-                                direct=True,
-                                chunk_bytes=knobs.get_direct_io_chunk_bytes(),
-                            )
-                            return
-                        native.write_file(
+                        # Stamped next to the span's own ends, with
+                        # nothing that can block in between: a reader of
+                        # a profiler trace pairs these with the
+                        # ``tss.storage.write_work`` events.
+                        held = time.monotonic()
+                        digest = write(
                             lib,
                             tmp_path,
                             write_io.buf,
                             direct=True,
                             chunk_bytes=knobs.get_direct_io_chunk_bytes(),
+                            stamps=chunks,
                         )
+                        done = time.monotonic()
+                    if want_digest:
+                        write_io.digest_out = digest
+                    if times is not None:
+                        times.record_native_write(handed, held, done, nbytes, chunks)
 
                 await asyncio.get_running_loop().run_in_executor(
                     self._get_executor(), work

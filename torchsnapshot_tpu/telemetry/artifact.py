@@ -129,6 +129,10 @@ def build_artifact(
             artifact["intervals"][f"stage_{kind}"] = _round_intervals(
                 ivs, offset
             )
+        # Inside io: the native writes' pwrites and bounce-buffer copies
+        # (merged), and each ``storage.write_work`` interval (unmerged).
+        for kind, ivs in (io_summary.get("write_substreams") or {}).items():
+            artifact["intervals"][kind] = _round_intervals(ivs, offset)
         # Engine/QoS introspection (additive, v1-compatible): preemption
         # totals and closed pause episodes, wall-clock-stamped like every
         # other interval stream.
