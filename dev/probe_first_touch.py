@@ -1,11 +1,17 @@
 """Host-only probe of PR 39 (``chiprun -- python dev/probe_first_touch.py``):
 what the first write into fresh host pages costs on the chip's machine, and
-whether huge pages or a bulk populate cure it. ``PERF.md`` section 6."""
+whether huge pages or a bulk populate cure it. ``PERF.md`` section 6.
+
+PR 41 added the last arm (ROADMAP S1 (a), for the record): eight readers
+copy 4 MiB chunks out of warm memory into a fresh 1.5 GiB destination while
+8, 13 or 16 other threads touch its pages ahead of them, a chunk at a time;
+does touching ahead pass what the readers do alone into fresh pages?"""
 
 import json
 import mmap
 import os
 import sys
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
@@ -34,6 +40,37 @@ def fill(arr, threads=1):
         with ThreadPoolExecutor(threads) as ex:
             list(ex.map(lambda i: arr[i * n : (i + 1) * n].fill(1), range(threads)))
     return time.perf_counter() - t0
+
+
+def touch_ahead(touchers, nbytes=1536 * MIB, chunk=4 * MIB, readers=8, warm=False):
+    """GB/s at which ``readers`` threads fill a fresh destination from warm
+    memory, each chunk first touched (a byte a page) by one of ``touchers``
+    threads running ahead; 0 touchers: the readers take the faults."""
+    src = np.ones(chunk, np.uint8)
+    dest = np.empty(nbytes, np.uint8)
+    if warm:
+        dest.fill(1)
+    chunks = nbytes // chunk
+    touched = [threading.Event() for _ in range(chunks)]
+
+    def toucher(t):
+        for i in range(t, chunks, touchers):
+            dest[i * chunk : (i + 1) * chunk : mmap.PAGESIZE] = 1
+            touched[i].set()
+
+    def reader(r):
+        for i in range(r, chunks, readers):
+            if touchers:
+                touched[i].wait()
+            np.copyto(dest[i * chunk : (i + 1) * chunk], src)
+
+    t0 = time.perf_counter()
+    with ThreadPoolExecutor(touchers + readers) as ex:
+        jobs = [ex.submit(toucher, t) for t in range(touchers)]
+        jobs += [ex.submit(reader, r) for r in range(readers)]
+        for job in jobs:
+            job.result()
+    return round(nbytes / (time.perf_counter() - t0) / 1e9, 3)
 
 
 def meminfo(key):
@@ -84,6 +121,12 @@ def main():
     except OSError as e:
         out["populate_write"] = repr(e)
     del a, m
+    out["cpu_count"] = os.cpu_count()
+    out["readers8_into_touched_1536mib"] = touch_ahead(0, warm=True)
+    for round_ in (1, 2):
+        out[f"readers8_alone_fresh_1536mib_r{round_}"] = touch_ahead(0)
+        for touchers in (8, 13, 16):
+            out[f"touchers{touchers}_ahead_of_readers8_1536mib_r{round_}"] = touch_ahead(touchers)
     print(json.dumps(out, indent=1))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/probe_first_touch.json", "w") as f:

@@ -308,7 +308,7 @@ class ShardedArrayBufferConsumer(BufferConsumer):
     destination buffer (reference ``ShardedTensorBufferConsumer:288``).
 
     ``fresh_targets``: the restore allocated the destination buffers itself
-    (``alloc_target_shards``) and nobody sees them before it ends, so a
+    (``target_shard_rects``) and nobody sees them before it ends, so a
     piece that goes whole into one contiguous run of one buffer may be read
     there (:meth:`destination`)."""
 
@@ -618,16 +618,15 @@ class ShardedArrayIOPreparer:
 # host buffers, then assemble a jax.Array from the filled buffers.
 # ---------------------------------------------------------------------------
 
-def alloc_target_shards(sharding, global_shape, np_dtype) -> Dict[Tuple[int, ...], Tuple[np.ndarray, List[int], List[int]]]:
-    """One host buffer per unique addressable shard index of ``sharding``."""
-    out: Dict[Tuple[int, ...], Tuple[np.ndarray, List[int], List[int]]] = {}
+def target_shard_rects(sharding, global_shape) -> List[Tuple[List[int], List[int]]]:
+    """``(offsets, sizes)`` of each unique addressable shard index of
+    ``sharding``: one host buffer each is what a restore fills."""
+    index_map = sharding.addressable_devices_indices_map(tuple(global_shape))
+    out: Dict[Tuple[int, ...], Tuple[List[int], List[int]]] = {}
     for device in sharding.addressable_devices:
-        index = sharding.addressable_devices_indices_map(tuple(global_shape))[device]
-        offsets, sizes = index_to_offsets_sizes(index, global_shape)
-        key = tuple(offsets)
-        if key not in out:
-            out[key] = (np.empty(tuple(sizes), dtype=np_dtype), offsets, sizes)
-    return out
+        offsets, sizes = index_to_offsets_sizes(index_map[device], global_shape)
+        out.setdefault(tuple(offsets), (offsets, sizes))
+    return list(out.values())
 
 
 def process_shard_map(  # spmd-pure

@@ -85,12 +85,25 @@ class BufferConsumer(abc.ABC):
         verified."""
         return None
 
+    async def acquire_target(self) -> None:
+        """Awaited by the read pipeline once the request is admitted, before
+        its fetch (and so before :meth:`destination` is asked): a consumer
+        whose destination has no memory yet gets it here, and may wait for
+        it. The default has nothing to get."""
+
 
 def destination_of(consumer: object) -> Optional[memoryview]:
     """``consumer.destination()``; None for a consumer that only quacks like
     a :class:`BufferConsumer` and predates the method."""
     offer = getattr(consumer, "destination", None)
     return offer() if offer is not None else None
+
+
+async def acquire_target_of(consumer: object) -> None:
+    """``await consumer.acquire_target()``, likewise."""
+    acquire = getattr(consumer, "acquire_target", None)
+    if acquire is not None:
+        await acquire()
 
 
 @dataclass

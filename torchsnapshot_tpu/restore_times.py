@@ -79,6 +79,9 @@ _SUMS = (
     "fetch_wait_s",
     "fetch_copied_bytes",
     "landed_bytes",
+    "recycled_bytes",
+    "fresh_target_bytes",
+    "target_wait_s",
     "mount_bytes",
     "consume_wait_s",
     "place_wait_s",

@@ -80,6 +80,7 @@ from .io_types import (
     WriteIO,
     WriteReq,
     WriteTimes,
+    acquire_target_of,
     destination_of,
 )
 from .storage_plugins.cloud_retry import (
@@ -1162,6 +1163,9 @@ async def execute_read_reqs(
 
         async def read_one(ctx, _payload):
             nonlocal fetched_at
+            # A destination that has no memory yet gets it now, once a read
+            # (the restore's arena of host pages), not at plan time.
+            await acquire_target_of(req.buffer_consumer)
             read_io = await fetch(ctx)
             want = (
                 _read_digest_record(digests, req.path) if verify_reads else None

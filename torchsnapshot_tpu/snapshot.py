@@ -51,8 +51,8 @@ from .io_preparers.chunked_array import ChunkedArrayIOPreparer
 from .io_preparers.object import ObjectIOPreparer
 from .io_preparers.sharded_array import (
     ShardedArrayIOPreparer,
-    alloc_target_shards,
     assemble_jax_array,
+    target_shard_rects,
 )
 from .io_types import (
     SMALL_OBJECT_BYTES,
@@ -60,6 +60,7 @@ from .io_types import (
     ReadReq,
     StoragePlugin,
     WriteIO,
+    acquire_target_of,
     destination_of,
 )
 from .manifest import (
@@ -90,7 +91,7 @@ from .scheduler import (
 )
 from .stateful import AppState, Stateful
 from .storage_plugin import url_to_storage_plugin_in_event_loop
-from . import hashing, restore_times, telemetry
+from . import hashing, host_arena, restore_times, telemetry
 from .utils import knobs
 from .version import __version__
 
@@ -1596,6 +1597,10 @@ class Snapshot:
         # One pool set for every per-stateful read pipeline of this restore
         # (instead of a fresh ThreadPoolExecutor per stateful).
         pools = PipelinePools()
+        # The host pages that device-bound leaves are read into, handed from
+        # leaf to leaf (host_arena.py); nothing is allocated until a leaf
+        # bound for a device that copies wants room. This restore's alone.
+        arena = host_arena.HostArena(min(host_arena.CAPACITY_BYTES, memory_budget))
         # Post-load rendezvous WITH error fan-out (the take path's
         # LinearBarrier, on the read side too): a rank failing mid-restore
         # unblocks and fails every peer within the barrier timeout —
@@ -1671,6 +1676,7 @@ class Snapshot:
                             swarm_enabled=swarm_enabled,
                             coord=coord,
                             digests=digest_index,
+                            arena=arena,
                         )
                         if stats:
                             read_totals["bytes_read"] += stats.get(
@@ -1755,6 +1761,7 @@ class Snapshot:
             raise aborted from e
         finally:
             restore_times.deactivate(times_token)
+            arena.close()
             _warn_consumed_targets()
             telemetry.fleet.note_op(None)
             pools.shutdown()
@@ -1776,6 +1783,7 @@ class Snapshot:
         swarm_enabled: bool = False,
         coord: Optional[Coordinator] = None,
         digests: Optional[Dict[str, Any]] = None,
+        arena: Optional["host_arena.HostArena"] = None,
     ) -> Dict[str, float]:
         """Restore one stateful, in three stretches that the restore's
         interval sink keeps apart: the plan, the pipeline (broadcast, swarm,
@@ -1786,6 +1794,7 @@ class Snapshot:
             plan = self._plan_stateful(
                 key, stateful, manifest, storage, memory_budget, event_loop,
                 include, bcast_enabled, swarm_enabled, coord, digests, times,
+                arena,
             )
         pipeline_t0 = time.monotonic()
         from . import bcast as bcast_mod
@@ -1832,6 +1841,8 @@ class Snapshot:
             pools=pools,
             digests=digests,
         )
+        if arena is not None:
+            times.add("target_wait_s", arena.take_wait_s())
         # Overlap on: a successful pipeline consumed every read, so every
         # countdown fired and finalized its entry inline; nothing remains.
         assert not plan.finalizers, f"unfinalized entries: {sorted(plan.finalizers)}"
@@ -1880,6 +1891,7 @@ class Snapshot:
         coord: Optional[Coordinator],
         digests: Optional[Dict[str, Any]],
         times: "restore_times.RestoreTimes",
+        arena: Optional["host_arena.HostArena"] = None,
     ) -> "_StatefulPlan":
         """Everything one stateful's restore does before its first byte
         moves: flatten the live values, fetch frame tables, route and plan
@@ -2058,6 +2070,9 @@ class Snapshot:
                 buffer_size_limit_bytes=_memory_budget_bytes_per_read,
                 frame_tables=frame_tables,
                 digests=digests,
+                # Views go back as finalizers run: with the finalizers put
+                # off to the pipeline's end, none would.
+                arena=arena if overlap else None,
             )
             if finalize is not None:
                 if not reqs:
@@ -3506,6 +3521,9 @@ class _CountingConsumer:
     def destination(self) -> Optional[memoryview]:
         return destination_of(self.inner)
 
+    async def acquire_target(self) -> None:
+        await acquire_target_of(self.inner)
+
     async def consume_buffer(self, buf, executor=None) -> None:
         inner = self.inner
         await inner.consume_buffer(buf, executor)
@@ -3886,6 +3904,161 @@ def _warn_consumed_targets() -> None:
         )
 
 
+_TargetSpec = Tuple[Tuple[int, ...], np.dtype]  # shape, dtype
+
+
+def _fresh_targets(
+    times: Optional["restore_times.RestoreTimes"], specs: List[_TargetSpec]
+) -> List[np.ndarray]:
+    """Host targets of fresh pages, one a spec, that the restore allocates
+    itself (counted as ``fresh_target_bytes``)."""
+    targets = [np.empty(shape, dtype=dtype) for shape, dtype in specs]
+    if times is not None:
+        times.add("fresh_target_bytes", sum(t.nbytes for t in targets))
+    return targets
+
+
+class _HostTargets:
+    """The host targets of one entry that is bound for a device: fresh pages
+    allocated at plan time, as a restore's targets have always been (the
+    CPU backend, where the placed array may share them; every path but the
+    overlapped direct one)."""
+
+    def __init__(
+        self,
+        specs: List[_TargetSpec],
+        plan: Callable[[List[np.ndarray]], List[ReadReq]],
+        times: Optional["restore_times.RestoreTimes"],
+    ) -> None:
+        self._targets: Optional[List[np.ndarray]] = _fresh_targets(times, specs)
+        self.reqs = plan(self._targets)
+
+    def targets(self) -> List[np.ndarray]:
+        return self._targets
+
+    def placed(self, array: Any) -> None:
+        self._targets = None
+
+
+class _LeasedHostTargets:
+    """The same, out of the restore's arena of host pages
+    (``host_arena.py``): views taken when the entry's first read is about to
+    be fetched and given back when the placed array is ready, so the next
+    entries are read into pages touched before.
+
+    The reads are planned twice over the same pure planner: once over
+    stand-ins that are never written (``np.empty`` touches nothing), for what
+    the pipeline must know beforehand (paths, ranges, costs), and once over
+    the views, for the consumers that fill them."""
+
+    def __init__(
+        self,
+        arena: "host_arena.HostArena",
+        specs: List[_TargetSpec],
+        plan: Callable[[List[np.ndarray]], List[ReadReq]],
+        times: Optional["restore_times.RestoreTimes"],
+    ) -> None:
+        self.specs = specs
+        self.plan = plan
+        self.times = times
+        sketch = plan([np.empty(shape, dtype=dtype) for shape, dtype in specs])
+        self.lease = arena.lease(
+            [int(np.prod(shape, dtype=np.int64)) * dtype.itemsize for shape, dtype in specs],
+            reads=len(sketch),
+        )
+        self._targets: Optional[List[np.ndarray]] = None
+        self._consumers: Optional[List[Any]] = None
+        self.reqs = [
+            ReadReq(
+                path=r.path,
+                buffer_consumer=_LeasedConsumer(self, i, r.buffer_consumer),
+                byte_range=r.byte_range,
+            )
+            for i, r in enumerate(sketch)
+        ]
+
+    async def acquire(self) -> None:
+        views = await self.lease.acquire()
+        if self._targets is None:
+            self._bind(views)
+
+    def _bind(self, views: Optional[List[np.ndarray]]) -> None:
+        if views is None:  # no room that was safe to wait for: fresh pages
+            targets = _fresh_targets(self.times, self.specs)
+        else:
+            targets = [
+                view.view(dtype).reshape(shape)
+                for view, (shape, dtype) in zip(views, self.specs)
+            ]
+            if self.times is not None:
+                recycled = self.lease.recycled_bytes
+                self.times.add("recycled_bytes", recycled)
+                self.times.add(
+                    "fresh_target_bytes", sum(t.nbytes for t in targets) - recycled
+                )
+        self._targets = targets
+        self._consumers = [r.buffer_consumer for r in self.plan(targets)]
+
+    def consumer(self, index: int) -> Any:
+        self.targets()
+        return self._consumers[index]
+
+    def targets(self) -> List[np.ndarray]:
+        if self._targets is None:  # no read of the entry was fetched through acquire
+            self._bind(self.lease.take_nowait())
+        return self._targets
+
+    def placed(self, array: Any) -> None:
+        """The entry is on its way to the device: the views go back when it
+        has arrived. ``device_put`` returns before the runtime has read the
+        host pages."""
+        self._targets = self._consumers = None
+        self.lease.give_back_when(array.block_until_ready)
+
+
+class _LeasedConsumer:
+    """One read of a :class:`_LeasedHostTargets` entry: the consumer planned
+    over the entry's views, once there are views."""
+
+    def __init__(self, owner: _LeasedHostTargets, index: int, planned: Any) -> None:
+        self.owner = owner
+        self.index = index
+        self.cost_bytes = planned.get_consuming_cost_bytes()
+        self.merge_exempt = getattr(planned, "merge_exempt", False)
+
+    async def acquire_target(self) -> None:
+        await self.owner.acquire()
+
+    def destination(self) -> Optional[memoryview]:
+        return destination_of(self.owner.consumer(self.index))
+
+    async def consume_buffer(self, buf, executor=None) -> None:
+        await self.owner.consumer(self.index).consume_buffer(buf, executor)
+
+    def get_consuming_cost_bytes(self) -> int:
+        return self.cost_bytes
+
+
+def _host_targets(
+    arena: Optional["host_arena.HostArena"],
+    live: Any,
+    specs: List[_TargetSpec],
+    plan: Callable[[List[np.ndarray]], List[ReadReq]],
+    times: Optional["restore_times.RestoreTimes"],
+):
+    """Host targets for an entry restored onto ``live``'s devices: out of
+    ``arena`` where there is one and the placed array cannot alias the host
+    pages it was put from (judged from the target sharding's devices), else
+    fresh ones."""
+    if (
+        arena is not None
+        and host_arena.copies_on_put(live.sharding.device_set)
+        and not any(dtype.hasobject for _shape, dtype in specs)
+    ):
+        return _LeasedHostTargets(arena, specs, plan, times)
+    return _HostTargets(specs, plan, times)
+
+
 def _prepare_restore_one(  # spmd-pure
     logical_path: str,
     entry: Entry,
@@ -3894,6 +4067,7 @@ def _prepare_restore_one(  # spmd-pure
     buffer_size_limit_bytes: Optional[int] = None,
     frame_tables: Optional[Dict[str, List[int]]] = None,
     digests: Optional[Dict[str, Any]] = None,
+    arena: Optional["host_arena.HostArena"] = None,
 ) -> Tuple[List[ReadReq], Optional[Callable[[], None]]]:
     """Plan the reads for one entry; returns (read_reqs, finalizer).
 
@@ -3905,6 +4079,10 @@ def _prepare_restore_one(  # spmd-pure
     every rank) lets the sharded exact-overlap planner align its byte
     ranges to the v2 hash-chunk grain, so ranged reshard reads verify at
     chunk granularity and compose with the read cache's sub-range tier.
+
+    ``arena`` (the overlapped direct path of a restore only): where a leaf
+    bound for a device may take its host targets from
+    (:func:`_host_targets`); a target the caller will see never does.
     """
     from .serialization import string_to_dtype
 
@@ -3940,30 +4118,35 @@ def _prepare_restore_one(  # spmd-pure
             and live.flags["C_CONTIGUOUS"]
             and live.flags["WRITEABLE"]
         )
-        # A target of the restore's own may have its read land in it; the
-        # caller's live array is overwritten only by bytes fetched whole.
-        target = live if in_place else np.empty(tuple(entry.shape), dtype=np_dtype)
-        if isinstance(entry, ChunkedArrayEntry):
-            reqs = ChunkedArrayIOPreparer.prepare_read(
+        def plan(targets: List[np.ndarray]) -> List[ReadReq]:
+            # A target of the restore's own may have its read land in it; the
+            # caller's live array is overwritten only by bytes fetched whole.
+            if isinstance(entry, ChunkedArrayEntry):
+                return ChunkedArrayIOPreparer.prepare_read(
+                    entry,
+                    targets[0],
+                    buffer_size_limit_bytes,
+                    frame_tables=frame_tables,
+                    fresh_target=not in_place,
+                )
+            return ArrayIOPreparer.prepare_read(
                 entry,
-                target,
-                buffer_size_limit_bytes,
-                frame_tables=frame_tables,
-                fresh_target=not in_place,
-            )
-        else:
-            reqs = ArrayIOPreparer.prepare_read(
-                entry,
-                target,
+                targets[0],
                 buffer_size_limit_bytes,
                 frame_table=(frame_tables or {}).get(entry.location),
                 fresh_target=not in_place,
             )
+
         if _is_jax_array(live):
+            # The host target exists only to be put on the device.
+            held = _host_targets(
+                arena, live, [(tuple(entry.shape), np_dtype)], plan, times
+            )
 
             def finalize_jax() -> None:
                 import jax
 
+                (target,) = held.targets()
                 sharding = live.sharding
                 if sharding.is_fully_addressable:
                     place = lambda: jax.device_put(target, sharding)  # noqa: E731
@@ -3984,8 +4167,14 @@ def _prepare_restore_one(  # spmd-pure
                     loaded[logical_path] = _place_over_target(
                         logical_path, live, place, times
                     )
+                held.placed(loaded[logical_path])
 
-            return reqs, finalize_jax
+            return held.reqs, finalize_jax
+        if in_place:
+            target = live
+        else:
+            (target,) = _fresh_targets(times, [(tuple(entry.shape), np_dtype)])
+        reqs = plan([target])
         loaded[logical_path] = target
         return reqs, None
 
@@ -3993,18 +4182,27 @@ def _prepare_restore_one(  # spmd-pure
         np_dtype = string_to_dtype(entry.dtype)
         if _is_jax_array(live) and list(live.shape) == list(entry.shape):
             sharding = live.sharding
-            buffers = alloc_target_shards(sharding, entry.shape, np_dtype)
-            targets = [(buf, off, sz) for buf, off, sz in buffers.values()]
-            reqs = ShardedArrayIOPreparer.prepare_read(
-                entry,
-                targets,
-                buffer_size_limit_bytes,
-                frame_tables=frame_tables,
-                digests=digests,
-                fresh_targets=True,
+            rects = target_shard_rects(sharding, entry.shape)
+
+            def plan(targets: List[np.ndarray]) -> List[ReadReq]:
+                return ShardedArrayIOPreparer.prepare_read(
+                    entry,
+                    [(buf, off, sz) for buf, (off, sz) in zip(targets, rects)],
+                    buffer_size_limit_bytes,
+                    frame_tables=frame_tables,
+                    digests=digests,
+                    fresh_targets=True,
+                )
+
+            held = _host_targets(
+                arena, live, [(tuple(sz), np_dtype) for _off, sz in rects], plan, times
             )
 
             def finalize_sharded() -> None:
+                buffers = {
+                    tuple(off): (buf, off, sz)
+                    for buf, (off, sz) in zip(held.targets(), rects)
+                }
                 with _placing(
                     times, logical_path, sharding, entry.shape, np_dtype.itemsize
                 ):
@@ -4014,8 +4212,9 @@ def _prepare_restore_one(  # spmd-pure
                         lambda: assemble_jax_array(sharding, entry.shape, buffers),
                         times,
                     )
+                held.placed(loaded[logical_path])
 
-            return reqs, finalize_sharded
+            return held.reqs, finalize_sharded
         # No live sharded target: materialize the full array on host.
         in_place = (
             isinstance(live, np.ndarray)
@@ -4024,7 +4223,10 @@ def _prepare_restore_one(  # spmd-pure
             and live.flags["C_CONTIGUOUS"]
             and live.flags["WRITEABLE"]
         )
-        target = live if in_place else np.empty(tuple(entry.shape), dtype=np_dtype)
+        if in_place:
+            target = live
+        else:
+            (target,) = _fresh_targets(times, [(tuple(entry.shape), np_dtype)])
         reqs = ShardedArrayIOPreparer.prepare_read(
             entry,
             [(target, [0] * len(entry.shape), list(entry.shape))],
