@@ -82,7 +82,8 @@ def test_a_new_ratio_metric_is_listed_for_the_cells_that_can_read_it(name):
     bench = run.load_json(ROOT, "BENCHMARK.json")
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
     save = {w["name"] for w in bench["workloads"] if w["traffic"].startswith("save_") and w["chips"] == 1}
-    assert set(entry["workloads"]) == (set(bench["workloads"][i]["name"] for i in (1, 2)) if "restore" in name else save)
+    restore = {w["name"] for w in bench["workloads"] if w["traffic"] in ("resume", "save_reshard")}
+    assert set(entry["workloads"]) == (restore if "restore" in name else save)
     assert entry["moves"] == ("restore_gbps" if "restore" in name else "goodput_pct")
 
 
