@@ -361,6 +361,58 @@ def test_foreground_restore_preempts_background_drain(tmp_path) -> None:
         assert np.array_equal(back[f"w{i}"], drain_state[f"w{i}"])
 
 
+def test_drains_through_the_fs_plugin_borrow_their_bounce_buffers_and_return_them(
+    tmp_path,
+) -> None:
+    """A synchronous take, then a background drain beside a foreground
+    restore, every leaf through the native engine: the engine lends the
+    writes its bounce buffers, never more of them than the plugin has
+    writer slots, has them all back when the drains end, and the drains'
+    stats say into which pages every written byte was copied."""
+    import json
+    import os
+
+    from torchsnapshot_tpu import native
+
+    lib = native.load_native()
+    if lib is None:
+        pytest.skip("native IO engine unavailable")
+    rng = np.random.default_rng(11)
+    state = StateDict(
+        **{f"w{i}": rng.standard_normal((64, 256)).astype(np.float32)
+           for i in range(8)}
+    )
+    before = native.write_bounce_stats(lib)
+    with knobs.override_direct_io_threshold_bytes(1024), knobs.override_qos_poll_s(0.005):
+        Snapshot.take(str(tmp_path / "sync"), {"m": state})
+        pending = Snapshot.async_take(
+            str(tmp_path / "bg"), {"m": state}, qos="background"
+        )
+        restored = StateDict(
+            **{f"w{i}": np.zeros((64, 256), dtype=np.float32) for i in range(8)}
+        )
+        Snapshot(str(tmp_path / "sync")).restore({"m": restored}, qos="foreground")
+        pending.wait()
+    after = native.write_bounce_stats(lib)
+    slots = knobs.get_direct_io_concurrency()
+    assert after["lent"] == 0 and after["kept"] <= max(slots, before["kept"])
+    assert after["allocated"] - before["allocated"] <= slots
+    assert after["kept_bytes"] <= 256 << 20
+    for name in ("sync", "bg"):
+        with open(os.path.join(tmp_path, name, ".telemetry", "rank_0.json")) as f:
+            artifact = json.load(f)
+        stats = artifact["pipeline_stats_s"]
+        # (Not ==: a synchronous take's stats leave out a pwrite that began
+        # between its two accounting windows.)
+        assert 0 < stats["mount_write_bytes"] <= sum(v.nbytes for v in state.values())
+        bounced = stats["write_bounce_warm_bytes"] + stats["write_bounce_fresh_bytes"]
+        # 0: a filesystem that refuses O_DIRECT, where nothing is bounced.
+        assert bounced in (0.0, stats["mount_write_bytes"])
+        assert Snapshot(str(tmp_path / name)).verify() == {}
+    for i in range(8):
+        assert np.array_equal(restored[f"w{i}"], state[f"w{i}"])
+
+
 def test_preemption_is_thread_safe_across_event_loops() -> None:
     """The arbiter is consulted from two event loops on two threads (the
     production shape: drain thread + main-thread restore) without locks

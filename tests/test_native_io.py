@@ -648,6 +648,7 @@ _TAKE_KEYS = (
     "write_work_sum_s", "write_queue_sum_s",
     "mount_write_s", "mount_write_sum_s", "mount_write_bytes",
     "write_copy_sum_s", "write_crc_sum_s",
+    "write_bounce_warm_bytes", "write_bounce_fresh_bytes",
     "stage_gather_s", "stage_gather_sum_s", "stage_d2h_sum_s",
 )
 _RESTORE_KEYS = ("pread_busy_s", "pread_sum_s", "reader_copy_sum_s")
@@ -691,6 +692,12 @@ def test_a_take_says_what_its_writers_and_lanes_seconds_are_made_of(taken, view)
     eps = 1e-5  # the artifact rounds to microseconds
     native_bytes = artifact["metrics"]["storage.fs.native_write_bytes"]
     assert stats["mount_write_bytes"] == native_bytes == tree["pieced"].nbytes + tree["whole"].nbytes
+    # Every byte a pwrite took had been copied into a bounce buffer, into
+    # pages that were warm or fresh (neither where the mount refuses O_DIRECT).
+    bounced = stats["write_bounce_warm_bytes"] + stats["write_bounce_fresh_bytes"]
+    assert bounced in (0.0, stats["mount_write_bytes"])
+    assert artifact["metrics"]["storage.fs.bounce_warm_bytes"] == stats["write_bounce_warm_bytes"]
+    assert artifact["metrics"]["storage.fs.bounce_fresh_bytes"] == stats["write_bounce_fresh_bytes"]
     inside = stats["mount_write_sum_s"] + stats["write_copy_sum_s"] + stats["write_crc_sum_s"]
     assert 0.0 < inside <= stats["write_work_sum_s"] + eps
     assert stats["write_queue_sum_s"] >= 0.0
@@ -763,5 +770,300 @@ def test_a_plugin_write_handed_a_sink_tells_it_what_the_engine_did(lib, tmp_path
     ((handed, held, n),), ((held_too, done, _),) = got["write_queue"], got["write_work"]
     assert handed <= held == held_too < done and n == nbytes
     assert sum(taken for _, _, taken in got["mount_write"]) == nbytes
+    # The same pwrites again, with the bytes of each that were copied into
+    # warm pages of the lent buffer, and into fresh ones.
+    assert [iv[:2] for iv in got["bounce_warm"]] == [iv[:2] for iv in got["bounce_fresh"]] == [iv[:2] for iv in got["mount_write"]]
+    bounced = [warm[2] + fresh[2] for warm, fresh in zip(got["bounce_warm"], got["bounce_fresh"])]
+    assert bounced in ([taken for _, _, taken in got["mount_write"]], [0] * len(bounced))
     for kind in ("write_copy", "mount_write", "write_crc"):
         assert got[kind] and all(held <= t0 <= t1 <= done for t0, t1, _ in got[kind]), kind
+
+
+# ------------------------------------------------- the write side's bounce
+#
+# A direct write copies its chunks into a bounce buffer the engine lends it
+# and keeps between writes, so the copy lands in pages an earlier write
+# touched. CPU runs: counts of buffers and bytes, never a rate.
+
+_OVER_THE_CAP = (256 << 20) + 4096
+
+
+@pytest.fixture
+def nothing_kept(lib, tmp_path):
+    """The engine's list of kept bounce buffers emptied, with what its gauges
+    read then. A write whose chunk is over the engine's cap on kept memory
+    drops the kept buffer it finds too small and, at its end, its own (of
+    which it touched one page)."""
+    while native.write_bounce_stats(lib)["kept"]:
+        native.write_file(lib, str(tmp_path / "flush"), b"\0" * 4096, direct=True, chunk_bytes=_OVER_THE_CAP)
+    stats = native.write_bounce_stats(lib)
+    assert stats["lent"] == stats["kept"] == stats["kept_bytes"] == 0
+    return stats
+
+
+def _written(lib, path, data, chunk, write=native.write_file):
+    """``data`` through the engine under O_DIRECT: its stamps."""
+    chunks = []
+    write(lib, path, data, direct=True, chunk_bytes=chunk, stamps=chunks)
+    if not sum(c[5] + c[6] for c in chunks) and len(data) >= 4096:
+        pytest.skip("this filesystem refuses O_DIRECT: nothing is bounced")
+    return chunks
+
+
+def test_a_second_and_a_tenth_write_allocate_nothing(lib, tmp_path, nothing_kept) -> None:
+    chunk = 1 << 20
+    data = np.random.default_rng(0).integers(0, 256, size=3 * chunk + 17, dtype=np.uint8)
+    first = _written(lib, str(tmp_path / "w0"), data, chunk)
+    # Its own buffer, new: the first chunk's copy is the pages' first touch,
+    # the later chunks of the same object land in them.
+    assert [(c[5], c[6]) for c in first] == [(0, chunk), (chunk, 0), (chunk, 0), (17, 0)]
+    one = dict(nothing_kept, allocated=nothing_kept["allocated"] + 1, kept=1, kept_bytes=chunk)
+    assert native.write_bounce_stats(lib) == one
+    for k in range(1, 10):
+        again = _written(lib, str(tmp_path / f"w{k}"), data, chunk)
+        assert sum(c[6] for c in again) == 0 and sum(c[5] for c in again) == data.size
+        assert native.write_bounce_stats(lib) == one
+        with open(tmp_path / f"w{k}", "rb") as f:
+            assert f.read() == data.tobytes()
+
+
+_CHUNK = 64 * 1024
+
+
+@pytest.mark.parametrize("digest", [True, False], ids=["digest", "plain"])
+@pytest.mark.parametrize(
+    "nbytes",
+    [4095, 4096, 4097, _CHUNK - 1, _CHUNK, _CHUNK + 1, 2 * _CHUNK + 4097, 100],
+)
+def test_nothing_of_an_earlier_object_reaches_a_later_file(lib, tmp_path, nothing_kept, nbytes, digest) -> None:
+    """A kept buffer holds the last object's bytes: the file written through
+    it is its source byte for byte and ends where the source does."""
+    import zlib
+
+    _written(lib, str(tmp_path / "ff"), np.full(4 * _CHUNK, 0xFF, np.uint8), _CHUNK)
+    before = native.write_bounce_stats(lib)
+    assert before["kept"] == 1
+    data = np.random.default_rng(nbytes).integers(0, 0xFF, size=nbytes, dtype=np.uint8)  # no 0xFF of its own
+    path, got = str(tmp_path / "later"), []
+
+    def write(*args, **kwargs):
+        got.append((native.write_file_digest if digest else native.write_file)(*args, **kwargs))
+
+    chunks = _written(lib, path, data, _CHUNK, write)
+    assert native.write_bounce_stats(lib) == before  # the same buffer, back again
+    if nbytes >= 4096:  # under a sector the engine writes buffered: no bounce
+        assert sum(c[5] for c in chunks) == nbytes and sum(c[6] for c in chunks) == 0
+    assert os.path.getsize(path) == nbytes
+    with open(path, "rb") as f:
+        assert f.read() == data.tobytes()
+    assert got == [[zlib.crc32(data), nbytes, None] if digest else None]
+
+
+def test_two_writes_at_once_hold_two_buffers_and_both_are_kept(lib, tmp_path, nothing_kept) -> None:
+    import threading
+
+    chunk, nbytes = 64 * 1024, 8 << 20
+    datas = [np.random.default_rng(i).integers(0, 256, size=nbytes + i, dtype=np.uint8) for i in range(2)]
+    for attempt in range(20):
+        stamps, errors, gate = [[], []], [], threading.Barrier(2)
+
+        def write(i: int) -> None:
+            try:
+                gate.wait(timeout=60)
+                native.write_file(lib, str(tmp_path / f"at_once{i}"), datas[i], direct=True, chunk_bytes=chunk, stamps=stamps[i])
+            except BaseException as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=write, args=(i,)) for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not errors and not any(t.is_alive() for t in threads)
+        for i in range(2):
+            with open(tmp_path / f"at_once{i}", "rb") as f:
+                assert f.read() == datas[i].tobytes()
+        if not sum(c[5] + c[6] for c in stamps[0]):
+            pytest.skip("this filesystem refuses O_DIRECT: nothing is bounced")
+        stats = native.write_bounce_stats(lib)
+        assert stats["lent"] == 0 and stats["kept"] == stats["allocated"] - nothing_kept["allocated"] <= 2
+        # Each held its buffer from its first pwrite to its last: where those
+        # spans overlap, two buffers were out at once.
+        held = [(s[0][1], s[-1][3]) for s in stamps]
+        if max(h[0] for h in held) < min(h[1] for h in held):
+            assert stats["kept"] == 2 and stats["kept_bytes"] == 2 * chunk
+            return
+    pytest.fail("two 8 MiB writes started together never overlapped in 20 attempts")
+
+
+def test_six_writers_under_two_slots_never_need_a_third_buffer(lib, tmp_path, nothing_kept) -> None:
+    """As ``fs.py`` drives the engine: more callers than writer slots, a
+    shortened switch interval, and every file exact."""
+    import sys
+    import threading
+
+    chunk = 64 * 1024
+    slots = threading.Semaphore(2)
+    datas = [np.random.default_rng(i).integers(0, 256, size=(1 << 20) + 4097 * i + 1, dtype=np.uint8) for i in range(6)]
+    errors = []
+
+    def write(i: int) -> None:
+        try:
+            for k in range(4):
+                with slots:
+                    native.write_file(lib, str(tmp_path / f"s{i}_{k}"), datas[i], direct=True, chunk_bytes=chunk)
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=write, args=(i,)) for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    for i in range(6):
+        for k in range(4):
+            with open(tmp_path / f"s{i}_{k}", "rb") as f:
+                assert f.read() == datas[i].tobytes()
+    stats = native.write_bounce_stats(lib)
+    assert stats["lent"] == 0 and 1 <= stats["kept"] <= 2
+    assert stats["allocated"] - nothing_kept["allocated"] == stats["kept"]
+    assert stats["kept_bytes"] == stats["kept"] * chunk
+
+
+def test_a_write_that_fails_mid_object_and_its_retry_return_what_they_borrowed(lib, tmp_path, nothing_kept) -> None:
+    """The second chunk's ``pwrite`` is cut short by the file size limit and
+    the next one refused (EFBIG; the interpreter ignores SIGXFSZ)."""
+    import resource
+
+    chunk = 64 * 1024
+    data = np.random.default_rng(1).integers(0, 256, size=3 * chunk + 5, dtype=np.uint8)
+    path = str(tmp_path / "limited")
+    _written(lib, str(tmp_path / "probe"), data, chunk)  # skips where nothing is bounced
+    primed = native.write_bounce_stats(lib)
+    soft, hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    resource.setrlimit(resource.RLIMIT_FSIZE, (chunk + 8192, hard))
+    try:
+        with pytest.raises(OSError) as e:
+            native.write_file(lib, path, data, direct=True, chunk_bytes=chunk, stamps=[])
+    finally:
+        resource.setrlimit(resource.RLIMIT_FSIZE, (soft, hard))
+    assert e.value.errno == errno.EFBIG
+    assert os.path.getsize(path) == chunk + 8192  # it failed mid-object
+    assert native.write_bounce_stats(lib) == primed
+    again = _written(lib, path, data, chunk)
+    assert sum(c[5] for c in again) == data.size
+    assert native.write_bounce_stats(lib) == primed
+    with open(path, "rb") as f:
+        assert f.read() == data.tobytes()
+
+
+def test_a_take_whose_writes_fail_and_retry_leaves_no_buffer_lent(lib, tmp_path) -> None:
+    """The fault schedule's ``op=write``: three writes fail before they
+    reach the engine and are retried; the take commits, restores bit for
+    bit, and every buffer the engine lent is back."""
+    from torchsnapshot_tpu import Snapshot, StateDict
+    from torchsnapshot_tpu import snapshot as snapshot_mod
+
+    arrs = {f"a{i}": np.random.default_rng(i).standard_normal(70_000).astype(np.float32) for i in range(4)}
+    path = str(tmp_path / "snap")
+    with knobs.override_direct_io_threshold_bytes(1024), knobs.override_faults(
+        "backoff=0.01;op=write,kind=transient,times=3,path=0/s"
+    ):
+        Snapshot.take(path, {"s": StateDict(**arrs)})
+    metrics = Snapshot.last_telemetry.metrics.as_dict()
+    assert metrics["faults.transient"] == 3
+    # The leaves' writes are the take's own and stamped; the counters say
+    # what the drain's stats say.
+    stats = snapshot_mod.LAST_SYNC_DRAIN_STATS
+    bounced = stats["write_bounce_warm_bytes"] + stats["write_bounce_fresh_bytes"]
+    counted = metrics["storage.fs.bounce_warm_bytes"] + metrics["storage.fs.bounce_fresh_bytes"]
+    assert counted in (0, sum(a.nbytes for a in arrs.values()))
+    # (A synchronous take's stats leave out a pwrite that began between its
+    # two accounting windows; the counters leave out none.)
+    assert 0 < stats["mount_write_bytes"] <= sum(a.nbytes for a in arrs.values())
+    assert bounced in (0, stats["mount_write_bytes"])  # 0: no O_DIRECT here
+    stats = native.write_bounce_stats(lib)
+    assert stats["lent"] == 0 and stats["kept"] <= knobs.get_direct_io_concurrency()
+    back = StateDict(**{k: np.zeros_like(v) for k, v in arrs.items()})
+    Snapshot(path).restore({"s": back})
+    assert all(np.array_equal(back[k], v) for k, v in arrs.items())
+
+
+def test_a_larger_chunk_reallocates_once_and_a_smaller_one_not_at_all(lib, tmp_path, nothing_kept) -> None:
+    data = np.random.default_rng(2).integers(0, 256, size=(1 << 20) + 3, dtype=np.uint8)
+    allocated = nothing_kept["allocated"]
+    for chunk, grown in [(64 * 1024, 1), (256 * 1024, 2), (256 * 1024, 2), (64 * 1024, 2), (256 * 1024, 2)]:
+        _written(lib, str(tmp_path / "obj"), data, chunk)
+        want = dict(allocated=allocated + grown, lent=0, kept=1, kept_bytes=(64 * 1024, 256 * 1024)[grown - 1])
+        assert native.write_bounce_stats(lib) == want, (chunk, grown)
+        with open(tmp_path / "obj", "rb") as f:
+            assert f.read() == data.tobytes()
+
+
+def test_a_buffer_over_the_cap_is_not_kept(lib, tmp_path, nothing_kept) -> None:
+    """No more than 256 MiB stays with the engine, whatever chunk is asked."""
+    data = os.urandom(8192)
+    native.write_file(lib, str(tmp_path / "big_chunk"), data, direct=True, chunk_bytes=_OVER_THE_CAP)
+    stats = native.write_bounce_stats(lib)
+    assert stats["kept"] == stats["kept_bytes"] == stats["lent"] == 0
+    with open(tmp_path / "big_chunk", "rb") as f:
+        assert f.read() == data
+
+
+def test_a_forked_child_writes_through_a_list_of_its_own(lib, tmp_path) -> None:
+    """A kept buffer's pages are the parent's: a child copying into them
+    would fault each in anew, so it starts with none, and with no write of
+    the parent's counted as its own."""
+    import subprocess
+    import sys
+    import textwrap
+
+    script = textwrap.dedent(
+        f"""
+        import os, sys
+        import numpy as np
+        from torchsnapshot_tpu import native
+
+        lib = native.load_native()
+        data = np.random.default_rng(3).integers(0, 256, size=(1 << 20) + 3, dtype=np.uint8)
+
+        def write(name):
+            chunks = []
+            native.write_file(lib, os.path.join({str(tmp_path)!r}, name), data, direct=True, chunk_bytes=65536, stamps=chunks)
+            with open(os.path.join({str(tmp_path)!r}, name), "rb") as f:
+                assert f.read() == data.tobytes()
+            return sum(c[6] for c in chunks), native.write_bounce_stats(lib)
+
+        fresh, stats = write("parent0")
+        direct = fresh > 0  # else this filesystem refuses O_DIRECT
+        assert stats == dict(allocated=direct, lent=0, kept=direct, kept_bytes=65536 * direct), stats
+        pid = os.fork()
+        if pid == 0:
+            before = native.write_bounce_stats(lib)
+            fresh, after = write("child")
+            ok = (
+                before == dict(allocated=0, lent=0, kept=0, kept_bytes=0)
+                and fresh == 65536 * direct
+                and after == dict(allocated=direct, lent=0, kept=direct, kept_bytes=65536 * direct)
+            )
+            os._exit(0 if ok else 1)
+        _, status = os.waitpid(pid, 0)
+        fresh, stats = write("parent1")
+        assert fresh == 0 and stats["allocated"] == direct, stats
+        sys.exit(os.waitstatus_to_exitcode(status))
+        """
+    )
+    done = subprocess.run(
+        [sys.executable, "-W", "ignore", "-c", script],
+        timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True,
+        text=True,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]

@@ -23,7 +23,7 @@ for _name in ("test_architecture", "test_contract"):
 pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
-# ------------------------------------------------ the metrics PR 40 brought
+# --------------------------- the metrics PR 40 brought, and PR 43's one
 #
 # Each reads what the take's artifact and ``LAST_RESTORE_STATS`` say of the
 # seconds inside the native engine's calls; against a program that stamps
@@ -34,7 +34,7 @@ from perfbench import readers  # noqa: E402
 _DRAIN = {
     "wall_s": 5.0, "io_busy_s": 4.8, "mount_write_s": 2.0, "mount_write_sum_s": 3.0,
     "mount_write_bytes": 3.0e9, "write_work_sum_s": 8.0, "write_copy_sum_s": 4.0,
-    "write_crc_sum_s": 0.5, "write_queue_sum_s": 24.0,
+    "write_crc_sum_s": 0.5, "write_queue_sum_s": 24.0, "write_bounce_warm_bytes": 2.7e9,
     "stage_d2h_sum_s": 10.0, "stage_gather_sum_s": 6.0,
 }
 _RESTORE = {
@@ -44,7 +44,7 @@ _RESTORE = {
 _RATIO_METRICS = {
     "io_mount_write_busy_pct": 40.0, "io_mount_write_gbps": 1.5, "io_mount_write_depth": 1.5,
     "io_writer_mount_pct": 37.5, "io_writer_copy_pct": 50.0, "io_writer_crc_pct": 6.25,
-    "io_write_queued_pct": 75.0, "stage_d2h_gather_pct": 60.0,
+    "io_write_queued_pct": 75.0, "stage_d2h_gather_pct": 60.0, "io_bounce_warm_pct": 90.0,
     "restore_pread_gbps": 3.2, "restore_pread_depth": 4.0, "restore_reader_copy_pct": 62.5,
 }
 _LIFT_METRICS = {

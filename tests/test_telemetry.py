@@ -304,6 +304,8 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
         "mount_write_bytes",
         "write_copy_sum_s",
         "write_crc_sum_s",
+        "write_bounce_warm_bytes",
+        "write_bounce_fresh_bytes",
     } == set(snapshot_mod.LAST_SYNC_DRAIN_STATS)
 
     # Scheduler stage/io spans.

@@ -119,7 +119,10 @@ class WriteTimes:
     ``RestoreTimes`` is for a restore's reads. Every interval is on
     ``time.monotonic()``'s clock, as ``(t0, t1, nbytes)``."""
 
-    KINDS = ("write_queue", "write_work", "write_copy", "mount_write", "write_crc")
+    KINDS = (
+        "write_queue", "write_work", "write_copy", "mount_write", "write_crc",
+        "bounce_warm", "bounce_fresh",
+    )
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -133,7 +136,7 @@ class WriteTimes:
         held: float,
         done: float,
         nbytes: int,
-        chunks: List[Tuple[float, float, float, float, float]],
+        chunks: List[Tuple[float, float, float, float, float, float, float]],
     ) -> None:
         """One write of the fs plugin through the native engine, stamped on
         the writing thread: ``handed`` to the plugin's executor, ``held``
@@ -141,17 +144,24 @@ class WriteTimes:
         ``storage.write_work`` span (``write_queue`` before it,
         ``write_work`` after it), ``done`` when the engine call had
         returned, the GIL taken again; ``chunks`` the engine's own stamps
-        (``native.WriteChunk``): each chunk's copy into the bounce buffer,
-        its ``pwrite`` (``mount_write``, with the bytes it took) and its crc.
+        (``native.WriteChunk``): each chunk's copy into the bounce buffer the
+        engine lent the write (warm from the write before, not allocated for
+        the object), its ``pwrite`` (``mount_write``, with the bytes it took)
+        and its crc; ``bounce_warm`` / ``bounce_fresh`` are the ``pwrite``'s
+        interval again with the bytes of it that were copied into pages of the
+        buffer an earlier copy had written, and into pages none had (they sum
+        to ``mount_write``'s where the mount takes ``O_DIRECT``).
         No span of their own: ``storage.write_work`` stays one an object."""
         with self._lock:
             w = self._intervals
             w["write_queue"].append((handed, held, nbytes))
             w["write_work"].append((held, done, nbytes))
-            for t_copy, t_mount, t_crc, t_end, taken in chunks:
+            for t_copy, t_mount, t_crc, t_end, taken, warm, fresh in chunks:
                 w["write_copy"].append((t_copy, t_mount, 0))
                 w["mount_write"].append((t_mount, t_crc, int(taken)))
                 w["write_crc"].append((t_crc, t_end, 0))
+                w["bounce_warm"].append((t_mount, t_crc, int(warm)))
+                w["bounce_fresh"].append((t_mount, t_crc, int(fresh)))
 
     def intervals(self) -> Dict[str, List[Tuple[float, float, int]]]:
         """A snapshot copy per kind (safe to merge and clip while writes run)."""

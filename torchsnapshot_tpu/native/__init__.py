@@ -108,6 +108,8 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tss_read_pool_configure.restype = ctypes.c_int
     lib.tss_read_pool_stats.argtypes = [ctypes.POINTER(ctypes.c_uint64 * 6)]
     lib.tss_read_pool_stats.restype = None
+    lib.tss_write_bounce_stats.argtypes = [ctypes.POINTER(ctypes.c_uint64 * 4)]
+    lib.tss_write_bounce_stats.restype = None
     lib.tss_file_size.argtypes = [ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
     lib.tss_file_size.restype = ctypes.c_int
     lib.tss_write_file_digest.argtypes = [
@@ -233,15 +235,18 @@ def _buf_address(mv: memoryview) -> int:
 
 # What the engine stamps, GIL-free, on ``time.monotonic()``'s clock
 # (``tss_io.cpp``): a write chunk as ``(t_copy, t_mount, t_crc, t_end,
-# nbytes)``: the writing thread copied into the bounce buffer over ``[t_copy,
-# t_mount)``, was in ``pwrite`` over ``[t_mount, t_crc)`` and hashed over
-# ``[t_crc, t_end)``; ``nbytes`` is what the ``pwrite`` took of the object. A
-# read chunk as ``(t0, t1, p0, p1)``: the reader thread had the chunk over
-# ``[t0, t1)`` (the ``pread`` into its bounce buffer and the copy out of it
-# into the destination's pages) and was in ``pread`` over ``[p0, p1)``.
-WriteChunk = Tuple[float, float, float, float, float]
+# nbytes, warm, fresh)``: the writing thread copied into the bounce buffer over
+# ``[t_copy, t_mount)``, was in ``pwrite`` over ``[t_mount, t_crc)`` and hashed
+# over ``[t_crc, t_end)``; ``nbytes`` is what the ``pwrite`` took of the
+# object, ``warm`` of them copied into pages of the borrowed buffer that an
+# earlier copy had written and ``fresh`` into pages none had (both 0 for a
+# buffered chunk, which has no copy). A read chunk as ``(t0, t1, p0, p1)``:
+# the reader thread had the chunk over ``[t0, t1)`` (the ``pread`` into its
+# bounce buffer and the copy out of it into the destination's pages) and was
+# in ``pread`` over ``[p0, p1)``.
+WriteChunk = Tuple[float, float, float, float, float, float, float]
 ReadChunk = Tuple[float, float, float, float]
-_WRITE_STAMP_DOUBLES = 5
+_WRITE_STAMP_DOUBLES = 7
 _READ_STAMP_DOUBLES = 4
 
 
@@ -388,6 +393,15 @@ def read_pool_stats(lib: ctypes.CDLL) -> Dict[str, int]:
     lib.tss_read_pool_stats(ctypes.byref(out))
     keys = ("depth", "in_flight", "high_water", "buffers", "buffer_bytes", "chunks_read")
     return dict(zip(keys, out))
+
+
+def write_bounce_stats(lib: ctypes.CDLL) -> Dict[str, int]:
+    """The gauges of the bounce buffers the engine lends to direct writes:
+    ``allocated`` since the process (or its fork) began, ``lent`` to a write
+    now, ``kept`` for the next one and the ``kept_bytes`` of those."""
+    out = (ctypes.c_uint64 * 4)()
+    lib.tss_write_bounce_stats(ctypes.byref(out))
+    return dict(zip(("allocated", "lent", "kept", "kept_bytes"), out))
 
 
 def file_size(lib: ctypes.CDLL, path: str) -> int:

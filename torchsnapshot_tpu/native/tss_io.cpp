@@ -7,9 +7,11 @@
 // a bounce buffer, falling back to buffered I/O wherever O_DIRECT is
 // unsupported (tmpfs, overlayfs, unaligned tails). The rates on record are
 // those of the chip machine's 9p mount (PERF.md; it has no block device): a
-// native write 0.6-0.9 GB/s an object, two at a time, timed around the whole
-// call (the copy into the bounce buffer, the pwrite and the crc in series on
-// one thread: the stamps below tell them apart); a native read 0.5-0.6
+// native write 2.5 GB/s an object through a bounce buffer the engine kept
+// warm (0.6-0.9 while every object first touched one of its own: PERF.md
+// section 6, PR 43), two at a time, timed around the whole call (the copy
+// into the bounce buffer, the pwrite and the crc in series on one thread:
+// the stamps below tell them apart); a native read 0.5-0.6
 // GB/s as one serial stream, 3.4-3.9 GB/s as eight 4 MiB chunk reads in
 // flight, 1.2-1.4 GB/s buffered or landing in a fresh destination with no
 // bounce buffer (probe of PR 29).
@@ -55,6 +57,9 @@
 namespace {
 
 constexpr uint64_t kAlign = 4096;  // covers 512/4096 logical sector sizes
+// The most bounce memory either side keeps: the reader threads' buffers
+// together, and the write side's kept ones together.
+constexpr uint64_t kMaxBounceBytes = 256ull << 20;
 
 uint64_t align_up(uint64_t v) { return (v + kAlign - 1) / kAlign * kAlign; }
 uint64_t align_down(uint64_t v) { return v / kAlign * kAlign; }
@@ -65,19 +70,24 @@ double monotonic_s() {
   return static_cast<double>(ts.tv_sec) + 1e-9 * static_cast<double>(ts.tv_nsec);
 }
 
-// What the writing thread did with each chunk, when the caller asks: five
-// doubles a chunk. [0] to [1] it copied into the bounce buffer (the buffer's
-// allocation on the first chunk, the memcpy, the tail's memset), [1] to [2]
-// it was in pwrite (retries included), [2] to [3] it hashed (nothing where
-// the caller wants no digest); [4] is the bytes of the object the pwrite
-// took. A buffered write has no copy: [0] == [1].
-constexpr uint64_t kWriteStampDoubles = 5;
+// What the writing thread did with each chunk, when the caller asks: seven
+// doubles a chunk. [0] to [1] it copied into the bounce buffer (borrowing the
+// buffer on the first chunk, the memcpy, the tail's memset), [1] to [2] it
+// was in pwrite (retries included), [2] to [3] it hashed (nothing where the
+// caller wants no digest); [4] is the bytes of the object the pwrite took,
+// and of those [5] had been copied into pages of the buffer that a copy had
+// written before (warm) and [6] into pages no copy had (fresh: the copy was
+// their first touch). A buffered write has no copy: [0] == [1], [5] == [6]
+// == 0.
+constexpr uint64_t kWriteStampDoubles = 7;
 
 struct WriteStamps {
   std::vector<double> v;
-  void add(double copy0, double mount0, double crc0, double end, uint64_t nbytes) {
-    const double row[kWriteStampDoubles] = {copy0, mount0, crc0, end,
-                                            static_cast<double>(nbytes)};
+  void add(double copy0, double mount0, double crc0, double end, uint64_t nbytes,
+           uint64_t warm = 0, uint64_t fresh = 0) {
+    const double row[kWriteStampDoubles] = {
+        copy0, mount0, crc0, end, static_cast<double>(nbytes),
+        static_cast<double>(warm), static_cast<double>(fresh)};
     v.insert(v.end(), row, row + kWriteStampDoubles);
   }
 };
@@ -141,6 +151,102 @@ int read_buffered(int fd, char* dst, uint64_t nbytes, uint64_t off) {
   return 0;
 }
 
+// The bounce buffers of direct writes, lent and taken back. A write borrows
+// one at its first direct chunk and returns it when its chunks are done,
+// however they ended, so its copy lands in pages an earlier write has touched:
+// a first touch is what a writer spent two thirds of its seconds on while
+// every object had a buffer of its own (PERF.md section 6, PR 43). A borrow
+// allocates only where nothing is kept or the one kept is too small, so the
+// buffers in being are never more than the writes that were in flight at
+// once (the caller's cap: fs.py's semaphore), and those kept never more than
+// kMaxBounceBytes: one returned beyond that is freed. A kept buffer holds an
+// earlier object's bytes; write_impl writes out only what it has just copied
+// or zeroed.
+struct Bounce {
+  char* p = nullptr;
+  uint64_t cap = 0;
+  uint64_t touched = 0;  // how far into the buffer some copy has written
+};
+
+class BouncePool {
+ public:
+  // A buffer of `cap` bytes or more: the one returned last where it is large
+  // enough (one that is not goes), else a new one. `p` null: no memory.
+  Bounce borrow(uint64_t cap) {
+    Bounce b;
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      if (!kept_.empty()) {
+        b = kept_.back();
+        kept_.pop_back();
+        kept_bytes_ -= b.cap;
+      }
+      if (b.cap >= cap) {
+        ++lent_;
+        return b;
+      }
+    }
+    free(b.p);
+    void* p = nullptr;
+    if (posix_memalign(&p, kAlign, cap) != 0) return Bounce{};
+    std::lock_guard<std::mutex> g(mu_);
+    ++lent_;
+    ++allocated_;
+    return Bounce{static_cast<char*>(p), cap, 0};
+  }
+
+  void give_back(const Bounce& b) {
+    {
+      std::lock_guard<std::mutex> g(mu_);
+      --lent_;
+      if (kept_bytes_ + b.cap <= kMaxBounceBytes) {
+        kept_.push_back(b);
+        kept_bytes_ += b.cap;
+        return;
+      }
+    }
+    free(b.p);
+  }
+
+  void stats(uint64_t out[4]) {
+    std::lock_guard<std::mutex> g(mu_);
+    out[0] = allocated_;
+    out[1] = lent_;
+    out[2] = kept_.size();
+    out[3] = kept_bytes_;
+  }
+
+  void lock_for_fork() { mu_.lock(); }
+  void unlock_in_parent() { mu_.unlock(); }
+  // A forked child has none of the parent's writes, and a copy into a kept
+  // buffer would fault every page in anew (copy on write): it starts empty.
+  void empty_in_child() {
+    for (const Bounce& b : kept_) free(b.p);
+    kept_.clear();
+    kept_bytes_ = lent_ = allocated_ = 0;
+    mu_.unlock();
+  }
+
+ private:
+  std::mutex mu_;
+  std::vector<Bounce> kept_;
+  uint64_t kept_bytes_ = 0, lent_ = 0, allocated_ = 0;
+};
+
+// Never destroyed: a writer thread may outlive main().
+BouncePool* g_bounce = nullptr;
+
+BouncePool& bounce_pool() {
+  static BouncePool* const pool = [] {
+    g_bounce = new BouncePool();
+    pthread_atfork([] { g_bounce->lock_for_fork(); },
+                   [] { g_bounce->unlock_in_parent(); },
+                   [] { g_bounce->empty_in_child(); });
+    return g_bounce;
+  }();
+  return *pool;
+}
+
 // Shared implementation of the write entry points; `hc` (nullable) receives
 // a running crc32 over the bytes, updated chunk-by-chunk while the data is
 // cache-hot from the bounce-buffer copy; `st` (nullable) what the thread did
@@ -166,23 +272,29 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
   if (direct) {
     if (chunk_bytes < kAlign) chunk_bytes = 64ull << 20;
     chunk_bytes = align_down(chunk_bytes);
-    // Fresh for every object and freed after it: its pages are first
-    // touched by the copy below, which is why the allocation is the copy's.
-    void* bounce = nullptr;
+    // Borrowed at the first chunk, inside the copy's stamp (a new one's
+    // pages are first touched by the copy below), and returned after the
+    // last, whichever way the loop ends.
+    Bounce bounce;
     while (off < nbytes) {
       uint64_t n = std::min(chunk_bytes, nbytes - off);
       uint64_t padded = align_up(n);
       const double copy0 = now_if(st);
-      if (bounce == nullptr && posix_memalign(&bounce, kAlign, chunk_bytes) != 0) {
-        close(fd);
-        return -ENOMEM;
+      if (bounce.p == nullptr) {
+        bounce = bounce_pool().borrow(chunk_bytes);
+        if (bounce.p == nullptr) {
+          close(fd);
+          return -ENOMEM;
+        }
       }
-      memcpy(bounce, src + off, n);
-      if (padded > n) memset(static_cast<char*>(bounce) + n, 0, padded - n);
+      memcpy(bounce.p, src + off, n);
+      if (padded > n) memset(bounce.p + n, 0, padded - n);
+      const uint64_t touched = bounce.touched;
+      bounce.touched = std::max(touched, padded);
       const double mount0 = now_if(st);
       ssize_t w;
       do {
-        w = pwrite(fd, bounce, padded, off);
+        w = pwrite(fd, bounce.p, padded, off);
       } while (w < 0 && errno == EINTR);
       const int err = errno;
       const double crc0 = now_if(st);
@@ -192,12 +304,16 @@ int write_impl(const char* path, const void* buf, uint64_t nbytes,
       const uint64_t advanced =
           w < 0 ? 0 : std::min<uint64_t>(align_down(static_cast<uint64_t>(w)), n);
       if (hc && advanced > 0) hc->update(src + off, advanced);
-      if (st) st->add(copy0, mount0, crc0, hc && advanced > 0 ? monotonic_s() : crc0, advanced);
+      if (st) {
+        const uint64_t warm = std::min(advanced, touched);
+        st->add(copy0, mount0, crc0, hc && advanced > 0 ? monotonic_s() : crc0,
+                advanced, warm, advanced - warm);
+      }
       if (w < 0 && err != EINVAL) rc = -err;  // EINVAL: O_DIRECT rejected mid-stream
       if (advanced == 0) break;
       off += advanced;
     }
-    free(bounce);
+    if (bounce.p != nullptr) bounce_pool().give_back(bounce);
     if (rc == 0 && off < nbytes) {
       // Finish buffered (EINVAL fallback or zero-length write).
       int fd2 = open(path, O_WRONLY, 0644);
@@ -243,7 +359,6 @@ int give_write_stamps(int rc, const WriteStamps& st, double** stamps_out,
 // for its lifetime, so the pool holds at most depth x (chunk + one sector),
 // and the chunk is clamped so that this stays under kMaxBounceBytes.
 
-constexpr uint64_t kMaxBounceBytes = 256ull << 20;
 constexpr uint64_t kReadStampDoubles = 4;
 // The depth a new pool starts with: what tss_read_pool_configure last set, so
 // that a forked child's pool is sized as its parent's was.
@@ -483,12 +598,12 @@ ReadPool& pool() {
 
 extern "C" {
 
-int tss_io_version() { return 5; }
+int tss_io_version() { return 6; }
 
 // Create/truncate `path` and write `nbytes` from `buf`.
 // use_direct != 0 attempts O_DIRECT via an aligned bounce buffer of
 // chunk_bytes; any O_DIRECT failure falls back to buffered I/O and the write
-// still succeeds. `stamps_out`, when given, receives an array of five
+// still succeeds. `stamps_out`, when given, receives an array of seven
 // doubles a chunk (`*chunks_out` chunks; the caller releases it with
 // tss_free): what the writing thread did with the chunk and when, on
 // CLOCK_MONOTONIC (WriteStamps above). Null: the call reads no clock.
@@ -590,6 +705,11 @@ int tss_read_pool_configure(int depth) {
 }
 
 void tss_free(void* p) { free(p); }
+
+// Gauges of the write side's bounce buffers: allocated since the process (or
+// its fork) began, lent to a write now, kept for the next one, the bytes of
+// those kept.
+void tss_write_bounce_stats(uint64_t out[4]) { bounce_pool().stats(out); }
 
 // Gauges of the reader pool: depth, chunk reads in flight now, the most ever
 // in flight since the last configure, bounce buffers held, their bytes,

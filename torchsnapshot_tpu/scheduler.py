@@ -765,16 +765,24 @@ class _WritePipeline:
             # not own and is in io_busy_small_s only): the union and the sum
             # of the pwrites with their bytes, and the sums of the
             # storage.write_work intervals, of the copies into the bounce
-            # buffer, of the crc and of the waits for a writer slot.
-            mount = written["mount_write"]
-            stats["mount_write_s"] = _busy_in(_merge_intervals(mount), wins)
-            stats["mount_write_bytes"] = float(
-                sum(
-                    nbytes
-                    for t0, _, nbytes in mount
-                    if any(w0 <= t0 < w1 for w0, w1 in wins)
-                )
+            # buffer, of the crc and of the waits for a writer slot; and of
+            # the pwrites' bytes, those copied into pages of a bounce buffer
+            # that were warm from an earlier copy, and into fresh ones.
+            stats["mount_write_s"] = _busy_in(
+                _merge_intervals(written["mount_write"]), wins
             )
+            for name, kind in (
+                ("mount_write_bytes", "mount_write"),
+                ("write_bounce_warm_bytes", "bounce_warm"),
+                ("write_bounce_fresh_bytes", "bounce_fresh"),
+            ):
+                stats[name] = float(
+                    sum(
+                        nbytes
+                        for t0, _, nbytes in written[kind]
+                        if any(w0 <= t0 < w1 for w0, w1 in wins)
+                    )
+                )
             for name, kind in (
                 ("write_work_sum_s", "write_work"),
                 ("mount_write_sum_s", "mount_write"),

@@ -10,9 +10,14 @@ repeats them).
 
 Concurrency: the event loop may have many plugin ops in flight; blocking work
 runs on a private thread pool. Writes: a semaphore caps concurrent native
-writes at ``knobs.get_direct_io_concurrency()`` objects; one that is handed a
-``WriteIO.times`` tells it how long it waited for its slot and what the engine
-did with each chunk (copy, ``pwrite``, crc). Reads: no semaphore
+writes at ``knobs.get_direct_io_concurrency()`` objects, and each copies its
+chunks into a bounce buffer the engine lends it and keeps between writes (as
+many buffers as writes were ever in flight at once, of
+``knobs.get_direct_io_chunk_bytes()`` each, at most 256 MiB kept), so the copy
+lands in pages an earlier write touched, not in ones new to every object; one
+that is handed a ``WriteIO.times`` tells it how long it waited for its slot
+and what the engine did with each chunk (copy, ``pwrite``, crc, and how many
+of the bytes were copied into warm pages). Reads: no semaphore
 around an object. A native read is chunk reads of ``_READ_CHUNK_BYTES`` on
 the engine's reader pool, and the cap, ``knobs.get_direct_read_depth()``,
 counts chunks on the mount for the whole process: those of one large leaf,
@@ -222,6 +227,14 @@ class FSStoragePlugin(StoragePlugin):
                         write_io.digest_out = digest
                     if times is not None:
                         times.record_native_write(handed, held, done, nbytes, chunks)
+                        telemetry.counter_add(
+                            "storage.fs.bounce_warm_bytes",
+                            int(sum(c[5] for c in chunks)),
+                        )
+                        telemetry.counter_add(
+                            "storage.fs.bounce_fresh_bytes",
+                            int(sum(c[6] for c in chunks)),
+                        )
 
                 await asyncio.get_running_loop().run_in_executor(
                     self._get_executor(), work
