@@ -16,7 +16,8 @@ cut, and a jitted train step that DONATES its state.
              the fork cannot fit, so capture degrades leaf by leaf. A step
              the fork leaves no room for is reported, not retried.
   programs   the device programs the library jits — batched fork (whole
-             copies and the row cut of leaves over the piece size),
+             copies and the row cut of leaves over the piece size, by DMA
+             on the HBM tiling and re-laid on the device off it),
              on-device slab pack
              (``TORCHSNAPSHOT_TPU_ENABLE_BATCHING=1``) — fed every bit
              pattern of every sub-32-bit float, put from the host: denormals
@@ -801,18 +802,40 @@ def run_programs_leg(ctx: dict) -> dict:
     host["big_float32"] = rng.integers(
         0, 1 << 32, size=(2 * nbytes // 4 // 4096, 4096), dtype=np.uint32
     ).view(np.float32)  # random bits: denormals and NaN payloads, twice the size
+    # Leaves off the HBM tiling, which the chip holds column first: the
+    # fork re-lays them in pieces on the device, bfloat16 through the DMA
+    # that takes its bits, the others by XLA alone.
+    odd = {
+        "odd_bfloat16_stack": (ml_dtypes.bfloat16, (16, 2688, 232)),
+        "odd_bfloat16_wide": (ml_dtypes.bfloat16, (2688, 3608)),
+        "odd_uint16_stack": (np.uint16, (16, 2688, 232)),
+        "odd_int8_wide": (np.int8, (2688, 10304)),
+    } if ctx["measured"] else {
+        "odd_bfloat16_stack": (ml_dtypes.bfloat16, (16, 24, 29)),
+        "odd_int8_wide": (np.int8, (24, 92)),
+    }
+    for k, (dt, shape) in odd.items():
+        host[k] = all_patterns(dt, shape)
+    host["odd_float32_wide"] = rng.integers(
+        0, 1 << 32, size=(5376, 1100) if ctx["measured"] else (24, 11), dtype=np.uint32
+    ).view(np.float32)
     state = put(host)
     total = sum(v.nbytes for v in host.values())
     cut = {
         k: piece_row_ranges(v.shape, v.dtype)
         for k, v in host.items() if copy_preserves_bits(v.dtype)
     }
-    want_pieces = sum(len(r) for r in cut.values() if r)
+    want_pieces = sum(len(r.ranges) for r in cut.values() if r)
     want_pieced = sum(host[k].nbytes for k, r in cut.items() if r)
+    want_relaid = [k for k, r in cut.items() if r and r.relaid]
     if ctx["measured"]:
         check(
             bool(cut["big_bfloat16"]) and bool(cut["big_float32"]),
             "[programs] the big bfloat16 and float32 leaves are not over the piece size",
+        )
+        check(
+            sorted(want_relaid) == sorted(k for k in host if k.startswith("odd_")),
+            f"[programs] the leaves off the tiling are not all cut to be re-laid: {want_relaid}",
         )
     for mode in ("take", "async_take"):
         metrics = round_trip(f"programs_big_{mode}", mode, state, host)
@@ -833,8 +856,27 @@ def run_programs_leg(ctx: dict) -> dict:
             f"[programs] {mode}: {pieces} pieces / {pieced} bytes, expected "
             f"{want_pieces} / {want_pieced} in an async take and none in a synchronous one",
         )
+        relaid = (
+            int(metrics.get("capture.fork_relaid_leaves", 0)),
+            int(metrics.get("capture.fork_relaid_bytes", 0)),
+        )
+        on_host = int(metrics.get("stage.host_relaid_bytes", 0))
+        log(
+            f"[programs] {mode}: {relaid[0]} leaves / {relaid[1] / 1e6:.0f} MB re-laid by the fork, "
+            f"{on_host / 1e6:.0f} MB re-laid on the host"
+        )
+        want = (len(want_relaid), sum(host[k].nbytes for k in want_relaid))
+        check(
+            relaid == (want if mode == "async_take" else (0, 0)),
+            f"[programs] {mode}: the fork re-laid {relaid}, expected {want} in an async take "
+            "and nothing in a synchronous one",
+        )
+        if mode == "async_take":
+            check(on_host == 0, f"[programs] {mode}: {on_host} bytes were re-laid on the host")
         out[f"big_leaves_forked_{mode}"] = forked
         out[f"pieces_{mode}"] = pieces
+        out[f"fork_relaid_leaves_{mode}"] = relaid[0]
+        out[f"host_relaid_bytes_{mode}"] = on_host
     free_tree(dict(state))
     log(
         f"[programs] fork (whole and in pieces) + transfers: {len(host)} leaves / {total / 1e6:.0f} MB "

@@ -15,6 +15,10 @@ it read). Three questions:
       storage write of every gathered leaf.
 
 Writes ``chiprun_out/probe_d2h_pieces.json`` and prints it.
+
+``--relay`` is the probe of PR 46 (``probe_relay``): (iv) which program
+re-lays a leaf the DMA cut refuses, on the device, and keeps every bit.
+Writes ``chiprun_out/probe_d2h_relay.json``.
 """
 
 import json
@@ -408,7 +412,131 @@ def probe_decompose(seed):
     return results
 
 
+# ------------------------------------------- (iv) the re-laying cut (PR 46)
+
+RELAY_SHAPES = [(16, 2688, 1856), (2688, 10304), (16, 1856, 2688), (2688, 4096)]  # PR 44's four
+RELAY_TINY = [(16, 256, 116), (256, 704), (16, 116, 256), (256, 512)]
+
+
+def relay_candidates(x, ranges):
+    """The two programs that were tried first and do not keep every bit of
+    bfloat16 on the v5e: XLA's own bitcast before integer slices, and slices
+    of the float under an explicit row-major layout."""
+    from jax.experimental.layout import Format, Layout
+
+    row = int(np.prod(x.shape[1:]))
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+
+    def xla_bitcast(x):
+        u = jax.lax.bitcast_convert_type(x, bits)
+        return [u[a:b].reshape((b - a) * row // 128, 128) for a, b in ranges]
+
+    def layout_float(x):
+        return [x[a:b] for a, b in ranges]
+
+    row_major = Format(Layout(major_to_minor=tuple(range(x.ndim))), x.sharding)
+    return {
+        "xla_bitcast": jax.jit(xla_bitcast),
+        "layout_float": jax.jit(layout_float, out_shardings=[row_major] * len(ranges)),
+    }
+
+
+def _timed_ms(fn, x, reps=5):
+    jax.block_until_ready(fn(x))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(x))
+        times.append((time.perf_counter() - t0) * 1e3)
+    return sorted(times)[len(times) // 2]
+
+
+def probe_relay():
+    """(iv), PR 46: a leaf the DMA cut refuses for its shape, written by the
+    fork as row-range pieces re-laid on the device. PR 44's four shapes in
+    bfloat16 (every pattern, put from the host) and the odd ones in float32,
+    int8 and uint16, through the library's own fork: the leaf's device
+    layout and how its whole copy reaches the host; the cut's mover, the
+    pieces' layout, whether their host copies are C-contiguous and are the
+    C-order bytes of the rows; the fork's time to ready as pieces and whole;
+    how fast the pieces cross on one thread. Beside it, for bfloat16, the
+    two programs that do not keep every bit."""
+    from torchsnapshot_tpu import d2h, io_preparer
+
+    if INTERPRET:
+        d2h.PIECE_BYTES = 64 * 1024
+    shapes = RELAY_SHAPES if not INTERPRET else RELAY_TINY
+    samples = [(s, jnp.bfloat16) for s in shapes]
+    samples += [(shapes[1], np.float32), (shapes[1], np.int8), (shapes[0], np.uint16)]
+    rng = np.random.default_rng(46)
+    out = {}
+    for shape, dtype in samples:
+        dt = np.dtype(dtype)
+        word = f"uint{8 * dt.itemsize}"
+        n = int(np.prod(shape))
+        if dt.itemsize < 4:
+            words = np.resize(np.arange(1 << (8 * dt.itemsize), dtype=word), n)
+        else:
+            words = rng.integers(0, 1 << 32, size=n, dtype=np.uint64).astype(np.uint32)
+            special = np.array(
+                [(s << 31) | (e << 23) | m for s in (0, 1) for e in range(256) for m in (0, 1, 0x7FFFFF, 0x400001)],
+                dtype=np.uint32,
+            )
+            words[: special.size] = special
+        host = words.view(dt).reshape(shape)
+        x = jax.device_put(host)
+        back = np.asarray(jnp.copy(x))
+        cut = io_preparer._fork_cut(x)
+        rec = {
+            "device_major_to_minor": list(x.format.layout.major_to_minor),
+            "whole_host_c_contiguous": bool(back.flags.c_contiguous),
+            "whole_host_strides": list(back.strides),
+            "mover": None if cut is None else ("relaid" if cut.relaid else "dma"),
+            "dma_takes_bits_in_order": None if cut is None or cut.order is None else list(cut.order),
+            "whole_ms": _timed_ms(io_preparer._batch_copy_fn((x.sharding,), (None,)), [x]),
+        }
+        programs = {"library": lambda xs, cut=cut: io_preparer._batch_copy_fn((xs.sharding,), (cut,))([xs])[0]}
+        if dt.name == "bfloat16":
+            programs.update(relay_candidates(x, cut.ranges))
+        rec["pieces"] = len(cut.ranges)
+        for name, fn in programs.items():
+            pieces = fn(x)
+            jax.block_until_ready(pieces)
+            t0 = time.perf_counter()
+            for p in pieces:
+                p.copy_to_host_async()
+            hosts = [np.asarray(p) for p in pieces]
+            d2h_s = time.perf_counter() - t0
+            got = np.concatenate([h.reshape(-1).view(np.uint8) for h in hosts]).view(word)
+            bad = np.flatnonzero(got != words)
+            rec[name] = {
+                "piece_shape": list(pieces[0].shape),
+                "piece_dtype": str(pieces[0].dtype),
+                "piece_major_to_minor": sorted({tuple(p.format.layout.major_to_minor) for p in pieces}),
+                "host_c_contiguous": all(h.flags.c_contiguous for h in hosts),
+                "differing_elements": int(bad.size),
+                "differing_patterns": len({int(words[i]) for i in bad}),
+                "d2h_gbps_one_thread": host.nbytes / d2h_s / 1e9,
+                "fork_ms": _timed_ms(fn, x),
+            }
+            del pieces, hosts
+            print(f"[relay] {shape} {dt.name} {name}: {rec[name]}", flush=True)
+        out[f"{dt.name}{list(shape)}"] = rec
+        print(f"[relay] {shape} {dt.name}: {({k: v for k, v in rec.items() if not isinstance(v, dict)})}", flush=True)
+        x.delete()
+    bad = {k: v["library"]["differing_elements"] for k, v in out.items() if v["library"]["differing_elements"]}
+    print("RELAY EXACT:", "every bit" if not bad else f"DIFFERS {bad}")
+    out["exact"] = not bad
+    return out
+
+
 def main():
+    if "--relay" in sys.argv:
+        out = {"relay": probe_relay()}
+        os.makedirs(os.path.join(REPO_ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(REPO_ROOT, "chiprun_out", "probe_d2h_relay.json"), "w") as f:
+            json.dump(out, f, indent=1)
+        return 0 if out["relay"]["exact"] else 1
     if "--decompose" in sys.argv:
         out = {"decompose": probe_decompose(3900000040)}
         os.makedirs(os.path.join(REPO_ROOT, "chiprun_out"), exist_ok=True)

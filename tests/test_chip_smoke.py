@@ -58,8 +58,8 @@ def test_cpu_tiny_dry_run_passes_end_to_end(tmp_path) -> None:
         # packed and cut on the host, float16/float8 never forked.
         "[programs] slab pack: 304 leaves restore bit-exact",
         "132 leaves host-captured because a device copy would rewrite their dtype",
-        "[programs] async_take of 5 big leaves / 2 MB: 2 forked",
-        "[programs] fork (whole and in pieces) + transfers: 5 leaves",
+        "[programs] async_take of 8 big leaves / 2 MB: 5 forked",
+        "[programs] fork (whole and in pieces) + transfers: 8 leaves",
         "[four] restore into transposed (tp, dp) mesh: bit-exact",
         "[four] restore into flat (4,) mesh: bit-exact",
         "[resume] bit-exact against saved step 3",

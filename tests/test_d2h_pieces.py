@@ -1,11 +1,13 @@
 """The transfer grain (``d2h.PIECE_BYTES``): a forked leaf over the piece size
 leaves the fork as row-range pieces, cut bit for bit by a DMA inside the one
-fork program; the lanes move the pieces under their own window into one host
+fork program, or, where its shape is off the HBM tiling, re-laid there in
+integers; the lanes move the pieces under their own window into one host
 buffer a leaf, and what is hashed, written and committed is what the
-whole-leaf path writes. Leaves the cut does not take go whole, as before.
+whole-leaf path writes. Leaves no mover takes go whole, as before.
 """
 
 import asyncio
+import math
 import os
 
 import numpy as np
@@ -16,6 +18,7 @@ from torchsnapshot_tpu.io_preparers.array import (
     ArrayBufferStager,
     ArrayIOPreparer,
     PiecedArray,
+    device_piece_cut,
     piece_row_ranges,
 )
 from torchsnapshot_tpu.manifest import entry_to_dict
@@ -83,7 +86,7 @@ def _patterned_state():
         "f32": f32.view(np.float32).reshape(128, 512),
         "i8": np.tile(np.arange(256, dtype=np.uint8), 1024).view(np.int8).reshape(256, 1024),
         "flags": (rng.integers(0, 2, size=(256, 1024)) > 0),  # bool: whole
-        "odd_cols": rng.standard_normal((512, 100)).astype(np.float32),  # whole
+        "odd_cols": rng.standard_normal((500, 100)).astype(np.float32),  # no lanes' worth of rows divides 500: whole
         "vector": rng.standard_normal(1 << 16).astype(np.float32),  # 1-D: whole
         "small": np.arange(7, dtype=np.int32),
     }
@@ -112,6 +115,12 @@ def _objects(path: str) -> dict:
 # ------------------------------------------------------------- the row ranges
 
 
+def _covers_once(ranges, rows: int) -> None:
+    assert ranges[0][0] == 0 and ranges[-1][1] == rows
+    for (a0, a1), (b0, _b1) in zip(ranges, ranges[1:]):
+        assert a0 < a1 == b0
+
+
 @pytest.mark.parametrize(
     "shape,dtype",
     [
@@ -123,11 +132,9 @@ def _objects(path: str) -> dict:
     ],
 )
 def test_row_ranges_cover_a_leaf_once(grain, shape, dtype) -> None:
-    ranges = piece_row_ranges(shape, np.dtype(dtype))
-    assert ranges is not None and len(ranges) >= 2
-    assert ranges[0][0] == 0 and ranges[-1][1] == shape[0]
-    for (a0, a1), (b0, _b1) in zip(ranges, ranges[1:]):
-        assert a0 < a1 == b0
+    ranges, relaid, _ = piece_row_ranges(shape, np.dtype(dtype))
+    assert not relaid and len(ranges) >= 2
+    _covers_once(ranges, shape[0])
     unit = 8 if len(shape) == 2 else 1
     assert all(r0 % unit == 0 and r1 % unit == 0 for r0, r1 in ranges)
     row_bytes = int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
@@ -142,9 +149,9 @@ def test_row_ranges_cover_a_leaf_once(grain, shape, dtype) -> None:
         ((1, 1 << 20), "float32", "one row"),
         ((8, 1 << 16), "float32", "one unit of 8 rows"),
         ((1 << 18,), "float32", "one dimension"),
-        ((1001, 128), "float32", "rows not a multiple of 8"),
-        ((1024, 100), "float32", "columns not a multiple of 128"),
-        ((16, 12, 1024), "float32", "a slab's rows not a multiple of 8"),
+        ((1023, 100), "float32", "off the tiling, and 32 rows fill whole lanes: 1023 is no multiple"),
+        ((15, 12, 1000), "float32", "off the tiling, and 4 slabs fill whole lanes: 15 is no multiple"),
+        ((2, 70000), "int8", "off the tiling, and 8 rows fill whole lanes: it has two"),
         ((1024, 128), "bool", "the DMA takes no bool"),
         ((1024, 128), "float16", "float16 never forks"),
         ((1024, 128), "float64", "the DMA takes no 64-bit type"),
@@ -186,6 +193,7 @@ def test_fork_pieces_big_leaves_in_one_program_and_keeps_every_bit(grain, monkey
         assert c.shape == host[n].shape and c.dtype == host[n].dtype
         assert c.sharding == state[n].sharding and c.nbytes == host[n].nbytes
         assert [p.shape[0] for p in c.pieces] == [r1 - r0 for r0, r1 in c.ranges]
+        assert c.ranges == piece_row_ranges(c.shape, c.dtype).ranges
         assert all(p.nbytes <= PIECE for p in c.pieces)
         got = np.concatenate([np.asarray(p) for p in c.pieces])
         assert got.tobytes() == host[n].tobytes(), n
@@ -228,13 +236,14 @@ def test_a_refusal_by_the_kernel_compiler_forks_whole(grain, tmp_path, monkeypat
 
     monkeypatch.setattr(io_preparer, "_cut_rows", refuse)
     monkeypatch.setattr(io_preparer, "_BATCH_COPIES", BoundedLRU())  # no program built before
-    monkeypatch.setattr(io_preparer, "_cut_refused", False)
+    monkeypatch.setattr(io_preparer, "_dma_cut_refused", False)
+    monkeypatch.setattr(io_preparer, "_relay_cut_refused", False)
     host, state = _patterned_state()
     path = str(tmp_path / "ck")
     with caplog.at_level("WARNING"):
         Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
-    assert "forking whole leaves" in caplog.text
-    assert io_preparer._cut_refused
+    assert "forking those leaves whole" in caplog.text
+    assert io_preparer._dma_cut_refused and not io_preparer._relay_cut_refused
     assert _metrics()["d2h.pieces"] == 0
     copies = io_preparer._defensive_device_copies(list(state.values()))
     assert all(isinstance(c, jax.Array) for c in copies)
@@ -267,8 +276,10 @@ def test_pieced_take_writes_what_the_whole_leaf_path_writes(grain, tmp_path, mon
     pieced_names = ("every_bf16", "stack_bf16", "f32", "i8")
     assert pieced["d2h.pieced_bytes"] == sum(host[n].nbytes for n in pieced_names)
     assert pieced["d2h.pieces"] == sum(
-        len(piece_row_ranges(host[n].shape, host[n].dtype)) for n in pieced_names
+        len(piece_row_ranges(host[n].shape, host[n].dtype).ranges) for n in pieced_names
     )
+    assert pieced["capture.fork_relaid_leaves"] == pieced["capture.fork_relaid_bytes"] == 0
+    assert pieced["stage.host_relaid_bytes"] == 0
     assert pieced["d2h.bytes"] == sum(v.nbytes for v in host.values())  # once a byte
     assert pieced["capture.forked_leaves"] == len(host)
     assert "capture.host_captured_bytes" not in pieced
@@ -344,14 +355,234 @@ def test_the_window_counts_pieces_and_whole_leaves_as_before(grain, tmp_path) ->
     assert metrics["d2h.window_waits"] == 22
 
     whole = {
-        f"v{i}": jax.random.normal(jax.random.PRNGKey(i), (512, 100), jnp.float32)
+        f"v{i}": jax.random.normal(jax.random.PRNGKey(i), (500, 100), jnp.float32)
         for i in range(30)
-    }  # 200 KiB each, not cut (100 columns): twenty fit under 4 MiB
+    }  # 200 KB each, not cut (100 columns, 500 rows): twenty fit under 4 MiB
     Snapshot.async_take(str(tmp_path / "w"), {"m": StateDict(**whole)}).wait()
     metrics = _metrics()
     assert metrics["d2h.pieces"] == 0
-    assert metrics["d2h.hinted_ahead_hwm_bytes"] == 20 * 512 * 100 * 4
+    assert metrics["d2h.hinted_ahead_hwm_bytes"] == 20 * 500 * 100 * 4
     assert metrics["d2h.window_waits"] == 10
+
+
+# ---------------------------------------------------------- the re-laying cut
+
+# Scale models of the shapes the DMA cut refuses: an expert stack whose
+# minor dimension is no multiple of 128 and a fused projection whose width
+# is none ((16, 2688, 1856) and (2688, 10304) in the benchmark's ninth
+# cell), rows no multiple of 8, a slab's rows no multiple of 8.
+RELAID_SHAPES = [(16, 168, 116), (168, 704), (1001, 128), (1024, 100), (16, 12, 1024)]
+RELAID_DTYPES = ["bfloat16", "float32", "int8", "uint16"]
+
+
+def _bit_patterns(shape, dtype):
+    """Every pattern of an 8- or 16-bit dtype, tiled; for float32 random
+    words with denormals, infinities and NaN payloads of both signs."""
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(dtype)
+    n = int(np.prod(shape))
+    if dt.itemsize < 4:
+        words = np.resize(np.arange(1 << (8 * dt.itemsize), dtype=f"uint{8 * dt.itemsize}"), n)
+    else:
+        words = np.random.default_rng(46).integers(0, 1 << 32, size=n, dtype=np.uint64).astype(np.uint32)
+        words[:8] = [1, 0x007FFFFF, 0x7F800001, 0x7FC00001, 0xFFFFFFFF, 0x80000001, 0x7F800000, 0xFF800000]
+    return words.view(dt).reshape(shape)
+
+
+def _relaid_state():
+    import jax
+
+    host = {
+        f"{dtype}_{'x'.join(map(str, shape))}": _bit_patterns(shape, dtype)
+        for shape in RELAID_SHAPES
+        for dtype in RELAID_DTYPES
+    }  # the smallest, int8 (1024, 100), is over the piece size
+    host["aligned"] = _bit_patterns((1024, 128), "bfloat16")  # the DMA's
+    host["small"] = np.arange(7, dtype=np.int32)
+    return host, {k: jax.device_put(v) for k, v in host.items()}
+
+
+@pytest.mark.parametrize("dtype", RELAID_DTYPES)
+@pytest.mark.parametrize("shape", RELAID_SHAPES)
+def test_relaid_ranges_cover_a_leaf_once_in_whole_lanes(grain, shape, dtype) -> None:
+    import jax.numpy as jnp
+
+    itemsize = jnp.dtype(dtype).itemsize
+    cut = piece_row_ranges(shape, jnp.dtype(dtype))
+    assert cut.relaid and len(cut.ranges) >= 2
+    _covers_once(cut.ranges, shape[0])
+    row = int(np.prod(shape[1:]))
+    unit = 128 // math.gcd(row, 128)  # the fewest rows that fill whole lanes
+    for r0, r1 in cut.ranges:
+        assert (r1 - r0) % unit == 0 and (r1 - r0) * row % 128 == 0
+        assert (r1 - r0) * row * itemsize <= PIECE or r1 - r0 == unit
+
+
+@pytest.mark.parametrize("dtype", RELAID_DTYPES)
+@pytest.mark.parametrize("shape", [(16, 168, 116), (168, 704), (1001, 128)])
+def test_the_relaying_cut_keeps_every_bit(grain, shape, dtype) -> None:
+    """All 65,536 bfloat16 patterns, every int8 and uint16, float32 with
+    NaN payloads and denormals: each piece's host copy is C-contiguous and
+    the pieces together are the C-order bytes of the leaf."""
+    import jax
+    import jax.numpy as jnp
+
+    host = _bit_patterns(shape, dtype)
+    (copy,) = io_preparer._defensive_device_copies([jax.device_put(host)])
+    assert isinstance(copy, PiecedArray) and copy.shape == host.shape and copy.dtype == host.dtype
+    cut = piece_row_ranges(shape, jnp.dtype(dtype))
+    assert cut.relaid and copy.ranges == cut.ranges
+    row_bytes = host.nbytes // shape[0]
+    pieces = [np.asarray(p) for p in copy.pieces]
+    assert all(p.flags["C_CONTIGUOUS"] and p.shape[-1] == 128 for p in pieces)
+    assert [p.nbytes for p in pieces] == [(r1 - r0) * row_bytes for r0, r1 in cut.ranges]
+    # bfloat16 leaves the fork as its bits; XLA moves the others as they are.
+    assert all(p.dtype == (np.uint16 if dtype == "bfloat16" else host.dtype) for p in pieces)
+    assert b"".join(p.tobytes() for p in pieces) == host.tobytes()
+
+
+def test_a_relaid_take_writes_what_the_whole_leaf_path_writes(grain, tmp_path, monkeypatch) -> None:
+    """The same storage objects, checksums and manifest as the take with no
+    leaf pieced, and the counters say which mover wrote what."""
+    host, state = _relaid_state()
+    relaid_path, whole_path = str(tmp_path / "relaid"), str(tmp_path / "whole")
+    Snapshot.async_take(relaid_path, {"m": StateDict(**state)}).wait()
+    relaid = _metrics()
+    names = [n for n in host if n not in ("aligned", "small")]
+    assert len(names) == len(RELAID_SHAPES) * len(RELAID_DTYPES)
+    cuts = {n: piece_row_ranges(host[n].shape, host[n].dtype) for n in host}
+    assert all(cuts[n].relaid for n in names) and not cuts["aligned"].relaid
+    assert relaid["capture.fork_relaid_leaves"] == len(names)
+    assert relaid["capture.fork_relaid_bytes"] == sum(host[n].nbytes for n in names)
+    assert relaid["capture.forked_leaves"] == len(host)
+    assert relaid["d2h.pieces"] == sum(len(c.ranges) for c in cuts.values() if c)
+    assert relaid["d2h.pieced_bytes"] == sum(host[n].nbytes for n in names + ["aligned"])
+    assert relaid["d2h.bytes"] == sum(v.nbytes for v in host.values())
+    assert relaid["stage.host_relaid_bytes"] == 0
+
+    monkeypatch.setattr(d2h, "PIECE_BYTES", 1 << 40)
+    prepare_cache.reset(get_coordinator())
+    Snapshot.async_take(whole_path, {"m": StateDict(**state)}).wait()
+    whole = _metrics()
+    assert whole["d2h.pieces"] == 0 and whole["capture.fork_relaid_leaves"] == 0
+    a, b = _objects(relaid_path), _objects(whole_path)
+    assert sorted(a) == sorted(b) and any(r.startswith(".checksums") for r in a)
+    for rel in a:
+        assert a[rel] == b[rel], rel
+    assert {k: entry_to_dict(v) for k, v in Snapshot(relaid_path).get_manifest().items()} == {
+        k: entry_to_dict(v) for k, v in Snapshot(whole_path).get_manifest().items()
+    }
+    _assert_restores(relaid_path, host, state)
+
+
+def test_a_strided_host_copy_is_counted_where_the_stage_relays_it(grain, tmp_path) -> None:
+    """What is left for the host: a leaf that reaches the stager in another
+    order than row-major (here a Fortran-ordered host array; on the chip a
+    synchronous take's or a small leaf's device order) is made contiguous
+    there, and ``stage.host_relaid_bytes`` counts it."""
+    strided = np.asfortranarray(np.arange(96 * 50, dtype=np.float32).reshape(96, 50))
+    straight = np.arange(64, dtype=np.float32)
+    path = str(tmp_path / "ck")
+    Snapshot.take(path, {"m": StateDict(strided=strided, straight=straight)})
+    assert _metrics()["stage.host_relaid_bytes"] == strided.nbytes
+    assert Snapshot(path).read_object("0/m/strided").tobytes() == np.ascontiguousarray(strided).tobytes()
+
+    class StridedPiece:
+        """A piece whose host copy comes in the device's order."""
+
+        def __init__(self, host):
+            self.host, self.deleted = host, False
+
+        def __array__(self, dtype=None, copy=None):
+            return self.host
+
+        def delete(self):
+            self.deleted = True
+
+    times = d2h.StageTimes()
+    piece = StridedPiece(strided)
+    into = np.empty(strided.nbytes, np.uint8)
+    d2h.resolve_on_host(piece, into, times)
+    assert piece.deleted and times.host_relaid_bytes == strided.nbytes
+    assert into.tobytes() == np.ascontiguousarray(strided).tobytes()
+    d2h.resolve_on_host(StridedPiece(straight), np.empty(straight.nbytes, np.uint8), times)
+    assert times.host_relaid_bytes == strided.nbytes
+
+
+def test_a_refusal_of_the_relaying_cut_keeps_the_dma_cut(grain, tmp_path, monkeypatch, caplog) -> None:
+    """The kernel compiler refuses the re-laying cut's DMA: those leaves
+    fork whole from then on, the aligned ones are still cut."""
+
+    def refuse(x, interpret):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: no such tiling")
+
+    monkeypatch.setattr(io_preparer, "_bits_by_dma", refuse)
+    monkeypatch.setattr(io_preparer, "_BATCH_COPIES", BoundedLRU())
+    monkeypatch.setattr(io_preparer, "_dma_cut_refused", False)
+    monkeypatch.setattr(io_preparer, "_relay_cut_refused", False)
+    host, state = _relaid_state()
+    path = str(tmp_path / "ck")
+    with caplog.at_level("WARNING"):
+        Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
+    assert "re-laying cut was refused" in caplog.text
+    assert io_preparer._relay_cut_refused and not io_preparer._dma_cut_refused
+    metrics = _metrics()
+    assert metrics["capture.fork_relaid_leaves"] == 0
+    assert metrics["d2h.pieced_bytes"] == host["aligned"].nbytes
+    _assert_restores(path, host, state)
+
+
+def _never_asked():
+    raise AssertionError("the device's order is asked only of a bfloat16 leaf to re-lay")
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, order, on_tpu, want",
+    [
+        # The TPU's kernel compiler aborts the process on a DMA off the HBM
+        # tiling: in the device's own order the minor dimension has to be a
+        # multiple of 128 and the one before it of 8, or the leaf stays whole.
+        ((1001, 128), "bfloat16", (0, 1), True, None),  # 1001 rows before the lanes
+        ((1001, 128), "bfloat16", (0, 1), False, (0, 1)),  # the interpreter takes any shape
+        ((16, 256, 116), "bfloat16", (0, 2, 1), True, None),  # 116 % 8
+        ((16, 250, 120), "bfloat16", (0, 2, 1), True, None),  # 250 % 128
+        ((16, 256, 120), "bfloat16", (0, 2, 1), True, (0, 2, 1)),  # 120: whole tiles of 8, not of 16
+        ((256, 1000), "bfloat16", (1, 0), True, (1, 0)),
+        ((256, 1004), "bfloat16", (1, 0), True, None),  # 1004 % 8
+        ((256, 1000), "bfloat16", (0, 1), True, None),  # held row-major: 1000 % 128
+        ((16, 256, 120), "bfloat16", (2, 0, 1), True, (2, 0, 1)),  # 16 slabs before 256 lanes
+        # XLA moves these bit for bit: re-laid with no kernel, whatever the order.
+        ((1001, 128), "float32", None, True, "xla"),
+        ((16, 256, 116), "int8", None, True, "xla"),
+        ((256, 1004), "uint16", None, True, "xla"),
+        # On the tiling: the DMA cut, and no order is asked for.
+        ((1024, 128), "bfloat16", None, True, "dma"),
+        ((16, 16, 256), "float32", None, True, "dma"),
+        # What the rule sends whole stays whole.
+        ((1023, 100), "bfloat16", None, True, "whole"),
+        ((64, 128), "bfloat16", None, True, "whole"),
+    ],
+)
+def test_the_cut_of_a_leaf_as_the_device_holds_it(grain, shape, dtype, order, on_tpu, want) -> None:
+    """``device_piece_cut`` is what stands between a take and the kernel
+    compiler's abort: a pure function of the leaf's shape, dtype, the
+    device's order of its dimensions and the platform."""
+    import jax.numpy as jnp
+
+    rule = piece_row_ranges(shape, jnp.dtype(dtype))
+    cut = device_piece_cut(shape, jnp.dtype(dtype), (lambda: order) if order else _never_asked, on_tpu)
+    if want == "whole":
+        assert rule is None and cut is None
+    elif want == "dma":
+        assert cut == rule and not cut.relaid and cut.order is None
+    elif want == "xla":
+        assert cut == rule and cut.relaid and cut.order is None
+    elif want is None:
+        assert rule is not None and rule.relaid and cut is None  # the guard alone refuses it
+    else:
+        assert cut == rule._replace(order=want)
+
 
 
 # ------------------------------------------------------------------- failures
@@ -523,6 +754,21 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _device_order(leaf):
+    """The described chip's own order of ``leaf``'s dimensions, major to
+    minor: what ``jax.Array.format`` says of a live array."""
+    import jax
+    import jax.numpy as jnp
+
+    (formats,), _ = jax.jit(jnp.copy).lower(leaf).compile().input_formats
+    return tuple(formats.layout.major_to_minor)
+
+
+def _described_cut(leaf):
+    """``io_preparer._fork_cut`` of a leaf that is only described."""
+    return device_piece_cut(leaf.shape, leaf.dtype, lambda: _device_order(leaf), True)
+
+
 @pytest.mark.parametrize(
     "shapes",
     [
@@ -532,25 +778,63 @@ def one_chip():
         # expert stacks, a vocabulary share of 18,992 rows, a float32 router
         [((10, 1536, 5120), "bfloat16"), ((32, 2048, 512), "bfloat16"), ((18992, 2048), "bfloat16"),
          ((2560, 6144), "bfloat16"), ((16384, 1024), "float32"), ((2560, 512), "float32")],
+        # nemotron-3-nano: stacks of minor dimension 1856 and a fused in_proj of
+        # 10304 columns, which the chip holds column first, beside their aligned
+        # twins, a vocabulary slice, float32 of an odd width and a vector
+        [((16, 2688, 1856), "bfloat16"), ((2688, 10304), "bfloat16"), ((16, 1856, 2688), "bfloat16"),
+         ((2688, 4096), "bfloat16"), ((16384, 2688), "bfloat16"), ((4096, 1100), "float32"),
+         ((2688,), "float32")],
+        # at the guard: bfloat16 the chip holds in partial tiles (rows no multiple of 8
+        # before 128 lanes; 2052 % 8 before 4096 lanes) goes whole, handed to the kernel
+        # compiler it aborts the process; whole tiles of 8 that are no multiple of 16
+        # (1864, 10312) compile; float32 off the tiling needs no kernel
+        [((100001, 128), "bfloat16"), ((4096, 2052), "bfloat16"), ((16, 2688, 1864), "bfloat16"),
+         ((2688, 10312), "bfloat16"), ((100001, 128), "float32")],
     ],
 )
 def test_the_fork_of_real_shapes_compiles_for_the_v5e_with_no_temporary(one_chip, shapes) -> None:
-    """The TPU's own compiler takes the cut at the sizes the benchmark's
+    """The TPU's own compiler takes both movers at the sizes the benchmark's
     states have (it refuses a DMA off the HBM tiling, which interpret mode
-    does not), and the program holds no byte beyond its outputs."""
+    does not). Every piece leaves row-major, whatever order the chip holds
+    its leaf in; the DMA cut holds no byte beyond its outputs, the
+    re-laying cut at most one leaf's."""
     import jax
     import jax.numpy as jnp
 
     leaves = [jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip) for s, d in shapes]
-    cuts = tuple(
-        None if r is None else tuple(r) for r in (piece_row_ranges(a.shape, a.dtype) for a in leaves)
-    )
+    cuts = tuple(_described_cut(a) for a in leaves)
     assert any(cuts) and not all(cuts)
+    relaid = [a for a, c in zip(leaves, cuts) if c and c.relaid]
+    if shapes[0][0] == (16, 2688, 1856):
+        assert [a.shape for a in relaid] == [(16, 2688, 1856), (2688, 10304), (4096, 1100)]
+        assert [c.order for c in cuts[:2]] == [(0, 2, 1), (1, 0)]  # not row-major on the chip
+    elif shapes[0][0] == (100001, 128):
+        assert [c and c.order for c in cuts] == [None, None, (0, 2, 1), (1, 0), None]
+        assert cuts[4].relaid and all(piece_row_ranges(a.shape, a.dtype).relaid for a in leaves)
+        # Off the TPU nothing aborts and the interpreter takes them all.
+        assert all(device_piece_cut(a.shape, a.dtype, lambda: (0, 1), False) for a in leaves[:2])
+    else:
+        assert not relaid
     compiled = (
         io_preparer._batch_copy_fn(tuple(one_chip for _ in leaves), cuts).lower(leaves).compile()
     )
+    for cut, formats in zip(cuts, compiled.output_formats):
+        if cut is not None:
+            assert len(formats) == len(cut.ranges)
+            for f in formats:
+                order = tuple(f.layout.major_to_minor)
+                assert order == tuple(range(len(order))), (cut, order)
     stats = compiled.memory_analysis()
-    total = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves)
-    assert stats.temp_size_in_bytes == 0
-    assert total <= stats.output_size_in_bytes <= total + 4096 * len(leaves)
-    assert compiled.as_text().count("tpu_custom_call") == sum(1 for c in cuts if c)
+    nbytes = [int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves]
+    biggest = max([n for n, a in zip(nbytes, leaves) if a in relaid], default=0)
+    assert stats.temp_size_in_bytes <= biggest * 1.06  # the chip pads 1856 lanes to 1920
+    # A whole copy comes in the chip's tiles: partial ones are padded to (8, 128).
+    padding = 0
+    for a, n, cut in zip(leaves, nbytes, cuts):
+        if cut is None and len(a.shape) > 1:
+            dims = [a.shape[i] for i in _device_order(a)]
+            dims[-1], dims[-2] = -(-dims[-1] // 128) * 128, -(-dims[-2] // 8) * 8
+            padding += int(np.prod(dims)) * a.dtype.itemsize - n
+    assert sum(nbytes) <= stats.output_size_in_bytes <= sum(nbytes) + padding + 4096 * len(leaves)
+    kernels = sum(1 for c in cuts if c and (not c.relaid or c.order is not None))
+    assert compiled.as_text().count("tpu_custom_call") == kernels

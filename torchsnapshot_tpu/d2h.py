@@ -19,17 +19,28 @@ Two cooperating pieces:
   memory budget has not seen.
   **The grain.** A leaf that ``async_take`` forked and that is over
   :data:`PIECE_BYTES` reaches the lanes as row-range pieces the fork itself
-  wrote (``io_preparer._cut_rows``: DMAs inside the one fork program, every
-  bit kept). A piece is admitted under :data:`PIECE_WINDOW_BYTES`, and the
-  lane that resolved it copies it into its rows of the leaf's one host
-  buffer and drops it, so a few tens of MiB are in flight where whole
-  leaves put hundreds: the job's steps beside the drain lose a third to a
-  half of what they lost (``PERF.md`` section 6, PR 39). A whole-leaf
-  transfer (a synchronous take, a sharded leaf, a leaf under the piece size
-  or of a shape or dtype the cut does not take) is admitted under
-  :data:`HINT_WINDOW_BYTES` as before: the lanes tell the two apart by
-  what they are handed. Beyond a leaf's buffer the host holds at most one
-  window of resolved pieces.
+  wrote, every bit kept, by one of two movers inside the one fork program:
+  DMAs of whole HBM tiles (``io_preparer._cut_rows``) or, where the leaf's
+  shape is off the tiling (a width of 1856 or 10304, 1001 rows: a leaf the
+  device may hold column first, whose host copy would come in that order
+  and be re-laid by a strided copy on the drain's event loop), integer
+  copies that re-lay each range row-major on the device
+  (``io_preparer._relay_rows``). Either way a piece's host bytes are the
+  C-order bytes of its rows. A piece is admitted under
+  :data:`PIECE_WINDOW_BYTES`, and the lane that resolved it copies it into
+  its rows of the leaf's one host buffer and drops it, so a few tens of MiB
+  are in flight where whole leaves put hundreds: the job's steps beside the
+  drain lose a third to a half of what they lost (``PERF.md`` section 6,
+  PR 39). **What still crosses whole**, under :data:`HINT_WINDOW_BYTES` as
+  before, and is re-laid on the host where the device does not hold it
+  row-major (``stage.host_relaid_bytes``): a synchronous take, a sharded
+  leaf, a leaf at or under the piece size, a leaf of one row or whose rows
+  fill no whole number of 128 lanes, bool / float16 / float8 / 64-bit
+  leaves, and a bfloat16 leaf off the tiling that the device holds in no
+  whole tiles either (``io_preparers.array.piece_row_ranges``,
+  ``device_piece_cut``). The lanes tell the two apart by what they
+  are handed. Beyond a leaf's buffer the host holds at most one window of
+  resolved pieces.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
   sub-phase intervals (``d2h`` / ``serialize`` / ``hash`` / ``gather``). The
   scheduler derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s``/
@@ -77,7 +88,8 @@ logger = logging.getLogger(__name__)
 HINT_WINDOW_BYTES = 512 * 1024 * 1024
 
 # The grain. A forked leaf over PIECE_BYTES leaves the fork as row-range
-# pieces of at most that size (``io_preparers.array.piece_row_ranges``), and
+# pieces of at most that size (``io_preparers.array.piece_row_ranges``; one
+# unit of rows where a unit is bigger), and
 # pieces are admitted under PIECE_WINDOW_BYTES a device: four of them. From
 # PR 39's runs on a v5e (``PERF.md`` section 6), 3.24 GB of params saved every
 # 44 donated steps of 0.206 s beside the storage writes: whole leaves under
@@ -112,6 +124,8 @@ def resolve_on_host(
     host = np.asarray(arr)
     if into is None:
         return host
+    if times is not None:
+        times.count_host_relaid(host)  # ``reshape(-1)`` below would re-lay it
     # As bytes: one memcpy with the GIL released, whatever the dtype. Copied
     # before the piece is dropped, so a backend whose host value aliases the
     # device buffer is safe too.
@@ -148,6 +162,18 @@ class StageTimes:
         self._intervals: Dict[str, List[Tuple[float, float, int]]] = {
             k: [] for k in self.KINDS
         }
+        self.host_relaid_bytes = 0
+
+    def count_host_relaid(self, host: np.ndarray) -> None:
+        """``stage.host_relaid_bytes``: a leaf the device holds in another
+        order than row-major reaches the host in that order (as does a
+        strided host array), and the stage makes it contiguous by a strided
+        copy, a whole leaf's on the drain's event loop
+        (``serialization.array_as_bytes_view``): what the fork's re-laying
+        cut spares the big forked leaves (``io_preparer._relay_rows``)."""
+        if not host.flags["C_CONTIGUOUS"]:
+            with self._lock:
+                self.host_relaid_bytes += host.nbytes
 
     def record(
         self,

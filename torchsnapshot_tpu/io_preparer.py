@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Any, Dict, List, Optional, Set, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -36,9 +36,10 @@ from .manifest import (
 )
 from .io_preparers.array import (
     ArrayIOPreparer,
+    PieceCut,
     PiecedArray,
     copy_preserves_bits,
-    piece_row_ranges,
+    device_piece_cut,
 )
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer, should_chunk
 from .io_preparers.object import ObjectIOPreparer
@@ -250,8 +251,10 @@ def _is_oom_error(e: BaseException) -> bool:
 # dtype the fork program would rewrite (a property of the model, not of a take).
 _fork_unsupported_warned = False
 _dtype_capture_warned = False
-# Set once the kernel compiler has refused the fork's row cut (``_try_fork``).
-_cut_refused = False
+# Whether the kernel compiler has refused a mover of the fork's row cut in
+# this process (``_try_fork``): the leaves it would have cut fork whole.
+_dma_cut_refused = False
+_relay_cut_refused = False
 
 
 def _is_fork_unsupported_error(group: List[Any], e: BaseException) -> bool:
@@ -279,28 +282,37 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
                 f"({forked_bytes[0]} + {need} > {limit} bytes)"
             )
     shardings = tuple(a.sharding for a in group)
-    cuts = tuple(_fork_cut(a) for a in group)
-    try:
-        copies = _batch_copy_fn(shardings, cuts)(group)
-    except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades
-        if not any(cuts) or "Mosaic" not in str(e):
-            raise
-        # The row cut is a Pallas kernel, and its compiler may refuse a
-        # shape the rule above lets through. The take must not fail for it:
-        # this process forks whole leaves from here on.
-        global _cut_refused
-        _cut_refused = True
-        logger.warning(
-            "async_take: the fork's row cut was refused by the kernel "
-            "compiler (%s); forking whole leaves from now on",
-            e,
-        )
-        cuts = (None,) * len(group)
-        copies = _batch_copy_fn(shardings, cuts)(group)
+    while True:
+        cuts = tuple(_fork_cut(a) for a in group)
+        try:
+            copies = _batch_copy_fn(shardings, cuts)(group)
+            break
+        except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades
+            if not any(cuts) or "Mosaic" not in str(e):
+                raise
+            # Both movers hand the leaf to a Pallas kernel, and its compiler
+            # may refuse a shape the rule lets through. The take must not
+            # fail for it: this process gives up the re-laying cut first
+            # (the DMA cut of the aligned leaves stays), then the DMA cut,
+            # and forks those leaves whole from here on.
+            global _dma_cut_refused, _relay_cut_refused
+            if any(c is not None and c.relaid for c in cuts):
+                _relay_cut_refused, which = True, "re-laying cut"
+            else:
+                _dma_cut_refused, which = True, "row cut"
+            logger.warning(
+                "async_take: the fork's %s was refused by the kernel "
+                "compiler (%s); forking those leaves whole from now on",
+                which,
+                e,
+            )
     copies = [
-        c if cut is None else PiecedArray(a.shape, a.dtype, a.sharding, c, cut)
+        c if cut is None else PiecedArray(a.shape, a.dtype, a.sharding, c, cut.ranges)
         for a, c, cut in zip(group, copies, cuts)
     ]
+    relaid = [a for a, cut in zip(group, cuts) if cut is not None and cut.relaid]
+    telemetry.counter_add("capture.fork_relaid_leaves", len(relaid))
+    telemetry.counter_add("capture.fork_relaid_bytes", sum(int(a.nbytes) for a in relaid))
     telemetry.counter_add("capture.forked_leaves", len(group))
     if limit is not None:
         # Accounting feeds only the simulated limit; skip the per-shard
@@ -401,24 +413,25 @@ def _device_assignment_key(sharding) -> Any:
     return tuple(d.id for d in sharding._device_assignment)
 
 
-_RowRanges = Tuple[Tuple[int, int], ...]
-
-
-def _fork_cut(arr: Any) -> Optional[_RowRanges]:
-    """The row ranges the fork writes ``arr``'s copy as, or None for one
-    whole copy: a leaf that lives whole in one device's own memory, stays
-    one storage object, and is over the piece size in a shape and dtype the
-    cut takes (``io_preparers.array.piece_row_ranges``)."""
+def _fork_cut(arr: Any) -> Optional[PieceCut]:
+    """How the fork writes ``arr``'s copy as row-range pieces, or None for
+    one whole copy: a leaf that lives whole in one device's own memory,
+    stays one storage object, and is over the piece size in a shape and
+    dtype a mover takes (``io_preparers.array.device_piece_cut``)."""
     sharding = arr.sharding
     if len(sharding.device_set) != 1 or sharding.memory_kind not in (None, "device"):
         return None
-    if _cut_refused or should_chunk(arr):
+    if should_chunk(arr):
         return None
-    ranges = piece_row_ranges(arr.shape, arr.dtype)
-    return None if ranges is None else tuple(ranges)
+    cut = device_piece_cut(
+        arr.shape, arr.dtype, lambda: arr.format.layout.major_to_minor, _on_tpu(sharding)
+    )
+    if cut is None or (_relay_cut_refused if cut.relaid else _dma_cut_refused):
+        return None
+    return cut
 
 
-def _cut_rows(x: Any, ranges: _RowRanges, interpret: bool) -> List[Any]:
+def _cut_rows(x: Any, ranges: Sequence[Tuple[int, int]], interpret: bool) -> List[Any]:
     """``x``'s rows as one array a range, written by HBM-to-HBM DMAs: every
     byte is read once and written once, as ``jnp.copy`` would, and none is
     computed on, so every bit pattern of every dtype comes through (an XLA
@@ -456,16 +469,73 @@ def _cut_rows(x: Any, ranges: _RowRanges, interpret: bool) -> List[Any]:
     )
 
 
+def _bits_by_dma(x: Any, interpret: bool) -> Any:
+    """``x``'s bits as unsigned integers of its width, by one DMA between
+    two views of HBM: nothing is computed on. XLA's own ``bitcast-convert``
+    of bfloat16 is a kernel, and on the v5e it flushes the 254 denormals and
+    rewrites 253 NaN payloads (``PERF.md`` section 6, PR 46's probe)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+
+    def kernel(x_ref, out, sem):
+        copy = pltpu.make_async_copy(x_ref.bitcast(bits), out, sem)
+        copy.start()
+        copy.wait()
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(x.shape, bits),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        interpret=interpret,
+    )(x)
+
+
+def _relay_rows(
+    x: Any,
+    ranges: Sequence[Tuple[int, int]],
+    order: Optional[Tuple[int, ...]],
+    interpret: bool,
+) -> List[Any]:
+    """``x``'s rows as one array a range, **re-laid on the device**: a leaf
+    whose width is no multiple of the 128 lanes the device may hold column
+    first (a ``(2688, 10304)`` bfloat16 array lives as ``major_to_minor
+    (1, 0)``), its host copy comes in that order, and a strided copy on the
+    host makes it contiguous at a third of a GB/s. Here each range is
+    sliced and reshaped to ``(n / 128, 128)``, a shape the device holds
+    row-major and hands the host C-contiguous: the C-order bytes of the
+    rows, whatever the piece's dtype. XLA moves integers and 32-bit floats
+    bit for bit and sub-32-bit floats not (``slice_preserves_bits``), so
+    bfloat16 comes with ``order``, the device's own order of its
+    dimensions, and its bits become integers first, by a DMA that takes the
+    leaf in that order: XLA then puts no copy of its own before the DMA,
+    the two transposes compile to views."""
+    if order is not None:
+        inverse = tuple(int(i) for i in np.argsort(order))
+        x = _bits_by_dma(x.transpose(order), interpret).transpose(inverse)
+    row = int(np.prod(x.shape[1:]))
+    return [x[r0:r1].reshape((r1 - r0) * row // 128, 128) for r0, r1 in ranges]
+
+
 def _on_tpu(sharding: Any) -> bool:
     return all(d.platform == "tpu" for d in sharding.device_set)
 
 
-def _batch_copy_fn(
-    shardings: Tuple[Any, ...], cuts: Tuple[Optional[_RowRanges], ...]
-):
+def _batch_copy_fn(shardings: Tuple[Any, ...], cuts: Tuple[Optional[PieceCut], ...]):
     """The fork of one group: a whole ``jnp.copy`` a leaf, or its copy as
-    row-range pieces where ``cuts`` gives ranges (``_fork_cut``), all in one
-    jitted lambda: one program a take, every forked byte written once."""
+    row-range pieces where ``cuts`` gives a cut (``_fork_cut``), written by
+    the cut's mover, all in one jitted lambda: one program a take."""
+
+    def pieces(x, sharding, cut):
+        interpret = not _on_tpu(sharding)
+        if cut.relaid:
+            return _relay_rows(x, cut.ranges, cut.order, interpret)
+        return _cut_rows(x, cut.ranges, interpret)
 
     def build():
         import jax
@@ -473,13 +543,11 @@ def _batch_copy_fn(
 
         return jax.jit(
             lambda xs: [
-                jnp.copy(x)
-                if cut is None
-                else _cut_rows(x, cut, interpret=not _on_tpu(s))
+                jnp.copy(x) if cut is None else pieces(x, s, cut)
                 for x, s, cut in zip(xs, shardings, cuts)
             ],
             out_shardings=[
-                s if cut is None else [s] * len(cut)
+                s if cut is None else [s] * len(cut.ranges)
                 for s, cut in zip(shardings, cuts)
             ],
         )

@@ -31,7 +31,7 @@ import math
 import pickle
 import time
 from concurrent.futures import Executor
-from typing import Any, Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -133,10 +133,11 @@ def chunk_row_ranges(
 
 
 def _dma_moves(dtype: Any) -> bool:
-    """Whether the fork's row cut (a Pallas HBM-to-HBM DMA, which moves bits
-    and computes nothing) takes ``dtype``: bfloat16, the 32-bit types and
-    the 8- and 16-bit integers. Mosaic refuses bool, float16 and 64-bit
-    types; float16 and float8 never fork at all."""
+    """Whether the fork's row cut (Pallas HBM-to-HBM DMAs, which move bits
+    and compute nothing, and integer copies behind them) takes ``dtype``:
+    bfloat16, the 32-bit types and the 8- and 16-bit integers. Mosaic
+    refuses bool, float16 and 64-bit types; float16 and float8 never fork
+    at all."""
     dt = np.dtype(dtype)
     if dt.name == "bfloat16":
         return True
@@ -145,14 +146,34 @@ def _dma_moves(dtype: Any) -> bool:
     )
 
 
-def piece_row_ranges(shape, dtype: Any) -> Optional[List[Tuple[int, int]]]:
-    """Row ranges [r0, r1) of the pieces a forked leaf crosses to the host
-    in, each at most ``d2h.PIECE_BYTES`` (when a single row fits), or None
-    where the leaf goes whole: not over the piece size, one row, a dtype the
-    DMA does not take, or a shape it cannot cut. The DMA moves whole HBM
-    tiles: the last dimension is a multiple of 128 and the one before it of
-    8, so a 2-D leaf is cut at multiples of 8 rows and a deeper one between
-    any two of its slabs. By shape and dtype alone, on every backend."""
+class PieceCut(NamedTuple):
+    """How the fork writes a leaf as pieces: the row ranges [r0, r1), and
+    which mover writes them. ``relaid`` False: DMAs of whole HBM tiles, a
+    piece an array of the leaf's rows. ``relaid`` True: the leaf's bits as
+    integers, each range re-laid row-major into lanes of 128
+    (``io_preparer._relay_rows``); ``order`` is then, for a leaf whose bits
+    a DMA has to take first (``device_piece_cut``), the device's own order
+    of its dimensions, major to minor. Either way a piece's host copy is
+    the C-order bytes of its rows."""
+
+    ranges: Tuple[Tuple[int, int], ...]
+    relaid: bool
+    order: Optional[Tuple[int, ...]] = None
+
+
+def piece_row_ranges(shape, dtype: Any) -> Optional[PieceCut]:
+    """The pieces a forked leaf crosses to the host in, each at most
+    ``d2h.PIECE_BYTES`` (when a single unit of rows fits), or None where the
+    leaf goes whole: not over the piece size, one row, one piece, a dtype
+    the fork's movers do not take, or rows that no whole number of lanes
+    holds. One cut, two movers. The DMA moves whole HBM tiles: where the
+    last dimension is a multiple of 128 and the one before it of 8, a 2-D
+    leaf is cut at multiples of 8 rows and a deeper one between any two of
+    its slabs, and no byte is computed on. Any other shape (a width of 1856
+    or 10304, 1001 rows) the device may not even hold row-major, and its
+    host copy would be re-laid there by a strided copy: the fork re-lays
+    it, in integers, cut at multiples of the fewest rows that fill whole
+    lanes of 128 elements. By shape and dtype alone, on every backend."""
     shape = tuple(int(d) for d in shape)
     if len(shape) < 2 or not _dma_moves(dtype):
         return None
@@ -160,14 +181,41 @@ def piece_row_ranges(shape, dtype: Any) -> Optional[List[Tuple[int, int]]]:
     if itemsize * int(np.prod(shape)) <= d2h.PIECE_BYTES:
         return None
     unit = 8 if len(shape) == 2 else 1
-    if shape[0] % unit or shape[-1] % 128 or (len(shape) > 2 and shape[-2] % 8):
-        return None
+    relaid = bool(
+        shape[0] % unit or shape[-1] % 128 or (len(shape) > 2 and shape[-2] % 8)
+    )
+    if relaid:
+        unit = 128 // math.gcd(int(np.prod(shape[1:])), 128)
+        if shape[0] % unit:
+            return None
     ranges = chunk_row_ranges(
         (shape[0] // unit, unit) + shape[1:], itemsize, d2h.PIECE_BYTES
     )
     if len(ranges) < 2:
         return None
-    return [(r0 * unit, r1 * unit) for r0, r1 in ranges]
+    return PieceCut(tuple((r0 * unit, r1 * unit) for r0, r1 in ranges), relaid)
+
+
+def device_piece_cut(
+    shape, dtype: Any, device_order: Callable[[], Sequence[int]], on_tpu: bool
+) -> Optional[PieceCut]:
+    """``piece_row_ranges`` for a leaf as one device holds it: the cut the
+    fork program is built from, or None where the leaf goes whole. XLA
+    moves integers and 32-bit floats bit for bit, so those are re-laid as
+    they are. A bfloat16 leaf to re-lay has its bits taken first, by one
+    DMA of the whole leaf in the device's own order of its dimensions
+    (``device_order()``, major to minor; asked only for such a leaf), and
+    the TPU's kernel compiler takes whole HBM tiles only: handed a leaf
+    whose minor dimension in that order is no multiple of 128, or the one
+    before it of 8, it does not raise, it aborts the process. Such a leaf
+    stays whole."""
+    cut = piece_row_ranges(shape, dtype)
+    if cut is None or not cut.relaid or slice_preserves_bits(dtype):
+        return cut
+    order = tuple(int(i) for i in device_order())
+    if on_tpu and (int(shape[order[-1]]) % 128 or int(shape[order[-2]]) % 8):
+        return None
+    return cut._replace(order=order)
 
 
 class PiecedArray:
@@ -353,14 +401,19 @@ class ArrayBufferStager(BufferStager):
         # async defensive copy below.
         serializer = Serializer.RAW if self.stage_raw else self.entry.serializer
         arr = self.arr
+        ctx = d2h.get_active()
+        times = ctx.times if ctx is not None else None
+        location = self.entry.location
         if isinstance(arr, PiecedArray):
-            host = await _gather_pieces(arr, executor, self.entry.location)
+            host = await _gather_pieces(arr, executor, location)
         elif _is_jax_array(arr):
-            host = await _traced_to_host(
-                arr, executor, self.entry.location, _nbytes_of(arr)
-            )
+            host = await _traced_to_host(arr, executor, location, _nbytes_of(arr))
+            if times is not None:
+                times.count_host_relaid(host)
         else:
             host = np.asarray(arr)
+            if times is not None:
+                times.count_host_relaid(host)
             if (
                 self.is_async_snapshot
                 and serializer == Serializer.RAW
@@ -376,9 +429,6 @@ class ArrayBufferStager(BufferStager):
                 host = host.copy()
             elif not host.flags["C_CONTIGUOUS"]:
                 host = np.ascontiguousarray(host)
-        ctx = d2h.get_active()
-        times = ctx.times if ctx is not None else None
-        location = self.entry.location
         if serializer == Serializer.RAW:
             # Zero-copy fast path: the staged buffer IS a memoryview of the
             # resolved host buffer — no serialization pass, no intermediate

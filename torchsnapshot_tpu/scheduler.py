@@ -802,6 +802,9 @@ class _WritePipeline:
         telemetry.counter_add("d2h.window_waits", lanes.window_waits)
         telemetry.counter_add("d2h.pieces", lanes.pieces)
         telemetry.counter_add("d2h.pieced_bytes", lanes.pieced_bytes)
+        telemetry.counter_add(
+            "stage.host_relaid_bytes", self._staging_ctx.times.host_relaid_bytes
+        )
         telemetry.counter_add("scheduler.bytes_staged", self.bytes_staged)
         if self.bytes_deduped:
             telemetry.counter_add("scheduler.bytes_deduped", self.bytes_deduped)
