@@ -789,8 +789,9 @@ def run_programs_leg(ctx: dict) -> dict:
     # (2) Big leaves, default knobs: the fork of an async take, which cuts
     # the leaves over the piece size into row-range pieces (``d2h.PIECE_BYTES``:
     # on the chip every bfloat16 pattern and the random float32 bits cross in
-    # pieces), and the whole transfers of a synchronous take and of the
-    # dtypes that never fork (two hash grains and more).
+    # pieces), the same cut made a leaf at a time in a synchronous take's
+    # stage, and the whole transfers of the dtypes that never fork (two hash
+    # grains and more).
     from torchsnapshot_tpu import d2h
     from torchsnapshot_tpu.io_preparers.array import piece_row_ranges
 
@@ -852,17 +853,23 @@ def run_programs_leg(ctx: dict) -> dict:
             "(float32, bfloat16) in an async take",
         )
         check(
-            (pieces, pieced) == ((want_pieces, want_pieced) if mode == "async_take" else (0, 0)),
+            (pieces, pieced) == (want_pieces, want_pieced),
             f"[programs] {mode}: {pieces} pieces / {pieced} bytes, expected "
-            f"{want_pieces} / {want_pieced} in an async take and none in a synchronous one",
+            f"{want_pieces} / {want_pieced} (the fork's cut, or the synchronous stage's)",
         )
         relaid = (
             int(metrics.get("capture.fork_relaid_leaves", 0)),
             int(metrics.get("capture.fork_relaid_bytes", 0)),
         )
+        staged = (
+            int(metrics.get("stage.sync_cut_leaves", 0)),
+            int(metrics.get("stage.sync_cut_relaid_bytes", 0)),
+            int(metrics.get("stage.sync_cut_refused", 0)),
+        )
         on_host = int(metrics.get("stage.host_relaid_bytes", 0))
         log(
             f"[programs] {mode}: {relaid[0]} leaves / {relaid[1] / 1e6:.0f} MB re-laid by the fork, "
+            f"{staged[0]} leaves cut in the stage ({staged[1] / 1e6:.0f} MB re-laid there, {staged[2]} refused), "
             f"{on_host / 1e6:.0f} MB re-laid on the host"
         )
         want = (len(want_relaid), sum(host[k].nbytes for k in want_relaid))
@@ -871,8 +878,13 @@ def run_programs_leg(ctx: dict) -> dict:
             f"[programs] {mode}: the fork re-laid {relaid}, expected {want} in an async take "
             "and nothing in a synchronous one",
         )
-        if mode == "async_take":
-            check(on_host == 0, f"[programs] {mode}: {on_host} bytes were re-laid on the host")
+        want_staged = (sum(1 for r in cut.values() if r), want[1], 0) if mode == "take" else (0, 0, 0)
+        check(
+            staged == want_staged,
+            f"[programs] {mode}: the stage cut (leaves, re-laid bytes, refused) {staged}, expected {want_staged}",
+        )
+        check(on_host == 0, f"[programs] {mode}: {on_host} bytes were re-laid on the host")
+        out[f"stage_cut_leaves_{mode}"] = staged[0]
         out[f"big_leaves_forked_{mode}"] = forked
         out[f"pieces_{mode}"] = pieces
         out[f"fork_relaid_leaves_{mode}"] = relaid[0]

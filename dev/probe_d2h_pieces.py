@@ -486,7 +486,7 @@ def probe_relay():
         host = words.view(dt).reshape(shape)
         x = jax.device_put(host)
         back = np.asarray(jnp.copy(x))
-        cut = io_preparer._fork_cut(x)
+        cut = io_preparer.leaf_cut(x)
         rec = {
             "device_major_to_minor": list(x.format.layout.major_to_minor),
             "whole_host_c_contiguous": bool(back.flags.c_contiguous),

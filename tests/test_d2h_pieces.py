@@ -242,7 +242,7 @@ def test_a_refusal_by_the_kernel_compiler_forks_whole(grain, tmp_path, monkeypat
     path = str(tmp_path / "ck")
     with caplog.at_level("WARNING"):
         Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
-    assert "forking those leaves whole" in caplog.text
+    assert "row cut was refused" in caplog.text
     assert io_preparer._dma_cut_refused and not io_preparer._relay_cut_refused
     assert _metrics()["d2h.pieces"] == 0
     copies = io_preparer._defensive_device_copies(list(state.values()))
@@ -308,12 +308,20 @@ def test_pieced_take_writes_what_the_whole_leaf_path_writes(grain, tmp_path, mon
     _assert_restores(whole_path, host, state)
 
 
-def test_a_synchronous_take_pieces_nothing(grain, tmp_path) -> None:
+def test_a_synchronous_take_pieces_what_the_fork_would(grain, tmp_path) -> None:
+    """No fork, the same pieces (PR 48; it pieced nothing before): the stage
+    cuts each big leaf at its turn (``tests/test_sync_take_stage.py``)."""
     host, state = _patterned_state()
     path = str(tmp_path / "ck")
     Snapshot.take(path, {"m": StateDict(**state)})
     metrics = _metrics()
-    assert metrics["d2h.pieces"] == 0 and metrics["d2h.pieced_bytes"] == 0
+    pieced_names = ("every_bf16", "stack_bf16", "f32", "i8")
+    assert metrics["d2h.pieced_bytes"] == sum(host[n].nbytes for n in pieced_names)
+    assert metrics["d2h.pieces"] == sum(
+        len(piece_row_ranges(host[n].shape, host[n].dtype).ranges) for n in pieced_names
+    )
+    assert metrics["stage.sync_cut_leaves"] == len(pieced_names)
+    assert "capture.forked_leaves" not in metrics
     assert metrics["d2h.bytes"] == sum(v.nbytes for v in host.values())
     _assert_restores(path, host, state)
 
@@ -765,7 +773,7 @@ def _device_order(leaf):
 
 
 def _described_cut(leaf):
-    """``io_preparer._fork_cut`` of a leaf that is only described."""
+    """``io_preparer.leaf_cut`` of a leaf that is only described."""
     return device_piece_cut(leaf.shape, leaf.dtype, lambda: _device_order(leaf), True)
 
 
@@ -838,3 +846,44 @@ def test_the_fork_of_real_shapes_compiles_for_the_v5e_with_no_temporary(one_chip
     assert sum(nbytes) <= stats.output_size_in_bytes <= sum(nbytes) + padding + 4096 * len(leaves)
     kernels = sum(1 for c in cuts if c and (not c.relaid or c.order is not None))
     assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, relaid, order",
+    [
+        # a synchronous take cuts a leaf at a time: nemotron's stacks and in_proj
+        # the chip holds column first, their aligned twins, a vocabulary slice ...
+        ((16, 2688, 1856), "bfloat16", True, (0, 2, 1)),
+        ((2688, 10304), "bfloat16", True, (1, 0)),
+        ((16, 1856, 2688), "bfloat16", False, None),
+        ((16384, 2688), "bfloat16", False, None),
+        # ... pythia's embedding and fused qkv, trinity's 151 MB stack, float32 off the tiling
+        ((50432, 4096), "bfloat16", False, None),
+        ((4096, 12288), "bfloat16", False, None),
+        ((8, 3072, 3072), "bfloat16", False, None),
+        ((5376, 1100), "float32", True, None),
+    ],
+)
+def test_the_stage_cut_of_one_real_leaf_compiles_for_the_v5e(one_chip, shape, dtype, relaid, order) -> None:
+    """``io_preparer.cut_in_stage``'s program of one leaf, as the TPU's own
+    compiler takes it: pieces row-major, the outputs the leaf's bytes, at
+    most one leaf of temporaries where it is re-laid and none where a DMA
+    moves it, so a leaf's cut holds at most twice its bytes of HBM while the
+    program runs and once while its pieces cross."""
+    import jax
+    import jax.numpy as jnp
+
+    leaf = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
+    cut = _described_cut(leaf)
+    assert cut is not None and cut.relaid == relaid and cut.order == order
+    compiled = io_preparer._batch_copy_fn((one_chip,), (cut,), BoundedLRU()).lower([leaf]).compile()
+    (formats,) = compiled.output_formats
+    assert len(formats) == len(cut.ranges)
+    for f in formats:
+        assert tuple(f.layout.major_to_minor) == tuple(range(len(f.layout.major_to_minor)))
+    nbytes = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+    stats = compiled.memory_analysis()
+    assert nbytes <= stats.output_size_in_bytes <= nbytes + 4096 * len(cut.ranges)
+    assert stats.temp_size_in_bytes <= (nbytes * 1.06 if relaid else 0)
+    assert compiled.as_text().count("tpu_custom_call") == (0 if relaid and order is None else 1)
+

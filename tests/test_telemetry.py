@@ -306,6 +306,14 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
         "write_crc_sum_s",
         "write_bounce_warm_bytes",
         "write_bounce_fresh_bytes",
+        # ... and what a synchronous take's stage did with its big leaves.
+        "stage_sync_cut_leaves",
+        "stage_sync_cut_bytes",
+        "stage_sync_cut_relaid_bytes",
+        "stage_sync_cut_refused",
+        "stage_recycled_bytes",
+        "stage_fresh_bytes",
+        "stage_target_wait_s",
     } == set(snapshot_mod.LAST_SYNC_DRAIN_STATS)
 
     # Scheduler stage/io spans.

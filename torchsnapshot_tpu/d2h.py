@@ -31,16 +31,27 @@ Two cooperating pieces:
   its rows of the leaf's one host buffer and drops it, so a few tens of MiB
   are in flight where whole leaves put hundreds: the job's steps beside the
   drain lose a third to a half of what they lost (``PERF.md`` section 6,
-  PR 39). **What still crosses whole**, under :data:`HINT_WINDOW_BYTES` as
-  before, and is re-laid on the host where the device does not hold it
-  row-major (``stage.host_relaid_bytes``): a synchronous take, a sharded
-  leaf, a leaf at or under the piece size, a leaf of one row or whose rows
-  fill no whole number of 128 lanes, bool / float16 / float8 / 64-bit
-  leaves, and a bfloat16 leaf off the tiling that the device holds in no
-  whole tiles either (``io_preparers.array.piece_row_ranges``,
-  ``device_piece_cut``). The lanes tell the two apart by what they
-  are handed. Beyond a leaf's buffer the host holds at most one window of
-  resolved pieces.
+  PR 39). **A synchronous take** has no fork and no step beside it: its
+  stage cuts the same leaves (``io_preparer.leaf_cut``, the one predicate)
+  at their turn, a leaf at a time, by the same movers
+  (``io_preparer.cut_in_stage``), while the pieces cut and not yet
+  gathered hold at most :data:`CUT_WINDOW_BYTES` of HBM a device; they
+  cross under a wider window (:data:`SYNC_PIECE_WINDOW_BYTES`) and are
+  gathered into a view of the take's arena of host pages, handed from
+  leaf to leaf as hash and write finish with them (``host_arena.py``: a
+  first touch costs several times a write into a used page, and such a
+  take is nothing but its stage and its writes). A cut the device has no
+  room for, or the kernel compiler refuses, leaves that leaf whole.
+  **What still crosses whole**, under :data:`HINT_WINDOW_BYTES` as before,
+  and is re-laid on the host where the device does not hold it row-major
+  (``stage.host_relaid_bytes``): a sharded leaf, a chunk of a leaf split
+  into several objects, a compressed entry, a leaf at or under the piece
+  size, a leaf of one row or whose rows fill no whole number of 128 lanes,
+  bool / float16 / float8 / 64-bit leaves, and a bfloat16 leaf off the
+  tiling that the device holds in no whole tiles either
+  (``io_preparers.array.piece_row_ranges``, ``device_piece_cut``). The
+  lanes tell the two apart by what they are handed. Beyond a leaf's buffer
+  the host holds at most one window of resolved pieces.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
   sub-phase intervals (``d2h`` / ``serialize`` / ``hash`` / ``gather``). The
   scheduler derives ``stage_d2h_s``/``stage_serialize_s``/``stage_hash_s``/
@@ -66,7 +77,7 @@ import logging
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Deque, Dict, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -107,6 +118,19 @@ HINT_WINDOW_BYTES = 512 * 1024 * 1024
 # Values, not knobs.
 PIECE_BYTES = 16 * 1024 * 1024
 PIECE_WINDOW_BYTES = 64 * 1024 * 1024
+
+# A synchronous take has no fork, so its big leaves are cut at their turn in
+# the stage, by the fork's own movers, a leaf at a time
+# (``io_preparer.cut_for_stage``), and the pieces are a second copy of the
+# leaf in HBM until they have crossed. CUT_WINDOW_BYTES bounds the pieces of
+# the leaves cut and not yet gathered on one device (one leaf bigger than
+# the window goes alone): three 160 MB stacks, enough that the next leaf's
+# pieces exist while the last of this one's cross. No step runs beside such
+# a take, so its pieces are admitted under a wider window than the drain's:
+# SYNC_PIECE_WINDOW_BYTES (chosen on a v5e, ``CHANGES.md`` PR 48). Values
+# chosen by the take's kind, not knobs.
+CUT_WINDOW_BYTES = 512 * 1024 * 1024
+SYNC_PIECE_WINDOW_BYTES = 256 * 1024 * 1024
 
 
 def resolve_on_host(
@@ -163,6 +187,32 @@ class StageTimes:
             k: [] for k in self.KINDS
         }
         self.host_relaid_bytes = 0
+        # A synchronous take's leaves cut in the stage (``stage.sync_cut_*``)
+        # and, of the bytes gathered into one host buffer a leaf, those that
+        # landed in pages an earlier leaf had used and in fresh ones
+        # (``stage.recycled_bytes`` / ``stage.fresh_bytes``).
+        self.sync_cut_leaves = 0
+        self.sync_cut_bytes = 0
+        self.sync_cut_relaid_bytes = 0
+        self.sync_cut_refused = 0
+        self.recycled_bytes = 0
+        self.fresh_bytes = 0
+
+    def count_sync_cut(self, nbytes: int, relaid: bool) -> None:
+        with self._lock:
+            self.sync_cut_leaves += 1
+            self.sync_cut_bytes += nbytes
+            if relaid:
+                self.sync_cut_relaid_bytes += nbytes
+
+    def count_sync_cut_refused(self) -> None:
+        with self._lock:
+            self.sync_cut_refused += 1
+
+    def count_gather_pages(self, recycled: int, fresh: int) -> None:
+        with self._lock:
+            self.recycled_bytes += recycled
+            self.fresh_bytes += fresh
 
     def count_host_relaid(self, host: np.ndarray) -> None:
         """``stage.host_relaid_bytes``: a leaf the device holds in another
@@ -270,16 +320,60 @@ class timed:
 
 
 class _DeviceWindow:
-    """One device's hinted-and-unresolved bytes, and the transfers waiting
-    for room: ``(future, nbytes, limit)`` in the order they asked, ``limit``
-    the window the transfer is admitted under (a piece's or a whole
-    leaf's)."""
+    """One device's bytes admitted and not yet done (transfers hinted and
+    unresolved; a synchronous take's leaves cut and not yet gathered), and
+    those waiting for room: ``(future, nbytes, limit)`` in the order they
+    asked, ``limit`` the window the request is admitted under (a piece's or
+    a whole leaf's). Touched on the event loop only, so it needs no lock."""
 
-    __slots__ = ("ahead", "waiting")
+    __slots__ = ("ahead", "waiting", "hwm", "waits")
 
     def __init__(self) -> None:
         self.ahead = 0
         self.waiting: Deque[Tuple[Any, int, int]] = collections.deque()
+        self.hwm = 0  # the most ever admitted at once
+        self.waits = 0  # requests that waited for room
+
+    def _fits(self, nbytes: int, limit: int) -> bool:
+        return self.ahead == 0 or self.ahead + nbytes <= limit
+
+    def _admit(self, nbytes: int) -> None:
+        self.ahead += nbytes
+        self.hwm = max(self.hwm, self.ahead)
+
+    async def room(self, nbytes: int, limit: int, loop) -> None:
+        """Wait until ``nbytes`` fit under ``limit`` (one request bigger
+        than the window goes alone), in the order asked."""
+        if not self.waiting and self._fits(nbytes, limit):
+            self._admit(nbytes)
+            return
+        self.waits += 1
+        turn = loop.create_future()
+        self.waiting.append((turn, nbytes, limit))
+        try:
+            await turn
+        except BaseException:
+            # Cancelled (an abort's sweep). Still in line: ``_pump`` drops
+            # the cancelled turn when it comes up. Given room in the same
+            # turn of the loop: hand it on.
+            if not turn.cancelled():
+                self.done(nbytes)
+            raise
+
+    def done(self, nbytes: int) -> None:
+        self.ahead -= nbytes
+        self._pump()
+
+    def _pump(self) -> None:
+        """Give room to the requests at the head of the line, in order."""
+        while self.waiting:
+            turn, nbytes, limit = self.waiting[0]
+            if not turn.cancelled():
+                if not self._fits(nbytes, limit):
+                    break
+                self._admit(nbytes)
+                turn.set_result(None)
+            self.waiting.popleft()
 
 
 class TransferLanes:
@@ -294,15 +388,31 @@ class TransferLanes:
         self.lane_count = lanes if lanes is not None else knobs.get_d2h_lanes()
         self._executor: Optional[ThreadPoolExecutor] = None
         self._windows: Dict[Optional[int], _DeviceWindow] = {}
-        # What the window did, for the take's telemetry: the most bytes ever
-        # hinted and unresolved on one device (``d2h.hinted_ahead_hwm_bytes``)
-        # and the transfers that waited for room (``d2h.window_waits``).
-        self.hinted_ahead_hwm_bytes = 0
-        self.window_waits = 0
-        # The transfers that were pieces of a leaf the fork cut, and their
-        # bytes (``d2h.pieces``, ``d2h.pieced_bytes``).
+        # A synchronous take's cuts (:data:`CUT_WINDOW_BYTES`).
+        self._cut_windows: Dict[Optional[int], _DeviceWindow] = {}
+        # The transfers that were pieces of a leaf (cut by the fork, or by a
+        # synchronous take's stage), and their bytes (``d2h.pieces``,
+        # ``d2h.pieced_bytes``).
         self.pieces = 0
         self.pieced_bytes = 0
+
+    # What the windows did, for the take's telemetry: the most bytes ever
+    # hinted and unresolved on one device (``d2h.hinted_ahead_hwm_bytes``),
+    # the transfers that waited for room (``d2h.window_waits``), and the
+    # most HBM a synchronous take's cut pieces held on one device
+    # (``stage.sync_cut_hwm_bytes``).
+
+    @property
+    def hinted_ahead_hwm_bytes(self) -> int:
+        return max((w.hwm for w in self._windows.values()), default=0)
+
+    @property
+    def window_waits(self) -> int:
+        return sum(w.waits for w in self._windows.values())
+
+    @property
+    def cut_hwm_bytes(self) -> int:
+        return max((w.hwm for w in self._cut_windows.values()), default=0)
 
     def executor(self) -> ThreadPoolExecutor:
         if self._executor is None:
@@ -312,6 +422,12 @@ class TransferLanes:
             )
         return self._executor
 
+    def cut_window(self, device: Optional[int]) -> _DeviceWindow:
+        """``device``'s window of leaves cut and not yet gathered: ``await
+        room(nbytes, CUT_WINDOW_BYTES, loop)`` before the cut, ``done``
+        once the last piece has crossed (or the cut failed)."""
+        return self._cut_windows.setdefault(device, _DeviceWindow())
+
     async def start(
         self,
         arr: Any,
@@ -320,15 +436,17 @@ class TransferLanes:
         times: Optional[StageTimes] = None,
         location: str = "",
         into: Optional[np.ndarray] = None,
+        piece_window_bytes: int = PIECE_WINDOW_BYTES,
     ) -> np.ndarray:
         """``arr`` on the host: wait for room in its device's window, hint
         the transfer, resolve it on a lane.
 
         ``into`` (a writable ``uint8`` view of ``nbytes``) marks ``arr`` as
-        one piece of a leaf that the fork cut: it is admitted under
-        :data:`PIECE_WINDOW_BYTES`, and the lane that resolved it copies it
-        into ``into`` (its rows of the leaf's host buffer) and drops it
-        (:func:`resolve_on_host`).
+        one piece of a leaf that was cut on the device: it is admitted under
+        ``piece_window_bytes`` (:data:`PIECE_WINDOW_BYTES` beside a step,
+        :data:`SYNC_PIECE_WINDOW_BYTES` in a synchronous take), and the lane
+        that resolved it copies it into ``into`` (its rows of the leaf's
+        host buffer) and drops it (:func:`resolve_on_host`).
 
         The resolve is timed inside the lane thread, so the recorded ``d2h``
         interval is transfer time only (a piece's copy into its leaf's
@@ -345,58 +463,16 @@ class TransferLanes:
 
         limit = HINT_WINDOW_BYTES
         if into is not None:
-            limit = PIECE_WINDOW_BYTES
+            limit = piece_window_bytes
             self.pieces += 1
             self.pieced_bytes += nbytes
         window = self._windows.setdefault(device, _DeviceWindow())
-        await self._room(window, nbytes, limit, loop)
+        await window.room(nbytes, limit, loop)
         try:
             hint_copy_to_host(arr)
             return await loop.run_in_executor(self.executor(), resolve)
         finally:
-            self._resolved(window, nbytes)
-
-    def _fits(self, window: _DeviceWindow, nbytes: int, limit: int) -> bool:
-        return window.ahead == 0 or window.ahead + nbytes <= limit
-
-    def _admit(self, window: _DeviceWindow, nbytes: int) -> None:
-        window.ahead += nbytes
-        if window.ahead > self.hinted_ahead_hwm_bytes:
-            self.hinted_ahead_hwm_bytes = window.ahead
-
-    async def _room(
-        self, window: _DeviceWindow, nbytes: int, limit: int, loop
-    ) -> None:
-        if not window.waiting and self._fits(window, nbytes, limit):
-            self._admit(window, nbytes)
-            return
-        self.window_waits += 1
-        turn = loop.create_future()
-        window.waiting.append((turn, nbytes, limit))
-        try:
-            await turn
-        except BaseException:
-            # Cancelled (an abort's sweep). Still in line: ``_pump`` drops
-            # the cancelled turn when it comes up. Given room in the same
-            # turn of the loop: hand it on.
-            if not turn.cancelled():
-                self._resolved(window, nbytes)
-            raise
-
-    def _resolved(self, window: _DeviceWindow, nbytes: int) -> None:
-        window.ahead -= nbytes
-        self._pump(window)
-
-    def _pump(self, window: _DeviceWindow) -> None:
-        """Give room to the transfers at the head of the line, in order."""
-        while window.waiting:
-            turn, nbytes, limit = window.waiting[0]
-            if not turn.cancelled():
-                if not self._fits(window, nbytes, limit):
-                    break
-                self._admit(window, nbytes)
-                turn.set_result(None)
-            window.waiting.popleft()
+            window.done(nbytes)
 
     def shutdown(self, cancel_queued: bool = False) -> None:
         if self._executor is not None:
@@ -405,14 +481,27 @@ class TransferLanes:
 
 
 class StagingContext:
-    """What one write pipeline exposes to its stagers: the transfer lanes
-    and the sub-phase interval sink."""
+    """What one write pipeline exposes to its stagers: the transfer lanes,
+    the sub-phase interval sink and, in a synchronous take's pipeline,
+    ``arena``: a call that gives the arena its big leaves' host buffers are
+    leased from (``host_arena.HostArena``, made at the first call; None
+    beside a step, where a gather lands in fresh pages), with the window
+    that take's pieces are admitted under."""
 
-    __slots__ = ("lanes", "times")
+    __slots__ = ("lanes", "times", "arena", "piece_window_bytes")
 
-    def __init__(self, lanes: TransferLanes, times: StageTimes) -> None:
+    def __init__(
+        self,
+        lanes: TransferLanes,
+        times: StageTimes,
+        arena: Optional[Callable[[], Any]] = None,
+    ) -> None:
         self.lanes = lanes
         self.times = times
+        self.arena = arena
+        self.piece_window_bytes = (
+            PIECE_WINDOW_BYTES if arena is None else SYNC_PIECE_WINDOW_BYTES
+        )
 
 
 _ACTIVE: contextvars.ContextVar[Optional[StagingContext]] = (

@@ -6,8 +6,9 @@ at 1.0 GB/s and copies into touched pages at 7.9; ``PERF.md`` section 6),
 and a restore used to read every device-bound leaf into an ``np.empty`` of
 its own: the readers paid a first touch for every byte of the state. The
 native read pool keeps its bounce buffers warm for the same reason
-(``native/tss_io.cpp``); this is the restore's side of it, and the first
-user of "one owner of host pages" (ROADMAP D15).
+(``native/tss_io.cpp``); this is "one owner of host pages" (ROADMAP D15)
+for the two operations that have nothing beside them to disturb: a restore
+and a synchronous take.
 
 A :class:`HostArena` is ``capacity`` bytes, allocated on first use and
 touched by the first leaves that use them. An entry's targets are one
@@ -26,9 +27,18 @@ read it waits for needs. Where there is no such lease, or the entry is
 larger than the arena, the entry takes fresh pages of its own (``None``)
 as before.
 
-One user: ``Snapshot.restore``, for targets that exist only to be put on a
-device whose ``device_put`` copies (:func:`copies_on_put`). The save side's
-buffers stay fresh: recycled ones cost the steps more (``PERF.md``, PR 39).
+Two users, one policy: **pages are recycled where no train step runs beside
+the operation, and stay fresh where one does.** ``Snapshot.restore`` leases
+the targets that exist only to be put on a device whose ``device_put``
+copies (:func:`copies_on_put`). ``Snapshot.take`` leases the one host buffer
+into which a big leaf's pieces are gathered (a lease of one read, taken at
+the leaf's turn in the stage and given back when its hash and its storage
+write are done, or when the request fails or is cancelled: every lease that
+is out is then worth waiting for, and the writers pace the take through
+it); the arena is the write pipeline's, made at the first such leaf and
+closed with the pipeline. An ``async_take`` drain lands its gathers in fresh
+pages of each leaf's own: recycled ones moved faster and cost the steps
+beside the drain 4-9 points of their rate (``PERF.md``, PR 39).
 """
 
 from __future__ import annotations
@@ -137,8 +147,8 @@ class Lease:
 
 
 class HostArena:
-    """``capacity_bytes`` of host pages for one restore. Nothing is
-    allocated before the first lease takes room."""
+    """``capacity_bytes`` of host pages for one restore or one synchronous
+    take. Nothing is allocated before the first lease takes room."""
 
     def __init__(self, capacity_bytes: int = CAPACITY_BYTES) -> None:
         self.capacity = capacity_bytes // PAGE_BYTES * PAGE_BYTES
@@ -221,17 +231,27 @@ class HostArena:
     def _give_back(self, lease: Lease) -> None:
         with self._lock:
             block, lease._block, lease.views = lease._block, None, None
-            if block is None or self._closed:
+            if self._closed:
                 return
-            self._out.remove(lease)
-            free = sorted(self._free + [block])
-            merged = [free[0]]
-            for start, end in free[1:]:
-                if start == merged[-1][1]:
-                    merged[-1] = (merged[-1][0], end)
-                else:
-                    merged.append((start, end))
-            self._free = merged
+            if lease in self._waiters:
+                # Given back while it waited (its entry was cancelled): it
+                # must not be handed a block nobody would return.
+                self._waiters.remove(lease)
+                lease._decided = True
+                if not self._waiters:
+                    self.wait_s += time.monotonic() - self._waiting_since
+            elif block is None:
+                return
+            if block is not None:
+                self._out.remove(lease)
+                free = sorted(self._free + [block])
+                merged = [free[0]]
+                for start, end in free[1:]:
+                    if start == merged[-1][1]:
+                        merged[-1] = (merged[-1][0], end)
+                    else:
+                        merged.append((start, end))
+                self._free = merged
             # Oldest first, and nobody past the first that must go on
             # waiting: reads come largest first, so what fits the second
             # fits the first.

@@ -283,29 +283,14 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
             )
     shardings = tuple(a.sharding for a in group)
     while True:
-        cuts = tuple(_fork_cut(a) for a in group)
+        cuts = tuple(leaf_cut(a) for a in group)
         try:
             copies = _batch_copy_fn(shardings, cuts)(group)
             break
         except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades
             if not any(cuts) or "Mosaic" not in str(e):
                 raise
-            # Both movers hand the leaf to a Pallas kernel, and its compiler
-            # may refuse a shape the rule lets through. The take must not
-            # fail for it: this process gives up the re-laying cut first
-            # (the DMA cut of the aligned leaves stays), then the DMA cut,
-            # and forks those leaves whole from here on.
-            global _dma_cut_refused, _relay_cut_refused
-            if any(c is not None and c.relaid for c in cuts):
-                _relay_cut_refused, which = True, "re-laying cut"
-            else:
-                _dma_cut_refused, which = True, "row cut"
-            logger.warning(
-                "async_take: the fork's %s was refused by the kernel "
-                "compiler (%s); forking those leaves whole from now on",
-                which,
-                e,
-            )
+            _give_up_cut(cuts, e)
     copies = [
         c if cut is None else PiecedArray(a.shape, a.dtype, a.sharding, c, cut.ranges)
         for a, c, cut in zip(group, copies, cuts)
@@ -413,11 +398,32 @@ def _device_assignment_key(sharding) -> Any:
     return tuple(d.id for d in sharding._device_assignment)
 
 
-def _fork_cut(arr: Any) -> Optional[PieceCut]:
-    """How the fork writes ``arr``'s copy as row-range pieces, or None for
-    one whole copy: a leaf that lives whole in one device's own memory,
-    stays one storage object, and is over the piece size in a shape and
-    dtype a mover takes (``io_preparers.array.device_piece_cut``)."""
+def _give_up_cut(cuts: Sequence[Optional[PieceCut]], e: BaseException) -> None:
+    """Both movers hand the leaf to a Pallas kernel, and its compiler may
+    refuse a shape the rule lets through. A take must not fail for it: this
+    process gives up the re-laying cut first (the DMA cut of the aligned
+    leaves stays), then the DMA cut, and moves those leaves whole from here
+    on."""
+    global _dma_cut_refused, _relay_cut_refused
+    if any(c is not None and c.relaid for c in cuts):
+        _relay_cut_refused, which = True, "re-laying cut"
+    else:
+        _dma_cut_refused, which = True, "row cut"
+    logger.warning(
+        "the %s was refused by the kernel compiler (%s); the big leaves "
+        "it would take are copied and cross whole from now on",
+        which,
+        e,
+    )
+
+
+def leaf_cut(arr: Any) -> Optional[PieceCut]:
+    """How ``arr`` leaves the device as row-range pieces, or None where it
+    goes whole: a leaf that lives whole in one device's own memory, stays
+    one storage object, and is over the piece size in a shape and dtype a
+    mover takes (``io_preparers.array.device_piece_cut``). One predicate for
+    the two places that cut: ``async_take``'s fork, which writes the copy as
+    the pieces, and a synchronous take's stage (:func:`cut_in_stage`)."""
     sharding = arr.sharding
     if len(sharding.device_set) != 1 or sharding.memory_kind not in (None, "device"):
         return None
@@ -526,9 +532,13 @@ def _on_tpu(sharding: Any) -> bool:
     return all(d.platform == "tpu" for d in sharding.device_set)
 
 
-def _batch_copy_fn(shardings: Tuple[Any, ...], cuts: Tuple[Optional[PieceCut], ...]):
+def _batch_copy_fn(
+    shardings: Tuple[Any, ...],
+    cuts: Tuple[Optional[PieceCut], ...],
+    cache: Optional[BoundedLRU] = None,
+):
     """The fork of one group: a whole ``jnp.copy`` a leaf, or its copy as
-    row-range pieces where ``cuts`` gives a cut (``_fork_cut``), written by
+    row-range pieces where ``cuts`` gives a cut (``leaf_cut``), written by
     the cut's mover, all in one jitted lambda: one program a take."""
 
     def pieces(x, sharding, cut):
@@ -552,10 +562,38 @@ def _batch_copy_fn(shardings: Tuple[Any, ...], cuts: Tuple[Optional[PieceCut], .
             ],
         )
 
-    return _BATCH_COPIES.get_or_build((shardings, cuts), build)
+    cache = _BATCH_COPIES if cache is None else cache
+    return cache.get_or_build((shardings, cuts), build)
 
 
 _BATCH_COPIES = BoundedLRU()
+# A synchronous take's programs of one leaf each (``cut_in_stage``): one a
+# distinct cut and sharding, whatever the leaf's other dimensions (``jit``
+# keeps an executable a shape). Apart from the forks', which they would push
+# out: a state has more kinds of big leaf than a job has state structures.
+_STAGE_CUTS = BoundedLRU(64)
+
+
+def cut_in_stage(arr: Any, cut: PieceCut) -> Optional[PiecedArray]:
+    """A synchronous take's cut of one leaf at its turn in the stage: the
+    pieces the fork would have written (``cut`` from :func:`leaf_cut`), by
+    the fork's own movers in a program of the one leaf, so that the leaf
+    crosses under the pieces' window, lands row-major, and is gathered into
+    host pages the take has used before (``io_preparers.array``). The
+    caller bounds the HBM the pieces hold (``d2h.CUT_WINDOW_BYTES``) and
+    leaves the leaf whole where the device has no room for them (an
+    allocation failure raised here or, for the program's own temporaries,
+    at a piece's resolve: ``_is_oom_error``). None where the kernel
+    compiler refuses the mover: the leaf then crosses whole too, as it did
+    before this existed, and the take goes on."""
+    try:
+        (pieces,) = _batch_copy_fn((arr.sharding,), (cut,), _STAGE_CUTS)([arr])
+    except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades here
+        if "Mosaic" not in str(e):
+            raise
+        _give_up_cut((cut,), e)
+        return None
+    return PiecedArray(arr.shape, arr.dtype, arr.sharding, pieces, cut.ranges)
 
 
 def capture_flattened(
@@ -688,7 +726,11 @@ def prepare_write(
                 )
             else:
                 entry, reqs = ArrayIOPreparer.prepare_write(
-                    storage_path, arr, replicated, is_async_snapshot and not is_captured
+                    storage_path,
+                    arr,
+                    replicated,
+                    is_async_snapshot and not is_captured,
+                    whole_leaf=True,
                 )
             stager_s += time.monotonic() - t0
             manifest[logical_path] = entry

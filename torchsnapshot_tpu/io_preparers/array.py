@@ -276,7 +276,8 @@ async def _traced_to_host(
 ) -> np.ndarray:
     """Resolve one device→host transfer, attributed as ``stage.d2h``.
     ``into``: ``arr`` is a piece of a leaf, to land in these bytes of the
-    leaf's host buffer (``d2h.TransferLanes.start``).
+    leaf's host buffer (``d2h.TransferLanes.start``), admitted under the
+    pieces' window of the pipeline's kind.
 
     Inside a write pipeline (an active :class:`~..d2h.StagingContext`) the
     transfer waits for room in its device's hint window, is hinted, and
@@ -290,7 +291,13 @@ async def _traced_to_host(
     if ctx is not None:
         loop = asyncio.get_running_loop()
         return await ctx.lanes.start(
-            arr, nbytes, loop, times=ctx.times, location=location, into=into
+            arr,
+            nbytes,
+            loop,
+            times=ctx.times,
+            location=location,
+            into=into,
+            piece_window_bytes=ctx.piece_window_bytes,
         )
     tm = telemetry.get_active()
     if tm is None:
@@ -302,17 +309,22 @@ async def _traced_to_host(
 
 
 async def _gather_pieces(
-    arr: PiecedArray, executor: Optional[Executor], location: str
+    arr: PiecedArray,
+    executor: Optional[Executor],
+    location: str,
+    into: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """The pieced leaf whole in one host buffer: every piece is started
     through the lanes at once (each waits its turn under the pieces' window)
-    and lands in its rows. Beyond the buffer, which the request's admission
-    debited, the host holds at most one window of resolved pieces. A failing
-    piece cancels the others and waits them out, so the window is balanced
-    when the error leaves here."""
-    host = np.empty(arr.shape, dtype=arr.dtype)
+    and lands in its rows. The buffer is ``into`` (``arr.nbytes`` of lent
+    ``uint8``, which is then what comes back: the C-order bytes of the leaf)
+    or fresh pages of the leaf's own. Beyond the buffer, which the request's
+    admission debited, the host holds at most one window of resolved pieces.
+    A failing piece cancels the others and waits them out, so the window is
+    balanced when the error leaves here."""
+    host = into if into is not None else np.empty(arr.shape, dtype=arr.dtype)
     flat = host.reshape(-1).view(np.uint8)
-    row_bytes = host.nbytes // host.shape[0]
+    row_bytes = arr.nbytes // arr.shape[0]
     tasks = [
         asyncio.ensure_future(
             _traced_to_host(
@@ -341,10 +353,17 @@ class ArrayBufferStager(BufferStager):
         arr: Any,  # jax.Array | np.ndarray
         entry: ArrayEntry,
         is_async_snapshot: bool = False,
+        whole_leaf: bool = False,
     ) -> None:
         self.arr = arr
         self.entry = entry
         self.is_async_snapshot = is_async_snapshot
+        # ``arr`` is a leaf of the state as it stands, one storage object:
+        # not a chunk's or a shard's slice. A synchronous take may cut such
+        # a leaf on the device at its turn (``_stage_cut``).
+        self.whole_leaf = whole_leaf
+        # The staged buffer's pages, where the take's arena lent them.
+        self._lease: Optional[Any] = None
         # Sole owner of level resolution, at construction (== prepare
         # time), never at stage time: a deferred background drain must not
         # re-read knobs whose env changed since (wrong level breaks the
@@ -382,6 +401,12 @@ class ArrayBufferStager(BufferStager):
         """Drop the array reference between takes so a cached prepared
         state never pins device/host buffers past its pipeline's commit."""
         self.arr = None
+        self.release_staged()
+
+    def release_staged(self) -> None:
+        lease, self._lease = self._lease, None
+        if lease is not None:
+            lease.give_back()
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         if not self.entry.frame_bytes:
@@ -406,10 +431,22 @@ class ArrayBufferStager(BufferStager):
         location = self.entry.location
         if isinstance(arr, PiecedArray):
             host = await _gather_pieces(arr, executor, location)
-        elif _is_jax_array(arr):
-            host = await _traced_to_host(arr, executor, location, _nbytes_of(arr))
             if times is not None:
-                times.count_host_relaid(host)
+                times.count_gather_pages(0, host.nbytes)
+        elif _is_jax_array(arr):
+            host = None
+            if (
+                ctx is not None
+                and ctx.arena is not None
+                and self.whole_leaf
+                and serializer == Serializer.RAW
+                and not self.stage_raw
+            ):
+                host = await self._stage_cut(arr, ctx, executor, location)
+            if host is None:
+                host = await _traced_to_host(arr, executor, location, _nbytes_of(arr))
+                if times is not None:
+                    times.count_host_relaid(host)
         else:
             host = np.asarray(arr)
             if times is not None:
@@ -484,6 +521,61 @@ class ArrayBufferStager(BufferStager):
             payload = pickle.dumps(host, protocol=pickle.HIGHEST_PROTOCOL)
             work.sized(len(payload))
         return payload
+
+    async def _stage_cut(
+        self, arr: Any, ctx: "d2h.StagingContext", executor: Optional[Executor], location: str
+    ) -> Optional[np.ndarray]:
+        """A synchronous take's big leaf (``ctx.arena``: no step runs beside
+        this pipeline, and it owns an arena of host pages): where the fork
+        would have cut it (``io_preparer.leaf_cut``, the one predicate), it
+        is cut now, by the fork's movers, and its pieces cross under the
+        pieces' window into a view of the arena that an earlier leaf of the
+        take has used, or into fresh pages where the arena has no room worth
+        waiting for. Returns the leaf's C-order bytes, or None where it is
+        no such leaf, the device has no room for its pieces, or the kernel
+        compiler refuses them: it then crosses whole, as before.
+
+        The view is taken before the cut, so the pieces hold HBM only while
+        they cross (``d2h.CUT_WINDOW_BYTES`` a device bounds them), and is
+        given back by :meth:`release_staged` once hash and write are done
+        with it, or here where the stage fails."""
+        from ..io_preparer import _is_oom_error, cut_in_stage, leaf_cut
+
+        cut = leaf_cut(arr)
+        if cut is None:
+            return None
+        nbytes = _nbytes_of(arr)
+        loop = asyncio.get_running_loop()
+        lease = self._lease = ctx.arena().lease([nbytes], reads=1)
+        pieced = views = None
+        try:
+            views = await lease.acquire()
+            window = ctx.lanes.cut_window(next(iter(arr.devices())).id)
+            await window.room(nbytes, d2h.CUT_WINDOW_BYTES, loop)
+            try:
+                pieced = cut_in_stage(arr, cut)
+                if pieced is not None:
+                    host = await _gather_pieces(
+                        pieced, executor, location, into=views[0] if views else None
+                    )
+            finally:
+                window.done(nbytes)
+        except BaseException as e:
+            self.release_staged()
+            # The program's own temporaries are allocated as it runs: a
+            # device that ran out then says so at a piece's resolve.
+            if not _is_oom_error(e):
+                raise
+            logger.info("no room on the device for the pieces of %s: %s", location, e)
+            pieced = None
+        if pieced is None:
+            self.release_staged()
+            ctx.times.count_sync_cut_refused()
+            return None
+        ctx.times.count_sync_cut(nbytes, cut.relaid)
+        recycled = lease.recycled_bytes if views else 0
+        ctx.times.count_gather_pages(recycled, nbytes - recycled)
+        return host
 
     def get_staging_cost_bytes(self) -> int:
         # A pieced leaf costs its one host buffer, like a whole one; the
@@ -875,6 +967,7 @@ class ArrayIOPreparer:
         arr: Any,
         replicated: bool = False,
         is_async_snapshot: bool = False,
+        whole_leaf: bool = False,
     ) -> Tuple[ArrayEntry, List[WriteReq]]:
         host_like = arr  # dtype/shape probes work on jax and numpy alike
         dtype = np.dtype(host_like.dtype)
@@ -898,7 +991,7 @@ class ArrayIOPreparer:
             replicated=replicated,
             frame_bytes=frame_bytes,
         )
-        stager = ArrayBufferStager(arr, entry, is_async_snapshot)
+        stager = ArrayBufferStager(arr, entry, is_async_snapshot, whole_leaf)
         reqs = [WriteReq(path=storage_path, buffer_stager=stager)]
         if frame_bytes:
             reqs.append(

@@ -45,6 +45,12 @@ class BufferStager(abc.ABC):
         """Estimated peak host memory consumed by :meth:`stage_buffer`."""
         ...
 
+    def release_staged(self) -> None:
+        """The staged buffer's last consumer is done with it (hash and
+        storage write finished, or the request failed or was cancelled):
+        a stager whose buffer is lent memory gives it back. Called by the
+        write pipeline, at most once effective a take."""
+
 
 @dataclass
 class WriteReq:

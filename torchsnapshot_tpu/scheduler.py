@@ -61,7 +61,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import psutil
 
-from . import d2h, hashing, restore_times, telemetry
+from . import d2h, hashing, host_arena, restore_times, telemetry
 from .engine import GraphExecutor, Node, Priority
 from .engine.executor import Budget as _Budget  # noqa: F401 - test surface
 from .engine.executor import ProgressReporter as _ProgressReporter  # noqa: F401
@@ -227,6 +227,14 @@ class PipelinePools:
         self._staging = self._hash = self._consuming = self._lanes = None
 
 
+def _release_staged(stager) -> None:
+    """``BufferStager.release_staged``, for a stager that is one (a
+    caller's own may only quack like one)."""
+    release = getattr(stager, "release_staged", None)
+    if release is not None:
+        release()
+
+
 class _WritePipeline:
     """The write-side graph builder + domain node bodies. Builds one engine
     chain per request (``stage → io``) and keeps the checkpoint semantics —
@@ -246,6 +254,7 @@ class _WritePipeline:
         ] = None,
         pools: Optional[PipelinePools] = None,
         priority: Optional[Priority] = None,
+        synchronous: bool = False,
     ) -> None:
         self.storage = storage
         # Thread pools: shared with the operation's other pipelines when the
@@ -297,9 +306,20 @@ class _WritePipeline:
         self._tm = telemetry.get_active()
         # Parallel D2H lanes + stage-time attribution, exposed to stagers
         # via the d2h contextvar around node-task creation.
+        # ``synchronous``: the pipeline of a ``Snapshot.take``, which no
+        # step runs beside. Its big leaves are cut on the device at their
+        # turn and gathered into views of an arena of host pages handed from
+        # leaf to leaf (``host_arena.py``): inside what the budget admits
+        # for those leaves, allocated at the first lease, closed with the
+        # pipeline. A drain beside a step gets none: its gathers land in
+        # fresh pages (``PERF.md``, PR 39).
+        self._arena: Optional[host_arena.HostArena] = None
+        self._arena_capacity = min(host_arena.CAPACITY_BYTES, memory_budget_bytes)
+        self._stagers = [r.buffer_stager for r in write_reqs]
         self._staging_ctx = d2h.StagingContext(
             lanes=self.pools.transfer_lanes(),
             times=d2h.StageTimes(tm=self._tm),
+            arena=self._host_arena if synchronous else None,
         )
         # Beside the io stream: what the plugin's native writes did, handed
         # to it with each object's ``WriteIO``.
@@ -342,6 +362,13 @@ class _WritePipeline:
             self._add_request(req)
 
     # ----------------------------------------------------- engine plumbing
+
+    def _host_arena(self) -> host_arena.HostArena:
+        """Made when the first big leaf asks for pages: a take of small
+        leaves has none."""
+        if self._arena is None:
+            self._arena = host_arena.HostArena(self._arena_capacity)
+        return self._arena
 
     def _staging_scope(self):
         """Context manager applied around node-task creation so every
@@ -425,6 +452,9 @@ class _WritePipeline:
             finally:
                 nbytes = memoryview(buf).nbytes
                 self.progress.note_written(nbytes)
+                # Hash and write are done with the bytes, whichever way
+                # they ended: lent pages go to the next leaf.
+                _release_staged(req.buffer_stager)
             self.progress.note_request_done()
 
         return io
@@ -614,6 +644,10 @@ class _WritePipeline:
         budget balanced and no staging/io coroutine running against a
         torn-down pipeline."""
         await self._engine.abort()
+        # A leaf staged into lent pages whose io node never ran (it waited
+        # for a slot, or rode the edge) still holds them.
+        for stager in self._stagers:
+            _release_staged(stager)
         # Debug-ledger cross-check: an aborted pipeline must leave zero
         # outstanding bytes; a leak here raises naming the debiting sites
         # (chained onto the failure that triggered the abort).
@@ -737,8 +771,27 @@ class _WritePipeline:
         # EXCEED stage_busy_s (that overlap is the speedup); each value
         # reads "seconds this sub-stream was busy". Each view clips to its
         # own windows.
-        sub = self._staging_ctx.times.intervals()
+        times = self._staging_ctx.times
+        sub = times.intervals()
         written = self._write_times.intervals()
+        # What a synchronous take's stage did with its big leaves (all 0
+        # beside a step but ``fresh_bytes``, the gathers of forked pieces):
+        # leaves cut on the device at their turn, their bytes, of those the
+        # bytes re-laid there, and leaves the device or the kernel compiler
+        # refused; of the gathered bytes, those that landed in pages of the
+        # arena an earlier leaf had used, and in fresh ones; and the seconds
+        # in which some leaf waited for room in the arena (a union).
+        staged = {
+            "stage_sync_cut_leaves": times.sync_cut_leaves,
+            "stage_sync_cut_bytes": times.sync_cut_bytes,
+            "stage_sync_cut_relaid_bytes": times.sync_cut_relaid_bytes,
+            "stage_sync_cut_refused": times.sync_cut_refused,
+            "stage_recycled_bytes": times.recycled_bytes,
+            "stage_fresh_bytes": times.fresh_bytes,
+            "stage_target_wait_s": (
+                self._arena.take_wait_s() if self._arena is not None else 0.0
+            ),
+        }
         for stats, wins in (
             (self.drain_stats, [drain_window]),
             (self.pipeline_stats, windows),
@@ -791,6 +844,7 @@ class _WritePipeline:
                 ("write_queue_sum_s", "write_queue"),
             ):
                 stats[name] = _sum_in(written[kind], wins)
+            stats.update((name, float(value)) for name, value in staged.items())
         # Pipeline-level metrics (no-ops unless a telemetry session is on).
         telemetry.gauge_max(
             "scheduler.budget_hwm_bytes", self.budget.high_water_bytes
@@ -802,9 +856,10 @@ class _WritePipeline:
         telemetry.counter_add("d2h.window_waits", lanes.window_waits)
         telemetry.counter_add("d2h.pieces", lanes.pieces)
         telemetry.counter_add("d2h.pieced_bytes", lanes.pieced_bytes)
-        telemetry.counter_add(
-            "stage.host_relaid_bytes", self._staging_ctx.times.host_relaid_bytes
-        )
+        telemetry.counter_add("stage.host_relaid_bytes", times.host_relaid_bytes)
+        for name, value in staged.items():
+            telemetry.counter_add(name.replace("stage_", "stage.", 1), value)
+        telemetry.gauge_max("stage.sync_cut_hwm_bytes", lanes.cut_hwm_bytes)
         telemetry.counter_add("scheduler.bytes_staged", self.bytes_staged)
         if self.bytes_deduped:
             telemetry.counter_add("scheduler.bytes_deduped", self.bytes_deduped)
@@ -848,6 +903,16 @@ class _WritePipeline:
         self._crc_executor = None
         if self._owns_pools or failed:
             self.pools.shutdown(cancel_queued=failed)
+        if self._arena is not None:
+            # Every leaf has given its view back by now (the io body, a
+            # failing stage, the abort's sweep): the take keeps no page.
+            lent = self._arena.in_use_bytes
+            if lent:
+                logger.error(
+                    "write pipeline closed with %d bytes of its host arena "
+                    "still lent", lent
+                )
+            self._arena.close()
 
 
 class PendingIOWork:
@@ -960,6 +1025,7 @@ async def execute_write_reqs(
     ] = None,
     pools: Optional[PipelinePools] = None,
     priority: Optional[Priority] = None,
+    synchronous: bool = False,
 ) -> PendingIOWork:
     """Runs to the capture point (all non-deferred requests staged) and
     returns a :class:`PendingIOWork` that drains the rest (deferred staging +
@@ -968,7 +1034,10 @@ async def execute_write_reqs(
     hard-linked, not rewritten. ``pools``: thread pools shared with the
     operation's other pipelines (owned, and torn down, by the caller).
     ``priority``: the pipeline's QoS class (default: the ambient
-    ``engine.qos`` scope, NORMAL outside any scope)."""
+    ``engine.qos`` scope, NORMAL outside any scope). ``synchronous``: the
+    caller waits for the whole pipeline and runs no step beside it
+    (``Snapshot.take``), so its big leaves are cut on the device and land
+    in recycled host pages (``_WritePipeline``)."""
     pipeline = _WritePipeline(
         write_reqs,
         storage,
@@ -977,6 +1046,7 @@ async def execute_write_reqs(
         base_loader=base_loader,
         pools=pools,
         priority=priority,
+        synchronous=synchronous,
     )
     await pipeline.run_until_staged()
     return PendingIOWork(pipeline)
@@ -993,6 +1063,7 @@ def sync_execute_write_reqs(
     ] = None,
     pools: Optional[PipelinePools] = None,
     priority: Optional[Priority] = None,
+    synchronous: bool = False,
 ) -> PendingIOWork:
     return event_loop.run_until_complete(
         execute_write_reqs(
@@ -1003,6 +1074,7 @@ def sync_execute_write_reqs(
             base_loader=base_loader,
             pools=pools,
             priority=priority,
+            synchronous=synchronous,
         )
     )
 

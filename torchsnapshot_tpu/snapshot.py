@@ -1157,6 +1157,7 @@ class Snapshot:
             rank=rank,
             event_loop=event_loop,
             base_loader=base_loader,
+            synchronous=not is_async_snapshot,
         )
         _phase("capture")
 
