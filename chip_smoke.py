@@ -3,10 +3,10 @@
 Drives the library's normal entry points (``Snapshot.take``,
 ``Snapshot.async_take``, ``PendingSnapshot.wait``, ``Snapshot.restore``,
 ``verify``, ``read_object``, ``PyTreeStateful``/``Box``) with default knobs
-under a real trainer: the flagship transformer at the widths ``bench.py``
-and ``benchmarks/fsdp`` stand for (d_model 4096, 32 heads, d_ff 16384,
-vocab 32000, seq 512, bf16 params, ``optax.adamw``) with depth as the only
-cut, and a jitted train step that DONATES its state.
+under a real trainer: the flagship transformer (``models/transformer.py``)
+at d_model 4096, 32 heads, d_ff 16384, vocab 32000, seq 512, bf16 params,
+``optax.adamw``, with depth as the only cut, and a jitted train step that
+DONATES its state.
 
   leg A      fork fits (depth 2, ~4 GB of params+moments): async_take, keep
              stepping with donation while the drain runs, wait, sync take,
@@ -57,6 +57,75 @@ REAL_WIDTH = dict(vocab_size=32000, d_model=4096, n_heads=32, d_ff=16384, max_se
 TINY_WIDTH = dict(vocab_size=512, d_model=128, n_heads=4, d_ff=512, max_seq_len=32)
 
 
+_ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# The library's own programs (batched fork, chunk slices, slab pack) compile
+# in well under jax's default 1 s persistence floor: without lowering it
+# they are recompiled by every process.
+_CACHE_THRESHOLDS = {
+    "jax_persistent_cache_min_compile_time_secs": 0.0,
+    "jax_persistent_cache_min_entry_size_bytes": 0,
+}
+
+
+def configure_compile_cache() -> str:
+    """Place jax's persistent compilation cache; call before the backend
+    initialises. Returns the directory in effect.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set from outside wins and nothing else is
+    set in code. Otherwise the cache lives at ``<checkout>/.jax_cache`` — a
+    fixed path, never a temp name, pid or timestamp: the path is part of
+    the cache key, so a directory that moves never hits. Everything goes
+    through the environment so child processes inherit it."""
+    # jax reads its environment at import; once imported, the live config
+    # has to be told as well.
+    config = sys.modules["jax"].config if "jax" in sys.modules else None
+    if not os.environ.get(_ENV_CACHE_DIR):
+        os.environ[_ENV_CACHE_DIR] = os.path.join(REPO_ROOT, ".jax_cache")
+        if config is not None:
+            config.update("jax_compilation_cache_dir", os.environ[_ENV_CACHE_DIR])
+    for name, value in _CACHE_THRESHOLDS.items():
+        if name.upper() not in os.environ:
+            os.environ[name.upper()] = str(value)
+            if config is not None:
+                config.update(name, value)
+    return os.environ[_ENV_CACHE_DIR]
+
+
+def compile_cache_entries(cache_dir: str) -> int:
+    """Number of compiled programs persisted under ``cache_dir``."""
+    if not os.path.isdir(cache_dir):
+        return 0
+    return sum(1 for name in os.listdir(cache_dir) if name.endswith("-cache"))
+
+
+def device_record() -> dict:
+    """The device as jax reports it; printed with the result."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def require_native_engine() -> str:
+    """Build/load the native I/O engine BLOCKING and fail if it is absent,
+    so every take of the run uses one write path (the storage plugin loads
+    it non-blocking and writes buffered until g++ finishes). Returns the
+    path of the library loaded."""
+    from torchsnapshot_tpu import native
+
+    if native.load_native() is None:
+        raise SystemExit(
+            f"native I/O engine unavailable (expected {native.lib_path()}; "
+            "needs g++ and zlib, and TORCHSNAPSHOT_TPU_DISABLE_NATIVE_IO "
+            "unset): refusing to measure the pure-Python write path"
+        )
+    return native.loaded_path()
+
+
 def log(msg: str = "") -> None:
     print(msg, flush=True)
 
@@ -92,8 +161,6 @@ def run_child(argv, env, timeout_s: float) -> int:
 def parent_main(args) -> None:
     if args.platform == "cpu" and not args.tiny:
         sys.exit("--platform cpu is a dry run and needs --tiny")
-    from benchmarks.common import compile_cache_entries, configure_compile_cache
-
     t_start = time.monotonic()
     cache_dir = configure_compile_cache()
     env = dict(os.environ)
@@ -177,13 +244,6 @@ def filesystem_type(path: str) -> str:
 def preflight(args) -> dict:
     """Fail unless jax found the platform asked for; print what a reader of
     any later number needs to know about this installation."""
-    from benchmarks.common import (
-        compile_cache_entries,
-        configure_compile_cache,
-        device_record,
-        require_native_engine,
-    )
-
     cache_dir = configure_compile_cache()
     import jax
     import jaxlib
@@ -707,7 +767,7 @@ def run_programs_leg(ctx: dict) -> dict:
     import numpy as np
 
     from torchsnapshot_tpu import Snapshot, StateDict
-    from torchsnapshot_tpu.io_preparers.array import copy_preserves_bits
+    from torchsnapshot_tpu.device_programs import copy_preserves_bits
     from torchsnapshot_tpu.utils import knobs
 
     small_floats = [
@@ -793,7 +853,7 @@ def run_programs_leg(ctx: dict) -> dict:
     # stage, and the whole transfers of the dtypes that never fork (two hash
     # grains and more).
     from torchsnapshot_tpu import d2h
-    from torchsnapshot_tpu.io_preparers.array import piece_row_ranges
+    from torchsnapshot_tpu.device_programs import piece_row_ranges
 
     nbytes = max(2 * knobs.get_hash_chunk_bytes(), 3 * d2h.PIECE_BYTES) if ctx["measured"] else 256 * 1024
     host = {

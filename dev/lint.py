@@ -27,8 +27,8 @@ import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LINT_DIRS = ("torchsnapshot_tpu", "tests", "benchmarks", "examples", "dev", "docs")
-LINT_FILES = ("bench.py", "__graft_entry__.py")
+LINT_DIRS = ("torchsnapshot_tpu", "tests", "examples", "dev", "docs")
+LINT_FILES = ("chip_smoke.py", "__graft_entry__.py")
 
 
 def iter_targets(argv: list[str]) -> list[str]:

@@ -46,7 +46,7 @@ INTERPRET = jax.devices()[0].platform != "tpu"
 
 def cut_rows(x, ranges):
     """The library's own row cut (written from this probe's)."""
-    from torchsnapshot_tpu.io_preparer import _cut_rows
+    from torchsnapshot_tpu.device_programs import _cut_rows
 
     return _cut_rows(x, tuple(ranges), INTERPRET)
 
@@ -461,7 +461,7 @@ def probe_relay():
     C-order bytes of the rows; the fork's time to ready as pieces and whole;
     how fast the pieces cross on one thread. Beside it, for bfloat16, the
     two programs that do not keep every bit."""
-    from torchsnapshot_tpu import d2h, io_preparer
+    from torchsnapshot_tpu import d2h, device_programs
 
     if INTERPRET:
         d2h.PIECE_BYTES = 64 * 1024
@@ -486,16 +486,16 @@ def probe_relay():
         host = words.view(dt).reshape(shape)
         x = jax.device_put(host)
         back = np.asarray(jnp.copy(x))
-        cut = io_preparer.leaf_cut(x)
+        cut = device_programs.leaf_cut(x)
         rec = {
             "device_major_to_minor": list(x.format.layout.major_to_minor),
             "whole_host_c_contiguous": bool(back.flags.c_contiguous),
             "whole_host_strides": list(back.strides),
             "mover": None if cut is None else ("relaid" if cut.relaid else "dma"),
             "dma_takes_bits_in_order": None if cut is None or cut.order is None else list(cut.order),
-            "whole_ms": _timed_ms(io_preparer._batch_copy_fn((x.sharding,), (None,)), [x]),
+            "whole_ms": _timed_ms(device_programs.batch_copy_fn((x.sharding,), (None,)), [x]),
         }
-        programs = {"library": lambda xs, cut=cut: io_preparer._batch_copy_fn((xs.sharding,), (cut,))([xs])[0]}
+        programs = {"library": lambda xs, cut=cut: device_programs.batch_copy_fn((xs.sharding,), (cut,))([xs])[0]}
         if dt.name == "bfloat16":
             programs.update(relay_candidates(x, cut.ranges))
         rec["pieces"] = len(cut.ranges)

@@ -25,7 +25,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DOC_SOURCES = [
     "README.md",
-    "benchmarks/README.md",
     "docs/getting_started.md",
     "docs/api_reference.md",
     "docs/utilities.md",

@@ -108,7 +108,7 @@ def pytest_runtest_teardown(item):
 # --- A compile cache of the test's own ---------------------------------------
 # A measuring entry point keeps jax's persistent compile cache where
 # JAX_COMPILATION_CACHE_DIR says and otherwise at <checkout>/.jax_cache
-# (benchmarks/common.py, perfbench/run.py). test_chip_smoke.py holds the dry
+# (chip_smoke.py, perfbench/run.py). test_chip_smoke.py holds the dry
 # run to leaving that directory alone, so a test that starts such a child, or
 # drives a harness or an architecture that may, asks for this fixture: the
 # variable points under its own tmp_path for the test's length, and a child

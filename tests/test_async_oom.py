@@ -138,7 +138,7 @@ def test_degraded_take_layout_matches_normal_take(tmp_path) -> None:
 def test_injected_resource_exhausted_from_fork_degrades(tmp_path, monkeypatch) -> None:
     """A real XLA RESOURCE_EXHAUSTED raised by the batched copy (not the
     simulation knob) takes the same degradation path."""
-    import torchsnapshot_tpu.io_preparer as iop
+    from torchsnapshot_tpu import device_programs
 
     def exploding_copy_fn(shardings, cuts):
         def fn(xs):
@@ -149,7 +149,7 @@ def test_injected_resource_exhausted_from_fork_degrades(tmp_path, monkeypatch) -
 
         return fn
 
-    monkeypatch.setattr(iop, "_batch_copy_fn", exploding_copy_fn)
+    monkeypatch.setattr(device_programs, "batch_copy_fn", exploding_copy_fn)
     x = _mesh_sharded()
     expected = np.asarray(x).copy()
     pending = Snapshot.async_take(str(tmp_path / "ckpt"), {"s": StateDict(w=x)})
@@ -170,7 +170,7 @@ def test_injected_resource_exhausted_from_fork_degrades(tmp_path, monkeypatch) -
 def test_non_oom_fork_error_still_raises(tmp_path, monkeypatch) -> None:
     """Degradation is for allocation failure only; other fork errors are
     real bugs and must propagate."""
-    import torchsnapshot_tpu.io_preparer as iop
+    from torchsnapshot_tpu import device_programs
 
     def broken_copy_fn(shardings, cuts):
         def fn(xs):
@@ -178,7 +178,7 @@ def test_non_oom_fork_error_still_raises(tmp_path, monkeypatch) -> None:
 
         return fn
 
-    monkeypatch.setattr(iop, "_batch_copy_fn", broken_copy_fn)
+    monkeypatch.setattr(device_programs, "batch_copy_fn", broken_copy_fn)
     x = _mesh_sharded()
     with pytest.raises(ValueError, match="not an allocation failure"):
         Snapshot.async_take(str(tmp_path / "ckpt"), {"s": StateDict(w=x)})

@@ -176,8 +176,8 @@ def test_async_take_mixed_device_assignments(tmp_path) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Preemption torture (BASELINE.json config: async_take under TPU-VM
-# preemption): a worker is SIGKILLed mid-background-drain. The new snapshot
+# Preemption torture (async_take under TPU-VM preemption): a worker is
+# SIGKILLed mid-background-drain. The new snapshot
 # must never commit, survivors must fail within the barrier timeout with a
 # clear error, and a previously committed snapshot must stay verifiably
 # intact. (Reference pattern: ``tests/test_async_take.py:25-64``.)

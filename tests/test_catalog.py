@@ -215,6 +215,30 @@ def test_job_take_chains_and_rebases(tmp_path) -> None:
     )
 
 
+def test_warm_replica_following_a_chain_reads_only_the_delta_from_origin(tmp_path) -> None:
+    """A replica that restored step T-1 through the read cache restores
+    step T reading the delta's new bytes from origin and the chain-shared
+    backbone from the cache: the cache keys objects by their digests, which
+    dedup'd chain objects share."""
+    from torchsnapshot_tpu import snapshot as snapshot_mod
+
+    bucket = str(tmp_path / "bucket")
+    for i in range(2):
+        Snapshot.take(os.path.join(bucket, f"step_{i}"), _state(i), job="j", step=i)
+    frozen_bytes, lora_bytes = 4000 * 4, 64 * 4
+
+    def restore(step: int) -> dict:
+        _assert_restores(os.path.join(bucket, f"step_{step}"), step)
+        return dict(snapshot_mod.LAST_RESTORE_STATS["attribution"])
+
+    with knobs.override_read_cache_dir(str(tmp_path / "cache")):
+        cold = restore(0)
+        warm = restore(1)
+    assert cold["cache_bytes"] == 0 and cold["origin_bytes"] >= frozen_bytes + lora_bytes
+    assert warm["cache_bytes"] >= frozen_bytes
+    assert lora_bytes <= warm["origin_bytes"] < frozen_bytes
+
+
 def test_job_take_cold_process_scans_catalog(tmp_path) -> None:
     """A fresh process (cold chain cache) finds the chain head by catalog
     scan, not only via the in-process fast path."""

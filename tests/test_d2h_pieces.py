@@ -13,14 +13,9 @@ import os
 import numpy as np
 import pytest
 
-from torchsnapshot_tpu import Snapshot, StateDict, d2h, io_preparer, prepare_cache
-from torchsnapshot_tpu.io_preparers.array import (
-    ArrayBufferStager,
-    ArrayIOPreparer,
-    PiecedArray,
-    device_piece_cut,
-    piece_row_ranges,
-)
+from torchsnapshot_tpu import Snapshot, StateDict, d2h, device_programs, io_preparer, prepare_cache
+from torchsnapshot_tpu.device_programs import PiecedArray, device_piece_cut, piece_row_ranges
+from torchsnapshot_tpu.io_preparers.array import ArrayBufferStager, ArrayIOPreparer
 from torchsnapshot_tpu.manifest import entry_to_dict
 from torchsnapshot_tpu.parallel.coordinator import get_coordinator
 from torchsnapshot_tpu.scheduler import _WritePipeline
@@ -172,13 +167,13 @@ def test_fork_pieces_big_leaves_in_one_program_and_keeps_every_bit(grain, monkey
 
     host, state = _patterned_state()
     built = []
-    real = io_preparer._batch_copy_fn
+    real = device_programs.batch_copy_fn
 
     def counting(shardings, cuts):
         built.append(cuts)
         return real(shardings, cuts)
 
-    monkeypatch.setattr(io_preparer, "_batch_copy_fn", counting)
+    monkeypatch.setattr(device_programs, "batch_copy_fn", counting)
     names = list(state)
     copies = dict(zip(names, io_preparer._defensive_device_copies([state[n] for n in names])))
     assert len(built) == 1  # one program for the group, pieces and whole copies alike
@@ -228,22 +223,71 @@ def test_a_leaf_chunked_into_storage_objects_forks_whole(grain) -> None:
         assert isinstance(io_preparer._defensive_device_copies([big])[0], jax.Array)
 
 
+def _fork_program_cases():
+    """A leaf each way it leaves the device, and whether the stage's
+    program of one leaf (``cut_in_stage``'s cache) is the one built."""
+    f32 = np.arange(128 * 512, dtype=np.float32).reshape(128, 512)
+    return {
+        "whole": (np.arange(7, dtype=np.int32), False),
+        "dma_cut": (f32, False),
+        "relaid": (_every_bf16()[:1000].reshape(-1, 100), False),  # (2560, 100): off the tiling
+        "stage_one_leaf": (f32, True),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_fork_program_cases()))
+def test_the_fork_program_is_the_module_the_benchmark_reads(grain, case) -> None:
+    """``perfbench/metrics/fork_roofline.json`` finds the fork's device time
+    in a trace by the XLA module's name, ``jit__lambda``: the fork is one
+    ``jax.jit`` of a ``lambda``, whatever movers it holds. A refactor that
+    names the function zeroes ``fork_roofline`` in six cells in silence, so
+    the name is held here until both sides agree on another (ROADMAP D17)."""
+    import jax
+
+    host, in_stage = _fork_program_cases()[case]
+    x = jax.device_put(host)
+    cut = device_programs.leaf_cut(x)
+    assert (cut is None) == (case == "whole")
+    assert cut is None or cut.relaid == (case == "relaid")
+    cache = device_programs._STAGE_CUTS if in_stage else None
+    fn = device_programs.batch_copy_fn((x.sharding,), (cut,), cache)
+    assert fn.lower([x]).compile().as_text().startswith("HloModule jit__lambda,")
+
+
+def test_a_stage_cut_is_kept_apart_from_the_forks(grain, monkeypatch) -> None:
+    """Two caches, two objects: a synchronous take's programs of one leaf
+    land in ``_STAGE_CUTS`` and push no fork out of ``_BATCH_COPIES``."""
+    import jax
+
+    monkeypatch.setattr(device_programs, "_STAGE_CUTS", BoundedLRU(64))
+    x = jax.device_put(np.arange(128 * 512, dtype=np.float32).reshape(128, 512))
+    cut = device_programs.leaf_cut(x)
+    forks_before = len(device_programs._BATCH_COPIES)
+    pieced = device_programs.cut_in_stage(x, cut)
+    assert isinstance(pieced, PiecedArray) and pieced.ranges == cut.ranges
+    assert device_programs._STAGE_CUTS is not device_programs._BATCH_COPIES
+    assert len(device_programs._STAGE_CUTS) == 1
+    assert len(device_programs._BATCH_COPIES) == forks_before
+    got = np.concatenate([np.asarray(p) for p in pieced.pieces])
+    assert got.tobytes() == np.asarray(x).tobytes()
+
+
 def test_a_refusal_by_the_kernel_compiler_forks_whole(grain, tmp_path, monkeypatch, caplog) -> None:
     import jax
 
     def refuse(x, ranges, interpret):
         raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: no such tiling")
 
-    monkeypatch.setattr(io_preparer, "_cut_rows", refuse)
-    monkeypatch.setattr(io_preparer, "_BATCH_COPIES", BoundedLRU())  # no program built before
-    monkeypatch.setattr(io_preparer, "_dma_cut_refused", False)
-    monkeypatch.setattr(io_preparer, "_relay_cut_refused", False)
+    monkeypatch.setattr(device_programs, "_cut_rows", refuse)
+    monkeypatch.setattr(device_programs, "_BATCH_COPIES", BoundedLRU())  # no program built before
+    monkeypatch.setattr(device_programs, "_dma_cut_refused", False)
+    monkeypatch.setattr(device_programs, "_relay_cut_refused", False)
     host, state = _patterned_state()
     path = str(tmp_path / "ck")
     with caplog.at_level("WARNING"):
         Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
     assert "row cut was refused" in caplog.text
-    assert io_preparer._dma_cut_refused and not io_preparer._relay_cut_refused
+    assert device_programs._dma_cut_refused and not device_programs._relay_cut_refused
     assert _metrics()["d2h.pieces"] == 0
     copies = io_preparer._defensive_device_copies(list(state.values()))
     assert all(isinstance(c, jax.Array) for c in copies)
@@ -525,16 +569,16 @@ def test_a_refusal_of_the_relaying_cut_keeps_the_dma_cut(grain, tmp_path, monkey
     def refuse(x, interpret):
         raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: no such tiling")
 
-    monkeypatch.setattr(io_preparer, "_bits_by_dma", refuse)
-    monkeypatch.setattr(io_preparer, "_BATCH_COPIES", BoundedLRU())
-    monkeypatch.setattr(io_preparer, "_dma_cut_refused", False)
-    monkeypatch.setattr(io_preparer, "_relay_cut_refused", False)
+    monkeypatch.setattr(device_programs, "_bits_by_dma", refuse)
+    monkeypatch.setattr(device_programs, "_BATCH_COPIES", BoundedLRU())
+    monkeypatch.setattr(device_programs, "_dma_cut_refused", False)
+    monkeypatch.setattr(device_programs, "_relay_cut_refused", False)
     host, state = _relaid_state()
     path = str(tmp_path / "ck")
     with caplog.at_level("WARNING"):
         Snapshot.async_take(path, {"m": StateDict(**state)}).wait()
     assert "re-laying cut was refused" in caplog.text
-    assert io_preparer._relay_cut_refused and not io_preparer._dma_cut_refused
+    assert device_programs._relay_cut_refused and not device_programs._dma_cut_refused
     metrics = _metrics()
     assert metrics["capture.fork_relaid_leaves"] == 0
     assert metrics["d2h.pieced_bytes"] == host["aligned"].nbytes
@@ -773,7 +817,7 @@ def _device_order(leaf):
 
 
 def _described_cut(leaf):
-    """``io_preparer.leaf_cut`` of a leaf that is only described."""
+    """``device_programs.leaf_cut`` of a leaf that is only described."""
     return device_piece_cut(leaf.shape, leaf.dtype, lambda: _device_order(leaf), True)
 
 
@@ -824,7 +868,7 @@ def test_the_fork_of_real_shapes_compiles_for_the_v5e_with_no_temporary(one_chip
     else:
         assert not relaid
     compiled = (
-        io_preparer._batch_copy_fn(tuple(one_chip for _ in leaves), cuts).lower(leaves).compile()
+        device_programs.batch_copy_fn(tuple(one_chip for _ in leaves), cuts).lower(leaves).compile()
     )
     for cut, formats in zip(cuts, compiled.output_formats):
         if cut is not None:
@@ -865,7 +909,7 @@ def test_the_fork_of_real_shapes_compiles_for_the_v5e_with_no_temporary(one_chip
     ],
 )
 def test_the_stage_cut_of_one_real_leaf_compiles_for_the_v5e(one_chip, shape, dtype, relaid, order) -> None:
-    """``io_preparer.cut_in_stage``'s program of one leaf, as the TPU's own
+    """``device_programs.cut_in_stage``'s program of one leaf, as the TPU's own
     compiler takes it: pieces row-major, the outputs the leaf's bytes, at
     most one leaf of temporaries where it is re-laid and none where a DMA
     moves it, so a leaf's cut holds at most twice its bytes of HBM while the
@@ -876,7 +920,7 @@ def test_the_stage_cut_of_one_real_leaf_compiles_for_the_v5e(one_chip, shape, dt
     leaf = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=one_chip)
     cut = _described_cut(leaf)
     assert cut is not None and cut.relaid == relaid and cut.order == order
-    compiled = io_preparer._batch_copy_fn((one_chip,), (cut,), BoundedLRU()).lower([leaf]).compile()
+    compiled = device_programs.batch_copy_fn((one_chip,), (cut,), BoundedLRU()).lower([leaf]).compile()
     (formats,) = compiled.output_formats
     assert len(formats) == len(cut.ranges)
     for f in formats:

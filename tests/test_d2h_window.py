@@ -14,7 +14,6 @@ import numpy as np
 import pytest
 
 from torchsnapshot_tpu import Snapshot, StateDict, d2h
-from torchsnapshot_tpu.io_preparers import array as array_mod
 from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
 from torchsnapshot_tpu.scheduler import _WritePipeline
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
@@ -221,7 +220,6 @@ def _count_hints(monkeypatch, fail_at=None):
         real(arr)
 
     monkeypatch.setattr(d2h, "hint_copy_to_host", counting)
-    monkeypatch.setattr(array_mod, "hint_copy_to_host", counting)
     return calls
 
 

@@ -17,10 +17,7 @@ import numpy as np
 import pytest
 
 from torchsnapshot_tpu import Snapshot, StateDict
-from torchsnapshot_tpu.io_preparers.array import (
-    copy_preserves_bits,
-    slice_preserves_bits,
-)
+from torchsnapshot_tpu.device_programs import copy_preserves_bits, slice_preserves_bits
 from torchsnapshot_tpu.io_preparers.chunked_array import should_chunk
 from torchsnapshot_tpu.io_preparers.sharded_array import shard_pieces
 from torchsnapshot_tpu.utils import knobs

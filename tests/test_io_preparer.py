@@ -5,11 +5,9 @@
 import numpy as np
 import pytest
 
+from torchsnapshot_tpu.device_programs import chunk_row_ranges
 from torchsnapshot_tpu.io_preparer import classify, get_storage_path
-from torchsnapshot_tpu.io_preparers.chunked_array import (
-    chunk_row_ranges,
-    should_chunk,
-)
+from torchsnapshot_tpu.io_preparers.chunked_array import should_chunk
 from torchsnapshot_tpu.io_preparers.sharded_array import (
     index_to_offsets_sizes,
     local_unique_shards,

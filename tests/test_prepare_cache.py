@@ -387,9 +387,8 @@ def test_steady_state_warm_stall_within_target(tmp_path) -> None:
     """The tentpole's acceptance number, in CI-runnable form: repeated
     async takes of the same tree under donate capture must hold the WARM
     (cache-hit) stall at or under the 0.1s target, with the cold
-    (store-on-miss) take excluded. Sized well below bench.py's tree so the
-    bound holds on shared CI runners; the bench's steady leg measures the
-    full-size version and reports cold vs warm separately."""
+    (store-on-miss) take excluded. Sized small so the bound holds on shared
+    CI runners."""
     import time
 
     from torchsnapshot_tpu import snapshot as snapshot_mod

@@ -1439,3 +1439,57 @@ def test_lint_fix_mode(tmp_path):
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert target.read_text() == "x = 1\ny = 2\n"
+
+
+# ---------------------------------------------------------------------------
+# Import direction: how a leaf leaves the device is decided in
+# ``device_programs.py``, under the planner and the preparers that use it.
+# ---------------------------------------------------------------------------
+
+_PACKAGE = os.path.join(REPO_ROOT, "torchsnapshot_tpu")
+# module (relative to the package) -> the package's modules it may not
+# import, at any depth of nesting: a function-level import is an arrow too.
+_MAY_NOT_IMPORT = {
+    "device_programs.py": {"io_preparer", "io_preparers", "scheduler", "snapshot"},
+    **{
+        os.path.join("io_preparers", name): {"io_preparer"}
+        for name in sorted(os.listdir(os.path.join(_PACKAGE, "io_preparers")))
+        if name.endswith(".py")
+    },
+}
+
+
+def _package_imports(relpath):
+    """The package's own modules ``relpath`` imports, as dotted names under
+    ``torchsnapshot_tpu``, from every ``import`` in the file (function bodies
+    included)."""
+    import ast
+
+    with open(os.path.join(_PACKAGE, relpath)) as f:
+        tree = ast.parse(f.read())
+    here = ["torchsnapshot_tpu"] + relpath[: -len(".py")].split(os.sep)
+    found = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            if node.level:
+                base = here[: len(here) - node.level]
+                base += node.module.split(".") if node.module else []
+                names = [base] if node.module else []
+                names += [base + [a.name] for a in node.names]
+            else:
+                names = [(node.module or "").split(".")]
+        elif isinstance(node, ast.Import):
+            names = [a.name.split(".") for a in node.names]
+        else:
+            continue
+        found.update(
+            ".".join(n[1:]) for n in names if n[0] == "torchsnapshot_tpu" and len(n) > 1
+        )
+    return found
+
+
+@pytest.mark.parametrize("relpath", sorted(_MAY_NOT_IMPORT))
+def test_import_direction_device_programs_sit_under_the_preparers(relpath):
+    banned = _MAY_NOT_IMPORT[relpath]
+    upward = {m for m in _package_imports(relpath) if m.split(".")[0] in banned}
+    assert not upward, f"{relpath} imports upward: {sorted(upward)}"

@@ -11,9 +11,10 @@ import os
 import numpy as np
 import pytest
 
-from torchsnapshot_tpu import Snapshot, StateDict, d2h, host_arena, io_preparer, prepare_cache
+from torchsnapshot_tpu import Snapshot, StateDict, d2h, device_programs, host_arena, prepare_cache
 from torchsnapshot_tpu.host_arena import HostArena
-from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer, piece_row_ranges
+from torchsnapshot_tpu.device_programs import piece_row_ranges
+from torchsnapshot_tpu.io_preparers.array import ArrayIOPreparer
 from torchsnapshot_tpu.parallel.coordinator import get_coordinator
 from torchsnapshot_tpu.scheduler import _WritePipeline
 from torchsnapshot_tpu.storage_plugins.memory import MemoryStoragePlugin
@@ -380,7 +381,7 @@ def test_a_cut_the_device_has_no_room_for_leaves_the_leaf_whole(grain, tmp_path,
     """HBM nearly full (a preemption take): the cut's allocation fails, the
     leaf crosses whole as it did before, the take commits."""
     host = _big_state(3)
-    real = io_preparer._batch_copy_fn
+    real = device_programs.batch_copy_fn
     seen = []
 
     def no_room(shardings, cuts, cache=None):
@@ -394,7 +395,7 @@ def test_a_cut_the_device_has_no_room_for_leaves_the_leaf_whole(grain, tmp_path,
 
         return call
 
-    monkeypatch.setattr(io_preparer, "_batch_copy_fn", no_room)
+    monkeypatch.setattr(device_programs, "batch_copy_fn", no_room)
     path = str(tmp_path / "ck")
     Snapshot.take(path, {"m": StateDict(**_put(host))})
     m = _metrics()
@@ -402,7 +403,7 @@ def test_a_cut_the_device_has_no_room_for_leaves_the_leaf_whole(grain, tmp_path,
     assert m["stage.sync_cut_bytes"] == m["d2h.pieced_bytes"] == 256 * KIB
     assert m["d2h.bytes"] == sum(v.nbytes for v in host.values())
     assert [a.lent_at_close for a in arenas] == [0]
-    assert not io_preparer._dma_cut_refused and not io_preparer._relay_cut_refused
+    assert not device_programs._dma_cut_refused and not device_programs._relay_cut_refused
     for name, want in host.items():
         assert _same_bits(Snapshot(path).read_object(f"0/m/{name}"), want), name
     assert Snapshot(path).verify() == {}
@@ -432,23 +433,23 @@ def test_a_program_that_runs_out_of_room_as_it_runs_leaves_the_leaf_whole(grain,
 
 
 def test_a_refusal_by_the_kernel_compiler_in_the_stage_is_remembered(grain, tmp_path, monkeypatch, caplog) -> None:
-    monkeypatch.setattr(io_preparer, "_STAGE_CUTS", BoundedLRU())
-    monkeypatch.setattr(io_preparer, "_dma_cut_refused", False)
-    monkeypatch.setattr(io_preparer, "_relay_cut_refused", False)
-    real = io_preparer._batch_copy_fn
+    monkeypatch.setattr(device_programs, "_STAGE_CUTS", BoundedLRU())
+    monkeypatch.setattr(device_programs, "_dma_cut_refused", False)
+    monkeypatch.setattr(device_programs, "_relay_cut_refused", False)
+    real = device_programs.batch_copy_fn
 
     def refusing(shardings, cuts, cache=None):
         if any(c is not None and c.relaid for c in cuts):
             raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: no such tiling")
         return real(shardings, cuts, cache)
 
-    monkeypatch.setattr(io_preparer, "_batch_copy_fn", refusing)
+    monkeypatch.setattr(device_programs, "batch_copy_fn", refusing)
     host = _big_state(4)
     path = str(tmp_path / "ck")
     with caplog.at_level("WARNING"):
         Snapshot.take(path, {"m": StateDict(**_put(host))})
     assert "re-laying cut was refused" in caplog.text
-    assert io_preparer._relay_cut_refused and not io_preparer._dma_cut_refused
+    assert device_programs._relay_cut_refused and not device_programs._dma_cut_refused
     m = _metrics()
     # The first odd leaf met the compiler; the second was never offered.
     assert m["stage.sync_cut_refused"] == 1 and m["stage.sync_cut_leaves"] == 2
@@ -469,7 +470,7 @@ def test_the_cut_pieces_never_hold_more_hbm_than_the_window(grain, arenas, monke
     host["huge"] = _bits("float32", (1024, 256))  # 1 MiB: over the window
     storage = MemoryStoragePlugin()
     live = {"now": 0, "hwm": 0, "alone": None}
-    real_cut = io_preparer.cut_in_stage
+    real_cut = device_programs.cut_in_stage
 
     def cut(arr, c):
         live["now"] += arr.nbytes
@@ -484,7 +485,7 @@ def test_the_cut_pieces_never_hold_more_hbm_than_the_window(grain, arenas, monke
                 live["now"] -= nbytes
             super().done(nbytes)
 
-    monkeypatch.setattr(io_preparer, "cut_in_stage", cut)
+    monkeypatch.setattr(device_programs, "cut_in_stage", cut)
     monkeypatch.setattr(d2h, "_DeviceWindow", Window)
     pipeline = _pipeline(host, storage)
 
@@ -521,7 +522,7 @@ def test_async_take_never_touches_an_arena_and_forks_the_same_pieces(grain, tmp_
     def no_cut(arr, cut):
         raise AssertionError("async_take cut a leaf in the stage")
 
-    monkeypatch.setattr(io_preparer, "cut_in_stage", no_cut)
+    monkeypatch.setattr(device_programs, "cut_in_stage", no_cut)
     host = _big_state(6)
     state = _put(host)
     path = str(tmp_path / "ck")

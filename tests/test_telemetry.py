@@ -241,7 +241,7 @@ def test_e2e_traced_take_and_restore(tmp_path) -> None:
     TORCHSNAPSHOT_TPU_TRACE set emits valid Chrome trace JSON containing
     phase, scheduler stage/io, and storage-plugin spans whose summed
     storage-write bytes equal the manifest's logical byte total, while
-    bench.py's stall_phases_s / drain-stats keys stay unchanged."""
+    the ``LAST_TAKE_PHASES`` / drain-stats keys stay unchanged."""
     from torchsnapshot_tpu import snapshot as snapshot_mod
 
     app = {

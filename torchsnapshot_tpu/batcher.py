@@ -39,7 +39,7 @@ from .manifest import (
     Entry,
     ShardedArrayEntry,
 )
-from .io_preparer import _device_assignment_key, _is_oom_error
+from .device_programs import device_assignment_key, is_oom_error
 from .io_preparers.array import (
     FRAME_TABLE_SUFFIX as _FRAME_TABLE_SUFFIX,
     PollingTableStager,
@@ -240,7 +240,7 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
                 packed, executor, self.members[0][0].path, self.total
             )
         except Exception as e:
-            if not _is_oom_error(e):
+            if not is_oom_error(e):
                 raise
             with _PACK_LOCK:
                 if len(_PACK_FAILED) >= _PACK_FAILED_CAP:
@@ -274,7 +274,7 @@ class DeviceBatchedBufferStager(BatchedBufferStager):
 # them unpacked one-per-byte, and an 8→4-bit bitcast would mis-size the
 # slab. Sub-32-bit floats (bfloat16, float16, float8) are excluded too: the
 # pack program flushes their denormals and rewrites their NaN payloads
-# (``io_preparers.array.slice_preserves_bits``), so their slabs are packed
+# (``device_programs.slice_preserves_bits``), so their slabs are packed
 # on the host from per-member transfers. bool packs via astype (same 0/1
 # byte representation). Complex bitcasts are unsupported by XLA.
 _DEVICE_PACKABLE_DTYPES = frozenset(
@@ -296,10 +296,10 @@ _DEVICE_PACKABLE_DTYPES = frozenset(
 
 def _device_batchable(req: WriteReq) -> bool:
     """True when a member can join an on-device packed slab."""
-    from .io_preparers.array import ArrayBufferStager, _is_jax_array
+    from .io_preparers.array import ArrayBufferStager, is_jax_array
 
     stager = req.buffer_stager
-    if not isinstance(stager, ArrayBufferStager) or not _is_jax_array(stager.arr):
+    if not isinstance(stager, ArrayBufferStager) or not is_jax_array(stager.arr):
         return False
     arr = stager.arr
     # Fully-addressable only: packing is an independent local computation, so
@@ -314,7 +314,7 @@ def _device_batchable(req: WriteReq) -> bool:
 
 def _pack_key(arrs) -> tuple:
     return tuple(
-        (str(a.dtype), a.shape, _device_assignment_key(a.sharding)) for a in arrs
+        (str(a.dtype), a.shape, device_assignment_key(a.sharding)) for a in arrs
     )
 
 
@@ -476,7 +476,7 @@ def batch_write_requests(
                 knobs.is_device_batching_enabled()
                 and all(_device_batchable(req) for req, _, _ in slab)
                 and len(
-                    {_device_assignment_key(req.buffer_stager.arr.sharding) for req, _, _ in slab}
+                    {device_assignment_key(req.buffer_stager.arr.sharding) for req, _, _ in slab}
                 )
                 == 1
             ):

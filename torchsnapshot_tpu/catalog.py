@@ -46,8 +46,8 @@ be safely collected:
 Chain-aware restore needs no new machinery: the content-addressed read
 cache (``storage_plugins/cache.py``) keys data objects by their sidecar
 digests, which dedup'd chain objects share — a warm replica following a
-chain reads only each delta's new bytes from origin (proven in
-``benchmarks/continuous/``).
+chain reads only each delta's new bytes from origin
+(``tests/test_catalog.py``).
 
 Crash convergence of retention GC (chaos-tested in ``tests/test_chaos.py``):
 condemned snapshots are deleted in a fixed order — ``.snapshot_metadata``

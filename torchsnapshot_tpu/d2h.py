@@ -20,21 +20,21 @@ Two cooperating pieces:
   **The grain.** A leaf that ``async_take`` forked and that is over
   :data:`PIECE_BYTES` reaches the lanes as row-range pieces the fork itself
   wrote, every bit kept, by one of two movers inside the one fork program:
-  DMAs of whole HBM tiles (``io_preparer._cut_rows``) or, where the leaf's
+  DMAs of whole HBM tiles (``device_programs._cut_rows``) or, where the leaf's
   shape is off the tiling (a width of 1856 or 10304, 1001 rows: a leaf the
   device may hold column first, whose host copy would come in that order
   and be re-laid by a strided copy on the drain's event loop), integer
   copies that re-lay each range row-major on the device
-  (``io_preparer._relay_rows``). Either way a piece's host bytes are the
+  (``device_programs._relay_rows``). Either way a piece's host bytes are the
   C-order bytes of its rows. A piece is admitted under
   :data:`PIECE_WINDOW_BYTES`, and the lane that resolved it copies it into
   its rows of the leaf's one host buffer and drops it, so a few tens of MiB
   are in flight where whole leaves put hundreds: the job's steps beside the
   drain lose a third to a half of what they lost (``PERF.md`` section 6,
   PR 39). **A synchronous take** has no fork and no step beside it: its
-  stage cuts the same leaves (``io_preparer.leaf_cut``, the one predicate)
+  stage cuts the same leaves (``device_programs.leaf_cut``, the one predicate)
   at their turn, a leaf at a time, by the same movers
-  (``io_preparer.cut_in_stage``), while the pieces cut and not yet
+  (``device_programs.cut_in_stage``), while the pieces cut and not yet
   gathered hold at most :data:`CUT_WINDOW_BYTES` of HBM a device; they
   cross under a wider window (:data:`SYNC_PIECE_WINDOW_BYTES`) and are
   gathered into a view of the take's arena of host pages, handed from
@@ -49,7 +49,7 @@ Two cooperating pieces:
   size, a leaf of one row or whose rows fill no whole number of 128 lanes,
   bool / float16 / float8 / 64-bit leaves, and a bfloat16 leaf off the
   tiling that the device holds in no whole tiles either
-  (``io_preparers.array.piece_row_ranges``, ``device_piece_cut``). The
+  (``device_programs.piece_row_ranges``, ``device_piece_cut``). The
   lanes tell the two apart by what they are handed. Beyond a leaf's buffer
   the host holds at most one window of resolved pieces.
 - :class:`StageTimes` — a thread-safe sink for the staging stream's
@@ -99,7 +99,7 @@ logger = logging.getLogger(__name__)
 HINT_WINDOW_BYTES = 512 * 1024 * 1024
 
 # The grain. A forked leaf over PIECE_BYTES leaves the fork as row-range
-# pieces of at most that size (``io_preparers.array.piece_row_ranges``; one
+# pieces of at most that size (``device_programs.piece_row_ranges``; one
 # unit of rows where a unit is bigger), and
 # pieces are admitted under PIECE_WINDOW_BYTES a device: four of them. From
 # PR 39's runs on a v5e (``PERF.md`` section 6), 3.24 GB of params saved every
@@ -121,7 +121,7 @@ PIECE_WINDOW_BYTES = 64 * 1024 * 1024
 
 # A synchronous take has no fork, so its big leaves are cut at their turn in
 # the stage, by the fork's own movers, a leaf at a time
-# (``io_preparer.cut_for_stage``), and the pieces are a second copy of the
+# (``device_programs.cut_in_stage``), and the pieces are a second copy of the
 # leaf in HBM until they have crossed. CUT_WINDOW_BYTES bounds the pieces of
 # the leaves cut and not yet gathered on one device (one leaf bigger than
 # the window goes alone): three 160 MB stacks, enough that the next leaf's
@@ -220,7 +220,7 @@ class StageTimes:
         strided host array), and the stage makes it contiguous by a strided
         copy, a whole leaf's on the drain's event loop
         (``serialization.array_as_bytes_view``): what the fork's re-laying
-        cut spares the big forked leaves (``io_preparer._relay_rows``)."""
+        cut spares the big forked leaves (``device_programs._relay_rows``)."""
         if not host.flags["C_CONTIGUOUS"]:
             with self._lock:
                 self.host_relaid_bytes += host.nbytes

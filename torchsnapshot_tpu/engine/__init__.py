@@ -12,8 +12,7 @@ Priority classes (``FOREGROUND > NORMAL > BACKGROUND``) preempt at chunk
 granularity through the process-wide :class:`QoSArbiter`: a foreground
 replica restore arriving mid-drain steals the next admission (budget,
 io/hash/transfer-pool slots) rather than waiting for the
-drain to finish. See ``docs/performance.md`` ("The dataflow engine") and
-``benchmarks/qos/``.
+drain to finish. See ``docs/performance.md`` ("The dataflow engine").
 """
 
 from .graph import Node, Priority  # noqa: F401

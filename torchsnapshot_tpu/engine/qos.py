@@ -24,8 +24,7 @@ Starvation is bounded: a continuously-preempted engine admits one round of
 work every ``TORCHSNAPSHOT_TPU_QOS_MAX_PAUSE_S`` seconds regardless of
 demand, so a long-lived foreground class slows background work to a
 trickle but can never wedge it. ``TORCHSNAPSHOT_TPU_QOS=0`` disables the
-arbiter entirely (FIFO — the A/B baseline ``benchmarks/qos`` measures
-against).
+arbiter entirely (FIFO).
 
 The ambient class travels via a ``contextvars.ContextVar`` (the same
 pattern d2h/telemetry use): ``Snapshot.take/async_take/restore`` wrap the

@@ -1,8 +1,8 @@
 """Parallel chunked hashing: tree digests that scale with cores and verify
 byte ranges.
 
-The PR 6 staging ablation (``benchmarks/staging``) attributed essentially all
-remaining null-sink staging wall to hashing: the sidecar format
+A staging ablation on the host (PR 6) attributed essentially all remaining
+null-sink staging wall to hashing: the sidecar format
 (``[crc32, size, sha256-hex]``) forces one *serial* crc32+sha256 fold per
 storage object — a whole-object sha256 cannot be computed out of order,
 cannot be split across the hash pool, and cannot verify a byte range. This

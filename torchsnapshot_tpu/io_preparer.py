@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import logging
 import time
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -34,19 +34,13 @@ from .manifest import (
     PrimitiveEntry,
     PRIMITIVE_TYPES,
 )
-from .io_preparers.array import (
-    ArrayIOPreparer,
-    PieceCut,
-    PiecedArray,
-    copy_preserves_bits,
-    device_piece_cut,
-)
+from . import d2h, device_programs, telemetry
+from .device_programs import PiecedArray, copy_preserves_bits, is_oom_error
+from .io_preparers.array import ArrayIOPreparer, is_jax_array
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer, should_chunk
 from .io_preparers.object import ObjectIOPreparer
 from .io_preparers.sharded_array import ShardedArrayIOPreparer
-from . import telemetry
 from .utils import knobs
-from .utils.lru import BoundedLRU
 
 logger = logging.getLogger(__name__)
 
@@ -54,12 +48,6 @@ logger = logging.getLogger(__name__)
 def get_storage_path(logical_path: str, rank: int, replicated: bool) -> str:
     """Reference ``io_preparer.py:51-57`` (``sharded/`` handled separately)."""
     return f"replicated/{logical_path}" if replicated else f"{rank}/{logical_path}"
-
-
-def _is_jax_array(obj: Any) -> bool:
-    import jax
-
-    return isinstance(obj, jax.Array)
 
 
 def _globally_replicated(arr: Any, world_size: int) -> bool:
@@ -120,7 +108,7 @@ class HostCapturedArray:
 def _is_plannable_array(value: Any) -> bool:
     """jax.Array, or a capture carrying the same planning metadata: through
     the host, or forked in pieces."""
-    return _is_jax_array(value) or isinstance(
+    return is_jax_array(value) or isinstance(
         value, (HostCapturedArray, PiecedArray)
     )
 
@@ -191,7 +179,7 @@ def _defensive_device_copies(arrs: List[Any]) -> List[Any]:
     the stall (a warning reports both).
 
     **Dtypes the copy would rewrite** (float16, float8: the device copy
-    replaces NaN payloads, ``io_preparers.array.copy_preserves_bits``) are
+    replaces NaN payloads, ``device_programs.copy_preserves_bits``) are
     never forked: those leaves are host-captured up front — D2H moves bits
     unchanged — and counted (``capture.dtype_captured_leaves``).
     """
@@ -214,7 +202,7 @@ def _defensive_device_copies(arrs: List[Any]) -> List[Any]:
             out[i] = c
     for i, a in enumerate(arrs):
         if out[i] is None:
-            groups.setdefault(_device_assignment_key(a.sharding), []).append(i)
+            groups.setdefault(device_programs.device_assignment_key(a.sharding), []).append(i)
     # Cumulative successfully-forked local bytes across this take, for the
     # simulated-HBM-limit knob (mirrors real accounting: forks accumulate).
     forked_bytes = [0]
@@ -242,19 +230,10 @@ def _local_fork_nbytes(arr: Any) -> int:
     return sum(int(s.data.nbytes) for s in arr.addressable_shards)
 
 
-def _is_oom_error(e: BaseException) -> bool:
-    s = str(e)
-    return "RESOURCE_EXHAUSTED" in s or "out of memory" in s.lower()
-
-
 # Log-once guards: the backend-capability degradation below, and leaves whose
 # dtype the fork program would rewrite (a property of the model, not of a take).
 _fork_unsupported_warned = False
 _dtype_capture_warned = False
-# Whether the kernel compiler has refused a mover of the fork's row cut in
-# this process (``_try_fork``): the leaves it would have cut fork whole.
-_dma_cut_refused = False
-_relay_cut_refused = False
 
 
 def _is_fork_unsupported_error(group: List[Any], e: BaseException) -> bool:
@@ -283,14 +262,17 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
             )
     shardings = tuple(a.sharding for a in group)
     while True:
-        cuts = tuple(leaf_cut(a) for a in group)
+        # A leaf the planner will chunk is cut there, not here.
+        cuts = tuple(
+            None if should_chunk(a) else device_programs.leaf_cut(a) for a in group
+        )
         try:
-            copies = _batch_copy_fn(shardings, cuts)(group)
+            copies = device_programs.batch_copy_fn(shardings, cuts)(group)
             break
         except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades
             if not any(cuts) or "Mosaic" not in str(e):
                 raise
-            _give_up_cut(cuts, e)
+            device_programs.give_up_cut(cuts, e)
     copies = [
         c if cut is None else PiecedArray(a.shape, a.dtype, a.sharding, c, cut.ranges)
         for a, c, cut in zip(group, copies, cuts)
@@ -309,9 +291,10 @@ def _try_fork(group: List[Any], forked_bytes: List[int]) -> List[Any]:
 # Bisection depth bound for the degraded fork: each distinct sub-group is a
 # fresh XLA program whose compile runs inside the (already degraded) stall,
 # so recursion stops at quarters — at most 6 extra compiles per failing
-# group, reused across takes via the _BATCH_COPIES LRU. Anything a quarter
-# group can't fit is host-captured without further compile attempts. (The
-# simulated-limit knob raises before compiling, so tests pay nothing.)
+# group, reused across takes via ``device_programs``' LRU of forks. Anything
+# a quarter group can't fit is host-captured without further compile
+# attempts. (The simulated-limit knob raises before compiling, so tests pay
+# nothing.)
 _MAX_FORK_BISECT_DEPTH = 2
 
 
@@ -336,7 +319,7 @@ def _fork_or_capture(
                     e,
                 )
             return _host_capture_group(group)
-        if not _is_oom_error(e):
+        if not is_oom_error(e):
             raise
     if len(group) == 1 or depth >= _MAX_FORK_BISECT_DEPTH:
         captured.extend(group)
@@ -351,8 +334,6 @@ def _host_capture_group(group: List[Any]) -> List[HostCapturedArray]:
     """Blocking host capture of a group of arrays: async D2H hints for EVERY
     shard of EVERY array first, so the per-shard resolves pipeline on the
     transfer engine instead of serializing array by array."""
-    from .io_preparers.array import hint_copy_to_host
-
     # Which path a leaf took is a fact of the take, exported with it (the
     # persisted telemetry artifact), not only a log line.
     telemetry.counter_add("capture.host_captured_leaves", len(group))
@@ -361,7 +342,7 @@ def _host_capture_group(group: List[Any]) -> List[HostCapturedArray]:
     )
     for a in group:
         for s in a.addressable_shards:
-            hint_copy_to_host(s.data)
+            d2h.hint_copy_to_host(s.data)
     return [_host_capture(a) for a in group]
 
 
@@ -392,210 +373,6 @@ def _host_capture(arr: Any) -> HostCapturedArray:
     )
 
 
-def _device_assignment_key(sharding) -> Any:
-    """One jitted computation requires all operands to share a device
-    assignment (order included, which ``device_set`` loses)."""
-    return tuple(d.id for d in sharding._device_assignment)
-
-
-def _give_up_cut(cuts: Sequence[Optional[PieceCut]], e: BaseException) -> None:
-    """Both movers hand the leaf to a Pallas kernel, and its compiler may
-    refuse a shape the rule lets through. A take must not fail for it: this
-    process gives up the re-laying cut first (the DMA cut of the aligned
-    leaves stays), then the DMA cut, and moves those leaves whole from here
-    on."""
-    global _dma_cut_refused, _relay_cut_refused
-    if any(c is not None and c.relaid for c in cuts):
-        _relay_cut_refused, which = True, "re-laying cut"
-    else:
-        _dma_cut_refused, which = True, "row cut"
-    logger.warning(
-        "the %s was refused by the kernel compiler (%s); the big leaves "
-        "it would take are copied and cross whole from now on",
-        which,
-        e,
-    )
-
-
-def leaf_cut(arr: Any) -> Optional[PieceCut]:
-    """How ``arr`` leaves the device as row-range pieces, or None where it
-    goes whole: a leaf that lives whole in one device's own memory, stays
-    one storage object, and is over the piece size in a shape and dtype a
-    mover takes (``io_preparers.array.device_piece_cut``). One predicate for
-    the two places that cut: ``async_take``'s fork, which writes the copy as
-    the pieces, and a synchronous take's stage (:func:`cut_in_stage`)."""
-    sharding = arr.sharding
-    if len(sharding.device_set) != 1 or sharding.memory_kind not in (None, "device"):
-        return None
-    if should_chunk(arr):
-        return None
-    cut = device_piece_cut(
-        arr.shape, arr.dtype, lambda: arr.format.layout.major_to_minor, _on_tpu(sharding)
-    )
-    if cut is None or (_relay_cut_refused if cut.relaid else _dma_cut_refused):
-        return None
-    return cut
-
-
-def _cut_rows(x: Any, ranges: Sequence[Tuple[int, int]], interpret: bool) -> List[Any]:
-    """``x``'s rows as one array a range, written by HBM-to-HBM DMAs: every
-    byte is read once and written once, as ``jnp.copy`` would, and none is
-    computed on, so every bit pattern of every dtype comes through (an XLA
-    slice of bfloat16 flushes its denormals). Off the TPU the same kernel
-    runs in Pallas's interpreter."""
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    k = len(ranges)
-
-    def kernel(x_ref, *refs):
-        outs, sems = refs[:k], refs[k]
-        copies = [
-            pltpu.make_async_copy(x_ref.at[pl.ds(r0, r1 - r0)], out, sems.at[i])
-            for i, ((r0, r1), out) in enumerate(zip(ranges, outs))
-        ]
-        for c in copies:
-            c.start()
-        for c in copies:
-            c.wait()
-
-    return list(
-        pl.pallas_call(
-            kernel,
-            out_shape=[
-                jax.ShapeDtypeStruct((r1 - r0,) + tuple(x.shape[1:]), x.dtype)
-                for r0, r1 in ranges
-            ],
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * k,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((k,))],
-            interpret=interpret,
-        )(x)
-    )
-
-
-def _bits_by_dma(x: Any, interpret: bool) -> Any:
-    """``x``'s bits as unsigned integers of its width, by one DMA between
-    two views of HBM: nothing is computed on. XLA's own ``bitcast-convert``
-    of bfloat16 is a kernel, and on the v5e it flushes the 254 denormals and
-    rewrites 253 NaN payloads (``PERF.md`` section 6, PR 46's probe)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
-
-    def kernel(x_ref, out, sem):
-        copy = pltpu.make_async_copy(x_ref.bitcast(bits), out, sem)
-        copy.start()
-        copy.wait()
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, bits),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
-        interpret=interpret,
-    )(x)
-
-
-def _relay_rows(
-    x: Any,
-    ranges: Sequence[Tuple[int, int]],
-    order: Optional[Tuple[int, ...]],
-    interpret: bool,
-) -> List[Any]:
-    """``x``'s rows as one array a range, **re-laid on the device**: a leaf
-    whose width is no multiple of the 128 lanes the device may hold column
-    first (a ``(2688, 10304)`` bfloat16 array lives as ``major_to_minor
-    (1, 0)``), its host copy comes in that order, and a strided copy on the
-    host makes it contiguous at a third of a GB/s. Here each range is
-    sliced and reshaped to ``(n / 128, 128)``, a shape the device holds
-    row-major and hands the host C-contiguous: the C-order bytes of the
-    rows, whatever the piece's dtype. XLA moves integers and 32-bit floats
-    bit for bit and sub-32-bit floats not (``slice_preserves_bits``), so
-    bfloat16 comes with ``order``, the device's own order of its
-    dimensions, and its bits become integers first, by a DMA that takes the
-    leaf in that order: XLA then puts no copy of its own before the DMA,
-    the two transposes compile to views."""
-    if order is not None:
-        inverse = tuple(int(i) for i in np.argsort(order))
-        x = _bits_by_dma(x.transpose(order), interpret).transpose(inverse)
-    row = int(np.prod(x.shape[1:]))
-    return [x[r0:r1].reshape((r1 - r0) * row // 128, 128) for r0, r1 in ranges]
-
-
-def _on_tpu(sharding: Any) -> bool:
-    return all(d.platform == "tpu" for d in sharding.device_set)
-
-
-def _batch_copy_fn(
-    shardings: Tuple[Any, ...],
-    cuts: Tuple[Optional[PieceCut], ...],
-    cache: Optional[BoundedLRU] = None,
-):
-    """The fork of one group: a whole ``jnp.copy`` a leaf, or its copy as
-    row-range pieces where ``cuts`` gives a cut (``leaf_cut``), written by
-    the cut's mover, all in one jitted lambda: one program a take."""
-
-    def pieces(x, sharding, cut):
-        interpret = not _on_tpu(sharding)
-        if cut.relaid:
-            return _relay_rows(x, cut.ranges, cut.order, interpret)
-        return _cut_rows(x, cut.ranges, interpret)
-
-    def build():
-        import jax
-        import jax.numpy as jnp
-
-        return jax.jit(
-            lambda xs: [
-                jnp.copy(x) if cut is None else pieces(x, s, cut)
-                for x, s, cut in zip(xs, shardings, cuts)
-            ],
-            out_shardings=[
-                s if cut is None else [s] * len(cut.ranges)
-                for s, cut in zip(shardings, cuts)
-            ],
-        )
-
-    cache = _BATCH_COPIES if cache is None else cache
-    return cache.get_or_build((shardings, cuts), build)
-
-
-_BATCH_COPIES = BoundedLRU()
-# A synchronous take's programs of one leaf each (``cut_in_stage``): one a
-# distinct cut and sharding, whatever the leaf's other dimensions (``jit``
-# keeps an executable a shape). Apart from the forks', which they would push
-# out: a state has more kinds of big leaf than a job has state structures.
-_STAGE_CUTS = BoundedLRU(64)
-
-
-def cut_in_stage(arr: Any, cut: PieceCut) -> Optional[PiecedArray]:
-    """A synchronous take's cut of one leaf at its turn in the stage: the
-    pieces the fork would have written (``cut`` from :func:`leaf_cut`), by
-    the fork's own movers in a program of the one leaf, so that the leaf
-    crosses under the pieces' window, lands row-major, and is gathered into
-    host pages the take has used before (``io_preparers.array``). The
-    caller bounds the HBM the pieces hold (``d2h.CUT_WINDOW_BYTES``) and
-    leaves the leaf whole where the device has no room for them (an
-    allocation failure raised here or, for the program's own temporaries,
-    at a piece's resolve: ``_is_oom_error``). None where the kernel
-    compiler refuses the mover: the leaf then crosses whole too, as it did
-    before this existed, and the take goes on."""
-    try:
-        (pieces,) = _batch_copy_fn((arr.sharding,), (cut,), _STAGE_CUTS)([arr])
-    except Exception as e:  # noqa: BLE001 - only the kernel's compiler degrades here
-        if "Mosaic" not in str(e):
-            raise
-        _give_up_cut((cut,), e)
-        return None
-    return PiecedArray(arr.shape, arr.dtype, arr.sharding, pieces, cut.ranges)
-
-
 def capture_flattened(
     flattened: Dict[str, Any], timings: Optional[Dict[str, float]] = None
 ) -> Dict[str, Any]:
@@ -613,7 +390,7 @@ def capture_flattened(
     Returns ``flattened`` with device leaves replaced by their captures
     (the input dict is never mutated); ``timings["d2h_hint"]`` accumulates
     the capture wall time."""
-    device_paths = [p for p, v in flattened.items() if _is_jax_array(v)]
+    device_paths = [p for p, v in flattened.items() if is_jax_array(v)]
     if (
         not device_paths
         or not knobs.is_async_device_copy_enabled()
@@ -712,7 +489,7 @@ def prepare_write(
             if is_captured:
                 arr = arr.assembled_local()
             elif (
-                _is_jax_array(arr)
+                is_jax_array(arr)
                 and len(arr.sharding.device_set) > 1
                 and arr.sharding.is_fully_replicated
             ):

@@ -27,15 +27,15 @@ from __future__ import annotations
 
 import asyncio
 import logging
-import math
 import pickle
 import time
 from concurrent.futures import Executor
-from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import d2h, telemetry
+from .. import d2h, device_programs, telemetry
+from ..device_programs import PiecedArray
 from ..io_types import BufferConsumer, BufferStager, BufferType, ReadReq, WriteReq
 from ..manifest import ArrayEntry
 from ..restore_times import consume_landed, run_consume_work
@@ -67,184 +67,10 @@ FRAME_TABLE_SUFFIX = ".ftab"
 logger = logging.getLogger(__name__)
 
 
-def _is_jax_array(obj: Any) -> bool:
+def is_jax_array(obj: Any) -> bool:
     import jax
 
     return isinstance(obj, jax.Array)
-
-
-# XLA does not treat sub-32-bit floats as opaque bits. On the TPU toolchain
-# this repository was brought up on (v5e, jax/jaxlib 0.9.0, libtpu 0.0.34;
-# every bit pattern of each dtype put from the host) a slice or a bitcast of
-# bfloat16 flushes all 254 denormals to zero, and a copy, slice or bitcast of
-# float16 / float8_e4m3fn / float8_e5m2 rewrites NaN payloads; ``jnp.copy`` of
-# bfloat16 kept every pattern, and 32-bit floats, integers and bool are exact
-# in every program. Transfers (D2H, H2D) move bits unchanged. So a leaf of
-# such a dtype never enters a device program that would rewrite it: it
-# reaches the host whole and is cut, packed or captured there. The rule is
-# by dtype alone, on every backend, so the CPU suite runs the routing the
-# chip runs.
-
-
-def _is_small_float(dtype: Any) -> bool:
-    dt = np.dtype(dtype)
-    return dt.itemsize < 4 and dt.name.startswith(("float", "bfloat"))
-
-
-def slice_preserves_bits(dtype: Any) -> bool:
-    """Whether a device slice / bitcast / concatenate of ``dtype`` returns
-    the operand's bits unchanged (chunk slices, shard subdivision, the slab
-    pack)."""
-    return not _is_small_float(dtype)
-
-
-def copy_preserves_bits(dtype: Any) -> bool:
-    """Whether ``jnp.copy`` of ``dtype`` returns the operand's bits unchanged
-    (the async-take fork)."""
-    return not _is_small_float(dtype) or np.dtype(dtype).name == "bfloat16"
-
-
-# The hint's single owner moved to ``d2h`` (the transfer lanes issue hints
-# too); re-exported here for the existing importers (io_preparer, tests).
-hint_copy_to_host = d2h.hint_copy_to_host
-
-
-def chunk_row_ranges(
-    shape, itemsize: int, max_chunk_bytes: int
-) -> List[Tuple[int, int]]:
-    """Row ranges [r0, r1) per dim-0 chunk, each chunk <= max_chunk_bytes
-    (when a single row fits). Shared by the chunked-array preparer (one
-    storage object per chunk) and the prepared-state cache's replay of
-    that split."""
-    dim0 = int(shape[0])
-    row_bytes = itemsize * int(np.prod(shape[1:])) if len(shape) > 1 else itemsize
-    rows_per_chunk = max(1, max_chunk_bytes // max(row_bytes, 1))
-    n_chunks = math.ceil(dim0 / rows_per_chunk)
-    # Even spread so the last chunk isn't tiny.
-    base = dim0 // n_chunks
-    extra = dim0 % n_chunks
-    ranges = []
-    r0 = 0
-    for i in range(n_chunks):
-        rows = base + (1 if i < extra else 0)
-        ranges.append((r0, r0 + rows))
-        r0 += rows
-    return ranges
-
-
-def _dma_moves(dtype: Any) -> bool:
-    """Whether the fork's row cut (Pallas HBM-to-HBM DMAs, which move bits
-    and compute nothing, and integer copies behind them) takes ``dtype``:
-    bfloat16, the 32-bit types and the 8- and 16-bit integers. Mosaic
-    refuses bool, float16 and 64-bit types; float16 and float8 never fork
-    at all."""
-    dt = np.dtype(dtype)
-    if dt.name == "bfloat16":
-        return True
-    return (dt.kind in "iuf" and dt.itemsize == 4) or (
-        dt.kind in "iu" and dt.itemsize in (1, 2)
-    )
-
-
-class PieceCut(NamedTuple):
-    """How the fork writes a leaf as pieces: the row ranges [r0, r1), and
-    which mover writes them. ``relaid`` False: DMAs of whole HBM tiles, a
-    piece an array of the leaf's rows. ``relaid`` True: the leaf's bits as
-    integers, each range re-laid row-major into lanes of 128
-    (``io_preparer._relay_rows``); ``order`` is then, for a leaf whose bits
-    a DMA has to take first (``device_piece_cut``), the device's own order
-    of its dimensions, major to minor. Either way a piece's host copy is
-    the C-order bytes of its rows."""
-
-    ranges: Tuple[Tuple[int, int], ...]
-    relaid: bool
-    order: Optional[Tuple[int, ...]] = None
-
-
-def piece_row_ranges(shape, dtype: Any) -> Optional[PieceCut]:
-    """The pieces a forked leaf crosses to the host in, each at most
-    ``d2h.PIECE_BYTES`` (when a single unit of rows fits), or None where the
-    leaf goes whole: not over the piece size, one row, one piece, a dtype
-    the fork's movers do not take, or rows that no whole number of lanes
-    holds. One cut, two movers. The DMA moves whole HBM tiles: where the
-    last dimension is a multiple of 128 and the one before it of 8, a 2-D
-    leaf is cut at multiples of 8 rows and a deeper one between any two of
-    its slabs, and no byte is computed on. Any other shape (a width of 1856
-    or 10304, 1001 rows) the device may not even hold row-major, and its
-    host copy would be re-laid there by a strided copy: the fork re-lays
-    it, in integers, cut at multiples of the fewest rows that fill whole
-    lanes of 128 elements. By shape and dtype alone, on every backend."""
-    shape = tuple(int(d) for d in shape)
-    if len(shape) < 2 or not _dma_moves(dtype):
-        return None
-    itemsize = np.dtype(dtype).itemsize
-    if itemsize * int(np.prod(shape)) <= d2h.PIECE_BYTES:
-        return None
-    unit = 8 if len(shape) == 2 else 1
-    relaid = bool(
-        shape[0] % unit or shape[-1] % 128 or (len(shape) > 2 and shape[-2] % 8)
-    )
-    if relaid:
-        unit = 128 // math.gcd(int(np.prod(shape[1:])), 128)
-        if shape[0] % unit:
-            return None
-    ranges = chunk_row_ranges(
-        (shape[0] // unit, unit) + shape[1:], itemsize, d2h.PIECE_BYTES
-    )
-    if len(ranges) < 2:
-        return None
-    return PieceCut(tuple((r0 * unit, r1 * unit) for r0, r1 in ranges), relaid)
-
-
-def device_piece_cut(
-    shape, dtype: Any, device_order: Callable[[], Sequence[int]], on_tpu: bool
-) -> Optional[PieceCut]:
-    """``piece_row_ranges`` for a leaf as one device holds it: the cut the
-    fork program is built from, or None where the leaf goes whole. XLA
-    moves integers and 32-bit floats bit for bit, so those are re-laid as
-    they are. A bfloat16 leaf to re-lay has its bits taken first, by one
-    DMA of the whole leaf in the device's own order of its dimensions
-    (``device_order()``, major to minor; asked only for such a leaf), and
-    the TPU's kernel compiler takes whole HBM tiles only: handed a leaf
-    whose minor dimension in that order is no multiple of 128, or the one
-    before it of 8, it does not raise, it aborts the process. Such a leaf
-    stays whole."""
-    cut = piece_row_ranges(shape, dtype)
-    if cut is None or not cut.relaid or slice_preserves_bits(dtype):
-        return cut
-    order = tuple(int(i) for i in device_order())
-    if on_tpu and (int(shape[order[-1]]) % 128 or int(shape[order[-2]]) % 8):
-        return None
-    return cut._replace(order=order)
-
-
-class PiecedArray:
-    """A forked leaf that left the fork as row-range pieces: the metadata
-    the write planners read of a ``jax.Array`` that lives whole on one
-    device (``shape`` / ``dtype`` / ``sharding``), so it plans as that leaf
-    does (one ``ArrayEntry``, one storage object at the same location),
-    and the device arrays that hold its rows. Its stager moves the pieces
-    through the transfer lanes into one host buffer of the leaf's size."""
-
-    __slots__ = ("shape", "dtype", "sharding", "pieces", "ranges")
-
-    def __init__(
-        self,
-        shape: Tuple[int, ...],
-        dtype: Any,
-        sharding: Any,
-        pieces: Sequence[Any],
-        ranges: Sequence[Tuple[int, int]],
-    ) -> None:
-        self.shape = shape
-        self.dtype = dtype
-        self.sharding = sharding
-        self.pieces = pieces
-        self.ranges = ranges
-
-    @property
-    def nbytes(self) -> int:
-        return int(np.prod(self.shape)) * np.dtype(self.dtype).itemsize
 
 
 def to_host(
@@ -253,8 +79,8 @@ def to_host(
     """Kick off an async D2H transfer; return an awaitable resolver. The
     path of a stager driven outside a write pipeline (no lanes, no window).
     ``into``: as :func:`~..d2h.resolve_on_host` takes it."""
-    if _is_jax_array(arr):
-        hint_copy_to_host(arr)
+    if is_jax_array(arr):
+        d2h.hint_copy_to_host(arr)
 
     async def resolve() -> np.ndarray:
         loop = asyncio.get_running_loop()
@@ -433,7 +259,7 @@ class ArrayBufferStager(BufferStager):
             host = await _gather_pieces(arr, executor, location)
             if times is not None:
                 times.count_gather_pages(0, host.nbytes)
-        elif _is_jax_array(arr):
+        elif is_jax_array(arr):
             host = None
             if (
                 ctx is not None
@@ -527,7 +353,8 @@ class ArrayBufferStager(BufferStager):
     ) -> Optional[np.ndarray]:
         """A synchronous take's big leaf (``ctx.arena``: no step runs beside
         this pipeline, and it owns an arena of host pages): where the fork
-        would have cut it (``io_preparer.leaf_cut``, the one predicate), it
+        would have cut it (``device_programs.leaf_cut``, the one predicate;
+        ``whole_leaf`` says it stays one storage object), it
         is cut now, by the fork's movers, and its pieces cross under the
         pieces' window into a view of the arena that an earlier leaf of the
         take has used, or into fresh pages where the arena has no room worth
@@ -539,9 +366,7 @@ class ArrayBufferStager(BufferStager):
         they cross (``d2h.CUT_WINDOW_BYTES`` a device bounds them), and is
         given back by :meth:`release_staged` once hash and write are done
         with it, or here where the stage fails."""
-        from ..io_preparer import _is_oom_error, cut_in_stage, leaf_cut
-
-        cut = leaf_cut(arr)
+        cut = device_programs.leaf_cut(arr)
         if cut is None:
             return None
         nbytes = _nbytes_of(arr)
@@ -553,7 +378,7 @@ class ArrayBufferStager(BufferStager):
             window = ctx.lanes.cut_window(next(iter(arr.devices())).id)
             await window.room(nbytes, d2h.CUT_WINDOW_BYTES, loop)
             try:
-                pieced = cut_in_stage(arr, cut)
+                pieced = device_programs.cut_in_stage(arr, cut)
                 if pieced is not None:
                     host = await _gather_pieces(
                         pieced, executor, location, into=views[0] if views else None
@@ -564,7 +389,7 @@ class ArrayBufferStager(BufferStager):
             self.release_staged()
             # The program's own temporaries are allocated as it runs: a
             # device that ran out then says so at a piece's resolve.
-            if not _is_oom_error(e):
+            if not device_programs.is_oom_error(e):
                 raise
             logger.info("no room on the device for the pieces of %s: %s", location, e)
             pieced = None

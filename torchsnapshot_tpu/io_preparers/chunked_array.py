@@ -6,10 +6,10 @@ partitioner split a replicated array's write load across processes at chunk
 granularity. On TPU the per-chunk slice ``arr[r0:r1]`` is an XLA device op, so
 chunk transfers stream out of HBM back-to-back without a full host-side copy
 first — for the dtypes a device slice returns bit for bit; a sub-32-bit float
-array is not chunked (``array.slice_preserves_bits``).
+array is not chunked (``device_programs.slice_preserves_bits``).
 
-The row-range math (``chunk_row_ranges``) lives in ``array.py``, shared with
-the prepared-state cache.
+The row-range math (``chunk_row_ranges``) lives in ``device_programs.py``,
+shared with the fork's piece cut and the prepared-state cache.
 """
 
 from __future__ import annotations
@@ -20,10 +20,11 @@ import numpy as np
 
 from ..io_types import ReadReq, WriteReq
 from ..manifest import ChunkedArrayEntry, Shard
+from ..device_programs import chunk_row_ranges, slice_preserves_bits
 from ..utils import knobs
-from .array import ArrayIOPreparer, chunk_row_ranges, slice_preserves_bits
+from .array import ArrayIOPreparer
 
-__all__ = ["should_chunk", "chunk_row_ranges", "ChunkedArrayIOPreparer"]
+__all__ = ["should_chunk", "ChunkedArrayIOPreparer"]
 
 
 def should_chunk(arr: Any) -> bool:

@@ -39,13 +39,13 @@ from ..serialization import (
     is_raw_family,
     string_to_dtype,
 )
+from ..device_programs import slice_preserves_bits
 from ..utils import knobs
 from .array import (
     ArrayIOPreparer,
     FramedSliceConsumer,
     consumed_by_landing,
     landing_view,
-    slice_preserves_bits,
 )
 
 # A target to restore into: (host buffer, global offsets, sizes)
@@ -121,7 +121,7 @@ def shard_pieces(
     """One local shard as the ``(offsets, sizes, data)`` pieces it is written
     in: subdivided to ``max_bytes`` for pipelining, except that a sub-32-bit
     float shard stays whole — its pieces would be cut on the device, and a
-    device slice rewrites that dtype's bits (``array.slice_preserves_bits``;
+    device slice rewrites that dtype's bits (``device_programs.slice_preserves_bits``;
     by dtype alone, so a host-captured shard lays out the same). Shared by
     ``prepare_write`` and the prepared-state cache's rebind, which must
     produce the same pieces in the same order."""

@@ -1,7 +1,7 @@
 """Mixture-of-Experts workload: expert-parallel (EP) sharded state.
 
 The reference's closest analogue is torchrec's row-wise sharded embedding
-tables (``benchmarks/torchrec/main.py:54-113``) — per-device parameter
+tables (its torchrec benchmark, ``main.py:54-113``) — per-device parameter
 shards that a checkpoint must save locally and reshard elastically. The
 TPU-native version of that regime is MoE expert parallelism: expert weights
 stacked on a leading ``experts`` axis and sharded over the mesh's ``ep``

@@ -1,8 +1,8 @@
 """Flagship workload: a decoder-only transformer with TP/FSDP shardings.
 
 The reference ships no model code of its own — its benchmarks synthesize
-large DDP/FSDP/torchrec workloads to checkpoint (``benchmarks/fsdp/main.py:
-35-72`` builds a 1.9B-param transformer). This module is the TPU-native
+large DDP/FSDP/torchrec workloads to checkpoint (its FSDP benchmark,
+``main.py:35-72``, builds a 1.9B-param transformer). This module is the TPU-native
 equivalent: a flax decoder-only LM sized like the reference's FSDP benchmark,
 plus Megatron-style sharding rules over a ``(dp, tp)`` mesh so benchmarks,
 the multi-chip dry run, and the torchrec-style embedding tests exercise the

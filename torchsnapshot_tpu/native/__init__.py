@@ -15,8 +15,8 @@ older tree copied over this one) can never load, whatever its mtime says.
 If no compiler is available, compilation fails, or
 ``TORCHSNAPSHOT_TPU_DISABLE_NATIVE_IO=1`` is set, ``load_native()`` returns
 ``None`` and the FS storage plugin uses the pure-Python path (counted as
-``storage.fs.native_fallback_bytes``). Entry points that measure call
-``benchmarks.common.require_native_engine()`` instead and fail.
+``storage.fs.native_fallback_bytes``). Entry points that measure
+(``chip_smoke.py``, ``perfbench/run.py``) load it blocking and fail without it.
 """
 
 from __future__ import annotations

@@ -36,8 +36,8 @@ _DEFAULT_TIMEOUT_S = 300.0
 # ---------------------------------------------------------------------------
 # Store round-trip accounting. Every concrete store op is one logical
 # round-trip against the (rank-0-hosted) control-plane server, so these
-# counters are the raw material for the coordination-cost scaling model in
-# ``benchmarks/stall`` — they turn "the stall grows with world size" into
+# counters are the raw material for a coordination-cost scaling model:
+# they turn "the stall grows with world size" into
 # "this take issued N round-trips" and make the pod-scale stall a
 # calculation instead of a hope. Diagnostics only: per-process, reset by the
 # caller around the section being measured.

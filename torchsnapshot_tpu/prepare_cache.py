@@ -51,16 +51,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .io_preparer import (
-    HostCapturedArray,
-    _is_jax_array,
-    capture_flattened,
-    classify,
-)
+from .device_programs import chunk_row_ranges
+from .io_preparer import HostCapturedArray, capture_flattened, classify
 from .io_preparers.array import (
     ArrayBufferStager,
     PollingTableStager,
-    chunk_row_ranges,
+    is_jax_array,
 )
 from .io_preparers.chunked_array import should_chunk
 from .io_preparers.object import ObjectBufferStager
@@ -167,7 +163,7 @@ class PreparedTake:
         if isinstance(arr, HostCapturedArray):
             arr = arr.assembled_local()
         elif (
-            _is_jax_array(arr)
+            is_jax_array(arr)
             and len(arr.sharding.device_set) > 1
             and arr.sharding.is_fully_replicated
         ):

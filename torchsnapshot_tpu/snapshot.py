@@ -45,7 +45,9 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 import numpy as np
 
 from .flatten import flatten, inflate
-from .io_preparer import _is_oom_error, prepare_write
+from .device_programs import is_oom_error
+from .io_preparer import prepare_write
+from .io_preparers.array import is_jax_array
 from .io_preparers.array import ArrayIOPreparer
 from .io_preparers.chunked_array import ChunkedArrayIOPreparer
 from .io_preparers.object import ObjectIOPreparer
@@ -120,8 +122,8 @@ LAST_SYNC_DRAIN_STATS: Dict[str, float] = {}
 # (``bcast.LAST_RESTORE_BCAST``), the swarm-restore record
 # (``swarm.LAST_RESTORE_SWARM``), and the origin-vs-peer-vs-cache byte
 # attribution (``attribution``). The restore analogue of the take
-# diagnostics above — bench.py's restore regression gate and the serving
-# benchmark read it without needing a telemetry session. Diagnostics only:
+# diagnostics above — ``perfbench`` and ``dev/probe_*.py`` read it without
+# needing a telemetry session. Diagnostics only:
 # overwritten per restore, per process.
 LAST_RESTORE_STATS: Dict[str, Any] = {}
 
@@ -1539,8 +1541,7 @@ class Snapshot:
         WHOLE restore, so any lower-class engine in this process (a
         background drain, scrub, gc, cache populate, a background swarm
         fetch) pauses its next admission at chunk granularity until this
-        restore completes; see ``benchmarks/qos/`` for the measured p99
-        effect.
+        restore completes.
 
         ``job``/``step``: opt into the catalog's ROLLOUT record stream —
         each rank appends one compact restore-side record (wall time,
@@ -1949,8 +1950,9 @@ class Snapshot:
         # hosts with no spare core even inline overlap loses (the copy
         # executes on the host's only core and starves behind GIL-holding
         # consumers) — but with a real accelerator backend the device_put
-        # is a PJRT hand-off and overlap WINS 1.5x even on one core
-        # (round 5, benchmarks/restore_overlap/), hence the platform-aware
+        # is a PJRT hand-off and overlap won 1.5x even on one core
+        # (round 5, a harness since deleted; not measured on the current
+        # chip), hence the platform-aware
         # auto gate; gated off, finalizers run phase-split after the
         # pipeline.
         # The hint keeps a numpy-only restore from consulting (and thereby
@@ -1963,14 +1965,14 @@ class Snapshot:
         def _target_platforms() -> Set[str]:
             platforms: Set[str] = set()
             for v in live_flattened.values():
-                if _is_jax_array(v):
+                if is_jax_array(v):
                     for d in v.sharding.device_set:
                         platforms.add(getattr(d, "platform", "cpu"))
             return platforms
 
         overlap = knobs.is_restore_overlap_enabled(
             has_jax_targets=any(
-                _is_jax_array(v) for v in live_flattened.values()
+                is_jax_array(v) for v in live_flattened.values()
             ),
             target_platforms=_target_platforms,
         )
@@ -3687,12 +3689,6 @@ def _count_leaves(flattened: Dict[str, Any]) -> None:
     telemetry.counter_add("take.small_leaf_bytes", small_bytes)
 
 
-def _is_jax_array(obj: Any) -> bool:
-    import jax
-
-    return isinstance(obj, jax.Array)
-
-
 def _matches_include(path: str, globs: List[str]) -> bool:
     """Whether a logical path is selected by a lazy-restore include list.
 
@@ -3743,7 +3739,7 @@ def _wanted_framed_locations(
     shards = getattr(entry, "shards", None) or []
     if shards:
         targets = None
-        if _is_jax_array(live) and list(live.shape) == list(entry.shape):
+        if is_jax_array(live) and list(live.shape) == list(entry.shape):
             targets = []
             seen = set()
             index_map = live.sharding.addressable_devices_indices_map(
@@ -3876,7 +3872,7 @@ def _place_over_target(
     try:
         return place()
     except Exception as e:  # noqa: BLE001 - only allocation failure degrades
-        if not _is_oom_error(e) or live.is_deleted():
+        if not is_oom_error(e) or live.is_deleted():
             raise
     if times is not None:
         times.add("place_retry_s", time.monotonic() - t0)
@@ -4138,7 +4134,7 @@ def _prepare_restore_one(  # spmd-pure
                 fresh_target=not in_place,
             )
 
-        if _is_jax_array(live):
+        if is_jax_array(live):
             # The host target exists only to be put on the device.
             held = _host_targets(
                 arena, live, [(tuple(entry.shape), np_dtype)], plan, times
@@ -4181,7 +4177,7 @@ def _prepare_restore_one(  # spmd-pure
 
     if isinstance(entry, ShardedArrayEntry):
         np_dtype = string_to_dtype(entry.dtype)
-        if _is_jax_array(live) and list(live.shape) == list(entry.shape):
+        if is_jax_array(live) and list(live.shape) == list(entry.shape):
             sharding = live.sharding
             rects = target_shard_rects(sharding, entry.shape)
 

@@ -78,12 +78,6 @@ _WARNED_KEYSET_SIGS: "set" = set()
 # v5: the chunk-streamed write path and its three knobs left the signature.
 _FINGERPRINT_VERSION = 5
 
-def _is_jax_array(obj: Any) -> bool:
-    import jax
-
-    return isinstance(obj, jax.Array)
-
-
 def _leaf_descriptor(value: Any, world_size: int) -> Tuple:
     """Everything about one leaf that shapes the plan — never its values.
 

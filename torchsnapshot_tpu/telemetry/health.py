@@ -15,8 +15,7 @@ cannot trip a ratio test. Detectors need ``MIN_HISTORY`` prior steps before
 they arm — a short series produces no events, never a guess.
 
 Surfaces: ``python -m torchsnapshot_tpu timeline <bucket> --job <j>``
-renders the trend table with flagged steps; ``benchmarks/continuous``
-embeds the same render in its artifact; :func:`log_anomalies` emits ONE
+renders the trend table with flagged steps; :func:`log_anomalies` emits ONE
 log warning per anomaly kind (not per step) so a 500-step drift does not
 flood the job log.
 

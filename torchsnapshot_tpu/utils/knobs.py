@@ -621,8 +621,7 @@ def is_restore_overlap_enabled(
     actually has live jax device targets (``has_jax_targets``) — on any
     host whose TARGET arrays live on a real accelerator: there the
     ``device_put`` dispatch hands off to the PJRT client (transfer-engine/
-    network bound), so overlap needs no spare core (harness:
-    ``benchmarks/restore_overlap/``; not measured on the current chip).
+    network bound), so overlap needs no spare core (not measured on the current chip).
     Disabled when the targets are CPU-backed on a single-core host:
     CPU-backend dispatch executes the copy on the host's only core and
     starves behind the busy read pipeline.
@@ -765,8 +764,7 @@ def get_hash_chunk_bytes() -> int:
     digests — recorded in a v2 sidecar whose chunk list makes RANGED reads
     verifiable and scrub corruption chunk-attributable. Objects no larger
     than one chunk keep the exact v1 record. Default 64 MiB. ``0`` disables
-    chunking entirely — the serial v1 fold and v1-only sidecars (the compat escape hatch and the
-    A/B baseline of ``benchmarks/staging``'s hash sweep). The grain is part
+    chunking entirely — the serial v1 fold and v1-only sidecars (the compat escape hatch). The grain is part
     of a v2 object's dedup identity: keep it stable across the takes of an
     incremental chain, or changed-grain objects re-upload."""
     val = os.environ.get(_ENV_HASH_CHUNK)
@@ -792,10 +790,6 @@ def override_hash_chunk_bytes(value: int):
     return _override_env(_ENV_HASH_CHUNK, str(value))
 
 
-def override_hash_workers(value: int):
-    return _override_env(_ENV_HASH_WORKERS, str(value))
-
-
 _ENV_QOS = "TORCHSNAPSHOT_TPU_QOS"
 _ENV_QOS_POLL_S = "TORCHSNAPSHOT_TPU_QOS_POLL_S"
 _ENV_QOS_MAX_PAUSE_S = "TORCHSNAPSHOT_TPU_QOS_MAX_PAUSE_S"
@@ -807,8 +801,7 @@ def is_qos_enabled() -> bool:
     this process, lower-class engines stop admitting new work — budget,
     io/hash/transfer-pool slots all yield at the next admission point
     (in-flight steps finish). Off =
-    every operation competes FIFO, the pre-engine behavior (the A/B
-    baseline ``benchmarks/qos`` measures against)."""
+    every operation competes FIFO, the pre-engine behavior."""
     return os.environ.get(_ENV_QOS, "1") not in ("0", "false", "False")
 
 
@@ -1061,14 +1054,6 @@ def override_read_cache_dir(path: str):
     return _override_env(_ENV_READ_CACHE_DIR, path)
 
 
-def override_read_cache_bytes(value: int):
-    return _override_env(_ENV_READ_CACHE_BYTES, str(value))
-
-
-def override_read_cache_verify(enabled: bool):
-    return _override_env(_ENV_READ_CACHE_VERIFY, "1" if enabled else "0")
-
-
 _ENV_VERIFY_READS = "TORCHSNAPSHOT_TPU_VERIFY_READS"
 
 
@@ -1186,10 +1171,6 @@ def override_bcast_reader_deadline_s(value: float):
     return _override_env(_ENV_BCAST_READER_DEADLINE, str(value))
 
 
-def override_bcast_reelect_max(value: int):
-    return _override_env(_ENV_BCAST_REELECT_MAX, str(value))
-
-
 _ENV_SWARM_RESTORE = "TORCHSNAPSHOT_TPU_SWARM_RESTORE"
 _ENV_SWARM_CHUNK_DEADLINE = "TORCHSNAPSHOT_TPU_SWARM_CHUNK_DEADLINE_S"
 _ENV_SWARM_FANOUT = "TORCHSNAPSHOT_TPU_SWARM_FANOUT"
@@ -1257,10 +1238,6 @@ def override_swarm_chunk_deadline_s(value: float):
     return _override_env(_ENV_SWARM_CHUNK_DEADLINE, str(value))
 
 
-def override_swarm_fanout(value: int):
-    return _override_env(_ENV_SWARM_FANOUT, str(value))
-
-
 _ENV_READ_MERGE_GAP = "TORCHSNAPSHOT_TPU_READ_MERGE_GAP_BYTES"
 
 
@@ -1313,10 +1290,6 @@ def get_max_chain_len() -> int:
 
 def override_catalog(enabled: bool):
     return _override_env(_ENV_CATALOG, "1" if enabled else "0")
-
-
-def override_max_chain_len(value: int):
-    return _override_env(_ENV_MAX_CHAIN_LEN, str(value))
 
 
 _ENV_FAULTS = "TORCHSNAPSHOT_TPU_FAULTS"

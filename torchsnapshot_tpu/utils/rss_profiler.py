@@ -1,6 +1,6 @@
 """Background RSS-delta sampler (reference ``rss_profiler.py:32-56``).
 
-Used by benchmarks/tests to verify the scheduler's memory budget holds::
+Used by tests to verify the scheduler's memory budget holds::
 
     deltas = []
     with measure_rss_deltas(rss_deltas=deltas):
