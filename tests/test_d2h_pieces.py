@@ -836,6 +836,13 @@ def _described_cut(leaf):
         [((16, 2688, 1856), "bfloat16"), ((2688, 10304), "bfloat16"), ((16, 1856, 2688), "bfloat16"),
          ((2688, 4096), "bfloat16"), ((16384, 2688), "bfloat16"), ((4096, 1100), "float32"),
          ((2688,), "float32")],
+        # lfm2-24b-a2b: three stacks a layer (minors 1536 and 2048), a fused in_proj, the dense
+        # MLP, the tied table: every leaf over the piece size is on the tiling and takes the DMA
+        # cut; a square of 8.4 MB, the three-tap convolution and the float32 router (which the
+        # chip holds column first) and a gain go whole
+        [((8, 2048, 1536), "bfloat16"), ((8, 1536, 2048), "bfloat16"), ((2048, 6144), "bfloat16"),
+         ((2048, 11776), "bfloat16"), ((11776, 2048), "bfloat16"), ((8192, 2048), "bfloat16"),
+         ((2048, 2048), "bfloat16"), ((2048, 1, 3), "bfloat16"), ((2048, 64), "float32"), ((64,), "float32")],
         # at the guard: bfloat16 the chip holds in partial tiles (rows no multiple of 8
         # before 128 lanes; 2052 % 8 before 4096 lanes) goes whole, handed to the kernel
         # compiler it aborts the process; whole tiles of 8 that are no multiple of 16
