@@ -5,7 +5,12 @@ whether huge pages or a bulk populate cure it. ``PERF.md`` section 6.
 PR 41 added the last arm (ROADMAP S1 (a), for the record): eight readers
 copy 4 MiB chunks out of warm memory into a fresh 1.5 GiB destination while
 8, 13 or 16 other threads touch its pages ahead of them, a chunk at a time;
-does touching ahead pass what the readers do alone into fresh pages?"""
+does touching ahead pass what the readers do alone into fresh pages?
+
+PR 51 added ``--arena``: what the restore's arena touches of its own pages
+(``HostArena.pretouch``, eight GIL-free touchers) in the 0.15 s a restore's
+plan gives it, while the main thread sleeps, runs Python (the plan holds
+the GIL), or allocates and frees big stand-in arrays as the plan does."""
 
 import json
 import mmap
@@ -73,6 +78,31 @@ def touch_ahead(touchers, nbytes=1536 * MIB, chunk=4 * MIB, readers=8, warm=Fals
     return round(nbytes / (time.perf_counter() - t0) / 1e9, 3)
 
 
+def arena_touch(beside, lap_s=0.15):
+    """GB the arena's touchers finish in ``lap_s`` and their rate, with the
+    main thread doing ``beside`` meanwhile."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from torchsnapshot_tpu import host_arena
+
+    arena = host_arena.HostArena()
+    t0 = time.perf_counter()
+    arena.pretouch(arena.capacity)
+    spins = 0
+    while time.perf_counter() - t0 < lap_s:
+        if beside == "sleep":
+            time.sleep(0.001)
+        elif beside == "python":
+            spins += sum(range(1000))
+        else:  # the plan's stand-ins: an mmap and a munmap each
+            del [np.empty(400 * MIB, np.uint8) for _ in range(2)][:]
+    arena.close()
+    return {
+        "gb": round(arena.pretouched_bytes / 1e9, 4),
+        "gbps": round(arena.pretouched_bytes / 1e9 / arena.pretouch_s, 3),
+        "stop_wait_ms": round(arena.pretouch_stop_wait_s * 1e3, 3),
+    }
+
+
 def meminfo(key):
     with open("/proc/meminfo") as f:
         for line in f:
@@ -83,6 +113,15 @@ def meminfo(key):
 def main():
     n = 512 * MIB
     out = {}
+    if "--arena" in sys.argv:
+        for round_ in (1, 2, 3):
+            for beside in ("sleep", "python", "standins"):
+                out[f"arena_touch_beside_{beside}_r{round_}"] = arena_touch(beside)
+        print(json.dumps(out, indent=1))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/probe_first_touch_arena.json", "w") as f:
+            json.dump(out, f, indent=1)
+        return 0
     for name in ("enabled", "defrag", "shmem_enabled"):
         try:
             with open(f"/sys/kernel/mm/transparent_hugepage/{name}") as f:

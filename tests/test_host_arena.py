@@ -13,6 +13,7 @@ CPU runs: counts, addresses and bits only, never a rate.
 import asyncio
 import contextlib
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -309,6 +310,149 @@ def test_the_cpu_backends_devices_are_not_said_to_copy() -> None:
 
 
 # ---------------------------------------------------------------------------
+# Touched while the restore plans
+# ---------------------------------------------------------------------------
+
+
+def _touchers():
+    return [t for t in threading.enumerate() if t.name.startswith("tss-host-arena-touch")]
+
+
+def _let_the_touchers_finish(arena, timeout=30.0) -> None:
+    """Until every byte wanted so far has been touched (they then wait for
+    more to be wanted, or for the end)."""
+    deadline = time.monotonic() + timeout
+    while arena._touch.done < arena._touch.wanted:
+        assert time.monotonic() < deadline, "the touchers never caught up"
+        time.sleep(0.001)
+
+
+@pytest.fixture
+def held_touchers(monkeypatch):
+    """Touchers that stand at the engine's door until they are told to
+    stop, as ones that have not come round to their first stripe do; when
+    each was let in, with the flag up, is on record."""
+    real, calls = native.touch_stripes, []
+
+    def touch(lib, address, state, stripe_bytes, page_bytes):
+        while not state.stop:
+            time.sleep(0.001)
+        calls.append(time.monotonic())
+        return real(lib, address, state, stripe_bytes, page_bytes)
+
+    monkeypatch.setattr(native, "touch_stripes", touch)
+    return calls
+
+
+def test_pretouched_pages_count_as_used_and_are_handed_out_first() -> None:
+    arena = HostArena(256 * KIB)
+    arena.lease([16 * KIB], 1)
+    arena.pretouch(64 * KIB)
+    assert arena.allocated and len(_touchers()) == host_arena.PRETOUCH_THREADS
+    _let_the_touchers_finish(arena)
+    first = _take(arena, 16 * KIB)
+    assert not _touchers()
+    assert arena.pretouched_bytes == 64 * KIB == arena.touched_bytes
+    assert _addr(first.views[0]) == arena._mem.ctypes.data and first.recycled_bytes == 16 * KIB
+    second = _take(arena, 64 * KIB)  # 48 KiB of it touched beforehand
+    assert _addr(second.views[0]) == _addr(first.views[0]) + 16 * KIB
+    assert second.recycled_bytes == 48 * KIB and arena.touched_bytes == 80 * KIB
+    assert arena.pretouch_s > 0 and 0 <= arena.pretouch_stop_wait_s <= arena.pretouch_s
+    arena.close()
+
+
+@pytest.mark.parametrize("asked, wanted", [([8 * KIB], 8 * KIB), ([8 * KIB, 12 * KIB], 20 * KIB), ([48 * KIB] * 3, 64 * KIB)])
+def test_no_more_is_touched_than_planned_leases_will_use(asked, wanted) -> None:
+    arena = HostArena(64 * KIB)
+    for nbytes in asked:
+        arena.pretouch(nbytes)
+    arena.pretouch(65 * KIB)  # a lease the arena cannot hold takes pages of its own
+    arena.pretouch(0)
+    _let_the_touchers_finish(arena)
+    assert arena._touch.wanted == wanted == arena._touch.claimed
+    lease = _take(arena, 4 * KIB)
+    assert arena.pretouched_bytes == wanted and lease.recycled_bytes == 4 * KIB
+    arena.pretouch(32 * KIB)  # a lease holds a view: never again
+    assert not _touchers() and arena._touch.wanted == wanted
+    arena.close()
+
+
+@pytest.mark.parametrize("mib", [1, 8, 32])
+def test_a_lease_taken_while_the_touchers_run_is_never_written_by_one(mib) -> None:
+    """The invariant, against the engine itself: the lease takes the whole
+    of what is being touched the moment the touchers have begun, fills it,
+    and nothing but the filling is found there afterwards."""
+    arena = HostArena(mib * 1024 * KIB)
+    arena.pretouch(arena.capacity)
+    lease = _take(arena, arena.capacity)
+    assert not _touchers()
+    (view,) = lease.views
+    view[:] = 0xA5
+    time.sleep(0.05)
+    assert arena.pretouched_bytes <= arena.capacity and lease.recycled_bytes == arena.pretouched_bytes
+    assert view.min() == 0xA5 == view.max()
+    arena.close()
+    assert view.min() == 0xA5 == view.max()
+
+
+def test_touchers_are_gone_before_the_first_view_and_never_come_back(held_touchers) -> None:
+    arena = HostArena(64 * 1024 * KIB)
+    arena.pretouch(arena.capacity)
+    assert len(_touchers()) == host_arena.PRETOUCH_THREADS and not held_touchers
+    lease = _take(arena, 40 * 1024 * KIB)
+    taken_at = time.monotonic()
+    assert not _touchers()
+    lease.views[0][:] = 0x5A
+    # Each was let into the engine only with the flag up, and touched nothing.
+    assert len(held_touchers) == host_arena.PRETOUCH_THREADS
+    assert all(at <= taken_at for at in held_touchers)
+    assert arena.pretouched_bytes == 0 and lease.recycled_bytes == 0
+    arena.pretouch(8 * 1024 * KIB)
+    assert not _touchers()
+    arena.close()
+    assert lease.views[0].min() == 0x5A == lease.views[0].max()
+
+
+def test_close_while_touching_joins_before_the_memory_goes(held_touchers) -> None:
+    arena = HostArena(64 * 1024 * KIB)
+    arena.pretouch(arena.capacity)
+    assert _touchers() and arena.allocated
+    arena.close()
+    closed_at = time.monotonic()
+    assert not _touchers() and not arena.allocated
+    assert len(held_touchers) == host_arena.PRETOUCH_THREADS
+    assert all(at <= closed_at for at in held_touchers)
+    arena.pretouch(8 * KIB)  # closed: serves nobody
+    assert not _touchers() and not arena.allocated
+
+
+@pytest.mark.parametrize("mib", [16, 64])
+def test_close_in_the_middle_of_the_touching_leaves_no_toucher_in_the_memory(mib) -> None:
+    """Against the engine itself: closed as soon as the first stripes are
+    out, the touchers inside them."""
+    arena = HostArena(mib * 1024 * KIB)
+    arena.pretouch(arena.capacity)
+    deadline = time.monotonic() + 30
+    while not arena._touch.claimed:
+        assert time.monotonic() < deadline, "no toucher ever claimed a stripe"
+        time.sleep(0)
+    arena.close()
+    assert not _touchers() and not arena.allocated
+    assert arena.pretouched_bytes <= arena._touch.claimed <= arena.capacity
+    assert arena.pretouched_bytes % PAGE_BYTES == 0
+
+
+def test_without_the_engine_nothing_is_touched_beforehand(monkeypatch) -> None:
+    monkeypatch.setattr(native, "load_native_nonblocking", lambda: None)
+    arena = HostArena(64 * KIB)
+    arena.pretouch(16 * KIB)
+    assert not arena.allocated and not _touchers()
+    lease = _take(arena, 16 * KIB)
+    assert lease.recycled_bytes == 0 and arena.pretouched_bytes == 0 and arena.pretouch_s == 0
+    arena.close()
+
+
+# ---------------------------------------------------------------------------
 # The restore through it
 # ---------------------------------------------------------------------------
 
@@ -469,10 +613,15 @@ def test_a_restore_through_a_small_arena_is_exact_and_finishes(
         # come back: the arena is left alone.
         assert not arena.allocated and stats["recycled_bytes"] == 0
     elif case == "budget_split":
-        assert stats["recycled_bytes"] == 0  # a piece a read, a leaf too large
+        # A piece a read, a leaf too large: no page comes back to be used
+        # again, so nothing is recycled but what was touched beforehand.
+        assert stats["recycled_bytes"] <= stats["pretouched_bytes"]
     elif case != "slab_merged" and capacity_kib < 4096:
         assert stats["recycled_bytes"] > 0
-        assert stats["fresh_target_bytes"] >= arena.touched_bytes - 10 * PAGE_BYTES
+        # Every page the arena has used was some leaf's first touch or the
+        # arena's own, while the restore planned.
+        first_touched = stats["fresh_target_bytes"] + stats["pretouched_bytes"]
+        assert first_touched >= arena.touched_bytes - 10 * PAGE_BYTES
 
 
 def test_recycled_and_fresh_account_for_every_landed_byte(
@@ -489,7 +638,10 @@ def test_recycled_and_fresh_account_for_every_landed_byte(
     stats = snapshot_mod.LAST_RESTORE_STATS
     assert stats["landed_bytes"] == stats["bytes_read"] == sum(np.asarray(v).nbytes for v in tree.values())
     assert stats["recycled_bytes"] + stats["fresh_target_bytes"] == stats["landed_bytes"]
-    assert 0 < stats["recycled_bytes"] < stats["landed_bytes"]
+    assert 0 < stats["recycled_bytes"] <= stats["landed_bytes"]
+    # The arena's pages had their first touch from a leaf or, while the
+    # restore planned, from the arena itself.
+    assert stats["fresh_target_bytes"] + stats["pretouched_bytes"] > 0
     assert stats["target_wait_s"] >= 0.0
 
 
@@ -649,3 +801,89 @@ def test_the_capacity_is_never_more_than_the_memory_budget(tmp_path, devices_tha
     (arena,) = devices_that_copy
     assert arena.capacity == 32 * KIB
     assert host_arena.CAPACITY_BYTES <= 4 << 30
+
+
+@pytest.fixture
+def touchers_that_finish(monkeypatch):
+    """A test-sized plan is over before a thread has started: the first
+    lease lets the touchers finish what was wanted before it stops them."""
+    real = HostArena._end_pretouch
+
+    def end(self):
+        if self._touchers:
+            _let_the_touchers_finish(self)
+        real(self)
+
+    monkeypatch.setattr(HostArena, "_end_pretouch", end)
+
+
+@pytest.mark.parametrize("capacity_kib", [300, 8192])
+def test_a_restore_through_pretouched_pages_is_exact(
+    tmp_path, monkeypatch, devices_that_copy, touchers_that_finish, capacity_kib
+) -> None:
+    """What the plan's leases will use is touched beforehand, no more, and
+    counts as recycled; every leaf bit for bit."""
+    monkeypatch.setattr(host_arena, "CAPACITY_BYTES", capacity_kib * KIB)
+    real_lease, leases = HostArena.lease, []
+
+    def lease(self, *args, **kwargs):
+        leases.append(real_lease(self, *args, **kwargs))
+        return leases[-1]
+
+    monkeypatch.setattr(HostArena, "lease", lease)
+    tree = _state()
+    del tree["host"]
+    url = str(tmp_path / "snap")
+    Snapshot.take(url, {"s": StateDict(**tree)})
+    tgt = StateDict(**_zero_targets(tree))
+    Snapshot(url).restore({"s": tgt})
+    _assert_restored(tgt, tree)
+    (arena,) = devices_that_copy
+    stats = snapshot_mod.LAST_RESTORE_STATS
+    assert not _touchers() and arena._closed
+    leased = sum(lease.nbytes for lease in leases)
+    assert len(leases) == len(tree) and 0 < leased <= 8192 * KIB
+    assert stats["pretouched_bytes"] == arena.pretouched_bytes == min(arena.capacity, leased)
+    assert stats["recycled_bytes"] >= min(stats["pretouched_bytes"], stats["landed_bytes"]) - 40 * PAGE_BYTES
+    assert stats["recycled_bytes"] + stats["fresh_target_bytes"] == stats["landed_bytes"] == stats["bytes_read"]
+    assert arena.touched_bytes <= leased
+    assert stats["pretouch_s"] > 0 and stats["pretouch_stop_wait_s"] >= 0
+
+
+@pytest.mark.parametrize("fails_at", [1, 4])
+def test_a_restore_that_fails_in_its_plan_leaves_no_toucher_behind(
+    tmp_path, monkeypatch, devices_that_copy, held_touchers, fails_at
+) -> None:
+    monkeypatch.setattr(host_arena, "CAPACITY_BYTES", 8192 * KIB)
+    tree = _state(6)
+    url = str(tmp_path / "snap")
+    Snapshot.take(url, {"s": StateDict(**tree)})
+    tgt = StateDict(**_zero_targets(tree))
+    real, planned = snapshot_mod._prepare_restore_one, []
+
+    def failing(logical_path, *args, **kwargs):
+        if len(planned) == fails_at:
+            assert _touchers()  # leases were planned: the touchers are at it
+            raise RuntimeError("the plan cannot go on")
+        planned.append(logical_path)
+        return real(logical_path, *args, **kwargs)
+
+    monkeypatch.setattr(snapshot_mod, "_prepare_restore_one", failing)
+    with pytest.raises(Exception, match="plan cannot go on"):
+        Snapshot(url).restore({"s": tgt})
+    (arena,) = devices_that_copy
+    assert arena._closed and not arena.allocated and not _touchers()
+    assert arena.pretouched_bytes == 0 and arena.touched_bytes == 0
+
+
+def test_on_the_cpu_platform_nothing_is_touched_beforehand(tmp_path, arenas) -> None:
+    tree = _state(4)
+    url = str(tmp_path / "snap")
+    Snapshot.take(url, {"s": StateDict(**tree)})
+    tgt = StateDict(**_zero_targets(tree))
+    Snapshot(url).restore({"s": tgt})
+    _assert_restored(tgt, tree)
+    (arena,) = arenas
+    stats = snapshot_mod.LAST_RESTORE_STATS
+    assert not arena.allocated and not _touchers()
+    assert stats["pretouched_bytes"] == 0 == stats["pretouch_s"] == stats["pretouch_stop_wait_s"]

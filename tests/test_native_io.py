@@ -62,6 +62,50 @@ def test_write_read_roundtrip(lib, tmp_path, nbytes: int) -> None:
     assert bytes(out) == data.tobytes()
 
 
+@pytest.mark.parametrize("touchers", [1, 3])
+def test_touchers_write_a_byte_a_page_as_far_as_is_wanted_and_nothing_once_stopped(lib, touchers) -> None:
+    import threading
+    import time
+
+    page, pages = 4096, 40
+    buf = np.full(pages * page + 16, 7, dtype=np.uint8)
+    state, ends = native.TouchState(), []
+
+    def toucher():
+        ends.append(native.touch_stripes(lib, buf.ctypes.data, state, 4 * page, page))
+
+    threads = [threading.Thread(target=toucher) for _ in range(touchers)]
+    for t in threads:
+        t.start()
+
+    def wait_for(nbytes):
+        deadline = time.monotonic() + 30
+        while state.done < nbytes and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert state.done == nbytes == state.claimed
+
+    time.sleep(0.01)
+    assert np.all(buf == 7)  # nothing is wanted yet
+    state.wanted = 10 * page  # two whole stripes and a short one
+    wait_for(10 * page)
+    assert np.flatnonzero(buf != 7).tolist() == [i * page for i in range(10)]
+    state.wanted = 30 * page
+    wait_for(30 * page)
+    state.stop = 1
+    for t in threads:
+        t.join(30)
+    state.wanted = pages * page  # too late
+    assert ends == [native.TOUCHED_ALL] * touchers
+    assert np.flatnonzero(buf != 7).tolist() == [i * page for i in range(30)]
+
+
+def test_a_toucher_told_to_stop_beforehand_touches_nothing(lib) -> None:
+    buf = np.full(8 * 4096, 7, dtype=np.uint8)
+    state = native.TouchState(wanted=buf.nbytes, stop=1)
+    assert native.touch_stripes(lib, buf.ctypes.data, state, 4096, 4096) == native.TOUCHED_ALL
+    assert state.claimed == 0 == state.done and np.all(buf == 7)
+
+
 def test_small_chunk_many_iterations(lib, tmp_path) -> None:
     """Chunk smaller than payload: exercises the bounce-buffer loop."""
     data = np.arange(64 * 1024, dtype=np.uint8).tobytes()

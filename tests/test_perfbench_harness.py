@@ -23,7 +23,7 @@ for _name in ("test_architecture", "test_contract"):
 pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
-# --------------------------- the metrics PR 40 brought, and PR 43's one
+# --------------------------- the metrics PR 40 brought, PR 43's one, PR 51's one
 #
 # Each reads what the take's artifact and ``LAST_RESTORE_STATS`` say of the
 # seconds inside the native engine's calls; against a program that stamps
@@ -40,12 +40,14 @@ _DRAIN = {
 _RESTORE = {
     "mount_bytes": 9.6e9, "mount_busy_s": 4.0, "mount_sum_s": 32.0,
     "pread_busy_s": 3.0, "pread_sum_s": 12.0, "reader_copy_sum_s": 20.0,
+    "bytes_read": 9.6e9, "pretouched_bytes": 0.48e9,
 }
 _RATIO_METRICS = {
     "io_mount_write_busy_pct": 40.0, "io_mount_write_gbps": 1.5, "io_mount_write_depth": 1.5,
     "io_writer_mount_pct": 37.5, "io_writer_copy_pct": 50.0, "io_writer_crc_pct": 6.25,
     "io_write_queued_pct": 75.0, "stage_d2h_gather_pct": 60.0, "io_bounce_warm_pct": 90.0,
     "restore_pread_gbps": 3.2, "restore_pread_depth": 4.0, "restore_reader_copy_pct": 62.5,
+    "restore_pretouched_pct": 5.0,
 }
 _LIFT_METRICS = {
     "step_block_gather_lift": "gather",

@@ -11,7 +11,8 @@ for the two operations that have nothing beside them to disturb: a restore
 and a synchronous take.
 
 A :class:`HostArena` is ``capacity`` bytes, allocated on first use and
-touched by the first leaves that use them. An entry's targets are one
+touched by the first leaves that use them, or, in a restore, by the arena
+itself beforehand (below). An entry's targets are one
 :class:`Lease`: page-aligned views carved first-fit from the lowest free
 address (so the arena touches no more fresh pages than were ever wanted at
 once), taken in one step when the entry's first read is about to be fetched
@@ -27,6 +28,26 @@ read it waits for needs. Where there is no such lease, or the entry is
 larger than the arena, the entry takes fresh pages of its own (``None``)
 as before.
 
+**What is touched when.** The first arena's worth of a restore's reads
+would land in pages nobody has touched, at the fault's rate and not the
+copy's. The one stretch of a restore in which nobody faults is its plan:
+so from the moment the plan has made its first lease
+(:meth:`HostArena.pretouch`, called for every lease planned) until the
+first lease takes room, ``PRETOUCH_THREADS`` threads of the arena's own
+first touch its pages, a byte a page through the native engine (GIL-free;
+without the engine nothing is touched beforehand), in stripes claimed from
+address 0 upward, as far as the leases planned so far will reach and never
+past ``capacity``: a restore that leases 50 MB touches 50 MB. What they
+finished without a gap counts as used (``touched_bytes``,
+``pretouched_bytes``), and first fit hands those pages to the first,
+largest reads. **The invariant: no byte of a view that a lease holds is
+ever written by a toucher.** ``_take`` stops and joins the touchers before
+it makes the first view (the engine looks at the stop flag before every
+page, so that is a page fault's wait, ``pretouch_stop_wait_s``), they never
+start again, and ``close`` stops and joins them before the memory goes, on
+a failed restore too. Touching *beside* the readers buys nothing: the
+faults share one rate (``PERF.md`` section 5, PR 41).
+
 Two users, one policy: **pages are recycled where no train step runs beside
 the operation, and stay fresh where one does.** ``Snapshot.restore`` leases
 the targets that exist only to be put on a device whose ``device_put``
@@ -36,7 +57,10 @@ the leaf's turn in the stage and given back when its hash and its storage
 write are done, or when the request fails or is cancelled: every lease that
 is out is then worth waiting for, and the writers pace the take through
 it); the arena is the write pipeline's, made at the first such leaf and
-closed with the pipeline. An ``async_take`` drain lands its gathers in fresh
+closed with the pipeline; it does not touch beforehand: a take has no plan
+in which nothing moves (its first leaf is cut as its stage begins), and the
+only end-to-end number that holds a synchronous take is a set-up time that
+could not show it. An ``async_take`` drain lands its gathers in fresh
 pages of each leaf's own: recycled ones moved faster and cost the steps
 beside the drain 4-9 points of their rate (``PERF.md``, PR 39).
 """
@@ -53,6 +77,8 @@ from typing import Any, Callable, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import native
+
 logger = logging.getLogger(__name__)
 
 PAGE_BYTES = mmap.PAGESIZE
@@ -60,6 +86,12 @@ PAGE_BYTES = mmap.PAGESIZE
 # touches for device-bound leaves); never more than the memory budget.
 # Sized on the v5e's machine, ``CHANGES.md`` PR 41.
 CAPACITY_BYTES = 1024 * 1024 * 1024
+# Who first-touches a restore's arena while the restore plans
+# (:meth:`HostArena.pretouch`): as many threads as the engine has readers at
+# its default depth, each a stripe at a time. More touchers fault no faster
+# on the v5e's machine (8 / 13 / 16: ``PERF.md`` section 5).
+PRETOUCH_THREADS = 8
+PRETOUCH_STRIPE_BYTES = 4 * 1024 * 1024
 
 # Platforms whose ``device_put`` of a host array copies it to memory of the
 # device's own. The CPU backend may hand back an array that shares the numpy
@@ -148,7 +180,8 @@ class Lease:
 
 class HostArena:
     """``capacity_bytes`` of host pages for one restore or one synchronous
-    take. Nothing is allocated before the first lease takes room."""
+    take. Nothing is allocated before the first lease takes room or, in a
+    restore, is planned (:meth:`pretouch`)."""
 
     def __init__(self, capacity_bytes: int = CAPACITY_BYTES) -> None:
         self.capacity = capacity_bytes // PAGE_BYTES * PAGE_BYTES
@@ -164,9 +197,48 @@ class HostArena:
         self._closed = False
         self._settling: Optional["queue.SimpleQueue"] = None
         self._settler: Optional[threading.Thread] = None
+        # Pre-touching (``pretouch``). A toucher is one call into the native
+        # engine and takes neither ``_lock`` nor the GIL: ``_take`` joins
+        # them while it holds both.
+        self.pretouched_bytes = 0  # the prefix the touchers finished
+        self.pretouch_s = 0.0  # from the first request to their end
+        self.pretouch_stop_wait_s = 0.0  # of it, waiting for them to stop
+        self._touch = native.TouchState()  # the engine's side of it
+        self._touch_unfinished: List[int] = []  # a toucher's, as it ends
+        self._touch_since = 0.0
+        self._touchers: List[threading.Thread] = []
 
     def lease(self, sizes: Sequence[int], reads: int) -> Lease:
         return Lease(self, sizes, reads)
+
+    def pretouch(self, nbytes: int) -> None:
+        """A lease of ``nbytes`` has been planned and nothing has been
+        fetched yet: have that many more bytes of the arena, from address 0
+        up and never past ``capacity``, first touched in the background
+        until the first lease takes room. The pages then count as used
+        (``touched_bytes``), and first fit hands them to the first reads.
+        Nothing happens without the native engine, which touches GIL-free."""
+        if not 0 < nbytes <= self.capacity:
+            return  # the lease will take fresh pages of its own
+        with self._lock:
+            if self._closed or self._touch.stop:
+                return
+            if not self._touchers:
+                lib = native.load_native_nonblocking()
+                if lib is None:
+                    return
+                self._allocate()
+                self._touch_since = time.monotonic()
+                for i in range(PRETOUCH_THREADS):
+                    toucher = threading.Thread(
+                        target=self._touch_all,
+                        args=(lib, self._mem.ctypes.data),
+                        name=f"tss-host-arena-touch-{i}",
+                        daemon=True,
+                    )
+                    toucher.start()
+                    self._touchers.append(toucher)
+            self._touch.wanted = min(self.capacity, self._touch.wanted + nbytes)
 
     @property
     def allocated(self) -> bool:
@@ -203,11 +275,10 @@ class HostArena:
             del self._free[i]
         else:
             self._free[i] = (stop, end)
-        if self._mem is None:
-            # np.empty, not mmap: its first touch is the cheaper (PR 39).
-            raw = np.empty(self.capacity + PAGE_BYTES, dtype=np.uint8)
-            skew = -raw.ctypes.data % PAGE_BYTES
-            self._mem = raw[skew : skew + self.capacity]
+        # No byte of a view is ever written by a toucher: they are gone
+        # before the first view exists.
+        self._end_pretouch()
+        self._allocate()
         views, at, recycled = [], start, 0
         for size in lease.sizes:
             views.append(self._mem[at : at + size])
@@ -220,6 +291,34 @@ class HostArena:
             self.in_use_hwm_bytes, sum(held.nbytes for held in self._out)
         )
         return True
+
+    def _allocate(self) -> None:
+        if self._mem is None:
+            # np.empty, not mmap: its first touch is the cheaper (PR 39).
+            raw = np.empty(self.capacity + PAGE_BYTES, dtype=np.uint8)
+            skew = -raw.ctypes.data % PAGE_BYTES
+            self._mem = raw[skew : skew + self.capacity]
+
+    def _touch_all(self, lib: Any, base: int) -> None:
+        self._touch_unfinished.append(
+            native.touch_stripes(lib, base, self._touch, PRETOUCH_STRIPE_BYTES, PAGE_BYTES)
+        )
+
+    def _end_pretouch(self) -> None:
+        """Stop the touchers and wait until the last has left the memory
+        (a page fault away: the engine looks at the flag before every page);
+        what they finished from address 0 up without a gap counts as used."""
+        self._touch.stop = 1  # for good: a lease holds a view from now on
+        if not self._touchers:
+            return
+        t0 = time.monotonic()
+        for toucher in self._touchers:
+            toucher.join()
+        self._touchers = []
+        self.pretouched_bytes = min([self._touch.claimed] + self._touch_unfinished)
+        self.touched_bytes = max(self.touched_bytes, self.pretouched_bytes)
+        now = time.monotonic()
+        self.pretouch_s, self.pretouch_stop_wait_s = now - self._touch_since, now - t0
 
     def _worth_waiting(self, lease: Lease) -> bool:
         return (
@@ -305,6 +404,7 @@ class HostArena:
         then ends); the memory goes with the last of them."""
         with self._lock:
             self._closed = True
+            self._end_pretouch()  # before the memory goes
             self._mem = None
             self._free = []
             self._out = []

@@ -76,6 +76,23 @@ def _build(out_path: str) -> None:
         raise
 
 
+TOUCHED_ALL = 2**64 - 1
+
+
+class TouchState(ctypes.Structure):
+    """What the touchers of one stretch of memory share
+    (:func:`touch_stripes`; ``tss_io.cpp`` has its twin): the owner raises
+    ``wanted`` and sets ``stop``, the engine advances ``claimed`` as stripes
+    are taken and ``done`` as they are finished."""
+
+    _fields_ = [
+        ("claimed", ctypes.c_uint64),
+        ("wanted", ctypes.c_uint64),
+        ("done", ctypes.c_uint64),
+        ("stop", ctypes.c_int32),
+    ]
+
+
 def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tss_io_version.restype = ctypes.c_int
     stamps_out = [
@@ -112,6 +129,14 @@ def _configure(lib: ctypes.CDLL) -> ctypes.CDLL:
     lib.tss_write_bounce_stats.restype = None
     lib.tss_file_size.argtypes = [ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64)]
     lib.tss_file_size.restype = ctypes.c_int
+    lib.tss_touch_stripes.argtypes = [
+        ctypes.c_void_p,
+        ctypes.POINTER(TouchState),
+        ctypes.c_uint64,
+        ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint64),
+    ]
+    lib.tss_touch_stripes.restype = None
     lib.tss_write_file_digest.argtypes = [
         ctypes.c_char_p,
         ctypes.c_void_p,
@@ -402,6 +427,22 @@ def write_bounce_stats(lib: ctypes.CDLL) -> Dict[str, int]:
     out = (ctypes.c_uint64 * 4)()
     lib.tss_write_bounce_stats(ctypes.byref(out))
     return dict(zip(("allocated", "lent", "kept", "kept_bytes"), out))
+
+
+def touch_stripes(
+    lib: ctypes.CDLL, address: int, state: TouchState, stripe_bytes: int, page_bytes: int
+) -> int:
+    """One toucher's whole life, GIL-free: first-touch the memory at
+    ``address`` a byte a page, in stripes claimed from ``state.claimed``
+    upward as far as ``state.wanted`` has come, until ``state.stop`` is set
+    (looked at before every page). Returns the offset of the first page of
+    its last stripe that it left untouched, or :data:`TOUCHED_ALL` where it
+    finished every stripe it claimed."""
+    unfinished_at = ctypes.c_uint64(0)
+    lib.tss_touch_stripes(
+        address, ctypes.byref(state), stripe_bytes, page_bytes, ctypes.byref(unfinished_at)
+    )
+    return unfinished_at.value
 
 
 def file_size(lib: ctypes.CDLL, path: str) -> int:

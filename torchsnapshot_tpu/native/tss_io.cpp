@@ -598,7 +598,7 @@ ReadPool& pool() {
 
 extern "C" {
 
-int tss_io_version() { return 6; }
+int tss_io_version() { return 7; }
 
 // Create/truncate `path` and write `nbytes` from `buf`.
 // use_direct != 0 attempts O_DIRECT via an aligned bounce buffer of
@@ -715,6 +715,54 @@ void tss_write_bounce_stats(uint64_t out[4]) { bounce_pool().stats(out); }
 // in flight since the last configure, bounce buffers held, their bytes,
 // chunks read since the last configure.
 void tss_read_pool_stats(uint64_t out[6]) { pool().stats(out); }
+
+// What the touchers of one stretch of memory share (native.TouchState): the
+// owner raises `wanted` and sets `stop`; the touchers advance `claimed` as
+// they take stripes and `done` as they finish them.
+struct TouchState {
+  uint64_t claimed;
+  uint64_t wanted;
+  uint64_t done;
+  int32_t stop;
+};
+
+// One toucher of host_arena.py: first-touch `base[0, st->wanted)`, a byte a
+// page of `page_bytes`, in stripes of `stripe_bytes` claimed from
+// `st->claimed` upward (so the stripes go out in address order), sleeping
+// where nothing more is wanted yet, until `st->stop` reads non-zero: looked at
+// before every page, so a toucher is out of the memory a page fault after it
+// is told. `*unfinished_at` receives the offset of the first page of its last
+// stripe that it did not touch (UINT64_MAX: it finished every stripe it
+// claimed). The caller owns the memory and nobody reads it yet (no view of it
+// is out while a toucher runs), so what is written is of no account. The
+// whole life of the thread is this one call: it never takes the GIL.
+void tss_touch_stripes(void* base, TouchState* st, uint64_t stripe_bytes,
+                       uint64_t page_bytes, uint64_t* unfinished_at) {
+  volatile char* p = static_cast<volatile char*>(base);
+  *unfinished_at = UINT64_MAX;
+  const struct timespec nap = {0, 50 * 1000};
+  while (__atomic_load_n(&st->stop, __ATOMIC_ACQUIRE) == 0) {
+    uint64_t from = __atomic_load_n(&st->claimed, __ATOMIC_RELAXED);
+    const uint64_t want = __atomic_load_n(&st->wanted, __ATOMIC_ACQUIRE);
+    if (from >= want) {
+      nanosleep(&nap, nullptr);
+      continue;
+    }
+    const uint64_t to = std::min(from + stripe_bytes, want);
+    if (!__atomic_compare_exchange_n(&st->claimed, &from, to, false,
+                                     __ATOMIC_ACQ_REL, __ATOMIC_RELAXED)) {
+      continue;
+    }
+    for (uint64_t at = from; at < to; at += page_bytes) {
+      if (__atomic_load_n(&st->stop, __ATOMIC_RELAXED) != 0) {
+        *unfinished_at = at;
+        return;
+      }
+      p[at] = 0;
+    }
+    __atomic_fetch_add(&st->done, to - from, __ATOMIC_RELEASE);
+  }
+}
 
 // File size probe (0 on success with *size set).
 int tss_file_size(const char* path, uint64_t* size) {

@@ -82,6 +82,9 @@ _SUMS = (
     "recycled_bytes",
     "fresh_target_bytes",
     "target_wait_s",
+    "pretouched_bytes",
+    "pretouch_s",
+    "pretouch_stop_wait_s",
     "mount_bytes",
     "consume_wait_s",
     "place_wait_s",

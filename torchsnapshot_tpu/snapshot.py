@@ -1600,8 +1600,10 @@ class Snapshot:
         # (instead of a fresh ThreadPoolExecutor per stateful).
         pools = PipelinePools()
         # The host pages that device-bound leaves are read into, handed from
-        # leaf to leaf (host_arena.py); nothing is allocated until a leaf
-        # bound for a device that copies wants room. This restore's alone.
+        # leaf to leaf (host_arena.py); nothing is allocated until the plan
+        # meets a leaf bound for a device that copies, and from then until
+        # its first read the pages are first touched in the background. This
+        # restore's alone.
         arena = host_arena.HostArena(min(host_arena.CAPACITY_BYTES, memory_budget))
         # Post-load rendezvous WITH error fan-out (the take path's
         # LinearBarrier, on the read side too): a rank failing mid-restore
@@ -1690,6 +1692,9 @@ class Snapshot:
                             read_totals["requests"] += stats.get(
                                 "requests", 0.0
                             )
+            times.add("pretouched_bytes", arena.pretouched_bytes)
+            times.add("pretouch_s", arena.pretouch_s)
+            times.add("pretouch_stop_wait_s", arena.pretouch_stop_wait_s)
             with times.work("load"):
                 # Restore telemetry artifact
                 # (.telemetry/restore_rank_<k>.json): the restore-side
@@ -3963,6 +3968,9 @@ class _LeasedHostTargets:
             [int(np.prod(shape, dtype=np.int64)) * dtype.itemsize for shape, dtype in specs],
             reads=len(sketch),
         )
+        # Nothing is fetched while the restore plans: the arena's pages are
+        # first touched meanwhile, as far as the planned leases will reach.
+        arena.pretouch(self.lease.nbytes)
         self._targets: Optional[List[np.ndarray]] = None
         self._consumers: Optional[List[Any]] = None
         self.reqs = [
